@@ -53,28 +53,12 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from . import kvtransport, mesh_utils, overlap as overlap_mod, packing, quant
 
-try:  # jax >= 0.4.35
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-# The replication-check kwarg was renamed check_rep -> check_vma across
-# jax releases; probe once which spelling this jax takes.
-import inspect as _inspect
-
-_SHARD_MAP_REP_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_shard_map_impl).parameters
-    else "check_rep"
-)
-
 
 def shard_map_compat(fn, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``shard_map`` across jax versions: forwards ``check_vma`` under
-    whichever replication-check spelling this jax accepts."""
-    return _shard_map_impl(
+    """``jax.shard_map`` with the replication check off by default."""
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_SHARD_MAP_REP_KW: check_vma},
+        check_vma=check_vma,
     )
 
 
@@ -202,11 +186,6 @@ class CommunicatorBase:
         # env -> tuned -> off), "none" pins it off, "int8"/"fp8" scale
         # packed buckets onto that wire dtype around the sum collective.
         self.comm_dtype = quant.canonical_comm_dtype(comm_dtype)
-        # Seed the latency-hiding-scheduler / async-collective XLA flags
-        # while they can still take effect (no-op off-TPU, after backend
-        # init, or when overlap is off — see overlap.ensure_overlap_flags).
-        if self.overlap is not False:
-            overlap_mod.ensure_overlap_flags()
         # Host-plane transport context.  Communicator construction is SPMD
         # (every process builds the same communicators in the same order —
         # the same contract MPI_Comm_create relies on), so a class-level
@@ -984,10 +963,14 @@ class CommunicatorBase:
         ``jax.Array``s sharded along axis 0 over the world
         (``shape[0] = per_host_batch * process_count``).  Per-host leading
         axes must be divisible by the host's local device count.
-        Single-process: returns ``batch`` unchanged.
+        Single-process: the host's batch IS the global batch, and each
+        leaf is placed with the same world sharding — left where
+        ``jnp.asarray`` puts it (the first device), a resident batch
+        would be re-sharded off that one chip at every step.
         """
         if self.size == 1:
-            return batch
+            sharding = jax.sharding.NamedSharding(self.mesh, self._world_spec)
+            return jax.device_put(batch, sharding)
         from jax.experimental import multihost_utils
 
         spec = self._world_spec

@@ -192,16 +192,11 @@ def _is_deadline(e: Exception) -> bool:
     """Did a blocking KV get time out (vs a real transport error)?
 
     jaxlib surfaces the gRPC DEADLINE_EXCEEDED status as
-    ``XlaRuntimeError`` with the status name in the message; match the
-    exception type where jax exports it, plus the status text."""
-    try:
-        from jax.errors import JaxRuntimeError
+    ``JaxRuntimeError`` with the status name in the message; match the
+    exception type plus the status text."""
+    from jax.errors import JaxRuntimeError
 
-        if not isinstance(e, JaxRuntimeError):
-            return False
-    except ImportError:  # older jax: no common base exported
-        pass
-    return "DEADLINE" in str(e).upper()
+    return isinstance(e, JaxRuntimeError) and "DEADLINE" in str(e).upper()
 
 
 def _put_chunks(c, key: str, view: memoryview) -> int:

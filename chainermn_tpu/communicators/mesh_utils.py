@@ -77,17 +77,8 @@ def build_mesh(
 
 
 def axis_size_traced(name: str) -> int:
-    """Static size of a mesh axis from inside ``shard_map``.
-
-    ``jax.lax.axis_size`` only exists in newer jax releases; the portable
-    spelling is ``psum`` of the Python constant 1 over the axis, which
-    constant-folds to the axis size (an ``int``) without emitting a
-    collective.
-    """
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(name)
-    return jax.lax.psum(1, name)
+    """Static size of a mesh axis from inside ``shard_map``."""
+    return jax.lax.axis_size(name)
 
 
 def flat_rank(axes: Sequence[str]):

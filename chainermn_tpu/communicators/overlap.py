@@ -18,14 +18,18 @@ threads:
    compute is still pending.  Each bucket's collective depends only on
    its own member leaves (per-bucket pack, not pack-everything-first),
    keeping the dependence frontier minimal.
-2. **Async lowering** (:data:`OVERLAP_XLA_FLAGS`): the curated flag set
-   that makes the TPU compiler split eligible collectives into
-   ``all-reduce-start``/``all-reduce-done`` pairs and run the
-   latency-hiding scheduler so independent backward compute lands
-   between them.  The flags only matter on real TPU backends; the
-   schedule itself is platform-neutral and bit-exact everywhere (the
-   per-bucket math is identical to the eager path — only trace order
-   changes, and fp addition inside each bucket is untouched).
+2. **Async lowering** is the compiler's: whether a collective becomes
+   an ``all-reduce-start``/``all-reduce-done`` pair with backward
+   compute between the two is libtpu's scheduling.  This package writes
+   no compiler flag — an unknown flag in ``XLA_FLAGS`` aborts the
+   process at backend init (jaxlib 0.9.0), and no chip A/B exists for
+   any.  (At libtpu 0.0.34's defaults the four-chip LM step compiled to
+   17 plain all-reduces and no start/done pair — ``chip_smoke.py``, PR
+   21; which flags, handed to libtpu through ``LIBTPU_INIT_ARGS``,
+   change that is ROADMAP S6's A/B.)  The schedule itself is
+   platform-neutral and bit-exact everywhere (the per-bucket math is
+   identical to the eager path — only trace order changes, and fp
+   addition inside each bucket is untouched).
 
 Escape hatch: ``CHAINERMN_TPU_OVERLAP=0`` restores the eager
 pack-all-then-reduce-all emission.  The schedule's granularity (buckets
@@ -49,26 +53,6 @@ ENV_OVERLAP = "CHAINERMN_TPU_OVERLAP"
 ENV_OVERLAP_GRANULARITY = "CHAINERMN_TPU_OVERLAP_GRANULARITY"
 
 DEFAULT_GRANULARITY = 1
-
-#: Curated XLA flag set for async collectives + latency hiding on TPU.
-#: These make the compiler (a) split all-reduce/all-gather/
-#: collective-permute into start/done pairs, (b) fuse the async pairs
-#: with surrounding loops where legal, and (c) run the latency-hiding
-#: scheduler so independent backward compute is placed between start and
-#: done.  They are TPU-compiler flags: harmless to *carry* in XLA_FLAGS
-#: on CPU runs of the same script, but only applied by
-#: :func:`ensure_overlap_flags` when a TPU backend is plausibly in play
-#: (or ``force=True``), because mutating XLA_FLAGS after backend init is
-#: a silent no-op and unknown flags can abort older jaxlibs.
-OVERLAP_XLA_FLAGS: Tuple[str, ...] = (
-    "--xla_tpu_enable_latency_hiding_scheduler=true",
-    "--xla_tpu_enable_async_collective_fusion=true",
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
-    "--xla_tpu_enable_async_collective_fusion_multiple_steps=true",
-    "--xla_tpu_overlap_compute_collective_tc=true",
-    "--xla_enable_async_all_reduce=true",
-    "--xla_enable_async_collective_permute=true",
-)
 
 
 def overlap_enabled(default: bool = True) -> bool:
@@ -152,40 +136,3 @@ def build_overlap_schedule(
         tuple(order[i : i + g]) for i in range(0, len(order), g)
     )
     return OverlapSchedule(stages=stages, granularity=g)
-
-
-def _tpu_plausible() -> bool:
-    """Whether this process could be headed for a TPU backend, WITHOUT
-    initializing one (checking ``jax.devices()`` here would freeze the
-    backend before the flags land)."""
-    plat = os.environ.get("JAX_PLATFORMS", "").lower()
-    if plat:
-        return "tpu" in plat
-    return bool(
-        os.environ.get("TPU_NAME")
-        or os.environ.get("TPU_WORKER_ID")
-        or os.path.exists("/dev/accel0")
-        or os.path.exists("/dev/vfio")
-    )
-
-
-def ensure_overlap_flags(force: bool = False) -> List[str]:
-    """Idempotently append :data:`OVERLAP_XLA_FLAGS` to ``XLA_FLAGS``.
-
-    Returns the flags newly added (empty when already present, when
-    overlap is disabled via :data:`ENV_OVERLAP`, or when no TPU backend
-    is plausibly in play and ``force`` is False).  Call this BEFORE the
-    first jax backend touch — XLA reads the variable once at init.
-    """
-    if not overlap_enabled():
-        return []
-    if not force and not _tpu_plausible():
-        return []
-    current = os.environ.get("XLA_FLAGS", "")
-    have = set(current.split())
-    added = [f for f in OVERLAP_XLA_FLAGS if f not in have]
-    if added:
-        os.environ["XLA_FLAGS"] = " ".join(
-            ([current] if current else []) + added
-        )
-    return added

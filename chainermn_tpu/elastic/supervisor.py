@@ -37,6 +37,7 @@ stdlib dummy workers.
 from __future__ import annotations
 
 import dataclasses
+import glob
 import json
 import os
 import re
@@ -56,6 +57,36 @@ from chainermn_tpu.elastic.heartbeat import HeartbeatMonitor, read_beat
 EXIT_PREEMPTED = 75
 
 _RESUME_RE = re.compile(r"resumed from iteration (\d+)")
+
+
+def refuse_shared_chips(n_processes: int, env, launcher: str) -> None:
+    """Fail fast when ``launcher`` would start several processes that
+    each take this host's TPU chips.
+
+    A chip belongs to one process at a time, and a JAX process takes
+    every chip it sees: the second such process hangs or dies at backend
+    init.  Giving each child its own chip needs a set of libtpu topology
+    variables per child that nothing here has ever run with, so it is
+    not built — multi-process launchers are not brought up on TPU.
+    Decided from the environment the children inherit (their platform
+    list, else the TPU device files), so the parent stays off the
+    backend."""
+    if n_processes < 2:
+        return
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms:
+        on_tpu = platforms.split(",")[0].strip().lower() == "tpu"
+    else:
+        on_tpu = bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
+    if on_tpu:
+        raise RuntimeError(
+            f"{launcher} would start {n_processes} JAX processes on a TPU "
+            "host, and each would claim every chip: a chip belongs to one "
+            "process, so all but the first hang or die at backend init. "
+            "Per-process chip assignment is not implemented; drive all "
+            "chips from one process, or set JAX_PLATFORMS=cpu for a CPU "
+            "world."
+        )
 
 
 @dataclasses.dataclass
@@ -231,6 +262,9 @@ class ElasticSupervisor:
         coord = f"{cfg.coordinator_host}:{port}"
         inc_dir = os.path.join(self._workdir, f"inc{self.incarnation}")
         os.makedirs(inc_dir, exist_ok=True)
+        refuse_shared_chips(
+            world, {**os.environ, **(cfg.env or {})}, "the elastic supervisor"
+        )
         ranks = []
         for r in range(world):
             hb = os.path.join(inc_dir, f"hb.rank{r}")

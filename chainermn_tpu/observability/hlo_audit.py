@@ -426,26 +426,20 @@ class TracedStep(NamedTuple):
 def trace_step(fn, *args, **kwargs) -> TracedStep:
     """Trace ``fn(*args, **kwargs)`` without executing it.
 
-    Accepts plain callables AND already-``jax.jit``-wrapped ones: a jitted
-    callable is traced through its own AOT ``.trace`` surface (one trace,
-    reusing jit's cached machinery — no re-wrap double-trace), which also
-    exposes its ``donate_argnums``; everything else goes through
-    ``jax.make_jaxpr``, kwargs included.  Args may be real arrays or
-    ``jax.ShapeDtypeStruct``s."""
+    Accepts plain callables AND already-``jax.jit``-wrapped ones: a
+    callable with jit's AOT ``.trace`` surface is traced through it (one
+    trace, reusing jit's cached machinery — no re-wrap double-trace),
+    which also exposes its ``donate_argnums``, and an error from that
+    trace is the caller's to see: re-tracing with ``make_jaxpr`` would
+    lose the donation declaration and let the R005 audit pass on
+    ``None``.  Everything else goes through ``jax.make_jaxpr``, kwargs
+    included.  Args may be real arrays or ``jax.ShapeDtypeStruct``s."""
     import jax
 
     tracer = getattr(fn, "trace", None)
     if callable(tracer):
-        try:
-            tr = tracer(*args, **kwargs)
-            closed = getattr(tr, "jaxpr", None)
-            if closed is not None:
-                donate = getattr(tr, "donate_argnums", None)
-                return TracedStep(
-                    closed, tuple(donate) if donate is not None else None
-                )
-        except Exception:
-            pass  # not jit's AOT surface — fall through to make_jaxpr
+        tr = tracer(*args, **kwargs)
+        return TracedStep(tr.jaxpr, tuple(tr.donate_argnums))
     return TracedStep(jax.make_jaxpr(fn)(*args, **kwargs), None)
 
 

@@ -1,8 +1,8 @@
 """Trace spans — named regions visible in three sinks at once.
 
 ``with span("fwd"):`` stamps the region onto the profiler timeline
-(``utils/profiling.annotate`` → Perfetto/TensorBoard, a no-op when
-``jax.profiler`` is unavailable), measures the host-side duration, and
+(``utils/profiling.annotate`` → Perfetto/TensorBoard), measures the
+host-side duration, and
 publishes it to whichever telemetry sinks are active: the current
 :class:`~chainermn_tpu.observability.reporter.Reporter` (as a
 ``span/<name>`` scalar + histogram) and the current

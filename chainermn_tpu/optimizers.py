@@ -59,6 +59,41 @@ def _check_batch_divisibility(batch, n_dev, n_accum=1):
             )
 
 
+_AOT_METHODS = ("lower", "trace", "eval_shape")
+
+
+def _forward_aot(step, jitted_for):
+    """Give a plain-function ``step`` wrapper jit's AOT surface.
+
+    ``lower``/``trace``/``eval_shape`` are methods of the jitted callable,
+    not entries of its ``__dict__``, so ``functools.wraps`` does not carry
+    them.  ``jitted_for(*args)`` returns the jitted callable ``step``
+    dispatches those arguments to (ZeRO steps build theirs per parameter
+    tree).  ``bench.py`` lowers the step for XLA's cost model and the
+    collective linter reads donation off ``trace``: a step without the
+    surface is an error there, not a fallback."""
+
+    def forward(name):
+        def method(*args, **kwargs):
+            return getattr(jitted_for(*args), name)(*args, **kwargs)
+
+        return method
+
+    for name in _AOT_METHODS:
+        setattr(step, name, forward(name))
+    return step
+
+
+def _carry_step_surface(wrapper, step_fn):
+    """Re-expose a built step's AOT surface (and the recompile-count
+    guard's ``_cache_size``, where the step has one) on ``wrapper``."""
+    for name in _AOT_METHODS:
+        setattr(wrapper, name, getattr(step_fn, name))
+    if hasattr(step_fn, "_cache_size"):
+        wrapper._cache_size = step_fn._cache_size
+    return wrapper
+
+
 def _instrument_step(step_fn):
     """Telemetry wrapper for a built train step: when a Reporter or
     StepRecorder is installed (``observability.telemetry_active``) each
@@ -81,13 +116,7 @@ def _instrument_step(step_fn):
             rep.count("train_step_calls")
         return out
 
-    # Keep jit's AOT surface reachable (bench.py lowers the step for
-    # XLA's cost model; the recompile-count guard reads _cache_size);
-    # plain-function steps just skip this.
-    for attr in ("lower", "eval_shape", "trace", "_cache_size"):
-        if hasattr(step_fn, attr):
-            setattr(instrumented, attr, getattr(step_fn, attr))
-    return instrumented
+    return _carry_step_surface(instrumented, step_fn)
 
 
 def _run_first_call_lint(step_fn, comm, mode, args, kwargs):
@@ -149,10 +178,7 @@ def _lint_hook(step_fn, comm):
             _run_first_call_lint(step_fn, comm, mode, args, kwargs)
         return step_fn(*args, **kwargs)
 
-    for attr in ("lower", "eval_shape", "trace", "_cache_size"):
-        if hasattr(step_fn, attr):
-            setattr(linted, attr, getattr(step_fn, attr))
-    return linted
+    return _carry_step_surface(linted, step_fn)
 
 
 def flat_shard_state_spec(optimizer, shard_size: int, world):
@@ -676,9 +702,8 @@ class MultiNodeOptimizer:
             _check_batch_divisibility(batch, n_dev, n_accum)
             return jitted(params, state, batch)
 
-        if hasattr(jitted, "_cache_size"):
-            step._cache_size = jitted._cache_size
-        return self._finalize_step(step)
+        step._cache_size = jitted._cache_size
+        return self._finalize_step(_forward_aot(step, lambda *a: jitted))
 
     def _scatter_grads(self, grads, shard_size, n, world):
         """Pack a full local gradient tree and reduce-scatter it to this
@@ -780,17 +805,20 @@ class MultiNodeOptimizer:
 
         compiled = {}
 
-        def step(params, state, batch):
+        def jitted_for(params, *_):
             # PyTreeDefs are hashable and stable — safe cache keys (an id()
             # of a temporary would be reusable after GC).
-            _check_batch_divisibility(batch, comm.device_size, n_accum)
             key = jax.tree.structure(params)
             fn = compiled.get(key)
             if fn is None:
                 fn = compiled[key] = make(params)
-            return fn(params, state, batch)
+            return fn
 
-        return step
+        def step(params, state, batch):
+            _check_batch_divisibility(batch, comm.device_size, n_accum)
+            return jitted_for(params)(params, state, batch)
+
+        return _forward_aot(step, jitted_for)
 
     def _make_zero3_train_step(
         self, loss_fn, batch_spec, donate, has_aux, rng, n_accum, loss_scale
@@ -845,12 +873,11 @@ class MultiNodeOptimizer:
 
         compiled = {}
 
-        def step(flat_params, state, batch):
+        def jitted_for(flat_params, *_):
             if self._z3_meta is None:
                 raise RuntimeError(
                     "zero_stage=3: call init(params) (or shard_params) first"
                 )
-            _check_batch_divisibility(batch, comm.device_size, n_accum)
             # The traced body bakes in the unpack metadata, so the cache key
             # must include it — same padded size with a different tree
             # layout must re-trace, not silently reuse the wrong unpacking.
@@ -859,9 +886,14 @@ class MultiNodeOptimizer:
             fn = compiled.get(key)
             if fn is None:
                 fn = compiled[key] = make(flat_params)
+            return fn
+
+        def step(flat_params, state, batch):
+            fn = jitted_for(flat_params)
+            _check_batch_divisibility(batch, comm.device_size, n_accum)
             return fn(flat_params, state, batch)
 
-        return step
+        return _forward_aot(step, jitted_for)
 
     def make_train_step_with_state(
         self,
@@ -974,15 +1006,18 @@ class MultiNodeOptimizer:
 
             compiled = {}
 
-            def step(params, state, model_state, batch):
-                _check_batch_divisibility(batch, comm.device_size)
+            def jitted_for(params, *_):
                 key = jax.tree.structure(params)
                 fn = compiled.get(key)
                 if fn is None:
                     fn = compiled[key] = make(params)
-                return fn(params, state, model_state, batch)
+                return fn
 
-            return step
+            def step(params, state, model_state, batch):
+                _check_batch_divisibility(batch, comm.device_size)
+                return jitted_for(params)(params, state, model_state, batch)
+
+            return _forward_aot(step, jitted_for)
 
         # zero_stage == 3: flat sharded master buffer in place of params.
         def body3(pshard, state, model_state, batch):
@@ -1011,20 +1046,24 @@ class MultiNodeOptimizer:
 
         compiled3 = {}
 
-        def step3(flat_params, state, model_state, batch):
+        def jitted_for3(flat_params, *_):
             if self._z3_meta is None:
                 raise RuntimeError(
                     "zero_stage=3: call init(params) (or shard_params) first"
                 )
-            _check_batch_divisibility(batch, comm.device_size)
             treedef, metas = self._z3_meta
             key = (flat_params.shape, treedef, tuple(metas))
             fn = compiled3.get(key)
             if fn is None:
                 fn = compiled3[key] = make3(flat_params)
+            return fn
+
+        def step3(flat_params, state, model_state, batch):
+            fn = jitted_for3(flat_params)
+            _check_batch_divisibility(batch, comm.device_size)
             return fn(flat_params, state, model_state, batch)
 
-        return step3
+        return _forward_aot(step3, jitted_for3)
 
     # ------------------------------------------------------------------
     # Imperative parity API (reference: optimizer.setup(model) + update())

@@ -25,13 +25,8 @@ from jax import lax
 
 
 def _axis_size(axis_name) -> int:
-    """Static mapped-axis size across jax versions: ``lax.axis_size`` where
-    it exists; on older jax ``core.axis_frame(name)`` IS the size."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    from jax import core
-
-    return core.axis_frame(axis_name)
+    """Static mapped-axis size."""
+    return lax.axis_size(axis_name)
 
 
 def topk_route(gate_logits: jax.Array, n_experts: int, capacity: int,

@@ -648,9 +648,15 @@ def run_shard_groups(args) -> int:
     import socket
     import subprocess
 
+    from chainermn_tpu.elastic.supervisor import refuse_shared_chips
+
     if args.verify:
         args.sampled = True
     size = 1 + args.groups * args.tp * args.pp
+    # The router (this process) joins the jax.distributed world and
+    # touches the backend too, so every one of the ``size`` processes
+    # would hold chips.
+    refuse_shared_chips(size, os.environ, "tools.serve --tp/--pp")
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]
@@ -865,4 +871,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from chainermn_tpu.utils.profiling import setup_compilation_cache
+
+    setup_compilation_cache()
     sys.exit(main())

@@ -292,9 +292,7 @@ def main(argv=None):
                         print(f"preempted: checkpoint saved at "
                               f"iteration {gstep}")
                     ctx.exit_preempted()
-            gb = (batch[0], batch[1])
-            if comm.size > 1:
-                gb = comm.global_batch(gb)
+            gb = comm.global_batch((batch[0], batch[1]))
             if recorder is not None and gstep == 0:
                 from chainermn_tpu import observability as obs
 
@@ -353,4 +351,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from chainermn_tpu.utils.profiling import setup_compilation_cache
+
+    setup_compilation_cache()
     main()

@@ -181,10 +181,10 @@ def main(argv=None):
                         f"leaves={plan_report.n_leaves} world={comm.size}"
                     )
 
-    # Multi-process deployment: each process draws a LOCAL slice of the
-    # global batch from its scattered shard and comm.global_batch
-    # assembles the device-global arrays (single-process runs keep the
-    # exact original arithmetic: local slice == global batch).
+    # Each process draws a LOCAL slice of the global batch from its
+    # scattered shard and comm.global_batch assembles the device-global
+    # arrays, sharded over the world (single-process: local slice ==
+    # global batch).
     if args.batchsize % comm.size:
         raise SystemExit(
             f"--batchsize {args.batchsize} must divide by the process "
@@ -224,9 +224,7 @@ def main(argv=None):
                         print(f"preempted: checkpoint saved at "
                               f"iteration {gstep}")
                     ctx.exit_preempted()
-            gb = (batch[0], batch[1])
-            if comm.size > 1:
-                gb = comm.global_batch(gb)
+            gb = comm.global_batch((batch[0], batch[1]))
             if recorder is not None and gstep == 0:
                 from chainermn_tpu import observability as obs
 
@@ -287,4 +285,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from chainermn_tpu.utils.profiling import setup_compilation_cache
+
+    setup_compilation_cache()
     main()

@@ -135,4 +135,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from chainermn_tpu.utils.profiling import setup_compilation_cache
+
+    setup_compilation_cache()
     main()

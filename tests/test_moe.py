@@ -281,17 +281,6 @@ def test_moe_rejects_mismatched_gate_width(ep_mesh):
         jax.jit(f)(x, gate_w, experts)
 
 
-def _shard_map_norep(body, **kw):
-    """shard_map with replication checking off, across jax versions
-    (the kwarg was renamed check_rep -> check_vma)."""
-    for flag in ("check_vma", "check_rep"):
-        try:
-            return shard_map(body, **{**kw, flag: False})
-        except TypeError:
-            continue
-    return shard_map(body, **kw)
-
-
 def test_return_aux_scalar_shim(ep_mesh):
     """One-release back-compat: ``return_aux='scalar'`` restores the old
     ``(y, load_balance_loss)`` contract (with a DeprecationWarning);
@@ -316,11 +305,11 @@ def test_return_aux_scalar_shim(ep_mesh):
         in_specs=(P("intra"), P(), P("intra")),
         out_specs=(P("intra"), P()),
     )
-    y_new, lbl_new = jax.jit(_shard_map_norep(body(True), **specs))(
+    y_new, lbl_new = jax.jit(shard_map(body(True), **specs))(
         x, gate_w, experts
     )
     with pytest.warns(DeprecationWarning, match="scalar"):
-        y_old, lbl_old = jax.jit(_shard_map_norep(body("scalar"), **specs))(
+        y_old, lbl_old = jax.jit(shard_map(body("scalar"), **specs))(
             x, gate_w, experts
         )
     # The shim's scalar IS the dict's load_balance_loss; y unchanged.

@@ -509,3 +509,32 @@ def test_imperative_api_full_feature_matrix(mesh):
         np.testing.assert_allclose(
             np.asarray(tgt[k]), np.asarray(rp[k]), rtol=1e-4, atol=1e-5
         )
+
+
+@pytest.mark.parametrize("zero_stage", [0, 1, 3])
+def test_steps_expose_jit_aot_surface(devices8, zero_stage):
+    """bench.py lowers the step for XLA's cost model and the collective
+    linter reads donation off ``trace``: every built step carries jit's
+    ``lower``/``trace``/``eval_shape`` through its wrappers."""
+    comm = create_communicator("xla_ici")
+    opt = create_multi_node_optimizer(
+        optax.sgd(0.1), comm, zero_stage=zero_stage)
+    params = {"w": jnp.ones((16, 4))}
+    state = opt.init(params)
+    if zero_stage == 3:
+        params = opt.shard_params(params)
+    batch = jnp.ones((8, 16))
+    stats = {"n": jnp.zeros(())}
+    plain = opt.make_train_step(
+        lambda p, b: jnp.mean((b @ p["w"]) ** 2), donate=True)
+    with_state = opt.make_train_step_with_state(
+        lambda p, s, b: (jnp.mean((b @ p["w"]) ** 2), s), donate=True)
+    for step, args, donated in (
+        (plain, (params, state, batch), (0, 1)),
+        (with_state, (params, state, stats, batch), (0, 1, 2)),
+    ):
+        assert step.trace(*args).donate_argnums == donated
+        assert step.eval_shape(*args)[-1].shape == ()
+        hlo = step.lower(*args).compile().as_text()
+        assert "all-reduce" in hlo or "reduce-scatter" in hlo
+
