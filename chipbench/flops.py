@@ -1,0 +1,55 @@
+"""Operations and bytes the algorithm needs, from shapes alone, and the
+table of peaks.  The yardstick's own copy: ``bench.py``'s model-FLOP
+arithmetic (Megatron convention, no recomputation counted) was sound and is
+copied here; ``PERF.md`` lists the original for a later PR to delete."""
+
+import json
+import os
+
+_HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def peaks(device_kind):
+    """Published peaks of one chip; an unknown kind is an error."""
+    with open(os.path.join(_HERE, "peaks.json")) as f:
+        table = json.load(f)
+    if device_kind not in table or device_kind == "source":
+        raise RuntimeError(
+            f"no peaks recorded for device kind {device_kind!r} in "
+            f"chipbench/peaks.json (known: "
+            f"{sorted(k for k in table if k != 'source')})")
+    return table[device_kind]
+
+
+def lm_train_flops_per_token(n_params, seq_len, d_model, n_layers):
+    """6 n_params (2 forward + 4 backward) plus causal attention
+    12 span_avg d per layer (QK^T and AV are 4 span d forward, backward
+    twice that), span_avg = (S + 1) / 2 keys a query, self included."""
+    span_avg = (seq_len + 1) / 2.0
+    return 6.0 * n_params + 12.0 * span_avg * d_model * n_layers
+
+
+def causal_attention_flops(batch, seq_len, n_heads, d_head, n_layers):
+    """Needed FLOPs of causal attention, forward + backward, for one step:
+    the same 12 span_avg d a token a layer as above (d = heads x d_head);
+    the masked half is not needed and not counted."""
+    span_avg = (seq_len + 1) / 2.0
+    return (12.0 * span_avg * n_heads * d_head * n_layers
+            * batch * seq_len)
+
+
+def causal_attention_bytes(batch, seq_len, n_heads, d_head, n_layers,
+                           itemsize=2):
+    """Least HBM traffic of flash attention forward + backward for one
+    step: forward reads Q, K, V and writes O; backward reads Q, K, V, O,
+    dO and writes dQ, dK, dV — 12 passes over a (B, S, H, D) tensor in the
+    compute type (the per-row log-sum-exp is 1/D of one and left out)."""
+    return 12.0 * batch * seq_len * n_heads * d_head * itemsize * n_layers
+
+
+def roofline_seconds(flops, nbytes, peak):
+    """The least time the chip could take, and which bound applies."""
+    t_flops = flops / peak["bf16_flops"]
+    t_bytes = nbytes / peak["hbm_bytes_per_s"]
+    return max(t_flops, t_bytes), (
+        "compute" if t_flops >= t_bytes else "memory")
