@@ -13,9 +13,13 @@ telemetry is library-native and SPMD-aware:
   (``jax.monitoring``) and device-memory stats.
 * :mod:`hlo_audit` — per-collective counts and per-mesh-axis operand
   bytes of any traced step fn (the generalized bench census).
-* :func:`span` — named regions on the profiler timeline AND in the
-  JSONL log with host-side durations; :func:`named_scope` for traced
-  code.
+* :mod:`spans` — the scope vocabulary: :func:`named_scope` for traced
+  code (device time by scope), :func:`annotate` / :func:`span` for host
+  regions on the profiler's clock (``span`` also publishes the host-side
+  duration to the Reporter and the JSONL log).
+* :mod:`device_trace` — the reader of a profiler capture: device time
+  by step phase and kernel region, idle gaps by host span
+  (:func:`device_trace.capture` around a few steps).
 * :mod:`tracing` — cross-replica request tracing for the serving tier:
   :class:`SpanCtx` contexts over the cluster wire, a crash-surviving
   :class:`FlightRecorder`, Chrome-trace export, per-stage percentiles,
@@ -58,7 +62,9 @@ from chainermn_tpu.observability.exporter import (  # noqa: F401
 from chainermn_tpu.observability.anomaly import (  # noqa: F401
     AnomalyDetector,
 )
+from chainermn_tpu.observability import device_trace  # noqa: F401
 from chainermn_tpu.observability.spans import (  # noqa: F401
+    annotate,
     named_scope,
     span,
     telemetry_active,

@@ -1,0 +1,461 @@
+"""Device time by program scope — the program's one reader of a profiler
+capture.
+
+A capture's op events say *which instruction* ran and when; the compiled
+program says *which scope* each instruction was traced under
+(``metadata={op_name="jit(train_step)/fwd-bwd/jvp(TransformerLM)/..."}``
+in ``compiled.as_text()``; a fusion carries its root's).  Instruction
+names are unique within a program and the capture's op events are named
+by them, so the join needs nothing but public API:
+
+    scope_table(compiled)        {instruction name: op_name path}
+    attribute(ops, table)        device seconds by phase and by region
+    idle_by_host_span(ops, host) idle seconds by ``chainermn:`` host span
+    capture({name: jitted})      profile a caller's k steps, return one
+                                 report (and hand it to the sinks)
+
+Two readings of one step (the vocabulary is ``observability/spans.py``):
+
+* **phase** — the outermost of ``fwd-bwd`` / ``allreduce`` /
+  ``opt-update`` on an op's path.  Phases partition the busy time: with
+  the time no phase claims (``unattributed``) they add up to ``busy``.
+* **region** — the innermost kernel or allreduce-stage name
+  (``flash-fwd``, ``fused-ce``, ``grad-pack``, ...): a finer cut of
+  *part* of the busy time.
+
+A fusion carries the ``op_name`` of ONE of the ops the compiler fused
+into it: a weight-gradient matmul with the AdamW update fused in counts
+whole as ``fwd-bwd`` (the matmul's) on the TPU compiler of this toolchain.
+``mixed`` is the busy time of such fusions — those that hold ops of a
+second phase — and so bounds what the phase reading cannot split.
+Container ops (``while``, ``conditional``, ``call``) span the ops of
+their bodies and are left out; where two ops overlap, the time goes to
+the one that started last.
+"""
+
+from __future__ import annotations
+
+import bisect
+import glob
+import os
+import re
+import shutil
+import tempfile
+from typing import Dict, Iterable, List, Optional, Tuple
+
+from chainermn_tpu.observability import hlo_audit, spans
+
+CONTAINERS = ("while", "conditional", "call")
+DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+HOST_PLANE = "/host:CPU"
+#: A capture is refused (``attribute`` still returns, readers must not
+#: report) when less than this share of the busy time joins to the table.
+MIN_JOINED_SHARE = 0.98
+
+_WRAPPER = re.compile(r"^[\w\-.]+\((.*)\)$")
+_MODULE_EVENT = re.compile(r"^([\w.\-]+?)(?:\(\d+\))?$")
+
+Triple = Tuple[str, float, float]
+
+
+class ScopeTable(dict):
+    """``{instruction name: op_name path}`` of one compiled program.
+    ``containers`` holds the names of its ``while`` / ``conditional`` /
+    ``call`` instructions, ``mixed`` those of the fusions that hold ops
+    of another step phase than the one the fusion itself is named under,
+    ``program`` the module's name (``jit_train_step``)."""
+
+    def __init__(self, paths=(), containers=(), program="", mixed=()):
+        super().__init__(paths)
+        self.containers = frozenset(containers)
+        self.mixed = frozenset(mixed)
+        self.program = program
+
+
+def scope_table(compiled) -> ScopeTable:
+    """The table of a compiled program (``jitted.lower(...).compile()``)
+    or of its ``as_text()``."""
+    text = compiled if isinstance(compiled, str) else compiled.as_text()
+    instructions = hlo_audit.hlo_instructions(text)
+    paths, containers = {}, set()
+    phases_in: Dict[str, set] = {}
+    for ins in instructions:
+        paths[ins.name] = ins.op_name
+        if ins.opcode in CONTAINERS:
+            containers.add(ins.name)
+        phase = classify(ins.op_name)[0]
+        if phase is not None:
+            phases_in.setdefault(ins.computation, set()).add(phase)
+    mixed = set()
+    for ins in instructions:
+        if ins.opcode != "fusion":
+            continue
+        inside = set().union(*(phases_in.get(c, ()) for c in ins.operands))
+        if inside - {classify(ins.op_name)[0]}:
+            mixed.add(ins.name)
+    return ScopeTable(paths, containers, hlo_audit.hlo_module_name(text),
+                      mixed)
+
+
+def instruction_name(text: str) -> str:
+    """The instruction an op event is named by.  On this toolchain the
+    event's name is the whole instruction text (``%fusion.12 = f32[..]
+    fusion(...)``); a bare name passes through."""
+    head = text.split(" = ", 1)[0] if " = " in text else text
+    return head.strip().removeprefix("ROOT ").lstrip("%")
+
+
+def scope_components(path: str) -> List[str]:
+    """The components of an ``op_name`` path with the transformation
+    wrappers taken off: ``transpose(jvp(fwd-bwd))`` -> ``fwd-bwd``,
+    ``jvp()`` -> nothing."""
+    out = []
+    for part in path.split("/"):
+        while True:
+            m = _WRAPPER.match(part)
+            if m is None:
+                break
+            part = m.group(1)
+        if part:
+            out.append(part)
+    return out
+
+
+def classify(path: str) -> Tuple[Optional[str], Optional[str]]:
+    """``(phase, region)`` of a path: the outermost step phase and the
+    innermost kernel / allreduce-stage name, ``None`` where it has none."""
+    phase = region = None
+    for part in scope_components(path):
+        if phase is None and part in spans.STEP_PHASES:
+            phase = part
+        elif part not in spans.STEP_PHASES and spans.is_scope(part):
+            region = part
+    return phase, region
+
+
+def attribute(ops: Iterable[Triple], table: ScopeTable) -> dict:
+    """Device seconds of one device's ops by scope.
+
+    ``ops`` are ``(name, start, end)`` triples (seconds; the name an
+    instruction's, or its whole text).  Returns ``{"phase": {name: s},
+    "region": {name: s}, "unattributed": s, "joined": s, "busy": s}``:
+    ``busy`` is the union of the op intervals, ``joined`` the part of it
+    whose instruction the table holds, ``unattributed`` the part whose
+    instruction it does not hold or whose path names no phase,
+    ``mixed`` the part spent in fusions that also hold ops of another
+    phase (the compiler names a fusion after one of the ops it fused, so
+    a weight-gradient matmul with the optimizer update fused in counts
+    whole under one of the two: ``mixed`` bounds what the phase reading
+    cannot split).
+    """
+    live = []
+    for name, start, end in ops:
+        key = instruction_name(name)
+        if key in table.containers or end <= start:
+            continue
+        live.append((start, end, key))
+    live.sort()
+    out = {"phase": {}, "region": {}, "unattributed": 0.0, "joined": 0.0,
+           "mixed": 0.0, "busy": 0.0}
+
+    def credit(key, seconds):
+        out["busy"] += seconds
+        if key in table.mixed:
+            out["mixed"] += seconds
+        path = table.get(key)
+        if path is None:
+            out["unattributed"] += seconds
+            return
+        out["joined"] += seconds
+        phase, region = classify(path)
+        if phase is None:
+            out["unattributed"] += seconds
+        else:
+            out["phase"][phase] = out["phase"].get(phase, 0.0) + seconds
+        if region is not None:
+            out["region"][region] = out["region"].get(region, 0.0) + seconds
+
+    # Sweep in start order; at every instant the time goes to the running
+    # op that started last, so that the readings partition the union.
+    stack: list = []
+    now = 0.0
+    for start, end, key in live + [(float("inf"), float("inf"), None)]:
+        while stack and now < start:
+            top_end, top_key = stack[-1]
+            if top_end <= now:
+                stack.pop()
+                continue
+            until = min(top_end, start)
+            credit(top_key, until - now)
+            now = until
+        now = start
+        stack.append((end, key))
+    return out
+
+
+def _merged(ops: Iterable[Triple]) -> List[List[float]]:
+    """The op intervals merged into disjoint busy intervals, in order."""
+    busy: list = []
+    for _, start, end in sorted(ops, key=lambda o: o[1]):
+        if busy and start <= busy[-1][1]:
+            busy[-1][1] = max(busy[-1][1], end)
+        else:
+            busy.append([start, end])
+    return busy
+
+
+def idle_by_host_span(ops: Iterable[Triple],
+                      host_events: Iterable[Triple]) -> Dict[str, float]:
+    """Idle seconds of one device between its first and last op, each gap
+    put down to the ``chainermn:`` host annotation that covers most of
+    it (``"unannotated"`` where none does), on the capture's one clock."""
+    busy = _merged(ops)
+    host = [h for h in host_events if h[0].startswith(spans.HOST_PREFIX)]
+    out: Dict[str, float] = {}
+    for (_, gap_start), (gap_end, _) in zip(busy, busy[1:]):
+        best, label = 0.0, "unannotated"
+        for name, start, end in host:
+            cover = min(gap_end, end) - max(gap_start, start)
+            if cover > best:
+                best, label = cover, name
+        out[label] = out.get(label, 0.0) + (gap_end - gap_start)
+    return out
+
+
+def _mean(values) -> float:
+    values = list(values)
+    return sum(values) / len(values) if values else 0.0
+
+
+def _abstract(tree):
+    import jax
+
+    def leaf(x):
+        if not isinstance(x, jax.Array):
+            return x
+        # An array left where ``jnp.asarray`` put it is not committed to
+        # that device: the program places it, so no sharding is noted.
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if x.committed else None)
+
+    return jax.tree.map(leaf, tree)
+
+
+def read_capture(path: str):
+    """``(devices, host)`` of an ``.xplane.pb`` file (``.gz`` accepted),
+    through ``jax.profiler.ProfileData``: per device plane its op and
+    program (module) events as ``(name, start, end)`` triples in seconds,
+    and the host threads' ``chainermn:`` annotations."""
+    from jax.profiler import ProfileData
+
+    if path.endswith(".gz"):
+        import gzip
+
+        with gzip.open(path, "rb") as f:
+            data = ProfileData.from_serialized_xspace(f.read())
+    else:
+        data = ProfileData.from_file(path)
+
+    def triples(line):
+        return [(ev.name, ev.start_ns * 1e-9,
+                 (ev.start_ns + ev.duration_ns) * 1e-9)
+                for ev in line.events]
+
+    devices, host = [], []
+    for plane in data.planes:
+        if DEVICE_PLANE.match(plane.name):
+            lines = {ln.name: ln for ln in plane.lines}
+            if OPS_LINE in lines:
+                devices.append({
+                    "name": plane.name,
+                    "ops": triples(lines[OPS_LINE]),
+                    "modules": (triples(lines[MODULES_LINE])
+                                if MODULES_LINE in lines else []),
+                })
+        elif plane.name == HOST_PLANE:
+            for ln in plane.lines:
+                host.extend(t for t in triples(ln)
+                            if t[0].startswith(spans.HOST_PREFIX))
+    return devices, host
+
+
+def report_from(devices, host, tables: Dict[str, ScopeTable]) -> dict:
+    """One report from what :func:`read_capture` returns and the scope
+    table of each named program.  Per program: how often it ran and, a
+    call, its device ms by phase and by region (mean over devices).  Ops
+    of programs that were not named count as busy time of ``"other"``."""
+    by_module = {t.program: name for name, t in tables.items()}
+    programs: Dict[str, dict] = {}
+    idle: Dict[str, float] = {}
+    window = busy = 0.0
+    for dev in devices:
+        ops = sorted(dev["ops"], key=lambda o: o[1])
+        # Programs run one after another on a device: an op belongs to
+        # the last program run that started before it.
+        runs = []
+        for mod_name, start, end in sorted(dev["modules"],
+                                           key=lambda m: m[1]):
+            m = _MODULE_EVENT.match(mod_name)
+            runs.append((start, end, by_module.get(
+                m.group(1) if m else mod_name, "other")))
+        starts = [r[0] for r in runs]
+        inside: Dict[str, list] = {}
+        for op in ops:
+            i = bisect.bisect_right(starts, op[1]) - 1
+            if i >= 0 and op[2] <= runs[i][1]:
+                inside.setdefault(runs[i][2], []).append(op)
+        for name, picked in inside.items():
+            got = attribute(picked, tables.get(name, ScopeTable()))
+            got["calls"] = sum(1 for r in runs if r[2] == name)
+            programs.setdefault(name, []).append(got)
+        merged = _merged(ops)
+        if merged:
+            busy += sum(end - start for start, end in merged)
+            window += merged[-1][1] - merged[0][0]
+        for label, seconds in idle_by_host_span(ops, host).items():
+            idle[label] = idle.get(label, 0.0) + seconds
+    n = max(len(devices), 1)
+    out = {"devices": len(devices), "window_s": window / n,
+           "busy_s": busy / n,
+           "idle_share": 1.0 - busy / window if window else None,
+           "idle_by_host_span_ms": {k: v / n * 1e3
+                                    for k, v in sorted(idle.items())},
+           "programs": {}}
+    for name, per_dev in sorted(programs.items()):
+        calls = _mean(g["calls"] for g in per_dev)
+
+        def ms(field, key=None):
+            values = (g[field] if key is None else g[field].get(key, 0.0)
+                      for g in per_dev)
+            return _mean(values) / max(calls, 1) * 1e3
+
+        def ms_by_name(field):
+            names = sorted({k for g in per_dev for k in g[field]})
+            return {k: ms(field, k) for k in names}
+
+        total = _mean(g["busy"] for g in per_dev)
+        out["programs"][name] = {
+            "calls": calls,
+            "busy_ms": ms("busy"),
+            "unattributed_ms": ms("unattributed"),
+            "mixed_ms": ms("mixed"),
+            "joined_share": (_mean(g["joined"] for g in per_dev) / total
+                             if total else None),
+            "phase_ms": ms_by_name("phase"),
+            "region_ms": ms_by_name("region"),
+        }
+    return out
+
+
+class Capture:
+    """A profiler session around a caller's few steps.
+
+    ``programs`` maps a name to a jitted callable (anything with
+    ``lower``: ``jax.jit`` results, the steps ``make_train_step``
+    builds).  Call them through ``cap[name]`` inside the session: that
+    notes the abstract arguments (shapes, dtypes, shardings — taken
+    before the call, donated buffers are gone after it), from which
+    :meth:`stop` lowers and compiles each program once more for its
+    scope table (a compilation-cache hit).
+
+        with device_trace.capture({"train_step": step}) as cap:
+            for _ in range(4):
+                params, state, loss = cap["train_step"](params, state, batch)
+            jax.block_until_ready(loss)
+        cap.report["programs"]["train_step"]["phase_ms"]
+
+    End the block with the device drained, or the last steps are cut.
+    The Python tracer is off (it slows the host by a few per cent and
+    nothing here reads it).  On exit the report goes to the sinks that
+    are installed: one ``device_profile`` row of the current
+    ``StepRecorder``, ``device/<program>/<scope>_ms`` scalars of the
+    current ``Reporter``.
+    """
+
+    def __init__(self, programs: dict, logdir: Optional[str] = None):
+        self.programs = dict(programs)
+        self.logdir = logdir
+        self.report: Optional[dict] = None
+        self._args: Dict[str, tuple] = {}
+        self._dir: Optional[str] = None
+
+    def __getitem__(self, name):
+        fn = self.programs[name]
+
+        def noted(*args, **kwargs):
+            self._args[name] = (_abstract(args), _abstract(kwargs))
+            return fn(*args, **kwargs)
+
+        return noted
+
+    def start(self) -> "Capture":
+        import jax
+
+        self._dir = self.logdir or tempfile.mkdtemp(
+            prefix="chainermn_tpu_trace_")
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(self._dir, profiler_options=options)
+        return self
+
+    def _end_session(self, read: bool):
+        import jax
+
+        jax.profiler.stop_trace()
+        try:
+            if not read:
+                return None
+            found = glob.glob(os.path.join(
+                self._dir, "plugins", "profile", "*", "*.xplane.pb"))
+            if not found:
+                raise RuntimeError(
+                    f"the profiler wrote no trace to {self._dir}")
+            return read_capture(max(found, key=os.path.getmtime))
+        finally:
+            if self.logdir is None:
+                shutil.rmtree(self._dir, ignore_errors=True)
+
+    def stop(self) -> dict:
+        devices, host = self._end_session(read=True)
+        tables = {}
+        for name, (args, kwargs) in self._args.items():
+            compiled = self.programs[name].lower(*args, **kwargs).compile()
+            tables[name] = scope_table(compiled)
+        self.report = report_from(devices, host, tables)
+        publish(self.report)
+        return self.report
+
+    def __enter__(self):
+        return self.start()
+
+    def __exit__(self, exc_type, exc, tb):
+        if exc_type is None:
+            self.stop()
+        else:
+            self._end_session(read=False)
+        return False
+
+
+#: ``with capture({"train_step": step}) as cap: ...`` — see :class:`Capture`.
+capture = Capture
+
+
+def publish(report: dict) -> None:
+    """Hand a report to the installed sinks (none installed: nothing)."""
+    from chainermn_tpu.observability import reporter as _reporter
+    from chainermn_tpu.observability import step_log as _step_log
+
+    rec = _step_log.current_recorder()
+    if rec is not None:
+        rec.record("device_profile", **report)
+    rep = _reporter.get_reporter()
+    if rep is not None:
+        for program, row in report["programs"].items():
+            for kind in ("phase_ms", "region_ms"):
+                for scope, value in row[kind].items():
+                    rep.observe(f"device/{program}/{scope}_ms", value)
+            rep.observe(f"device/{program}/unattributed_ms",
+                        row["unattributed_ms"])
+            rep.observe(f"device/{program}/busy_ms", row["busy_ms"])

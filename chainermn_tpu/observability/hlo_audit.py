@@ -243,14 +243,28 @@ _HLO_INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*(?P<rest>.+)$"
 )
 _HLO_SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
+_HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_HLO_MODULE_RE = re.compile(r"^HloModule\s+([\w.\-]+)")
+# ``%fused_computation.3 (p0: f32[8]) -> f32[8] {`` / ``ENTRY %main.7 (...``
+_HLO_COMPUTATION_RE = re.compile(
+    r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
 
 
-class _HloInstr(NamedTuple):
+class HloInstr(NamedTuple):
+    """One instruction line of HLO text.  ``op_name`` is the name-stack
+    path of its ``metadata={op_name="jit(..)/fwd-bwd/..."}`` (a fusion
+    carries one op's of those it fused), ``""`` where the compiler kept
+    none; ``operands`` holds every ``%name`` after the opcode, the
+    computations it ``calls=`` among them; ``computation`` is the one
+    the line stands in."""
+
     index: int
     name: str
     opcode: str
     operands: Tuple[str, ...]
     nbytes: int
+    op_name: str
+    computation: str = ""
 
 
 def _hlo_shape_bytes(type_str: str) -> int:
@@ -271,7 +285,7 @@ def _hlo_shape_bytes(type_str: str) -> int:
     return elems * itemsize
 
 
-def _parse_hlo_instr(index: int, line: str) -> Optional[_HloInstr]:
+def _parse_hlo_instr(index: int, line: str) -> Optional[HloInstr]:
     m = _HLO_INSTR_RE.match(line)
     if m is None:
         return None
@@ -299,13 +313,43 @@ def _parse_hlo_instr(index: int, line: str) -> Optional[_HloInstr]:
     om = re.match(r"([a-zA-Z][\w\-]*)\s*\(", rest)
     if om is None:
         return None
-    return _HloInstr(
+    # The op's own metadata comes last on the line (a custom call's
+    # backend config may hold an empty ``metadata={}`` before it).
+    op_names = _HLO_OP_NAME_RE.findall(rest)
+    return HloInstr(
         index=index,
         name=m.group("name"),
         opcode=om.group(1),
         operands=tuple(re.findall(r"%([\w.\-]+)", rest)),
         nbytes=_hlo_shape_bytes(type_str),
+        op_name=op_names[-1] if op_names else "",
     )
+
+
+def hlo_instructions(hlo_text: str) -> List[HloInstr]:
+    """Every instruction of every computation of an HLO module's text
+    (``compiled.as_text()``), ``while``/``conditional`` bodies and fused
+    computations included, in text order.  The one HLO-text parser: the
+    collective census below and
+    :func:`chainermn_tpu.observability.device_trace.scope_table` both
+    read through it."""
+    out = []
+    computation = ""
+    for i, line in enumerate(hlo_text.splitlines()):
+        head = _HLO_COMPUTATION_RE.match(line)
+        if head is not None:
+            computation = head.group(1)
+            continue
+        ins = _parse_hlo_instr(i, line)
+        if ins is not None:
+            out.append(ins._replace(computation=computation))
+    return out
+
+
+def hlo_module_name(hlo_text: str) -> str:
+    """``jit_train_step`` from ``HloModule jit_train_step, ...``."""
+    m = _HLO_MODULE_RE.match(hlo_text.lstrip())
+    return m.group(1) if m else ""
 
 
 def audit_hlo_text(hlo_text: str) -> CollectiveAudit:
@@ -325,13 +369,8 @@ def audit_hlo_text(hlo_text: str) -> CollectiveAudit:
     replica groups, not mesh-axis names); per-collective payload bytes
     land in ``op_bytes``/``bytes_per_primitive`` as usual.
     """
-    instrs: List[_HloInstr] = []
-    by_name: Dict[str, _HloInstr] = {}
-    for i, line in enumerate(hlo_text.splitlines()):
-        ins = _parse_hlo_instr(i, line)
-        if ins is not None:
-            instrs.append(ins)
-            by_name[ins.name] = ins
+    instrs = hlo_instructions(hlo_text)
+    by_name: Dict[str, HloInstr] = {ins.name: ins for ins in instrs}
 
     counts: Dict[str, int] = {}
     per_prim: Dict[str, int] = {}
@@ -347,7 +386,7 @@ def audit_hlo_text(hlo_text: str) -> CollectiveAudit:
 
     # Pair dones with their starts first (done references the start's
     # result by name), so the start-side walk knows which are paired.
-    start_to_done: Dict[str, _HloInstr] = {}
+    start_to_done: Dict[str, HloInstr] = {}
     for ins in instrs:
         if not ins.opcode.endswith(_ASYNC_DONE):
             continue

@@ -1,33 +1,93 @@
-"""Trace spans — named regions visible in three sinks at once.
+"""The scope vocabulary, and the two ways a region gets its name.
 
-``with span("fwd"):`` stamps the region onto the profiler timeline
-(``utils/profiling.annotate`` → Perfetto/TensorBoard), measures the
-host-side duration, and
-publishes it to whichever telemetry sinks are active: the current
-:class:`~chainermn_tpu.observability.reporter.Reporter` (as a
-``span/<name>`` scalar + histogram) and the current
-:class:`~chainermn_tpu.observability.step_log.StepRecorder` (buffered
-into the next step row's ``spans`` field).  With neither active the
-cost is two ``perf_counter`` calls — cheap enough to leave in library
-hot paths permanently, the design stance nvprof-era tooling never
-allowed the reference.
+Every name under which work shows up in a profiler capture is declared
+here, once, and entered where the work happens:
 
-Host-side durations measure *dispatch + any blocking* — under JAX's
-async dispatch a span around a jitted call is NOT device time (the
-profiler trace is); they are still the right signal for host-bound
-stalls (input pipeline, blocking readbacks, compile storms).
+* :func:`named_scope` — inside TRACED code.  The name becomes a path
+  component of every enclosed op's HLO ``op_name`` metadata
+  (``jit(train_step)/fwd-bwd/jvp(TransformerLM)/...``), which the
+  compiled program keeps and
+  :mod:`~chainermn_tpu.observability.device_trace` joins to the
+  capture's op events: device time by scope.  Only names of
+  :data:`STEP_PHASES`, :data:`ALLREDUCE_STAGES` and
+  :data:`KERNEL_REGIONS` are accepted.
+* :func:`annotate` / :func:`span` — on the HOST.  ``annotate("x")`` is a
+  bare ``jax.profiler.TraceAnnotation("chainermn:x")``: a region on the
+  profiler's own clock, so an idle gap of the device can be put down to
+  what the host was doing in it.  With no profiler session it costs about
+  a microsecond.  ``span("x")`` is ``annotate("x")`` plus the host-side
+  duration published to whichever telemetry sinks are active: the current
+  :class:`~chainermn_tpu.observability.reporter.Reporter` (``span/<name>``
+  scalar + histogram) and the current
+  :class:`~chainermn_tpu.observability.step_log.StepRecorder` (the next
+  step row's ``spans`` field).
 
-Inside traced code use :func:`named_scope` instead: it tags the HLO ops
-so the regions survive into the compiled profile.
+A host-side duration measures *dispatch + any blocking*: under JAX's
+async dispatch a span around a jitted call is NOT device time (a capture
+read by ``device_trace`` is).  It is still the right signal for
+host-bound stalls (input pipeline, blocking readbacks, compile storms).
 """
 
 from __future__ import annotations
 
 import contextlib
+import re
 import time
+
+import jax
 
 from chainermn_tpu.observability import reporter as _reporter
 from chainermn_tpu.observability import step_log as _step_log
+
+#: The phases of a train step (``optimizers.py``).  They partition the
+#: step: every op of the program is traced under exactly one of them
+#: (forward and backward need no scope of their own: inside ``fwd-bwd``
+#: the path already says ``jvp(...)`` or ``transpose(jvp(...))``).
+STEP_PHASES = ("fwd-bwd", "allreduce", "opt-update")
+
+#: Within ``allreduce`` (``CommunicatorBase.allreduce_grad``): bucket
+#: packing, one ``grad-stage<s>`` per stage of the overlapped schedule,
+#: and the unpack.
+ALLREDUCE_STAGES = ("grad-pack", "grad-unpack")
+_ALLREDUCE_STAGE = re.compile(r"^grad-stage\d+$")
+
+#: The kernels: the three flash ``pallas_call``s (also their ``name=``),
+#: the two fused-CE scans, paged decode attention.
+KERNEL_REGIONS = (
+    "flash-fwd", "flash-bwd-dq", "flash-bwd-dkv", "fused-ce",
+    "paged-decode-attn",
+)
+
+#: What the jitted programs compile as (``jit_<name>`` in a capture's
+#: ``XLA Modules`` line): the train steps of ``optimizers.py`` and the
+#: serving engine's four programs.
+PROGRAM_NAMES = (
+    "train_step", "train_step_zero", "train_step_zero3",
+    "train_step_with_state", "train_step_zero_with_state",
+    "train_step_zero3_with_state",
+    "prefill_step", "decode_step", "chunk_step", "cow_step",
+)
+
+#: Prefix of every host annotation the library emits.
+HOST_PREFIX = "chainermn:"
+
+#: Host regions: training (``train_step`` around the jitted call,
+#: ``global_batch`` around batch placement, ``evaluate``), and the
+#: serving stages — the scheduler iteration's phases, the engine's three
+#: parts of a program call, and the tracer's request stages.
+HOST_SPANS = (
+    "train_step", "global_batch", "evaluate",
+    "admit", "prefill", "prefill_chunk", "decode", "sample", "emit",
+    "table-build", "dispatch", "readback",
+)
+
+
+def is_scope(name: str) -> bool:
+    """Whether ``name`` is a device-side scope of the vocabulary."""
+    return (
+        name in STEP_PHASES or name in ALLREDUCE_STAGES
+        or name in KERNEL_REGIONS or bool(_ALLREDUCE_STAGE.match(name))
+    )
 
 
 def telemetry_active() -> bool:
@@ -40,6 +100,12 @@ def telemetry_active() -> bool:
     )
 
 
+def annotate(name: str):
+    """Host region ``chainermn:<name>`` on the profiler's clock (a
+    context manager); nothing is measured or published."""
+    return jax.profiler.TraceAnnotation(HOST_PREFIX + name)
+
+
 @contextlib.contextmanager
 def span(name: str):
     """Named host-side region: profiler annotation + duration fan-out.
@@ -49,8 +115,6 @@ def span(name: str):
     half-open span behind for the next request on the thread.  The
     exception propagates unchanged.
     """
-    from chainermn_tpu.utils.profiling import annotate
-
     t0 = time.perf_counter()
     err = False
     try:
@@ -75,13 +139,13 @@ def span(name: str):
 
 
 def named_scope(name: str):
-    """Device-side region naming for TRACED code (fwd/bwd/allreduce/
-    opt-update): tags the ops' HLO metadata so the regions appear in
-    compiled-program profiles.  Falls back to a null context on jax
-    builds without ``named_scope``."""
-    import jax
-
-    try:
-        return jax.named_scope(name)
-    except Exception:
-        return contextlib.nullcontext()
+    """Device-side region naming for TRACED code: ``jax.named_scope``
+    under a name of the vocabulary.  A name outside it raises — a scope
+    nobody reads, or one that silently is not there, is the failure the
+    vocabulary exists to end."""
+    if not is_scope(name):
+        raise ValueError(
+            f"{name!r} is not in the scope vocabulary "
+            "(observability/spans.py)"
+        )
+    return jax.named_scope(name)

@@ -257,10 +257,15 @@ class Tracer:
     def span(self, name: str, parent: Optional[SpanCtx] = None,
              replica=None, **attrs):
         """``with tr.span("prefill", parent=root):`` — closes and marks
-        ``error=True`` on exception paths, then re-raises."""
+        ``error=True`` on exception paths, then re-raises.  The region
+        also enters the ``chainermn:<name>`` profiler annotation, so the
+        stage sits on a profiler capture's clock too."""
+        from chainermn_tpu.observability.spans import annotate
+
         ctx = self.begin(name, parent, replica=replica, **attrs)
         try:
-            yield ctx
+            with annotate(name):
+                yield ctx
         except BaseException as exc:
             self.end(ctx, error=exc)
             raise

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chainermn_tpu.observability.spans import named_scope
 
 def invalid_block(n_pages: int) -> int:
     """The sentinel block id for unallocated table slots: out-of-bounds
@@ -169,6 +170,7 @@ def paged_attention_decode(
     )
 
 
+@named_scope("paged-decode-attn")
 def paged_attention_chunk(
     q,
     k_pages,

@@ -43,6 +43,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from chainermn_tpu.observability.spans import named_scope
+
 _NEG_INF = -1e30
 
 
@@ -215,21 +217,23 @@ def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
             pl.BlockSpec((1, block_k, 1), lambda b, i, j: (b // G, j, 0)),
         ]
         args += [q_seg, kv_seg]
-    return pl.pallas_call(
-        kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, Sq, 1), jnp.float32),
-        ],
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(*args)
+    with named_scope("flash-fwd"):
+        return pl.pallas_call(
+            kernel,
+            out_shape=[
+                jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
+                jax.ShapeDtypeStruct((BH, Sq, 1), jnp.float32),
+            ],
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            ],
+            scratch_shapes=scratch,
+            interpret=interpret,
+            name="flash-fwd",
+        )(*args)
 
 
 def _dq_kernel(
@@ -371,18 +375,22 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
             pl.BlockSpec((1, block_k, 1), lambda b, i, j: (b // G, j, 0)),
         ]
         dq_args += [q_seg, kv_seg]
-    dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, scale=scale, causal=causal, segmented=segmented,
-            block_q=block_q, block_k=block_k, window=window,
-        ),
-        out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
-        grid=(BH, Sq // block_q, Sk // block_k),
-        in_specs=dq_in,
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
-    )(*dq_args)
+    with named_scope("flash-bwd-dq"):
+        dq = pl.pallas_call(
+            functools.partial(
+                _dq_kernel, scale=scale, causal=causal, segmented=segmented,
+                block_q=block_q, block_k=block_k, window=window,
+            ),
+            out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
+            grid=(BH, Sq // block_q, Sk // block_k),
+            in_specs=dq_in,
+            out_specs=pl.BlockSpec(
+                (1, block_q, D), lambda b, i, j: (b, i, 0)
+            ),
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+            interpret=interpret,
+            name="flash-bwd-dq",
+        )(*dq_args)
 
     # dkv grid walks (BHk, n_k, G*n_q): one program chain per KV row with
     # every query head of its group innermost — the group's contributions
@@ -408,27 +416,30 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
             pl.BlockSpec((1, block_k, 1), lambda b, j, i: (b, j, 0)),
         ]
         dkv_args += [q_seg, kv_seg]
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, scale=scale, causal=causal, segmented=segmented,
-            block_q=block_q, block_k=block_k, n_q=n_q, window=window,
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((BHk, Sk, D), k.dtype),
-            jax.ShapeDtypeStruct((BHk, Sk, D), v.dtype),
-        ],
-        grid=(BHk, Sk // block_k, G * n_q),
-        in_specs=dkv_in,
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*dkv_args)
+    with named_scope("flash-bwd-dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(
+                _dkv_kernel, scale=scale, causal=causal,
+                segmented=segmented, block_q=block_q, block_k=block_k,
+                n_q=n_q, window=window,
+            ),
+            out_shape=[
+                jax.ShapeDtypeStruct((BHk, Sk, D), k.dtype),
+                jax.ShapeDtypeStruct((BHk, Sk, D), v.dtype),
+            ],
+            grid=(BHk, Sk // block_k, G * n_q),
+            in_specs=dkv_in,
+            out_specs=[
+                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((block_k, D), jnp.float32),
+            ],
+            interpret=interpret,
+            name="flash-bwd-dkv",
+        )(*dkv_args)
     return dq, dk, dv
 
 

@@ -32,6 +32,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from chainermn_tpu.observability.spans import named_scope
+
 #: the static default row chunk — the cache-miss / off-TPU fallback, and
 #: a mandatory member of the autotuner's search space (a tuned chunk can
 #: never lose to it).
@@ -132,9 +134,10 @@ def ce_scan_fwd(hidden, embedding, labels, chunk, strat):
             lse_c,
         )
 
-    (loss_sum, n_valid), lse = jax.lax.scan(
-        body, (jnp.float32(0.0), jnp.float32(0.0)), (h_chunks, l_chunks)
-    )
+    with named_scope("fused-ce"):
+        (loss_sum, n_valid), lse = jax.lax.scan(
+            body, (jnp.float32(0.0), jnp.float32(0.0)), (h_chunks, l_chunks)
+        )
     return loss_sum, n_valid, lse.reshape(N)
 
 
@@ -176,11 +179,12 @@ def ce_scan_bwd(hidden, embedding, labels, lse, g_loss, g_lse, chunk,
         )
         return d_emb, dh_c
 
-    d_emb, dh = jax.lax.scan(
-        body,
-        jnp.zeros(embedding.shape, jnp.float32),
-        (h_chunks, l_chunks, lse_chunks, g_lse_chunks),
-    )
+    with named_scope("fused-ce"):
+        d_emb, dh = jax.lax.scan(
+            body,
+            jnp.zeros(embedding.shape, jnp.float32),
+            (h_chunks, l_chunks, lse_chunks, g_lse_chunks),
+        )
     return (
         dh.reshape(N, D).astype(hidden.dtype),
         d_emb.astype(embedding.dtype),

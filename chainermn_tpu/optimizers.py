@@ -95,18 +95,20 @@ def _carry_step_surface(wrapper, step_fn):
 
 
 def _instrument_step(step_fn):
-    """Telemetry wrapper for a built train step: when a Reporter or
-    StepRecorder is installed (``observability.telemetry_active``) each
-    call runs under ``span("train_step")`` — profiler annotation +
-    host-side duration into both sinks — and bumps the reporter's
-    ``train_step_calls`` counter.  With no telemetry installed the cost
-    is one boolean check, so steps stay wrappable unconditionally."""
+    """Host-side wrapper of a built train step.  Every call runs under
+    the ``chainermn:train_step`` profiler annotation (about a microsecond
+    with no profiler session), so a capture can put the device's idle
+    gaps down to it.  When a Reporter or StepRecorder is installed
+    (``observability.telemetry_active``) the call also runs under
+    ``span("train_step")`` — host-side duration into both sinks — and
+    bumps the reporter's ``train_step_calls`` counter."""
     from chainermn_tpu.observability import spans as _spans
 
     @functools.wraps(step_fn)
     def instrumented(*args, **kwargs):
         if not _spans.telemetry_active():
-            return step_fn(*args, **kwargs)
+            with _spans.annotate("train_step"):
+                return step_fn(*args, **kwargs)
         from chainermn_tpu.observability import reporter as _rep
 
         with _spans.span("train_step"):
@@ -117,6 +119,19 @@ def _instrument_step(step_fn):
         return out
 
     return _carry_step_surface(instrumented, step_fn)
+
+
+def _jit_step(mapped, name, donate_argnums):
+    """``jax.jit`` a step body under a program name of the vocabulary
+    (``observability/spans.py``): the module compiles as ``jit_<name>``,
+    which is how a profiler capture lists it, whatever the body's
+    function was called."""
+
+    def program(*args):
+        return mapped(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, donate_argnums=donate_argnums)
 
 
 def _run_first_call_lint(step_fn, comm, mode, args, kwargs):
@@ -558,20 +573,22 @@ class MultiNodeOptimizer:
                 updates, inner = opt.update(stale, inner, pshard)
                 return optax.apply_updates(pshard, updates), inner
 
-            pshard, inner = lax.cond(
-                state.step > 0,
-                do_update,
-                lambda operand: (operand[0], operand[1]),
-                (pshard, state.inner, state.comm_buf),
-            )
+            with named_scope("opt-update"):
+                pshard, inner = lax.cond(
+                    state.step > 0,
+                    do_update,
+                    lambda operand: (operand[0], operand[1]),
+                    (pshard, state.inner, state.comm_buf),
+                )
             new_state = MultiNodeOptimizerState(
                 inner=inner, step=state.step + 1, comm_buf=gshard
             )
             return pshard, new_state
         if loss_scale is not None:
             gshard = gshard / loss_scale
-        updates, inner = opt.update(gshard, state.inner, pshard)
-        pshard = optax.apply_updates(pshard, updates)
+        with named_scope("opt-update"):
+            updates, inner = opt.update(gshard, state.inner, pshard)
+            pshard = optax.apply_updates(pshard, updates)
         return pshard, MultiNodeOptimizerState(
             inner=inner, step=state.step + 1, comm_buf=()
         )
@@ -592,7 +609,9 @@ class MultiNodeOptimizer:
         pshard, new_state = self._apply_shard_update(
             pshard, state, gshard, loss_scale
         )
-        pfull = lax.all_gather(pshard, world, axis=0, tiled=True)
+        with named_scope("allreduce"):
+            # the second half of the ring allreduce ZeRO-1/2 split in two
+            pfull = lax.all_gather(pshard, world, axis=0, tiled=True)
         new_params = unpack(pfull[: shard_size * n])
         new_params = jax.tree.map(
             lambda x, ref: x.astype(ref.dtype), new_params, params
@@ -694,7 +713,7 @@ class MultiNodeOptimizer:
             out_specs=(P(),) * n_out,
         )
         donate_argnums = (0, 1) if donate else ()
-        jitted = jax.jit(mapped, donate_argnums=donate_argnums)
+        jitted = _jit_step(mapped, "train_step", donate_argnums)
         n_dev = comm.device_size
 
         @functools.wraps(jitted)
@@ -726,7 +745,8 @@ class MultiNodeOptimizer:
         ``(gshard, mean_loss, aux)``; with ``n_accum == 1`` there is no scan
         and aux comes back unstacked, matching the stage-0/1 contract."""
         if n_accum == 1:
-            loss, aux, grads = one(params, batch, base_key)
+            with named_scope("fwd-bwd"):
+                loss, aux, grads = one(params, batch, base_key)
             return self._scatter_grads(grads, shard_size, n, world), loss, aux
 
         micro = self._split_micro(batch, n_accum)
@@ -735,7 +755,8 @@ class MultiNodeOptimizer:
             sacc, lacc = carry
             i, b = xs
             key = None if base_key is None else jax.random.fold_in(base_key, i)
-            loss, aux, grads = one(params, b, key)
+            with named_scope("fwd-bwd"):
+                loss, aux, grads = one(params, b, key)
             sacc = sacc + self._scatter_grads(grads, shard_size, n, world)
             return (sacc, lacc + loss), aux
 
@@ -801,7 +822,9 @@ class MultiNodeOptimizer:
                 in_specs=(P(), state_spec, batch_spec),
                 out_specs=(P(), state_spec) + (P(),) * (n_out - 2),
             )
-            return jax.jit(mapped, donate_argnums=(0, 1) if donate else ())
+            return _jit_step(
+                mapped, "train_step_zero", (0, 1) if donate else ()
+            )
 
         compiled = {}
 
@@ -869,7 +892,9 @@ class MultiNodeOptimizer:
                 in_specs=(P(world), state_spec, batch_spec),
                 out_specs=(P(world), state_spec) + (P(),) * (n_out - 2),
             )
-            return jax.jit(mapped, donate_argnums=(0, 1) if donate else ())
+            return _jit_step(
+                mapped, "train_step_zero3", (0, 1) if donate else ()
+            )
 
         compiled = {}
 
@@ -935,9 +960,10 @@ class MultiNodeOptimizer:
             batch_spec = P(axes if len(axes) > 1 else axes[0])
 
         def grads_and_state(params, model_state, batch):
-            (loss, new_model_state), grads = jax.value_and_grad(
-                loss_fn, has_aux=True
-            )(params, model_state, batch)
+            with named_scope("fwd-bwd"):
+                (loss, new_model_state), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True
+                )(params, model_state, batch)
             loss = lax.pmean(loss, axes)
             new_model_state = jax.tree.map(
                 lambda x: lax.pmean(x, axes)
@@ -968,7 +994,7 @@ class MultiNodeOptimizer:
         )
         donate_argnums = (0, 1, 2) if donate else ()
         return self._finalize_step(
-            jax.jit(mapped, donate_argnums=donate_argnums)
+            _jit_step(mapped, "train_step_with_state", donate_argnums)
         )
 
     def _make_zero_with_state_step(self, grads_and_state, batch_spec, donate):
@@ -1000,8 +1026,9 @@ class MultiNodeOptimizer:
                     in_specs=(P(), state_spec, P(), batch_spec),
                     out_specs=(P(), state_spec, P(), P()),
                 )
-                return jax.jit(
-                    mapped, donate_argnums=(0, 1, 2) if donate else ()
+                return _jit_step(
+                    mapped, "train_step_zero_with_state",
+                    (0, 1, 2) if donate else (),
                 )
 
             compiled = {}
@@ -1042,7 +1069,10 @@ class MultiNodeOptimizer:
                 in_specs=(P(world), state_spec, P(), batch_spec),
                 out_specs=(P(world), state_spec, P(), P()),
             )
-            return jax.jit(mapped, donate_argnums=(0, 1, 2) if donate else ())
+            return _jit_step(
+                mapped, "train_step_zero3_with_state",
+                (0, 1, 2) if donate else (),
+            )
 
         compiled3 = {}
 

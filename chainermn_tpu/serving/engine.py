@@ -40,6 +40,7 @@ import numpy as np
 
 from chainermn_tpu.communicators import quant
 from chainermn_tpu.models.transformer import TransformerLM
+from chainermn_tpu.observability.spans import annotate
 from chainermn_tpu.serving.kv_cache import PagedKVCache
 from chainermn_tpu.serving.spec import DraftModel, propose_draft as _ngram_draft
 
@@ -669,24 +670,27 @@ class InferenceEngine:
                 f"prompt of {L} tokens leaves no room to generate within "
                 f"max_len {self.config.max_len}"
             )
-        S = self._bucket_grow(L, self._prefill_buckets,
-                              self.config.max_len, "prompt length")
-        W = self.table_width(L)
-        padded = np.zeros((1, S), np.int32)
-        padded[0, :L] = toks
-        table = self.kv.padded_table(seq_id, W)[None]
-        self._prefill_shapes.add((S, W))
+        with annotate("table-build"):
+            S = self._bucket_grow(L, self._prefill_buckets,
+                                  self.config.max_len, "prompt length")
+            W = self.table_width(L)
+            padded = np.zeros((1, S), np.int32)
+            padded[0, :L] = toks
+            table = self.kv.padded_table(seq_id, W)[None]
+            self._prefill_shapes.add((S, W))
         self._mirror("prefill", padded, table, np.asarray([L], np.int32))
-        out = self._prefill_jit(
-            self.params, self._cache, jnp.asarray(padded),
-            jnp.asarray(table), jnp.asarray([L], np.int32),
-        )
+        with annotate("dispatch"):
+            out = self._prefill_jit(
+                self.params, self._cache, jnp.asarray(padded),
+                jnp.asarray(table), jnp.asarray([L], np.int32),
+            )
         last, self._cache = out[0], out[1]
         if self.kv_dtype is not None:
             self._note_kv_err(out[2])
         self._tokens_prefilled += L
         self._max_prefilled = max(self._max_prefilled, L)
-        return np.asarray(last[0])
+        with annotate("readback"):
+            return np.asarray(last[0])
 
     def decode(self, tokens, seq_ids, seq_lens) -> np.ndarray:
         """One decode iteration: for each running sequence, write the
@@ -724,28 +728,31 @@ class InferenceEngine:
                 f"decode batch {B} exceeds max_batch "
                 f"{self.config.max_batch}"
             )
-        Bp = _bucket(B, self.config.batch_buckets, "decode batch")
-        W = max(
-            self.table_width(int(l) + 1) for l in seq_lens
-        )
-        tok = np.zeros((Bp,), np.int32)
-        tok[:B] = np.asarray(tokens, np.int32)
-        lens = np.full((Bp,), -1, np.int32)
-        lens[:B] = np.asarray(seq_lens, np.int32)
-        tables = np.full((Bp, W), self.kv.invalid, np.int32)
-        for i, sid in enumerate(seq_ids):
-            tables[i] = self.kv.padded_table(sid, W)
-        self._decode_shapes.add((Bp, W))
+        with annotate("table-build"):
+            Bp = _bucket(B, self.config.batch_buckets, "decode batch")
+            W = max(
+                self.table_width(int(l) + 1) for l in seq_lens
+            )
+            tok = np.zeros((Bp,), np.int32)
+            tok[:B] = np.asarray(tokens, np.int32)
+            lens = np.full((Bp,), -1, np.int32)
+            lens[:B] = np.asarray(seq_lens, np.int32)
+            tables = np.full((Bp, W), self.kv.invalid, np.int32)
+            for i, sid in enumerate(seq_ids):
+                tables[i] = self.kv.padded_table(sid, W)
+            self._decode_shapes.add((Bp, W))
         self._mirror("decode", tok, tables, lens)
-        out = self._decode_jit(
-            self.params, self._cache, jnp.asarray(tok),
-            jnp.asarray(tables), jnp.asarray(lens),
-        )
+        with annotate("dispatch"):
+            out = self._decode_jit(
+                self.params, self._cache, jnp.asarray(tok),
+                jnp.asarray(tables), jnp.asarray(lens),
+            )
         logits, self._cache = out[0], out[1]
         if self.kv_dtype is not None:
             self._note_kv_err(out[2])
         self._tokens_decoded += B
-        return np.asarray(logits[:B])
+        with annotate("readback"):
+            return np.asarray(logits[:B])
 
     def chunk(self, token_rows, seq_ids, start_lens) -> np.ndarray:
         """One multi-token step: for each row, write ``len(token_rows[i])``
@@ -783,15 +790,16 @@ class InferenceEngine:
         # else (multi-row verify batches, tiny buckets) stays on the
         # single-device chunk program.
         use_sp = bool(self.sp and B == 1 and T % self.sp == 0)
-        tok = np.zeros((Bp, T), np.int32)
-        start = np.full((Bp,), -1, np.int32)
-        tables = np.full((Bp, W), self.kv.invalid, np.int32)
-        for i, (row, sid, s) in enumerate(
-            zip(token_rows, seq_ids, start_lens)
-        ):
-            tok[i, : len(row)] = np.asarray(row, np.int32)
-            start[i] = int(s)
-            tables[i] = self.kv.padded_table(sid, W)
+        with annotate("table-build"):
+            tok = np.zeros((Bp, T), np.int32)
+            start = np.full((Bp,), -1, np.int32)
+            tables = np.full((Bp, W), self.kv.invalid, np.int32)
+            for i, (row, sid, s) in enumerate(
+                zip(token_rows, seq_ids, start_lens)
+            ):
+                tok[i, : len(row)] = np.asarray(row, np.int32)
+                start[i] = int(s)
+                tables[i] = self.kv.padded_table(sid, W)
         if use_sp:
             self._sp_shapes.add((Bp, T, W))
             step = self._sp_chunk_jit
@@ -799,10 +807,11 @@ class InferenceEngine:
             self._chunk_shapes.add((Bp, T, W))
             step = self._chunk_jit
         self._mirror("chunk", tok, tables, start, use_sp)
-        out = step(
-            self.params, self._cache, jnp.asarray(tok),
-            jnp.asarray(tables), jnp.asarray(start),
-        )
+        with annotate("dispatch"):
+            out = step(
+                self.params, self._cache, jnp.asarray(tok),
+                jnp.asarray(tables), jnp.asarray(start),
+            )
         logits, self._cache = out[0], out[1]
         if self.kv_dtype is not None:
             self._note_kv_err(out[2])
@@ -813,7 +822,8 @@ class InferenceEngine:
             default=0,
         )
         self._max_prefilled = max(self._max_prefilled, covered)
-        return np.asarray(logits[:B])
+        with annotate("readback"):
+            return np.asarray(logits[:B])
 
     def prefill_cached(self, token_ids, seq_id, n_cached: int) -> np.ndarray:
         """Prefill a prompt whose first ``n_cached`` tokens are already
@@ -897,18 +907,19 @@ class InferenceEngine:
         ties).  Otherwise counter-based: the RNG is seeded from
         ``(seed, position)`` alone, so the draw does not depend on batch
         composition, scheduling order, or preemption history."""
-        if params.temperature == 0.0:
-            return int(np.argmax(logits))
-        z = logits.astype(np.float64) / params.temperature
-        if params.top_k:
-            k = min(params.top_k, z.shape[-1])
-            cutoff = np.partition(z, -k)[-k]
-            z = np.where(z >= cutoff, z, -np.inf)
-        z = z - z.max()
-        p = np.exp(z)
-        p /= p.sum()
-        rng = np.random.default_rng((int(params.seed), int(position)))
-        return int(rng.choice(p.shape[-1], p=p))
+        with annotate("sample"):
+            if params.temperature == 0.0:
+                return int(np.argmax(logits))
+            z = logits.astype(np.float64) / params.temperature
+            if params.top_k:
+                k = min(params.top_k, z.shape[-1])
+                cutoff = np.partition(z, -k)[-k]
+                z = np.where(z >= cutoff, z, -np.inf)
+            z = z - z.max()
+            p = np.exp(z)
+            p /= p.sum()
+            rng = np.random.default_rng((int(params.seed), int(position)))
+            return int(rng.choice(p.shape[-1], p=p))
 
     # -- maintenance ---------------------------------------------------
     def defragment(self) -> int:

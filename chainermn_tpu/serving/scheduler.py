@@ -29,6 +29,7 @@ from enum import Enum
 from typing import Callable, Deque, Dict, List, Optional
 
 from chainermn_tpu.observability import tracing as _tracing
+from chainermn_tpu.observability.spans import annotate
 from chainermn_tpu.serving.engine import InferenceEngine, SamplingParams
 from chainermn_tpu.serving.kv_cache import OutOfBlocks
 
@@ -426,15 +427,16 @@ class ContinuousBatchingScheduler:
         self._finished[req.request_id] = req
 
     def _emit(self, req: Request, token: int, tr=None) -> None:
-        req.generated.append(token)
-        if req.first_token_step is None:
-            req.first_token_step = self._step
-        if req.tenant is not None and self.reporter is not None:
-            self.reporter.count(f"tenant/{req.tenant}/tokens_out", 1)
-        if tr is not None and req.trace is not None:
-            tr.token(req.trace)
-        if req.on_token is not None:
-            req.on_token(req.request_id, token)
+        with annotate("emit"):
+            req.generated.append(token)
+            if req.first_token_step is None:
+                req.first_token_step = self._step
+            if req.tenant is not None and self.reporter is not None:
+                self.reporter.count(f"tenant/{req.tenant}/tokens_out", 1)
+            if tr is not None and req.trace is not None:
+                tr.token(req.trace)
+            if req.on_token is not None:
+                req.on_token(req.request_id, token)
 
     # -- the iteration -------------------------------------------------
     def step(self) -> int:
@@ -447,7 +449,9 @@ class ContinuousBatchingScheduler:
         # carrying a context) every tracing branch below is dead.
         tr = _tracing.get_tracer()
 
-        for req in self._admit():
+        with annotate("admit"):
+            admitted = self._admit()
+        for req in admitted:
             traced = tr is not None and req.trace is not None
             if traced and req.trace_enq is not None:
                 now = tr.clock()
@@ -487,9 +491,10 @@ class ContinuousBatchingScheduler:
                     req.prefill_pos = hit
                     continue
                 else:
-                    logits = self.engine.prefill_cached(
-                        req.context, req.request_id, hit
-                    )
+                    with annotate("prefill"):
+                        logits = self.engine.prefill_cached(
+                            req.context, req.request_id, hit
+                        )
                 self.engine.kv.register_prefix(
                     req.request_id, req.prompt,
                     namespace=req.prefix_namespace,
@@ -569,9 +574,10 @@ class ContinuousBatchingScheduler:
                     self.reporter.count("serve/dup_prefill_slices", 1)
             rtraced = tr is not None and req.trace is not None
             t0 = tr.clock() if rtraced else 0.0
-            logits = self.engine.chunk(
-                [req.context[pos:end]], [req.request_id], [pos]
-            )
+            with annotate("prefill_chunk"):
+                logits = self.engine.chunk(
+                    [req.context[pos:end]], [req.request_id], [pos]
+                )
             if rtraced:
                 tr.record_span(
                     "prefill_chunk", req.trace, t0, tr.clock() - t0,
@@ -690,19 +696,20 @@ class ContinuousBatchingScheduler:
             # position len-1+j+1, bit-exact to j+1 sequential decodes as
             # long as d1..dj matched the sampled stream.
             lens = [len(r.context) - 1 for r in batch]
-            if drafts:
-                logits_rows = self.engine.chunk(
-                    [[r.context[-1]] + drafts.get(r.request_id, [])
-                     for r in batch],
-                    [r.request_id for r in batch],
-                    lens,
-                )
-            else:
-                logits = self.engine.decode(
-                    [r.context[-1] for r in batch],
-                    [r.request_id for r in batch],
-                    lens,
-                )
+            with annotate("decode"):
+                if drafts:
+                    logits_rows = self.engine.chunk(
+                        [[r.context[-1]] + drafts.get(r.request_id, [])
+                         for r in batch],
+                        [r.request_id for r in batch],
+                        lens,
+                    )
+                else:
+                    logits = self.engine.decode(
+                        [r.context[-1] for r in batch],
+                        [r.request_id for r in batch],
+                        lens,
+                    )
             accepted_by_id: Dict[int, int] = {}
             for i, req in enumerate(batch):
                 d = drafts.get(req.request_id, [])

@@ -8,7 +8,8 @@ exports Prometheus textfile metrics.
 Usage::
 
     # one JSON object: steps/sec, loss curve, span totals, compile
-    # events, collective counts (multi-rank logs aggregate per step):
+    # events, collective counts (multi-rank logs aggregate per step),
+    # the newest device_profile row (device ms a step by scope):
     python -m chainermn_tpu.tools.obs summarize steps.jsonl
 
     # several ranks' logs together (values rank-aggregate):
@@ -215,6 +216,14 @@ def summarize(rows: List[dict], curve_points: int = 16) -> dict:
             "bytes_per_axis": {
                 k: v // n_audit_ranks for k, v in per_axis.items()
             },
+        }
+    profiles = [r for r in rows if r.get("event") == "device_profile"]
+    if profiles:
+        # One capture of a few steps (observability.device_trace): the
+        # newest row, as written — device ms a call by phase and region.
+        out["device_profile"] = {
+            k: v for k, v in profiles[-1].items()
+            if k not in ("event", "rank", "t")
         }
     return out
 

@@ -1,17 +1,16 @@
-"""Profiling hooks — the tracing subsystem the reference lacked.
+"""Timing helpers and the compile-cache switch.
 
 SURVEY §5.1: the reference relied on Chainer's TimerHook + external nvprof.
-Here profiling is first-class: ``trace()`` wraps ``jax.profiler`` (produces
-a TensorBoard/Perfetto trace of device steps incl. collective overlap),
-``annotate()`` stamps named regions, and ``StepTimer`` gives the in-loop
-throughput/bandwidth numbers that back ``bench.py`` — including the
-``allreduce bus-bw GB/s`` metric BASELINE.json tracks.
+Here: ``slope_time`` / ``median_slope`` / ``sync`` are the timing backbone
+of ``bench.py`` and the autotuner, ``allreduce_bus_bandwidth_gbs`` the
+``allreduce bus-bw GB/s`` arithmetic BASELINE.json tracks, and
+``setup_compilation_cache`` what every entry point calls first.  Profiler
+captures and named regions live in ``chainermn_tpu.observability``
+(``device_trace.capture``, ``spans.annotate`` / ``span`` / ``named_scope``).
 """
 
 from __future__ import annotations
 
-import contextlib
-import time
 from typing import Optional
 
 import jax
@@ -85,51 +84,6 @@ def sync(tree):
         elif hasattr(leaf, "ravel"):
             jax.device_get(leaf.ravel()[:1])
     return tree
-
-
-@contextlib.contextmanager
-def trace(logdir: str = "/tmp/chainermn_tpu_trace"):
-    """Capture a device-level profiler trace around the with-block.  A
-    trace that cannot start or stop raises: whoever reads ``logdir``
-    afterwards must never find a trace that silently is not there."""
-    jax.profiler.start_trace(logdir)
-    try:
-        yield logdir
-    finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named region for profiler timelines (usable as context manager)."""
-    return jax.profiler.TraceAnnotation(name)
-
-
-class StepTimer:
-    """Steady-state step timing with warmup discard."""
-
-    def __init__(self, warmup: int = 2):
-        self.warmup = warmup
-        self._times = []
-        self._t0: Optional[float] = None
-        self._count = 0
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
-        self._count += 1
-        if self._count > self.warmup:
-            self._times.append(dt)
-        return False
-
-    @property
-    def mean_s(self) -> float:
-        return sum(self._times) / max(len(self._times), 1)
-
-    def throughput(self, items_per_step: int) -> float:
-        return items_per_step / self.mean_s if self._times else 0.0
 
 
 def allreduce_bus_bandwidth_gbs(

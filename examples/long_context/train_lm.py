@@ -25,6 +25,7 @@ correctness canary, not a benchmark.
 """
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -102,6 +103,13 @@ def main(argv=None):
                    "segment ids keep attention inside document boundaries "
                    "through EVERY backend (flash kernel masks, rotating "
                    "ring/zigzag KV ids, ulysses all-gathered ids)")
+    p.add_argument("--step-log", default=None, metavar="PATH",
+                   help="write a JSONL step-event log (one row a step) "
+                        "and, once, after two warm-up steps, a "
+                        "device_profile row: four steps captured with the "
+                        "profiler and read by observability.device_trace "
+                        "(device ms a step by scope); summarize with "
+                        "python -m chainermn_tpu.tools.obs summarize PATH")
     args = p.parse_args(argv)
 
     comm = chainermn_tpu.create_communicator("xla_ici", inter_size=args.dp)
@@ -249,10 +257,11 @@ def main(argv=None):
             return jnp.sum(ce * wt) / (denom / comm.device_size)
 
         dp_step = mn_opt.make_train_step(loss_fn, donate=False)
+        programs = {"train_step": dp_step}
 
         def step(carry, batch):
             params, st = carry
-            params, st, loss = dp_step(params, st, batch)
+            params, st, loss = programs["train_step"](params, st, batch)
             return (params, st), loss
 
         carry = (params, mn_opt.init(params))
@@ -388,19 +397,23 @@ def main(argv=None):
                 out_specs=(P(), emb_spec, P(), st_emb_spec, P()),
             ))
 
+            programs = {"train_step": jitted_vtp}
+
             def step(carry, batch):
                 pr, emb, st_r, st_e = carry
-                pr, emb, st_r, st_e, loss = jitted_vtp(
+                pr, emb, st_r, st_e, loss = programs["train_step"](
                     pr, emb, st_r, st_e, *batch, positions
                 )
                 return (pr, emb, st_r, st_e), loss
 
             carry = (params_rest, emb0, st_rest0, st_emb0)
         else:
+            programs = {"train_step": jitted}
+
             def step(carry, batch):
                 params, opt_state = carry
-                params, opt_state, loss = jitted(params, opt_state, *batch,
-                                                 positions)
+                params, opt_state, loss = programs["train_step"](
+                    params, opt_state, *batch, positions)
                 return (params, opt_state), loss
 
             carry = (params, opt_state)
@@ -437,6 +450,31 @@ def main(argv=None):
             if comm.rank == 0:
                 print(f"resumed from step {it}")
 
+    # --step-log: one row a step, and one device profile after warm-up.
+    # The steps reach their program through ``programs`` so that the
+    # capture can note each call's arguments (it lowers the program once
+    # more from them for its scope table).
+    telemetry = contextlib.ExitStack()
+    recorder = cap = None
+    trained, profile_after, profile_steps = 0, 2, 4
+    if args.step_log:
+        from chainermn_tpu import observability as obs
+
+        recorder = telemetry.enter_context(
+            obs.StepRecorder(args.step_log, rank=comm.rank)
+        )
+
+    def end_capture():
+        sync(last)
+        programs.update(cap.programs)
+        report = cap.stop()  # also the recorder's device_profile row
+        if comm.rank == 0:
+            row = report["programs"].get("train_step", {})
+            print("device profile, ms a step by phase: "
+                  f"{row.get('phase_ms')} by region: "
+                  f"{row.get('region_ms')} unattributed: "
+                  f"{row.get('unattributed_ms')}")
+
     last = float("nan")
     for epoch in range(args.epochs):
         t0, n_tok = time.perf_counter(), 0
@@ -462,9 +500,19 @@ def main(argv=None):
                 tgt_np = np.roll(tok_np, -1, axis=1)
             tok = jnp.asarray(tok_np[:, perm])
             tgt = jnp.asarray(tgt_np[:, perm])
+            if recorder is not None and trained == profile_after:
+                sync(last)
+                cap = obs.device_trace.capture(programs).start()
+                programs.update({name: cap[name] for name in programs})
             carry, last = step(carry, (tok, tgt, wt))
             n_tok += B * S
             gstep += 1
+            trained += 1
+            if recorder is not None:
+                recorder.step(step=gstep - 1, items=B * S)
+            if cap is not None and trained == profile_after + profile_steps:
+                end_capture()
+                cap = None
             if ckpt is not None and gstep % args.checkpoint_every == 0:
                 ckpt.save({"carry": carry}, gstep, block=False)
         if n_tok:
@@ -475,6 +523,9 @@ def main(argv=None):
                 f"epoch {epoch}: loss {float(last):.4f} "
                 f"({n_tok / dt:,.0f} tok/s)"
             )
+    if cap is not None:  # the run ended inside the captured steps
+        end_capture()
+    telemetry.close()
     if ckpt is not None:
         ckpt.wait()
         from chainermn_tpu.utils.native import tree_digest

@@ -456,29 +456,6 @@ def test_obs_cli_subprocess(tmp_path):
     assert prom.read_text() == PROM_GOLDEN
 
 
-# ---------------------------------------------------------------------------
-# profiling: a trace that cannot start is an error, never a silent no-op
-# ---------------------------------------------------------------------------
-
-def test_trace_surfaces_profiler_failure(monkeypatch, tmp_path):
-    import jax
-
-    from chainermn_tpu.utils import profiling
-
-    def refuse(logdir):
-        raise RuntimeError("profiler refused")
-
-    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
-    ran = []
-    with pytest.raises(RuntimeError, match="profiler refused"):
-        with profiling.trace(str(tmp_path / "trace")):
-            ran.append("body")
-    assert not ran
-    with profiling.annotate("region"):
-        ran.append("annotated")
-    assert ran == ["annotated"]
-
-
 def test_compilation_cache_dir(monkeypatch, tmp_path):
     """With JAX_COMPILATION_CACHE_DIR set no directory is set in code;
     without it the cache is the checkout's fixed .jax_cache."""

@@ -139,15 +139,6 @@ def test_bus_bandwidth_formula():
     assert abs(got - 17.5) < 1e-6
 
 
-def test_step_timer():
-    t = profiling.StepTimer(warmup=1)
-    for _ in range(4):
-        with t:
-            pass
-    assert t.mean_s >= 0.0
-    assert t.throughput(10) > 0
-
-
 # ---------------------------------------------------------------------------
 # corpus BLEU (reference seq2seq reported BLEU; in-repo implementation)
 # ---------------------------------------------------------------------------
