@@ -21,7 +21,10 @@ Two readings of one step (the vocabulary is ``observability/spans.py``):
   the time no phase claims (``unattributed``) they add up to ``busy``.
 * **region** — the innermost kernel or allreduce-stage name
   (``flash-fwd``, ``fused-ce``, ``grad-pack``, ...): a finer cut of
-  *part* of the busy time.
+  *part* of the busy time.  ``region_tiles`` puts beside a flash
+  region's time the block geometry its kernel was built with and the
+  tiles it finds live, visits and copies a head row
+  (``spans.tiles_scope``, a component of the same paths).
 
 A fusion carries the ``op_name`` of ONE of the ops the compiler fused
 into it: a weight-gradient matmul with the AdamW update fused in counts
@@ -65,13 +68,24 @@ class ScopeTable(dict):
     ``containers`` holds the names of its ``while`` / ``conditional`` /
     ``call`` instructions, ``mixed`` those of the fusions that hold ops
     of another step phase than the one the fusion itself is named under,
-    ``program`` the module's name (``jit_train_step``)."""
+    ``program`` the module's name (``jit_train_step``), ``tiles`` the
+    distinct block geometries its kernels were built with, by region
+    (``spans.tiles_scope``)."""
 
     def __init__(self, paths=(), containers=(), program="", mixed=()):
         super().__init__(paths)
         self.containers = frozenset(containers)
         self.mixed = frozenset(mixed)
         self.program = program
+        self.tiles: Dict[str, List[dict]] = {}
+        for path in self.values():
+            found = [t for t in map(spans.parse_tiles,
+                                    scope_components(path)) if t]
+            region = classify(path)[1]
+            if found and region is not None:
+                seen = self.tiles.setdefault(region, [])
+                if found[-1] not in seen:
+                    seen.append(found[-1])
 
 
 def scope_table(compiled) -> ScopeTable:
@@ -345,6 +359,7 @@ def report_from(devices, host, tables: Dict[str, ScopeTable]) -> dict:
                              if total else None),
             "phase_ms": ms_by_name("phase"),
             "region_ms": ms_by_name("region"),
+            "region_tiles": tables[name].tiles if name in tables else {},
         }
     return out
 

@@ -82,6 +82,31 @@ HOST_SPANS = (
 )
 
 
+#: Inside a flash kernel's region: the block geometry the call was built
+#: with and its tile census a head row (``ops.flash_attention``).  Decided
+#: at trace time, so it is a record, not a rate: it rides in the path,
+#: ``device_trace`` reads it back off the compiled program and shows it
+#: beside the region's device time.  Not a region itself.
+TILE_FIELDS = ("block_q", "block_k", "live", "visited", "copied")
+_TILES = re.compile(
+    r"^tiles-q(\d+)-k(\d+)-live(\d+)-visited(\d+)-copied(\d+)$")
+
+
+def tiles_scope(block_q: int, block_k: int, live: int, visited: int,
+                copied: int):
+    """Scope component that records a kernel's geometry (TRACED code,
+    inside the kernel's :func:`named_scope`)."""
+    return jax.named_scope(
+        f"tiles-q{block_q}-k{block_k}-live{live}-visited{visited}"
+        f"-copied{copied}")
+
+
+def parse_tiles(part: str):
+    """``{field: int}`` of a :func:`tiles_scope` component, else None."""
+    m = _TILES.match(part)
+    return dict(zip(TILE_FIELDS, map(int, m.groups()))) if m else None
+
+
 def is_scope(name: str) -> bool:
     """Whether ``name`` is a device-side scope of the vocabulary."""
     return (

@@ -10,17 +10,19 @@ sequence-parallel model composes both: ring outside, this kernel inside
 each block pair.
 
 Causal skipping: grid programs whose whole K block is in the future of the
-whole Q block write nothing and skip the matmuls (``pl.when``), so the
-causal kernel does ~half the FLOPs, like the CUDA flash-attention kernels.
+whole Q block write nothing and skip the matmuls (``pl.when``), and their
+index maps stop at the band, so such a step copies nothing either.  A
+step has a fixed cost, so the blocks are as large as the scoped VMEM
+takes (:func:`auto_block_size`): at S=2048 a head row is four steps, not
+256.  What a call was built with — blocks, tiles live / visited / copied
+a head row (:func:`tile_census`) — rides in the kernels' scope path and
+goes to the telemetry sinks.
 
 Differentiable: a ``custom_vjp`` with explicit FlashAttention-2-style
 backward kernels — the forward saves one fp32 log-sum-exp per row, and the
 dQ / dK+dV kernels recompute probabilities blockwise from it, so neither
-pass ever materializes the S×S matrix.  Measured on a v5e-class chip at
-S=8192/bf16/D=128 (slope-timed; see docs/performance.md "Measuring"):
-forward ~67 TFLOP/s (4.5-4.9x XLA's materialized-logits attention),
-forward+backward 4.4x, backward alone ~81 TFLOP/s — at the chip's own
-sustained matmul roofline — with O(S) memory in both passes.
+pass ever materializes the S×S matrix: O(S) memory in both passes.
+Device times on a TPU v5e are in PERF.md (§5 and §6, PR 25).
 
 Optional segment-id masks support packed-sequence training: tokens attend
 only within their own segment, and padding rows produce zero output and
@@ -43,7 +45,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from chainermn_tpu.observability.spans import named_scope
+from chainermn_tpu.observability import reporter as _reporter
+from chainermn_tpu.observability import step_log as _step_log
+from chainermn_tpu.observability.spans import (
+    named_scope,
+    telemetry_active,
+    tiles_scope,
+)
 
 _NEG_INF = -1e30
 
@@ -84,10 +92,80 @@ def _band_live(causal, window, q_start, block_q, k_start, block_k):
     if causal:
         run = k_start <= q_start + block_q - 1
     if window is not None:
-        run = jnp.logical_and(
-            run, k_start + block_k - 1 >= q_start - (window - 1)
-        )
+        run = run & (k_start + block_k - 1 >= q_start - (window - 1))
     return run
+
+
+def _kv_live_range(iq, block_q, block_k, n_k, causal, window, xp=jnp):
+    """``(first, last)`` K block that q block ``iq``'s band reaches — the
+    same bounds as :func:`_band_live`, solved for the block index — each
+    held inside ``[0, n_k - 1]``.  ``xp`` is ``jnp`` in an index map and
+    ``numpy`` in the tile census."""
+    lo, hi = 0, n_k - 1
+    if causal:
+        hi = xp.minimum(hi, (iq * block_q + block_q - 1) // block_k)
+    if window is not None:
+        lo = xp.clip((iq * block_q - (window - 1)) // block_k, 0, n_k - 1)
+    return lo, hi
+
+
+def _q_live_range(ik, block_q, block_k, n_q, causal, window, xp=jnp):
+    """``(first, last)`` Q block whose band reaches k block ``ik`` (the
+    dk/dv kernel streams the query side), inside ``[0, n_q - 1]``."""
+    lo, hi = 0, n_q - 1
+    if causal:
+        lo = xp.minimum(n_q - 1, (ik * block_k) // block_q)
+    if window is not None:
+        hi = xp.minimum(
+            hi, (ik * block_k + block_k - 1 + window - 1) // block_q
+        )
+    return lo, hi
+
+
+def _banded(live_range, causal, window):
+    """``clamp(outer, inner)`` for an index map: the streamed operand's
+    block index held inside the band of the resident one.  Pallas copies
+    a block only when its index changes between consecutive grid steps,
+    so a tile outside the band (which ``pl.when`` skips anyway) moves no
+    data.  Identity when nothing bands the attention."""
+    if not causal and window is None:
+        return lambda outer, inner: inner
+
+    def clamp(outer, inner):
+        lo, hi = live_range(outer)
+        return jnp.clip(inner, lo, hi)
+
+    return clamp
+
+
+def tile_census(Sq, Sk, block_q, block_k, causal, window):
+    """What one head row's grid does at this geometry — ``{"fwd", "dq",
+    "dkv"}``, each ``{block_q, block_k, live, visited, copied}``: tiles
+    that intersect the band (they run the matmuls), grid steps, and
+    fetches of the streamed operand (K/V in forward and dq, the query
+    side in dk/dv: a step whose clamped block index repeats the previous
+    step's copies nothing).  Computed with the index maps' own bounds."""
+    n_q, n_k = Sq // block_q, Sk // block_k
+    iq = np.arange(n_q)[:, None]
+    ik = np.arange(n_k)[None, :]
+    k_lo, k_hi = _kv_live_range(iq, block_q, block_k, n_k, causal, window,
+                                xp=np)
+    q_lo, q_hi = _q_live_range(ik, block_q, block_k, n_q, causal, window,
+                               xp=np)
+    grid = (n_q, n_k)
+    live = int(np.broadcast_to(_band_live(
+        causal, window, iq * block_q, block_q, ik * block_k, block_k,
+    ), grid).sum())
+    kv_steps = np.broadcast_to(np.clip(ik, k_lo, k_hi), grid).ravel()
+    q_steps = np.broadcast_to(np.clip(iq, q_lo, q_hi), grid).T.ravel()
+
+    def fetches(steps):
+        return 1 + int(np.count_nonzero(np.diff(steps)))
+
+    base = {"block_q": block_q, "block_k": block_k, "live": live,
+            "visited": n_q * n_k}
+    kv = dict(base, copied=fetches(kv_steps))
+    return {"fwd": kv, "dq": kv, "dkv": dict(base, copied=fetches(q_steps))}
 
 
 def _attn_kernel(
@@ -164,6 +242,65 @@ def _attn_kernel(
         lse_ref[0] = (m_ref[:, 0] + jnp.log(denom))[:, None]
 
 
+#: What Mosaic lets one kernel use of a v5e core's 128 MiB VMEM unasked
+#: (its default scoped limit on this toolchain) — the budget the static
+#: default geometry is chosen within — and the most this module asks for
+#: when a pinned or tuned geometry needs more.
+VMEM_SCOPED_DEFAULT = 16 * 1024 * 1024
+VMEM_LIMIT_MAX = 96 * 1024 * 1024
+
+
+def _lanes(d: int) -> int:
+    """Mosaic pads the lane (last) dim to a multiple of 128."""
+    return max(128, -(-int(d) // 128) * 128)
+
+
+def flash_vmem_bytes(block_q: int, block_k: int, D: int, itemsize: int,
+                     which: str = "fwd", segmented: bool = False) -> int:
+    """VMEM bytes one grid program of the flash kernels holds.
+
+    Streamed blocks and outputs count twice (the pipeline fetches tile
+    ``t+1`` while ``t`` computes), scratch once, a ``(block, 1)`` column
+    (lse, delta, the running max and sum, segment ids) as the 128 lanes
+    it is padded to, and the ``(block_q, block_k)`` fp32 intermediates of
+    the body (``s``, ``p``, the mask; ``dp``, ``ds`` and the transposes
+    in the backward) as the share of them Mosaic keeps whole: two tiles,
+    three with a segment mask.  That share is read off the compiler —
+    the least scoped limit under which each kernel compiles for a v5e at
+    128 head rows, over D 128-256, bf16 and fp32, with and without
+    segment ids (PERF.md §6, PR 25): the rule never picks a tile that
+    fails to compile inside the default there.  ``which``: ``"fwd"``, or
+    ``"bwd"`` for the larger of the dq and dk/dv kernels (two
+    ``pallas_call``s at the same blocks)."""
+    qd = block_q * _lanes(D)
+    kd = block_k * _lanes(D)
+    q_col = block_q * 128 * 4
+    seg = 2 * (q_col + block_k * 128 * 4) if segmented else 0
+    tiles = (3 if segmented else 2) * block_q * block_k * 4
+    if which == "fwd":
+        streamed = 2 * (qd + 2 * kd) * itemsize + seg
+        outputs = 2 * (qd * itemsize + q_col)
+        scratch = qd * 4 + 2 * q_col
+        return streamed + outputs + scratch + tiles
+    # q, k, v, do and the lse and delta columns stream in both kernels.
+    streamed = 2 * (2 * qd + 2 * kd) * itemsize + 2 * 2 * q_col + seg
+    dq = streamed + 2 * qd * itemsize + qd * 4
+    dkv = streamed + 2 * 2 * kd * itemsize + 2 * kd * 4
+    return max(dq, dkv) + tiles
+
+
+def _compiler_params(footprint: int):
+    """Mosaic parameters for a kernel of this footprint: the scoped-VMEM
+    limit is raised to what the blocks need, and a quarter more, when
+    that is more than the default (a pinned or tuned 2048 x 2048 tile);
+    never lowered."""
+    if footprint <= VMEM_SCOPED_DEFAULT:
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(footprint + footprint // 4, VMEM_LIMIT_MAX)
+    )
+
+
 def _kv_group(BHq: int, BHk: int) -> int:
     """Query-heads-per-KV-head group size, derived purely from the leading
     (batch*heads) dims — GQA/MQA need no extra static arguments.
@@ -179,6 +316,16 @@ def _kv_group(BHq: int, BHk: int) -> int:
     return BHq // BHk
 
 
+#: The two kernel wrappers are jitted in their own right: a model calls
+#: them once a layer with the same shapes, and a jitted callee is traced
+#: once and lowered to one function that every layer calls — Mosaic's
+#: lowering of a 1024 x 1024 tile, paid at every process start even with
+#: the compile cache warm, is then paid three times and not 3 x layers.
+_KERNEL_STATICS = ("scale", "causal", "block_q", "block_k", "interpret",
+                   "window")
+
+
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
 def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
                   q_seg=None, kv_seg=None, window=None):
     """(BH, S, D) flash attention forward; returns (o, lse).
@@ -205,19 +352,28 @@ def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
         pltpu.VMEM((block_q, 1), jnp.float32),
         pltpu.VMEM((block_q, 1), jnp.float32),
     ]
+    kv_j = _banded(
+        lambda i: _kv_live_range(i, block_q, block_k, grid[2], causal,
+                                 window),
+        causal, window,
+    )
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b // G, j, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b // G, j, 0)),
+        pl.BlockSpec((1, block_k, D),
+                     lambda b, i, j: (b // G, kv_j(i, j), 0)),
+        pl.BlockSpec((1, block_k, D),
+                     lambda b, i, j: (b // G, kv_j(i, j), 0)),
     ]
     args = [q, k, v]
     if segmented:
         in_specs += [
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, 1), lambda b, i, j: (b // G, j, 0)),
+            pl.BlockSpec((1, block_k, 1),
+                         lambda b, i, j: (b // G, kv_j(i, j), 0)),
         ]
         args += [q_seg, kv_seg]
-    with named_scope("flash-fwd"):
+    tiles = tile_census(Sq, Sk, block_q, block_k, causal, window)["fwd"]
+    with named_scope("flash-fwd"), tiles_scope(**tiles):
         return pl.pallas_call(
             kernel,
             out_shape=[
@@ -231,6 +387,8 @@ def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
                 pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
             ],
             scratch_shapes=scratch,
+            compiler_params=_compiler_params(flash_vmem_bytes(
+                block_q, block_k, D, q.dtype.itemsize, "fwd", segmented)),
             interpret=interpret,
             name="flash-fwd",
         )(*args)
@@ -342,6 +500,7 @@ def _dkv_kernel(
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
 def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
                   interpret, dlse=None, q_seg=None, kv_seg=None,
                   window=None):
@@ -364,30 +523,42 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)[..., None]
 
+    n_q = Sq // block_q
+    n_k = Sk // block_k
+    params = _compiler_params(flash_vmem_bytes(
+        block_q, block_k, D, q.dtype.itemsize, "bwd", segmented))
+    kv_j = _banded(
+        lambda i: _kv_live_range(i, block_q, block_k, n_k, causal, window),
+        causal, window,
+    )
     q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b // G, j, 0))
+    k_spec = pl.BlockSpec((1, block_k, D),
+                          lambda b, i, j: (b // G, kv_j(i, j), 0))
     r_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
     dq_in = [q_spec, k_spec, k_spec, q_spec, r_spec, r_spec]
     dq_args = [q, k, v, do, lse, delta]
     if segmented:
         dq_in += [
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, 1), lambda b, i, j: (b // G, j, 0)),
+            r_spec,
+            pl.BlockSpec((1, block_k, 1),
+                         lambda b, i, j: (b // G, kv_j(i, j), 0)),
         ]
         dq_args += [q_seg, kv_seg]
-    with named_scope("flash-bwd-dq"):
+    tiles = tile_census(Sq, Sk, block_q, block_k, causal, window)
+    with named_scope("flash-bwd-dq"), tiles_scope(**tiles["dq"]):
         dq = pl.pallas_call(
             functools.partial(
                 _dq_kernel, scale=scale, causal=causal, segmented=segmented,
                 block_q=block_q, block_k=block_k, window=window,
             ),
             out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
-            grid=(BH, Sq // block_q, Sk // block_k),
+            grid=(BH, n_q, n_k),
             in_specs=dq_in,
             out_specs=pl.BlockSpec(
                 (1, block_q, D), lambda b, i, j: (b, i, 0)
             ),
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+            compiler_params=params,
             interpret=interpret,
             name="flash-bwd-dq",
         )(*dq_args)
@@ -397,26 +568,28 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
     # accumulate in the scratch and flush once, so GQA's dk/dv reduction
     # needs no extra pass.  Query-side rows for (kv row b, inner step i)
     # live at q row b*G + i // n_q, q block i % n_q.
-    n_q = Sq // block_q
-    qT_spec = pl.BlockSpec(
-        (1, block_q, D), lambda b, j, i: (b * G + i // n_q, i % n_q, 0)
+    # The query side is the streamed one here: its block index stops at
+    # the band of k block j, within each query head of the group.
+    q_i = _banded(
+        lambda j: _q_live_range(j, block_q, block_k, n_q, causal, window),
+        causal, window,
     )
+
+    def q_rows(b, j, i):
+        return (b * G + i // n_q, q_i(j, i % n_q), 0)
+
+    qT_spec = pl.BlockSpec((1, block_q, D), q_rows)
     kT_spec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
-    rT_spec = pl.BlockSpec(
-        (1, block_q, 1), lambda b, j, i: (b * G + i // n_q, i % n_q, 0)
-    )
+    rT_spec = pl.BlockSpec((1, block_q, 1), q_rows)
     dkv_in = [qT_spec, kT_spec, kT_spec, qT_spec, rT_spec, rT_spec]
     dkv_args = [q, k, v, do, lse, delta]
     if segmented:
         dkv_in += [
-            pl.BlockSpec(
-                (1, block_q, 1),
-                lambda b, j, i: (b * G + i // n_q, i % n_q, 0),
-            ),
+            rT_spec,
             pl.BlockSpec((1, block_k, 1), lambda b, j, i: (b, j, 0)),
         ]
         dkv_args += [q_seg, kv_seg]
-    with named_scope("flash-bwd-dkv"):
+    with named_scope("flash-bwd-dkv"), tiles_scope(**tiles["dkv"]):
         dk, dv = pl.pallas_call(
             functools.partial(
                 _dkv_kernel, scale=scale, causal=causal,
@@ -427,7 +600,7 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
                 jax.ShapeDtypeStruct((BHk, Sk, D), k.dtype),
                 jax.ShapeDtypeStruct((BHk, Sk, D), v.dtype),
             ],
-            grid=(BHk, Sk // block_k, G * n_q),
+            grid=(BHk, n_k, G * n_q),
             in_specs=dkv_in,
             out_specs=[
                 pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
@@ -437,6 +610,7 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
                 pltpu.VMEM((block_k, D), jnp.float32),
                 pltpu.VMEM((block_k, D), jnp.float32),
             ],
+            compiler_params=params,
             interpret=interpret,
             name="flash-bwd-dkv",
         )(*dkv_args)
@@ -654,19 +828,63 @@ def _xla_attention(q, k, v, scale, causal, q_segment_ids=None,
     return jnp.einsum("bhqk,bkhd->bqhd", w, v.astype(jnp.float32)).astype(q.dtype)
 
 
-def auto_block_size(S: int) -> int:
-    """The STATIC default block edge: largest-coverage choice near S/16
-    that both divides S and meets the sublane alignment (128/256/512 are
-    multiples of every sublane count) — a poor auto pick must not
-    silently demote a previously-compiling shape to the XLA fallback.
-    This is also the fallback the tuning subsystem resolves to on a
-    cache miss, and a mandatory member of its search space (a tuned pick
-    can never lose to it)."""
-    target = int(np.clip(S // 16, 128, 512))
-    cands = [b for b in (128, 256, 512) if S % b == 0]
-    if not cands:
+def _sublane(dtype) -> int:
+    """Rows of one tile of this dtype: a compiled block's second-to-last
+    dim must be a multiple of it."""
+    return 16 if jnp.dtype(dtype) == jnp.bfloat16 else 8
+
+
+def auto_block_size(S: int, D: int, dtype, which: str = "fwd",
+                    segmented: bool = False,
+                    window: Optional[int] = None) -> int:
+    """The STATIC default block edge along a length-``S`` axis: the
+    largest multiple of 128 that divides ``S`` and whose square tile fits
+    Mosaic's default scoped VMEM (:data:`VMEM_SCOPED_DEFAULT`) by
+    :func:`flash_vmem_bytes` for this head dim, dtype, kernel (``which``:
+    ``"fwd"`` or ``"bwd"``) and segment mask: 1024 at D=128 in bf16, 512
+    at D=256 in fp32 or with segment ids.  A grid step has a fixed cost
+    and the kernels come near the MXU only at large tiles, so few full
+    steps beat many small ones (PERF.md §6, PR 25: the sweep at B=8,
+    H=16, S=2048 took the three kernels from 41.9 ms a layer at
+    128 x 128 to 6.4 at 1024 x 1024).  Under a sliding window no wider
+    than the band, which a wider tile would only fill with masked work.
+    Each axis is sized alone: the footprint grows with either edge, so a
+    pair of fitting edges fits.  A length no multiple of 128 divides
+    keeps the old answer (``min(128, S)``: whole when short, else a
+    block that does not divide and sends the call to XLA) — an auto pick
+    must not demote a shape that compiles.  This is also what the tuning
+    subsystem resolves to on a cache miss, and a mandatory member of its
+    search space."""
+    edges = [b for b in range(128, S + 1, 128) if S % b == 0]
+    if not edges:
         return min(128, S)
-    return min(cands, key=lambda b: abs(b - target))
+    if window is not None:
+        edges = [b for b in edges if b <= max(window, edges[0])]
+    itemsize = jnp.dtype(dtype).itemsize
+    fits = [b for b in edges
+            if flash_vmem_bytes(b, b, D, itemsize, which, segmented)
+            <= VMEM_SCOPED_DEFAULT]
+    return max(fits, default=edges[0])
+
+
+def _publish_geometry(fwd: dict, bwd: dict) -> None:
+    """One record a :func:`flash_attention` call that reaches the kernels
+    (at TRACE time: a jitted step publishes again only when retraced):
+    the blocks each kernel was given and the tiles it finds live, visits
+    and copies a head row.  To the installed sinks: a ``flash_geometry``
+    row of the StepRecorder, ``flash/<kernel>/<field>`` gauges and a
+    ``flash/calls`` counter of the Reporter."""
+    record = {"flash-fwd": fwd, "flash-bwd-dq": bwd["dq"],
+              "flash-bwd-dkv": bwd["dkv"]}
+    rec = _step_log.current_recorder()
+    if rec is not None:
+        rec.record("flash_geometry", **record)
+    rep = _reporter.get_reporter()
+    if rep is not None:
+        rep.count("flash/calls")
+        for kernel, tiles in record.items():
+            for field, value in tiles.items():
+                rep.gauge(f"flash/{kernel}/{field}", value)
 
 
 def flash_attention(
@@ -717,17 +935,20 @@ def flash_attention(
     measured-best entry for this (device kind, dtype, shape bucket,
     causal/window) — populated by ``python -m chainermn_tpu.tools
     .autotune`` or ``bench.py --autotune``, never implicitly.  On a miss,
-    off-TPU, or under pytest, the static auto size applies: ``S/16``
-    clamped to [128, 512] — measured optimal per length on a v5e-class
-    chip (S=2048→128, 4096→256, 8192→512; at 8192/bf16/D=128 the kernel
-    sustains ~67 TFLOP/s forward, 4.5-4.9x XLA's materialized-logits
-    attention, slope-timed per docs/performance.md).  Pinning either
-    block explicitly bypasses the cache entirely.
+    off-TPU, or under pytest, the static rule applies
+    (:func:`auto_block_size`): along each axis the largest multiple of
+    128 that divides it and whose square tile fits Mosaic's default
+    scoped VMEM by the kernels' own footprint — 1024 at D=128 in bf16.
+    It rests on the ledger's PR 24 line of ``cgpt-train-1chip`` (the old
+    S/16 rule's 128 x 128 at S=2048: ``kernel.flash_ms`` 336.5 at 5.0%
+    of the roofline) and on the block sweep of PERF.md §6, PR 25.
+    Pinning either block explicitly bypasses the cache entirely.
 
     ``block_q_bwd``/``block_k_bwd``: optional separate geometry for the
     backward kernels (tuned independently — the backward streams two
-    extra operands and runs two kernels, so its optimum can differ);
-    default to the forward blocks (tuned or static).
+    extra operands and runs two kernels, so its optimum can differ).
+    With nothing pinned they default to the rule's answer for the
+    backward's footprint; blocks pinned for the forward carry over.
     """
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
@@ -757,7 +978,8 @@ def flash_attention(
         interpret = default_interpret()
 
     segmented = q_segment_ids is not None
-    if block_q is None and block_k is None and not interpret:
+    pinned = block_q is not None or block_k is not None
+    if not pinned and not interpret:
         # Caller pinned nothing: consult the persistent tune cache (a
         # trace-time read; inert under pytest and off-TPU, so interpret/
         # CPU behavior stays bit-identical to the static defaults).
@@ -777,10 +999,18 @@ def flash_attention(
             if tuned_bwd is not None:
                 block_q_bwd, block_k_bwd = tuned_bwd
 
+    def static(S, which):
+        return auto_block_size(S, D, q.dtype, which, segmented, window)
+
+    if not pinned and block_q_bwd is None and block_k_bwd is None:
+        # Nothing pinned or tuned: the backward gets the rule's own
+        # answer (it holds more per tile).  Blocks a caller pinned for
+        # the forward carry over to the backward, as they always did.
+        block_q_bwd, block_k_bwd = static(Sq, "bwd"), static(Sk, "bwd")
     if block_q is None:
-        block_q = auto_block_size(Sq)
+        block_q = static(Sq, "fwd")
     if block_k is None:
-        block_k = auto_block_size(Sk)
+        block_k = static(Sk, "fwd")
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
     # Sublane tiling constraint on compiled TPU kernels: the block's
@@ -788,7 +1018,7 @@ def flash_attention(
     # The lane (last) dim need not be a multiple of 128 — Mosaic pads it —
     # so any head_dim ≤ 128 compiles.  Interpret mode has no tiling, so
     # the CPU harness can exercise smaller shapes.
-    sublane = 16 if q.dtype == jnp.bfloat16 else 8
+    sublane = _sublane(q.dtype)
     tile_ok = interpret or (
         block_q % sublane == 0 and block_k % sublane == 0
     )
@@ -827,6 +1057,13 @@ def flash_attention(
         )
         block_q_bwd, block_k_bwd = (bq_b, bk_b) if bwd_ok else (None, None)
 
+    if telemetry_active():
+        _publish_geometry(
+            tile_census(Sq, Sk, block_q, block_k, causal, window)["fwd"],
+            tile_census(Sq, Sk, block_q_bwd or block_q,
+                        block_k_bwd or block_k, causal, window),
+        )
+
     # (B, S, H, D) → (B*H, S, D); kv keep their own (possibly smaller)
     # head count — the batch-major flattening makes q row b's kv row
     # exactly b // (H // Hk) (see _kv_group).
@@ -854,8 +1091,9 @@ def flash_block_plan(S: int, D: int, dtype, interpret: bool):
     (ring/zigzag).  Mirrors :func:`flash_attention`'s gating: D ≤ 256
     compiled, blocks always DIVIDING S (a
     non-dividing block floors the grid and silently drops tail rows —
-    interpret mode included), sized near the measured-optimal S/16
-    clamped to [128, 512]."""
+    interpret mode included), sized by :func:`auto_block_size`: the
+    largest tile that fits the default scoped VMEM in the forward AND
+    the backward kernels, which share the one block here."""
     if interpret:
         # Interpreter-mode block policy: a full-S block materializes the
         # S×S matrix (defeating the O(S) property), while a degenerate
@@ -873,10 +1111,11 @@ def flash_block_plan(S: int, D: int, dtype, interpret: bool):
         return True, b
     if D > 256:
         return False, 0
-    if any(S % b == 0 for b in (128, 256, 512)):
-        return True, auto_block_size(S)
-    sublane = 16 if dtype == jnp.bfloat16 else 8
-    if S <= 512 and S % sublane == 0:
+    if S % 128 == 0:
+        # One block serves the forward and both backward kernels here.
+        return True, min(auto_block_size(S, D, dtype, "fwd"),
+                         auto_block_size(S, D, dtype, "bwd"))
+    if S <= 512 and S % _sublane(dtype) == 0:
         return True, S
     return False, 0
 

@@ -1,13 +1,15 @@
 """Kernel autotuning — searched block configs for the Pallas hot paths.
 
-The hot kernels (``ops.flash_attention``, ``ops.fused_ce``) shipped with
-one magic geometry each: ``block_q``/``block_k`` ~ S/16 clamped to
-[128, 512] and ``chunk = 512``.  Blockwise TPU kernels are highly
-sensitive to tile shape, and the best choice shifts with sequence
-length, head dim, dtype and the causal/window band — so, following the
-reference framework's own design principle (expose the knob, but pick a
-fast default FOR the user: ``allreduce_grad_dtype``,
-``double_buffering``), this package measures the best config per shape
+The hot kernels (``ops.flash_attention``, ``ops.fused_ce``) ship with
+one static geometry each: ``block_q``/``block_k`` the largest tile that
+fits Mosaic's default scoped VMEM (``ops.flash_attention
+.auto_block_size``; PERF.md §6, PR 25) and ``chunk = 512``.  Blockwise
+TPU kernels are highly sensitive to tile shape, and the best choice
+shifts with sequence length, head dim, dtype and the causal/window band
+— so, following the reference framework's own design principle (expose
+the knob, but pick a fast default FOR the user:
+``allreduce_grad_dtype``, ``double_buffering``), this package measures
+the best config per shape
 once and remembers it:
 
 * :mod:`~chainermn_tpu.tuning.search_space` — per-kernel candidate

@@ -388,9 +388,14 @@ def tune_flash(
     """
     import numpy as np
 
-    fwd_space = flash_search_space(Sq, Sk, D, dtype, which="fwd")
-    bwd_space = flash_search_space(Sq, Sk, D, dtype, which="bwd")
-    default_cfg = flash_default_config(Sq, Sk)
+    fwd_space = flash_search_space(Sq, Sk, D, dtype, which="fwd",
+                                   window=window)
+    bwd_space = flash_search_space(Sq, Sk, D, dtype, which="bwd",
+                                   window=window)
+    default_cfg = flash_default_config(Sq, Sk, D, dtype, "fwd",
+                                       window=window)
+    default_bwd = flash_default_config(Sq, Sk, D, dtype, "bwd",
+                                       window=window)
     dev = device_kind()
     fwd_key = flash_cache_key("fwd", dev, dtype, Sq, Sk, D, causal, window)
     bwd_key = flash_cache_key("bwd", dev, dtype, Sq, Sk, D, causal, window)
@@ -400,7 +405,7 @@ def tune_flash(
             "fwd": {"key": fwd_key, "candidates": fwd_space,
                     "default": default_cfg},
             "bwd": {"key": bwd_key, "candidates": bwd_space,
-                    "default": default_cfg},
+                    "default": default_bwd},
         }
     _require_tuning_allowed("flash attention")
     cache = cache or shared_cache()
@@ -501,7 +506,7 @@ def tune_flash(
         build_bwd, bwd_space, n1=n1, repeats=repeats, log=log
     )
     out["bwd"] = _finish(
-        bwd_key, results, default_cfg, cache,
+        bwd_key, results, default_bwd, cache,
         {"kernel": "flash_bwd", "dtype": dtype_name(dtype),
          "Sq": Sq, "Sk": Sk, "D": D, "causal": causal,
          "window": window, "batch_heads": batch_heads,

@@ -7,14 +7,14 @@ schema.  The kernel's own static default is ALWAYS a member of the
 space, so the measured argmin can never be slower than shipping the
 magic number (the tuner picks the default when nothing beats it).
 
-VMEM model (v4/v5 class chips have ~16 MiB/core): a Pallas grid program
-holds its input blocks double-buffered (the pipeline prefetches tile
-``i+1`` while computing ``i``), its output blocks double-buffered, and
-its scratch once.  The estimate errs conservative — Mosaic pads the lane
-(last) dim to a multiple of 128 — and candidates over budget are pruned
-before compilation rather than left to die as OOM (they are *also*
-skipped-on-error in the measure harness, for the shapes the model
-misjudges).
+VMEM model: the flash kernels' own footprint
+(``ops.flash_attention.flash_vmem_bytes`` — streamed blocks twice,
+scratch once, the fp32 ``(block_q, block_k)`` intermediates), the one the
+static default is chosen by and the kernels' scoped-VMEM limit is set
+from.  Candidates past the most a kernel may ask for
+(``VMEM_LIMIT_MAX``) are pruned before compilation rather than left to
+die as OOM (they are *also* skipped-on-error in the measure harness, for
+the shapes the model misjudges).
 """
 
 from __future__ import annotations
@@ -23,15 +23,9 @@ from typing import List, Optional
 
 from chainermn_tpu.tuning.cache import bucket_pow2, make_key
 
-VMEM_BYTES = 16 * 1024 * 1024
-#: fraction of VMEM the estimate may claim — headroom for Mosaic's own
-#: temporaries and the iota/mask intermediates inside the kernel body.
-VMEM_BUDGET_FRACTION = 0.75
-
 #: candidate tile edges: every multiple-of-sublane power of two between
-#: the smallest tile worth scheduling and the largest that a 16 MiB VMEM
-#: can double-buffer at common head dims.
-BLOCK_CANDIDATES = (64, 128, 256, 512, 1024)
+#: the smallest tile worth scheduling and a whole 2048 sequence.
+BLOCK_CANDIDATES = (64, 128, 256, 512, 1024, 2048)
 
 #: fused-CE row-chunk candidates; the static default 512 sits mid-range.
 CE_CHUNK_CANDIDATES = (128, 256, 512, 1024, 2048, 4096)
@@ -40,41 +34,10 @@ CE_CHUNK_CANDIDATES = (128, 256, 512, 1024, 2048, 4096)
 CE_TILE_BYTES_MAX = 512 * 1024 * 1024
 
 
-def _pad_lane(d: int) -> int:
-    """Mosaic pads the lane (last) dim to a multiple of 128."""
-    return max(128, ((int(d) + 127) // 128) * 128)
-
-
 def _sublane(dtype) -> int:
     from chainermn_tpu.tuning.cache import dtype_name
 
     return 16 if dtype_name(dtype) == "bfloat16" else 8
-
-
-def flash_vmem_bytes(block_q: int, block_k: int, D: int, itemsize: int,
-                     which: str = "fwd", segmented: bool = False) -> int:
-    """Estimated VMEM bytes for one grid program of the flash kernels.
-
-    ``which``: ``"fwd"`` models the forward kernel; ``"bwd"`` the max of
-    the dq and dk/dv kernels (they are separate ``pallas_call``s, so the
-    binding constraint is whichever is larger).
-    """
-    Dp = _pad_lane(D)
-    qd = block_q * Dp
-    kd = block_k * Dp
-    seg = 2 * (block_q + block_k) * 4 if segmented else 0
-    if which == "fwd":
-        inputs = 2 * (qd + 2 * kd) * itemsize + seg
-        outputs = 2 * (qd * itemsize + block_q * 4)
-        scratch = qd * 4 + 2 * block_q * 4
-        return inputs + outputs + scratch
-    # backward: q, k, v, do + lse, delta rows in both kernels
-    rows = 2 * 2 * block_q * 4
-    dq_in = 2 * (2 * qd + 2 * kd) * itemsize + rows + seg
-    dq_total = dq_in + 2 * qd * itemsize + qd * 4
-    dkv_in = 2 * (2 * qd + 2 * kd) * itemsize + rows + seg
-    dkv_total = dkv_in + 2 * 2 * kd * itemsize + 2 * kd * 4
-    return max(dq_total, dkv_total)
 
 
 def flash_search_space(
@@ -85,18 +48,22 @@ def flash_search_space(
     which: str = "fwd",
     segmented: bool = False,
     vmem_budget: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> List[dict]:
     """Valid ``{"block_q", "block_k"}`` candidates for the flash kernels:
     blocks divide their sequence, meet the dtype's sublane alignment, and
-    fit the VMEM budget.  The static auto default is inserted if the
-    filters somehow excluded it (it compiles today, so it stays
+    fit the VMEM a kernel may ask for.  The static default is inserted if
+    the filters somehow excluded it (it compiles today, so it stays
     reachable)."""
     import numpy as np
 
-    from chainermn_tpu.ops.flash_attention import auto_block_size
+    from chainermn_tpu.ops.flash_attention import (
+        VMEM_LIMIT_MAX,
+        flash_vmem_bytes,
+    )
 
     if vmem_budget is None:
-        vmem_budget = int(VMEM_BYTES * VMEM_BUDGET_FRACTION)
+        vmem_budget = VMEM_LIMIT_MAX
     itemsize = np.dtype(dtype).itemsize
     sub = _sublane(dtype)
     out = []
@@ -110,17 +77,23 @@ def flash_search_space(
                                 segmented) > vmem_budget:
                 continue
             out.append({"block_q": bq, "block_k": bk})
-    default = {"block_q": auto_block_size(Sq), "block_k": auto_block_size(Sk)}
+    default = flash_default_config(Sq, Sk, D, dtype, which, segmented,
+                                   window)
     if default not in out:
         out.append(default)
     return out
 
 
-def flash_default_config(Sq: int, Sk: int) -> dict:
+def flash_default_config(Sq: int, Sk: int, D: int, dtype,
+                         which: str = "fwd", segmented: bool = False,
+                         window: Optional[int] = None) -> dict:
     """The static default geometry (what a cache miss resolves to)."""
     from chainermn_tpu.ops.flash_attention import auto_block_size
 
-    return {"block_q": auto_block_size(Sq), "block_k": auto_block_size(Sk)}
+    return {
+        "block_q": auto_block_size(Sq, D, dtype, which, segmented, window),
+        "block_k": auto_block_size(Sk, D, dtype, which, segmented, window),
+    }
 
 
 def flash_cache_key(kind: str, dev_kind: str, dtype, Sq: int, Sk: int,

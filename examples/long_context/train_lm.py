@@ -473,7 +473,9 @@ def main(argv=None):
             print("device profile, ms a step by phase: "
                   f"{row.get('phase_ms')} by region: "
                   f"{row.get('region_ms')} unattributed: "
-                  f"{row.get('unattributed_ms')}")
+                  f"{row.get('unattributed_ms')} flash tiles by region "
+                  f"(blocks; live/visited/copied a head row): "
+                  f"{row.get('region_tiles')}")
 
     last = float("nan")
     for epoch in range(args.epochs):

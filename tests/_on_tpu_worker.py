@@ -52,12 +52,17 @@ def flash():
 
     assert jax.default_backend() == "tpu", jax.default_backend()
     rng = np.random.RandomState(0)
-    for dtype, causal, S, tol in [
-        (jnp.bfloat16, True, 1024, 2e-2),
-        (jnp.bfloat16, False, 1024, 2e-2),
-        (jnp.float32, True, 1024, 2e-3),
+    for dtype, causal, S, D, tol in [
+        (jnp.bfloat16, True, 1024, 64, 2e-2),
+        (jnp.bfloat16, False, 1024, 64, 2e-2),
+        (jnp.float32, True, 1024, 64, 2e-3),
+        # The default geometry where it makes several tiles (1024 x 1024
+        # of them: 2 x 2 with one dead, 4 x 4 with six), at the
+        # benchmark's head dim: clamped index maps, compiled.
+        (jnp.bfloat16, True, 2048, 128, 2e-2),
+        (jnp.bfloat16, True, 4096, 128, 2e-2),
     ]:
-        B, H, D = 1, 2, 64
+        B, H = 1, 2
         q, k, v = (
             jnp.asarray(rng.randn(B, S, H, D), dtype) / (D**0.25)
             for _ in range(3)
@@ -87,7 +92,8 @@ def flash():
         g = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
         gref = jax.jit(jax.grad(loss_xla, argnums=(0, 1, 2)))(q, k, v)
         _assert_grads_close(g, gref, 10 * tol, (dtype, causal))
-        print(f"flash-on-tpu ok: dtype={jnp.dtype(dtype).name} causal={causal}")
+        print(f"flash-on-tpu ok: dtype={jnp.dtype(dtype).name} "
+              f"causal={causal} S={S} D={D}")
 
     # Segment-id masks (packed sequences), compiled: fwd + grads match the
     # dense oracle; padding rows are exactly zero in BOTH passes.

@@ -367,6 +367,67 @@ def test_report_splits_the_capture_by_program():
         {"chainermn:train_step": 500.0, "unannotated": 1250.0})
 
 
+def test_tiles_component_is_a_record_not_a_region():
+    part = "tiles-q512-k1024-live10-visited16-copied9"
+    assert spans.parse_tiles(part) == {
+        "block_q": 512, "block_k": 1024, "live": 10, "visited": 16,
+        "copied": 9}
+    assert spans.parse_tiles("flash-fwd") is None
+    assert not spans.is_scope(part)
+    path = f"jit(train_step)/fwd-bwd/jvp(LM)/flash-fwd/{part}/pallas_call"
+    assert device_trace.classify(path) == ("fwd-bwd", "flash-fwd")
+
+    def f(x):
+        with spans.tiles_scope(block_q=512, block_k=1024, live=10,
+                               visited=16, copied=9):
+            return jnp.sin(x)
+
+    compiled = jax.jit(f).lower(jnp.ones((8,))).compile()
+    assert f"jit(f)/{part}/sin" in compiled.as_text()
+
+
+def test_report_puts_the_tiles_beside_the_region():
+    tiles = "tiles-q64-k32-live6-visited8-copied4"
+    table = device_trace.ScopeTable({
+        "f": f"jit(train_step)/fwd-bwd/jvp(LM)/flash-fwd/{tiles}/"
+             "pallas_call",
+        "f2": f"jit(train_step)/fwd-bwd/jvp(LM)/flash-fwd/{tiles}/"
+              "pallas_call",
+        "g": "jit(train_step)/fwd-bwd/transpose(jvp(LM))/flash-bwd-dq/"
+             "tiles-q32-k32-live10-visited16-copied9/pallas_call",
+        "a": "jit(train_step)/fwd-bwd/jvp(LM)/mul",
+    }, program="jit_train_step")
+    assert table.tiles == {
+        "flash-fwd": [{"block_q": 64, "block_k": 32, "live": 6,
+                       "visited": 8, "copied": 4}],
+        "flash-bwd-dq": [{"block_q": 32, "block_k": 32, "live": 10,
+                          "visited": 16, "copied": 9}],
+    }
+    devices = [{"name": "/device:TPU:0",
+                "ops": [("f", 0.0, 1.0), ("g", 1.0, 3.0)],
+                "modules": [("jit_train_step(1)", 0.0, 3.0)]}]
+    row = device_trace.report_from(
+        devices, [], {"train_step": table})["programs"]["train_step"]
+    assert row["region_ms"] == pytest.approx(
+        {"flash-fwd": 1000.0, "flash-bwd-dq": 2000.0})
+    assert row["region_tiles"] == table.tiles
+
+
+def test_compiled_step_carries_the_flash_geometry(tiny_step):
+    """The three kernels' blocks and tile census ride in the compiled
+    step's own paths: a capture shows them whether or not the step was
+    traced under a telemetry sink."""
+    step, params, state, feed = tiny_step
+    table = device_trace.scope_table(
+        step.lower(params, state, feed(0)).compile())
+    assert set(table.tiles) == {"flash-fwd", "flash-bwd-dq",
+                                "flash-bwd-dkv"}
+    for found in table.tiles.values():
+        assert len(found) == 1 and set(found[0]) == set(spans.TILE_FIELDS)
+        assert 0 < found[0]["live"] <= found[0]["visited"]
+        assert 0 < found[0]["copied"] <= found[0]["visited"]
+
+
 # ------------------------------------------------------------------- capture
 def test_capture_reports_and_hands_the_report_to_the_sinks(tmp_path,
                                                            tiny_step):

@@ -142,11 +142,17 @@ def test_flash_block_plan_blocks_always_divide():
             ok, b = flash_block_plan(S, 64, jnp.float32, interpret)
             if ok:
                 assert S % b == 0, (S, interpret, b)
-    # Compiled path prefers the measured-optimal ~S/16 among divisors.
+    # Compiled path: the geometry rule's largest square tile inside the
+    # default scoped VMEM, the smaller of the forward's and the
+    # backward's (one block serves all three kernels of a chunk).
+    ok, b = flash_block_plan(2048, 128, jnp.bfloat16, False)
+    assert ok and b == 1024
     ok, b = flash_block_plan(2048, 64, jnp.float32, False)
-    assert ok and b == 128
-    ok, b = flash_block_plan(8192, 64, jnp.float32, False)
+    assert ok and b == 512      # the fp32 backward's streams are twice as wide
+    ok, b = flash_block_plan(8192, 256, jnp.float32, False)
     assert ok and b == 512
+    ok, b = flash_block_plan(384, 128, jnp.bfloat16, False)
+    assert ok and b == 384
 
 
 def test_flash_block_plan_interpret_clamps_block():
@@ -536,3 +542,294 @@ def test_flash_window_fallback_and_validation():
         flash_attention(q, k, v, causal=False, window=30)
     with pytest.raises(ValueError, match="window"):
         flash_attention(q, k, v, causal=True, window=0)
+
+
+# ---------------------------------------------------------------------------
+# The geometry rule, the band clamp of the index maps, the tile census.
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("segmented", [False, True])
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("D", [64, 128, 256])
+def test_auto_block_size_divides_aligns_and_fits(D, dtype, which, segmented):
+    """The static default along an axis: divides S, meets the sublane
+    count, and its square tile fits the default scoped VMEM by the
+    kernels' own footprint — and is the LARGEST such edge."""
+    from chainermn_tpu.ops.flash_attention import (
+        VMEM_SCOPED_DEFAULT,
+        auto_block_size,
+        flash_vmem_bytes,
+    )
+
+    itemsize = jnp.dtype(dtype).itemsize
+    sublane = 16 if dtype == jnp.bfloat16 else 8
+
+    def fits(b):
+        return flash_vmem_bytes(
+            b, b, D, itemsize, which, segmented) <= VMEM_SCOPED_DEFAULT
+
+    for S in (128, 256, 384, 512, 1024, 1536, 2048, 2688, 4096, 8192):
+        b = auto_block_size(S, D, dtype, which, segmented)
+        assert S % b == 0 and b % sublane == 0 and fits(b), (S, b)
+        larger = [c for c in range(b + 128, S + 1, 128) if S % c == 0]
+        assert not any(fits(c) for c in larger), (S, b, larger)
+    # What the benchmark's cells get (D=128, bf16, S=2048), and a head
+    # wide enough to halve it.
+    assert auto_block_size(2048, 128, jnp.bfloat16, which) == 1024
+    assert auto_block_size(2048, 256, jnp.float32, which) == 512
+    assert auto_block_size(2048, 128, jnp.bfloat16, which, True) == 512
+    # A length no multiple of 128 divides keeps the old answer.
+    assert auto_block_size(100, D, dtype, which, segmented) == 100
+    assert auto_block_size(1000, D, dtype, which, segmented) == 128
+    # Under a sliding window: no wider than the band.
+    assert auto_block_size(2048, D, dtype, which, segmented, window=300) == 256
+    assert auto_block_size(2048, D, dtype, which, segmented, window=64) == 128
+
+
+def test_flash_vmem_bytes_counts_tiles_and_columns():
+    """The footprint grows with either edge, with D, with the dtype and
+    with segment columns; the backward's streams outweigh the forward's;
+    a footprint past the default raises the kernel's scoped limit to it,
+    one inside it asks for nothing."""
+    from chainermn_tpu.ops.flash_attention import (
+        VMEM_LIMIT_MAX,
+        VMEM_SCOPED_DEFAULT,
+        _compiler_params,
+        flash_vmem_bytes,
+    )
+
+    base = flash_vmem_bytes(512, 512, 128, 2, "fwd")
+    assert flash_vmem_bytes(1024, 512, 128, 2, "fwd") > base
+    assert flash_vmem_bytes(512, 1024, 128, 2, "fwd") > base
+    assert flash_vmem_bytes(512, 512, 256, 2, "fwd") > base
+    assert flash_vmem_bytes(512, 512, 128, 4, "fwd") > base
+    assert flash_vmem_bytes(512, 512, 128, 2, "fwd", True) > base
+    assert flash_vmem_bytes(512, 512, 128, 2, "bwd") > base
+    # D <= 128 pads to the same 128 lanes.
+    assert flash_vmem_bytes(512, 512, 64, 2, "fwd") == base
+    # The (block_q, block_k) fp32 intermediates are in it: the blocks
+    # grow with the edge, the tiles with its square.
+    tile = 512 * 512 * 4
+    for which, segmented, n in [("fwd", False, 2), ("bwd", False, 2),
+                                ("fwd", True, 3), ("bwd", True, 3)]:
+        small = flash_vmem_bytes(512, 512, 128, 2, which, segmented)
+        assert flash_vmem_bytes(1024, 1024, 128, 2, which, segmented) == \
+            2 * small + 2 * n * tile
+    assert _compiler_params(VMEM_SCOPED_DEFAULT) is None
+    big = flash_vmem_bytes(2048, 2048, 128, 2, "fwd")
+    assert _compiler_params(big).vmem_limit_bytes == big + big // 4
+    assert _compiler_params(10 * VMEM_LIMIT_MAX).vmem_limit_bytes == \
+        VMEM_LIMIT_MAX
+
+
+_GEOMETRIES = [
+    # (Sq, Sk, block_q, block_k, causal, window)
+    (256, 256, 32, 32, True, None),
+    (256, 256, 32, 64, True, None),
+    (256, 256, 128, 32, True, None),
+    (256, 256, 64, 64, False, None),
+    (256, 256, 32, 64, True, 1),
+    (256, 256, 64, 32, True, 40),
+    (256, 256, 32, 32, True, 300),
+    (128, 256, 32, 64, True, None),     # more keys than queries
+    (256, 128, 64, 32, True, 17),       # more queries than keys
+    (2048, 2048, 1024, 1024, True, None),
+]
+
+
+@pytest.mark.parametrize("Sq,Sk,bq,bk,causal,window", _GEOMETRIES)
+def test_band_clamp_stays_in_range_and_matches_band_live(
+        Sq, Sk, bq, bk, causal, window):
+    """The clamped index maps never name a block outside [0, n); a tile
+    is live by `_band_live` exactly when the clamp leaves its index
+    alone, in the K/V maps (forward, dq) and the query-side map (dk/dv);
+    a dead tile repeats a live tile's index."""
+    from chainermn_tpu.ops.flash_attention import (
+        _band_live,
+        _banded,
+        _kv_live_range,
+        _q_live_range,
+    )
+
+    n_q, n_k = Sq // bq, Sk // bk
+    kv_j = _banded(
+        lambda i: _kv_live_range(i, bq, bk, n_k, causal, window),
+        causal, window)
+    q_i = _banded(
+        lambda j: _q_live_range(j, bq, bk, n_q, causal, window),
+        causal, window)
+    for i in range(n_q):
+        for j in range(n_k):
+            live = bool(_band_live(causal, window, i * bq, bq, j * bk, bk))
+            jc, ic = int(kv_j(i, j)), int(q_i(j, i))
+            assert 0 <= jc < n_k and 0 <= ic < n_q, (i, j, jc, ic)
+            if live:
+                assert (jc, ic) == (j, i), (i, j, jc, ic)
+            else:
+                assert jc != j or ic != i or (n_q == 1 and n_k == 1)
+            # Whatever the clamp names instead is live itself, unless the
+            # whole row (column) is dead.
+            row_live = [jj for jj in range(n_k) if _band_live(
+                causal, window, i * bq, bq, jj * bk, bk)]
+            if row_live:
+                assert jc in row_live, (i, j, jc, row_live)
+            col_live = [ii for ii in range(n_q) if _band_live(
+                causal, window, ii * bq, bq, j * bk, bk)]
+            if col_live:
+                assert ic in col_live, (i, j, ic, col_live)
+
+
+@pytest.mark.parametrize("Sq,Sk,bq,bk,causal,window", _GEOMETRIES)
+def test_tile_census_counts_the_grid(Sq, Sk, bq, bk, causal, window):
+    """live / visited / copied a head row, against a walk of the grid in
+    the kernels' own step order."""
+    from chainermn_tpu.ops.flash_attention import (
+        _band_live,
+        _banded,
+        _kv_live_range,
+        _q_live_range,
+        tile_census,
+    )
+
+    n_q, n_k = Sq // bq, Sk // bk
+    kv_j = _banded(
+        lambda i: _kv_live_range(i, bq, bk, n_k, causal, window),
+        causal, window)
+    q_i = _banded(
+        lambda j: _q_live_range(j, bq, bk, n_q, causal, window),
+        causal, window)
+    live = sum(
+        bool(_band_live(causal, window, i * bq, bq, j * bk, bk))
+        for i in range(n_q) for j in range(n_k))
+    kv_walk = [int(kv_j(i, j)) for i in range(n_q) for j in range(n_k)]
+    q_walk = [int(q_i(j, i)) for j in range(n_k) for i in range(n_q)]
+
+    def fetches(walk):
+        return 1 + sum(a != b for a, b in zip(walk, walk[1:]))
+
+    got = tile_census(Sq, Sk, bq, bk, causal, window)
+    base = {"block_q": bq, "block_k": bk, "live": live,
+            "visited": n_q * n_k}
+    assert got["fwd"] == got["dq"] == dict(base, copied=fetches(kv_walk))
+    assert got["dkv"] == dict(base, copied=fetches(q_walk))
+
+
+def test_tile_census_at_the_benchmark_shape():
+    """S=2048 a head row: the old 128 x 128 default visited 256 tiles to
+    run 136 (and copied K/V for all 256 before the clamp); the rule's
+    1024 x 1024 visits 4, runs 3, copies 2 (the first K/V block serves
+    the first three steps)."""
+    from chainermn_tpu.ops.flash_attention import tile_census
+
+    old = tile_census(2048, 2048, 128, 128, True, None)["fwd"]
+    assert (old["live"], old["visited"], old["copied"]) == (136, 256, 135)
+    new = tile_census(2048, 2048, 1024, 1024, True, None)
+    for kernel in ("fwd", "dq", "dkv"):
+        t = new[kernel]
+        assert (t["live"], t["visited"], t["copied"]) == (3, 4, 2)
+
+
+def _mode_kwargs(mode, B, S, H):
+    """(Hk, flash kwargs, oracle kwargs) of one masking mode."""
+    if mode == "causal":
+        return H, {}, {}
+    if mode == "window":
+        return H, {"window": 40}, {"window": 40}
+    rng = np.random.RandomState(1)
+    seg = jnp.asarray(
+        np.sort(rng.randint(0, 3, size=(B, S)), axis=1).astype(np.int32))
+    segs = {"q_segment_ids": seg, "kv_segment_ids": seg}
+    if mode == "segments":
+        return H, segs, segs
+    if mode == "gqa":
+        return H // 2, {}, {}
+    assert mode == "gqa+window+segments"
+    return H // 2, dict(segs, window=40), dict(segs, window=40)
+
+
+@pytest.mark.parametrize(
+    "mode", ["causal", "window", "segments", "gqa", "gqa+window+segments"])
+@pytest.mark.parametrize(
+    "blocks", [(32, 32), (32, 64), (64, 32), (16, 128), (128, 16)],
+    ids=lambda b: f"{b[0]}x{b[1]}")
+def test_flash_rectangular_blocks_match_oracle(blocks, mode):
+    """Forward and gradients at pinned rectangular blocks (block_k >
+    block_q and <), small enough that several tiles of every kernel lie
+    outside the band and take a clamped block index."""
+    B, S, H, D = 2, 128, 4, 32
+    bq, bk = blocks
+    Hk, flash_kw, oracle_kw = _mode_kwargs(mode, B, S, H)
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, S, Hk, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, S, Hk, D), jnp.float32)
+
+    def f_flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
+                               **flash_kw)
+
+    def f_ref(q, k, v):
+        return _xla_attention(q, k, v, 1.0 / D**0.5, True, **oracle_kw)
+
+    np.testing.assert_allclose(
+        np.asarray(f_flash(q, k, v)), np.asarray(f_ref(q, k, v)),
+        rtol=2e-5, atol=2e-5)
+    g1 = jax.grad(lambda *a: (f_flash(*a) ** 2).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    g2 = jax.grad(lambda *a: (f_ref(*a) ** 2).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-4)
+
+
+def test_flash_separate_backward_blocks_match_oracle():
+    """Forward at one geometry, backward at another (both rectangular)."""
+    q, k, v = make_qkv(S=128, D=32)
+
+    def f_flash(q, k, v):
+        return (flash_attention(
+            q, k, v, causal=True, block_q=64, block_k=32,
+            block_q_bwd=32, block_k_bwd=64) ** 2).sum()
+
+    def f_ref(q, k, v):
+        return (_xla_attention(q, k, v, 1.0 / 32**0.5, True) ** 2).sum()
+
+    g1 = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-4)
+
+
+def test_flash_geometry_record_reaches_the_sinks(tmp_path):
+    """A call that reaches the kernels publishes its geometry once, at
+    trace time, to the installed sinks — and to none when none is."""
+    import json
+
+    from chainermn_tpu.observability import Reporter, step_log
+    from chainermn_tpu.observability import reporter as reporter_mod
+
+    q, k, v = make_qkv(S=128, D=32)
+    f = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=32, block_k=64))
+    rep = Reporter()
+    path = str(tmp_path / "steps.jsonl")
+    with reporter_mod.scope(rep), step_log.recording(path):
+        f(q, k, v)
+        f(q, k, v)                      # no retrace: no second record
+    summary = rep.summary()
+    assert summary["counters"]["flash/calls"] == 1
+    gauges = {n: g["value"] for n, g in summary["gauges"].items()}
+    assert gauges["flash/flash-fwd/block_q"] == 32
+    assert gauges["flash/flash-fwd/block_k"] == 64
+    assert gauges["flash/flash-bwd-dkv/visited"] == 8
+    assert gauges["flash/flash-fwd/live"] == 6
+    rows = [json.loads(line) for line in open(path)]
+    rows = [r for r in rows if r["event"] == "flash_geometry"]
+    assert len(rows) == 1
+    assert rows[0]["flash-fwd"] == {
+        "block_q": 32, "block_k": 64, "live": 6, "visited": 8, "copied": 4}
+    assert set(rows[0]) >= {"flash-fwd", "flash-bwd-dq", "flash-bwd-dkv"}

@@ -1,0 +1,108 @@
+"""The flash kernels compiled for a described TPU v5e, without the chip.
+
+Interpret mode cannot show what Mosaic refuses: a block that is not
+aligned to the tiling, or more scoped VMEM than a kernel may use.  The
+TPU's compiler is installed here and compiles for a chip that is
+described and not attached (``jax.experimental.topologies``), a few
+seconds a kernel.  The static default geometry has to compile inside the
+default scoped VMEM at every head dim, dtype and mask the rule sizes it
+for, and a pinned geometry past it under the limit the kernels compute.
+
+Only one process at a time may load the TPU's library, so the topology
+is described inside a fixture of this one file (never at import), and
+every compile runs in the test's own process.
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: the next run would warn
+    # on every entry.  Off for this file's tests.
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _compile(one_chip, *, S, D, dtype, bq, bk, which, segmented=False,
+             window=None, BH=128):
+    def arr(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    q, col = arr(BH, S, D), arr(BH, S, 1, dt=jnp.float32)
+    seg = {}
+    operands = [q, q, q] if which == "fwd" else [q, q, q, q, col, q]
+    if segmented:
+        operands += [arr(BH, S, 1, dt=jnp.int32)] * 2
+
+    def fn(*a):
+        if segmented:
+            *a, qs, ks = a
+            seg.update(q_seg=qs, kv_seg=ks)
+        kernel = fa._flash_bh_fwd if which == "fwd" else fa._flash_bh_bwd
+        return kernel(*a, scale=0.1, causal=True, block_q=bq, block_k=bk,
+                      interpret=False, window=window, **seg)
+
+    compiled = jax.jit(fn).lower(*operands).compile()
+    assert compiled.as_text().count("tpu_custom_call") == (
+        1 if which == "fwd" else 2)
+    return compiled
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+@pytest.mark.parametrize("D,dtype,segmented", [
+    (128, jnp.bfloat16, False),      # the benchmark's cells
+    (64, jnp.bfloat16, False),
+    (256, jnp.bfloat16, False),      # 1024 forward, 512 backward
+    (128, jnp.float32, False),
+    (128, jnp.bfloat16, True),       # a segment mask halves the tile
+    (256, jnp.float32, True),
+], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_default_geometry_compiles_inside_the_default_vmem(
+        one_chip, D, dtype, segmented, which):
+    S = 2048
+    b = fa.auto_block_size(S, D, dtype, which, segmented)
+    footprint = fa.flash_vmem_bytes(
+        b, b, D, jnp.dtype(dtype).itemsize, which, segmented)
+    assert fa._compiler_params(footprint) is None
+    _compile(one_chip, S=S, D=D, dtype=dtype, bq=b, bk=b, which=which,
+             segmented=segmented)
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_pinned_geometry_past_the_default_gets_its_limit(one_chip, which):
+    """2048 x 2048 needs more than the default 16 MiB: it compiles
+    because the kernels ask for their own footprint."""
+    footprint = fa.flash_vmem_bytes(2048, 2048, 128, 2, which)
+    assert fa._compiler_params(footprint).vmem_limit_bytes > footprint
+    _compile(one_chip, S=2048, D=128, dtype=jnp.bfloat16, bq=2048, bk=2048,
+             which=which)
+
+
+@pytest.mark.parametrize("bq,bk,window", [
+    (512, 1024, None), (1024, 256, None), (256, 256, 300)])
+def test_banded_index_maps_compile(one_chip, bq, bk, window):
+    """Rectangular blocks and a sliding window: the clamped index maps
+    lower through Mosaic in all three kernels."""
+    for which in ("fwd", "bwd"):
+        _compile(one_chip, S=2048, D=128, dtype=jnp.bfloat16, bq=bq, bk=bk,
+                 which=which, window=window)

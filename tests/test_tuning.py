@@ -197,10 +197,14 @@ def test_flash_search_space_validity(dtype, sub):
     for cfg in space:
         assert Sq % cfg["block_q"] == 0 and Sk % cfg["block_k"] == 0
         assert cfg["block_q"] % sub == 0 and cfg["block_k"] % sub == 0
-    assert flash_default_config(Sq, Sk) in space
+    assert flash_default_config(Sq, Sk, 128, dtype, "fwd") in space
     # The VMEM model prunes: a giant head dim shrinks the space.
     big_d = flash_search_space(Sq, Sk, 2048, dtype, which="fwd")
     assert len(big_d) < len(space)
+    # The default stays a member where the filters exclude it.
+    tight = flash_search_space(Sq, Sk, 128, dtype, which="fwd",
+                               vmem_budget=1)
+    assert tight == [flash_default_config(Sq, Sk, 128, dtype, "fwd")]
 
 
 def test_flash_bwd_space_tighter_than_fwd():
@@ -259,11 +263,24 @@ def test_flash_default_blocks_match_explicit():
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q, k, v = (jax.random.normal(kk, (2, 256, 2, 64), jnp.float32)
                for kk in ks)
-    b = auto_block_size(256)
+    b = auto_block_size(256, 64, jnp.float32, "fwd")
+    assert b == 256  # the rule's largest fitting tile: the whole axis
     out_auto = flash_attention(q, k, v, causal=True)
     out_pinned = flash_attention(q, k, v, causal=True, block_q=b, block_k=b)
     np.testing.assert_array_equal(np.asarray(out_auto),
                                   np.asarray(out_pinned))
+    # ... and so is the backward's default geometry.
+    bb = auto_block_size(256, 64, jnp.float32, "bwd")
+
+    def grads(**blocks):
+        return jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, **blocks).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    for a, p in zip(grads(), grads(block_q=b, block_k=b, block_q_bwd=bb,
+                                   block_k_bwd=bb)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(p))
 
 
 def test_flash_candidate_configs_numerically_match_default():
