@@ -18,8 +18,17 @@ from __future__ import annotations
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chainermn_tpu.models.block_table import (
+    BlockTable,
+    LayerSpec,
+    SSMSpec,
+    gpt2_table,
+)
+from chainermn_tpu.observability.spans import named_scope
 
 
 def _tuned_block_ctx(page_count, page_size, n_kv, d_head, dtype):
@@ -69,6 +78,9 @@ class MultiHeadAttention(nn.Module):
                                     # axis (shard_map); K/V all-gather to
                                     # the full slice before the page write
                                     # (paged="chunk" only)
+    scale: Optional[float] = None   # softmax scale; None = 1/sqrt(d_head).
+                                    # An ``attention_fn`` must have been
+                                    # built with the same one
 
     @nn.compact
     def __call__(self, q_in, kv_in, mask=None, *, block_tables=None,
@@ -79,6 +91,20 @@ class MultiHeadAttention(nn.Module):
             raise ValueError(
                 f"n_kv_heads ({n_kv}) must divide n_heads ({self.n_heads})"
             )
+        if self.scale is not None:
+            if self.paged is not None:
+                raise ValueError(
+                    "a softmax scale other than 1/sqrt(d_head) is not "
+                    "built for the paged KV cache (ops/decode_attention)"
+                )
+            if self.attention_fn is not None and getattr(
+                    self.attention_fn, "scale", None) != self.scale:
+                raise ValueError(
+                    f"this layer's softmax scale is {self.scale}, the "
+                    f"attention_fn was built with "
+                    f"{getattr(self.attention_fn, 'scale', None)}: pass "
+                    f"the same scale to make_flash_attention_fn"
+                )
         dense = lambda name, h: nn.DenseGeneral(  # noqa: E731
             (h, d_head), dtype=self.dtype, name=name, use_bias=False
         )
@@ -332,7 +358,7 @@ class MultiHeadAttention(nn.Module):
                 # back over the group through repeat's transpose).
                 k = jnp.repeat(k, self.n_heads // n_kv, axis=2)
                 v = jnp.repeat(v, self.n_heads // n_kv, axis=2)
-            scale = 1.0 / np.sqrt(d_head)
+            scale = self.scale or 1.0 / np.sqrt(d_head)
             logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
             if mask is not None:
                 logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
@@ -355,15 +381,98 @@ class FeedForward(nn.Module):
         return nn.Dense(self.d_model, dtype=self.dtype, use_bias=False, name="wo")(h)
 
 
-class EncoderLayer(nn.Module):
+class GatedFeedForward(nn.Module):
+    """SwiGLU: ``wo(silu(a) * b)`` with ``[a | b] = wi x``, one input
+    matrix of width ``2 d_ff``."""
+
     d_model: int
-    n_heads: int
     d_ff: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        h = nn.Dense(2 * self.d_ff, dtype=self.dtype, use_bias=False,
+                     name="wi")(x)
+        a, b = jnp.split(h, 2, axis=-1)
+        return nn.Dense(self.d_model, dtype=self.dtype, use_bias=False,
+                        name="wo")(nn.silu(a) * b)
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """Inverse softplus of a step drawn log-uniformly from [1e-3, 1e-1]."""
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, dtype, np.log(1e-3), np.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 mixer (arXiv:2405.21060), as the ``granitemoehybrid``
+    family lays it out: ``[z | xBC | dt] = in_proj(h)``; a causal depthwise
+    convolution and SiLU over ``xBC = [x | B | C]``; ``dt = softplus(dt +
+    dt_bias)``, ``A = -exp(A_log)``; the state-space scan
+    (:func:`chainermn_tpu.ops.ssd.ssd_scan`); the gated norm
+    ``RMSNorm(y * silu(z))`` over all channels; ``out_proj``.  Every
+    sequence starts from a zero state (no document boundaries inside a
+    row, no recurrent cache: training and whole-sequence evaluation)."""
+
+    d_model: int
+    ssm: SSMSpec
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, h):
+        from chainermn_tpu.ops.ssd import causal_conv_silu, ssd_scan
+
+        z = self.ssm
+        f32 = jnp.float32
+        with named_scope("mamba-mixer"):
+            proj = nn.Dense(z.d_inner + z.conv_dim + z.n_heads,
+                            dtype=self.dtype, use_bias=False,
+                            name="in_proj")(h)
+            gate, xbc, dt = jnp.split(
+                proj, [z.d_inner, z.d_inner + z.conv_dim], axis=-1)
+            xbc = causal_conv_silu(
+                xbc,
+                self.param("conv_kernel", nn.initializers.lecun_normal(),
+                           (z.d_conv, z.conv_dim), f32),
+                self.param("conv_bias", nn.initializers.zeros,
+                           (z.conv_dim,), f32))
+            x, B, C = jnp.split(
+                xbc, [z.d_inner, z.d_inner + z.n_groups * z.d_state],
+                axis=-1)
+            heads = (z.n_heads,)
+            dt = jax.nn.softplus(
+                dt.astype(f32) + self.param("dt_bias", _dt_bias_init, heads))
+            lead = x.shape[:2]
+            y = ssd_scan(
+                x.reshape(lead + (z.n_heads, z.d_head)), dt,
+                -jnp.exp(self.param("A_log", _a_log_init, heads)),
+                B.reshape(lead + (z.n_groups, z.d_state)),
+                C.reshape(lead + (z.n_groups, z.d_state)),
+                self.param("D", nn.initializers.ones, heads, f32),
+                chunk=z.chunk)
+            y = y.reshape(lead + (z.d_inner,)).astype(f32)
+            y = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                           name="norm")(y * nn.silu(gate.astype(f32)))
+            return nn.Dense(self.d_model, dtype=self.dtype, use_bias=False,
+                            name="out_proj")(y)
+
+
+class Block(nn.Module):
+    """One layer, built from its row of the block table:
+    ``x + rm * mixer(norm(x))`` then ``x + rm * ffn(norm(x))``."""
+
+    d_model: int
+    row: LayerSpec
     dtype: Any = jnp.bfloat16
     attention_fn: Optional[Callable] = None
     decode: bool = False
     cache_len: int = 0
-    n_kv_heads: Optional[int] = None
     paged: Optional[str] = None
     page_count: int = 0
     page_size: int = 0
@@ -372,16 +481,52 @@ class EncoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, mask=None, *, block_tables=None, seq_lens=None):
-        h = nn.LayerNorm(dtype=self.dtype)(x)
-        x = x + MultiHeadAttention(
-            self.d_model, self.n_heads, self.dtype, self.attention_fn,
-            decode=self.decode, cache_len=self.cache_len,
-            n_kv_heads=self.n_kv_heads, paged=self.paged,
-            page_count=self.page_count, page_size=self.page_size,
-            kv_dtype=self.kv_dtype, sp_axis=self.sp_axis,
-        )(h, h, mask, block_tables=block_tables, seq_lens=seq_lens)
-        h = nn.LayerNorm(dtype=self.dtype)(x)
-        return x + FeedForward(self.d_model, self.d_ff, self.dtype)(h)
+        row = self.row
+
+        def norm():
+            cls = nn.LayerNorm if row.norm == "layernorm" else nn.RMSNorm
+            return cls(epsilon=row.norm_eps, dtype=self.dtype)
+
+        def residual(x, branch):
+            if row.residual_multiplier != 1.0:
+                branch = branch * jnp.asarray(
+                    row.residual_multiplier, branch.dtype)
+            return x + branch
+
+        h = norm()(x)
+        if row.mixer == "attention":
+            mixed = MultiHeadAttention(
+                self.d_model, row.n_heads, self.dtype, self.attention_fn,
+                decode=self.decode, cache_len=self.cache_len,
+                n_kv_heads=row.n_kv_heads, paged=self.paged,
+                page_count=self.page_count, page_size=self.page_size,
+                kv_dtype=self.kv_dtype, sp_axis=self.sp_axis,
+                scale=row.attn_scale,
+            )(h, h, mask, block_tables=block_tables, seq_lens=seq_lens)
+        else:
+            if self.decode or self.paged is not None:
+                raise ValueError(
+                    "a mamba2 layer keeps no recurrent state between "
+                    "calls: incremental decoding and the paged KV cache "
+                    "are built for attention layers only"
+                )
+            mixed = Mamba2Mixer(self.d_model, row.ssm, row.norm_eps,
+                                self.dtype)(h)
+        x = residual(x, mixed)
+        h = norm()(x)
+        ffn = FeedForward if row.ffn == "gelu" else GatedFeedForward
+        return residual(x, ffn(self.d_model, row.d_ff, self.dtype)(h))
+
+
+def EncoderLayer(d_model: int, n_heads: int, d_ff: int,
+                 dtype: Any = jnp.bfloat16,
+                 attention_fn: Optional[Callable] = None,
+                 n_kv_heads: Optional[int] = None, **kwargs) -> Block:
+    """The GPT-2-style row of the table as a layer (pre-LayerNorm
+    attention + two-matrix GELU FFN): what ViT and the encoder-decoder
+    build their stacks from."""
+    return Block(d_model, LayerSpec(n_heads=n_heads, n_kv_heads=n_kv_heads,
+                                    d_ff=d_ff), dtype, attention_fn, **kwargs)
 
 
 class DecoderLayer(nn.Module):
@@ -450,7 +595,14 @@ class Transformer(nn.Module):
 
 class TransformerLM(nn.Module):
     """Decoder-only LM — the long-context workhorse for the
-    sequence-parallel (ring attention / Ulysses) layers."""
+    sequence-parallel (ring attention / Ulysses) layers.
+
+    Its layers come from a per-layer block table
+    (:mod:`chainermn_tpu.models.block_table`).  ``table=None`` is the
+    GPT-2-style row in every layer, written from ``n_layers``,
+    ``n_heads``, ``d_ff`` and ``n_kv_heads``; a given ``table`` replaces
+    those four (``block_table.table_from_config`` writes one from a
+    published config)."""
 
     vocab: int
     d_model: int = 512
@@ -472,6 +624,12 @@ class TransformerLM(nn.Module):
                                     # MultiHeadAttention.kv_dtype
     sp_axis: Optional[str] = None   # sequence-parallel chunk prefill —
                                     # see MultiHeadAttention.sp_axis
+    table: Optional[BlockTable] = None  # the per-layer block table
+
+    @property
+    def block_table(self) -> BlockTable:
+        return self.table or gpt2_table(
+            self.n_layers, self.n_heads, self.d_ff, self.n_kv_heads)
 
     @nn.compact
     def __call__(self, tokens, position_offset=None, return_hidden=False,
@@ -494,7 +652,9 @@ class TransformerLM(nn.Module):
         ``(B, S, d_model)`` instead of logits — the input for
         :func:`chainermn_tpu.ops.fused_cross_entropy`, which never
         materializes the ``(B*S, vocab)`` logits the default
-        ``embed.attend`` path does.
+        ``embed.attend`` path does.  A table's ``logits_scaling`` is
+        divided into the hidden states then, so that ``hidden @ E^T`` are
+        the logits on both paths.
 
         ``inputs_embeds``: optional pre-computed ``(B, S, d_model)`` token
         embeddings replacing the internal table lookup (positions are
@@ -509,16 +669,18 @@ class TransformerLM(nn.Module):
         tensors — the standard long-context memory/FLOP trade."""
         import jax.lax as _lax
 
-        pe = jnp.asarray(sinusoidal_positions(self.max_len, self.d_model))
+        table = self.block_table
         S = tokens.shape[1]
-        if position_offset is None:
-            pos = pe[:S]
-        elif getattr(position_offset, "ndim", 0) == 2:
-            pos = pe[position_offset]      # (B, S) per-sequence positions
-        elif getattr(position_offset, "ndim", 0):
-            pos = pe[position_offset]      # explicit per-token positions
-        else:
-            pos = _lax.dynamic_slice_in_dim(pe, position_offset, S, axis=0)
+        pos = None
+        if table.positions == "sinusoidal":
+            pe = jnp.asarray(sinusoidal_positions(self.max_len, self.d_model))
+            if position_offset is None:
+                pos = pe[:S]
+            elif getattr(position_offset, "ndim", 0):
+                # (S,) explicit per-token or (B, S) per-sequence positions
+                pos = pe[position_offset]
+            else:
+                pos = _lax.dynamic_slice_in_dim(pe, position_offset, S, axis=0)
         if inputs_embeds is None:
             embed = nn.Embed(
                 self.vocab, self.d_model, dtype=self.dtype, name="embed"
@@ -534,32 +696,40 @@ class TransformerLM(nn.Module):
                 )
             embed = None
             x = inputs_embeds.astype(self.dtype)
-        if pos.ndim == 3:                  # (B, S, d): already per-batch
-            x = x + pos.astype(self.dtype)
-        else:
-            x = x + pos[None].astype(self.dtype)
+        if table.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(table.embedding_multiplier, x.dtype)
+        if pos is not None:
+            # (B, S, d) is already per-batch
+            x = x + (pos if pos.ndim == 3 else pos[None]).astype(self.dtype)
         # Pluggable attention (flash/ring/ulysses) imposes its own
         # causality and ignores the mask argument — skip materializing
         # the (S, S) mask, which at long context is the largest host
         # constant in the program (S=16k: 256 MiB as bool).
         mask = None if self.attention_fn is not None else causal_mask(S)
         layer_cls = (
-            nn.remat(EncoderLayer, static_argnums=())
-            if self.remat else EncoderLayer
+            nn.remat(Block, static_argnums=()) if self.remat else Block
         )
-        for i in range(self.n_layers):
+        for i, row in enumerate(table.layers):
             x = layer_cls(
-                self.d_model, self.n_heads, self.d_ff, self.dtype,
-                self.attention_fn, name=f"layer_{i}",
-                decode=self.decode, cache_len=self.max_len if self.decode else 0,
-                n_kv_heads=self.n_kv_heads, paged=self.paged,
-                page_count=self.page_count, page_size=self.page_size,
-                kv_dtype=self.kv_dtype, sp_axis=self.sp_axis,
+                self.d_model, row, self.dtype, self.attention_fn,
+                name=f"layer_{i}", decode=self.decode,
+                cache_len=self.max_len if self.decode else 0,
+                paged=self.paged, page_count=self.page_count,
+                page_size=self.page_size, kv_dtype=self.kv_dtype,
+                sp_axis=self.sp_axis,
             )(x, mask, block_tables=block_tables, seq_lens=seq_lens)
-        x = nn.LayerNorm(dtype=self.dtype, name="final_norm")(x)
+        norm_cls = (nn.LayerNorm if table.final_norm == "layernorm"
+                    else nn.RMSNorm)
+        x = norm_cls(epsilon=table.norm_eps, dtype=self.dtype,
+                     name="final_norm")(x)
         if return_hidden:
+            if table.logits_scaling != 1.0:
+                x = x / jnp.asarray(table.logits_scaling, x.dtype)
             return x
-        return embed.attend(x.astype(jnp.float32))
+        logits = embed.attend(x.astype(jnp.float32))
+        if table.logits_scaling != 1.0:
+            logits = logits / table.logits_scaling
+        return logits
 
 
 def generate(
@@ -594,7 +764,7 @@ def generate(
     dec = TransformerLM(
         vocab=lm.vocab, d_model=lm.d_model, n_heads=lm.n_heads,
         d_ff=lm.d_ff, n_layers=lm.n_layers, max_len=lm.max_len,
-        dtype=lm.dtype, decode=True,
+        dtype=lm.dtype, decode=True, table=lm.table,
     )
     # eval_shape: cache geometry without allocating (and then discarding)
     # a second full parameter set; zeros ARE the empty cache (index 0).

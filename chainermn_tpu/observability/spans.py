@@ -52,10 +52,14 @@ ALLREDUCE_STAGES = ("grad-pack", "grad-unpack")
 _ALLREDUCE_STAGE = re.compile(r"^grad-stage\d+$")
 
 #: The kernels: the three flash ``pallas_call``s (also their ``name=``),
-#: the two fused-CE scans, paged decode attention.
+#: the two fused-CE scans, paged decode attention; a Mamba-2 mixer from
+#: its input to its output projection (``models/transformer.py``) and,
+#: nested in it, the chunked state-space scan, forward and backward, and
+#: the causal convolution with its SiLU (``ops/ssd.py``).  The innermost
+#: name on an op's path is its region.
 KERNEL_REGIONS = (
     "flash-fwd", "flash-bwd-dq", "flash-bwd-dkv", "fused-ce",
-    "paged-decode-attn",
+    "paged-decode-attn", "mamba-mixer", "ssd-scan", "ssm-conv",
 )
 
 #: What the jitted programs compile as (``jit_<name>`` in a capture's

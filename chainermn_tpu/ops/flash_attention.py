@@ -1141,9 +1141,15 @@ def seg_to_bh(ids, H: int):
 def make_flash_attention_fn(causal: bool = True, q_segment_ids=None,
                             kv_segment_ids=None, window=None,
                             block_q=None, block_k=None,
-                            block_q_bwd=None, block_k_bwd=None):
+                            block_q_bwd=None, block_k_bwd=None,
+                            scale: Optional[float] = None):
     """Adapter for the transformer layers' ``attention_fn`` slot (mask
     argument ignored; causality is the kernel's).
+
+    ``scale``: the softmax scale, passed through to
+    :func:`flash_attention` (None = ``1/sqrt(D)``) and kept as the
+    adapter's ``scale`` attribute, by which a layer that states its own
+    scale checks it was given the matching adapter.
 
     ``block_q``/``block_k``/``block_q_bwd``/``block_k_bwd``: optional
     pinned kernel geometry (``bench.py --autotune`` binds the tuned
@@ -1191,7 +1197,8 @@ def make_flash_attention_fn(causal: bool = True, q_segment_ids=None,
         return flash_attention(
             q, k, v, causal=causal, q_segment_ids=qs, kv_segment_ids=ks,
             window=window, block_q=block_q, block_k=block_k,
-            block_q_bwd=block_q_bwd, block_k_bwd=block_k_bwd,
+            block_q_bwd=block_q_bwd, block_k_bwd=block_k_bwd, scale=scale,
         )
 
+    fn.scale = scale
     return fn

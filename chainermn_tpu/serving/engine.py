@@ -256,6 +256,15 @@ class InferenceEngine:
     def __init__(self, lm: TransformerLM, params,
                  config: Optional[EngineConfig] = None, *,
                  plan=None, mesh=None):
+        if lm.table is not None:
+            kinds = sorted({row.mixer for row in lm.table.layers})
+            raise ValueError(
+                f"InferenceEngine does not serve a model built from a "
+                f"block table (mixers: {kinds}): the paged cache and the "
+                f"scheduler keep keys and values only, no recurrent "
+                f"state, and the page geometry is read from n_heads / "
+                f"n_layers (ROADMAP.md R3)"
+            )
         cfg = (config or EngineConfig(max_len=lm.max_len)).resolved()
         if cfg.max_len > lm.max_len:
             raise ValueError(

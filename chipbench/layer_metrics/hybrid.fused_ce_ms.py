@@ -1,0 +1,7 @@
+"""Device ms a step of the fused cross-entropy scans in the hybrid cell."""
+
+from chipbench import scope_reduce
+
+
+def read(ctx):
+    return scope_reduce.region_ms(ctx, "fused-ce")

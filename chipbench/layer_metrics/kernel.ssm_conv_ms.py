@@ -1,0 +1,7 @@
+"""Device ms a step of the causal depthwise convolution and its SiLU."""
+
+from chipbench import scope_reduce
+
+
+def read(ctx):
+    return scope_reduce.region_ms(ctx, "ssm-conv")

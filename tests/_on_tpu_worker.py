@@ -188,6 +188,41 @@ def flash():
         _assert_grads_close(g3, gr3, 0.2, ("gqa", Hk))
         print(f"flash-on-tpu ok: GQA Hk={Hk}")
 
+    # The hybrid cell's attention layer, COMPILED: GQA 32 / 8 at D=64,
+    # S=8192 with the softmax scale the model states (1/64, not
+    # 1/sqrt(D)), through the adapter the layers call.  The oracle walks
+    # the kv heads one group at a time (a group's scores are 1 GB).
+    from chainermn_tpu.ops.flash_attention import make_flash_attention_fn
+
+    B4, S4, H4, Hk4, D4, scale4 = 1, 8192, 32, 8, 64, 1.0 / 64
+    G4 = H4 // Hk4
+    q4 = jnp.asarray(rng.randn(B4, S4, H4, D4), jnp.bfloat16)
+    k4 = jnp.asarray(rng.randn(B4, S4, Hk4, D4), jnp.bfloat16)
+    v4 = jnp.asarray(rng.randn(B4, S4, Hk4, D4), jnp.bfloat16)
+    adapter = make_flash_attention_fn(causal=True, scale=scale4)
+    assert adapter.scale == scale4
+
+    def sq(fn):
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32) ** 2).sum()
+
+    o4 = jax.jit(adapter)(q4, k4, v4)
+    g4 = jax.jit(jax.grad(sq(adapter), argnums=(0, 1, 2)))(q4, k4, v4)
+    group_ref = jax.jit(lambda q, k, v: _xla_attention(
+        q, jnp.repeat(k, G4, axis=2), jnp.repeat(v, G4, axis=2), scale4,
+        True))
+    group_grad = jax.jit(jax.grad(sq(group_ref), argnums=(0, 1, 2)))
+    for j in range(Hk4):
+        hq, hk = slice(j * G4, (j + 1) * G4), slice(j, j + 1)
+        np.testing.assert_allclose(
+            np.asarray(o4[:, :, hq], np.float32),
+            np.asarray(group_ref(q4[:, :, hq], k4[:, :, hk], v4[:, :, hk]),
+                       np.float32), rtol=2e-2, atol=2e-2)
+        _assert_grads_close(
+            (g4[0][:, :, hq], g4[1][:, :, hk], g4[2][:, :, hk]),
+            group_grad(q4[:, :, hq], k4[:, :, hk], v4[:, :, hk]), 0.2,
+            ("gqa-32/8-s8192", j))
+    print("flash-on-tpu ok: GQA 32/8 D=64 S=8192 scale=1/64")
+
     # Sliding-window band, COMPILED: the band mask and the two-sided
     # block skips have their own Mosaic lowering; fwd + grads vs the
     # dense banded oracle at a window spanning ~1.5 blocks.

@@ -44,13 +44,14 @@ def one_chip():
 
 
 def _compile(one_chip, *, S, D, dtype, bq, bk, which, segmented=False,
-             window=None, BH=128):
+             window=None, BH=128, BHk=None):
     def arr(*shape, dt=dtype):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     q, col = arr(BH, S, D), arr(BH, S, 1, dt=jnp.float32)
+    kv = arr(BHk or BH, S, D)       # fewer kv head rows: GQA
     seg = {}
-    operands = [q, q, q] if which == "fwd" else [q, q, q, q, col, q]
+    operands = [q, kv, kv] if which == "fwd" else [q, kv, kv, q, col, q]
     if segmented:
         operands += [arr(BH, S, 1, dt=jnp.int32)] * 2
 
@@ -86,6 +87,18 @@ def test_default_geometry_compiles_inside_the_default_vmem(
     assert fa._compiler_params(footprint) is None
     _compile(one_chip, S=S, D=D, dtype=dtype, bq=b, bk=b, which=which,
              segmented=segmented)
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_hybrid_cell_attention_compiles_at_its_default(one_chip, which):
+    """GQA 32 / 8 at D=64, S=8192, two rows: the attention layer of the
+    ``granite4hm-train-1chip`` cell at the geometry the rule gives it."""
+    S, D = 8192, 64
+    b = fa.auto_block_size(S, D, jnp.bfloat16, which)
+    assert fa._compiler_params(
+        fa.flash_vmem_bytes(b, b, D, 2, which)) is None
+    _compile(one_chip, S=S, D=D, dtype=jnp.bfloat16, bq=b, bk=b,
+             which=which, BH=2 * 32, BHk=2 * 8)
 
 
 @pytest.mark.parametrize("which", ["fwd", "bwd"])
