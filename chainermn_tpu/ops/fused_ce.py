@@ -12,17 +12,35 @@ the autodiff residual doubles it.
 TPU-native design: never materialize the full logit matrix.  Tokens are
 processed in row chunks; each chunk's logits live only inside the chunk
 computation (bf16 MXU matmul, fp32 accumulation), reduced immediately to
-the scalar loss contribution plus a per-token log-sum-exp.  The backward
-pass recomputes each chunk's logits from the saved ``lse`` (one fp32
-scalar per token — the flash-attention residual trick applied to the
-vocabulary axis) and accumulates the embedding gradient chunk by chunk
-in a ``lax.scan`` carry.  Peak extra memory is ``chunk x V`` fp32
-(default 64 MiB at V=32k) instead of ``N x V``.
+the scalar loss contribution plus a per-token log-sum-exp.  Peak extra
+memory is ``chunk x V`` fp32 (default 64 MiB at V=32k) instead of
+``N x V``.
+
+Two gradient rules share the chunk helpers below, chosen by what the
+caller's function returns:
+
+* :func:`fused_cross_entropy` returns the loss alone, so the cotangent
+  that reaches it is one scalar and ``dlogits = g * (p - onehot)`` is
+  known up to that scalar while the chunk's logits exist.  Its forward
+  rule makes ``d hidden`` and ``d embedding`` in the SAME scan (the
+  embedding gradient in the fp32 carry) and its backward rule scales
+  them by ``g``: three vocabulary matmuls a chunk, **no logits
+  recomputed**, and neither ``hidden`` nor the per-token log-sum-exp
+  kept for backward.  Called without differentiation it runs the
+  forward scan alone (one matmul a chunk).
+* :func:`fused_cross_entropy_with_lse` also hands out the per-token
+  log-sum-exp as a differentiable output (z-loss); its ``dlogits`` has a
+  term ``g_lse_i * p`` whose per-token weights exist only in backward, so
+  its backward scan **recomputes each chunk's logits** from the saved
+  ``lse`` (one fp32 scalar per token — the flash-attention residual
+  trick applied to the vocabulary axis): four matmuls a chunk.
 
 The same per-chunk (max, sum-exp) reduction is the building block of the
 vocab-parallel (tensor-parallel) cross-entropy in
-``chainermn_tpu.parallel.sharding``: there the V axis is sharded and the
-two reductions become ``psum``/``pmax`` over the model axis.
+``chainermn_tpu.parallel.sharding``: there the V axis is sharded, the
+two reductions become ``psum``/``pmax`` over the model axis, and the
+gradient rule is the recomputing one (:func:`ce_scan_fwd` /
+:func:`ce_scan_bwd` with its own strategy).
 """
 
 from __future__ import annotations
@@ -32,7 +50,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from chainermn_tpu.observability.spans import named_scope
+from chainermn_tpu.observability import reporter as _reporter
+from chainermn_tpu.observability import step_log as _step_log
+from chainermn_tpu.observability.spans import named_scope, telemetry_active
 
 #: the static default row chunk — the cache-miss / off-TPU fallback, and
 #: a mandatory member of the autotuner's search space (a tuned chunk can
@@ -104,80 +124,103 @@ class LocalVocabStrategy:
         return jnp.maximum(labels, 0), labels >= 0
 
 
+def _chunk_lse(logits, strat):
+    """Per-token log-sum-exp of one ``(C, V_local)`` fp32 logit tile."""
+    m = strat.merge_max(jnp.max(logits, axis=-1))
+    se = strat.merge_sum(jnp.sum(jnp.exp(logits - m[:, None]), axis=-1))
+    return m + jnp.log(se)
+
+
+def _chunk_loss(carry, logits, lse_c, l_c, strat):
+    """``(loss_sum, n_valid)`` carry plus this chunk's valid tokens'
+    ``lse - picked``."""
+    loss_sum, n_valid = carry
+    valid = l_c >= 0
+    idx, owner = strat.label_local(l_c)
+    picked_s = jnp.take_along_axis(logits, idx[:, None], axis=-1)[:, 0]
+    picked = strat.merge_pick(jnp.where(owner, picked_s, 0.0))
+    tok_loss = jnp.where(valid, lse_c - picked, 0.0)
+    return (loss_sum + tok_loss.sum(),
+            n_valid + valid.sum().astype(jnp.float32))
+
+
+def _chunk_softmax_onehot(logits, lse_c, l_c, strat):
+    """``(p, onehot, valid[:, None])`` of one logit tile: the softmax
+    (local shard), the owned labels' one-hot rows and the valid mask."""
+    p = jnp.exp(logits - lse_c[:, None])
+    idx, owner = strat.label_local(l_c)
+    onehot = jax.nn.one_hot(
+        idx, logits.shape[1], dtype=p.dtype
+    ) * owner[:, None]
+    return p, onehot, (l_c >= 0)[:, None]
+
+
+def _chunk_grads(dlogits, h_c, embedding, strat):
+    """``(dh_c, d_emb contribution)`` of one chunk, both fp32: bf16
+    operands on the MXU, fp32 accumulation."""
+    dlogits = dlogits.astype(jnp.bfloat16)
+    dh_c = strat.reduce_dh(jnp.dot(
+        dlogits, embedding.astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    ))
+    d_emb_c = jax.lax.dot_general(
+        dlogits, h_c.astype(jnp.bfloat16),
+        (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return dh_c, d_emb_c
+
+
+def _chunked(hidden, labels, chunk):
+    """``(h_chunks (n, C, D), l_chunks (n, C))`` for the row scan."""
+    N = hidden.shape[0]
+    C = _pick_chunk(N, chunk)
+    return (hidden.reshape(N // C, C, hidden.shape[1]),
+            labels.reshape(N // C, C))
+
+
 def ce_scan_fwd(hidden, embedding, labels, chunk, strat):
     """Chunked CE forward: sum over valid tokens of ``lse - picked`` plus
     the valid count and per-token lse, never holding more than one
     ``(chunk, V_local)`` logit tile.  ``strat`` supplies the cross-shard
     merges (identity for the local case)."""
-    N = hidden.shape[0]
-    C = _pick_chunk(N, chunk)
-    h_chunks = hidden.reshape(N // C, C, hidden.shape[1])
-    l_chunks = labels.reshape(N // C, C)
+    h_chunks, l_chunks = _chunked(hidden, labels, chunk)
 
     def body(carry, hc_lc):
-        loss_sum, n_valid = carry
         h_c, l_c = hc_lc
         logits = _chunk_logits(h_c, embedding)  # (C, V_local) fp32
-        m = strat.merge_max(jnp.max(logits, axis=-1))
-        se = strat.merge_sum(
-            jnp.sum(jnp.exp(logits - m[:, None]), axis=-1)
-        )
-        lse_c = m + jnp.log(se)
-        valid = l_c >= 0
-        idx, owner = strat.label_local(l_c)
-        picked_s = jnp.take_along_axis(logits, idx[:, None], axis=-1)[:, 0]
-        picked = strat.merge_pick(jnp.where(owner, picked_s, 0.0))
-        tok_loss = jnp.where(valid, lse_c - picked, 0.0)
-        return (
-            (loss_sum + tok_loss.sum(),
-             n_valid + valid.sum().astype(jnp.float32)),
-            lse_c,
-        )
+        lse_c = _chunk_lse(logits, strat)
+        return _chunk_loss(carry, logits, lse_c, l_c, strat), lse_c
 
     with named_scope("fused-ce"):
         (loss_sum, n_valid), lse = jax.lax.scan(
             body, (jnp.float32(0.0), jnp.float32(0.0)), (h_chunks, l_chunks)
         )
-    return loss_sum, n_valid, lse.reshape(N)
+    return loss_sum, n_valid, lse.reshape(hidden.shape[0])
 
 
 def ce_scan_bwd(hidden, embedding, labels, lse, g_loss, g_lse, chunk,
                 strat):
-    """Chunked CE backward: recompute each chunk's logits from the saved
-    lse (remat), assemble ``dlogits = g*(p - onehot) + g_lse*p``, and
-    accumulate ``d embedding`` in the scan carry.  Returns (dh, d_emb) in
-    the input dtypes."""
+    """Chunked CE backward, the recomputing rule: remake each chunk's
+    logits from the saved lse (remat), assemble ``dlogits = g*(p -
+    onehot) + g_lse*p``, and accumulate ``d embedding`` in the scan
+    carry.  Returns (dh, d_emb) in the input dtypes."""
     N, D = hidden.shape
-    C = _pick_chunk(N, chunk)
-    h_chunks = hidden.reshape(N // C, C, D)
-    l_chunks = labels.reshape(N // C, C)
-    lse_chunks = lse.reshape(N // C, C)
-    g_lse_chunks = g_lse.reshape(N // C, C)
+    h_chunks, l_chunks = _chunked(hidden, labels, chunk)
+    lse_chunks = lse.reshape(l_chunks.shape)
+    g_lse_chunks = g_lse.reshape(l_chunks.shape)
 
     def body(d_emb, args):
         h_c, l_c, lse_c, g_lse_c = args
         logits = _chunk_logits(h_c, embedding)  # recompute (remat)
-        p = jnp.exp(logits - lse_c[:, None])    # softmax (local shard)
-        valid = (l_c >= 0)[:, None]
-        idx, owner = strat.label_local(l_c)
-        onehot = jax.nn.one_hot(
-            idx, logits.shape[1], dtype=p.dtype
-        ) * owner[:, None]
+        p, onehot, valid = _chunk_softmax_onehot(logits, lse_c, l_c, strat)
         # d loss_sum / d logits = (p - onehot) per valid token;
         # d lse / d logits = p (lse is an output in its own right).
         dlogits = jnp.where(
             valid, g_loss * (p - onehot), 0.0
         ) + g_lse_c[:, None] * p
-        dh_c = strat.reduce_dh(jnp.dot(
-            dlogits.astype(jnp.bfloat16), embedding.astype(jnp.bfloat16),
-            preferred_element_type=jnp.float32,
-        ))
-        d_emb = d_emb + jax.lax.dot_general(
-            dlogits.astype(jnp.bfloat16), h_c.astype(jnp.bfloat16),
-            (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return d_emb, dh_c
+        dh_c, d_emb_c = _chunk_grads(dlogits, h_c, embedding, strat)
+        return d_emb + d_emb_c, dh_c
 
     with named_scope("fused-ce"):
         d_emb, dh = jax.lax.scan(
@@ -186,6 +229,47 @@ def ce_scan_bwd(hidden, embedding, labels, lse, g_loss, g_lse, chunk,
             (h_chunks, l_chunks, lse_chunks, g_lse_chunks),
         )
     return (
+        dh.reshape(N, D).astype(hidden.dtype),
+        d_emb.astype(embedding.dtype),
+    )
+
+
+def ce_scan_fwd_grads(hidden, embedding, labels, chunk):
+    """Chunked CE forward that also makes the gradients of ``loss_sum``
+    (a cotangent of 1) while each chunk's logits exist: one scan, three
+    matmuls a chunk, ``d embedding`` in the fp32 carry.  Full vocabulary
+    on one device only.  Returns (loss_sum, n_valid, dh, d_emb), the
+    gradients in the input dtypes."""
+    N, D = hidden.shape
+    strat = LocalVocabStrategy()
+    h_chunks, l_chunks = _chunked(hidden, labels, chunk)
+
+    def body(carry, hc_lc):
+        loss_carry, d_emb = carry
+        h_c, l_c = hc_lc
+        logits = _chunk_logits(h_c, embedding)  # (C, V) fp32, made once
+        lse_c = _chunk_lse(logits, strat)
+        loss_carry = _chunk_loss(loss_carry, logits, lse_c, l_c, strat)
+        p, onehot, valid = _chunk_softmax_onehot(logits, lse_c, l_c, strat)
+        # Made once, in bf16, for both gradient matmuls (as the
+        # recomputing rule's backward has it): left free, the compiler
+        # redoes the softmax inside each matmul's operand and reads the
+        # fp32 tile twice — +2.1 ms a call at V=50257 on a v5e, -0.2 at
+        # V=25088 where the tile stays on-chip (PERF.md §6, PR 27).
+        dlogits = jax.lax.optimization_barrier(
+            jnp.where(valid, p - onehot, 0.0).astype(jnp.bfloat16))
+        dh_c, d_emb_c = _chunk_grads(dlogits, h_c, embedding, strat)
+        return (loss_carry, d_emb + d_emb_c), dh_c
+
+    with named_scope("fused-ce"):
+        ((loss_sum, n_valid), d_emb), dh = jax.lax.scan(
+            body,
+            ((jnp.float32(0.0), jnp.float32(0.0)),
+             jnp.zeros(embedding.shape, jnp.float32)),
+            (h_chunks, l_chunks),
+        )
+    return (
+        loss_sum, n_valid,
         dh.reshape(N, D).astype(hidden.dtype),
         d_emb.astype(embedding.dtype),
     )
@@ -222,6 +306,57 @@ def _fused_ce_vjp_bwd(chunk, res, cots):
 _fused_ce_sum.defvjp(_fused_ce_vjp_fwd, _fused_ce_vjp_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _fused_ce_loss(hidden, embedding, labels, chunk):
+    """:func:`_fused_ce_sum` without the lse output: (loss_sum, n_valid).
+    With no per-token output the cotangent is one scalar, so the rule
+    below makes the gradients in the forward scan and recomputes
+    nothing."""
+    loss_sum, n_valid, _lse = ce_scan_fwd(
+        hidden, embedding, labels, chunk, LocalVocabStrategy())
+    return loss_sum, n_valid
+
+
+def _fused_ce_loss_vjp_fwd(hidden, embedding, labels, chunk):
+    loss_sum, n_valid, dh, d_emb = ce_scan_fwd_grads(
+        hidden, embedding, labels, chunk)
+    return (loss_sum, n_valid), (dh, d_emb)
+
+
+def _fused_ce_loss_vjp_bwd(chunk, res, cots):
+    g_loss, _g_nvalid = cots
+    with named_scope("fused-ce"):
+        dh, d_emb = (
+            (g_loss * g.astype(jnp.float32)).astype(g.dtype) for g in res
+        )
+    return dh, d_emb, None
+
+
+_fused_ce_loss.defvjp(_fused_ce_loss_vjp_fwd, _fused_ce_loss_vjp_bwd)
+
+
+def _publish_geometry(h2, embedding, chunk, form: str) -> None:
+    """One ``ce_geometry`` record a traced loss head (at TRACE time,
+    beside ``flash_geometry`` and ``ssd_geometry``): a row of the
+    StepRecorder, ``fused_ce/<field>`` gauges and a ``fused_ce/calls``
+    counter of the Reporter.  ``form`` names the gradient rule the call
+    carries: ``grad_in_forward`` or ``recompute`` (the gauge
+    ``fused_ce/grad_in_forward`` reads 1 or 0)."""
+    rows = h2.shape[0]
+    tile = _pick_chunk(rows, chunk)
+    record = {"rows": rows, "vocab": embedding.shape[0], "d": h2.shape[1],
+              "chunk": tile, "chunks": rows // tile}
+    rec = _step_log.current_recorder()
+    if rec is not None:
+        rec.record("ce_geometry", form=form, **record)
+    rep = _reporter.get_reporter()
+    if rep is not None:
+        rep.count("fused_ce/calls")
+        rep.gauge("fused_ce/grad_in_forward", form == "grad_in_forward")
+        for field, value in record.items():
+            rep.gauge(f"fused_ce/{field}", value)
+
+
 def fused_cross_entropy(hidden, embedding, labels, *, chunk=None):
     """Mean softmax cross-entropy of ``hidden @ embedding.T`` against
     ``labels``, computed without materializing the ``(N, V)`` logit
@@ -236,9 +371,13 @@ def fused_cross_entropy(hidden, embedding, labels, *, chunk=None):
       with the flash kernels' segment masks.
 
     Returns the scalar mean over valid tokens (0.0 when none are valid).
-    Differentiable in ``hidden`` and ``embedding``; the backward pass
-    recomputes each chunk's logits from a saved per-token log-sum-exp
-    (4 bytes/token) instead of storing them.
+    Differentiable in ``hidden`` and ``embedding``.  Under
+    differentiation the forward pass makes both gradients chunk by chunk
+    in its one scan (the output is a scalar, so they are known up to the
+    scalar cotangent) and keeps them, in the inputs' dtypes, as the only
+    residuals; the backward pass scales them.  No logits are recomputed
+    (:func:`fused_cross_entropy_with_lse` is the path that recomputes).
+    Without differentiation only the loss scan runs.
 
     ``chunk`` — rows per scan tile.  The default (None) resolves to the
     autotuned chunk for this (device kind, dtype, N, V, D) when the
@@ -246,24 +385,34 @@ def fused_cross_entropy(hidden, embedding, labels, *, chunk=None):
     :data:`DEFAULT_CHUNK` — always the static default off-TPU and under
     pytest.  Passing an int pins it.
     """
-    h2, l2 = _validate_and_flatten(hidden, embedding, labels, chunk)
-    chunk = _resolve_chunk(
-        chunk, h2.shape[0], embedding.shape[0], h2.shape[1], hidden.dtype
-    )
-    loss_sum, n_valid, _lse = _fused_ce_sum(h2, embedding, l2, chunk)
+    h2, l2, chunk = _prepare(
+        hidden, embedding, labels, chunk, "grad_in_forward")
+    loss_sum, n_valid = _fused_ce_loss(h2, embedding, l2, chunk)
     return loss_sum / jnp.maximum(n_valid, 1.0)
 
 
 def fused_cross_entropy_with_lse(hidden, embedding, labels, *, chunk=None):
     """:func:`fused_cross_entropy` variant also returning the per-token
     log-sum-exp ``(N,)`` — the z-loss / logit-scale diagnostic, and the
-    merge quantity for vocab-sharded composition."""
+    merge quantity for vocab-sharded composition.  The lse's cotangent
+    is a per-token vector known only in backward, so this path keeps
+    ``hidden`` and the lse (4 bytes/token) and its backward scan
+    recomputes each chunk's logits."""
+    h2, l2, chunk = _prepare(hidden, embedding, labels, chunk, "recompute")
+    loss_sum, n_valid, lse = _fused_ce_sum(h2, embedding, l2, chunk)
+    return loss_sum / jnp.maximum(n_valid, 1.0), lse
+
+
+def _prepare(hidden, embedding, labels, chunk, form):
+    """``(hidden (N, D), labels (N,), resolved chunk)`` of a public call,
+    its geometry published when telemetry is on."""
     h2, l2 = _validate_and_flatten(hidden, embedding, labels, chunk)
     chunk = _resolve_chunk(
         chunk, h2.shape[0], embedding.shape[0], h2.shape[1], hidden.dtype
     )
-    loss_sum, n_valid, lse = _fused_ce_sum(h2, embedding, l2, chunk)
-    return loss_sum / jnp.maximum(n_valid, 1.0), lse
+    if telemetry_active():
+        _publish_geometry(h2, embedding, chunk, form)
+    return h2, l2, chunk
 
 
 def _validate_and_flatten(hidden, embedding, labels, chunk):

@@ -208,6 +208,45 @@ def test_scan_geometry_reaches_the_sinks(tmp_path):
     assert len(rows) == 1 and {k: rows[0][k] for k in want} == want
 
 
+@pytest.mark.parametrize("with_lse,form", [
+    (False, "grad_in_forward"), (True, "recompute")])
+def test_loss_head_geometry_reaches_the_sinks(tmp_path, with_lse, form):
+    """A traced loss head publishes its geometry once, with the gradient
+    rule it carries: the loss-only path makes its gradients in the
+    forward scan, the with-lse path recomputes its logits."""
+    import json
+
+    from chainermn_tpu.observability import Reporter, step_log
+    from chainermn_tpu.observability import reporter as reporter_mod
+    from chainermn_tpu.ops import fused_ce
+
+    def loss(h, e, lab):
+        if with_lse:
+            return fused_ce.fused_cross_entropy_with_lse(
+                h, e, lab, chunk=20)[0]
+        return fused_ce.fused_cross_entropy(h, e, lab, chunk=20)
+
+    f = jax.jit(jax.grad(loss, argnums=(0, 1)))
+    args = (jnp.ones((2, 24, 8), jnp.bfloat16), jnp.ones((11, 8)),
+            jnp.zeros((2, 24), jnp.int32))
+    rep = Reporter()
+    path = str(tmp_path / "steps.jsonl")
+    with reporter_mod.scope(rep), step_log.recording(path):
+        f(*args)
+        f(*args)                        # no retrace: no second record
+    summary = rep.summary()
+    assert summary["counters"]["fused_ce/calls"] == 1
+    # 48 rows in tiles of 16, the largest divisor under the chunk asked for
+    want = {"rows": 48, "vocab": 11, "d": 8, "chunk": 16, "chunks": 3}
+    gauges = {f"fused_ce/{k}": v for k, v in want.items()}
+    gauges["fused_ce/grad_in_forward"] = float(form == "grad_in_forward")
+    assert {n: g["value"] for n, g in summary["gauges"].items()} == gauges
+    rows = [json.loads(line) for line in open(path)]
+    rows = [r for r in rows if r["event"] == "ce_geometry"]
+    assert len(rows) == 1 and rows[0]["form"] == form
+    assert {k: rows[0][k] for k in want} == want
+
+
 # ------------------------------------------- the GPT-2 row is today's block
 
 CGPT_TINY = {"vocab_size": 211, "n_embd": 64, "n_head": 2, "n_inner": 128,

@@ -1,4 +1,5 @@
-"""The flash kernels compiled for a described TPU v5e, without the chip.
+"""The flash kernels, and the loss head's gradient, compiled for a
+described TPU v5e, without the chip.
 
 Interpret mode cannot show what Mosaic refuses: a block that is not
 aligned to the tiling, or more scoped VMEM than a kernel may use.  The
@@ -119,3 +120,40 @@ def test_banded_index_maps_compile(one_chip, bq, bk, window):
     for which in ("fwd", "bwd"):
         _compile(one_chip, S=2048, D=128, dtype=jnp.bfloat16, bq=bq, bk=bk,
                  which=which, window=window)
+
+
+@pytest.mark.parametrize("vocab", [50257, 25088])
+def test_loss_head_gradient_one_scan_three_matmuls(one_chip, vocab):
+    """The gradient of ``fused_cross_entropy`` at the cells' sizes
+    (16,384 rows of 2,048, chunk 1024; the cgpt cells' vocabulary and the
+    hybrid cell's): ONE chunk loop of three matmuls, where the
+    recomputing rule (``fused_cross_entropy_with_lse``) compiles to two
+    loops and four.  Temporaries: the recomputing rule's and at most one
+    fp32 logit tile more — the forward's tile now coexists with the
+    embedding-gradient carry and the bf16 ``dlogits`` (the recomputing
+    backward fuses its remade tile away).  The hybrid cell's whole step,
+    1.3 GB under the chip's limit, is unmoved by it (PERF.md §6, PR 27)."""
+    from chainermn_tpu.ops import fused_ce
+
+    rows, d, chunk = 16384, 2048, 1024
+    operands = (
+        jax.ShapeDtypeStruct((rows, d), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((vocab, d), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip))
+
+    def compiled(loss):
+        c = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            *operands).compile()
+        text = c.as_text()
+        return (text.count(" while("), text.count(" convolution("),
+                c.memory_analysis().temp_size_in_bytes)
+
+    loops, matmuls, temp = compiled(
+        lambda h, e, lab: fused_ce.fused_cross_entropy(
+            h, e, lab, chunk=chunk))
+    assert (loops, matmuls) == (1, 3)
+    loops_r, matmuls_r, temp_r = compiled(
+        lambda h, e, lab: fused_ce.fused_cross_entropy_with_lse(
+            h, e, lab, chunk=chunk)[0])
+    assert (loops_r, matmuls_r) == (2, 4)
+    assert temp <= temp_r + chunk * vocab * 4
