@@ -364,40 +364,10 @@ def bench_lm(comm, args):
     )
     use_remat = args.lm_remat
 
-    # --autotune: search the Pallas block spaces for THIS step's shapes
-    # (persisting winners in the tune cache), then pin the chosen configs
-    # explicitly so the measured run uses exactly what the tuner picked.
-    fa_kwargs = {}
-    ce_chunk = args.lm_ce_chunk
-    autotune_rec = None
-    if args.autotune:
-        from chainermn_tpu.tuning import cache_path, tune_lm_shapes
-
-        tuned = tune_lm_shapes(
-            batch=B, seq=S, n_heads=cfg["n_heads"],
-            d_model=cfg["d_model"], vocab=cfg["vocab"],
-            window=args.lm_window,
-        )
-        fwd = tuned["flash"].get("fwd", {}).get("chosen")
-        bwd = tuned["flash"].get("bwd", {}).get("chosen")
-        if fwd:
-            fa_kwargs.update(block_q=fwd["block_q"],
-                             block_k=fwd["block_k"])
-        if bwd:
-            fa_kwargs.update(block_q_bwd=bwd["block_q"],
-                             block_k_bwd=bwd["block_k"])
-        ce = tuned["fused_ce"].get("chosen")
-        if ce:
-            ce_chunk = ce["chunk"]
-        autotune_rec = {
-            "flash_fwd": fwd, "flash_bwd": bwd, "fused_ce": ce,
-            "cache_path": cache_path(),
-        }
-
     model = TransformerLM(
         **cfg, remat=use_remat,
         attention_fn=make_flash_attention_fn(
-            causal=True, window=args.lm_window, **fa_kwargs
+            causal=True, window=args.lm_window
         ),
     )
     rng = np.random.RandomState(0)
@@ -420,7 +390,7 @@ def bench_lm(comm, args):
         toks, labs = batch
         h = model.apply({"params": p}, toks, return_hidden=True)
         return fused_cross_entropy(
-            h, p["embed"]["embedding"], labs, chunk=ce_chunk
+            h, p["embed"]["embedding"], labs, chunk=args.lm_ce_chunk
         )
 
     step = opt.make_train_step(loss_fn, donate=True)
@@ -542,8 +512,6 @@ def bench_lm(comm, args):
             "speedup": round(base_time / step_time, 3),
             "quant_abs_err": quant_err,
         }
-    if autotune_rec is not None:
-        result["autotune"] = autotune_rec
     if args.plan:
         result["plan"] = args.plan
         result["plan_layout"] = _plan_layout_report(args.plan, params)
@@ -825,8 +793,7 @@ def _serve_draft_ab(args, model, params, prompts, best):
     batch size, identical traffic.  Exact-match acceptance pins the
     streams identical across the pair; what differs is the accept
     length (tokens banked per verify row) and the wall clock — the
-    draft choice is a pure throughput decision, and this A/B is the
-    measurement behind the tuned ``draft`` cache entry."""
+    draft choice is a pure throughput decision."""
     spec = max(1, args.serve_spec_tokens)
     bs = best["batch_size"]
     rows = []
@@ -1700,12 +1667,6 @@ def main(argv=None):
     ap.add_argument("--lm-remat", action="store_true",
                     help="enable per-layer remat (less activation memory, "
                          "~1/3 extra forward FLOPs; lets --lm-batch grow)")
-    ap.add_argument("--autotune", action="store_true",
-                    help="search the Pallas block configs for the LM "
-                         "step's shapes first (persisting winners in the "
-                         "tune cache), then bench with the chosen configs "
-                         "pinned; the chosen (block_q, block_k, chunk) "
-                         "land under the LM result's \"autotune\" key")
     ap.add_argument("--plan", default=None, metavar="NAME",
                     help="record a registry sharding plan (dp, tp, fsdp, "
                          "zero, dp_tp) against the benched model: the "

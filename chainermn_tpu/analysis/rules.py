@@ -204,7 +204,7 @@ def check_narrow_dtype_reduction(ctx: LintContext) -> List[Finding]:
     if ctx.comm is not None and \
             getattr(ctx.comm, "allreduce_grad_dtype", None) is not None:
         return []
-    # Likewise a resolved comm_dtype (ctor / env / tuned) declares the
+    # Likewise a resolved comm_dtype (ctor / env) declares the
     # quantized wire intentionally: the communicator itself emits the
     # blessed scale→cast→reduce→cast→unscale sequence.
     comm_quant = None

@@ -74,7 +74,7 @@ def create_communicator(
 
     ``bucket_bytes`` caps the fused gradient-allreduce buckets (see
     :mod:`chainermn_tpu.communicators.packing` and docs/performance.md):
-    ``None`` resolves env override → tuned value → 4 MiB default, ``0``
+    ``None`` resolves env override → 4 MiB default, ``0``
     disables bucketing (legacy per-leaf lowering), ``>0`` is an explicit
     cap.  ``scatter_inter`` (hierarchical only) decomposes its intra leg
     into reduce-scatter/all-gather so the inter (DCN) hop moves
@@ -85,14 +85,14 @@ def create_communicator(
     ``CHAINERMN_TPU_OVERLAP`` env gate (default ON), ``False`` pins the
     eager pack-all-then-reduce-all schedule (the ``--no-overlap`` A/B in
     bench.py).  ``overlap_granularity`` sets buckets emitted per
-    schedule stage (``None`` = env → tuned → 1).
+    schedule stage (``None`` = env → 1).
 
     ``comm_dtype`` puts gradient buckets on a low-precision wire
     (:mod:`chainermn_tpu.communicators.quant`): ``"int8"`` or ``"fp8"``
     (e4m3 where the backend supports it, int8 fallback otherwise) scale
     each packed bucket by its global amax, run the sum collective on
     the narrow dtype, and dequantize in f32.  ``None`` resolves the
-    ``CHAINERMN_TPU_COMM_DTYPE`` env → tuned value → off; ``"none"``
+    ``CHAINERMN_TPU_COMM_DTYPE`` env → off; ``"none"``
     pins it off.  Error vs the fp32 allreduce is bounded per dtype
     (docs/performance.md).
     """

@@ -158,7 +158,7 @@ class CommunicatorBase:
             jnp.dtype(allreduce_grad_dtype) if allreduce_grad_dtype else None
         )
         # Gradient bucketing cap (chainermn_tpu.communicators.packing):
-        # None = resolve at call time (env override -> tuned -> default),
+        # None = resolve at call time (env override -> default),
         # 0 = bucketing off (the legacy per-leaf/one-buffer lowering),
         # >0 = explicit per-bucket payload cap in bytes.
         if bucket_bytes is not None:
@@ -183,7 +183,7 @@ class CommunicatorBase:
         self.overlap_granularity = overlap_granularity
         # Low-precision gradient exchange (chainermn_tpu.communicators.
         # quant): None = resolve at call time (CHAINERMN_TPU_COMM_DTYPE
-        # env -> tuned -> off), "none" pins it off, "int8"/"fp8" scale
+        # env -> off), "none" pins it off, "int8"/"fp8" scale
         # packed buckets onto that wire dtype around the sum collective.
         self.comm_dtype = quant.canonical_comm_dtype(comm_dtype)
         # Host-plane transport context.  Communicator construction is SPMD
@@ -547,9 +547,10 @@ class CommunicatorBase:
         backward pass produces FIRST reduce while the rest still compute
         (see :mod:`chainermn_tpu.communicators.overlap`).
 
-        When a ``comm_dtype`` resolves (ctor -> ``CHAINERMN_TPU_COMM_DTYPE``
-        -> tuned), each float bucket is amax-scaled onto the narrow wire
-        dtype around its sum collective and dequantized in f32
+        When a ``comm_dtype`` resolves (ctor ->
+        ``CHAINERMN_TPU_COMM_DTYPE``), each float bucket is amax-scaled
+        onto the narrow wire dtype around its sum collective and
+        dequantized in f32
         (:mod:`chainermn_tpu.communicators.quant`) — bounded-error, not
         bit-exact; the bound per dtype is documented in
         docs/performance.md.  Quantization applies to the BUCKETED path
@@ -561,7 +562,7 @@ class CommunicatorBase:
             return tree
         dtypes = jax.tree.map(lambda x: x.dtype, tree)
         tree = _tree_cast(tree, self.allreduce_grad_dtype)
-        bb = self.resolve_bucket_bytes(tree) if len(leaves) > 1 else 0
+        bb = self.resolve_bucket_bytes() if len(leaves) > 1 else 0
         if bb > 0:
             out = self._allreduce_bucketed(tree, bb, overlap=overlap)
         else:
@@ -595,15 +596,14 @@ class CommunicatorBase:
         qsum = self._allreduce_sum_impl(q)
         return quant.dequantize_mean(qsum, scale, world, buf.dtype)
 
-    def resolve_comm_dtype(self, tree=None) -> str | None:
+    def resolve_comm_dtype(self) -> str | None:
         """Effective gradient wire dtype for one ``allreduce_grad`` call.
 
         Resolution order mirrors :meth:`resolve_bucket_bytes`: the
         constructor's ``comm_dtype`` if set ("none" pins off); else the
-        ``CHAINERMN_TPU_COMM_DTYPE`` environment override; else a tuned
-        value from the persistent tune cache (TPU runtime only — inert
-        under pytest and off-TPU); else off.  Returns a canonical name
-        from :data:`quant.COMM_DTYPE_CHOICES`, or ``None`` for off.
+        ``CHAINERMN_TPU_COMM_DTYPE`` environment override; else off.
+        Returns a canonical name from :data:`quant.COMM_DTYPE_CHOICES`,
+        or ``None`` for off.
         """
         cd = self.comm_dtype
         if cd is None:
@@ -613,35 +613,13 @@ class CommunicatorBase:
                     cd = quant.canonical_comm_dtype(env)
                 except ValueError:
                     cd = None
-        if cd is None and tree is not None:
-            cd = self._tuned_comm_dtype(tree)
         return None if cd in (None, "none") else cd
 
-    def _tuned_comm_dtype(self, tree):
-        try:
-            from chainermn_tpu.tuning.autotune import lookup_comm_dtype
-        except Exception:  # pragma: no cover - tuning subsystem absent
-            return None
-        leaves = jax.tree.leaves(tree)
-        per_dtype: dict = {}
-        for l in leaves:
-            dt = np.dtype(l.dtype)
-            per_dtype[dt] = per_dtype.get(dt, 0) + int(l.size) * dt.itemsize
-        dominant = max(per_dtype, key=per_dtype.get)
-        return lookup_comm_dtype(
-            total_bytes=sum(per_dtype.values()),
-            n_leaves=len(leaves),
-            dtype=dominant,
-            communicator=self.name,
-        )
-
-    def resolve_bucket_bytes(self, tree=None) -> int:
+    def resolve_bucket_bytes(self) -> int:
         """Effective bucket cap for one ``allreduce_grad`` call.
 
         Resolution order: the constructor's ``bucket_bytes`` if set; else
-        the ``CHAINERMN_TPU_BUCKET_BYTES`` environment override; else a
-        tuned value from the persistent tune cache (TPU runtime only —
-        inert under pytest and off-TPU, like every tuning lookup); else
+        the ``CHAINERMN_TPU_BUCKET_BYTES`` environment override; else
         :data:`packing.DEFAULT_BUCKET_BYTES`.  Returns 0 when bucketing
         is disabled.
         """
@@ -653,29 +631,9 @@ class CommunicatorBase:
                     bb = int(env)
                 except ValueError:
                     bb = None
-        if bb is None and tree is not None:
-            bb = self._tuned_bucket_bytes(tree)
         if bb is None:
             bb = packing.DEFAULT_BUCKET_BYTES
         return max(int(bb), 0)
-
-    def _tuned_bucket_bytes(self, tree):
-        try:
-            from chainermn_tpu.tuning.autotune import lookup_bucket_bytes
-        except Exception:  # pragma: no cover - tuning subsystem absent
-            return None
-        leaves = jax.tree.leaves(tree)
-        per_dtype: dict = {}
-        for l in leaves:
-            dt = np.dtype(l.dtype)
-            per_dtype[dt] = per_dtype.get(dt, 0) + int(l.size) * dt.itemsize
-        dominant = max(per_dtype, key=per_dtype.get)
-        return lookup_bucket_bytes(
-            total_bytes=sum(per_dtype.values()),
-            n_leaves=len(leaves),
-            dtype=dominant,
-            communicator=self.name,
-        )
 
     def resolve_overlap(self, overlap: bool | None = None) -> bool:
         """Effective overlap switch for one ``allreduce_grad`` call:
@@ -688,12 +646,12 @@ class CommunicatorBase:
             return self.overlap
         return overlap_mod.overlap_enabled()
 
-    def resolve_overlap_granularity(self, tree=None) -> int:
+    def resolve_overlap_granularity(self) -> int:
         """Effective schedule granularity (buckets emitted per stage).
 
         Resolution order mirrors :meth:`resolve_bucket_bytes`: ctor ->
-        ``CHAINERMN_TPU_OVERLAP_GRANULARITY`` env -> tuned value (TPU
-        runtime only) -> 1 (finest overlap: one collective per stage).
+        ``CHAINERMN_TPU_OVERLAP_GRANULARITY`` env -> 1 (finest overlap:
+        one collective per stage).
         """
         if self.overlap_granularity is not None:
             return self.overlap_granularity
@@ -703,30 +661,7 @@ class CommunicatorBase:
                 return max(1, int(raw))
             except ValueError:
                 pass
-        if tree is not None:
-            tuned = self._tuned_overlap_granularity(tree)
-            if tuned is not None:
-                return max(1, int(tuned))
         return overlap_mod.DEFAULT_GRANULARITY
-
-    def _tuned_overlap_granularity(self, tree):
-        try:
-            from chainermn_tpu.tuning.autotune import lookup_overlap_schedule
-        except Exception:  # pragma: no cover - tuning subsystem absent
-            return None
-        leaves = jax.tree.leaves(tree)
-        per_dtype: dict = {}
-        for l in leaves:
-            dt = np.dtype(l.dtype)
-            per_dtype[dt] = per_dtype.get(dt, 0) + int(l.size) * dt.itemsize
-        dominant = max(per_dtype, key=per_dtype.get)
-        cfg = lookup_overlap_schedule(
-            total_bytes=sum(per_dtype.values()),
-            n_leaves=len(leaves),
-            dtype=dominant,
-            communicator=self.name,
-        )
-        return None if cfg is None else cfg.get("granularity")
 
     def _allreduce_bucketed(self, tree, bucket_bytes: int,
                             overlap: bool | None = None):
@@ -756,7 +691,7 @@ class CommunicatorBase:
         # collective (quant.py's blessed pattern).  Integer buckets pass
         # through at full precision, and the schedule below is untouched
         # — scaled buckets still stage in reverse leaf-production order.
-        wire_dt = quant.wire_dtype(self.resolve_comm_dtype(tree))
+        wire_dt = quant.wire_dtype(self.resolve_comm_dtype())
         self._report_quant(packer, wire_dt)
 
         def reduce_bucket(buf):
@@ -772,7 +707,7 @@ class CommunicatorBase:
                 return packer.unpack(outs)
 
         schedule = overlap_mod.build_overlap_schedule(
-            packer, self.resolve_overlap_granularity(tree)
+            packer, self.resolve_overlap_granularity()
         )
         leaves = packer._check_tree(tree)
         outs: list = [None] * packer.n_buckets

@@ -32,9 +32,7 @@ threads:
    addition inside each bucket is untouched).
 
 Escape hatch: ``CHAINERMN_TPU_OVERLAP=0`` restores the eager
-pack-all-then-reduce-all emission.  The schedule's granularity (buckets
-fused per emission stage) x ``bucket_bytes`` is an autotune dimension —
-see ``chainermn_tpu.tuning`` (``tune_overlap_schedule``).
+pack-all-then-reduce-all emission.
 """
 
 from __future__ import annotations
@@ -49,7 +47,7 @@ from typing import List, Tuple
 ENV_OVERLAP = "CHAINERMN_TPU_OVERLAP"
 
 #: Environment override for the schedule granularity (buckets emitted
-#: per stage); unset resolves ctor -> tuned -> 1 (finest overlap).
+#: per stage); unset resolves ctor -> 1 (finest overlap).
 ENV_OVERLAP_GRANULARITY = "CHAINERMN_TPU_OVERLAP_GRANULARITY"
 
 DEFAULT_GRANULARITY = 1

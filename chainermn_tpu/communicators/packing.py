@@ -16,10 +16,10 @@ Two utilities live here:
 * :class:`GradPacker` — the bucketed form every communicator's
   ``allreduce_grad`` uses by default: the gradient pytree is split into
   contiguous per-dtype buckets capped at ``bucket_bytes``, each padded to
-  a power-of-two element count (collective-friendly sizes, stable tune-
-  cache buckets), and the communicator's characteristic allreduce runs
-  once per bucket — O(n_buckets) collectives instead of O(n_leaves),
-  with a lossless unpack (pure slicing, bit-exact).
+  a power-of-two element count (collective-friendly sizes), and the
+  communicator's characteristic allreduce runs once per bucket —
+  O(n_buckets) collectives instead of O(n_leaves), with a lossless
+  unpack (pure slicing, bit-exact).
 
 Padding note: a bucket whose next power of two would overshoot the
 ``bucket_bytes`` cap (a single oversize leaf, or a near-full bucket) is
@@ -349,8 +349,8 @@ def synthetic_grad_tree(
 ) -> dict:
     """Deterministic mixed-shape / mixed-dtype gradient pytree.
 
-    The shared shape-maker behind the ``allreduce_tree`` bench, the
-    bucket tuner, and the census golden test — one definition so their
+    The shared shape-maker behind the ``allreduce_tree`` bench and the
+    census golden test — one definition so their
     "64-leaf mixed-shape tree" is the same tree.  Leaf 0 is a scalar,
     every 5th leaf is 2-D, dtypes round-robin, and sizes follow a cycling
     weight so buckets straddle leaf boundaries.  Values are exact in

@@ -60,7 +60,7 @@ ENV_COMM_DTYPE = "CHAINERMN_TPU_COMM_DTYPE"
 ENV_KV_DTYPE = "CHAINERMN_TPU_KV_DTYPE"
 
 #: Canonical comm wire-dtype names accepted by ``comm_dtype=`` (plus
-#: ``"none"`` for explicit off and ``None`` for "resolve env -> tuned").
+#: ``"none"`` for explicit off and ``None`` for "resolve env").
 COMM_DTYPE_CHOICES = ("int8", "fp8")
 
 #: Canonical KV cache storage dtypes accepted by ``kv_dtype=``.
@@ -91,7 +91,7 @@ _NAME_ALIASES = {
 def canonical_comm_dtype(name: Any) -> Optional[str]:
     """Normalize a user spelling of ``comm_dtype``.
 
-    Returns ``None`` for "unset" (resolve env -> tuned -> off), the
+    Returns ``None`` for "unset" (resolve env -> off), the
     string ``"none"`` for an explicit off, or a canonical member of
     :data:`COMM_DTYPE_CHOICES`.  Raises on unknown names so typos fail
     at construction, not silently at full precision.
@@ -312,7 +312,7 @@ def measure_comm_quant_error(comm, tree, publish: bool = True) -> float:
     and ``publish`` is set.  Returns the error as a Python float — the
     number bench's A/B column and the verify-skill probe print.
     """
-    cd = comm.resolve_comm_dtype(tree)
+    cd = comm.resolve_comm_dtype()
     if cd is None:
         raise ValueError(
             "measure_comm_quant_error needs a communicator with a "

@@ -31,19 +31,6 @@ from chainermn_tpu.models.block_table import (
 from chainermn_tpu.observability.spans import named_scope
 
 
-def _tuned_block_ctx(page_count, page_size, n_kv, d_head, dtype):
-    """Tuned context-gather chunk (in pages) for paged decode attention.
-    ``None`` (one-shot gather) when the tune cache has no entry — and
-    always off-TPU / under pytest, where tuning lookups are inert, so CPU
-    decode numerics never depend on the cache."""
-    from chainermn_tpu.tuning import lookup_decode_block_ctx
-
-    return lookup_decode_block_ctx(
-        n_pages=page_count, page_size=page_size, n_kv=n_kv,
-        d_head=d_head, dtype=dtype,
-    )
-
-
 def sinusoidal_positions(max_len: int, d_model: int) -> np.ndarray:
     pos = np.arange(max_len)[:, None]
     div = np.exp(np.arange(0, d_model, 2) * (-np.log(10000.0) / d_model))
@@ -272,10 +259,6 @@ class MultiHeadAttention(nn.Module):
                 write_kv(write_chunk_pages, seq_lens)
                 out = paged_attention_chunk(
                     q, pk.value, pv.value, block_tables, attn_start,
-                    block_ctx=_tuned_block_ctx(
-                        self.page_count, self.page_size, n_kv, d_head,
-                        q.dtype,
-                    ),
                     **scales(),
                 )
                 return nn.DenseGeneral(
@@ -291,10 +274,6 @@ class MultiHeadAttention(nn.Module):
                 write_kv(write_token_pages, seq_lens)
                 out = paged_attention_decode(
                     q, pk.value, pv.value, block_tables, seq_lens + 1,
-                    block_ctx=_tuned_block_ctx(
-                        self.page_count, self.page_size, n_kv, d_head,
-                        q.dtype,
-                    ),
                     **scales(),
                 )
                 return nn.DenseGeneral(

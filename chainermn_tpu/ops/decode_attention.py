@@ -14,11 +14,7 @@ cached-KV model path (:mod:`chainermn_tpu.models.transformer`):
 
 * :func:`paged_attention_decode` — one-query-per-sequence attention over
   paged K/V.  The reference-quality jnp lowering (gather pages → masked
-  softmax) is the **CPU-safe fallback** the tier-1 suite runs under
-  ``JAX_PLATFORMS=cpu``; on TPU the gather is chunked along the context
-  by a tuned ``block_ctx`` (``tuning.decode_cache_key``) to bound the
-  transient gathered buffer — chunking a gather is a pure data-movement
-  choice, so the numerics are bit-identical to the one-shot gather.
+  softmax) is the one lowering on every backend.
 * :func:`write_prompt_pages` / :func:`write_token_pages` — the scatter
   writes that land prefill (whole prompt) and decode (one token per
   sequence) K/V into the pages.
@@ -38,8 +34,6 @@ and ``tests/golden/serving_decode_census.json``).
 """
 
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -129,7 +123,6 @@ def paged_attention_decode(
     block_tables,
     seq_lens,
     *,
-    block_ctx: Optional[int] = None,
     k_scales=None,
     v_scales=None,
 ):
@@ -147,12 +140,6 @@ def paged_attention_decode(
     (exactly-zero weights), softmax accumulates in fp32, and every
     reduction stays inside one sequence's row.
 
-    ``block_ctx``: gather the context in chunks of this many *pages*
-    (tuned on TPU via :func:`chainermn_tpu.tuning.lookup_decode_block_ctx`)
-    to bound the transient (B, ctx, Hkv, D) buffer; ``None`` gathers in
-    one shot.  Chunking only the gather leaves the attention numerics
-    untouched.
-
     ``k_scales``/``v_scales``: (N, page_size, Hkv) fp32 per-token-per-head
     scales for quantized (int8) pages — gathered through the same block
     table and multiplied back in after the gather (``kv_dtype`` in
@@ -166,7 +153,7 @@ def paged_attention_decode(
         )
     return paged_attention_chunk(
         q, k_pages, v_pages, block_tables, seq_lens - 1,
-        block_ctx=block_ctx, k_scales=k_scales, v_scales=v_scales,
+        k_scales=k_scales, v_scales=v_scales,
     )
 
 
@@ -178,7 +165,6 @@ def paged_attention_chunk(
     block_tables,
     start_lens,
     *,
-    block_ctx: Optional[int] = None,
     k_scales=None,
     v_scales=None,
 ):
@@ -211,41 +197,27 @@ def paged_attention_chunk(
         raise ValueError(f"n_kv_heads ({Hkv}) must divide n_heads ({H})")
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be given together")
-    W = block_tables.shape[1]
 
-    def gather(pages, tables):
-        g = jnp.take(pages, tables, axis=0, mode="fill", fill_value=0)
-        return g.reshape(B, tables.shape[1] * page_size, Hkv, D)
+    ctx = block_tables.shape[1] * page_size
 
-    def gather_deq(pages, scales, tables):
-        g = gather(pages, tables)
+    def gather(pages, scales):
+        g = jnp.take(pages, block_tables, axis=0, mode="fill", fill_value=0)
+        g = g.reshape(B, ctx, Hkv, D)
         if scales is None:
             return g
         from chainermn_tpu.communicators.quant import dequantize_kv
 
-        s = jnp.take(scales, tables, axis=0, mode="fill", fill_value=0)
-        s = s.reshape(B, tables.shape[1] * page_size, Hkv)
-        return dequantize_kv(g, s, q.dtype)
+        s = jnp.take(scales, block_tables, axis=0, mode="fill", fill_value=0)
+        return dequantize_kv(g, s.reshape(B, ctx, Hkv), q.dtype)
 
-    if block_ctx is None or block_ctx >= W:
-        k = gather_deq(k_pages, k_scales, block_tables)
-        v = gather_deq(v_pages, v_scales, block_tables)
-    else:
-        # Chunked gather: identical concatenated tensor, bounded transient.
-        ks, vs = [], []
-        for start in range(0, W, block_ctx):
-            t = block_tables[:, start:start + block_ctx]
-            ks.append(gather_deq(k_pages, k_scales, t))
-            vs.append(gather_deq(v_pages, v_scales, t))
-        k = jnp.concatenate(ks, axis=1)
-        v = jnp.concatenate(vs, axis=1)
+    k = gather(k_pages, k_scales)
+    v = gather(v_pages, v_scales)
 
     if Hkv != H:
         k = jnp.repeat(k, H // Hkv, axis=2)
         v = jnp.repeat(v, H // Hkv, axis=2)
     scale = 1.0 / np.sqrt(D)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    ctx = k.shape[1]
     bounds = start_lens[:, None] + jnp.arange(T, dtype=jnp.int32)[None] + 1
     mask = (jnp.arange(ctx)[None, None] < bounds[:, :, None])[:, None]
     logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)

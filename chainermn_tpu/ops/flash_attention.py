@@ -245,7 +245,7 @@ def _attn_kernel(
 #: What Mosaic lets one kernel use of a v5e core's 128 MiB VMEM unasked
 #: (its default scoped limit on this toolchain) — the budget the static
 #: default geometry is chosen within — and the most this module asks for
-#: when a pinned or tuned geometry needs more.
+#: when a pinned geometry needs more.
 VMEM_SCOPED_DEFAULT = 16 * 1024 * 1024
 VMEM_LIMIT_MAX = 96 * 1024 * 1024
 
@@ -292,7 +292,7 @@ def flash_vmem_bytes(block_q: int, block_k: int, D: int, itemsize: int,
 def _compiler_params(footprint: int):
     """Mosaic parameters for a kernel of this footprint: the scoped-VMEM
     limit is raised to what the blocks need, and a quarter more, when
-    that is more than the default (a pinned or tuned 2048 x 2048 tile);
+    that is more than the default (a pinned 2048 x 2048 tile);
     never lowered."""
     if footprint <= VMEM_SCOPED_DEFAULT:
         return None
@@ -852,9 +852,7 @@ def auto_block_size(S: int, D: int, dtype, which: str = "fwd",
     pair of fitting edges fits.  A length no multiple of 128 divides
     keeps the old answer (``min(128, S)``: whole when short, else a
     block that does not divide and sends the call to XLA) — an auto pick
-    must not demote a shape that compiles.  This is also what the tuning
-    subsystem resolves to on a cache miss, and a mandatory member of its
-    search space."""
+    must not demote a shape that compiles."""
     edges = [b for b in range(128, S + 1, 128) if S % b == 0]
     if not edges:
         return min(128, S)
@@ -930,25 +928,19 @@ def flash_attention(
     segment id -1 against all-nonnegative kv ids) produce zero output
     and zero gradients.
 
-    ``block_q``/``block_k`` default to a TUNED size when the persistent
-    autotune cache (``chainermn_tpu.tuning``, see docs/tuning.md) holds a
-    measured-best entry for this (device kind, dtype, shape bucket,
-    causal/window) — populated by ``python -m chainermn_tpu.tools
-    .autotune`` or ``bench.py --autotune``, never implicitly.  On a miss,
-    off-TPU, or under pytest, the static rule applies
-    (:func:`auto_block_size`): along each axis the largest multiple of
+    ``block_q``/``block_k`` default to the rule of
+    :func:`auto_block_size`: along each axis the largest multiple of
     128 that divides it and whose square tile fits Mosaic's default
     scoped VMEM by the kernels' own footprint — 1024 at D=128 in bf16.
     It rests on the ledger's PR 24 line of ``cgpt-train-1chip`` (the old
     S/16 rule's 128 x 128 at S=2048: ``kernel.flash_ms`` 336.5 at 5.0%
     of the roofline) and on the block sweep of PERF.md §6, PR 25.
-    Pinning either block explicitly bypasses the cache entirely.
 
     ``block_q_bwd``/``block_k_bwd``: optional separate geometry for the
-    backward kernels (tuned independently — the backward streams two
-    extra operands and runs two kernels, so its optimum can differ).
-    With nothing pinned they default to the rule's answer for the
-    backward's footprint; blocks pinned for the forward carry over.
+    backward kernels (the backward streams two extra operands and runs
+    two kernels, so its optimum can differ).  With nothing pinned they
+    default to the rule's answer for the backward's footprint; blocks
+    pinned for the forward carry over.
     """
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
@@ -979,31 +971,12 @@ def flash_attention(
 
     segmented = q_segment_ids is not None
     pinned = block_q is not None or block_k is not None
-    if not pinned and not interpret:
-        # Caller pinned nothing: consult the persistent tune cache (a
-        # trace-time read; inert under pytest and off-TPU, so interpret/
-        # CPU behavior stays bit-identical to the static defaults).
-        from chainermn_tpu.tuning.autotune import lookup_flash_blocks
-
-        tuned = lookup_flash_blocks(
-            "fwd", Sq=Sq, Sk=Sk, D=D, dtype=q.dtype, causal=causal,
-            window=window, segmented=segmented,
-        )
-        if tuned is not None:
-            block_q, block_k = tuned
-        if block_q_bwd is None and block_k_bwd is None:
-            tuned_bwd = lookup_flash_blocks(
-                "bwd", Sq=Sq, Sk=Sk, D=D, dtype=q.dtype, causal=causal,
-                window=window, segmented=segmented,
-            )
-            if tuned_bwd is not None:
-                block_q_bwd, block_k_bwd = tuned_bwd
 
     def static(S, which):
         return auto_block_size(S, D, q.dtype, which, segmented, window)
 
     if not pinned and block_q_bwd is None and block_k_bwd is None:
-        # Nothing pinned or tuned: the backward gets the rule's own
+        # Nothing pinned: the backward gets the rule's own
         # answer (it holds more per tile).  Blocks a caller pinned for
         # the forward carry over to the backward, as they always did.
         block_q_bwd, block_k_bwd = static(Sq, "bwd"), static(Sk, "bwd")
@@ -1046,7 +1019,7 @@ def flash_attention(
         )
 
     # Backward geometry rides the same gate as the forward's: an invalid
-    # pair (stale cache bucket, caller typo) silently reverts to the
+    # pair (a block that does not divide, a caller typo) reverts to the
     # forward blocks rather than demoting the whole call to the XLA path.
     if block_q_bwd is not None or block_k_bwd is not None:
         bq_b = block_q_bwd or block_q
@@ -1152,9 +1125,8 @@ def make_flash_attention_fn(causal: bool = True, q_segment_ids=None,
     scale checks it was given the matching adapter.
 
     ``block_q``/``block_k``/``block_q_bwd``/``block_k_bwd``: optional
-    pinned kernel geometry (``bench.py --autotune`` binds the tuned
-    blocks here); None defers to :func:`flash_attention`'s cache-then-
-    static default.
+    pinned kernel geometry; None defers to :func:`flash_attention`'s
+    rule (:func:`auto_block_size`).
 
     ``q_segment_ids``/``kv_segment_ids`` (optional int32) bind
     packed-sequence segment masks at CONSTRUCTION — the layers call

@@ -54,25 +54,8 @@ from chainermn_tpu.observability import reporter as _reporter
 from chainermn_tpu.observability import step_log as _step_log
 from chainermn_tpu.observability.spans import named_scope, telemetry_active
 
-#: the static default row chunk — the cache-miss / off-TPU fallback, and
-#: a mandatory member of the autotuner's search space (a tuned chunk can
-#: never lose to it).
+#: rows per scan tile when the caller passes no ``chunk``.
 DEFAULT_CHUNK = 512
-
-
-def _resolve_chunk(chunk, N: int, V: int, D: int, dtype) -> int:
-    """``chunk=None`` → the tuned chunk from the persistent autotune
-    cache (``chainermn_tpu.tuning``; populated only by the explicit CLI /
-    ``bench.py --autotune``), falling back to :data:`DEFAULT_CHUNK` on a
-    miss.  Inert under pytest and off-TPU — there None always resolves
-    to the static default, bit-identical to the pre-tuning behavior.
-    An explicit ``chunk`` bypasses the cache."""
-    if chunk is not None:
-        return int(chunk)
-    from chainermn_tpu.tuning.autotune import lookup_ce_chunk
-
-    tuned = lookup_ce_chunk(N=N, V=V, D=D, dtype=dtype)
-    return int(tuned) if tuned else DEFAULT_CHUNK
 
 
 def _pick_chunk(n: int, chunk: int) -> int:
@@ -379,11 +362,7 @@ def fused_cross_entropy(hidden, embedding, labels, *, chunk=None):
     (:func:`fused_cross_entropy_with_lse` is the path that recomputes).
     Without differentiation only the loss scan runs.
 
-    ``chunk`` — rows per scan tile.  The default (None) resolves to the
-    autotuned chunk for this (device kind, dtype, N, V, D) when the
-    persistent tune cache has one (see docs/tuning.md), else the static
-    :data:`DEFAULT_CHUNK` — always the static default off-TPU and under
-    pytest.  Passing an int pins it.
+    ``chunk`` — rows per scan tile; None is :data:`DEFAULT_CHUNK`.
     """
     h2, l2, chunk = _prepare(
         hidden, embedding, labels, chunk, "grad_in_forward")
@@ -407,9 +386,7 @@ def _prepare(hidden, embedding, labels, chunk, form):
     """``(hidden (N, D), labels (N,), resolved chunk)`` of a public call,
     its geometry published when telemetry is on."""
     h2, l2 = _validate_and_flatten(hidden, embedding, labels, chunk)
-    chunk = _resolve_chunk(
-        chunk, h2.shape[0], embedding.shape[0], h2.shape[1], hidden.dtype
-    )
+    chunk = DEFAULT_CHUNK if chunk is None else int(chunk)
     if telemetry_active():
         _publish_geometry(h2, embedding, chunk, form)
     return h2, l2, chunk

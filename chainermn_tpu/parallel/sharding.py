@@ -53,11 +53,10 @@ def transformer_param_spec(params, model_axis: str = "model"):
        rules now live in the declarative plan registry as plan ``"tp"``
        (``chainermn_tpu.sharding.get_plan("tp")``), which additionally
        resolves grads, optimizer moments, and the serving KV cache from
-       one table, is lintable (rule R006), and composes with the
-       autotuner's layout search.  This shim is kept for existing
-       callers and resolves leaf-for-leaf identically to the ``tp``
-       plan (pinned by ``tests/test_shardplan.py``); new code should
-       pass a :class:`~chainermn_tpu.sharding.ShardingPlan` to
+       one table, and is lintable (rule R006).  This shim is kept for
+       existing callers and resolves leaf-for-leaf identically to the
+       ``tp`` plan (pinned by ``tests/test_shardplan.py``); new code
+       should pass a :class:`~chainermn_tpu.sharding.ShardingPlan` to
        :func:`make_gspmd_train_step` instead.  See docs/sharding.md."""
 
     def spec_for(path, leaf) -> P:

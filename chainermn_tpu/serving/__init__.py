@@ -14,8 +14,8 @@ exercises the repo's own model (:class:`~chainermn_tpu.models.transformer
 * :mod:`~chainermn_tpu.serving.engine` — the execution engine: jitted
   prefill, single-token decode, and multi-token chunk steps with static
   padding buckets (bounded recompiles), the paged-attention data plane
-  from :mod:`~chainermn_tpu.ops.decode_attention` (CPU-safe, tuned
-  gather chunks on TPU), host-side deterministic sampling;
+  from :mod:`~chainermn_tpu.ops.decode_attention`, host-side
+  deterministic sampling;
 * :mod:`~chainermn_tpu.serving.spec` — draft proposal sources for
   speculative decoding: n-gram prompt lookup (model-free) and the
   layer-truncated self-draft model (both deterministic per request);

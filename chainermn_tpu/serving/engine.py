@@ -19,9 +19,7 @@ programs, and sampling.  Design constraints, in order:
    the number of requests — pinned by the recompile-count test.
 3. **CPU-safe.**  The data plane is pure jnp (gather/scatter + einsum
    softmax, :mod:`chainermn_tpu.ops.decode_attention`), so the tier-1
-   suite runs the whole engine under ``JAX_PLATFORMS=cpu``; on TPU the
-   same program picks up the tuned gather chunk
-   (``tuning.lookup_decode_block_ctx``) with identical numerics.
+   suite runs the whole engine under ``JAX_PLATFORMS=cpu``.
 
 The decode data plane is collective-free by construction — no psum ever
 belongs in a per-sequence cache read — and stays that way via the
@@ -32,6 +30,7 @@ belongs in a per-sequence cache read — and stays that way via the
 from __future__ import annotations
 
 import dataclasses
+import os
 from typing import List, Optional, Tuple
 
 import jax
@@ -55,77 +54,40 @@ ENV_PREFILL_CHUNK = "CHAINERMN_TPU_PREFILL_CHUNK"
 DEFAULT_CHUNK_CAP = 4096
 
 
-def _resolve_draft(cfg: "EngineConfig", lm: TransformerLM) -> str:
+def _resolve_draft(cfg: "EngineConfig") -> str:
     """``draft`` source resolution, same order as ``kv_dtype``: explicit
-    config -> ``CHAINERMN_TPU_DRAFT`` env -> autotune cache (inert under
-    pytest / off-TPU) -> ``"ngram"``."""
-    import os
-
+    config -> ``CHAINERMN_TPU_DRAFT`` env -> ``"ngram"``."""
     if cfg.draft is not None:
         if cfg.draft not in DRAFT_SOURCES:
             raise ValueError(
                 f"draft must be one of {DRAFT_SOURCES}, got {cfg.draft!r}")
         return cfg.draft
     env = os.environ.get(ENV_DRAFT)
-    if env is not None:
-        return env if env in DRAFT_SOURCES else "ngram"
-    try:
-        from chainermn_tpu.tuning import lookup_draft
-    except ImportError:  # pragma: no cover - partial installs
-        return "ngram"
-    return lookup_draft(
-        vocab=lm.vocab, d_model=lm.d_model, n_layers=lm.n_layers,
-        max_len=cfg.max_len, dtype=lm.dtype,
-    ) or "ngram"
+    return env if env in DRAFT_SOURCES else "ngram"
 
 
 def _resolve_prefill_chunk(cfg: "EngineConfig") -> int:
     """``prefill_chunk`` resolution (0 = off): explicit config ->
-    ``CHAINERMN_TPU_PREFILL_CHUNK`` env -> autotune cache -> off."""
-    import os
-
+    ``CHAINERMN_TPU_PREFILL_CHUNK`` env -> off."""
     if cfg.prefill_chunk is not None:
         return max(0, int(cfg.prefill_chunk))
-    env = os.environ.get(ENV_PREFILL_CHUNK)
-    if env is not None:
-        try:
-            return max(0, int(env))
-        except ValueError:
-            return 0
     try:
-        from chainermn_tpu.tuning import lookup_prefill_chunk
-    except ImportError:  # pragma: no cover - partial installs
+        return max(0, int(os.environ.get(ENV_PREFILL_CHUNK, 0)))
+    except ValueError:
         return 0
-    return lookup_prefill_chunk(
-        max_len=cfg.max_len, block_size=cfg.block_size,
-    ) or 0
 
 
-def _resolve_kv_dtype(cfg: "EngineConfig", lm: TransformerLM):
+def _resolve_kv_dtype(cfg: "EngineConfig"):
     """``kv_dtype`` resolution, mirroring the comm side's ctor -> env ->
-    tuned -> off order: an explicit config value (any spelling,
-    including ``"none"``) wins outright; an unset one consults the
-    ``CHAINERMN_TPU_KV_DTYPE`` env, then the autotune cache (inert under
-    pytest / off-TPU)."""
-    import os
-
+    off order: an explicit config value (any spelling, including
+    ``"none"``) wins outright; an unset one consults the
+    ``CHAINERMN_TPU_KV_DTYPE`` env."""
     if cfg.kv_dtype is not None:
         return quant.canonical_kv_dtype(cfg.kv_dtype)
-    env = os.environ.get(quant.ENV_KV_DTYPE)
-    if env is not None:
-        try:
-            return quant.canonical_kv_dtype(env)
-        except ValueError:
-            return None
     try:
-        from chainermn_tpu.tuning import lookup_kv_dtype
-    except ImportError:  # pragma: no cover - partial installs
+        return quant.canonical_kv_dtype(os.environ.get(quant.ENV_KV_DTYPE))
+    except ValueError:
         return None
-    n_kv = lm.n_kv_heads or lm.n_heads
-    return lookup_kv_dtype(
-        n_pages=cfg.n_blocks, page_size=cfg.block_size, n_kv=n_kv,
-        d_head=lm.d_model // lm.n_heads, dtype=lm.dtype,
-    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,7 +127,7 @@ class EngineConfig:
     #: KV page storage dtype: ``"int8"`` stores pages quantized with
     #: per-token-per-head scales (docs/serving.md — ~half the pool bytes
     #: per token, bounded decode error); ``None`` resolves
-    #: ``CHAINERMN_TPU_KV_DTYPE`` -> tuned value -> model dtype;
+    #: ``CHAINERMN_TPU_KV_DTYPE`` -> model dtype;
     #: ``"none"`` pins full precision.
     kv_dtype: Optional[str] = None
     prefill_buckets: Optional[Tuple[int, ...]] = None
@@ -176,9 +138,9 @@ class EngineConfig:
     chunk_buckets: Optional[Tuple[int, ...]] = None
     #: speculative draft source: ``"ngram"`` (prompt lookup, free) or
     #: ``"model"`` (layer-truncated self-draft under its own jit);
-    #: ``None`` resolves ``CHAINERMN_TPU_DRAFT`` -> tuned value ->
-    #: ``"ngram"``.  Either source is verified by the same chunk step,
-    #: so streams stay bit-exact regardless.
+    #: ``None`` resolves ``CHAINERMN_TPU_DRAFT`` -> ``"ngram"``.  Either
+    #: source is verified by the same chunk step, so streams stay
+    #: bit-exact regardless.
     draft: Optional[str] = None
     #: layers in the truncated draft (``draft="model"`` only); ``None``
     #: = ``max(1, n_layers // 2)``.  ``n_layers`` gives an exact (but
@@ -187,8 +149,8 @@ class EngineConfig:
     #: chunked prefill: prompts whose un-cached suffix exceeds this many
     #: tokens prefill in slices of this size, interleaved with decode
     #: iterations (bounds decode p99 under long-prompt arrival).
-    #: ``None`` resolves ``CHAINERMN_TPU_PREFILL_CHUNK`` -> tuned value
-    #: -> 0 (off); 0 pins off.
+    #: ``None`` resolves ``CHAINERMN_TPU_PREFILL_CHUNK`` -> 0 (off);
+    #: 0 pins off.
     prefill_chunk: Optional[int] = None
     #: sequence-parallel prefill: shard the chunk program's token axis
     #: over this many devices (pow2; the ``sp`` registry plan supplies
@@ -277,7 +239,7 @@ class InferenceEngine:
         self.kv = PagedKVCache(cfg.n_blocks, cfg.block_size,
                                prefix_cache=cfg.prefix_cache)
 
-        self.kv_dtype = _resolve_kv_dtype(cfg, lm)
+        self.kv_dtype = _resolve_kv_dtype(cfg)
         twin = dict(
             vocab=lm.vocab, d_model=lm.d_model, n_heads=lm.n_heads,
             d_ff=lm.d_ff, n_layers=lm.n_layers, max_len=lm.max_len,
@@ -504,26 +466,14 @@ class InferenceEngine:
             self._apply_plan(plan, mesh)
 
         # Draft source + chunked prefill (resolution: config -> env ->
-        # tuned -> default, like kv_dtype above).  The draft model is
+        # default, like kv_dtype above).  The draft model is
         # built AFTER plan placement so its param subset references the
         # placed arrays, not stale host copies.
-        self.draft_source = _resolve_draft(cfg, lm)
+        self.draft_source = _resolve_draft(cfg)
         self.prefill_chunk = _resolve_prefill_chunk(cfg)
         self.draft_model: Optional[DraftModel] = None
         if self.draft_source == "model":
-            k = cfg.draft_layers
-            if not k:
-                try:
-                    from chainermn_tpu.tuning import lookup_draft_layers
-
-                    k = lookup_draft_layers(
-                        vocab=lm.vocab, d_model=lm.d_model,
-                        n_layers=lm.n_layers, max_len=cfg.max_len,
-                        dtype=lm.dtype,
-                    )
-                except ImportError:  # pragma: no cover
-                    k = None
-            k = k or max(1, lm.n_layers // 2)
+            k = cfg.draft_layers or max(1, lm.n_layers // 2)
             self.draft_model = DraftModel(
                 lm, self.params, k, cfg.prefill_buckets
             )

@@ -10,8 +10,7 @@ cache.
 
 Plans compose with the mesh at the call site: a plan only says *which
 named axes* shard *which leaves*; ``plans_for_mesh`` filters the
-registry down to plans whose axes the mesh actually has (the autotuner's
-``layout`` search space).
+registry down to plans whose axes the mesh actually has.
 """
 
 from __future__ import annotations
@@ -55,8 +54,7 @@ def list_plans() -> List[ShardingPlan]:
 def plans_for_mesh(mesh, params=None) -> List[ShardingPlan]:
     """Registry plans whose every axis exists on ``mesh`` — and, when a
     parameter tree is given, that :func:`validate` clean against it
-    (including mesh divisibility).  This is the autotune ``layout``
-    candidate set."""
+    (including mesh divisibility)."""
     out = []
     for plan in list_plans():
         if not set(plan.axes) <= set(mesh.axis_names):

@@ -1,7 +1,8 @@
-"""Operational command-line tools (``python -m chainermn_tpu.tools.*``).
-
-Currently: :mod:`~chainermn_tpu.tools.autotune` — pre-populate the
-persistent kernel tune cache for the bench shapes (or any shape family)
-so training runs pick up measured-best Pallas block configs instead of
-the static defaults.
+"""Operational command-line tools (``python -m chainermn_tpu.tools.*``):
+:mod:`~chainermn_tpu.tools.elastic` (supervised training launcher),
+:mod:`~chainermn_tpu.tools.serve` (multi-replica serving),
+:mod:`~chainermn_tpu.tools.fabric` (training and serving trading chips),
+:mod:`~chainermn_tpu.tools.lint` (collective-correctness gate),
+:mod:`~chainermn_tpu.tools.obs` (step-log summaries and traces) and
+:mod:`~chainermn_tpu.tools.shardplan` (sharding-plan browser).
 """

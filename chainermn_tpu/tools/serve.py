@@ -743,7 +743,7 @@ def main(argv=None) -> int:
                     help="speculative draft source (with --spec-tokens):"
                          " n-gram prompt lookup or the layer-truncated "
                          "self-draft model (default: engine resolution "
-                         "— env, tuned cache, then ngram)")
+                         "— env, then ngram)")
     ap.add_argument("--draft-layers", type=int, default=None,
                     help="self-draft depth (--draft model; default: "
                          "half the target's layers)")

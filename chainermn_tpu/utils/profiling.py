@@ -1,9 +1,10 @@
 """Timing helpers and the compile-cache switch.
 
 SURVEY §5.1: the reference relied on Chainer's TimerHook + external nvprof.
-Here: ``slope_time`` / ``median_slope`` / ``sync`` are the timing backbone
-of ``bench.py`` and the autotuner, ``allreduce_bus_bandwidth_gbs`` the
-``allreduce bus-bw GB/s`` arithmetic BASELINE.json tracks, and
+Here: ``slope_time`` / ``median_slope`` / ``sync`` are the host-clock
+timing of ``bench.py`` and ``benchmarks/``,
+``allreduce_bus_bandwidth_gbs`` the ``allreduce bus-bw GB/s``
+arithmetic BASELINE.json tracks, and
 ``setup_compilation_cache`` what every entry point calls first.  Profiler
 captures and named regions live in ``chainermn_tpu.observability``
 (``device_trace.capture``, ``spans.annotate`` / ``span`` / ``named_scope``).
@@ -59,9 +60,8 @@ def slope_time(run, n1: int, n2: Optional[int] = None) -> float:
 def median_slope(run, n1: int = 5, repeats: int = 3):
     """Median of ``repeats`` independent :func:`slope_time` measurements,
     with the sorted samples, so the run-to-run spread is reported next
-    to the number.  The shared timing backbone of ``bench.py`` and the
-    kernel autotuner (``chainermn_tpu.tuning``).  Returns
-    ``(median_seconds_per_iter, sorted_samples)``."""
+    to the number.  Returns ``(median_seconds_per_iter,
+    sorted_samples)``."""
     samples = sorted(slope_time(run, n1) for _ in range(repeats))
     return samples[len(samples) // 2], samples
 

@@ -6,7 +6,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chainermn_tpu.ops.flash_attention import _xla_attention, flash_attention
+from chainermn_tpu.ops.flash_attention import (
+    _xla_attention,
+    auto_block_size,
+    flash_attention,
+)
 
 
 def make_qkv(B=2, S=256, H=2, D=64, seed=0, dtype=jnp.float32):
@@ -27,6 +31,58 @@ def test_flash_small_blocks():
     out = flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
     ref = _xla_attention(q, k, v, 1.0 / 8.0, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
+def test_flash_default_blocks_match_explicit():
+    """block_q=block_k=None must be EXACTLY the geometry of
+    ``auto_block_size``: bit-identical output and gradients."""
+    q, k, v = make_qkv()
+    b = auto_block_size(256, 64, jnp.float32, "fwd")
+    assert b == 256  # the rule's largest fitting tile: the whole axis
+    out_auto = flash_attention(q, k, v, causal=True)
+    out_pinned = flash_attention(q, k, v, causal=True, block_q=b, block_k=b)
+    np.testing.assert_array_equal(np.asarray(out_auto),
+                                  np.asarray(out_pinned))
+    bb = auto_block_size(256, 64, jnp.float32, "bwd")
+
+    def grads(**blocks):
+        return jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, **blocks).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    for a, p in zip(grads(), grads(block_q=b, block_k=b, block_q_bwd=bb,
+                                   block_k_bwd=bb)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(p))
+
+
+@pytest.mark.parametrize(
+    "block_q,block_k", [(32, 32), (64, 128), (128, 64), (256, 256)])
+def test_flash_candidate_configs_numerically_match_default(block_q, block_k):
+    """A block geometry changes speed, never values."""
+    q, k, v = make_qkv(B=1, seed=1)
+    ref = flash_attention(q, k, v, causal=True)
+    out = flash_attention(
+        q, k, v, causal=True, block_q=block_q, block_k=block_k)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
+def test_flash_bwd_blocks_numerics_match():
+    """A backward geometry different from the forward's must give the
+    same gradients."""
+    q, k, v = make_qkv(B=1, S=128, seed=2)
+
+    def loss(q, k, v, **kw):
+        return jnp.sum(flash_attention(q, k, v, causal=True, **kw) ** 2)
+
+    g_ref = jax.grad(loss, argnums=(0, 1, 2))(
+        q, k, v, block_q=64, block_k=64)
+    g_bwd = jax.grad(loss, argnums=(0, 1, 2))(
+        q, k, v, block_q=64, block_k=64, block_q_bwd=32, block_k_bwd=32)
+    for a, b in zip(g_ref, g_bwd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-5, atol=2e-5)
 
 
 def test_fallback_on_unaligned_shapes():

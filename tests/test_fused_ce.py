@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from chainermn_tpu.ops.fused_ce import (
+    DEFAULT_CHUNK,
     fused_cross_entropy,
     fused_cross_entropy_with_lse,
     naive_cross_entropy,
@@ -43,6 +44,19 @@ def test_value_matches_oracle(chunk):
     got = fused_cross_entropy(h, e, lab, chunk=chunk)
     want = naive_cross_entropy(h, e, lab)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3)
+
+
+def test_fused_ce_chunk_none_is_static_default():
+    h, e, lab = _mk()
+    got = fused_cross_entropy(h, e, lab)
+    want = fused_cross_entropy(h, e, lab, chunk=DEFAULT_CHUNK)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_fused_ce_rejects_bad_chunk():
+    h, e, lab = _mk()
+    with pytest.raises(ValueError, match="chunk"):
+        fused_cross_entropy(h, e, lab, chunk=0)
 
 
 def test_grads_match_oracle():

@@ -8,12 +8,19 @@ pack/unpack pair must be BIT-exact (pure layout moves), and the bucketed
 every communicator, because bucketing defaults ON.
 """
 
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from chainermn_tpu.communicators import build_mesh, create_communicator
+from chainermn_tpu.communicators.overlap import (
+    ENV_OVERLAP,
+    ENV_OVERLAP_GRANULARITY,
+)
 from chainermn_tpu.communicators.packing import (
     DEFAULT_BUCKET_BYTES,
     ENV_BUCKET_BYTES,
@@ -329,11 +336,6 @@ def test_env_escape_hatch(mesh24, monkeypatch):
 
 
 def test_overlap_env_escape_hatch(mesh24, monkeypatch):
-    from chainermn_tpu.communicators.overlap import (
-        ENV_OVERLAP,
-        ENV_OVERLAP_GRANULARITY,
-    )
-
     comm = create_communicator("naive", mesh=mesh24)
     monkeypatch.delenv(ENV_OVERLAP, raising=False)
     assert comm.resolve_overlap() is True  # ON by default
@@ -360,6 +362,36 @@ def test_overlap_env_escape_hatch(mesh24, monkeypatch):
     assert g2.resolve_overlap_granularity() == 2
     with pytest.raises(ValueError, match="overlap_granularity"):
         create_communicator("naive", mesh=mesh24, overlap_granularity=0)
+
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _cell_config_files():
+    with open(os.path.join(REPO_ROOT, "BENCHMARK.json")) as f:
+        return [c["file"] for c in json.load(f)["configs"]]
+
+
+@pytest.mark.parametrize("config_file", _cell_config_files())
+def test_default_communicator_is_the_measured_one(config_file, monkeypatch):
+    """A communicator built with no arguments and no environment is the
+    program the ledger measured: every value a cell's configuration file
+    pins under ``program.communicator`` is what the defaults resolve to."""
+    from chainermn_tpu.communicators.quant import ENV_COMM_DTYPE
+
+    for env in (ENV_BUCKET_BYTES, ENV_OVERLAP, ENV_OVERLAP_GRANULARITY,
+                ENV_COMM_DTYPE):
+        monkeypatch.delenv(env, raising=False)
+    with open(os.path.join(REPO_ROOT, config_file)) as f:
+        pinned = json.load(f)["program"]["communicator"]
+    comm = create_communicator()
+    assert {
+        "name": comm.name,
+        "bucket_bytes": comm.resolve_bucket_bytes(),
+        "overlap": comm.resolve_overlap(),
+        "overlap_granularity": comm.resolve_overlap_granularity(),
+        "comm_dtype": comm.resolve_comm_dtype() or "none",
+    } == pinned
 
 
 #: reduction collectives each variant lowers PER BUCKET: one fused psum

@@ -337,31 +337,8 @@ def test_engine_plan_requires_mesh():
 
 
 # ---------------------------------------------------------------------------
-# Autotune layout dimension + CLI
+# CLI
 # ---------------------------------------------------------------------------
-
-
-def test_layout_search_space_axis_filtering():
-    from chainermn_tpu.tuning import layout_search_space
-
-    full = layout_search_space(("data", "model"))
-    assert full[0] == {"plan": "dp"}  # static default always first
-    assert {c["plan"] for c in full} == {"dp", "dp_tp", "fsdp", "tp",
-                                         "zero"}
-    data_only = layout_search_space(("data",))
-    assert data_only[0] == {"plan": "dp"}
-    assert {c["plan"] for c in data_only} == {"dp", "fsdp", "zero"}
-
-
-def test_layout_tuning_inert_under_pytest(dp_tp_mesh):
-    from chainermn_tpu.tuning import lookup_layout, tune_layout
-
-    rec = tune_layout(mesh=dp_tp_mesh, dry_run=True)
-    assert rec["kernel"] == "layout" and rec["dry_run"]
-    assert rec["candidates"][0] == {"plan": "dp"}
-    # runtime lookups never fire under pytest / off-TPU
-    assert lookup_layout(mesh=dp_tp_mesh, n_params=1 << 14, n_leaves=16,
-                         dtype="float32") is None
 
 
 def _run_cli(*argv):
