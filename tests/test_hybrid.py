@@ -208,6 +208,37 @@ def test_scan_geometry_reaches_the_sinks(tmp_path):
     assert len(rows) == 1 and {k: rows[0][k] for k in want} == want
 
 
+def test_conv_geometry_reaches_the_sinks(tmp_path):
+    """A traced convolution publishes its geometry once, with the
+    backward it compiles with: the tiles of the one-pass kernel."""
+    import json
+
+    from chainermn_tpu.observability import Reporter, step_log
+    from chainermn_tpu.observability import reporter as reporter_mod
+
+    f = jax.jit(jax.grad(
+        lambda x, k, b: causal_conv_silu(x, k, b).sum(), argnums=(0, 1, 2)))
+    args = (jnp.ones((3, 40, 200)), jnp.ones((4, 200)), jnp.zeros((200,)))
+    rep = Reporter()
+    path = str(tmp_path / "steps.jsonl")
+    with reporter_mod.scope(rep), step_log.recording(path):
+        f(*args)
+        f(*args)                        # no retrace: no second record
+    summary = rep.summary()
+    assert summary["counters"]["ssm_conv/calls"] == 1
+    # 40 tokens in one tile of 128 (a register of lanes), 200 channels in
+    # four blocks of 64 rows, three batch rows
+    want = {"seq": 40, "channels": 200, "taps": 4, "seq_tile": 128,
+            "channel_tile": 64, "grid_steps": 12}
+    gauges = {f"ssm_conv/{k}": v for k, v in want.items()}
+    gauges["ssm_conv/one_pass"] = 1
+    assert {n: g["value"] for n, g in summary["gauges"].items()} == gauges
+    rows = [json.loads(line) for line in open(path)]
+    rows = [r for r in rows if r["event"] == "conv_geometry"]
+    assert len(rows) == 1 and rows[0]["backward"] == "one_pass"
+    assert {k: rows[0][k] for k in want} == want
+
+
 @pytest.mark.parametrize("with_lse,form", [
     (False, "grad_in_forward"), (True, "recompute")])
 def test_loss_head_geometry_reaches_the_sinks(tmp_path, with_lse, form):
