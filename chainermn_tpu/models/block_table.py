@@ -1,15 +1,19 @@
 """The per-layer block table of :class:`~chainermn_tpu.models.transformer.
 TransformerLM`, and the functions that write one.
 
-A decoder is a list of residual blocks ``x + rm * mixer(norm(x))``, ``x +
-rm * ffn(norm(x))``.  What differs between architectures is *which* mixer,
-norm and FFN each layer has and a handful of scalars; a :class:`BlockTable`
-states exactly that, one :class:`LayerSpec` a layer, and the model builds
-its layers from it.  The GPT-2-style block the repo started with is one
-row (:func:`gpt2_table`); a published ``granitemoehybrid`` config (Mamba-2
-mixers with an attention layer among every few, RMSNorm, SwiGLU, no
-positions, scalar multipliers) is turned into its table by
-:func:`table_from_config`.
+A decoder is a list of layers, each one or two residual branches: ``x +
+rm * mixer(norm(x))``, then ``x + rm * ffn(norm(x))``; a row whose
+``mixer`` or ``ffn`` is ``"none"`` has the other branch only.  What
+differs between architectures is *which* mixer, norm and FFN each layer
+has and a handful of scalars; a :class:`BlockTable` states exactly that,
+one :class:`LayerSpec` a layer, and the model builds its layers from it.
+The GPT-2-style block the repo started with is one row
+(:func:`gpt2_table`); :func:`table_from_config` turns a published
+``config.json`` into its table, by ``model_type``: ``granitemoehybrid``
+(Mamba-2 mixers with an attention layer among every few, RMSNorm, SwiGLU,
+no positions, scalar multipliers, a tied head) and ``nemotron_h``
+(single-branch layers — a Mamba-2 mixer, a GQA layer or a sparse-expert
+FFN each — RMSNorm, squared ReLU, no positions, an untied head).
 
 Plain frozen dataclasses: hashable, so a table is a static field of the
 flax module.
@@ -20,9 +24,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Mapping, Optional, Tuple
 
-MIXERS = ("attention", "mamba2")
+MIXERS = ("attention", "mamba2", "none")
 NORMS = ("layernorm", "rmsnorm")
-FFNS = ("gelu", "swiglu")
+FFNS = ("gelu", "swiglu", "relu2", "experts", "none")
 POSITIONS = ("sinusoidal", "none")
 
 
@@ -32,7 +36,9 @@ class SSMSpec:
     channels, each with a ``d_head x d_state`` state; ``n_groups`` sets of
     B/C shared by ``n_heads / n_groups`` heads; a causal depthwise
     convolution of ``d_conv`` taps; ``chunk`` tokens a block of the
-    chunked scan (the sequence length must be a multiple of it)."""
+    chunked scan (the sequence length must be a multiple of it);
+    ``norm_groups`` equal parts of the channels the gated norm runs over
+    one by one (1: over all of them)."""
 
     n_heads: int
     d_head: int
@@ -40,6 +46,7 @@ class SSMSpec:
     n_groups: int = 1
     d_conv: int = 4
     chunk: int = 256
+    norm_groups: int = 1
 
     @property
     def d_inner(self) -> int:
@@ -51,18 +58,56 @@ class SSMSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class ExpertsSpec:
+    """A sparse-expert FFN: a router over ``n_experts`` (the published
+    count: its width), ``top_k`` chosen a token, none dropped; the
+    experts ``held`` here, ``(first, count)`` of the ``n_experts`` (all
+    of them, or one expert-parallel rank's share); each expert
+    ``w_down relu(w_up h)^2`` at width ``d_expert``, and a shared expert
+    of the same form at ``d_shared`` that every token passes.
+    The router scores by sigmoid, chooses by score + a per-expert
+    correction bias, and weighs the chosen by their scores, normalised
+    over the chosen, times ``scaling``."""
+
+    n_experts: int
+    top_k: int
+    d_expert: int
+    d_shared: int
+    held: Optional[Tuple[int, int]] = None      # None = (0, n_experts)
+    scaling: float = 1.0
+
+    def __post_init__(self):
+        first, count = self.experts_held
+        if not (0 <= first and count >= 1
+                and first + count <= self.n_experts):
+            raise ValueError(
+                f"held {self.held} is no (first, count) of "
+                f"{self.n_experts} experts")
+        if not 1 <= self.top_k <= self.n_experts:
+            raise ValueError(f"top_k {self.top_k} of {self.n_experts}")
+
+    @property
+    def experts_held(self) -> Tuple[int, int]:
+        return self.held or (0, self.n_experts)
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """One row of the table."""
 
     mixer: str = "attention"           # one of MIXERS
-    norm: str = "layernorm"            # one of NORMS (both norms of the row)
+    norm: str = "layernorm"            # one of NORMS (every norm of the row)
     ffn: str = "gelu"                  # "gelu": wo(gelu(wi x));
                                        # "swiglu": wo(silu(a) * b), [a|b] = wi x
+                                       # "relu2": wo(relu(wi x)^2)
+                                       # "experts": see ExpertsSpec
     d_ff: int = 2048
     n_heads: int = 8                   # attention rows
     n_kv_heads: Optional[int] = None   # GQA/MQA (divides n_heads)
+    d_head: Optional[int] = None       # None = d_model / n_heads
     attn_scale: Optional[float] = None  # softmax scale; None = 1/sqrt(d_head)
     ssm: Optional[SSMSpec] = None      # mamba2 rows
+    experts: Optional[ExpertsSpec] = None   # "experts" rows
     residual_multiplier: float = 1.0   # rm above
     norm_eps: float = 1e-6
 
@@ -75,13 +120,19 @@ class LayerSpec:
                                  f"got {value!r}")
         if (self.mixer == "mamba2") != (self.ssm is not None):
             raise ValueError("a mamba2 row, and only it, carries an SSMSpec")
+        if (self.ffn == "experts") != (self.experts is not None):
+            raise ValueError("an experts row, and only it, carries an "
+                             "ExpertsSpec")
+        if self.mixer == "none" and self.ffn == "none":
+            raise ValueError("a row has a mixer, an ffn or both")
 
 
 @dataclasses.dataclass(frozen=True)
 class BlockTable:
     """The layers in order, and what surrounds them: the positions added
     to the embedding, ``x = embedding_multiplier * E[token]``, the final
-    norm and ``logits = norm(x) E^T / logits_scaling``."""
+    norm and ``logits = norm(x) W^T / logits_scaling``, ``W`` the
+    embedding table itself (``tied_head``) or a matrix of its own."""
 
     layers: Tuple[LayerSpec, ...]
     positions: str = "sinusoidal"      # one of POSITIONS
@@ -89,6 +140,7 @@ class BlockTable:
     logits_scaling: float = 1.0
     final_norm: str = "layernorm"
     norm_eps: float = 1e-6
+    tied_head: bool = True
 
     def __post_init__(self):
         if self.positions not in POSITIONS:
@@ -110,22 +162,54 @@ def gpt2_table(n_layers: int, n_heads: int, d_ff: int,
     return BlockTable(layers=(row,) * n_layers)
 
 
-def table_from_config(config: Mapping, n_layers: Optional[int] = None
-                      ) -> BlockTable:
-    """The table of a published ``granitemoehybrid`` ``config.json`` (the
-    dense members of the family: ``num_local_experts`` 0), by its own keys.
-    ``n_layers`` keeps the first so many entries of ``layer_types`` (a
-    pipeline stage, a cut to fit); None keeps ``num_hidden_layers``.
+def _refuse(refused):
+    for bad, what in refused:
+        if bad:
+            raise ValueError(f"table_from_config: this system does not "
+                             f"build {what}")
 
-    What this system cannot build raises here, by key: sparse experts,
-    rotary positions, biases on the projections, another norm or
-    activation, an attention head that is not ``hidden_size /
-    num_attention_heads`` wide."""
-    if config.get("model_type") != "granitemoehybrid":
+
+def _first_layers(kinds, n_layers):
+    if n_layers is None:
+        return kinds
+    if not 1 <= n_layers <= len(kinds):
+        raise ValueError(f"n_layers must be in [1, {len(kinds)}]")
+    return kinds[:n_layers]
+
+
+def table_from_config(config: Mapping, n_layers: Optional[int] = None,
+                      experts_held: Optional[Tuple[int, int]] = None
+                      ) -> BlockTable:
+    """The table of a published ``config.json``, by its own keys.  Two
+    families are read, by ``model_type``: ``granitemoehybrid`` (its dense
+    members: ``num_local_experts`` 0) and ``nemotron_h``.  ``n_layers``
+    keeps the first so many layers (a pipeline stage, a cut to fit); None
+    keeps ``num_hidden_layers``.  ``experts_held`` is the ``(first,
+    count)`` of the published experts this rank holds in every expert
+    layer (None: all): the deployment's, not a published key.
+
+    What this system cannot build raises here, by key."""
+    readers = {"granitemoehybrid": _granite_table,
+               "nemotron_h": _nemotron_h_table}
+    reader = readers.get(config.get("model_type"))
+    if reader is None:
         raise ValueError(
-            f"table_from_config reads granitemoehybrid configs, got "
+            f"table_from_config reads {sorted(readers)} configs, got "
             f"model_type {config.get('model_type')!r}")
-    refused = [
+    if reader is _granite_table:
+        if experts_held is not None:
+            raise ValueError("table_from_config: a granitemoehybrid table "
+                             "has no experts to hold")
+        return reader(config, n_layers)
+    return reader(config, n_layers, experts_held)
+
+
+def _granite_table(config, n_layers):
+    """``granitemoehybrid``.  Refused by key: sparse experts, rotary
+    positions, biases on the projections, another norm or activation, an
+    untied head, ``mamba_n_heads x mamba_d_head`` that is not
+    ``mamba_expand x hidden_size``."""
+    _refuse([
         (config.get("num_local_experts", 0) != 0, "num_local_experts > 0 "
          "(sparse experts)"),
         (config.get("position_embedding_type") != "nope",
@@ -142,19 +226,12 @@ def table_from_config(config: Mapping, n_layers: Optional[int] = None
         (config.get("mamba_expand", 2) * config["hidden_size"]
          != config["mamba_n_heads"] * config["mamba_d_head"],
          "mamba_n_heads x mamba_d_head != mamba_expand x hidden_size"),
-    ]
-    for bad, what in refused:
-        if bad:
-            raise ValueError(f"table_from_config: this system does not "
-                             f"build {what}")
+    ])
     kinds = list(config["layer_types"])
     if len(kinds) != config["num_hidden_layers"]:
         raise ValueError("layer_types does not list num_hidden_layers "
                          "entries")
-    if n_layers is not None:
-        if not 1 <= n_layers <= len(kinds):
-            raise ValueError(f"n_layers must be in [1, {len(kinds)}]")
-        kinds = kinds[:n_layers]
+    kinds = _first_layers(kinds, n_layers)
     ssm = SSMSpec(
         n_heads=config["mamba_n_heads"], d_head=config["mamba_d_head"],
         d_state=config["mamba_d_state"], n_groups=config["mamba_n_groups"],
@@ -179,3 +256,72 @@ def table_from_config(config: Mapping, n_layers: Optional[int] = None
         embedding_multiplier=float(config["embedding_multiplier"]),
         logits_scaling=float(config["logits_scaling"]),
         final_norm="rmsnorm", norm_eps=float(config["rms_norm_eps"]))
+
+
+def _nemotron_h_table(config, n_layers, experts_held):
+    """``nemotron_h``: one branch a layer, by ``hybrid_override_pattern``
+    — ``M`` a Mamba-2 mixer at ``mamba_num_heads x mamba_head_dim``
+    channels, its gated norm over each of the ``n_groups`` parts; ``*``
+    GQA at the config's own ``head_dim``, no rotary embedding; ``E`` the
+    sparse experts; ``-`` a squared-ReLU FFN at ``intermediate_size`` —
+    RMSNorm, no positions, no multipliers.  Refused by key: any bias but
+    the convolution's, another activation, router groups, weights not
+    normalised over the chosen, more or fewer than one shared expert."""
+    _refuse([
+        (bool(config.get("attention_bias")), "attention_bias"),
+        (bool(config.get("mlp_bias")), "mlp_bias"),
+        (bool(config.get("use_bias")), "use_bias"),
+        (bool(config.get("mamba_proj_bias")), "mamba_proj_bias"),
+        (not config.get("use_conv_bias", True), "no use_conv_bias"),
+        (config.get("mlp_hidden_act") != "relu2",
+         "mlp_hidden_act other than relu2"),
+        (config.get("mamba_hidden_act") != "silu",
+         "mamba_hidden_act other than silu"),
+        (config.get("n_group", 1) != 1 or config.get("topk_group", 1) != 1,
+         "n_group / topk_group other than 1 (router groups)"),
+        (config.get("n_shared_experts", 1) != 1,
+         "n_shared_experts other than 1"),
+        (not config.get("norm_topk_prob", True),
+         "norm_topk_prob false (router weights not normalised)"),
+        (config.get("norm_eps", config["layer_norm_epsilon"])
+         != config["layer_norm_epsilon"],
+         "norm_eps other than layer_norm_epsilon"),
+    ])
+    pattern = config["hybrid_override_pattern"]
+    if len(pattern) != config["num_hidden_layers"]:
+        raise ValueError("hybrid_override_pattern does not list "
+                         "num_hidden_layers entries")
+    kinds = _first_layers(pattern, n_layers)
+    eps = float(config["layer_norm_epsilon"])
+    groups = config["n_groups"]
+    one_branch = dict(norm="rmsnorm", norm_eps=eps)
+    rows = {
+        "M": LayerSpec(mixer="mamba2", ffn="none", ssm=SSMSpec(
+            n_heads=config["mamba_num_heads"],
+            d_head=config["mamba_head_dim"],
+            d_state=config["ssm_state_size"], n_groups=groups,
+            d_conv=config["conv_kernel"], chunk=config["chunk_size"],
+            norm_groups=groups), **one_branch),
+        "*": LayerSpec(mixer="attention", ffn="none",
+                       n_heads=config["num_attention_heads"],
+                       n_kv_heads=config["num_key_value_heads"],
+                       d_head=config["head_dim"], **one_branch),
+        "-": LayerSpec(mixer="none", ffn="relu2",
+                       d_ff=config["intermediate_size"], **one_branch),
+    }
+    if "E" in kinds:
+        rows["E"] = LayerSpec(mixer="none", ffn="experts", experts=ExpertsSpec(
+            n_experts=config["n_routed_experts"],
+            top_k=config["num_experts_per_tok"],
+            d_expert=config["moe_intermediate_size"],
+            d_shared=config["moe_shared_expert_intermediate_size"],
+            held=experts_held,
+            scaling=float(config["routed_scaling_factor"])), **one_branch)
+    unknown = sorted(set(kinds) - set(rows))
+    if unknown:
+        raise ValueError(f"hybrid_override_pattern holds kinds this system "
+                         f"does not build: {unknown}")
+    return BlockTable(
+        layers=tuple(rows[k] for k in kinds), positions="none",
+        final_norm="rmsnorm", norm_eps=eps,
+        tied_head=bool(config["tie_word_embeddings"]))

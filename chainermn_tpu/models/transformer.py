@@ -24,11 +24,12 @@ import numpy as np
 
 from chainermn_tpu.models.block_table import (
     BlockTable,
+    ExpertsSpec,
     LayerSpec,
     SSMSpec,
     gpt2_table,
 )
-from chainermn_tpu.observability.spans import named_scope
+from chainermn_tpu.observability.spans import named_scope, telemetry_active
 
 
 def sinusoidal_positions(max_len: int, d_model: int) -> np.ndarray:
@@ -68,11 +69,13 @@ class MultiHeadAttention(nn.Module):
     scale: Optional[float] = None   # softmax scale; None = 1/sqrt(d_head).
                                     # An ``attention_fn`` must have been
                                     # built with the same one
+    d_head: Optional[int] = None    # a head's width; None = d_model /
+                                    # n_heads
 
     @nn.compact
     def __call__(self, q_in, kv_in, mask=None, *, block_tables=None,
                  seq_lens=None):
-        d_head = self.d_model // self.n_heads
+        d_head = self.d_head or self.d_model // self.n_heads
         n_kv = self.n_kv_heads or self.n_heads
         if self.n_heads % n_kv:
             raise ValueError(
@@ -360,6 +363,20 @@ class FeedForward(nn.Module):
         return nn.Dense(self.d_model, dtype=self.dtype, use_bias=False, name="wo")(h)
 
 
+class Relu2FeedForward(nn.Module):
+    """``wo(relu(wi x)^2)``: two matrices, no gate."""
+
+    d_model: int
+    d_ff: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        h = nn.Dense(self.d_ff, dtype=self.dtype, use_bias=False, name="wi")(x)
+        return nn.Dense(self.d_model, dtype=self.dtype, use_bias=False,
+                        name="wo")(jnp.square(nn.relu(h)))
+
+
 class GatedFeedForward(nn.Module):
     """SwiGLU: ``wo(silu(a) * b)`` with ``[a | b] = wi x``, one input
     matrix of width ``2 d_ff``."""
@@ -388,13 +405,107 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
+class GroupedRMSNorm(nn.Module):
+    """RMSNorm over each of ``groups`` equal parts of the last axis, one
+    learned scale a channel; statistics in float32."""
+
+    groups: int
+    epsilon: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        d = x.shape[-1]
+        scale = self.param("scale", nn.initializers.ones, (d,), jnp.float32)
+        parts = x.astype(jnp.float32).reshape(
+            x.shape[:-1] + (self.groups, d // self.groups))
+        parts = parts * jax.lax.rsqrt(
+            jnp.mean(jnp.square(parts), axis=-1, keepdims=True)
+            + self.epsilon)
+        return (parts.reshape(x.shape) * scale).astype(self.dtype)
+
+
+class ExpertLayer(nn.Module):
+    """A sparse-expert FFN for one expert-parallel rank (an
+    :class:`ExpertsSpec` row): the router over all the published experts
+    in float32, the (token, choice) pairs of the experts held here sorted
+    by expert and put through ``w_down relu(w_up h)^2`` as grouped
+    matmuls (both stacks (count, d_expert, d_model): ``experts_up`` holds
+    its matrices output-major), each result added back times its router
+    weight, plus the shared expert (a :class:`Relu2FeedForward`) over
+    every token.  No token is dropped
+    (:mod:`chainermn_tpu.parallel.moe_dropless`); what the absent experts
+    would add is left out.  ``sow``s the chosen experts as
+    ``intermediates/chosen`` for whoever asks for that collection."""
+
+    d_model: int
+    spec: ExpertsSpec
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, h):
+        from chainermn_tpu.ops.grouped_matmul import (
+            TILE_ROWS,
+            grouped_relu2_mlp,
+            weight_blocks,
+        )
+        from chainermn_tpu.ops.ssd import publish_geometry
+        from chainermn_tpu.parallel import moe_dropless as moe
+
+        z, d = self.spec, self.d_model
+        first, count = z.experts_held
+        f32 = jnp.float32
+        stacked = nn.initializers.lecun_normal(batch_axis=(0,))
+        stacked_out_major = nn.initializers.lecun_normal(
+            in_axis=-1, out_axis=-2, batch_axis=(0,))
+        lead = h.shape[:-1]
+        tokens = int(np.prod(lead))
+        n_rows = moe.rows_bound(tokens * z.top_k, count, z.n_experts)
+        if telemetry_active():
+            publish_geometry("moe_geometry", "moe", {
+                "experts": z.n_experts, "experts_held": count,
+                "top_k": z.top_k, "tokens": tokens,
+                "pair_rows": tokens * z.top_k,
+                "buffer_rows": TILE_ROWS * moe.buffer_tiles(n_rows, count),
+                "tile_rows": TILE_ROWS,
+                **dict(zip(("d_block", "expert_block"), weight_blocks(
+                    d, z.d_expert, jnp.dtype(self.dtype).itemsize)))},
+                form="pallas_tile_aligned")
+        with named_scope("moe-layer"):
+            x = h.reshape(tokens, d)
+            with named_scope("moe-route"):
+                chosen, weight = moe.route(
+                    x, self.param("router", nn.initializers.lecun_normal(),
+                                  (d, z.n_experts), f32),
+                    self.param("router_bias", nn.initializers.zeros,
+                               (z.n_experts,), f32),
+                    top_k=z.top_k, scaling=z.scaling)
+                self.sow("intermediates", "chosen", chosen)
+                plan = moe.dispatch(chosen, (first, count), n_rows)
+            with named_scope("moe-dispatch"):
+                rows = moe.gather_rows(x, plan)
+            routed = grouped_relu2_mlp(
+                rows,
+                self.param("experts_up", stacked_out_major,
+                           (count, z.d_expert, d), f32),
+                self.param("experts_down", stacked, (count, z.d_expert, d),
+                           f32), plan.tile_group, plan.n_live)
+            with named_scope("moe-dispatch"):
+                out = moe.combine(routed, weight, plan, tokens)
+            with named_scope("moe-shared"):
+                out = out + Relu2FeedForward(d, z.d_shared, self.dtype,
+                                             name="shared")(x)
+            return out.astype(self.dtype).reshape(lead + (d,))
+
+
 class Mamba2Mixer(nn.Module):
     """The Mamba-2 mixer (arXiv:2405.21060), as the ``granitemoehybrid``
     family lays it out: ``[z | xBC | dt] = in_proj(h)``; a causal depthwise
     convolution and SiLU over ``xBC = [x | B | C]``; ``dt = softplus(dt +
     dt_bias)``, ``A = -exp(A_log)``; the state-space scan
     (:func:`chainermn_tpu.ops.ssd.ssd_scan`); the gated norm
-    ``RMSNorm(y * silu(z))`` over all channels; ``out_proj``.  Every
+    ``RMSNorm(y * silu(z))`` over all channels, or over each of
+    ``ssm.norm_groups`` equal parts of them; ``out_proj``.  Every
     sequence starts from a zero state (no document boundaries inside a
     row, no recurrent cache: training and whole-sequence evaluation)."""
 
@@ -436,15 +547,19 @@ class Mamba2Mixer(nn.Module):
                 self.param("D", nn.initializers.ones, heads, f32),
                 chunk=z.chunk)
             y = y.reshape(lead + (z.d_inner,)).astype(f32)
-            y = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
-                           name="norm")(y * nn.silu(gate.astype(f32)))
+            norm = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                              name="norm") if z.norm_groups == 1 else (
+                GroupedRMSNorm(z.norm_groups, self.norm_eps, self.dtype,
+                               name="norm"))
+            y = norm(y * nn.silu(gate.astype(f32)))
             return nn.Dense(self.d_model, dtype=self.dtype, use_bias=False,
                             name="out_proj")(y)
 
 
 class Block(nn.Module):
-    """One layer, built from its row of the block table:
-    ``x + rm * mixer(norm(x))`` then ``x + rm * ffn(norm(x))``."""
+    """One layer, built from its row of the block table: ``x + rm *
+    mixer(norm(x))`` where the row has a mixer, then ``x + rm *
+    ffn(norm(x))`` where it has an FFN."""
 
     d_model: int
     row: LayerSpec
@@ -472,29 +587,33 @@ class Block(nn.Module):
                     row.residual_multiplier, branch.dtype)
             return x + branch
 
-        h = norm()(x)
         if row.mixer == "attention":
-            mixed = MultiHeadAttention(
+            h = norm()(x)
+            x = residual(x, MultiHeadAttention(
                 self.d_model, row.n_heads, self.dtype, self.attention_fn,
                 decode=self.decode, cache_len=self.cache_len,
                 n_kv_heads=row.n_kv_heads, paged=self.paged,
                 page_count=self.page_count, page_size=self.page_size,
                 kv_dtype=self.kv_dtype, sp_axis=self.sp_axis,
-                scale=row.attn_scale,
-            )(h, h, mask, block_tables=block_tables, seq_lens=seq_lens)
-        else:
+                scale=row.attn_scale, d_head=row.d_head,
+            )(h, h, mask, block_tables=block_tables, seq_lens=seq_lens))
+        elif row.mixer == "mamba2":
             if self.decode or self.paged is not None:
                 raise ValueError(
                     "a mamba2 layer keeps no recurrent state between "
                     "calls: incremental decoding and the paged KV cache "
                     "are built for attention layers only"
                 )
-            mixed = Mamba2Mixer(self.d_model, row.ssm, row.norm_eps,
-                                self.dtype)(h)
-        x = residual(x, mixed)
-        h = norm()(x)
-        ffn = FeedForward if row.ffn == "gelu" else GatedFeedForward
-        return residual(x, ffn(self.d_model, row.d_ff, self.dtype)(h))
+            x = residual(x, Mamba2Mixer(self.d_model, row.ssm, row.norm_eps,
+                                        self.dtype)(norm()(x)))
+        if row.ffn == "experts":
+            return residual(x, ExpertLayer(self.d_model, row.experts,
+                                           self.dtype)(norm()(x)))
+        if row.ffn == "none":
+            return x
+        ffn = {"gelu": FeedForward, "swiglu": GatedFeedForward,
+               "relu2": Relu2FeedForward}[row.ffn]
+        return residual(x, ffn(self.d_model, row.d_ff, self.dtype)(norm()(x)))
 
 
 def EncoderLayer(d_model: int, n_heads: int, d_ff: int,
@@ -633,7 +752,10 @@ class TransformerLM(nn.Module):
         materializes the ``(B*S, vocab)`` logits the default
         ``embed.attend`` path does.  A table's ``logits_scaling`` is
         divided into the hidden states then, so that ``hidden @ E^T`` are
-        the logits on both paths.
+        the logits on both paths.  A table with an untied head
+        (``tied_head=False``) keeps its matrix as the parameter
+        ``lm_head``, (vocab, d_model) like the table: hand that one to
+        the loss.
 
         ``inputs_embeds``: optional pre-computed ``(B, S, d_model)`` token
         embeddings replacing the internal table lookup (positions are
@@ -685,8 +807,16 @@ class TransformerLM(nn.Module):
         # the (S, S) mask, which at long context is the largest host
         # constant in the program (S=16k: 256 MiB as bool).
         mask = None if self.attention_fn is not None else causal_mask(S)
+        policy = None
+        if any(r.ffn == "experts" for r in table.layers):
+            # One class for every row: these names are the expert layers'.
+            from chainermn_tpu.parallel.moe_dropless import REMAT_SAVES
+
+            policy = jax.checkpoint_policies.save_only_these_names(
+                *REMAT_SAVES)
         layer_cls = (
-            nn.remat(Block, static_argnums=()) if self.remat else Block
+            nn.remat(Block, static_argnums=(), policy=policy)
+            if self.remat else Block
         )
         for i, row in enumerate(table.layers):
             x = layer_cls(
@@ -701,11 +831,15 @@ class TransformerLM(nn.Module):
                     else nn.RMSNorm)
         x = norm_cls(epsilon=table.norm_eps, dtype=self.dtype,
                      name="final_norm")(x)
+        head = None if table.tied_head else self.param(
+            "lm_head", nn.initializers.normal(0.02),
+            (self.vocab, self.d_model), jnp.float32)
         if return_hidden:
             if table.logits_scaling != 1.0:
                 x = x / jnp.asarray(table.logits_scaling, x.dtype)
             return x
-        logits = embed.attend(x.astype(jnp.float32))
+        logits = (embed.attend(x.astype(jnp.float32)) if head is None
+                  else x.astype(jnp.float32) @ head.T)
         if table.logits_scaling != 1.0:
             logits = logits / table.logits_scaling
         return logits

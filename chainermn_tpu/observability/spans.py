@@ -55,11 +55,18 @@ _ALLREDUCE_STAGE = re.compile(r"^grad-stage\d+$")
 #: the two fused-CE scans, paged decode attention; a Mamba-2 mixer from
 #: its input to its output projection (``models/transformer.py``) and,
 #: nested in it, the chunked state-space scan, forward and backward, and
-#: the causal convolution with its SiLU (``ops/ssd.py``).  The innermost
-#: name on an op's path is its region.
+#: the causal convolution with its SiLU (``ops/ssd.py``); a sparse-expert
+#: layer from its router to the sum of its routed and shared parts
+#: (``models/transformer.py``) and, nested in it, its parts: the router
+#: (matmul, sigmoid, top-k, the sort of the pairs by expert), the gather
+#: of the held experts' rows and their weighted scatter back, the grouped
+#: matmuls of the held experts (``ops/grouped_matmul.py``, forward and
+#: backward), the shared expert.  The innermost name on an op's path is
+#: its region.
 KERNEL_REGIONS = (
     "flash-fwd", "flash-bwd-dq", "flash-bwd-dkv", "fused-ce",
     "paged-decode-attn", "mamba-mixer", "ssd-scan", "ssm-conv",
+    "moe-layer", "moe-route", "moe-dispatch", "moe-experts", "moe-shared",
 )
 
 #: What the jitted programs compile as (``jit_<name>`` in a capture's

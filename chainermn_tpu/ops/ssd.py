@@ -331,7 +331,7 @@ def causal_conv_silu(x, kernel, bias):
             f"than the {_CONV_HALO} tokens a tile sees of its neighbour")
     if telemetry_active():
         (B, S, C), (ts, tc) = x.shape, conv_tiles(*x.shape[1:])
-        _publish_geometry("conv_geometry", "ssm_conv", {
+        publish_geometry("conv_geometry", "ssm_conv", {
             "seq": S, "channels": C, "taps": kernel.shape[0],
             "seq_tile": ts, "channel_tile": tc,
             "grid_steps": B * -(-S // ts) * -(-C // tc)},
@@ -444,7 +444,7 @@ def _ssd_bwd(chunk, saved, dy):
 _ssd.defvjp(_ssd_fwd, _ssd_bwd)
 
 
-def _publish_geometry(event: str, prefix: str, record: dict,
+def publish_geometry(event: str, prefix: str, record: dict,
                       **labels) -> None:
     """One geometry record a traced op (at TRACE time, beside
     ``flash_geometry``): a row ``event`` of the StepRecorder,
@@ -483,7 +483,7 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int):
             f"ssd_scan: B {B.shape} and C {C.shape} must agree, their "
             f"groups ({G}) dividing the heads ({H})")
     if telemetry_active():
-        _publish_geometry("ssd_geometry", "ssd", {
+        publish_geometry("ssd_geometry", "ssd", {
             "chunk": chunk, "chunks": S // chunk, "heads": H, "d_head": P,
             "d_state": N, "groups": G})
     r = H // G

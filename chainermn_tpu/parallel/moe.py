@@ -1,17 +1,28 @@
-"""Expert parallelism — mixture-of-experts with all-to-all token routing.
+"""Expert parallelism with a capacity factor — mixture-of-experts with
+all-to-all token routing, for layouts where every device sends a FIXED
+number of slots to every expert.
 
-Net-new (SURVEY §2.5: "EP/MoE: reference has nothing").  The TPU-native
-shape: experts are sharded one-per-device over a mesh axis, tokens are
-routed to their expert's device with ``lax.all_to_all``, expert FFNs run
-batched on the MXU, and a second all-to-all routes results back — the
-standard Switch-style EP layout, built on the same differentiable
+Net-new (SURVEY §2.5: "EP/MoE: reference has nothing").  One expert (or
+an equal group of experts) lives on each device of a mesh axis; tokens
+are routed GShard-style, top-1 or top-2 by softmax, into an ``(E, C, T)``
+one-hot dispatch of ``capacity`` slots an expert, sent to the experts'
+devices with ``lax.all_to_all``, run there batched on the MXU, and routed
+back by a second all-to-all — built on the same differentiable
 ``alltoall`` primitive the reference exposed as a collective Function
 (REF:chainermn/functions/collective_communication.py) without ever using
 it this way.
 
 Capacity-based dispatch keeps shapes static for XLA: each device sends
 exactly ``capacity`` token slots to every expert (padded with zeros,
-weighted 0), so the program is retrace-free regardless of routing skew.
+weighted 0), so the program is retrace-free regardless of routing skew —
+and a token whose expert is full is DROPPED.  That, and a dispatch tensor
+that grows with experts x capacity x tokens, is why this path is for few
+experts and small top-k (its tests; no benchmark cell).  A published
+top-k-of-many router that drops nothing — sigmoid scores, 6 of 128, a
+rank that holds 8 of them — is ``parallel/moe_dropless.py``: pairs sorted
+by expert and grouped matmuls over the experts held, no capacity, and so
+far no exchange between ranks (this module's all-to-all is what it would
+borrow).
 """
 
 from __future__ import annotations

@@ -13,8 +13,8 @@ Modes (rank/incarnation read from CHAINERMN_TPU_ELASTIC_* env):
 * ``crash_always``  — exit 3 every incarnation (restart-budget tests).
 * ``crash_rank1_once`` — rank 1 exits 3 in incarnation 0; everyone
   else loops ``ok``-style (rescale tests).
-* ``teardown``      — incarnation 0: rank 1 exits 3 immediately while
-  rank 0 IGNORES SIGTERM and beats forever (the supervisor must
+* ``teardown``      — incarnation 0: rank 1 exits 3 as soon as rank 0
+  beats, while rank 0 IGNORES SIGTERM and beats forever (the supervisor must
   escalate to SIGKILL within its grace window); later incarnations
   ``ok``.
 * ``stall``         — incarnation 0: rank 1 stops beating after 2
@@ -51,6 +51,14 @@ def main():
         sys.exit(3)
     if mode == "crash_always":
         sys.exit(3)
+    if mode == "teardown" and first and rank == 1 and hb:
+        # Crash only once rank 0 beats: it ignores SIGTERM from before its
+        # first beat, and on a loaded machine it may still be starting
+        # when this rank is up (then SIGTERM would end it, and the
+        # supervisor would have nobody to SIGKILL).
+        peer, deadline = hb[:-1] + "0", time.time() + 8.0
+        while not os.path.exists(peer) and time.time() < deadline:
+            time.sleep(0.01)
     if mode in ("crash_rank1_once", "teardown") and first and rank == 1:
         sys.exit(3)
     if mode == "teardown" and first and rank == 0:

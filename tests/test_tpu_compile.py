@@ -203,3 +203,38 @@ def test_conv_kernels_are_one_pass_over_their_operands(one_chip, channels,
              * jnp.dtype(dtype).itemsize)
     assert compiled.cost_analysis()["bytes accessed"] <= 1.5 * moved
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("tile_rows", [256, 512])
+def test_grouped_matmuls_compile_at_the_expert_cells_widths(one_chip,
+                                                            tile_rows):
+    """The held experts' two matrices at the published widths (2688 ->
+    1856 -> 2688: 1856 is no multiple of 128, its blocks span the axis),
+    the forward product, its transposed twin and the weights' gradient,
+    four Mosaic calls, inside the VMEM limit the calls state."""
+    gm = importlib.import_module("chainermn_tpu.ops.grouped_matmul")
+    n_tiles, d, f, held = 24576 // tile_rows + 8, 2688, 1856, 8
+
+    def arr(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(x, w_up, w_down, tile_group, n_live):
+        # the public op picks interpret mode off the chip: compile its
+        # three calls as the chip would run them, both stacks (held, f, d)
+        hidden = gm._gmm_call(x, w_up.astype(x.dtype), tile_group, n_live,
+                              transpose_w=True, interpret=False)
+        out = gm._gmm_call(hidden, w_down.astype(x.dtype), tile_group,
+                           n_live, transpose_w=False, interpret=False)
+        dx = gm._gmm_call(out, w_down.astype(x.dtype), tile_group, n_live,
+                          transpose_w=True, interpret=False)
+        dw = gm._dw_call(hidden, out, tile_group, n_live, n_groups=held,
+                         interpret=False)
+        return dx, dw
+
+    compiled = jax.jit(step).lower(
+        arr(n_tiles * tile_rows, d), arr(held, f, d, dt=jnp.float32),
+        arr(held, f, d, dt=jnp.float32), arr(n_tiles, dt=jnp.int32),
+        arr(1, dt=jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 4
+    rows_mb = n_tiles * tile_rows * d * 2 / 1e6
+    assert compiled.memory_analysis().temp_size_in_bytes / 1e6 < 4 * rows_mb
