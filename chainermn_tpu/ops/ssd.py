@@ -20,13 +20,23 @@ block to block.  Decays, ``dt``, the running sums and the state are
 float32; the matrix products take their operands in the activations'
 type and accumulate in float32.
 
-The forward is a loop over the blocks that keeps nothing but the
-state each block started from (``S/Q`` states of ``H x P x N`` floats);
-the backward walks the blocks in reverse, rebuilds each block's decay
-matrix from its inputs and carries the state's cotangent.  The ``Q x Q``
-matrices of a block therefore never outlive the block, in either pass:
-at S=8192, 64 heads and Q=256 that is 33 MB a block alive instead of
-1 GB a layer.
+Each pass is ONE Mosaic kernel (``ssd-fwd``, ``ssd-bwd``) whose grid
+walks (batch row, block, group, run of heads) in order: the state of
+every head (in backward its cotangent) is carried from block to block in
+VMEM, and a block's decay matrix, ``C B^T`` and masked weights are made
+and used there (the running sums are made beside the call: a product with
+the triangle, an array of ``dt``'s size).  The forward writes ``y`` and
+the state each block started from (``S/Q`` states of ``H x P x N`` floats,
+kept only for a backward pass); the backward walks the blocks in reverse,
+rebuilds each block's decay from the sums and writes ``dx``, ``ddt``,
+``dB``, ``dC`` a block and ``dD`` summed.  The kernels hold the tokens on
+the lanes — the layout the convolution's kernels hand their result in and
+the compiler keeps a mixer's activations in (d_head 64 is half a register
+of lanes) — so a head is a run of 64 sublanes, sliced at no cost, and no
+transposing copy stands between the two.  A block's ``Q x Q`` matrices
+are worked in tiles of 128: only a tile on the diagonal takes an
+exponential an entry (:func:`_tiles`).  :func:`ssd_tiles` is the one rule
+for the heads a grid step holds, from the operands' shapes.
 """
 
 from __future__ import annotations
@@ -339,106 +349,490 @@ def causal_conv_silu(x, kernel, bias):
     return _conv_silu(x, kernel, bias)
 
 
-def _block(h_prev, x, dt, B, C, A, D):
-    """One block of ``Q`` tokens, every batch row and head at once, heads
-    before tokens (the layout the matrix unit wants them in).
+#: Heads a grid step of the scan's kernels holds at most: a run of heads
+#: shares its group's ``B`` and ``C`` blocks and one ``C B^T``, and its
+#: state products are one matmul of ``heads x d_head`` rows.
+_SSD_HEADS = 16
+#: One register of lanes: the side of a tile of a block's ``Q x Q`` matrices.
+_SSD_TILE = 128
 
-    ``h_prev`` (b, G, r, P, N) float32; ``x`` (b, G, r, Q, P); ``dt``
-    (b, G, r, Q) float32; ``B``, ``C`` (b, G, Q, N); ``A``, ``D`` (G, r)
-    float32 — ``G`` groups of ``r`` heads.  Returns ``(h_next, y)``, ``y``
-    float32 (b, G, r, Q, P) with the skip ``D x`` added."""
-    f32, op = jnp.float32, x.dtype
-    Q = x.shape[3]
-    live = jnp.tril(jnp.ones((Q, Q), bool))               # [t, s]: s <= t
-    # the running sum as a (tiny) product with the triangle, at full
-    # precision: a ``cumsum`` lowers to reduce-windows that lose their
-    # scope and cost more
-    cs = jnp.einsum("bgrs,ts->bgrt", dt * A[..., None], live.astype(f32),
-                    precision=lax.Precision.HIGHEST)
-    gap = cs[..., :, None] - cs[..., None, :]             # cs_t - cs_s
-    decay = jnp.exp(jnp.where(live, gap, -jnp.inf))       # (b, G, r, Q, Q)
-    cb = jnp.einsum("bgqn,bgsn->bgqs", C, B, preferred_element_type=f32)
-    weights = (cb[:, :, None] * decay).astype(op)
-    xdt = x.astype(f32) * dt[..., None]
-    y = jnp.einsum("bgrqs,bgrsp->bgrqp", weights, xdt.astype(op),
-                   preferred_element_type=f32)
-    carried = jnp.einsum("bgqn,bgrpn->bgrqp", C, h_prev.astype(op),
+_HIGHEST = lax.Precision.HIGHEST
+
+
+def _ssd_vmem(hb, chunk, heads, d_head, d_state, itemsize):
+    """VMEM bytes of the backward kernel (the larger of the two) at
+    ``hb`` heads a grid step: its blocks twice (the pipeline's two
+    buffers), the carried cotangent of every head's state, its scratch,
+    and what the compiler keeps of the body's values (the run's state and
+    its cotangent, a ``(rows, Q)`` value and eight tiles: fitted from
+    above to the limits the kernel compiles under at both cells'
+    geometries, PR 31: 13.6 MiB for 13.8 counted at 16 heads of chunk
+    256, 5.0 for 6.2 at 8 of chunk 128)."""
+    rows, Q, N = hb * d_head, chunk, d_state
+    T, nt = _tiles(Q)
+    blocks = (3 * rows * Q * itemsize         # x, dy, dx
+              + rows * N * 4                  # the block's starting state
+              + 4 * N * Q * itemsize          # B, C, dB, dC
+              + 4 * hb * Q * 4                # dt, cs, ddt, dcs
+              + Q * _SSD_TILE * 4)            # cs, a column a head
+    scratch = (heads * d_head * N * 4         # dH of every head
+               + 2 * rows * Q * (4 + itemsize)
+               + nt * (nt - 1) * rows * T * itemsize
+               + 2 * N * Q * 4 + Q * Q * (8 + itemsize))
+    body = 2 * rows * N * 4 + rows * Q * 4 + 8 * T * T * 4
+    return 2 * blocks + scratch + body
+
+
+def ssd_tiles(S, chunk, heads, groups, d_head, d_state, dtype):
+    """``(heads a grid step, VMEM bytes)`` of the scan's two kernels, from
+    the operands' shapes alone: the longest run of a group's heads, at
+    most :data:`_SSD_HEADS`, that divides the group and whose blocks,
+    scratch and one head's ``Q x Q`` values fit the scoped VMEM a kernel
+    gets by default.  Raises where one head does not (a chunk or a state
+    too large to hold: nothing smaller to fall back to)."""
+    from chainermn_tpu.ops.flash_attention import VMEM_SCOPED_DEFAULT
+
+    if S % chunk:
+        raise ValueError(
+            f"ssd_scan: the sequence length {S} is no multiple of the "
+            f"chunk {chunk}")
+    if heads % groups:
+        raise ValueError(
+            f"ssd_scan: {groups} groups do not divide {heads} heads")
+    r, itemsize = heads // groups, jnp.dtype(dtype).itemsize
+    for hb in range(min(r, _SSD_HEADS), 0, -1):
+        vmem = _ssd_vmem(hb, chunk, heads, d_head, d_state, itemsize)
+        if r % hb == 0 and vmem <= VMEM_SCOPED_DEFAULT:
+            return hb, vmem
+    raise ValueError(
+        f"ssd_scan: one head of {d_head} x {d_state} at chunk {chunk} "
+        f"needs {vmem} bytes of VMEM, over the {VMEM_SCOPED_DEFAULT} a "
+        f"kernel gets")
+
+
+def _keep(last, N):
+    """``exp(cs_Q)``, what a block keeps of the state it started from, as
+    a row of ``N`` lanes (Mosaic broadcasts a (1, 1) value along one axis
+    at a time)."""
+    return jnp.exp(jnp.broadcast_to(last, (1, N)))
+
+
+_TN = (((0,), (0,)), ((), ()))      # contract the sublanes of both
+_NT = (((1,), (1,)), ((), ()))      # contract the lanes of both
+
+
+def _tiles(Q):
+    """A block's ``Q x Q`` matrices as square tiles of one register of
+    lanes: ``(tile, tiles a side)``.  Of the tiles only those ON the
+    diagonal need a decay matrix (an exponential an entry, under the
+    triangle); a tile past it is dead, and one before it is live
+    everywhere, where ``exp(cs_t - cs_s) = exp(cs_t - cs_e) exp(cs_e -
+    cs_s)`` with ``e`` the last token of the tile's ``s`` — two factors
+    in (0, 1], a row each, that scale the product's operands instead of
+    its weights."""
+    T = _SSD_TILE if Q % _SSD_TILE == 0 else Q
+    return T, Q // T
+
+
+def _span(k, T):
+    return slice(k * T, (k + 1) * T)
+
+
+def _triangle(T, lower):
+    """A diagonal tile's mask, [r, c]: ``r >= c`` (``lower``) or ``r <=
+    c``."""
+    row = lax.broadcasted_iota(jnp.int32, (T, T), 0)
+    col = lax.broadcasted_iota(jnp.int32, (T, T), 1)
+    return row >= col if lower else row <= col
+
+
+def _row(ref, h, span=slice(None)):
+    """Head ``h``'s row of a (1, 1, heads, Q) block over the tokens
+    ``span``, read from the ref: a slice of the ref is aligned, a slice
+    of a one-row value past the first register is not."""
+    return ref[0, 0, h:h + 1, span]
+
+
+def _ssd_fwd_kernel(x_ref, dt_ref, cs_ref, csN_ref, bT_ref, cT_ref, d_ref,
+                    y_ref, *rest, hb, P, keep):
+    """One (batch row, block, group, run of ``hb`` heads) of the forward,
+    the tokens on the lanes.  ``cs_ref`` / ``csN_ref``: the block's
+    running sums of ``dt A``, a row a head and a column a head (a decay
+    tile needs both).  ``h_s`` carries every run's state (heads x P rows,
+    N) from block to block; ``cb_s`` holds the group's ``B C^T`` for its
+    runs (``cbo_s``: rounded, for the tiles before the diagonal);
+    ``off_s`` the state's part of ``y`` and ``us_s`` the decayed ``dt x``
+    of all the step's heads, so that the two state products are one
+    matmul each."""
+    starts_ref = rest[0] if keep else None
+    h_s, cb_s, cbo_s, off_s, us_s = rest[-5:]
+    f32, op, Q = jnp.float32, x_ref.dtype, x_ref.shape[-1]
+    T, nt = _tiles(Q)
+    i, j = pl.program_id(1), pl.program_id(3)
+    run = pl.program_id(2) * pl.num_programs(3) + j
+
+    @pl.when(i == 0)
+    def _():
+        h_s[run] = jnp.zeros(h_s.shape[1:], f32)
+
+    @pl.when(j == 0)
+    def _():                                        # [s, t] = B_s . C_t
+        cb_s[...] = lax.dot_general(bT_ref[0, 0], cT_ref[0, 0], _TN,
+                                    preferred_element_type=f32)
+        cbo_s[...] = cb_s[...].astype(op)
+
+    upper = _triangle(T, lower=False)
+    state = h_s[run]
+    if keep:
+        starts_ref[0, 0, 0] = state
+    off_s[...] = jnp.dot(state.astype(op), cT_ref[0, 0],
                          preferred_element_type=f32)
-    y = (y + jnp.exp(cs)[..., None] * carried
-         + D[..., None, None] * x.astype(f32))
-    to_end = jnp.exp(cs[..., -1:] - cs)                   # (b, G, r, Q)
-    h_next = (jnp.exp(cs[..., -1])[..., None, None] * h_prev
-              + jnp.einsum("bgrqp,bgqn->bgrpn",
-                           (xdt * to_end[..., None]).astype(op), B,
-                           preferred_element_type=f32))
-    return h_next, y
+
+    for h in range(hb):
+        rs = slice(h * P, (h + 1) * P)
+        cs = functools.partial(_row, cs_ref, h)
+        x = x_ref[0, 0, rs, :].astype(f32)                  # (P, Q)
+        xdt = x * _row(dt_ref, h)
+        xdt_o = xdt.astype(op)
+        for k in range(nt):                                 # tokens t
+            tl = _span(k, T)
+            decay = jnp.exp(jnp.where(
+                upper, cs(tl) - csN_ref[0, 0, tl, h:h + 1], -jnp.inf))
+            weights = (cb_s[tl, tl] * decay).astype(op)     # [s, t]
+            y = jnp.dot(xdt_o[:, tl], weights, preferred_element_type=f32)
+            for m in range(k):                              # tokens s before
+                sl, e = _span(m, T), slice((m + 1) * T - 1, (m + 1) * T)
+                pre = xdt[:, sl] * jnp.exp(cs(e) - cs(sl))
+                y = y + jnp.exp(cs(tl) - cs(e)) * jnp.dot(
+                    pre.astype(op), cbo_s[sl, tl],
+                    preferred_element_type=f32)
+            y = (y + jnp.exp(cs(tl)) * off_s[rs, tl]
+                 + d_ref[run * hb + h] * x[:, tl])
+            y_ref[0, 0, rs, tl] = y.astype(y_ref.dtype)
+        last = cs(slice(Q - 1, Q))
+        us_s[rs, :] = (xdt * jnp.exp(last - cs())).astype(op)
+        h_s[run, rs, :] = _keep(last, h_s.shape[2]) * state[rs]
+    h_s[run] += lax.dot_general(us_s[...], bT_ref[0, 0], _NT,
+                                preferred_element_type=f32)
 
 
-#: the token axis of each operand of :func:`_ssd`, in its order
-_TOKEN_AXES = (3, 3, 2, 2)          # x, dt, B, C
+def _ssd_bwd_kernel(x_ref, dt_ref, cs_ref, csN_ref, bT_ref, cT_ref, d_ref,
+                    starts_ref, dy_ref,
+                    dx_ref, ddt_ref, dcs_ref, dbT_ref, dcT_ref, dd_ref,
+                    dh_s, cb_s, cbo_s, dcb_s, db_s, dc_s,
+                    off_s, g_s, us_s, dyo_s, pre_s, post_s, *, hb, P):
+    """One (batch row, block, group, run of heads) of the backward, the
+    blocks in reverse.  ``dh_s`` carries the cotangent of every run's
+    state; a head's decay tiles are rebuilt from the running sums in
+    VMEM.  Written a block: ``dx``, the part of ``ddt`` that comes through
+    ``dt x``, and the running sums' cotangent ``dcs`` (the caller sums it
+    back into ``ddt`` and ``dA``).  ``dB`` and ``dC`` sum over a group's
+    heads: ``dcb_s`` (the cotangent of ``C B^T``, [t, s]) and ``db_s`` /
+    ``dc_s`` gather them over the group's runs, written with its last.
+    What ``dcs`` takes from the decay matrix needs no ``Q x Q`` sum: with
+    ``W`` the masked weights and ``u = dt x``, ``sum_s dW W = sum_p dy (W
+    u)`` and ``sum_t dW W = sum_p u (W^T dy)``, rows over the tokens from
+    one more product a head — each with the SAME rounded operands, so
+    that the two cancel as the matrix's own sums do."""
+    f32, op, Q = jnp.float32, x_ref.dtype, x_ref.shape[-1]
+    T, nt = _tiles(Q)
+    first = (pl.program_id(0) == 0) & (pl.program_id(1) == 0) & (
+        pl.program_id(2) == 0) & (pl.program_id(3) == 0)
+    i, j, rpg = pl.program_id(1), pl.program_id(3), pl.num_programs(3)
+    run = pl.program_id(2) * rpg + j
+
+    @pl.when(first)
+    def _():
+        dd_ref[...] = jnp.zeros(dd_ref.shape, f32)
+
+    @pl.when(i == 0)
+    def _():
+        dh_s[run] = jnp.zeros(dh_s.shape[1:], f32)
+
+    @pl.when(j == 0)
+    def _():                                        # [t, s] = C_t . B_s
+        cb_s[...] = lax.dot_general(cT_ref[0, 0], bT_ref[0, 0], _TN,
+                                    preferred_element_type=f32)
+        cbo_s[...] = cb_s[...].astype(op)
+        dcb_s[...] = jnp.zeros(dcb_s.shape, f32)
+        db_s[...] = jnp.zeros(db_s.shape, f32)
+        dc_s[...] = jnp.zeros(dc_s.shape, f32)
+
+    lower = _triangle(T, lower=True)
+    state, dh = starts_ref[0, 0, 0], dh_s[run]
+    off_s[...] = jnp.dot(state.astype(op), cT_ref[0, 0],
+                         preferred_element_type=f32)
+    g_s[...] = jnp.dot(dh.astype(op), bT_ref[0, 0],
+                       preferred_element_type=f32)
+    at_end = lax.broadcasted_iota(jnp.int32, (1, Q), 1) == Q - 1
+    pairs = [(k, m) for k in range(nt) for m in range(k)]   # t after s
+
+    def over_p(v):
+        return jnp.sum(v, axis=0, keepdims=True)
+
+    for h in range(hb):
+        rs = slice(h * P, (h + 1) * P)
+        cs, dt = functools.partial(_row, cs_ref, h), _row(dt_ref, h)
+        x = x_ref[0, 0, rs, :].astype(f32)                  # (P, Q)
+        dy_o = dy_ref[0, 0, rs, :]
+        dy = dy_o.astype(f32)
+        xdt = x * dt
+        xdt_o = xdt.astype(op)
+        xdt_r = xdt_o.astype(f32)
+        dxdt, dcs = [None] * nt, [None] * nt    # by s; by token
+        for k in range(nt):
+            tl = _span(k, T)
+            decay = jnp.exp(jnp.where(
+                lower, csN_ref[0, 0, tl, h:h + 1] - cs(tl), -jnp.inf))
+            weights = (cb_s[tl, tl] * decay).astype(op)     # [t, s]
+            dxdt[k] = jnp.dot(dy_o[:, tl], weights,
+                              preferred_element_type=f32)
+            wu = lax.dot_general(xdt_o[:, tl], weights, _NT,
+                                 preferred_element_type=f32)
+            dcs[k] = over_p(dy[:, tl] * wu - xdt_r[:, tl] * dxdt[k])
+            dcb_s[tl, tl] += decay * lax.dot_general(
+                dy_o[:, tl], xdt_o[:, tl], _TN, preferred_element_type=f32)
+        for n, (k, m) in enumerate(pairs):
+            tl, sl = _span(k, T), _span(m, T)
+            e = slice((m + 1) * T - 1, (m + 1) * T)
+            before = jnp.exp(cs(e) - cs(sl))                # over s
+            after = jnp.exp(cs(tl) - cs(e))                 # over t
+            pre = (xdt[:, sl] * before).astype(op)
+            post = (dy[:, tl] * after).astype(op)
+            pre_s[n, rs, :], post_s[n, rs, :] = pre, post
+            back = jnp.dot(post, cbo_s[tl, sl], preferred_element_type=f32)
+            fore = lax.dot_general(pre, cbo_s[tl, sl], _NT,
+                                   preferred_element_type=f32)
+            dxdt[m] = dxdt[m] + before * back
+            dcs[k] = dcs[k] + over_p(post.astype(f32) * fore)
+            dcs[m] = dcs[m] - over_p(pre.astype(f32) * back)
+        dxdt = dxdt[0] if nt == 1 else jnp.concatenate(dxdt, axis=1)
+        dcs = dcs[0] if nt == 1 else jnp.concatenate(dcs, axis=1)
+        grow = jnp.exp(cs())
+        dcs = dcs + grow * over_p(dy * off_s[rs, :])
+        dyo_s[rs, :] = (dy * grow).astype(op)
+        last = cs(slice(Q - 1, Q))
+        to_end, g = jnp.exp(last - cs()), g_s[rs, :]
+        us_s[rs, :] = (xdt * to_end).astype(op)
+        dxdt = dxdt + g * to_end
+        dto_end = over_p(g * xdt) * to_end
+        dlast = jnp.sum(dto_end, axis=1, keepdims=True) + jnp.exp(last) * (
+            over_p(jnp.sum(state[rs] * dh[rs], axis=1, keepdims=True)))
+        dcs_ref[0, 0, h:h + 1, :] = (
+            dcs - dto_end + jnp.where(at_end, dlast, 0.0))
+        ddt_ref[0, 0, h:h + 1, :] = over_p(dxdt * x)
+        dx_ref[0, 0, rs, :] = (
+            dxdt * dt + d_ref[run * hb + h] * dy).astype(dx_ref.dtype)
+        dd_ref[run, h:h + 1, :] += over_p(
+            jnp.sum(dy * x, axis=1, keepdims=True))
+        dh_s[run, rs, :] = _keep(last, dh_s.shape[2]) * dh[rs]
+
+    dh_s[run] += lax.dot_general(dyo_s[...], cT_ref[0, 0], _NT,
+                                 preferred_element_type=f32)
+    dc_s[...] += lax.dot_general(state.astype(op), dyo_s[...], _TN,
+                                 preferred_element_type=f32)
+    db_s[...] += lax.dot_general(dh.astype(op), us_s[...], _TN,
+                                 preferred_element_type=f32)
+    for n, (k, m) in enumerate(pairs):      # every head's, one product
+        dcb_s[_span(k, T), _span(m, T)] += lax.dot_general(
+            post_s[n], pre_s[n], _TN, preferred_element_type=f32)
+
+    @pl.when(j == rpg - 1)
+    def _():
+        dcb = dcb_s[...].astype(op)
+        dbT_ref[0, 0] = (db_s[...] + jnp.dot(
+            cT_ref[0, 0], dcb, preferred_element_type=f32)
+        ).astype(dbT_ref.dtype)
+        dcT_ref[0, 0] = (dc_s[...] + lax.dot_general(
+            bT_ref[0, 0], dcb, _NT, preferred_element_type=f32)
+        ).astype(dcT_ref.dtype)
 
 
-def _blocks_of(arrays, i, chunk):
-    return tuple(lax.dynamic_slice_in_dim(a, i * chunk, chunk, axis)
-                 for a, axis in zip(arrays, _TOKEN_AXES))
+def _block_sums(a, chunk, back=False):
+    """``a`` (b, S, H) float32 summed along the tokens inside each block
+    of ``chunk``: ``cs_t = sum_{s <= t} a_s``, or with ``back`` its
+    transpose ``sum_{t >= s} a_t`` (the running sums' cotangent) — a
+    product with the triangle at full precision (a ``cumsum`` lowers to
+    reduce-windows that lose their scope and cost more)."""
+    b, S, H = a.shape
+    live = jnp.tril(jnp.ones((chunk, chunk), jnp.float32))  # [t, s]: s <= t
+    return jnp.einsum(
+        "bnth,ts->bnsh" if back else "bnsh,ts->bnth",
+        a.reshape(b, S // chunk, chunk, H), live,
+        precision=_HIGHEST).reshape(b, S, H)
 
 
-def _put_block(a, i, block, chunk, axis):
-    return lax.dynamic_update_slice_in_dim(
-        a, block.astype(a.dtype), i * chunk, axis)
+def _ssd_layout(x, dt, B, C, A, D, chunk):
+    """What both kernels are called with — the tokens on the lanes, a run
+    of ``hb`` heads a block: ``x`` (b, R, hb P, S); ``dt`` and the running
+    sums of ``dt A`` (b, R, hb, S), the sums the other way round too (b,
+    R, S, hb); ``B``, ``C`` (b, G, N, S); ``D`` (H,) scalars — with the
+    grid, the block specs' maker and the compiler's parameters."""
+    b, S, H, P = x.shape
+    G, N = B.shape[2:]
+    hb, _ = ssd_tiles(S, chunk, H, G, P, N, x.dtype)
+    R, rpg, n = H // hb, H // G // hb, S // chunk
+    f32 = jnp.float32
+    dt = dt.astype(f32)
+    cs = _block_sums(dt * A.astype(f32), chunk).reshape(b, S, R, hb)
+    operands = (
+        x.reshape(b, S, R, hb * P).transpose(0, 2, 3, 1),
+        dt.reshape(b, S, R, hb).transpose(0, 2, 3, 1),
+        cs.transpose(0, 2, 3, 1), cs.transpose(0, 2, 1, 3),
+        B.transpose(0, 2, 3, 1), C.transpose(0, 2, 3, 1), D.astype(f32))
+
+    def specs(block_of):
+        """Block specs with the block axis read through ``block_of``."""
+        def run_tokens(rows):
+            return pl.BlockSpec(
+                (1, 1, rows, chunk),
+                lambda bi, i, g, j: (bi, g * rpg + j, 0, block_of(i)))
+
+        return {
+            "x": run_tokens(hb * P), "row": run_tokens(hb),
+            "col": pl.BlockSpec(
+                (1, 1, chunk, hb),
+                lambda bi, i, g, j: (bi, g * rpg + j, block_of(i), 0)),
+            "group": pl.BlockSpec(
+                (1, 1, N, chunk),
+                lambda bi, i, g, j: (bi, g, 0, block_of(i))),
+            "state": pl.BlockSpec(
+                (1, 1, 1, hb * P, N),
+                lambda bi, i, g, j: (bi, block_of(i), g * rpg + j, 0, 0)),
+            "scalars": pl.BlockSpec(memory_space=pltpu.SMEM)}
+
+    # every axis in order: the state is carried over the blocks, ``C B^T``
+    # and the group's sums over a group's runs; the rule's tiles fit the
+    # default scoped VMEM, so no limit is asked for
+    params = pltpu.CompilerParams(dimension_semantics=("arbitrary",) * 4)
+    return operands, (b, n, G, rpg), specs, params, (hb, R, n)
+
+
+#: The wrappers are jitted in their own right: the layers of a model share
+#: one lowering of each.
+@functools.partial(jax.jit, static_argnames=("chunk", "keep", "interpret"))
+def _ssd_fwd_call(x, dt, B, C, A, D, *, chunk, keep, interpret):
+    """``y`` (b, S, H, P) and, where ``keep``, the state each block
+    started from (b, n, R, hb P, N) float32, for the backward."""
+    b, S, H, P = x.shape
+    G, N = B.shape[2:]
+    f32 = jnp.float32
+    with named_scope("ssd-scan"):
+        operands, grid, specs, params, (hb, R, n) = _ssd_layout(
+            x, dt, B, C, A, D, chunk)
+        s = specs(lambda i: i)
+        out_shape = [jax.ShapeDtypeStruct(operands[0].shape, x.dtype)]
+        out_specs = [s["x"]]
+        if keep:
+            out_shape.append(
+                jax.ShapeDtypeStruct((b, n, R, hb * P, N), f32))
+            out_specs.append(s["state"])
+        T = _tiles(chunk)[0]
+        out = pl.pallas_call(
+            functools.partial(_ssd_fwd_kernel, hb=hb, P=P, keep=keep),
+            out_shape=out_shape, grid=grid,
+            in_specs=[s["x"], s["row"], s["row"], s["col"], s["group"],
+                      s["group"], s["scalars"]],
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((R, hb * P, N), f32),            # h_s
+                pltpu.VMEM((chunk, chunk), f32),            # cb_s
+                pltpu.VMEM((chunk, chunk), x.dtype),        # cbo_s
+                pltpu.VMEM((hb * P, chunk), f32),           # off_s
+                pltpu.VMEM((hb * P, chunk), x.dtype)],      # us_s
+            compiler_params=params,
+            cost_estimate=pl.CostEstimate(
+                flops=2 * b * S * (G * chunk * N + H * P * (
+                    (chunk + T) // 2 + 2 * N)),
+                transcendentals=b * S * H * T,
+                bytes_accessed=2 * x.size * x.dtype.itemsize + (
+                    b * n * H * P * N * 4 if keep else 0)),
+            interpret=interpret, name="ssd-fwd",
+        )(*operands)
+        y = out[0].transpose(0, 3, 1, 2).reshape(b, S, H, P)
+        return (y, out[1]) if keep else y
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _ssd_bwd_call(x, dt, B, C, A, D, starts, dy, *, chunk, interpret):
+    b, S, H, P = x.shape
+    G, N = B.shape[2:]
+    f32 = jnp.float32
+    with named_scope("ssd-scan"):
+        operands, grid, specs, params, (hb, R, n) = _ssd_layout(
+            x, dt, B, C, A, D, chunk)
+        s = specs(lambda i: n - 1 - i)
+        xT, dtT, _, _, bT = operands[:5]
+        dyT = dy.reshape(b, S, R, hb * P).transpose(0, 2, 3, 1)
+        rows, (T, nt) = hb * P, _tiles(chunk)
+        pairs = max(1, nt * (nt - 1) // 2)
+        dxT, ddt, dcs, dbT, dcT, dD = pl.pallas_call(
+            functools.partial(_ssd_bwd_kernel, hb=hb, P=P),
+            out_shape=[
+                jax.ShapeDtypeStruct(xT.shape, x.dtype),
+                jax.ShapeDtypeStruct(dtT.shape, f32),
+                jax.ShapeDtypeStruct(dtT.shape, f32),
+                jax.ShapeDtypeStruct(bT.shape, B.dtype),
+                jax.ShapeDtypeStruct(bT.shape, C.dtype),
+                jax.ShapeDtypeStruct((R, hb, 1), f32)],
+            grid=grid,
+            in_specs=[s["x"], s["row"], s["row"], s["col"], s["group"],
+                      s["group"], s["scalars"], s["state"], s["x"]],
+            out_specs=[s["x"], s["row"], s["row"], s["group"], s["group"],
+                       pl.BlockSpec((R, hb, 1),
+                                    lambda bi, i, g, j: (0, 0, 0))],
+            scratch_shapes=[
+                pltpu.VMEM((R, rows, N), f32),              # dh_s
+                pltpu.VMEM((chunk, chunk), f32),            # cb_s
+                pltpu.VMEM((chunk, chunk), x.dtype),        # cbo_s
+                pltpu.VMEM((chunk, chunk), f32),            # dcb_s
+                pltpu.VMEM((N, chunk), f32),                # db_s
+                pltpu.VMEM((N, chunk), f32),                # dc_s
+                pltpu.VMEM((rows, chunk), f32),             # off_s
+                pltpu.VMEM((rows, chunk), f32),             # g_s
+                pltpu.VMEM((rows, chunk), x.dtype),         # us_s
+                pltpu.VMEM((rows, chunk), x.dtype),         # dyo_s
+                pltpu.VMEM((pairs, rows, T), x.dtype),      # pre_s
+                pltpu.VMEM((pairs, rows, T), x.dtype)],     # post_s
+            compiler_params=params,
+            cost_estimate=pl.CostEstimate(
+                flops=2 * b * S * (3 * G * chunk * N + H * P * (
+                    3 * (chunk + T) // 2 + 5 * N)),
+                transcendentals=b * S * H * T,
+                bytes_accessed=3 * x.size * x.dtype.itemsize
+                + starts.size * 4),
+            interpret=interpret, name="ssd-bwd",
+        )(*operands, starts, dyT)
+
+        def tokens_first(a):            # (b, R, hb, S) -> (b, S, H)
+            return a.transpose(0, 3, 1, 2).reshape(b, S, H)
+
+        # the running sums' cotangent back through the sums, a = dt A
+        da = _block_sums(tokens_first(dcs), chunk, back=True)
+        return (dxT.transpose(0, 3, 1, 2).reshape(b, S, H, P),
+                (da * A.astype(f32) + tokens_first(ddt)).astype(dt.dtype),
+                dbT.transpose(0, 3, 1, 2), dcT.transpose(0, 3, 1, 2),
+                jnp.sum(da * dt.astype(f32), axis=(0, 1)).astype(A.dtype),
+                dD.reshape(H).astype(D.dtype))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _ssd(x, dt, B, C, A, D, chunk):
-    """``x`` (b, G, r, S, P), ``dt`` (b, G, r, S), ``B``, ``C``
-    (b, G, S, N): heads before tokens, so that a block is a run of rows
-    of every head."""
-    return _ssd_fwd(x, dt, B, C, A, D, chunk)[0]
+    """``x`` (b, S, H, P), ``dt`` (b, S, H), ``B``, ``C`` (b, S, G, N),
+    ``A``, ``D`` (H,)."""
+    return _ssd_fwd_call(x, dt, B, C, A, D, chunk=chunk, keep=False,
+                         interpret=default_interpret())
 
-
-# Both passes walk the blocks by index over the whole arrays and write each
-# block's results in place: handing ``lax.scan`` block-major operands costs
-# a transposing copy of every operand and result (PERF.md §6, PR 26).
 
 def _ssd_fwd(x, dt, B, C, A, D, chunk):
-    with named_scope("ssd-scan"):
-        b, G, r, S, P = x.shape
-        n, N = S // chunk, B.shape[-1]
-
-        def step(i, carry):
-            h, starts, y = carry
-            h_next, y_i = _block(
-                h, *_blocks_of((x, dt, B, C), i, chunk), A, D)
-            return (h_next, lax.dynamic_update_index_in_dim(starts, h, i, 0),
-                    _put_block(y, i, y_i, chunk, 3))
-
-        zero = jnp.zeros((b, G, r, P, N), jnp.float32)
-        _, starts, y = lax.fori_loop(0, n, step, (
-            zero, jnp.zeros((n,) + zero.shape, jnp.float32),
-            jnp.zeros_like(x)))
-        return y, (x, dt, B, C, A, D, starts)
+    y, starts = _ssd_fwd_call(x, dt, B, C, A, D, chunk=chunk, keep=True,
+                              interpret=default_interpret())
+    return y, (x, dt, B, C, A, D, starts)
 
 
 def _ssd_bwd(chunk, saved, dy):
-    x, dt, B, C, A, D, starts = saved
-    with named_scope("ssd-scan"):
-        n = x.shape[3] // chunk
-
-        def step(k, carry):
-            i = n - 1 - k
-            dh, dA, dD, grads = carry
-            _, pull = jax.vjp(_block, starts[i],
-                              *_blocks_of((x, dt, B, C), i, chunk), A, D)
-            dy_i = lax.dynamic_slice_in_dim(dy, i * chunk, chunk, 3)
-            dh, *here, dA_i, dD_i = pull((dh, dy_i.astype(jnp.float32)))
-            return (dh, dA + dA_i, dD + dD_i, tuple(
-                _put_block(g, i, g_i, chunk, axis)
-                for g, g_i, axis in zip(grads, here, _TOKEN_AXES)))
-
-        _, dA, dD, grads = lax.fori_loop(0, n, step, (
-            jnp.zeros_like(starts[0]), jnp.zeros_like(A), jnp.zeros_like(D),
-            tuple(jnp.zeros_like(a) for a in (x, dt, B, C))))
-        return (*grads, dA, dD)
+    return _ssd_bwd_call(*saved, dy, chunk=chunk,
+                         interpret=default_interpret())
 
 
 _ssd.defvjp(_ssd_fwd, _ssd_bwd)
@@ -474,25 +868,17 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int):
     starts from a zero state: a batch row is one document."""
     b, S, H, P = x.shape
     G, N = B.shape[2], B.shape[3]
-    if S % chunk:
+    if C.shape != B.shape:
+        raise ValueError(f"ssd_scan: B {B.shape} and C {C.shape} must agree")
+    hb, vmem = ssd_tiles(S, chunk, H, G, P, N, x.dtype)
+    if not default_interpret() and chunk % _SSD_TILE and chunk != S:
         raise ValueError(
-            f"ssd_scan: the sequence length {S} is no multiple of the "
-            f"chunk {chunk}")
-    if H % G or C.shape != B.shape:
-        raise ValueError(
-            f"ssd_scan: B {B.shape} and C {C.shape} must agree, their "
-            f"groups ({G}) dividing the heads ({H})")
+            f"ssd_scan: on the chip a block's tokens fill whole registers "
+            f"of {_SSD_TILE} lanes; the chunk {chunk} is no multiple")
     if telemetry_active():
         publish_geometry("ssd_geometry", "ssd", {
             "chunk": chunk, "chunks": S // chunk, "heads": H, "d_head": P,
-            "d_state": N, "groups": G})
-    r = H // G
-    with named_scope("ssd-scan"):   # heads before tokens, and back
-        heads_first = (
-            x.reshape(b, S, G, r, P).transpose(0, 2, 3, 1, 4),
-            dt.astype(jnp.float32).reshape(b, S, G, r).transpose(0, 2, 3, 1),
-            B.transpose(0, 2, 1, 3), C.transpose(0, 2, 1, 3))
-    y = _ssd(*heads_first, A.astype(jnp.float32).reshape(G, r),
-             D.astype(jnp.float32).reshape(G, r), chunk)
-    with named_scope("ssd-scan"):
-        return y.transpose(0, 3, 1, 2, 4).reshape(b, S, H, P)
+            "d_state": N, "groups": G, "heads_a_step": hb,
+            "grid_steps": b * (S // chunk) * (H // hb),
+            "vmem_bytes": vmem}, form="kernel")
+    return _ssd(x, dt, B, C, A, D, chunk)

@@ -122,52 +122,138 @@ def test_return_hidden_folds_the_logits_scaling(both_sides):
 
 # ------------------------------------------- chunked scan vs the recurrence
 
-def _scan_inputs(seed, b=2, S=32, H=4, P=8, G=2, N=16):
+#: ``base``: the first cases'; ``one_group``: the granite cell's geometry
+#: cut small (every head shares one B and C); ``groups``: the nemotron
+#: cell's (several groups of a few heads); ``runs``: more heads a group
+#: than a grid step holds, so the rule splits them into runs.
+_GEOMETRIES = {
+    "base": dict(H=4, P=8, G=2, N=16),
+    "one_group": dict(H=8, P=8, G=1, N=16),
+    "groups": dict(H=8, P=8, G=4, N=16),
+    "runs": dict(H=32, P=8, G=1, N=8),
+}
+
+
+def _scan_inputs(seed, b=2, S=32, H=4, P=8, G=2, N=16, dtype=jnp.float32):
     k = jax.random.split(jax.random.PRNGKey(seed), 7)
     return dict(
-        x=jax.random.normal(k[0], (b, S, H, P)),
+        x=jax.random.normal(k[0], (b, S, H, P)).astype(dtype),
         dt=0.3 * jax.nn.softplus(jax.random.normal(k[1], (b, S, H))),
         A=-jnp.exp(jax.random.normal(k[2], (H,))),
-        B=jax.random.normal(k[3], (b, S, G, N)),
-        C=jax.random.normal(k[4], (b, S, G, N)),
+        B=jax.random.normal(k[3], (b, S, G, N)).astype(dtype),
+        C=jax.random.normal(k[4], (b, S, G, N)).astype(dtype),
         D=jax.random.normal(k[5], (H,)),
     ), jax.random.normal(k[6], (b, S, H, P))
 
 
 def _recurrence(x, dt, A, B, C, D):
+    f32 = jnp.float32
     return jax.vmap(reference.recurrence, in_axes=(0, 0, None, 0, 0, None))(
-        x, dt, A, B, C, D)
+        x.astype(f32), dt, A, B.astype(f32), C.astype(f32), D)
 
 
-@pytest.mark.parametrize("chunk", [8, 32])
-def test_chunked_scan_matches_the_recurrence_forward(chunk):
-    args, _ = _scan_inputs(0)
+def _close(got, want, dtype):
+    """float32: to rounding.  bfloat16 (the cells' precision: operands of
+    the four products rounded, sums float32): to a few roundings of the
+    largest term."""
+    scale = float(jnp.max(jnp.abs(want)))
+    rtol, atol = (1e-4, 1e-5) if dtype == jnp.float32 else (2e-2, 2e-2)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), want, rtol=rtol, atol=atol * scale)
+
+
+_scan_cases = pytest.mark.parametrize(
+    "dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+
+
+@_scan_cases
+@pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
+@pytest.mark.parametrize("chunk", [8, 32], ids=["blocks4", "block1"])
+def test_chunked_scan_matches_the_recurrence_forward(chunk, geometry, dtype):
+    args, _ = _scan_inputs(0, dtype=dtype, **_GEOMETRIES[geometry])
     with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(
-            ssd_scan(**args, chunk=chunk), _recurrence(**args),
-            rtol=1e-4, atol=1e-5)
+        _close(ssd_scan(**args, chunk=chunk), _recurrence(**args), dtype)
 
 
+@_scan_cases
+@pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
 @pytest.mark.parametrize("name", ["x", "dt", "A", "B", "C", "D"])
-@pytest.mark.parametrize("chunk", [8, 32])
-def test_chunked_scan_matches_the_recurrence_gradient(chunk, name):
-    args, w = _scan_inputs(1)
+@pytest.mark.parametrize("chunk", [8, 32], ids=["blocks4", "block1"])
+def test_chunked_scan_matches_the_recurrence_gradient(chunk, name, geometry,
+                                                      dtype):
+    args, w = _scan_inputs(1, dtype=dtype, **_GEOMETRIES[geometry])
 
     def grad_of(fn):
-        return jax.grad(lambda a: jnp.sum(w * fn(**{**args, name: a})))(
-            args[name])
+        return jax.grad(lambda a: jnp.sum(
+            w * fn(**{**args, name: a}).astype(jnp.float32)))(args[name])
 
     with jax.default_matmul_precision("highest"):
         got = grad_of(lambda **a: ssd_scan(**a, chunk=chunk))
         want = grad_of(_recurrence)
-    np.testing.assert_allclose(
-        got, want, rtol=1e-4, atol=1e-5 * float(jnp.max(jnp.abs(want))))
+    assert got.dtype == args[name].dtype
+    _close(got, want, dtype)
+
+
+@_scan_cases
+@pytest.mark.parametrize("name", ["y", "x", "dt", "A", "B", "C", "D"])
+def test_chunked_scan_tiles_a_block_wider_than_the_lanes(name, dtype):
+    """Chunk 256 (the granite cell's): a block's ``Q x Q`` matrices in
+    2 x 2 tiles of 128 — the tile before the diagonal takes its decay as
+    two factors on the product's operand and result, the one past it is
+    never made.  Two blocks, so the state crosses one.  ``y``, then the
+    gradient by operand."""
+    args, w = _scan_inputs(3, b=1, S=512, dtype=dtype, **_GEOMETRIES["base"])
+
+    def of(fn):
+        if name == "y":
+            return fn(**args)
+        return jax.grad(lambda a: jnp.sum(
+            w * fn(**{**args, name: a}).astype(jnp.float32)))(args[name])
+
+    with jax.default_matmul_precision("highest"):
+        _close(of(lambda **a: ssd_scan(**a, chunk=256)), of(_recurrence),
+               dtype)
 
 
 def test_scan_refuses_a_length_that_is_no_multiple_of_the_chunk():
     args, _ = _scan_inputs(0)
     with pytest.raises(ValueError, match="no multiple of the chunk"):
         ssd_scan(**args, chunk=24)
+
+
+@pytest.mark.parametrize("cell,groups,chunk,heads_a_step", [
+    ("granite4hm-train-1chip", 1, 256, 16),
+    ("nemo3nano-train-1chip", 8, 128, 8)])
+def test_scan_tiles_of_the_cells_fit_the_default_vmem(cell, groups, chunk,
+                                                      heads_a_step):
+    """Both hybrid cells' scans (2 x 8192 tokens, 64 heads of 64, state
+    128, bfloat16) run the kernels at tiles inside the scoped VMEM a
+    kernel gets by default: one group's 64 heads in runs of 16, eight
+    groups' 8 heads a run each."""
+    from chainermn_tpu.ops.flash_attention import VMEM_SCOPED_DEFAULT
+    from chainermn_tpu.ops.ssd import ssd_tiles
+
+    hb, vmem = ssd_tiles(8192, chunk, 64, groups, 64, 128, jnp.bfloat16)
+    assert hb == heads_a_step and vmem <= VMEM_SCOPED_DEFAULT
+
+
+def test_scan_tiles_follow_the_shapes():
+    from chainermn_tpu.ops.ssd import ssd_tiles
+
+    # more heads a group than a step holds: runs of the longest divisor
+    assert ssd_tiles(32, 8, 32, 1, 8, 8, jnp.float32)[0] == 16
+    assert ssd_tiles(32, 8, 24, 2, 8, 8, jnp.float32)[0] == 12
+    assert ssd_tiles(32, 8, 14, 2, 8, 8, jnp.float32)[0] == 7
+    # float32 operands at the granite cell's shape: fewer heads fit
+    hb, _ = ssd_tiles(8192, 256, 64, 1, 64, 128, jnp.float32)
+    assert 64 % hb == 0 and hb < 16
+    # what cannot be tiled raises by the rule: no other form takes over
+    with pytest.raises(ValueError, match="bytes of VMEM"):
+        ssd_tiles(8192, 2048, 64, 1, 64, 128, jnp.bfloat16)
+    with pytest.raises(ValueError, match="do not divide"):
+        ssd_tiles(32, 8, 6, 4, 8, 8, jnp.float32)
+    with pytest.raises(ValueError, match="no multiple of the chunk"):
+        ssd_tiles(40, 16, 4, 2, 8, 8, jnp.float32)
 
 
 def test_causal_conv_sees_no_later_token():
@@ -200,12 +286,16 @@ def test_scan_geometry_reaches_the_sinks(tmp_path):
     summary = rep.summary()
     assert summary["counters"]["ssd/calls"] == 1
     want = {"chunk": 16, "chunks": 2, "heads": 4, "d_head": 8,
-            "d_state": 16, "groups": 2}
-    assert {n: g["value"] for n, g in summary["gauges"].items()} == {
-        f"ssd/{k}": v for k, v in want.items()}
+            "d_state": 16, "groups": 2, "heads_a_step": 2, "grid_steps": 8}
+    gauges = {n: g["value"] for n, g in summary["gauges"].items()}
+    assert gauges.pop("ssd/vmem_bytes") > 0
+    # the mechanism that engaged: both passes one Mosaic kernel
+    assert gauges == {"ssd/kernel": 1,
+                      **{f"ssd/{k}": v for k, v in want.items()}}
     rows = [json.loads(line) for line in open(path)]
     rows = [r for r in rows if r["event"] == "ssd_geometry"]
     assert len(rows) == 1 and {k: rows[0][k] for k in want} == want
+    assert rows[0]["form"] == "kernel"
 
 
 def test_conv_geometry_reaches_the_sinks(tmp_path):

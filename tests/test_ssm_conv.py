@@ -163,3 +163,21 @@ def test_backward_ops_sit_under_ssm_conv_inside_the_mixer():
         parts = device_trace.scope_components(path)
         assert parts.index("mamba-mixer") < parts.index("ssm-conv"), path
     assert all("transpose(" in path for path in backward)
+
+
+def test_scan_kernels_sit_under_ssd_scan_inside_the_mixer():
+    """``kernel.ssd_ms`` / ``nemo.ssd_ms`` read scope ``ssd-scan`` and
+    the mixers' readers ``mamba-mixer`` with it nested inside: both
+    Mosaic calls (``ssd-fwd``, ``ssd-bwd``) and the relabelings around
+    them carry both, in that order."""
+    loss, params, h = _mixer(jnp.float32)
+    table = device_trace.scope_table(
+        jax.jit(jax.grad(loss)).lower(params, h).compile())
+    scan = [path for path in table.values() if "ssd-scan" in path]
+    assert scan and all(
+        device_trace.classify(path)[1] == "ssd-scan" for path in scan)
+    assert any("ssd-fwd" in path for path in scan)
+    assert any("ssd-bwd" in path for path in scan)
+    for path in [p for p in scan if "Mamba2Mixer" in p]:
+        parts = device_trace.scope_components(path)
+        assert parts.index("mamba-mixer") < parts.index("ssd-scan"), path

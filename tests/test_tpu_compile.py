@@ -1,6 +1,6 @@
-"""The flash kernels, the loss head's gradient and the causal
-convolution's backward, compiled for a described TPU v5e, without the
-chip.
+"""The flash kernels, the loss head's gradient, the causal
+convolution's backward and the state-space scan's two kernels, compiled
+for a described TPU v5e, without the chip.
 
 Interpret mode cannot show what Mosaic refuses: a block that is not
 aligned to the tiling, or more scoped VMEM than a kernel may use.  The
@@ -17,6 +17,8 @@ every compile runs in the test's own process.
 
 import functools
 import importlib
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -203,6 +205,85 @@ def test_conv_kernels_are_one_pass_over_their_operands(one_chip, channels,
              * jnp.dtype(dtype).itemsize)
     assert compiled.cost_analysis()["bytes accessed"] <= 1.5 * moved
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+_SCAN_CELLS = {"granite4hm-train-1chip": (1, 256),     # groups, chunk
+               "nemo3nano-train-1chip": (8, 128)}
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+@pytest.mark.parametrize("cell", sorted(_SCAN_CELLS))
+def test_scan_kernels_compile_at_the_cells_geometry(one_chip, cell, which):
+    """``ssd-fwd`` and ``ssd-bwd`` at both hybrid cells' full geometry (2
+    x 8192 tokens, 64 heads of 64, state 128, bfloat16; one group at
+    chunk 256, eight at chunk 128): ONE Mosaic call a pass inside the
+    default scoped VMEM, at the tiles ``ssd_tiles`` gives — the calls ask
+    for no limit of their own."""
+    ssd = importlib.import_module("chainermn_tpu.ops.ssd")
+    groups, chunk = _SCAN_CELLS[cell]
+    b, S, H, P, N = 2, 8192, 64, 64, 128
+
+    def arr(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    hb, vmem = ssd.ssd_tiles(S, chunk, H, groups, P, N, jnp.bfloat16)
+    assert vmem <= fa.VMEM_SCOPED_DEFAULT
+    operands = (arr(b, S, H, P), arr(b, S, H, dt=jnp.float32),
+                arr(b, S, groups, N), arr(b, S, groups, N),
+                arr(H, dt=jnp.float32), arr(H, dt=jnp.float32))
+    if which == "fwd":
+        call = functools.partial(ssd._ssd_fwd_call, chunk=chunk, keep=True,
+                                 interpret=False)
+    else:
+        call = functools.partial(ssd._ssd_bwd_call, chunk=chunk,
+                                 interpret=False)
+        operands += (arr(b, S // chunk, H // hb, hb * P, N, dt=jnp.float32),
+                     arr(b, S, H, P))
+    compiled = jax.jit(call).lower(*operands).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert " while(" not in compiled.as_text()
+
+
+def test_mixer_layer_grows_no_copies_around_the_scan(one_chip, monkeypatch):
+    """One Mamba-2 mixer layer at the granite cell's shape, forward and
+    backward under remat as in the step: six Mosaic calls and no loop
+    (the convolution's forward twice and its backward, ``ssd-fwd`` twice
+    — once keeping the blocks' states — and ``ssd-bwd``), and no more
+    copies beside them than the parent of PR 31 compiled to — 17 ``copy``
+    instructions, 2 of them of an activation's size (the loop's bodies
+    held 7 of the 17, run once a block); here 9 and 2.  The kernels take
+    the tokens on the lanes, as the convolution's do and as the compiler
+    lays out ``in_proj``'s result: nothing is turned around between
+    them."""
+    from chainermn_tpu.models.block_table import SSMSpec
+    from chainermn_tpu.models.transformer import Mamba2Mixer
+
+    ssd = importlib.import_module("chainermn_tpu.ops.ssd")
+    monkeypatch.setattr(ssd, "default_interpret", lambda: False)
+    d_model, spec = 2048, SSMSpec(n_heads=64, d_head=64, d_state=128,
+                                  n_groups=1, d_conv=4, chunk=256)
+    mixer = Mamba2Mixer(d_model, spec, 1e-5, jnp.bfloat16)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: mixer.init(
+            jax.random.PRNGKey(0),
+            jnp.zeros((1, spec.chunk, d_model), jnp.bfloat16))))
+    h = jax.ShapeDtypeStruct((2, 8192, d_model), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(params, h):
+        layer = jax.checkpoint(lambda p, h: h + mixer.apply(p, h))
+        return jnp.sum(layer(params, h).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, h).compile().as_text()
+    assert text.count("tpu_custom_call") == 6 and " while(" not in text
+    sizes = []
+    for dtype, dims in re.findall(r"= (\w+)\[([\d,]*)\]\S* copy\(", text):
+        sizes.append((2 if dtype == "bf16" else 4) * math.prod(
+            int(d) for d in dims.split(",") if d))
+    assert len(sizes) <= 17
+    assert sum(size >= 2 * 8192 * 1024 for size in sizes) <= 2
 
 
 @pytest.mark.parametrize("tile_rows", [256, 512])
