@@ -11,9 +11,13 @@ The GPT-2-style block the repo started with is one row
 (:func:`gpt2_table`); :func:`table_from_config` turns a published
 ``config.json`` into its table, by ``model_type``: ``granitemoehybrid``
 (Mamba-2 mixers with an attention layer among every few, RMSNorm, SwiGLU,
-no positions, scalar multipliers, a tied head) and ``nemotron_h``
+no positions, scalar multipliers, a tied head), ``nemotron_h``
 (single-branch layers — a Mamba-2 mixer, a GQA layer or a sparse-expert
-FFN each — RMSNorm, squared ReLU, no positions, an untied head).
+FFN each — RMSNorm, squared ReLU, no positions, an untied head) and
+``zaya`` (every layer a compressed-convolutional-attention mixer with
+partial rotary positions and a top-1 sparse-expert FFN whose MLP router
+hands a state on to the next layer's, RMSNorm, gated experts, a tied
+head).
 
 Plain frozen dataclasses: hashable, so a table is a static field of the
 flax module.
@@ -24,10 +28,15 @@ from __future__ import annotations
 import dataclasses
 from typing import Mapping, Optional, Tuple
 
-MIXERS = ("attention", "mamba2", "none")
+MIXERS = ("attention", "mamba2", "cca", "none")
 NORMS = ("layernorm", "rmsnorm")
 FFNS = ("gelu", "swiglu", "relu2", "experts", "none")
-POSITIONS = ("sinusoidal", "none")
+#: "sinusoidal" is added to the embedding; "rotary" is applied inside the
+#: mixers, to queries and keys (the row's own spec says to how much of a
+#: head and at what base), and nothing is added to the embedding.
+POSITIONS = ("sinusoidal", "rotary", "none")
+ROUTERS = ("sigmoid", "mlp_softmax")
+EXPERTS = ("relu2", "swiglu")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,16 +67,61 @@ class SSMSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class CCASpec:
+    """Geometry of a compressed-convolutional-attention mixer
+    (arXiv:2510.04476): ``n_heads`` query and ``n_kv_heads`` key/value
+    heads of ``d_head`` in a latent narrower than the model; over the
+    ``(n_heads + n_kv_heads) x d_head`` query and key channels a causal
+    depthwise convolution of ``time0`` taps, then one of ``time1`` taps
+    grouped by head; rotary positions on the first ``rotary_dim`` of a
+    head's dimensions at base ``rope_theta``."""
+
+    n_heads: int
+    n_kv_heads: int
+    d_head: int
+    time0: int = 2
+    time1: int = 2
+    rotary_dim: int = 0
+    rope_theta: float = 10000.0
+
+    def __post_init__(self):
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(f"n_kv_heads ({self.n_kv_heads}) must divide "
+                             f"n_heads ({self.n_heads})")
+        if (self.n_kv_heads * self.d_head) % 2:
+            raise ValueError("the value's two halves (this token's, the "
+                             "one before's) split n_kv_heads x d_head")
+        if self.rotary_dim % 2 or not 0 <= self.rotary_dim <= self.d_head:
+            raise ValueError(f"rotary_dim {self.rotary_dim} is no even "
+                             f"part of a head of {self.d_head}")
+
+    @property
+    def conv_dim(self) -> int:
+        return (self.n_heads + self.n_kv_heads) * self.d_head
+
+
+@dataclasses.dataclass(frozen=True)
 class ExpertsSpec:
     """A sparse-expert FFN: a router over ``n_experts`` (the published
     count: its width), ``top_k`` chosen a token, none dropped; the
     experts ``held`` here, ``(first, count)`` of the ``n_experts`` (all
-    of them, or one expert-parallel rank's share); each expert
-    ``w_down relu(w_up h)^2`` at width ``d_expert``, and a shared expert
-    of the same form at ``d_shared`` that every token passes.
-    The router scores by sigmoid, chooses by score + a per-expert
-    correction bias, and weighs the chosen by their scores, normalised
-    over the chosen, times ``scaling``."""
+    of them, or one expert-parallel rank's share), each at width
+    ``d_expert``; and a shared expert of the same form at ``d_shared``
+    that every token passes (0: none).
+
+    ``router``: ``"sigmoid"`` scores by sigmoid of one matrix, chooses by
+    score + a per-expert correction bias, and weighs the chosen by their
+    scores, normalised over the chosen, times ``scaling``.
+    ``"mlp_softmax"`` (``top_k`` 1) brings the token down to ``d_router``,
+    adds the router state of the layer before times a learned vector,
+    and puts that — the state it hands on to the next layer — through an
+    RMSNorm and a three-matrix GELU MLP to a softmax over the experts;
+    the choice is by probability + a balancing bias, the weight the
+    chosen probability itself.  A layer with this router takes and
+    returns ``(x, r)``.
+
+    ``expert``: ``"relu2"`` is ``w_down relu(w_up h)^2``, ``"swiglu"``
+    ``w_down (silu(w_gate h) * (w_up h))``, three matrices."""
 
     n_experts: int
     top_k: int
@@ -75,8 +129,22 @@ class ExpertsSpec:
     d_shared: int
     held: Optional[Tuple[int, int]] = None      # None = (0, n_experts)
     scaling: float = 1.0
+    router: str = "sigmoid"            # one of ROUTERS
+    expert: str = "relu2"              # one of EXPERTS
+    d_router: int = 0                  # "mlp_softmax": the state's width
 
     def __post_init__(self):
+        if self.router not in ROUTERS or self.expert not in EXPERTS:
+            raise ValueError(
+                f"router must be one of {ROUTERS} and expert one of "
+                f"{EXPERTS}, got {self.router!r} and {self.expert!r}")
+        if (self.router == "mlp_softmax") != (self.d_router > 0):
+            raise ValueError("an mlp_softmax router, and only it, has a "
+                             "d_router")
+        if self.router == "mlp_softmax" and (
+                self.top_k != 1 or self.scaling != 1.0):
+            raise ValueError("the mlp_softmax router chooses one expert a "
+                             "token and weighs it by its probability")
         first, count = self.experts_held
         if not (0 <= first and count >= 1
                 and first + count <= self.n_experts):
@@ -107,6 +175,7 @@ class LayerSpec:
     d_head: Optional[int] = None       # None = d_model / n_heads
     attn_scale: Optional[float] = None  # softmax scale; None = 1/sqrt(d_head)
     ssm: Optional[SSMSpec] = None      # mamba2 rows
+    cca: Optional[CCASpec] = None      # cca rows
     experts: Optional[ExpertsSpec] = None   # "experts" rows
     residual_multiplier: float = 1.0   # rm above
     norm_eps: float = 1e-6
@@ -120,6 +189,8 @@ class LayerSpec:
                                  f"got {value!r}")
         if (self.mixer == "mamba2") != (self.ssm is not None):
             raise ValueError("a mamba2 row, and only it, carries an SSMSpec")
+        if (self.mixer == "cca") != (self.cca is not None):
+            raise ValueError("a cca row, and only it, carries a CCASpec")
         if (self.ffn == "experts") != (self.experts is not None):
             raise ValueError("an experts row, and only it, carries an "
                              "ExpertsSpec")
@@ -151,6 +222,21 @@ class BlockTable:
                              f"got {self.final_norm!r}")
         if not self.layers:
             raise ValueError("a block table has at least one layer")
+        if self.positions == "rotary" and any(
+                r.mixer == "attention" for r in self.layers):
+            raise ValueError("rotary positions are built inside the cca "
+                             "mixer only: an attention row has none")
+        if len({r.experts.d_router for r in self.layers
+                if r.experts is not None}) > 1:
+            raise ValueError("every expert row of a table has the same "
+                             "router state (d_router), or none has one")
+
+    @property
+    def d_router_state(self) -> int:
+        """Width of the router state the layers hand on beside the
+        residual stream; 0 where no row's router keeps one."""
+        return max((r.experts.d_router for r in self.layers
+                    if r.experts is not None), default=0)
 
 
 def gpt2_table(n_layers: int, n_heads: int, d_ff: int,
@@ -180,9 +266,9 @@ def _first_layers(kinds, n_layers):
 def table_from_config(config: Mapping, n_layers: Optional[int] = None,
                       experts_held: Optional[Tuple[int, int]] = None
                       ) -> BlockTable:
-    """The table of a published ``config.json``, by its own keys.  Two
+    """The table of a published ``config.json``, by its own keys.  Three
     families are read, by ``model_type``: ``granitemoehybrid`` (its dense
-    members: ``num_local_experts`` 0) and ``nemotron_h``.  ``n_layers``
+    members: ``num_local_experts`` 0), ``nemotron_h`` and ``zaya``.  ``n_layers``
     keeps the first so many layers (a pipeline stage, a cut to fit); None
     keeps ``num_hidden_layers``.  ``experts_held`` is the ``(first,
     count)`` of the published experts this rank holds in every expert
@@ -190,7 +276,7 @@ def table_from_config(config: Mapping, n_layers: Optional[int] = None,
 
     What this system cannot build raises here, by key."""
     readers = {"granitemoehybrid": _granite_table,
-               "nemotron_h": _nemotron_h_table}
+               "nemotron_h": _nemotron_h_table, "zaya": _zaya_table}
     reader = readers.get(config.get("model_type"))
     if reader is None:
         raise ValueError(
@@ -325,3 +411,63 @@ def _nemotron_h_table(config, n_layers, experts_held):
         layers=tuple(rows[k] for k in kinds), positions="none",
         final_norm="rmsnorm", norm_eps=eps,
         tied_head=bool(config["tie_word_embeddings"]))
+
+
+def _zaya_table(config, n_layers, experts_held):
+    """``zaya``: every layer (``layer_types`` ``hybrid``) a CCA mixer —
+    ``num_attention_heads`` query and ``num_key_value_heads`` key/value
+    heads of ``head_dim`` in the latent, convolutions of ``cca_time0`` and
+    ``cca_time1`` taps, rotary positions on ``partial_rotary_factor`` of
+    a head at ``rope_parameters.hybrid.rope_theta`` — then ``num_experts``
+    gated experts of ``moe_intermediate_size``, one a token by the MLP
+    router at ``router_hidden_size``, no shared expert; RMSNorm, a tied
+    head.  Refused by key: a window (``sliding_window``, a
+    ``hybrid_sliding`` layer), more than one expert a token, biases,
+    another activation or rope type, an untied head."""
+    rope = config.get("rope_parameters", {}).get("hybrid", {})
+    factor = rope.get("partial_rotary_factor",
+                      config.get("partial_rotary_factor", 1.0))
+    _refuse([
+        (config.get("sliding_window") is not None,
+         "sliding_window (windowed attention in the cca mixer)"),
+        (config.get("num_experts_per_tok", 1) != 1,
+         "num_experts_per_tok > 1 with the zaya router (it weighs its one "
+         "expert by the softmax probability itself)"),
+        (bool(config.get("attention_bias")), "attention_bias"),
+        (bool(config.get("lm_head_bias")), "lm_head_bias"),
+        (config.get("hidden_act") != "silu", "hidden_act other than silu"),
+        (not config.get("tie_word_embeddings", True),
+         "an untied output head"),
+        (rope.get("rope_type", "default") != "default",
+         "rope_type other than default"),
+        (factor != config.get("partial_rotary_factor", factor),
+         "partial_rotary_factor other than rope_parameters.hybrid's"),
+    ])
+    kinds = list(config["layer_types"])
+    if len(kinds) != config["num_hidden_layers"]:
+        raise ValueError("layer_types does not list num_hidden_layers "
+                         "entries")
+    kinds = _first_layers(kinds, n_layers)
+    unknown = sorted(set(kinds) - {"hybrid"})
+    if unknown:
+        raise ValueError(f"layer_types holds kinds this system does not "
+                         f"build: {unknown}")
+    eps = float(config["rms_norm_eps"])
+    row = LayerSpec(
+        mixer="cca", norm="rmsnorm", ffn="experts", norm_eps=eps,
+        cca=CCASpec(
+            n_heads=config["num_attention_heads"],
+            n_kv_heads=config["num_key_value_heads"],
+            d_head=config["head_dim"], time0=config["cca_time0"],
+            time1=config["cca_time1"],
+            rotary_dim=int(config["head_dim"] * factor),
+            rope_theta=float(rope.get("rope_theta", config.get(
+                "rope_theta", 10000.0)))),
+        experts=ExpertsSpec(
+            n_experts=config["num_experts"],
+            top_k=config["num_experts_per_tok"],
+            d_expert=config["moe_intermediate_size"], d_shared=0,
+            held=experts_held, router="mlp_softmax", expert="swiglu",
+            d_router=config["router_hidden_size"]))
+    return BlockTable(layers=(row,) * len(kinds), positions="rotary",
+                      final_norm="rmsnorm", norm_eps=eps)

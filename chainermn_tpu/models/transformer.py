@@ -24,6 +24,7 @@ import numpy as np
 
 from chainermn_tpu.models.block_table import (
     BlockTable,
+    CCASpec,
     ExpertsSpec,
     LayerSpec,
     SSMSpec,
@@ -429,11 +430,17 @@ class ExpertLayer(nn.Module):
     """A sparse-expert FFN for one expert-parallel rank (an
     :class:`ExpertsSpec` row): the router over all the published experts
     in float32, the (token, choice) pairs of the experts held here sorted
-    by expert and put through ``w_down relu(w_up h)^2`` as grouped
-    matmuls (both stacks (count, d_expert, d_model): ``experts_up`` holds
-    its matrices output-major), each result added back times its router
-    weight, plus the shared expert (a :class:`Relu2FeedForward`) over
-    every token.  No token is dropped
+    by expert and put through the held experts as grouped matmuls (every
+    stack (count, d_expert, d_model): ``experts_up`` and ``experts_gate``
+    hold their matrices output-major), each result added back times its
+    router weight, plus the shared expert (a :class:`Relu2FeedForward`)
+    over every token where the spec has one.  By the spec's kinds: the
+    router ``sigmoid`` (top-k, :func:`moe_dropless.route`) or
+    ``mlp_softmax`` (top-1, :func:`moe_dropless.route_mlp_softmax`, which
+    takes the router state of the layer before and hands its own on:
+    then the layer is called with ``state`` and returns ``(out,
+    state)``); the experts ``relu2`` (two matrices) or ``swiglu``
+    (three).  No token is dropped
     (:mod:`chainermn_tpu.parallel.moe_dropless`); what the absent experts
     would add is left out.  ``sow``s the chosen experts as
     ``intermediates/chosen`` for whoever asks for that collection."""
@@ -441,12 +448,14 @@ class ExpertLayer(nn.Module):
     d_model: int
     spec: ExpertsSpec
     dtype: Any = jnp.bfloat16
+    norm_eps: float = 1e-5      # the mlp_softmax router's own RMSNorm
 
     @nn.compact
-    def __call__(self, h):
+    def __call__(self, h, state=None):
         from chainermn_tpu.ops.grouped_matmul import (
             TILE_ROWS,
             grouped_relu2_mlp,
+            grouped_swiglu_mlp,
             weight_blocks,
         )
         from chainermn_tpu.ops.ssd import publish_geometry
@@ -455,12 +464,16 @@ class ExpertLayer(nn.Module):
         z, d = self.spec, self.d_model
         first, count = z.experts_held
         f32 = jnp.float32
+        lecun = nn.initializers.lecun_normal()
         stacked = nn.initializers.lecun_normal(batch_axis=(0,))
         stacked_out_major = nn.initializers.lecun_normal(
             in_axis=-1, out_axis=-2, batch_axis=(0,))
         lead = h.shape[:-1]
         tokens = int(np.prod(lead))
         n_rows = moe.rows_bound(tokens * z.top_k, count, z.n_experts)
+        if (z.router == "mlp_softmax") != (state is not None):
+            raise ValueError("an mlp_softmax router, and only it, takes "
+                             "the router state of the layer before")
         if telemetry_active():
             publish_geometry("moe_geometry", "moe", {
                 "experts": z.n_experts, "experts_held": count,
@@ -470,32 +483,215 @@ class ExpertLayer(nn.Module):
                 "tile_rows": TILE_ROWS,
                 **dict(zip(("d_block", "expert_block"), weight_blocks(
                     d, z.d_expert, jnp.dtype(self.dtype).itemsize)))},
-                form="pallas_tile_aligned")
+                form="pallas_tile_aligned", router=z.router,
+                expert=z.expert)
         with named_scope("moe-layer"):
             x = h.reshape(tokens, d)
             with named_scope("moe-route"):
-                chosen, weight = moe.route(
-                    x, self.param("router", nn.initializers.lecun_normal(),
-                                  (d, z.n_experts), f32),
-                    self.param("router_bias", nn.initializers.zeros,
-                               (z.n_experts,), f32),
-                    top_k=z.top_k, scaling=z.scaling)
+                bias = self.param("router_bias", nn.initializers.zeros,
+                                  (z.n_experts,), f32)
+                if z.router == "sigmoid":
+                    chosen, weight = moe.route(
+                        x, self.param("router", lecun, (d, z.n_experts),
+                                      f32),
+                        bias, top_k=z.top_k, scaling=z.scaling)
+                else:
+                    r = z.d_router
+                    chosen, weight, state = moe.route_mlp_softmax(
+                        x, state.reshape(tokens, r), {
+                            "down": self.param("router_down", lecun,
+                                               (d, r), f32),
+                            "gamma": self.param(
+                                "router_gamma", nn.initializers.ones, (r,),
+                                f32),
+                            "norm": self.param(
+                                "router_norm", nn.initializers.ones, (r,),
+                                f32),
+                            "w1": self.param("router_w1", lecun, (r, r),
+                                             f32),
+                            "w2": self.param("router_w2", lecun, (r, r),
+                                             f32),
+                            "w3": self.param("router_w3", lecun,
+                                             (r, z.n_experts), f32)},
+                        bias, eps=self.norm_eps)
+                    state = state.reshape(lead + (r,))
                 self.sow("intermediates", "chosen", chosen)
                 plan = moe.dispatch(chosen, (first, count), n_rows)
             with named_scope("moe-dispatch"):
                 rows = moe.gather_rows(x, plan)
-            routed = grouped_relu2_mlp(
-                rows,
-                self.param("experts_up", stacked_out_major,
-                           (count, z.d_expert, d), f32),
-                self.param("experts_down", stacked, (count, z.d_expert, d),
-                           f32), plan.tile_group, plan.n_live)
+            shape = (count, z.d_expert, d)
+            if z.expert == "relu2":
+                routed = grouped_relu2_mlp(
+                    rows,
+                    self.param("experts_up", stacked_out_major, shape, f32),
+                    self.param("experts_down", stacked, shape, f32),
+                    plan.tile_group, plan.n_live)
+            else:
+                routed = grouped_swiglu_mlp(
+                    rows,
+                    self.param("experts_gate", stacked_out_major, shape,
+                               f32),
+                    self.param("experts_up", stacked_out_major, shape, f32),
+                    self.param("experts_down", stacked, shape, f32),
+                    plan.tile_group, plan.n_live)
             with named_scope("moe-dispatch"):
                 out = moe.combine(routed, weight, plan, tokens)
-            with named_scope("moe-shared"):
-                out = out + Relu2FeedForward(d, z.d_shared, self.dtype,
-                                             name="shared")(x)
-            return out.astype(self.dtype).reshape(lead + (d,))
+            if z.d_shared:
+                with named_scope("moe-shared"):
+                    out = out + Relu2FeedForward(d, z.d_shared, self.dtype,
+                                                 name="shared")(x)
+            out = out.astype(self.dtype).reshape(lead + (d,))
+            return out if state is None else (out, state)
+
+
+def rebalance_routers(params, chosen, rate: float):
+    """``params`` (a :class:`TransformerLM`'s) with every expert layer's
+    ``router_bias`` moved one step by the balancing controller
+    (:func:`chainermn_tpu.parallel.moe_dropless.rebalance`), each by the
+    experts its own router chose this step.  ``chosen``: ``{layer name:
+    (tokens, top_k) int}``, as the layers sow them
+    (``intermediates/<layer>/ExpertLayer_0/chosen``) and a loss function
+    hands them back as its ``aux``.  A training loop calls it after the
+    optimizer's step; nothing else of ``params`` is touched."""
+    from chainermn_tpu.parallel.moe_dropless import rebalance
+
+    out = dict(params)
+    for name, took in chosen.items():
+        experts = dict(params[name]["ExpertLayer_0"])
+        experts["router_bias"] = rebalance(experts["router_bias"], took,
+                                           rate)
+        out[name] = dict(params[name], ExpertLayer_0=experts)
+    return out
+
+
+def rotate_partial(x, positions, rotary_dim: int, theta: float):
+    """Rotary positions on the first ``rotary_dim`` of the last axis of
+    ``x`` (b, S, heads, d_head), the rest passed through: dimension ``i``
+    of the first half of the rotated part pairs with ``i + rotary_dim /
+    2``, at the angle ``positions x theta^(-2 i / rotary_dim)``."""
+    half = rotary_dim // 2
+    freq = theta ** (-np.arange(half, dtype=np.float64) * 2.0 / rotary_dim)
+    angle = positions.astype(jnp.float32)[:, None] * jnp.asarray(
+        freq, jnp.float32)                                   # (S, half)
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    a, b, rest = (x[..., :half], x[..., half:rotary_dim],
+                  x[..., rotary_dim:])
+    return jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin, rest], axis=-1)
+
+
+def shift_tokens(x, by: int):
+    """``x`` (b, S, ...) moved ``by`` tokens later along the sequence,
+    zeros before the first: what a causal tap reads."""
+    if by == 0:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[1] = (by, 0)
+    return jnp.pad(x, pad)[:, :x.shape[1]]
+
+
+class CCAMixer(nn.Module):
+    """Compressed convolutional attention (arXiv:2510.04476) as the
+    ``zaya`` family lays it out (a :class:`CCASpec` row): queries and keys
+    are projected DOWN into a latent of ``n_heads`` and ``n_kv_heads``
+    heads, ``q~ = h W_q``, ``k~ = h W_k``; the values are two halves,
+    ``[h W_v1 ; h_(t-1) W_v2]`` (with two KV heads: head 0 reads this
+    token, head 1 the one before); over the query and key channels a
+    causal depthwise convolution of ``time0`` taps and then one of
+    ``time1`` taps grouped by head, both with a bias; ``q = conv[:q] +
+    (q~ + repeat(k~)) / 2``, ``k = conv[q:] + (mean_group(q~) + k~) / 2``;
+    per head ``q <- sqrt(D) q / |q|``, ``k <- temp_head sqrt(D) k / |k|``
+    (``temp``: one learned float32 a KV head), rotary positions on the
+    first ``rotary_dim`` dimensions; causal GQA softmax attention at
+    ``1/sqrt(D)`` IN THE LATENT; ``W_o`` back up to the model.  Every
+    sequence starts at position 0 with nothing before it: training and
+    whole-sequence evaluation (no cache, no sequence sharding)."""
+
+    d_model: int
+    cca: CCASpec
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, h, mask=None):
+        from chainermn_tpu.ops.ssd import publish_geometry
+
+        z = self.cca
+        f32 = jnp.float32
+        Hq, Hkv, D = z.n_heads, z.n_kv_heads, z.d_head
+        G, group, C = Hq + Hkv, Hq // Hkv, z.conv_dim
+        B, S = h.shape[:2]
+        if telemetry_active():
+            publish_geometry("cca_geometry", "cca", {
+                "seq": S, "d_model": self.d_model, "q_heads": Hq,
+                "kv_heads": Hkv, "d_head": D, "latent_q": Hq * D,
+                "latent_kv": Hkv * D, "taps0": z.time0, "taps1": z.time1,
+                "rotary_dim": z.rotary_dim}, form="xla_shifts")
+        scale = 1.0 / np.sqrt(D)
+        if self.attention_fn is not None and getattr(
+                self.attention_fn, "scale", None) not in (None, scale):
+            raise ValueError(
+                f"the cca mixer attends at 1/sqrt(d_head), the "
+                f"attention_fn was built with scale "
+                f"{self.attention_fn.scale}")
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, dtype=self.dtype, use_bias=False, name=name)
+        normal = nn.initializers.lecun_normal()
+        with named_scope("cca-mixer"):
+            q0 = dense(Hq * D, "query")(h)
+            k0 = dense(Hkv * D, "key")(h)
+            with named_scope("cca-conv"):
+                v = jnp.concatenate([
+                    dense(Hkv * D // 2, "value_now")(h),
+                    dense(Hkv * D // 2, "value_before")(shift_tokens(h, 1)),
+                ], axis=-1).reshape(B, S, Hkv, D)
+                w0 = self.param("conv0_kernel", normal, (z.time0, C), f32)
+                w1 = self.param(
+                    "conv1_kernel", nn.initializers.lecun_normal(
+                        in_axis=(0, 2), out_axis=3, batch_axis=(1,)),
+                    (z.time1, G, D, D), f32)
+                u = jnp.concatenate([q0, k0], axis=-1).astype(f32)
+                c = self.param("conv0_bias", nn.initializers.zeros, (C,),
+                               f32) + sum(   # the last tap: this token
+                    shift_tokens(u, z.time0 - 1 - j) * w0[j]
+                    for j in range(z.time0))
+                c = c.astype(self.dtype).reshape(B, S, G, D)
+                c = self.param("conv1_bias", nn.initializers.zeros, (C,),
+                               f32).reshape(G, D) + sum(
+                    jnp.einsum("bsgd,gde->bsge",
+                               shift_tokens(c, z.time1 - 1 - j),
+                               w1[j].astype(self.dtype)).astype(f32)
+                    for j in range(z.time1))
+                qh = q0.astype(f32).reshape(B, S, Hkv, group, D)
+                kh = k0.astype(f32).reshape(B, S, Hkv, 1, D)
+                q = c[:, :, :Hq] + (0.5 * (qh + kh)).reshape(B, S, Hq, D)
+                k = c[:, :, Hq:] + 0.5 * (
+                    jnp.mean(qh, axis=3) + kh[:, :, :, 0])
+            with named_scope("cca-rope"):
+                temp = self.param("temp", nn.initializers.ones, (Hkv,), f32)
+
+                def unit(x):
+                    return x * (np.sqrt(D) * jax.lax.rsqrt(jnp.sum(
+                        jnp.square(x), axis=-1, keepdims=True) + 1e-12))
+
+                q, k = unit(q), unit(k) * temp[:, None]
+                if z.rotary_dim:
+                    pos = jnp.arange(S)
+                    q = rotate_partial(q, pos, z.rotary_dim, z.rope_theta)
+                    k = rotate_partial(k, pos, z.rotary_dim, z.rope_theta)
+                q, k = q.astype(self.dtype), k.astype(self.dtype)
+            if self.attention_fn is not None:
+                out = self.attention_fn(q, k, v, mask)
+            else:
+                kk = jnp.repeat(k, group, axis=2)
+                vv = jnp.repeat(v, group, axis=2)
+                logits = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale
+                if mask is not None:
+                    logits = jnp.where(mask, logits,
+                                       jnp.finfo(jnp.float32).min)
+                weights = nn.softmax(logits.astype(f32)).astype(self.dtype)
+                out = jnp.einsum("bhqk,bkhd->bqhd", weights, vv)
+            return dense(self.d_model, "out")(out.reshape(B, S, Hq * D))
 
 
 class Mamba2Mixer(nn.Module):
@@ -559,7 +755,12 @@ class Mamba2Mixer(nn.Module):
 class Block(nn.Module):
     """One layer, built from its row of the block table: ``x + rm *
     mixer(norm(x))`` where the row has a mixer, then ``x + rm *
-    ffn(norm(x))`` where it has an FFN."""
+    ffn(norm(x))`` where it has an FFN.  A row whose experts' router
+    keeps a state (``ExpertsSpec.d_router``) is called with the state the
+    layer before handed on, ``(x, mask, router_state)``, and returns
+    ``(x, router_state)``: a second value from layer to layer beside the
+    residual stream, an argument and a result, never hidden state (any
+    row called with a state returns the pair, its own rows' or not)."""
 
     d_model: int
     row: LayerSpec
@@ -574,7 +775,8 @@ class Block(nn.Module):
     sp_axis: Optional[str] = None
 
     @nn.compact
-    def __call__(self, x, mask=None, *, block_tables=None, seq_lens=None):
+    def __call__(self, x, mask=None, router_state=None, *,
+                 block_tables=None, seq_lens=None):
         row = self.row
 
         def norm():
@@ -606,14 +808,33 @@ class Block(nn.Module):
                 )
             x = residual(x, Mamba2Mixer(self.d_model, row.ssm, row.norm_eps,
                                         self.dtype)(norm()(x)))
+        elif row.mixer == "cca":
+            if self.decode or self.paged is not None:
+                raise ValueError(
+                    "a cca layer keeps no cache between calls (its "
+                    "convolutions and its value read the token before): "
+                    "incremental decoding and the paged KV cache are "
+                    "built for attention layers only"
+                )
+            x = residual(x, CCAMixer(self.d_model, row.cca, self.dtype,
+                                     self.attention_fn)(norm()(x), mask))
+
+        def handed_on(x):
+            return x if router_state is None else (x, router_state)
+
         if row.ffn == "experts":
-            return residual(x, ExpertLayer(self.d_model, row.experts,
-                                           self.dtype)(norm()(x)))
+            experts = ExpertLayer(self.d_model, row.experts, self.dtype,
+                                  row.norm_eps)
+            if not row.experts.d_router:
+                return handed_on(residual(x, experts(norm()(x))))
+            out, router_state = experts(norm()(x), router_state)
+            return residual(x, out), router_state
         if row.ffn == "none":
-            return x
+            return handed_on(x)
         ffn = {"gelu": FeedForward, "swiglu": GatedFeedForward,
                "relu2": Relu2FeedForward}[row.ffn]
-        return residual(x, ffn(self.d_model, row.d_ff, self.dtype)(norm()(x)))
+        return handed_on(residual(
+            x, ffn(self.d_model, row.d_ff, self.dtype)(norm()(x))))
 
 
 def EncoderLayer(d_model: int, n_heads: int, d_ff: int,
@@ -731,7 +952,8 @@ class TransformerLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, position_offset=None, return_hidden=False,
-                 inputs_embeds=None, block_tables=None, seq_lens=None):
+                 inputs_embeds=None, block_tables=None, seq_lens=None,
+                 router_state=None):
         """``position_offset``: global position of this shard's first token —
         pass ``axis_index * S_local`` when the sequence dimension is sharded
         (sequence parallelism); requires a sequence-aware ``attention_fn``
@@ -765,6 +987,13 @@ class TransformerLM(nn.Module):
         ``return_hidden=True`` so the (equally vocab-sharded) LM head
         runs outside too.
 
+        ``router_state``: for a table whose routers keep a state
+        (``BlockTable.d_router_state`` wide; ``zaya``), what the pipeline
+        stage before this one handed over, ``(B, S, d_router_state)``
+        float32; None starts from zeros (the first stage).  The layers
+        pass it on beside ``x``; what the last layer hands on is ``sow``n
+        as ``intermediates/router_state`` for the stage after.
+
         ``remat=True`` wraps every layer in ``jax.checkpoint``: backward
         recomputes layer activations instead of storing ~6 per-layer
         tensors — the standard long-context memory/FLOP trade."""
@@ -772,6 +1001,11 @@ class TransformerLM(nn.Module):
 
         table = self.block_table
         S = tokens.shape[1]
+        if table.positions == "rotary" and position_offset is not None:
+            raise ValueError(
+                "rotary positions are built from 0 inside the cca mixer, "
+                "whose convolutions and value read the token before: a "
+                "sharded or offset sequence is not built")
         pos = None
         if table.positions == "sinusoidal":
             pe = jnp.asarray(sinusoidal_positions(self.max_len, self.d_model))
@@ -818,15 +1052,28 @@ class TransformerLM(nn.Module):
             nn.remat(Block, static_argnums=(), policy=policy)
             if self.remat else Block
         )
+        if table.d_router_state and router_state is None:
+            router_state = jnp.zeros(
+                x.shape[:2] + (table.d_router_state,), jnp.float32)
+        if router_state is not None and not table.d_router_state:
+            raise ValueError("router_state given to a table whose routers "
+                             "keep none")
         for i, row in enumerate(table.layers):
-            x = layer_cls(
+            layer = layer_cls(
                 self.d_model, row, self.dtype, self.attention_fn,
                 name=f"layer_{i}", decode=self.decode,
                 cache_len=self.max_len if self.decode else 0,
                 paged=self.paged, page_count=self.page_count,
                 page_size=self.page_size, kv_dtype=self.kv_dtype,
                 sp_axis=self.sp_axis,
-            )(x, mask, block_tables=block_tables, seq_lens=seq_lens)
+            )
+            if router_state is None:
+                x = layer(x, mask, block_tables=block_tables,
+                          seq_lens=seq_lens)
+            else:
+                x, router_state = layer(x, mask, router_state)
+        if router_state is not None:
+            self.sow("intermediates", "router_state", router_state)
         norm_cls = (nn.LayerNorm if table.final_norm == "layernorm"
                     else nn.RMSNorm)
         x = norm_cls(epsilon=table.norm_eps, dtype=self.dtype,

@@ -61,12 +61,18 @@ _ALLREDUCE_STAGE = re.compile(r"^grad-stage\d+$")
 #: (matmul, sigmoid, top-k, the sort of the pairs by expert), the gather
 #: of the held experts' rows and their weighted scatter back, the grouped
 #: matmuls of the held experts (``ops/grouped_matmul.py``, forward and
-#: backward), the shared expert.  The innermost name on an op's path is
-#: its region.
+#: backward), the shared expert; a compressed-convolutional-attention
+#: mixer from its down-projections to its output projection
+#: (``models/transformer.py``: the flash regions nest in it) and, nested
+#: in it, the two causal convolutions with the q-k mean and the value's
+#: shift (``cca-conv``) and the queries' and keys' L2 norms, the keys'
+#: learned scale and the rotation (``cca-rope``).  The innermost name on
+#: an op's path is its region.
 KERNEL_REGIONS = (
     "flash-fwd", "flash-bwd-dq", "flash-bwd-dkv", "fused-ce",
     "paged-decode-attn", "mamba-mixer", "ssd-scan", "ssm-conv",
     "moe-layer", "moe-route", "moe-dispatch", "moe-experts", "moe-shared",
+    "cca-mixer", "cca-conv", "cca-rope",
 )
 
 #: What the jitted programs compile as (``jit_<name>`` in a capture's

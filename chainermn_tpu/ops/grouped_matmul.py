@@ -268,3 +268,20 @@ def grouped_relu2_mlp(rows, w_up, w_down, tile_group, n_live):
         hidden = jnp.square(jax.nn.relu(hidden))
     return checkpoint_name(
         grouped_matmul(hidden, w_down, tile_group, n_live), SAVED_PRODUCTS)
+
+
+def grouped_swiglu_mlp(rows, w_gate, w_up, w_down, tile_group, n_live):
+    """``(silu(rows w_gate[g]^T) * (rows w_up[g]^T)) w_down[g]``, each
+    tile by its group's three matrices, all (G, d_expert, d_model): an
+    expert FFN of the gated kind over the held experts' rows, three
+    grouped matmuls and the gate's product between them."""
+    gate = checkpoint_name(
+        grouped_matmul(rows, w_gate, tile_group, n_live, True),
+        SAVED_PRODUCTS)
+    up = checkpoint_name(
+        grouped_matmul(rows, w_up, tile_group, n_live, True), SAVED_PRODUCTS)
+    with named_scope("moe-experts"):
+        hidden = (jax.nn.silu(gate.astype(jnp.float32))
+                  * up.astype(jnp.float32)).astype(rows.dtype)
+    return checkpoint_name(
+        grouped_matmul(hidden, w_down, tile_group, n_live), SAVED_PRODUCTS)

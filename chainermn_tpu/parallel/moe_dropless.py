@@ -1,5 +1,13 @@
 """Dropless top-k-of-many expert routing for one expert-parallel rank.
 
+Two routers, one dispatch: :func:`route` (sigmoid scores of one matrix,
+top-k, weights normalised over the chosen) and :func:`route_mlp_softmax`
+(a small MLP over a state handed from layer to layer, a softmax, the one
+most probable expert weighed by its probability).  Both are float32 at
+``highest`` throughout and return ``(chosen, weight)`` alike; everything
+after them is shared.  Neither's bias gets a gradient; :func:`rebalance`
+is one step of the controller that moves it by the load instead.
+
 The rank is told which experts it holds, ``(first, count)`` of the
 published ``n_experts``.  It routes every token over ALL the experts (the
 router keeps its published width), sorts the ``tokens x top_k`` (token,
@@ -59,12 +67,15 @@ BOUND_OVER_EXPECTED = 4
 
 def rows_bound(pairs: int, count: int, n_experts: int) -> int:
     """Rows the held experts' buffer is laid out for: every pair where
-    the rank holds a quarter of the experts or more."""
+    the rank holds a quarter of the experts or more (8 of 16 at one
+    expert a token: all 16,384 pairs of a 16,384-token step)."""
     return min(pairs, -(-BOUND_OVER_EXPECTED * pairs * count // n_experts))
 
 
 def route(h, w_router, bias, *, top_k: int, scaling: float = 1.0):
-    """Sigmoid top-k router, float32 throughout.
+    """Sigmoid top-k router (an ``ExpertsSpec`` whose ``router`` is
+    ``"sigmoid"``; ``"mlp_softmax"`` is :func:`route_mlp_softmax`),
+    float32 throughout.
 
     ``h``: (T, d); ``w_router``: (d, E); ``bias``: (E,), the per-expert
     correction added to the scores for the CHOICE only (it gets no
@@ -82,6 +93,53 @@ def route(h, w_router, bias, *, top_k: int, scaling: float = 1.0):
     weight = jnp.take_along_axis(scores, chosen, axis=-1)
     weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
     return chosen, weight * scaling
+
+
+def route_mlp_softmax(h, state, params, bias, *, eps: float):
+    """The ZAYA router (arXiv:2511.17127), float32 throughout: top-1 of a
+    softmax over an MLP of a state that passes from layer to layer.
+
+    ``h``: (T, d); ``state``: (T, r) float32, the state the layer before
+    handed on (zeros before the first); ``params``: ``down`` (d, r),
+    ``gamma`` (r,), ``norm`` (r,), ``w1``, ``w2`` (r, r), ``w3`` (r, E);
+    ``bias``: (E,), the balancing bias, added to the probabilities for the
+    CHOICE only (no gradient; the weight does not see it).  ``r_l = h
+    down + gamma * r_(l-1)``; ``p = softmax(gelu(gelu(RMSNorm(r_l) w1)
+    w2) w3)``.  Returns ``(chosen, weight, r_l)``: (T, 1) int32, the
+    expert with the largest ``p + bias`` (ties to the lower index); (T,
+    1) float32, ``p[chosen]`` itself — with one expert a token a weight
+    normalised over the chosen is 1 and teaches the router nothing —;
+    and the state to hand on."""
+    f32 = jnp.float32
+    dot = lambda a, b: jnp.dot(  # noqa: E731
+        a, b.astype(f32), precision=lax.Precision.HIGHEST)
+    state = dot(h.astype(f32), params["down"]) + (
+        params["gamma"].astype(f32) * state.astype(f32))
+    x = state * lax.rsqrt(
+        jnp.mean(jnp.square(state), axis=-1, keepdims=True) + eps
+    ) * params["norm"].astype(f32)
+    x = jax.nn.gelu(dot(x, params["w1"]), approximate=False)
+    x = jax.nn.gelu(dot(x, params["w2"]), approximate=False)
+    p = jax.nn.softmax(dot(x, params["w3"]), axis=-1)
+    _, chosen = lax.top_k(p + lax.stop_gradient(bias.astype(f32)), 1)
+    chosen = checkpoint_name(chosen.astype(jnp.int32), ROUTER_CHOICE)
+    return chosen, jnp.take_along_axis(p, chosen, axis=-1), state
+
+
+def rebalance(bias, chosen, rate: float):
+    """One step of a proportional balancing controller on a router's
+    bias, applied BESIDE the optimizer's update (the bias gets no
+    gradient: it enters the choice only).  ``bias``: (E,); ``chosen``:
+    (T, top_k), the experts the router chose this step.  The bias of an
+    expert that took more than its even share ``1/E`` of the pairs falls
+    by ``rate`` times the excess share, that of one that took less rises;
+    their sum stays.  The bias integrates the error, so a load held off
+    its share by a steady drift of the router's weights settles
+    ``drift / rate`` away from even."""
+    n = bias.shape[0]
+    share = jnp.mean(jax.nn.one_hot(
+        chosen.reshape(-1), n, dtype=jnp.float32), axis=0)
+    return bias + rate * (1.0 / n - share)
 
 
 class Dispatch(NamedTuple):

@@ -224,8 +224,10 @@ class InferenceEngine:
                 f"InferenceEngine does not serve a model built from a "
                 f"block table (mixers: {kinds}): the paged cache and the "
                 f"scheduler keep keys and values only, no recurrent "
-                f"state, and the page geometry is read from n_heads / "
-                f"n_layers (ROADMAP.md R3)"
+                f"state (a mamba2 mixer's), no token before the first (a "
+                f"cca mixer's convolutions and value shift) and no router "
+                f"state handed from layer to layer, and the page geometry "
+                f"is read from n_heads / n_layers (ROADMAP.md R3)"
             )
         cfg = (config or EngineConfig(max_len=lm.max_len)).resolved()
         if cfg.max_len > lm.max_len:

@@ -319,3 +319,58 @@ def test_grouped_matmuls_compile_at_the_expert_cells_widths(one_chip,
     assert compiled.as_text().count("tpu_custom_call") == 4
     rows_mb = n_tiles * tile_rows * d * 2 / 1e6
     assert compiled.memory_analysis().temp_size_in_bytes / 1e6 < 4 * rows_mb
+
+
+def test_zaya_layer_compiles_at_the_cells_shape(one_chip, monkeypatch):
+    """One ``zaya`` layer at the cell's shape (2 x 8192 tokens, the CCA
+    mixer's latent 8/2 heads of 128, 8 of 16 gated experts of 2048 held:
+    a buffer of 18,432 rows), forward and backward under remat with the
+    expert layer's policy as in the step: the three flash calls (the
+    forward twice) and nine grouped ones — gate, up and down once each
+    forward (kept, not recomputed), three ``dx`` and three ``dw`` — whole
+    2048 x 2048 matrices as one block inside the VMEM limit the calls
+    state."""
+    from chainermn_tpu.models.block_table import CCASpec, ExpertsSpec, LayerSpec
+    from chainermn_tpu.models.transformer import Block
+    from chainermn_tpu.ops import make_flash_attention_fn
+    from chainermn_tpu.parallel.moe_dropless import REMAT_SAVES
+
+    gm = importlib.import_module("chainermn_tpu.ops.grouped_matmul")
+    monkeypatch.setattr(fa, "default_interpret", lambda: False)
+    monkeypatch.setattr(gm, "default_interpret", lambda: False)
+    assert gm.weight_blocks(2048, 2048, 2) == (2048, 2048)
+    row = LayerSpec(
+        mixer="cca", norm="rmsnorm", ffn="experts", norm_eps=1e-5,
+        cca=CCASpec(n_heads=8, n_kv_heads=2, d_head=128, rotary_dim=64,
+                    rope_theta=5e6),
+        experts=ExpertsSpec(n_experts=16, top_k=1, d_expert=2048,
+                            d_shared=0, held=(0, 8), router="mlp_softmax",
+                            expert="swiglu", d_router=256))
+    layer = Block(2048, row, jnp.bfloat16,
+                  make_flash_attention_fn(causal=True))
+
+    def arr(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    x, r = arr((2, 8192, 2048), jnp.bfloat16), arr((2, 8192, 256),
+                                                   jnp.float32)
+    params = jax.tree.map(
+        lambda a: arr(a.shape, a.dtype),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 256, 2048), jnp.bfloat16),
+            None, jnp.zeros((1, 256, 256), jnp.float32))))
+
+    def loss(params, x, r):
+        fn = jax.checkpoint(
+            lambda p, x, r: layer.apply(p, x, None, r),
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *REMAT_SAVES))
+        out, state = fn(params, x, r)
+        return (jnp.sum(out.astype(jnp.float32) ** 2)
+                + jnp.sum(state ** 2))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        params, x, r).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4 + 9
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
