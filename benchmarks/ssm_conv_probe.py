@@ -53,10 +53,11 @@ def plain_conv_silu(x, kernel, bias):
     return jax.nn.silu(acc).astype(x.dtype)
 
 
-def device_ms(programs, calls):
+def device_ms(programs, calls, top=6):
     """``{name: (compiled, operands)}`` → ``{name: {"ms": [...],
     "ops_ms": [...]}}``: every program run ``calls`` times in ONE capture,
-    in order, timed on the device's clock.  Empty rows off the chip."""
+    in order, timed on the device's clock, with its ``top`` largest ops.
+    Empty rows off the chip."""
     for c, operands in programs.values():
         jax.block_until_ready(c(*operands))
     logdir = tempfile.mkdtemp(prefix="ssm_conv_probe_")
@@ -84,7 +85,7 @@ def device_ms(programs, calls):
                     (end - start) * 1e3 / calls)
         rows[name] = {
             "ms": [round((t - s) * 1e3, 4) for _, s, t in runs],
-            "ops_ms": [[op, round(ms, 4)] for op, ms in by_op.most_common(6)]}
+            "ops_ms": [[op, round(ms, 4)] for op, ms in by_op.most_common(top)]}
     return rows
 
 

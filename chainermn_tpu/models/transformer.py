@@ -475,16 +475,17 @@ class ExpertLayer(nn.Module):
             raise ValueError("an mlp_softmax router, and only it, takes "
                              "the router state of the layer before")
         if telemetry_active():
+            n_tiles = moe.buffer_tiles(n_rows, count)
             publish_geometry("moe_geometry", "moe", {
                 "experts": z.n_experts, "experts_held": count,
                 "top_k": z.top_k, "tokens": tokens,
                 "pair_rows": tokens * z.top_k,
-                "buffer_rows": TILE_ROWS * moe.buffer_tiles(n_rows, count),
-                "tile_rows": TILE_ROWS,
+                "buffer_rows": TILE_ROWS * n_tiles, "tile_rows": TILE_ROWS,
+                "dispatch_steps": n_tiles,
                 **dict(zip(("d_block", "expert_block"), weight_blocks(
                     d, z.d_expert, jnp.dtype(self.dtype).itemsize)))},
                 form="pallas_tile_aligned", router=z.router,
-                expert=z.expert)
+                expert=z.expert, dispatch_form=moe.DISPATCH_FORM)
         with named_scope("moe-layer"):
             x = h.reshape(tokens, d)
             with named_scope("moe-route"):

@@ -31,10 +31,29 @@ would be 0.5 GB a layer of rows that hold nothing.  A pair past the bound
 cannot be computed, and then the layer's output is NaN, so that the step's
 loss is: loud, never a silent drop.  :func:`load_stats` counts what a
 batch did.
+
+What the buffer's dead tiles cost.  The live tiles are a prefix of the
+buffer (``plan.n_live``), and the rows move through one pair of
+primitives that are each other's transpose and never visit a dead tile:
+:func:`take_rows` gathers a live tile's rows a step of a loop whose trip
+count is ``n_live`` (0.011 ms a tile of 256 rows x 2688; a dead tile
+costs its share of one zero fill of the buffer, 0.002 ms), and
+:func:`add_rows` reads from the tokens' side — every token's first row
+gathered (one pass over the tokens, whatever the buffer's size), the
+further rows of tokens with several scatter-added a tile's worth a step
+(none at one expert a token).  :func:`gather_rows` and :func:`combine`
+are the two joined by ``custom_vjp``: all five row movements of a
+rematerialised layer's step go through the same two pieces of code.
+Until PR 33 both were XLA ops over every row of the buffer, dead or not
+(PERF.md section 6: a scatter-add of 26,624 float32 rows took 3.1 ms, a
+row gather 1.4 + 0.8 ms for its select; ``benchmarks/
+moe_dispatch_probe.py`` keeps that form and the others that were
+weighed).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Tuple
 
 import jax
@@ -44,7 +63,13 @@ from jax import lax
 
 from jax.ad_checkpoint import checkpoint_name
 
-from chainermn_tpu.ops.grouped_matmul import SAVED_PRODUCTS, TILE_ROWS
+# (``named_scope``, the scope vocabulary's, comes by way of the kernels'
+# module: this layer imports ``ops``, which owns the sibling scope)
+from chainermn_tpu.ops.grouped_matmul import (
+    SAVED_PRODUCTS,
+    TILE_ROWS,
+    named_scope,
+)
 
 #: The name (``jax.ad_checkpoint.checkpoint_name``) of the routers'
 #: choice, 24 bytes a token.  With it saved a rematerialised layer's
@@ -151,6 +176,12 @@ class Dispatch(NamedTuple):
     tile_group: jax.Array   # (n_tiles,) int32: the tile's local expert
     n_live: jax.Array       # (1,) int32: tiles that hold anything
     past_bound: jax.Array   # () int32: held pairs the buffer had no row for
+    first_row: jax.Array    # (T,) int32: the row of a token's first held
+    #                         choice (``rows`` if it has none)
+    more_rows: jax.Array    # (rows,) int32: the rows of further choices,
+    #                         packed to the front in row order; (0,) at one
+    #                         expert a token
+    n_more: jax.Array       # () int32: how many of them there are
 
 
 def buffer_tiles(rows: int, count: int, tile_rows: int = TILE_ROWS) -> int:
@@ -164,14 +195,24 @@ def dispatch(chosen, held: Tuple[int, int], rows: int,
              tile_rows: int = TILE_ROWS) -> Dispatch:
     """Sort the (token, choice) pairs by expert and lay those of the held
     experts out in the tiles of a buffer for ``rows`` rows, each group
-    from a tile's first row."""
+    from a tile's first row — and the same plan from the tokens' side,
+    for :func:`add_rows`: the row of each token's first choice on a held
+    expert, and the rows of its further ones."""
     first, count = held
     T, k = chosen.shape
     pairs = T * k
     n_tiles = buffer_tiles(rows, count, tile_rows)
     expert = chosen.reshape(pairs) - first
     local = jnp.where((expert >= 0) & (expert < count), expert, count)
-    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    # Sorted with each pair goes one bit: whether it is a FURTHER pair of
+    # its token, one after the token's first on a held expert (none at one
+    # expert a token).  The tokens' side of the plan is read off it.
+    with named_scope("moe-dispatch"):
+        on_held = (local < count).reshape(T, k)
+        further = on_held & (jnp.cumsum(on_held, axis=1) > 1)
+    _, order = lax.sort(
+        (local, 2 * jnp.arange(pairs, dtype=jnp.int32)
+         + further.reshape(pairs)), num_keys=1, is_stable=True)
     sizes = jnp.sum(local[:, None] == jnp.arange(count), axis=0,
                     dtype=jnp.int32)     # (a scatter of ones is 5x slower)
     tiles = jnp.maximum(1, -(-sizes // tile_rows))
@@ -188,31 +229,178 @@ def dispatch(chosen, held: Tuple[int, int], rows: int,
     group = jnp.repeat(tile_group, tile_rows)
     within = row - tile_start[group] * tile_rows
     valid = (row < n_live * tile_rows) & (within < sizes[group])
-    pair = jnp.where(
-        valid, order[jnp.clip(pair_start[group] + within, 0, pairs - 1)], 0)
+    sorted_pair = order[jnp.clip(pair_start[group] + within, 0, pairs - 1)]
+    pair = jnp.where(valid, sorted_pair // 2, 0)
+    token = pair // k
+    with named_scope("moe-dispatch"):
+        # the plan from the tokens' side: where each token's first row is,
+        # and the further rows packed to the front of a list, in row order
+        more = valid & (sorted_pair % 2 == 1)
+        first_row = jnp.full((T,), row.shape[0], jnp.int32).at[
+            jnp.where(valid & ~more, token, T)].set(row, mode="drop")
+        packed = jnp.cumsum(more, dtype=jnp.int32) - 1
+        more_rows = jnp.zeros_like(row).at[
+            jnp.where(more, packed, row.shape[0])].set(row, mode="drop")
+        if k == 1:             # no token has a further row: an empty list
+            more_rows = more_rows[:0]
     return Dispatch(
-        token=pair // k, pair=pair, valid=valid, tile_group=tile_group,
+        token=token, pair=pair, valid=valid, tile_group=tile_group,
         n_live=n_live.reshape(1).astype(jnp.int32),
-        past_bound=jnp.sum(sizes) - jnp.sum(valid.astype(jnp.int32)))
+        past_bound=jnp.sum(sizes) - jnp.sum(valid.astype(jnp.int32)),
+        first_row=first_row, more_rows=more_rows, n_more=packed[-1] + 1)
 
 
+#: The dispatch's form, as the ``moe_geometry`` row names it: both row
+#: movers are XLA row gathers.  ``take_rows`` gathers a tile's rows a step
+#: of a loop over the plan's live tiles (a ``while`` whose trip count is
+#: ``plan.n_live``); ``add_rows`` gathers every token's first row, and
+#: only the further rows of tokens with several (``plan.n_more``: none at
+#: one expert a token) are scatter-added, a tile's worth a step.
+DISPATCH_FORM = "xla_gather_both_ways"
+
+
+def _tile_rows(plan: Dispatch) -> int:
+    return plan.token.shape[0] // plan.tile_group.shape[0]
+
+
+def _over_tiles(n, tile_rows: int, body, init):
+    """``body(first row, tile, carry)`` for ``n`` tiles in order, ``tile``
+    taking a tile's slice of a (rows, ...) array."""
+
+    def step(t, carry):
+        at = t * tile_rows
+        return body(at, lambda a: lax.dynamic_slice_in_dim(
+            a, at, tile_rows), carry)
+
+    return lax.fori_loop(0, n, step, init)
+
+
+@jax.jit
+def _take_call(x, plan: Dispatch, scale=None, y=None):
+    """``take_rows``; with ``scale`` (rows,) and ``y`` (rows, d) the two
+    gradients of a weighted add instead: ``(the rows of x times scale in
+    y.dtype, the row sums of float32(y) times the rows of x)``."""
+    rows, d = plan.token.shape[0], x.shape[1]
+
+    def body(at, tile, carry):
+        ok = tile(plan.valid)[:, None]
+        took = jnp.where(ok, x[tile(plan.token)], 0)
+        if y is None:
+            return lax.dynamic_update_slice_in_dim(carry, took, at, 0)
+        out, dots = carry
+        dot = jnp.sum(jnp.where(ok, tile(y).astype(jnp.float32), 0.0)
+                      * took, axis=-1)
+        return (lax.dynamic_update_slice_in_dim(
+                    out, (took * tile(scale)[:, None]).astype(out.dtype),
+                    at, 0),
+                lax.dynamic_update_slice_in_dim(dots, dot, at, 0))
+
+    with named_scope("moe-dispatch"):
+        init = jnp.zeros((rows, d), x.dtype) if y is None else (
+            jnp.zeros((rows, d), y.dtype), jnp.zeros((rows,), jnp.float32))
+        return _over_tiles(
+            jnp.minimum(plan.n_live[0], plan.tile_group.shape[0]),
+            _tile_rows(plan), body, init)
+
+
+@jax.jit
+def _add_call(rows, plan: Dispatch, scale=None):
+    """``add_rows``, each row times ``scale`` (rows,) where given."""
+    n_rows, tile_rows = rows.shape[0], _tile_rows(plan)
+
+    def weighed(at):
+        took = rows[at].astype(jnp.float32)
+        return took if scale is None else took * scale[at][:, None]
+
+    def body(at, tile, out):
+        more = tile(plan.more_rows)
+        listed = (at + jnp.arange(tile_rows) < plan.n_more)[:, None]
+        return out.at[plan.token[more]].add(
+            jnp.where(listed, weighed(more), 0.0))
+
+    with named_scope("moe-dispatch"):
+        has = plan.first_row < n_rows
+        first = jnp.where(has[:, None],
+                          weighed(jnp.where(has, plan.first_row, 0)), 0.0)
+        first = jnp.where(plan.past_bound > 0, jnp.nan, first)
+        if not plan.more_rows.shape[0]:
+            return first
+        return _over_tiles(-(-plan.n_more // tile_rows), tile_rows, body,
+                           first)
+
+
+def take_rows(x, plan: Dispatch):
+    """(rows, d) in ``x.dtype``: a live tile's rows are their tokens' rows
+    of ``x`` (T, d), zeros where a row holds no pair; the rows of dead
+    tiles are left unvisited, whatever they hold (the contract
+    ``grouped_matmul`` gives for its own output: read live rows only)."""
+    return _take_call(x, plan)
+
+
+def add_rows(rows, plan: Dispatch, n_tokens: int):
+    """(n_tokens, d) float32: every row of a live tile that holds a pair
+    added into its token's row, its first choice's row first and the
+    others in row order (their experts' order); the rows of dead tiles
+    are never read.  NaN throughout where a held pair found no row
+    (``plan.past_bound``)."""
+    assert n_tokens == plan.first_row.shape[0]
+    return _add_call(rows, plan)
+
+
+@jax.custom_vjp
 def gather_rows(x, plan: Dispatch):
-    """The held pairs' token rows, (rows, d); zeros where no pair is."""
-    return jnp.where(plan.valid[:, None], x[plan.token], 0)
+    """The held pairs' token rows, (rows, d); zeros where no pair is.
+    :func:`take_rows`, its transpose :func:`add_rows`."""
+    return take_rows(x, plan)
 
 
+def _gather_rows_fwd(x, plan):
+    # an empty array carries what the transpose needs of x: its dtype
+    return take_rows(x, plan), (plan, jnp.zeros((0,), x.dtype))
+
+
+def _gather_rows_bwd(saved, drows):
+    plan, like_x = saved
+    with named_scope("moe-dispatch"):
+        return _add_call(drows, plan).astype(like_x.dtype), None
+
+
+gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+def _pair_weights(weight, plan: Dispatch):
+    with named_scope("moe-dispatch"):
+        return jnp.where(plan.valid, weight.reshape(-1)[plan.pair], 0.0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def combine(y, weight, plan: Dispatch, n_tokens: int):
     """Add every row of ``y`` (rows, d) into its token's row, times the
-    pair's router weight: (n_tokens, d) float32.  NaN throughout where a
-    held pair found no row (``plan.past_bound``)."""
-    # The rows of dead tiles are whatever memory held: selected away
-    # before the product, so that neither side's gradient sees them.
-    w = jnp.where(plan.valid, weight.reshape(-1)[plan.pair], 0.0)
-    rows = jnp.where(plan.valid[:, None], y.astype(jnp.float32), 0.0
-                     ) * w[:, None]
-    out = jnp.zeros((n_tokens, y.shape[-1]), jnp.float32).at[
-        plan.token].add(rows)
-    return jnp.where(plan.past_bound > 0, jnp.nan, out)
+    pair's router weight (``weight``: (n_tokens, top_k) float32):
+    (n_tokens, d) float32.  NaN throughout where a held pair found no row
+    (``plan.past_bound``).  :func:`add_rows` of ``float32(y) * w``; its
+    transpose is :func:`take_rows` of the cotangent times ``w``, and the
+    weight's gradient the row sums of ``float32(y)`` times those rows."""
+    assert n_tokens == plan.first_row.shape[0]
+    return _add_call(y, plan, _pair_weights(weight, plan))
+
+
+def _combine_fwd(y, weight, plan, n_tokens):
+    return combine(y, weight, plan, n_tokens), (y, weight, plan)
+
+
+def _combine_bwd(n_tokens, saved, dout):
+    del n_tokens
+    y, weight, plan = saved
+    dy, dots = _take_call(dout, plan, _pair_weights(weight, plan), y)
+    with named_scope("moe-dispatch"):
+        # a pair has one row at most: the rows of dead tiles read zero
+        dweight = jnp.zeros((weight.size,), jnp.float32).at[plan.pair].add(
+            dots).reshape(weight.shape).astype(weight.dtype)
+    return dy, dweight, None
+
+
+combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def load_stats(chosen, n_experts: int, held: Tuple[int, int],
@@ -231,11 +419,12 @@ def load_stats(chosen, n_experts: int, held: Tuple[int, int],
     n_tiles = buffer_tiles(rows_bound(pairs, count, n_experts), count,
                            tile_rows)
     room = np.clip(n_tiles - (np.cumsum(tiles) - tiles), 0, None) * tile_rows
+    live = int(min(tiles.sum(), n_tiles))
     return {
         "pairs": int(pairs), "held_pairs": int(mine.sum()),
         "held_pairs_expected": pairs * count / n_experts,
         "max_load_over_mean": float(mine.max() / (pairs / n_experts)),
-        "live_tiles": int(min(tiles.sum(), n_tiles)),
-        "buffer_tiles": int(n_tiles),
+        "live_tiles": live, "buffer_tiles": int(n_tiles),
+        "live_rows_share": live / n_tiles,
         "pairs_past_bound": int(np.maximum(mine - room, 0).sum()),
     }
