@@ -99,9 +99,16 @@ class MultiHeadAttention(nn.Module):
         dense = lambda name, h: nn.DenseGeneral(  # noqa: E731
             (h, d_head), dtype=self.dtype, name=name, use_bias=False
         )
-        q = dense("query", self.n_heads)(q_in)
-        k = dense("key", n_kv)(kv_in)
-        v = dense("value", n_kv)(kv_in)
+        with named_scope("mixer-proj"):
+            q = dense("query", self.n_heads)(q_in)
+            k = dense("key", n_kv)(kv_in)
+            v = dense("value", n_kv)(kv_in)
+
+        def project_out(out):
+            with named_scope("mixer-proj"):
+                return nn.DenseGeneral(
+                    self.d_model, axis=(-2, -1), dtype=self.dtype,
+                    name="out", use_bias=False)(out)
 
         if self.paged is not None:
             # Paged KV cache (serving, docs/serving.md): K/V live in
@@ -265,10 +272,7 @@ class MultiHeadAttention(nn.Module):
                     q, pk.value, pv.value, block_tables, attn_start,
                     **scales(),
                 )
-                return nn.DenseGeneral(
-                    self.d_model, axis=(-2, -1), dtype=self.dtype,
-                    name="out", use_bias=False,
-                )(out)
+                return project_out(out)
             else:
                 if q.shape[1] != 1:
                     raise ValueError(
@@ -280,10 +284,7 @@ class MultiHeadAttention(nn.Module):
                     q, pk.value, pv.value, block_tables, seq_lens + 1,
                     **scales(),
                 )
-                return nn.DenseGeneral(
-                    self.d_model, axis=(-2, -1), dtype=self.dtype,
-                    name="out", use_bias=False,
-                )(out)
+                return project_out(out)
 
         if self.decode:
             # KV cache (flax "cache" collection): one new token per call is
@@ -347,9 +348,7 @@ class MultiHeadAttention(nn.Module):
                 logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
             weights = nn.softmax(logits.astype(jnp.float32)).astype(self.dtype)
             out = jnp.einsum("bhqk,bkhd->bqhd", weights, v)
-        return nn.DenseGeneral(
-            self.d_model, axis=(-2, -1), dtype=self.dtype, name="out", use_bias=False
-        )(out)
+        return project_out(out)
 
 
 class FeedForward(nn.Module):
@@ -635,8 +634,15 @@ class CCAMixer(nn.Module):
                 f"the cca mixer attends at 1/sqrt(d_head), the "
                 f"attention_fn was built with scale "
                 f"{self.attention_fn.scale}")
-        dense = lambda n, name: nn.Dense(  # noqa: E731
-            n, dtype=self.dtype, use_bias=False, name=name)
+        def dense(n, name):
+            layer = nn.Dense(n, dtype=self.dtype, use_bias=False, name=name)
+
+            def project(x):
+                with named_scope("mixer-proj"):
+                    return layer(x)
+
+            return project
+
         normal = nn.initializers.lecun_normal()
         with named_scope("cca-mixer"):
             q0 = dense(Hq * D, "query")(h)
@@ -718,9 +724,10 @@ class Mamba2Mixer(nn.Module):
         z = self.ssm
         f32 = jnp.float32
         with named_scope("mamba-mixer"):
-            proj = nn.Dense(z.d_inner + z.conv_dim + z.n_heads,
-                            dtype=self.dtype, use_bias=False,
-                            name="in_proj")(h)
+            with named_scope("mixer-proj"):
+                proj = nn.Dense(z.d_inner + z.conv_dim + z.n_heads,
+                                dtype=self.dtype, use_bias=False,
+                                name="in_proj")(h)
             gate, xbc, dt = jnp.split(
                 proj, [z.d_inner, z.d_inner + z.conv_dim], axis=-1)
             xbc = causal_conv_silu(
@@ -733,24 +740,33 @@ class Mamba2Mixer(nn.Module):
                 xbc, [z.d_inner, z.d_inner + z.n_groups * z.d_state],
                 axis=-1)
             heads = (z.n_heads,)
-            dt = jax.nn.softplus(
-                dt.astype(f32) + self.param("dt_bias", _dt_bias_init, heads))
+            with named_scope("mixer-gate"):
+                dt = jax.nn.softplus(
+                    dt.astype(f32)
+                    + self.param("dt_bias", _dt_bias_init, heads))
             lead = x.shape[:2]
+            x = x.reshape(lead + (z.n_heads, z.d_head))
+            with named_scope("mixer-gate"):
+                decay = -jnp.exp(self.param("A_log", _a_log_init, heads))
             y = ssd_scan(
-                x.reshape(lead + (z.n_heads, z.d_head)), dt,
-                -jnp.exp(self.param("A_log", _a_log_init, heads)),
+                x, dt, decay,
                 B.reshape(lead + (z.n_groups, z.d_state)),
                 C.reshape(lead + (z.n_groups, z.d_state)),
                 self.param("D", nn.initializers.ones, heads, f32),
                 chunk=z.chunk)
-            y = y.reshape(lead + (z.d_inner,)).astype(f32)
+            y = y.reshape(lead + (z.d_inner,))
             norm = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                               name="norm") if z.norm_groups == 1 else (
                 GroupedRMSNorm(z.norm_groups, self.norm_eps, self.dtype,
                                name="norm"))
-            y = norm(y * nn.silu(gate.astype(f32)))
-            return nn.Dense(self.d_model, dtype=self.dtype, use_bias=False,
-                            name="out_proj")(y)
+            # The gated norm is a module called ``norm``: its own name on
+            # the path would read as the layers' pre-norm (``MODEL_PARTS``),
+            # so flax's per-module scope is off around this one call.
+            with named_scope("mixer-gate"), nn.override_named_call(False):
+                y = norm(y.astype(f32) * nn.silu(gate.astype(f32)))
+            with named_scope("mixer-proj"):
+                return nn.Dense(self.d_model, dtype=self.dtype,
+                                use_bias=False, name="out_proj")(y)
 
 
 class Block(nn.Module):
@@ -780,26 +796,30 @@ class Block(nn.Module):
                  block_tables=None, seq_lens=None):
         row = self.row
 
-        def norm():
+        def normed(x):
             cls = nn.LayerNorm if row.norm == "layernorm" else nn.RMSNorm
-            return cls(epsilon=row.norm_eps, dtype=self.dtype)
+            with named_scope("norm"):
+                return cls(epsilon=row.norm_eps, dtype=self.dtype)(x)
 
         def residual(x, branch):
-            if row.residual_multiplier != 1.0:
-                branch = branch * jnp.asarray(
-                    row.residual_multiplier, branch.dtype)
-            return x + branch
+            with named_scope("residual"):
+                if row.residual_multiplier != 1.0:
+                    branch = branch * jnp.asarray(
+                        row.residual_multiplier, branch.dtype)
+                return x + branch
 
         if row.mixer == "attention":
-            h = norm()(x)
-            x = residual(x, MultiHeadAttention(
-                self.d_model, row.n_heads, self.dtype, self.attention_fn,
-                decode=self.decode, cache_len=self.cache_len,
-                n_kv_heads=row.n_kv_heads, paged=self.paged,
-                page_count=self.page_count, page_size=self.page_size,
-                kv_dtype=self.kv_dtype, sp_axis=self.sp_axis,
-                scale=row.attn_scale, d_head=row.d_head,
-            )(h, h, mask, block_tables=block_tables, seq_lens=seq_lens))
+            h = normed(x)
+            with named_scope("attn-mixer"):
+                branch = MultiHeadAttention(
+                    self.d_model, row.n_heads, self.dtype, self.attention_fn,
+                    decode=self.decode, cache_len=self.cache_len,
+                    n_kv_heads=row.n_kv_heads, paged=self.paged,
+                    page_count=self.page_count, page_size=self.page_size,
+                    kv_dtype=self.kv_dtype, sp_axis=self.sp_axis,
+                    scale=row.attn_scale, d_head=row.d_head,
+                )(h, h, mask, block_tables=block_tables, seq_lens=seq_lens)
+            x = residual(x, branch)
         elif row.mixer == "mamba2":
             if self.decode or self.paged is not None:
                 raise ValueError(
@@ -808,7 +828,7 @@ class Block(nn.Module):
                     "are built for attention layers only"
                 )
             x = residual(x, Mamba2Mixer(self.d_model, row.ssm, row.norm_eps,
-                                        self.dtype)(norm()(x)))
+                                        self.dtype)(normed(x)))
         elif row.mixer == "cca":
             if self.decode or self.paged is not None:
                 raise ValueError(
@@ -818,7 +838,7 @@ class Block(nn.Module):
                     "built for attention layers only"
                 )
             x = residual(x, CCAMixer(self.d_model, row.cca, self.dtype,
-                                     self.attention_fn)(norm()(x), mask))
+                                     self.attention_fn)(normed(x), mask))
 
         def handed_on(x):
             return x if router_state is None else (x, router_state)
@@ -827,15 +847,17 @@ class Block(nn.Module):
             experts = ExpertLayer(self.d_model, row.experts, self.dtype,
                                   row.norm_eps)
             if not row.experts.d_router:
-                return handed_on(residual(x, experts(norm()(x))))
-            out, router_state = experts(norm()(x), router_state)
+                return handed_on(residual(x, experts(normed(x))))
+            out, router_state = experts(normed(x), router_state)
             return residual(x, out), router_state
         if row.ffn == "none":
             return handed_on(x)
         ffn = {"gelu": FeedForward, "swiglu": GatedFeedForward,
                "relu2": Relu2FeedForward}[row.ffn]
-        return handed_on(residual(
-            x, ffn(self.d_model, row.d_ff, self.dtype)(norm()(x))))
+        h = normed(x)
+        with named_scope("ffn"):
+            branch = ffn(self.d_model, row.d_ff, self.dtype)(h)
+        return handed_on(residual(x, branch))
 
 
 def EncoderLayer(d_model: int, n_heads: int, d_ff: int,
@@ -1007,36 +1029,41 @@ class TransformerLM(nn.Module):
                 "rotary positions are built from 0 inside the cca mixer, "
                 "whose convolutions and value read the token before: a "
                 "sharded or offset sequence is not built")
-        pos = None
-        if table.positions == "sinusoidal":
-            pe = jnp.asarray(sinusoidal_positions(self.max_len, self.d_model))
-            if position_offset is None:
-                pos = pe[:S]
-            elif getattr(position_offset, "ndim", 0):
-                # (S,) explicit per-token or (B, S) per-sequence positions
-                pos = pe[position_offset]
-            else:
-                pos = _lax.dynamic_slice_in_dim(pe, position_offset, S, axis=0)
-        if inputs_embeds is None:
-            embed = nn.Embed(
-                self.vocab, self.d_model, dtype=self.dtype, name="embed"
+        if inputs_embeds is not None and not return_hidden:
+            raise ValueError(
+                "inputs_embeds requires return_hidden=True: the tied "
+                "embed.attend head has no table when the lookup is "
+                "external (vocab-sharded) — compute the head with "
+                "the same external table"
             )
-            x = embed(tokens)
-        else:
-            if not return_hidden:
-                raise ValueError(
-                    "inputs_embeds requires return_hidden=True: the tied "
-                    "embed.attend head has no table when the lookup is "
-                    "external (vocab-sharded) — compute the head with "
-                    "the same external table"
+        with named_scope("embed"):
+            pos = None
+            if table.positions == "sinusoidal":
+                pe = jnp.asarray(
+                    sinusoidal_positions(self.max_len, self.d_model))
+                if position_offset is None:
+                    pos = pe[:S]
+                elif getattr(position_offset, "ndim", 0):
+                    # (S,) explicit per-token or (B, S) per-sequence
+                    # positions
+                    pos = pe[position_offset]
+                else:
+                    pos = _lax.dynamic_slice_in_dim(
+                        pe, position_offset, S, axis=0)
+            if inputs_embeds is None:
+                embed = nn.Embed(
+                    self.vocab, self.d_model, dtype=self.dtype, name="embed"
                 )
-            embed = None
-            x = inputs_embeds.astype(self.dtype)
-        if table.embedding_multiplier != 1.0:
-            x = x * jnp.asarray(table.embedding_multiplier, x.dtype)
-        if pos is not None:
-            # (B, S, d) is already per-batch
-            x = x + (pos if pos.ndim == 3 else pos[None]).astype(self.dtype)
+                x = embed(tokens)
+            else:
+                embed = None
+                x = inputs_embeds.astype(self.dtype)
+            if table.embedding_multiplier != 1.0:
+                x = x * jnp.asarray(table.embedding_multiplier, x.dtype)
+            if pos is not None:
+                # (B, S, d) is already per-batch
+                x = x + (pos if pos.ndim == 3 else pos[None]).astype(
+                    self.dtype)
         # Pluggable attention (flash/ring/ulysses) imposes its own
         # causality and ignores the mask argument — skip materializing
         # the (S, S) mask, which at long context is the largest host
@@ -1077,8 +1104,9 @@ class TransformerLM(nn.Module):
             self.sow("intermediates", "router_state", router_state)
         norm_cls = (nn.LayerNorm if table.final_norm == "layernorm"
                     else nn.RMSNorm)
-        x = norm_cls(epsilon=table.norm_eps, dtype=self.dtype,
-                     name="final_norm")(x)
+        with named_scope("norm"):
+            x = norm_cls(epsilon=table.norm_eps, dtype=self.dtype,
+                         name="final_norm")(x)
         head = None if table.tied_head else self.param(
             "lm_head", nn.initializers.normal(0.02),
             (self.vocab, self.d_model), jnp.float32)

@@ -8,13 +8,15 @@ in ``compiled.as_text()``; a fusion carries its root's).  Instruction
 names are unique within a program and the capture's op events are named
 by them, so the join needs nothing but public API:
 
-    scope_table(compiled)        {instruction name: op_name path}
-    attribute(ops, table)        device seconds by phase and by region
+    scope_table(compiled)        {instruction name: op_name path}, and
+                                 which path OWNS each instruction
+    attribute(ops, table)        device seconds by phase, by region and,
+                                 within ``fwd-bwd``, by owner
     idle_by_host_span(ops, host) idle seconds by ``chainermn:`` host span
     capture({name: jitted})      profile a caller's k steps, return one
                                  report (and hand it to the sinks)
 
-Two readings of one step (the vocabulary is ``observability/spans.py``):
+Three readings of one step (the vocabulary is ``observability/spans.py``):
 
 * **phase** — the outermost of ``fwd-bwd`` / ``allreduce`` /
   ``opt-update`` on an op's path.  Phases partition the busy time: with
@@ -26,11 +28,27 @@ Two readings of one step (the vocabulary is ``observability/spans.py``):
   tiles it finds live, visits and copies a head row
   (``spans.tiles_scope``, a component of the same paths).
 
+* **owner** — within ``fwd-bwd``, the innermost name of the kernel
+  regions AND the model's parts (``spans.MODEL_PARTS``: ``ffn``,
+  ``norm``, ``mixer-proj``, ...) on the path that owns the instruction,
+  ``"(none)"`` where that path names neither.  Owners partition the
+  phase.  ``pass`` cuts the same seconds by forward / recompute /
+  backward (the path's ``transpose(...)`` wrappers and
+  ``rematted_computation``), ``layer`` by the path's ``layer_<i>``.
+
 A fusion carries the ``op_name`` of ONE of the ops the compiler fused
-into it: a weight-gradient matmul with the AdamW update fused in counts
-whole as ``fwd-bwd`` (the matmul's) on the TPU compiler of this toolchain.
-``mixed`` is the busy time of such fusions — those that hold ops of a
-second phase — and so bounds what the phase reading cannot split.
+into it, and the phase and region readings take that one: a
+weight-gradient matmul with the AdamW update fused in counts whole as
+``fwd-bwd`` (the matmul's) on the TPU compiler of this toolchain.  The
+owner reading decides for itself: a fusion is owned by its heaviest op —
+its ``convolution`` / ``dot`` (the largest where it holds several), else
+the instruction with the largest result — and an instruction the
+compiler gave no ``op_name`` (``copy``, ``bitcast``) by its one
+consumer, else by its first operand's producer (``inherited``).  What
+no reading can split is said beside it: ``shared`` is, of each owner's
+seconds, those spent in fusions that hold ops of a second (phase,
+region) — a bound, not a split — and ``mixed`` its phase-level case,
+the busy time of fusions that hold ops of a second phase.
 Container ops (``while``, ``conditional``, ``call``) span the ops of
 their bodies and are left out; where two ops overlap, the time goes to
 the one that started last.
@@ -39,6 +57,7 @@ the one that started last.
 from __future__ import annotations
 
 import bisect
+import functools
 import glob
 import os
 import re
@@ -57,8 +76,14 @@ HOST_PLANE = "/host:CPU"
 #: report) when less than this share of the busy time joins to the table.
 MIN_JOINED_SHARE = 0.98
 
+#: The owner of ``fwd-bwd`` time whose owning path names no region.
+NO_OWNER = "(none)"
+PASSES = ("forward", "recompute", "backward")
+
 _WRAPPER = re.compile(r"^[\w\-.]+\((.*)\)$")
 _MODULE_EVENT = re.compile(r"^([\w.\-]+?)(?:\(\d+\))?$")
+_LAYER = re.compile(r"(?:^|/)layer_(\d+)(?:/|$)")
+_NUMBERED = re.compile(r"\.\d+$")
 
 Triple = Tuple[str, float, float]
 
@@ -70,13 +95,21 @@ class ScopeTable(dict):
     of another step phase than the one the fusion itself is named under,
     ``program`` the module's name (``jit_train_step``), ``tiles`` the
     distinct block geometries its kernels were built with, by region
-    (``spans.tiles_scope``)."""
+    (``spans.tiles_scope``).  For the owner reading: ``owner_paths`` the
+    path that owns an instruction where that is not its own
+    (:meth:`owner_path`), ``owners_in`` the distinct ``owner(path)`` among
+    the ops of each fusion, ``inherited`` the instructions whose own path
+    names no phase and that took a neighbour's."""
 
-    def __init__(self, paths=(), containers=(), program="", mixed=()):
+    def __init__(self, paths=(), containers=(), program="", mixed=(),
+                 owner_paths=(), owners_in=(), inherited=()):
         super().__init__(paths)
         self.containers = frozenset(containers)
         self.mixed = frozenset(mixed)
         self.program = program
+        self.owner_paths = dict(owner_paths)
+        self.owners_in = dict(owners_in)
+        self.inherited = frozenset(inherited)
         self.tiles: Dict[str, List[dict]] = {}
         for path in self.values():
             found = [t for t in map(spans.parse_tiles,
@@ -87,6 +120,21 @@ class ScopeTable(dict):
                 if found[-1] not in seen:
                     seen.append(found[-1])
 
+    def owner_path(self, name: str) -> str:
+        """The path that owns an instruction: its heaviest op's for a
+        fusion, a neighbour's where it has none, else its own."""
+        return self.owner_paths.get(name) or self.get(name, "")
+
+
+def _heaviest(work) -> str:
+    """The path of the op a fusion is owned by: its ``convolution`` /
+    ``dot`` where it holds one, else the instruction with the largest
+    result (the one nearest the root among equals)."""
+    pool = [i for i in work if i.opcode in ("convolution", "dot")] or work
+    if not pool:
+        return ""
+    return max(pool, key=lambda i: (i.nbytes, i.index)).op_name
+
 
 def scope_table(compiled) -> ScopeTable:
     """The table of a compiled program (``jitted.lower(...).compile()``)
@@ -94,23 +142,93 @@ def scope_table(compiled) -> ScopeTable:
     text = compiled if isinstance(compiled, str) else compiled.as_text()
     instructions = hlo_audit.hlo_instructions(text)
     paths, containers = {}, set()
-    phases_in: Dict[str, set] = {}
+    by_name, inside, users = {}, {}, {}
     for ins in instructions:
         paths[ins.name] = ins.op_name
+        by_name[ins.name] = ins
+        inside.setdefault(ins.computation, []).append(ins)
         if ins.opcode in CONTAINERS:
             containers.add(ins.name)
-        phase = classify(ins.op_name)[0]
-        if phase is not None:
-            phases_in.setdefault(ins.computation, set()).add(phase)
-    mixed = set()
+
+    def producers(ins):
+        """The operands of ``ins`` that are instructions beside it (a
+        computation's parameters are its inputs, not work)."""
+        for name in ins.operands:
+            made = by_name.get(name)
+            if (made is not None and made.computation == ins.computation
+                    and made.opcode != "parameter"):
+                yield made
+
+    for ins in instructions:
+        for made in producers(ins):
+            users.setdefault(made.name, []).append(ins)
+
+    mixed, owner_paths, owners_in, fused = set(), {}, {}, set()
+
+    def fused_into(ins, nested=False):
+        """``(instruction, nested)`` over a fusion's computation (the
+        last computation it names: ``calls=``) and the computations of
+        the fusions nested in it."""
+        for called in [c for c in ins.operands if c in inside][-1:]:
+            fused.add(called)
+            for i in inside[called]:
+                yield i, nested
+                if i.opcode == "fusion":
+                    yield from fused_into(i, True)
+
+    # One loop over the fused computations: who is in each fusion (its
+    # phase-level case is ``mixed``, read off the fusion's own
+    # computation as it always was), and which of them owns it.  A
+    # constant is no work and, shared between fusions, keeps the path of
+    # any one use: it owns nothing.  Nor does an op whose path names no
+    # step phase (traced inside a jitted helper, it may start there).
     for ins in instructions:
         if ins.opcode != "fusion":
             continue
-        inside = set().union(*(phases_in.get(c, ()) for c in ins.operands))
-        if inside - {classify(ins.op_name)[0]}:
+        body = list(fused_into(ins))
+        phases = {classify(i.op_name)[0] for i, nested in body
+                  if not nested} - {None}
+        if phases - {classify(ins.op_name)[0]}:
             mixed.add(ins.name)
+        work = [i for i, _ in body
+                if i.opcode not in ("parameter", "constant")
+                and owner(i.op_name)[0] is not None]
+        owners_in[ins.name] = frozenset(owner(i.op_name) for i in work)
+        heavy = _heaviest(work)
+        if heavy and heavy != ins.op_name:
+            owner_paths[ins.name] = heavy
+
+    # An instruction whose own path names no phase (none at all on a
+    # ``copy`` the compiler inserted, an argument's name on a prefetch)
+    # takes its one consumer's owner, else its first operand's
+    # producer's, else its first consumer's.
+    inherited = set()
+
+    def phased(path):
+        return owner(path)[0] is not None
+
+    def resolve(name, seen):
+        path = owner_paths.get(name) or paths[name]
+        if phased(path) or name in seen:
+            return path
+        seen.add(name)
+        after = users.get(name, ())
+        first = next(producers(by_name[name]), None)
+        for near in (after[0] if len(after) == 1 else None, first,
+                     after[0] if after else None):
+            found = resolve(near.name, seen) if near is not None else ""
+            if phased(found):
+                owner_paths[name] = found
+                inherited.add(name)
+                return found
+        return path
+
+    for ins in instructions:
+        if (ins.computation not in fused and ins.name not in containers
+                and ins.opcode != "parameter"):
+            resolve(ins.name, set())
     return ScopeTable(paths, containers, hlo_audit.hlo_module_name(text),
-                      mixed)
+                      mixed, owner_paths, owners_in, inherited)
 
 
 def instruction_name(text: str) -> str:
@@ -137,16 +255,49 @@ def scope_components(path: str) -> List[str]:
     return out
 
 
-def classify(path: str) -> Tuple[Optional[str], Optional[str]]:
-    """``(phase, region)`` of a path: the outermost step phase and the
-    innermost kernel / allreduce-stage name, ``None`` where it has none."""
-    phase = region = None
+def _outermost_phase_innermost(path: str, named):
+    phase = name = None
     for part in scope_components(path):
         if phase is None and part in spans.STEP_PHASES:
             phase = part
-        elif part not in spans.STEP_PHASES and spans.is_scope(part):
-            region = part
-    return phase, region
+        elif part not in spans.STEP_PHASES and named(part):
+            name = part
+    return phase, name
+
+
+@functools.lru_cache(maxsize=1 << 16)
+def classify(path: str) -> Tuple[Optional[str], Optional[str]]:
+    """``(phase, region)`` of a path: the outermost step phase and the
+    innermost kernel / allreduce-stage name, ``None`` where it has none."""
+    return _outermost_phase_innermost(path, spans.is_region)
+
+
+@functools.lru_cache(maxsize=1 << 16)
+def owner(path: str) -> Tuple[Optional[str], Optional[str]]:
+    """``(phase, owner)`` of a path: as :func:`classify`, the innermost
+    name taken of the kernel regions and the model's parts together."""
+    return _outermost_phase_innermost(path, spans.is_scope)
+
+
+@functools.lru_cache(maxsize=1 << 16)
+def pass_of(path: str) -> str:
+    """Which pass a ``fwd-bwd`` path belongs to: ``recompute`` under
+    ``jax.checkpoint``'s ``rematted_computation``, ``backward`` under a
+    ``transpose(...)`` wrapper, else ``forward``."""
+    parts = path.split("/")
+    if "rematted_computation" in parts:
+        return "recompute"
+    if any(part.startswith("transpose(") for part in parts):
+        return "backward"
+    return "forward"
+
+
+@functools.lru_cache(maxsize=1 << 16)
+def layer_of(path: str) -> Optional[str]:
+    """``"3"`` of a path through ``layer_3`` (``TransformerLM`` names its
+    blocks so), else None."""
+    m = _LAYER.search(path)
+    return m.group(1) if m else None
 
 
 def attribute(ops: Iterable[Triple], table: ScopeTable) -> dict:
@@ -163,6 +314,17 @@ def attribute(ops: Iterable[Triple], table: ScopeTable) -> dict:
     a weight-gradient matmul with the optimizer update fused in counts
     whole under one of the two: ``mixed`` bounds what the phase reading
     cannot split).
+
+    Beside them, over the ops whose OWNING path (``table.owner_path``)
+    is in ``fwd-bwd``: ``"owner": {name or "(none)": s}``, a partition of
+    those seconds; ``"shared": {name: s}``, of each owner's seconds those
+    spent in fusions that hold ops of a second (phase, region), and
+    ``"shared_fusions": {instruction: s}`` the same by fusion;
+    ``"inherited"``, those of instructions that took a neighbour's path;
+    ``"pass": {"forward" | "recompute" | "backward": {name: s}}`` and
+    ``"layer": {"<i>": s}``.  ``"unowned"`` is the busy time no phase
+    owns by that rule (what ``unattributed`` is by the fusion's own
+    name).
     """
     live = []
     for name, start, end in ops:
@@ -172,10 +334,36 @@ def attribute(ops: Iterable[Triple], table: ScopeTable) -> dict:
         live.append((start, end, key))
     live.sort()
     out = {"phase": {}, "region": {}, "unattributed": 0.0, "joined": 0.0,
-           "mixed": 0.0, "busy": 0.0}
+           "mixed": 0.0, "busy": 0.0,
+           "owner": {}, "shared": {}, "shared_fusions": {},
+           "inherited": 0.0, "unowned": 0.0,
+           "pass": {name: {} for name in PASSES}, "layer": {}}
+
+    def add(to, name, seconds):
+        to[name] = to.get(name, 0.0) + seconds
+
+    def credit_owner(key, seconds):
+        path = table.owner_path(key)
+        phase, name = owner(path)
+        if phase is None:
+            out["unowned"] += seconds
+        if phase != "fwd-bwd":
+            return
+        name = name or NO_OWNER
+        add(out["owner"], name, seconds)
+        add(out["pass"][pass_of(path)], name, seconds)
+        if len(table.owners_in.get(key, ())) > 1:
+            add(out["shared"], name, seconds)
+            add(out["shared_fusions"], key, seconds)
+        if key in table.inherited:
+            out["inherited"] += seconds
+        layer = layer_of(path)
+        if layer is not None:
+            add(out["layer"], layer, seconds)
 
     def credit(key, seconds):
         out["busy"] += seconds
+        credit_owner(key, seconds)
         if key in table.mixed:
             out["mixed"] += seconds
         path = table.get(key)
@@ -187,9 +375,9 @@ def attribute(ops: Iterable[Triple], table: ScopeTable) -> dict:
         if phase is None:
             out["unattributed"] += seconds
         else:
-            out["phase"][phase] = out["phase"].get(phase, 0.0) + seconds
+            add(out["phase"], phase, seconds)
         if region is not None:
-            out["region"][region] = out["region"].get(region, 0.0) + seconds
+            add(out["region"], region, seconds)
 
     # Sweep in start order; at every instant the time goes to the running
     # op that started last, so that the readings partition the union.
@@ -339,15 +527,16 @@ def report_from(devices, host, tables: Dict[str, ScopeTable]) -> dict:
            "programs": {}}
     for name, per_dev in sorted(programs.items()):
         calls = _mean(g["calls"] for g in per_dev)
+        table = tables.get(name, ScopeTable())
 
-        def ms(field, key=None):
+        def ms(field, key=None, rows=per_dev):
             values = (g[field] if key is None else g[field].get(key, 0.0)
-                      for g in per_dev)
+                      for g in rows)
             return _mean(values) / max(calls, 1) * 1e3
 
-        def ms_by_name(field):
-            names = sorted({k for g in per_dev for k in g[field]})
-            return {k: ms(field, k) for k in names}
+        def ms_by_name(field, rows=per_dev):
+            names = sorted({k for g in rows for k in g[field]})
+            return {k: ms(field, k, rows) for k in names}
 
         total = _mean(g["busy"] for g in per_dev)
         out["programs"][name] = {
@@ -359,9 +548,39 @@ def report_from(devices, host, tables: Dict[str, ScopeTable]) -> dict:
                              if total else None),
             "phase_ms": ms_by_name("phase"),
             "region_ms": ms_by_name("region"),
-            "region_tiles": tables[name].tiles if name in tables else {},
+            "region_tiles": table.tiles,
+            "owner_ms": ms_by_name("owner"),
+            "shared_ms": ms_by_name("shared"),
+            "inherited_ms": ms("inherited"),
+            "unowned_ms": ms("unowned"),
+            "pass_ms": {p: ms_by_name(p, [g["pass"] for g in per_dev])
+                        for p in PASSES},
+            "layer_ms": dict(sorted(ms_by_name("layer").items(),
+                                    key=lambda kv: int(kv[0]))),
+            "shared_fusions": _largest_shared(
+                ms_by_name("shared_fusions"), table),
         }
     return out
+
+
+def _largest_shared(ms_by_fusion: Dict[str, float], table: ScopeTable,
+                    keep: int = 10) -> List[dict]:
+    """The fusions with most time among those with two or more owners,
+    the instances of one fusion (``convolution_add_fusion.3``, ``.7``:
+    one a layer) with the same owners added up; each with its owner and
+    with every (phase, region) found in it."""
+    kinds: Dict[tuple, dict] = {}
+    for name, value in ms_by_fusion.items():
+        owners = sorted("/".join(part or NO_OWNER for part in pair)
+                        for pair in table.owners_in[name])
+        owned_by = owner(table.owner_path(name))[1] or NO_OWNER
+        kind = (_NUMBERED.sub("", name), owned_by, tuple(owners))
+        row = kinds.setdefault(kind, {
+            "fusion": kind[0], "owner": owned_by, "owners": owners,
+            "count": 0, "ms": 0.0})
+        row["count"] += 1
+        row["ms"] += value
+    return sorted(kinds.values(), key=lambda r: -r["ms"])[:keep]
 
 
 class Capture:
@@ -385,8 +604,9 @@ class Capture:
     The Python tracer is off (it slows the host by a few per cent and
     nothing here reads it).  On exit the report goes to the sinks that
     are installed: one ``device_profile`` row of the current
-    ``StepRecorder``, ``device/<program>/<scope>_ms`` scalars of the
-    current ``Reporter``.
+    ``StepRecorder``, ``device/<program>/<scope>_ms`` and
+    ``device/<program>/owner/<name>_ms`` scalars of the current
+    ``Reporter``.
     """
 
     def __init__(self, programs: dict, logdir: Optional[str] = None):
@@ -471,6 +691,8 @@ def publish(report: dict) -> None:
             for kind in ("phase_ms", "region_ms"):
                 for scope, value in row[kind].items():
                     rep.observe(f"device/{program}/{scope}_ms", value)
+            for scope, value in row["owner_ms"].items():
+                rep.observe(f"device/{program}/owner/{scope}_ms", value)
             rep.observe(f"device/{program}/unattributed_ms",
                         row["unattributed_ms"])
             rep.observe(f"device/{program}/busy_ms", row["busy_ms"])

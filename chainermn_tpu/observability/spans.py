@@ -9,8 +9,8 @@ here, once, and entered where the work happens:
   compiled program keeps and
   :mod:`~chainermn_tpu.observability.device_trace` joins to the
   capture's op events: device time by scope.  Only names of
-  :data:`STEP_PHASES`, :data:`ALLREDUCE_STAGES` and
-  :data:`KERNEL_REGIONS` are accepted.
+  :data:`STEP_PHASES`, :data:`ALLREDUCE_STAGES`,
+  :data:`KERNEL_REGIONS` and :data:`MODEL_PARTS` are accepted.
 * :func:`annotate` / :func:`span` — on the HOST.  ``annotate("x")`` is a
   bare ``jax.profiler.TraceAnnotation("chainermn:x")``: a region on the
   profiler's own clock, so an idle gap of the device can be put down to
@@ -66,13 +66,32 @@ _ALLREDUCE_STAGE = re.compile(r"^grad-stage\d+$")
 #: (``models/transformer.py``: the flash regions nest in it) and, nested
 #: in it, the two causal convolutions with the q-k mean and the value's
 #: shift (``cca-conv``) and the queries' and keys' L2 norms, the keys'
-#: learned scale and the rotation (``cca-rope``).  The innermost name on
-#: an op's path is its region.
+#: learned scale and the rotation (``cca-rope``).  The innermost name of
+#: THIS tuple (or of an allreduce stage) on an op's path is its region.
 KERNEL_REGIONS = (
     "flash-fwd", "flash-bwd-dq", "flash-bwd-dkv", "fused-ce",
     "paged-decode-attn", "mamba-mixer", "ssd-scan", "ssm-conv",
     "moe-layer", "moe-route", "moe-dispatch", "moe-experts", "moe-shared",
     "cca-mixer", "cca-conv", "cca-rope",
+)
+
+#: The model's parts (``models/transformer.py``), so that every op of
+#: ``fwd-bwd`` has an owner: the table lookup with positions and scaling
+#: (``embed``; a flax module called ``embed`` puts the same name on the
+#: path), a layer's pre-norms and the final norm (``norm``), the
+#: residual's multiplier and add (``residual``), an attention layer from
+#: q/k/v to its output projection (``attn-mixer``: the flash regions nest
+#: in it as they do in ``cca-mixer``), every mixer's dense matrices
+#: (``mixer-proj``), the Mamba-2 mixer's float32 side — ``dt``'s softplus,
+#: ``-exp(A_log)``, the casts of ``y`` and the gate, ``y * silu(gate)``,
+#: the gated norm (``mixer-gate``) — and a dense FFN from ``wi`` to ``wo``
+#: (``ffn``).  The region reading does not see these names (a
+#: ``mixer-proj`` op inside ``mamba-mixer`` is still region
+#: ``mamba-mixer``); the OWNER reading of ``device_trace`` takes the
+#: innermost name of both tuples.
+MODEL_PARTS = (
+    "embed", "norm", "residual", "attn-mixer", "mixer-proj", "mixer-gate",
+    "ffn",
 )
 
 #: What the jitted programs compile as (``jit_<name>`` in a capture's
@@ -124,12 +143,18 @@ def parse_tiles(part: str):
     return dict(zip(TILE_FIELDS, map(int, m.groups()))) if m else None
 
 
+def is_region(name: str) -> bool:
+    """Whether ``name`` is a kernel region or an allreduce stage: what
+    the region reading of ``device_trace`` takes."""
+    return (
+        name in ALLREDUCE_STAGES or name in KERNEL_REGIONS
+        or bool(_ALLREDUCE_STAGE.match(name))
+    )
+
+
 def is_scope(name: str) -> bool:
     """Whether ``name`` is a device-side scope of the vocabulary."""
-    return (
-        name in STEP_PHASES or name in ALLREDUCE_STAGES
-        or name in KERNEL_REGIONS or bool(_ALLREDUCE_STAGE.match(name))
-    )
+    return name in STEP_PHASES or name in MODEL_PARTS or is_region(name)
 
 
 def telemetry_active() -> bool:

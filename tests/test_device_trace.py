@@ -428,6 +428,315 @@ def test_compiled_step_carries_the_flash_geometry(tiny_step):
         assert 0 < found[0]["copied"] <= found[0]["visited"]
 
 
+# --------------------------------------------------------------- the owners
+def test_the_models_parts_are_in_the_vocabulary():
+    assert spans.MODEL_PARTS == (
+        "embed", "norm", "residual", "attn-mixer", "mixer-proj",
+        "mixer-gate", "ffn")
+    assert not set(spans.MODEL_PARTS) & set(spans.KERNEL_REGIONS)
+    for name in spans.MODEL_PARTS:
+        with spans.named_scope(name):
+            pass
+        assert spans.is_scope(name) and not spans.is_region(name)
+    for name in spans.KERNEL_REGIONS + ("grad-pack", "grad-stage3"):
+        assert spans.is_region(name)
+    for name in ("mlp", "attn", "ffn-wi", "mixer", "layer_0", "fwd-bwd/ffn"):
+        with pytest.raises(ValueError, match="scope vocabulary"):
+            spans.named_scope(name)
+
+
+LM = "jit(train_step)/fwd-bwd/jvp(TransformerLM)"
+BACK = ("jit(train_step)/fwd-bwd/transpose(jvp(TransformerLM))/"
+        "jvp(TransformerLM)/checkpoint")
+
+
+@pytest.mark.parametrize("path,region,owned_by", [
+    (f"{LM}/layer_0/Mamba2Mixer_0/mamba-mixer/mixer-proj/in_proj/"
+     "dot_general", "mamba-mixer", "mixer-proj"),
+    (f"{LM}/layer_0/Mamba2Mixer_0/mamba-mixer/mixer-gate/mul",
+     "mamba-mixer", "mixer-gate"),
+    (f"{LM}/layer_1/ExpertLayer_0/moe-layer/moe-shared/shared/wi/"
+     "dot_general", "moe-shared", "moe-shared"),
+    (f"{LM}/layer_1/norm/RMSNorm_1/rsqrt", None, "norm"),
+    (f"{LM}/layer_2/attn-mixer/MultiHeadAttention_0/flash-fwd/flash-fwd",
+     "flash-fwd", "flash-fwd"),
+    (f"{LM}/layer_2/attn-mixer/MultiHeadAttention_0/mixer-proj/out/"
+     "dot_general", None, "mixer-proj"),
+    (f"{LM}/layer_2/attn-mixer/MultiHeadAttention_0/transpose", None,
+     "attn-mixer"),
+    (f"{LM}/embed/embed/jit(_take)/gather", None, "embed"),
+    (f"{LM}/layer_0/ffn/FeedForward_0/wi/dot_general", None, "ffn"),
+    (f"{LM}/div", None, None),
+])
+def test_the_region_reading_does_not_see_the_parts(path, region, owned_by):
+    assert device_trace.classify(path) == ("fwd-bwd", region)
+    assert device_trace.owner(path) == ("fwd-bwd", owned_by)
+
+
+@pytest.mark.parametrize("path,which,layer", [
+    (f"{LM}/layer_3/ffn/FeedForward_0/wi/dot_general", "forward", "3"),
+    (f"{BACK}/layer_11/ffn/FeedForward_0/wi/dot_general", "backward", "11"),
+    (f"{BACK}/rematted_computation/layer_0/norm/LayerNorm_1/rsqrt",
+     "recompute", "0"),
+    ("jit(train_step)/fwd-bwd/transpose(jvp(TransformerLM))/fwd-bwd/"
+     "jvp(TransformerLM)/remat2", "backward", None),
+    (f"{LM}/final_norm/mul", "forward", None),
+    (f"{LM}/player_1/my_layer_2/mul", "forward", None),
+])
+def test_pass_and_layer_come_from_the_paths_wrappers(path, which, layer):
+    assert device_trace.pass_of(path) == which
+    assert device_trace.layer_of(path) == layer
+
+
+def _owners_text():
+    """Five fusions and two copies of a made-up step: a dot under ``ffn``
+    with a residual add behind it; two elementwise ops of which the norm's
+    is the larger; a weight-gradient dot with the update fused in; a dot
+    in a NESTED fusion; a copy the compiler gave no path, before its one
+    consumer; a prefetch named after an argument, read by two."""
+    ffn = f"{LM}/layer_0/ffn/FeedForward_0/wo/dot_general"
+    res = f"{LM}/layer_0/residual/add"
+    nrm = f"{LM}/layer_1/norm/LayerNorm_0/mul"
+    wgrad = (f"{BACK}/layer_0/attn-mixer/MultiHeadAttention_0/mixer-proj/"
+             "query/dot_general")
+    upd = "jit(train_step)/opt-update/add"
+    gate = f"{BACK}/rematted_computation/layer_2/mixer-gate/mul"
+
+    def meta(path):
+        return f'metadata={{op_name="{path}"}}'
+
+    return "\n".join([
+        "HloModule jit_train_step",
+        "%fc.dot (p: bf16[8,8]) -> bf16[8,8] {",
+        "  %p.0 = bf16[8,8]{1,0} parameter(0), " + meta("args[0]['w']"),
+        f"  %d.0 = bf16[8,8]{{1,0}} convolution(%p.0, %p.0), {meta(ffn)}",
+        "  %zero = bf16[8,8]{1,0} constant(0), " + meta(upd),
+        f"  ROOT %a.0 = f32[8,8]{{1,0}} add(%d.0, %zero), {meta(res)}",
+        "}",
+        "%fc.loop (p: f32[8,8]) -> f32[8,8] {",
+        "  %p.1 = f32[8,8]{1,0} parameter(0)",
+        f"  %m.1 = f32[8,8]{{1,0}} multiply(%p.1, %p.1), {meta(nrm)}",
+        f"  ROOT %a.1 = bf16[8,8]{{1,0}} add(%m.1, %m.1), {meta(res)}",
+        "}",
+        "%fc.wgrad (p: f32[8,8]) -> f32[8,8] {",
+        "  %p.2 = f32[8,8]{1,0} parameter(0)",
+        f"  %d.2 = f32[8,8]{{1,0}} convolution(%p.2, %p.2), {meta(wgrad)}",
+        f"  ROOT %a.2 = f32[8,8]{{1,0}} add(%d.2, %p.2), {meta(upd)}",
+        "}",
+        "%fc.inner (p: f32[8,8]) -> f32[8,8] {",
+        "  %p.3 = f32[8,8]{1,0} parameter(0)",
+        f"  ROOT %d.3 = f32[4,4]{{1,0}} convolution(%p.3, %p.3), {meta(ffn)}",
+        "}",
+        "%fc.outer (p: f32[8,8]) -> f32[8,8] {",
+        "  %p.4 = f32[8,8]{1,0} parameter(0)",
+        "  %nested = f32[4,4]{1,0} fusion(%p.4), kind=kOutput, "
+        f"calls=%fc.inner, {meta(ffn)}",
+        f"  ROOT %m.4 = f32[8,8]{{1,0}} multiply(%p.4, %p.4), {meta(gate)}",
+        "}",
+        "%fc.plain (p: f32[8,8]) -> f32[8,8] {",
+        "  %p.5 = f32[8,8]{1,0} parameter(0)",
+        "  %big = f32[64,64]{1,0} broadcast(%p.5), "
+        + meta("jit(_take_call)/moe-dispatch/gather"),    # no phase
+        f"  ROOT %m.5 = f32[8,8]{{1,0}} multiply(%p.5, %p.5), {meta(gate)}",
+        "}",
+        "ENTRY %main (x: f32[8,8], w: f32[8,8]) -> f32[8,8] {",
+        "  %x = f32[8,8]{1,0} parameter(0), " + meta("x"),
+        "  %w = f32[8,8]{1,0} parameter(1), " + meta("args[0]['w']"),
+        "  %copy.9 = f32[8,8]{0,1} copy(%x)",
+        "  %prefetch = f32[8,8]{1,0:S(1)} copy(%w), "
+        + meta("args[0]['w']"),
+        f"  %dot_add = f32[8,8]{{1,0}} fusion(%copy.9), kind=kOutput, "
+        f"calls=%fc.dot, {meta(res)}",
+        f"  %loop = bf16[8,8]{{1,0}} fusion(%dot_add), kind=kLoop, "
+        f"calls=%fc.loop, {meta(res)}",
+        f"  %wgrad = f32[8,8]{{1,0}} fusion(%prefetch), kind=kOutput, "
+        f"calls=%fc.wgrad, {meta(upd)}",
+        f"  %outer = f32[8,8]{{1,0}} fusion(%prefetch), kind=kOutput, "
+        f"calls=%fc.outer, {meta(gate)}",
+        f"  ROOT %plain = f32[8,8]{{1,0}} fusion(%outer), kind=kLoop, "
+        f"calls=%fc.plain, {meta(gate)}",
+        "}",
+    ])
+
+
+@pytest.mark.parametrize("name,owned_by,n_owners,inherited", [
+    ("dot_add", ("fwd-bwd", "ffn"), 2, False),      # the dot, not the root
+    ("loop", ("fwd-bwd", "norm"), 2, False),        # the largest result
+    ("wgrad", ("fwd-bwd", "mixer-proj"), 2, False),  # not the update's
+    ("outer", ("fwd-bwd", "ffn"), 2, False),        # the nested dot
+    ("plain", ("fwd-bwd", "mixer-gate"), 1, False),  # not the phase-less
+    ("copy.9", ("fwd-bwd", "ffn"), 0, True),        # its one consumer's
+    ("prefetch", ("fwd-bwd", "mixer-proj"), 0, True),   # its first's
+])
+def test_a_fusion_is_owned_by_its_heaviest_op(name, owned_by, n_owners,
+                                              inherited):
+    table = device_trace.scope_table(_owners_text())
+    assert device_trace.owner(table.owner_path(name)) == owned_by
+    assert len(table.owners_in.get(name, ())) == n_owners
+    assert (name in table.inherited) == inherited
+    # the phase and region readings keep the fusion's own name
+    assert table["dot_add"].endswith("residual/add")
+    assert table["copy.9"] == ""
+    # ... and ``mixed`` reads a fusion's own computation, constants too
+    assert table.mixed == {"dot_add", "wgrad"}
+
+
+def test_owners_partition_the_phase_and_say_what_they_cannot_split():
+    table = device_trace.scope_table(_owners_text())
+    ops = [("copy.9", 0.0, 1.0), ("dot_add", 1.0, 4.0), ("loop", 4.0, 6.0),
+           ("prefetch", 5.0, 5.5), ("wgrad", 6.0, 10.0),
+           ("outer", 10.0, 11.0), ("plain", 11.0, 11.5),
+           ("stranger", 11.5, 12.0)]
+    got = device_trace.attribute(ops, table)
+    assert got["owner"] == pytest.approx({
+        "ffn": 1 + 3 + 1, "norm": 1.5, "mixer-proj": 0.5 + 4,
+        "mixer-gate": 0.5})
+    assert sum(got["owner"].values()) == pytest.approx(11.5)
+    # every fusion with a second owner's ops in it, by its owner
+    assert got["shared"] == pytest.approx(
+        {"ffn": 3 + 1, "norm": 1.5, "mixer-proj": 4})
+    assert got["shared_fusions"] == pytest.approx(
+        {"dot_add": 3, "loop": 1.5, "wgrad": 4, "outer": 1})
+    assert got["inherited"] == pytest.approx(1.5)
+    assert got["unowned"] == pytest.approx(0.5)          # the stranger
+    assert got["pass"]["forward"] == pytest.approx({"ffn": 5, "norm": 1.5})
+    assert got["pass"]["backward"] == pytest.approx({"mixer-proj": 4.5})
+    assert got["pass"]["recompute"] == pytest.approx({"mixer-gate": 0.5})
+    assert got["layer"] == pytest.approx({"0": 9.5, "1": 1.5, "2": 0.5})
+    # the old readings, by the fusions' own names: the update owns the
+    # weight gradient's 4 s and the gate the nested dot's second
+    assert got["phase"] == pytest.approx({"fwd-bwd": 6.0, "opt-update": 4})
+    assert got["region"] == {} and got["mixed"] == pytest.approx(3 + 4)
+    assert got["unattributed"] == pytest.approx(1 + 0.5 + 0.5)
+
+
+def test_the_report_carries_the_owner_reading_to_the_sinks():
+    table = device_trace.scope_table(_owners_text())
+    devices = [{"name": "/device:TPU:0", "modules": [
+        ("jit_train_step(1)", 0.0, 6.0), ("jit_train_step(1)", 6.0, 12.0)],
+        "ops": [("dot_add", 0.0, 3.0), ("loop", 3.0, 4.0),
+                ("stranger", 4.0, 4.5), ("dot_add", 6.0, 9.0),
+                ("plain", 9.0, 10.0)]}]
+    report = device_trace.report_from(devices, [], {"train_step": table})
+    row = report["programs"]["train_step"]
+    assert row["owner_ms"] == pytest.approx(
+        {"ffn": 3000.0, "norm": 500.0, "mixer-gate": 500.0})
+    assert row["shared_ms"] == pytest.approx({"ffn": 3000.0, "norm": 500.0})
+    assert row["pass_ms"] == {
+        "forward": pytest.approx({"ffn": 3000.0, "norm": 500.0}),
+        "recompute": pytest.approx({"mixer-gate": 500.0}), "backward": {}}
+    assert row["layer_ms"] == pytest.approx(
+        {"0": 3000.0, "1": 500.0, "2": 500.0})
+    assert list(row["layer_ms"]) == ["0", "1", "2"]
+    assert row["inherited_ms"] == 0 and row["unowned_ms"] == 250.0
+    assert row["shared_fusions"] == [
+        {"fusion": "dot_add", "owner": "ffn", "count": 1, "ms": 3000.0,
+         "owners": ["fwd-bwd/ffn", "fwd-bwd/residual"]},
+        {"fusion": "loop", "owner": "norm", "count": 1, "ms": 500.0,
+         "owners": ["fwd-bwd/norm", "fwd-bwd/residual"]}]
+    reporter = obs.Reporter()
+    with obs.scope(reporter):
+        device_trace.publish(report)
+    scalars = reporter.summary()["scalars"]
+    assert scalars["device/train_step/owner/ffn_ms"]["last"] == 3000.0
+    assert scalars["device/train_step/fwd-bwd_ms"]["last"] == 4000.0
+    json.dumps(report)                       # a row of the step log
+
+
+def _tiny_lm(kind):
+    from chainermn_tpu.models.block_table import (
+        BlockTable, CCASpec, ExpertsSpec, LayerSpec, SSMSpec)
+    from chainermn_tpu.models.transformer import TransformerLM
+
+    if kind in ("attention", "remat"):
+        return TransformerLM(vocab=64, d_model=32, n_heads=2, d_ff=64,
+                             n_layers=2, max_len=32, remat=kind == "remat")
+    row = {
+        "mamba2": LayerSpec(
+            mixer="mamba2", norm="rmsnorm", ffn="swiglu", d_ff=64,
+            ssm=SSMSpec(n_heads=4, d_head=16, d_state=16, chunk=16),
+            residual_multiplier=0.5),
+        "cca": LayerSpec(
+            mixer="cca", norm="rmsnorm", ffn="swiglu", d_ff=64,
+            cca=CCASpec(n_heads=4, n_kv_heads=2, d_head=16, rotary_dim=8)),
+        "experts": LayerSpec(
+            mixer="attention", norm="rmsnorm", ffn="experts", n_heads=2,
+            experts=ExpertsSpec(n_experts=8, top_k=2, d_expert=16,
+                                d_shared=32)),
+    }[kind]
+    table = BlockTable(
+        layers=(row, row), final_norm="rmsnorm", embedding_multiplier=2.0,
+        positions="rotary" if kind == "cca" else "none")
+    return TransformerLM(vocab=64, d_model=32, table=table, max_len=32,
+                         remat=True)
+
+
+@pytest.mark.parametrize("kind,parts,regions", [
+    ("attention", ("attn-mixer", "mixer-proj", "ffn"), ()),
+    ("remat", ("attn-mixer", "mixer-proj", "ffn"), ()),
+    ("mamba2", ("mixer-proj", "mixer-gate", "ffn"),
+     ("mamba-mixer", "ssd-scan", "ssm-conv")),
+    ("cca", ("mixer-proj", "ffn"), ("cca-mixer", "cca-conv", "cca-rope")),
+    ("experts", ("attn-mixer", "mixer-proj"),
+     ("moe-route", "moe-dispatch", "moe-experts", "moe-shared")),
+])
+def test_every_part_of_a_compiled_model_has_an_owner(kind, parts, regions):
+    """A tiny ``TransformerLM`` of each mixer kind through
+    ``make_train_step``, compiled on the CPU: the new names reach the
+    compiled text, nearly every ``fwd-bwd`` instruction has an owner, and
+    the passes are told apart."""
+    import re
+
+    import optax
+
+    import chainermn_tpu
+    from chainermn_tpu.communicators import build_mesh
+    from chainermn_tpu.ops.fused_ce import fused_cross_entropy
+
+    lm = _tiny_lm(kind)
+    comm = chainermn_tpu.create_communicator("xla_ici", mesh=build_mesh(
+        inter_size=1, intra_size=1, devices=jax.devices()[:1]))
+    opt = chainermn_tpu.create_multi_node_optimizer(optax.sgd(0.1), comm)
+    tokens = jnp.zeros((2, 32), jnp.int32)
+    params = lm.init(jax.random.PRNGKey(0), tokens)["params"]
+
+    def loss_fn(p, batch):
+        h = lm.apply({"params": p}, batch[0], return_hidden=True)
+        return fused_cross_entropy(h, p["embed"]["embedding"], batch[1],
+                                   chunk=32)
+
+    text = opt.make_train_step(loss_fn).lower(
+        params, opt.init(params), (tokens, tokens)).compile().as_text()
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    components = {part for path in paths for part in path.split("/")}
+    assert {"embed", "norm", "residual", *parts, *regions} <= components
+    # the gated norm is the gate's, whatever flax calls the module
+    assert not any("mixer-gate/norm" in path for path in paths)
+
+    table = device_trace.scope_table(text)
+    instructions = hlo_audit.hlo_instructions(text)
+    fused = {c for i in instructions if i.opcode == "fusion"
+             for c in i.operands}
+    ran = [i for i in instructions
+           if i.computation not in fused and i.name not in table.containers
+           and i.opcode not in ("parameter", "constant", "tuple",
+                                "get-tuple-element", "bitcast")]
+    owned = [(device_trace.owner(table.owner_path(i.name)),
+              device_trace.pass_of(table.owner_path(i.name))) for i in ran]
+    in_phase = [(name, which) for (phase, name), which in owned
+                if phase == "fwd-bwd"]
+    assert len(in_phase) > 100
+    named = [name for name, _ in in_phase if name is not None]
+    assert len(named) >= 0.95 * len(in_phase)
+    assert {"embed", "norm", "fused-ce", *parts, *regions} <= set(named)
+    passes = {which for _, which in in_phase}
+    assert passes == ({"forward", "backward"} if kind == "attention"
+                      else {"forward", "recompute", "backward"})
+    # the region reading is what it was: no part is a region
+    assert not {device_trace.classify(p)[1] for p in paths} & set(
+        spans.MODEL_PARTS)
+
+
 # ------------------------------------------------------------------- capture
 def test_capture_reports_and_hands_the_report_to_the_sinks(tmp_path,
                                                            tiny_step):
@@ -512,3 +821,63 @@ def test_join_holds_on_the_capture_recorded_on_the_chip():
                for k in report["idle_by_host_span_ms"])
     assert {name for name, _, _ in host} >= {
         "chainermn:train_step", "chainermn:global_batch"}
+
+
+#: What the parent of PR 34 read on the same fixture, ms a step: the
+#: phase, region, unattributed, joined and mixed readings do not move
+#: when the owner reading is added beside them.
+PINNED = {
+    "busy_ms": 0.08846499999998897,
+    "unattributed_ms": 0.0031529999999548495,
+    "mixed_ms": 0.0033233333333373047,
+    "joined_share": 1.0,
+    "phase_ms": {"allreduce": 0.013522000000001921,
+                 "fwd-bwd": 0.0704543333333608,
+                 "opt-update": 0.0013356666666713979},
+    "region_ms": {"flash-bwd-dkv": 0.007597999999997551,
+                  "flash-bwd-dq": 0.005720333333338508,
+                  "flash-fwd": 0.008780999999999373,
+                  "fused-ce": 0.02293133333335316,
+                  "grad-stage0": 0.008990333333333544,
+                  "grad-unpack": 0.004531666666668377},
+}
+
+
+@pytest.fixture(scope="module")
+def chip_row():
+    devices, host = device_trace.read_capture(
+        os.path.join(DATA, "tiny_step.xplane.pb.gz"))
+    with gzip.open(os.path.join(DATA, "tiny_step.hlo.txt.gz"), "rt") as f:
+        table = device_trace.scope_table(f.read())
+    report = device_trace.report_from(devices, host, {"train_step": table})
+    return report["programs"]["train_step"]
+
+
+@pytest.mark.parametrize("key", sorted(PINNED))
+def test_the_old_readings_are_the_parents_on_the_chip_fixture(chip_row, key):
+    assert chip_row[key] == pytest.approx(PINNED[key], rel=1e-12, abs=0)
+
+
+def test_the_owner_reading_on_the_chip_fixture(chip_row):
+    """The fixture was recorded before the model's parts had names (a
+    flax module called ``embed`` is the one that shows): the kernel
+    regions own what they own in the region reading or more (a copy
+    beside a kernel inherits it), the rest of ``fwd-bwd`` is ``(none)``,
+    and the readings add up."""
+    owners = chip_row["owner_ms"]
+    assert set(owners) >= {"(none)", "flash-fwd", "flash-bwd-dq",
+                           "flash-bwd-dkv", "fused-ce"}
+    assert set(owners) <= {"(none)", "embed"} | set(spans.KERNEL_REGIONS)
+    for region in ("flash-fwd", "flash-bwd-dq", "flash-bwd-dkv"):
+        assert owners[region] >= chip_row["region_ms"][region]
+    by_pass = chip_row["pass_ms"]
+    assert by_pass["recompute"] == {} and by_pass["backward"]
+    assert sum(sum(p.values()) for p in by_pass.values()) == pytest.approx(
+        sum(owners.values()), rel=1e-9)
+    assert sum(chip_row["layer_ms"].values()) < sum(owners.values())
+    assert set(chip_row["layer_ms"]) == {"0", "1"}
+    # the copies the compiler gave no path found an owner
+    assert chip_row["unowned_ms"] < chip_row["unattributed_ms"]
+    assert 0 < chip_row["inherited_ms"] < 0.1 * chip_row["busy_ms"]
+    assert sum(chip_row["shared_ms"].values()) < sum(owners.values())
+    assert len(chip_row["shared_fusions"]) <= 10
