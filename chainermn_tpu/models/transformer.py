@@ -1017,9 +1017,11 @@ class TransformerLM(nn.Module):
         pass it on beside ``x``; what the last layer hands on is ``sow``n
         as ``intermediates/router_state`` for the stage after.
 
-        ``remat=True`` wraps every layer in ``jax.checkpoint``: backward
-        recomputes layer activations instead of storing ~6 per-layer
-        tensors — the standard long-context memory/FLOP trade."""
+        ``remat=True`` wraps every layer in ``jax.checkpoint`` under the
+        one policy of :func:`remat_policy`: backward recomputes a layer's
+        activations from its input but for the few it keeps by name
+        (:func:`remat_names`) — the standard long-context memory/FLOP
+        trade."""
         import jax.lax as _lax
 
         table = self.block_table
@@ -1069,17 +1071,17 @@ class TransformerLM(nn.Module):
         # the (S, S) mask, which at long context is the largest host
         # constant in the program (S=16k: 256 MiB as bool).
         mask = None if self.attention_fn is not None else causal_mask(S)
-        policy = None
-        if any(r.ffn == "experts" for r in table.layers):
-            # One class for every row: these names are the expert layers'.
-            from chainermn_tpu.parallel.moe_dropless import REMAT_SAVES
+        layer_cls = Block
+        if self.remat:
+            layer_cls = nn.remat(Block, static_argnums=(),
+                                 policy=remat_policy())
+            if telemetry_active():
+                from chainermn_tpu.ops.ssd import publish_geometry
 
-            policy = jax.checkpoint_policies.save_only_these_names(
-                *REMAT_SAVES)
-        layer_cls = (
-            nn.remat(Block, static_argnums=(), policy=policy)
-            if self.remat else Block
-        )
+                publish_geometry("remat_geometry", "remat", remat_kept(
+                    table, self.d_model, x.shape[0] * S,
+                    jnp.dtype(self.dtype).itemsize,
+                    flash=self.attention_fn is not None))
         if table.d_router_state and router_state is None:
             router_state = jnp.zeros(
                 x.shape[:2] + (table.d_router_state,), jnp.float32)
@@ -1119,6 +1121,67 @@ class TransformerLM(nn.Module):
         if table.logits_scaling != 1.0:
             logits = logits / table.logits_scaling
         return logits
+
+
+def remat_names():
+    """The names (``jax.ad_checkpoint.checkpoint_name``) a rematerialised
+    layer keeps beside its input, whatever its row: the expert layers'
+    routing and grouped products (``moe_dropless.REMAT_SAVES``) and the
+    flash kernel's output and row statistics
+    (``flash_attention.FLASH_RESIDUALS``: one (tokens, heads x d_head)
+    activation and 4 bytes a token and head a flash layer, for a forward
+    kernel call a layer-step not run twice).  A name no layer of a table
+    emits is harmless.  NOT kept, each 20 to 100 times dearer a byte than
+    flash's 67 MB for 9.7 ms a step (granite, PERF.md section 6, PR 35):
+    the scan's ``y`` and block starts (268 MB a layer for 0.7 ms), the
+    convolution's output (142 MB for ~1 ms), the dense FFN's gate and up
+    (512 MB a layer)."""
+    from chainermn_tpu.ops.flash_attention import FLASH_RESIDUALS
+    from chainermn_tpu.parallel.moe_dropless import REMAT_SAVES
+
+    return (*REMAT_SAVES, FLASH_RESIDUALS)
+
+
+def remat_policy():
+    """The one ``jax.checkpoint`` policy of ``TransformerLM(remat=True)``:
+    save :func:`remat_names`, recompute everything else."""
+    return jax.checkpoint_policies.save_only_these_names(*remat_names())
+
+
+def remat_kept(table: BlockTable, d_model: int, tokens: int, itemsize: int,
+               flash: bool = True) -> dict:
+    """What the policy keeps of ``table``'s layers, ``d_model`` wide, over
+    a step of ``tokens`` tokens, from shapes: layers wrapped, flash and
+    expert layers among them, and ``<name>_bytes`` kept a step for every
+    name of :func:`remat_names` (activations ``itemsize`` bytes an
+    element).
+    ``flash``: whether the attention rows reach the flash kernels (a
+    model without an ``attention_fn`` runs the dense path, which names
+    nothing)."""
+    from chainermn_tpu.ops.grouped_matmul import TILE_ROWS
+    from chainermn_tpu.parallel import moe_dropless as moe
+
+    choice, products, flash_names = remat_names()
+    kept = {"layers": len(table.layers), "flash_layers": 0,
+            "expert_layers": 0, f"{choice}_bytes": 0,
+            f"{products}_bytes": 0, f"{flash_names}_bytes": 0}
+    for row in table.layers:
+        heads = {"attention": row, "cca": row.cca}.get(row.mixer)
+        if flash and heads is not None:
+            d_head = heads.d_head or d_model // heads.n_heads
+            kept["flash_layers"] += 1
+            kept[f"{flash_names}_bytes"] += tokens * heads.n_heads * (
+                d_head * itemsize + 4)
+        if row.ffn == "experts":
+            z = row.experts
+            count = z.experts_held[1]
+            rows = TILE_ROWS * moe.buffer_tiles(moe.rows_bound(
+                tokens * z.top_k, count, z.n_experts), count)
+            kept["expert_layers"] += 1
+            kept[f"{choice}_bytes"] += tokens * z.top_k * 4
+            kept[f"{products}_bytes"] += rows * itemsize * (
+                d_model + z.d_expert * (1 if z.expert == "relu2" else 2))
+    return kept
 
 
 def generate(

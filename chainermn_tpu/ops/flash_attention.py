@@ -42,6 +42,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -617,6 +618,46 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
     return dq, dk, dv
 
 
+#: The name (``jax.ad_checkpoint.checkpoint_name``) of what the backward
+#: kernels read of the forward kernel: its output ``o`` and the rows'
+#: log-sum-exp ``lse``.  It is put on them INSIDE the forward rules of
+#: :func:`_flash_bh` and :func:`_flash_bh_seg`, on the very values that
+#: go into the residual tuple: a ``jax.checkpoint`` whose policy saves
+#: this name then runs ``flash-fwd`` once a layer-step, where without it
+#: the backward pass runs the kernel again from the recomputed ``q, k,
+#: v`` only to have these two back (a name on a copy of ``o`` outside the
+#: ``custom_vjp`` saves nothing: ``lse`` exists nowhere else).  ``q, k,
+#: v`` stay unnamed, recomputed with their projections.  Outside a
+#: ``jax.checkpoint`` the names are identities the compiler drops.
+#: ``flash_attention_with_lse[_seg]`` (ring attention's) is left as it
+#: is: there ``lse`` is an output with a cotangent of its own.
+FLASH_RESIDUALS = "flash-residuals"
+
+
+def _named_residuals(o, lse):
+    """The forward kernel's ``o`` and ``lse`` as the forward rules hand
+    them on, named.  ``lse`` leaves the kernel as a (BH, S, 1) column,
+    which the chip holds one number a 128-lane row (268 MB for 2 MB of
+    data at 64 head rows of 8192 tokens: ``f32[64,8192,1]{2,1,0:T(8,128)}``
+    in the step compiled for a v5e); it is kept between the passes as
+    (BH, S), the tokens on the lanes, and :func:`_lse_column` gives the
+    backward kernels their column back.  ``o`` is handed on from a
+    barrier it shares with the flat ``lse``: nothing reads ``o`` before
+    the column has been turned (left to itself the scheduler put that off
+    until the layer's backward pass in two cells of three, the column
+    alive all the while).  Where nothing is rematerialised the two
+    reshapes meet and cancel and the barrier's other half is dead: such
+    a step compiles to what it was."""
+    lse = lse.reshape(lse.shape[:2])
+    o, _ = jax.lax.optimization_barrier((o, lse))
+    return (checkpoint_name(o, FLASH_RESIDUALS),
+            checkpoint_name(lse, FLASH_RESIDUALS))
+
+
+def _lse_column(lse):
+    return lse.reshape(lse.shape + (1,))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_bh(q, k, v, scale, causal, block_q, block_k, interpret,
               window=None, block_q_bwd=None, block_k_bwd=None):
@@ -642,13 +683,15 @@ def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
         block_q=block_q, block_k=block_k, interpret=interpret,
         window=window,
     )
+    o, lse = _named_residuals(o, lse)
     return o, (q, k, v, o, lse)
+
 
 def _flash_vjp_bwd(scale, causal, block_q, block_k, interpret, window,
                    block_q_bwd, block_k_bwd, res, do):
     q, k, v, o, lse = res
     dq, dk, dv = _flash_bh_bwd(
-        q, k, v, o, lse, do, scale=scale, causal=causal,
+        q, k, v, o, _lse_column(lse), do, scale=scale, causal=causal,
         block_q=block_q_bwd or block_q, block_k=block_k_bwd or block_k,
         interpret=interpret, window=window,
     )
@@ -689,6 +732,7 @@ def _flash_seg_vjp_fwd(q, k, v, q_seg, kv_seg, scale, causal, block_q,
         block_q=block_q, block_k=block_k, interpret=interpret,
         q_seg=q_seg, kv_seg=kv_seg, window=window,
     )
+    o, lse = _named_residuals(o, lse)
     return o, (q, k, v, o, lse, q_seg, kv_seg)
 
 
@@ -696,7 +740,7 @@ def _flash_seg_vjp_bwd(scale, causal, block_q, block_k, interpret, window,
                        block_q_bwd, block_k_bwd, res, do):
     q, k, v, o, lse, q_seg, kv_seg = res
     dq, dk, dv = _flash_bh_bwd(
-        q, k, v, o, lse, do, scale=scale, causal=causal,
+        q, k, v, o, _lse_column(lse), do, scale=scale, causal=causal,
         block_q=block_q_bwd or block_q, block_k=block_k_bwd or block_k,
         interpret=interpret, q_seg=q_seg, kv_seg=kv_seg, window=window,
     )
