@@ -13,11 +13,15 @@ The GPT-2-style block the repo started with is one row
 (Mamba-2 mixers with an attention layer among every few, RMSNorm, SwiGLU,
 no positions, scalar multipliers, a tied head), ``nemotron_h``
 (single-branch layers — a Mamba-2 mixer, a GQA layer or a sparse-expert
-FFN each — RMSNorm, squared ReLU, no positions, an untied head) and
-``zaya`` (every layer a compressed-convolutional-attention mixer with
-partial rotary positions and a top-1 sparse-expert FFN whose MLP router
-hands a state on to the next layer's, RMSNorm, gated experts, a tied
-head).
+FFN each — RMSNorm, squared ReLU, no positions, an untied head), ``zaya``
+(every layer a compressed-convolutional-attention mixer with partial
+rotary positions and a top-1 sparse-expert FFN whose MLP router hands a
+state on to the next layer's, RMSNorm, gated experts, a tied head) and
+``qwen3_next`` (three Gated DeltaNet linear-attention mixers to one gated
+attention row — QK-norm, rotary positions on part of a head, a sigmoid
+gate on the output — every layer followed by a softmax top-k
+sparse-expert FFN with a gated shared expert; the zero-centred RMSNorm,
+an untied head).
 
 Plain frozen dataclasses: hashable, so a table is a static field of the
 flax module.
@@ -28,14 +32,16 @@ from __future__ import annotations
 import dataclasses
 from typing import Mapping, Optional, Tuple
 
-MIXERS = ("attention", "mamba2", "cca", "none")
-NORMS = ("layernorm", "rmsnorm")
+MIXERS = ("attention", "mamba2", "cca", "gdn", "none")
+#: "rmsnorm_zc" is the zero-centred RMSNorm: the learned ``w`` starts at
+#: 0 and scales by ``1 + w``.
+NORMS = ("layernorm", "rmsnorm", "rmsnorm_zc")
 FFNS = ("gelu", "swiglu", "relu2", "experts", "none")
 #: "sinusoidal" is added to the embedding; "rotary" is applied inside the
 #: mixers, to queries and keys (the row's own spec says to how much of a
 #: head and at what base), and nothing is added to the embedding.
 POSITIONS = ("sinusoidal", "rotary", "none")
-ROUTERS = ("sigmoid", "mlp_softmax")
+ROUTERS = ("sigmoid", "mlp_softmax", "softmax")
 EXPERTS = ("relu2", "swiglu")
 
 
@@ -101,13 +107,48 @@ class CCASpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class GDNSpec:
+    """Geometry of a Gated DeltaNet mixer (arXiv:2412.06464): ``n_k_heads``
+    query/key heads of ``d_k`` and ``n_v_heads`` value heads of ``d_v``
+    (value head ``j`` reads key head ``j // (n_v_heads / n_k_heads)``),
+    each value head with a ``d_k x d_v`` state; a causal depthwise
+    convolution of ``d_conv`` taps over the query, key and value channels;
+    ``chunk`` tokens a chunk of the chunked gated delta rule."""
+
+    n_k_heads: int
+    n_v_heads: int
+    d_k: int
+    d_v: int
+    d_conv: int = 4
+    chunk: int = 64
+
+    def __post_init__(self):
+        if self.n_v_heads % self.n_k_heads:
+            raise ValueError(f"n_k_heads ({self.n_k_heads}) must divide "
+                             f"n_v_heads ({self.n_v_heads})")
+
+    @property
+    def key_dim(self) -> int:
+        return self.n_k_heads * self.d_k
+
+    @property
+    def value_dim(self) -> int:
+        return self.n_v_heads * self.d_v
+
+    @property
+    def conv_dim(self) -> int:
+        return 2 * self.key_dim + self.value_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class ExpertsSpec:
     """A sparse-expert FFN: a router over ``n_experts`` (the published
     count: its width), ``top_k`` chosen a token, none dropped; the
     experts ``held`` here, ``(first, count)`` of the ``n_experts`` (all
     of them, or one expert-parallel rank's share), each at width
     ``d_expert``; and a shared expert of the same form at ``d_shared``
-    that every token passes (0: none).
+    that every token passes (0: none), times ``sigmoid(h w_s)``, one
+    learned vector, where ``shared_gate`` says so.
 
     ``router``: ``"sigmoid"`` scores by sigmoid of one matrix, chooses by
     score + a per-expert correction bias, and weighs the chosen by their
@@ -118,7 +159,10 @@ class ExpertsSpec:
     RMSNorm and a three-matrix GELU MLP to a softmax over the experts;
     the choice is by probability + a balancing bias, the weight the
     chosen probability itself.  A layer with this router takes and
-    returns ``(x, r)``.
+    returns ``(x, r)``.  ``"softmax"`` takes a softmax of one matrix over
+    all the experts, chooses the ``top_k`` most probable (no bias), and
+    weighs them by their probabilities over the chosen ones' sum, times
+    ``scaling``.
 
     ``expert``: ``"relu2"`` is ``w_down relu(w_up h)^2``, ``"swiglu"``
     ``w_down (silu(w_gate h) * (w_up h))``, three matrices."""
@@ -132,6 +176,7 @@ class ExpertsSpec:
     router: str = "sigmoid"            # one of ROUTERS
     expert: str = "relu2"              # one of EXPERTS
     d_router: int = 0                  # "mlp_softmax": the state's width
+    shared_gate: bool = False          # sigmoid(h w_s) on the shared expert
 
     def __post_init__(self):
         if self.router not in ROUTERS or self.expert not in EXPERTS:
@@ -145,6 +190,9 @@ class ExpertsSpec:
                 self.top_k != 1 or self.scaling != 1.0):
             raise ValueError("the mlp_softmax router chooses one expert a "
                              "token and weighs it by its probability")
+        if self.shared_gate and not self.d_shared:
+            raise ValueError("a shared_gate gates a shared expert: "
+                             "d_shared is 0")
         first, count = self.experts_held
         if not (0 <= first and count >= 1
                 and first + count <= self.n_experts):
@@ -174,8 +222,17 @@ class LayerSpec:
     n_kv_heads: Optional[int] = None   # GQA/MQA (divides n_heads)
     d_head: Optional[int] = None       # None = d_model / n_heads
     attn_scale: Optional[float] = None  # softmax scale; None = 1/sqrt(d_head)
+    rotary_dim: int = 0                # attention rows: rotary positions on
+                                       # the first so many of a head's
+                                       # dimensions (0: none), at rope_theta
+    rope_theta: float = 10000.0
+    qk_norm: bool = False              # attention rows: the row's norm over
+                                       # each query and key head
+    out_gate: bool = False             # attention rows: [q | gate] = W_q h a
+                                       # head, out = W_o (attn * sigmoid(gate))
     ssm: Optional[SSMSpec] = None      # mamba2 rows
     cca: Optional[CCASpec] = None      # cca rows
+    gdn: Optional[GDNSpec] = None      # gdn rows
     experts: Optional[ExpertsSpec] = None   # "experts" rows
     residual_multiplier: float = 1.0   # rm above
     norm_eps: float = 1e-6
@@ -191,6 +248,16 @@ class LayerSpec:
             raise ValueError("a mamba2 row, and only it, carries an SSMSpec")
         if (self.mixer == "cca") != (self.cca is not None):
             raise ValueError("a cca row, and only it, carries a CCASpec")
+        if (self.mixer == "gdn") != (self.gdn is not None):
+            raise ValueError("a gdn row, and only it, carries a GDNSpec")
+        if self.mixer != "attention" and (
+                self.rotary_dim or self.qk_norm or self.out_gate):
+            raise ValueError("rotary_dim, qk_norm and out_gate are an "
+                             "attention row's")
+        if self.rotary_dim % 2 or self.rotary_dim < 0 or (
+                self.d_head is not None and self.rotary_dim > self.d_head):
+            raise ValueError(f"rotary_dim {self.rotary_dim} is no even "
+                             f"part of a head of {self.d_head}")
         if (self.ffn == "experts") != (self.experts is not None):
             raise ValueError("an experts row, and only it, carries an "
                              "ExpertsSpec")
@@ -222,10 +289,10 @@ class BlockTable:
                              f"got {self.final_norm!r}")
         if not self.layers:
             raise ValueError("a block table has at least one layer")
-        if self.positions == "rotary" and any(
-                r.mixer == "attention" for r in self.layers):
-            raise ValueError("rotary positions are built inside the cca "
-                             "mixer only: an attention row has none")
+        if self.positions != "rotary" and any(
+                r.rotary_dim for r in self.layers):
+            raise ValueError("an attention row with a rotary_dim belongs "
+                             "to a table whose positions are 'rotary'")
         if len({r.experts.d_router for r in self.layers
                 if r.experts is not None}) > 1:
             raise ValueError("every expert row of a table has the same "
@@ -266,9 +333,10 @@ def _first_layers(kinds, n_layers):
 def table_from_config(config: Mapping, n_layers: Optional[int] = None,
                       experts_held: Optional[Tuple[int, int]] = None
                       ) -> BlockTable:
-    """The table of a published ``config.json``, by its own keys.  Three
+    """The table of a published ``config.json``, by its own keys.  Four
     families are read, by ``model_type``: ``granitemoehybrid`` (its dense
-    members: ``num_local_experts`` 0), ``nemotron_h`` and ``zaya``.  ``n_layers``
+    members: ``num_local_experts`` 0), ``nemotron_h``, ``zaya`` and
+    ``qwen3_next``.  ``n_layers``
     keeps the first so many layers (a pipeline stage, a cut to fit); None
     keeps ``num_hidden_layers``.  ``experts_held`` is the ``(first,
     count)`` of the published experts this rank holds in every expert
@@ -276,7 +344,8 @@ def table_from_config(config: Mapping, n_layers: Optional[int] = None,
 
     What this system cannot build raises here, by key."""
     readers = {"granitemoehybrid": _granite_table,
-               "nemotron_h": _nemotron_h_table, "zaya": _zaya_table}
+               "nemotron_h": _nemotron_h_table, "zaya": _zaya_table,
+               "qwen3_next": _qwen3_next_table}
     reader = readers.get(config.get("model_type"))
     if reader is None:
         raise ValueError(
@@ -471,3 +540,71 @@ def _zaya_table(config, n_layers, experts_held):
             d_router=config["router_hidden_size"]))
     return BlockTable(layers=(row,) * len(kinds), positions="rotary",
                       final_norm="rmsnorm", norm_eps=eps)
+
+
+def _qwen3_next_table(config, n_layers, experts_held):
+    """``qwen3_next``: layer ``i`` a gated attention row where ``(i + 1) %
+    full_attention_interval == 0`` — ``num_attention_heads`` query and
+    ``num_key_value_heads`` key/value heads of ``head_dim``, QK-norm,
+    rotary positions on ``partial_rotary_factor`` of a head at
+    ``rope_theta``, a sigmoid gate on the output — else a Gated DeltaNet
+    mixer (``linear_num_key_heads`` / ``linear_num_value_heads`` heads of
+    ``linear_key_head_dim`` / ``linear_value_head_dim``, a convolution of
+    ``linear_conv_kernel_dim`` taps, chunks of 64); every layer then
+    ``num_experts`` gated experts of ``moe_intermediate_size``,
+    ``num_experts_per_tok`` a token by a softmax router renormalised over
+    the chosen, and a sigmoid-gated shared expert of
+    ``shared_expert_intermediate_size``; the zero-centred RMSNorm, an
+    untied head.  Refused by key: a window, scaled rotary positions,
+    dense layers among the sparse ones, router weights not renormalised,
+    biases, another activation, a tied head."""
+    _refuse([
+        (bool(config.get("use_sliding_window")),
+         "use_sliding_window (windowed attention)"),
+        (config.get("rope_scaling") is not None,
+         "rope_scaling (scaled rotary positions)"),
+        (config.get("decoder_sparse_step", 1) != 1,
+         "decoder_sparse_step other than 1 (dense layers between the "
+         "sparse ones)"),
+        (bool(config.get("mlp_only_layers")),
+         "mlp_only_layers (dense layers in place of sparse ones)"),
+        (not config.get("norm_topk_prob", True),
+         "norm_topk_prob false (router weights not renormalised over the "
+         "chosen)"),
+        (bool(config.get("attention_bias")), "attention_bias"),
+        (config.get("hidden_act") != "silu", "hidden_act other than silu"),
+        (bool(config.get("tie_word_embeddings")), "a tied output head"),
+    ])
+    every = config["full_attention_interval"]
+    kinds = _first_layers(
+        ["attention" if (i + 1) % every == 0 else "gdn"
+         for i in range(config["num_hidden_layers"])], n_layers)
+    eps = float(config["rms_norm_eps"])
+    common = dict(
+        norm="rmsnorm_zc", ffn="experts", norm_eps=eps,
+        experts=ExpertsSpec(
+            n_experts=config["num_experts"],
+            top_k=config["num_experts_per_tok"],
+            d_expert=config["moe_intermediate_size"],
+            d_shared=config["shared_expert_intermediate_size"],
+            held=experts_held, router="softmax", expert="swiglu",
+            shared_gate=True))
+    rows = {
+        "attention": LayerSpec(
+            mixer="attention", n_heads=config["num_attention_heads"],
+            n_kv_heads=config["num_key_value_heads"],
+            d_head=config["head_dim"],
+            rotary_dim=int(config["head_dim"]
+                           * config.get("partial_rotary_factor", 1.0)),
+            rope_theta=float(config.get("rope_theta", 10000.0)),
+            qk_norm=True, out_gate=True, **common),
+        "gdn": LayerSpec(mixer="gdn", gdn=GDNSpec(
+            n_k_heads=config["linear_num_key_heads"],
+            n_v_heads=config["linear_num_value_heads"],
+            d_k=config["linear_key_head_dim"],
+            d_v=config["linear_value_head_dim"],
+            d_conv=config["linear_conv_kernel_dim"]), **common),
+    }
+    return BlockTable(
+        layers=tuple(rows[k] for k in kinds), positions="rotary",
+        final_norm="rmsnorm_zc", norm_eps=eps, tied_head=False)

@@ -26,6 +26,7 @@ from chainermn_tpu.models.block_table import (
     BlockTable,
     CCASpec,
     ExpertsSpec,
+    GDNSpec,
     LayerSpec,
     SSMSpec,
     gpt2_table,
@@ -40,6 +41,28 @@ def sinusoidal_positions(max_len: int, d_model: int) -> np.ndarray:
     pe[:, 0::2] = np.sin(pos * div)
     pe[:, 1::2] = np.cos(pos * div)
     return pe
+
+
+class ZeroCentredRMSNorm(nn.Module):
+    """``x rsqrt(mean x^2 + eps) (1 + w)``: the learned ``w`` starts at 0;
+    statistics in float32."""
+
+    epsilon: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("scale", nn.initializers.zeros, (x.shape[-1],),
+                       jnp.float32)
+        x32 = x.astype(jnp.float32)
+        x32 = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
+        return (x32 * (1.0 + w)).astype(self.dtype)
+
+
+#: The block table's ``NORMS`` as modules.
+NORM_CLASSES = {"layernorm": nn.LayerNorm, "rmsnorm": nn.RMSNorm,
+                "rmsnorm_zc": ZeroCentredRMSNorm}
 
 
 class MultiHeadAttention(nn.Module):
@@ -72,12 +95,29 @@ class MultiHeadAttention(nn.Module):
                                     # built with the same one
     d_head: Optional[int] = None    # a head's width; None = d_model /
                                     # n_heads
+    rotary_dim: int = 0             # rotary positions 0..S-1 on the first
+                                    # so many dimensions of a query and key
+                                    # head (0: none), at ``rope_theta``
+    rope_theta: float = 10000.0
+    qk_norm: Optional[str] = None   # a norm of ``NORM_CLASSES`` over each
+                                    # query and key head, before the
+                                    # rotation (``q_norm``, ``k_norm``)
+    norm_eps: float = 1e-6          # its epsilon
+    out_gate: bool = False          # ``query`` projects [q | gate] a head
+                                    # and ``out`` takes attn * sigmoid(gate)
 
     @nn.compact
     def __call__(self, q_in, kv_in, mask=None, *, block_tables=None,
                  seq_lens=None):
         d_head = self.d_head or self.d_model // self.n_heads
         n_kv = self.n_kv_heads or self.n_heads
+        if (self.rotary_dim or self.qk_norm or self.out_gate) and (
+                self.decode or self.paged is not None):
+            raise ValueError(
+                "an attention row with rotary positions, QK-norm or an "
+                "output gate is built for training and whole-sequence "
+                "evaluation: the KV caches take no positions and keep "
+                "no gate")
         if self.n_heads % n_kv:
             raise ValueError(
                 f"n_kv_heads ({n_kv}) must divide n_heads ({self.n_heads})"
@@ -96,15 +136,39 @@ class MultiHeadAttention(nn.Module):
                     f"{getattr(self.attention_fn, 'scale', None)}: pass "
                     f"the same scale to make_flash_attention_fn"
                 )
-        dense = lambda name, h: nn.DenseGeneral(  # noqa: E731
-            (h, d_head), dtype=self.dtype, name=name, use_bias=False
+        dense = lambda name, h, width=d_head: nn.DenseGeneral(  # noqa: E731
+            (h, width), dtype=self.dtype, name=name, use_bias=False
         )
+        gate = None
         with named_scope("mixer-proj"):
-            q = dense("query", self.n_heads)(q_in)
+            if self.out_gate:
+                q, gate = jnp.split(
+                    dense("query", self.n_heads, 2 * d_head)(q_in), 2,
+                    axis=-1)
+            else:
+                q = dense("query", self.n_heads)(q_in)
             k = dense("key", n_kv)(kv_in)
             v = dense("value", n_kv)(kv_in)
+        if self.qk_norm or self.rotary_dim:
+            with named_scope("attn-rope"):
+                if self.qk_norm:
+                    norm = lambda name: NORM_CLASSES[self.qk_norm](  # noqa: E731
+                        epsilon=self.norm_eps, dtype=self.dtype, name=name)
+                    # (flax's own module scope would read as the layers'
+                    # pre-norm, as around Mamba2Mixer's gated norm)
+                    with nn.override_named_call(False):
+                        q, k = norm("q_norm")(q), norm("k_norm")(k)
+                if self.rotary_dim:
+                    pos = jnp.arange(q.shape[1])
+                    q, k = (rotate_partial(
+                        x.astype(jnp.float32), pos, self.rotary_dim,
+                        self.rope_theta).astype(self.dtype) for x in (q, k))
 
         def project_out(out):
+            if gate is not None:
+                with named_scope("mixer-gate"):
+                    out = out * nn.sigmoid(gate.astype(jnp.float32)).astype(
+                        out.dtype)
             with named_scope("mixer-proj"):
                 return nn.DenseGeneral(
                     self.d_model, axis=(-2, -1), dtype=self.dtype,
@@ -432,14 +496,16 @@ class ExpertLayer(nn.Module):
     by expert and put through the held experts as grouped matmuls (every
     stack (count, d_expert, d_model): ``experts_up`` and ``experts_gate``
     hold their matrices output-major), each result added back times its
-    router weight, plus the shared expert (a :class:`Relu2FeedForward`)
-    over every token where the spec has one.  By the spec's kinds: the
-    router ``sigmoid`` (top-k, :func:`moe_dropless.route`) or
-    ``mlp_softmax`` (top-1, :func:`moe_dropless.route_mlp_softmax`, which
-    takes the router state of the layer before and hands its own on:
-    then the layer is called with ``state`` and returns ``(out,
-    state)``); the experts ``relu2`` (two matrices) or ``swiglu``
-    (three).  No token is dropped
+    router weight, plus the shared expert (of the experts' own form: a
+    :class:`Relu2FeedForward` or a :class:`GatedFeedForward`) over every
+    token where the spec has one, times ``sigmoid(h w_s)`` where the spec
+    gates it.  By the spec's kinds: the router ``sigmoid`` (top-k,
+    :func:`moe_dropless.route`), ``softmax`` (top-k, no bias,
+    :func:`moe_dropless.route_softmax`) or ``mlp_softmax`` (top-1,
+    :func:`moe_dropless.route_mlp_softmax`, which takes the router state
+    of the layer before and hands its own on: then the layer is called
+    with ``state`` and returns ``(out, state)``); the experts ``relu2``
+    (two matrices) or ``swiglu`` (three).  No token is dropped
     (:mod:`chainermn_tpu.parallel.moe_dropless`); what the absent experts
     would add is left out.  ``sow``s the chosen experts as
     ``intermediates/chosen`` for whoever asks for that collection."""
@@ -488,13 +554,18 @@ class ExpertLayer(nn.Module):
         with named_scope("moe-layer"):
             x = h.reshape(tokens, d)
             with named_scope("moe-route"):
-                bias = self.param("router_bias", nn.initializers.zeros,
-                                  (z.n_experts,), f32)
-                if z.router == "sigmoid":
+                router = lambda: self.param(  # noqa: E731
+                    "router", lecun, (d, z.n_experts), f32)
+                # (the softmax router has no bias on the choice)
+                bias = None if z.router == "softmax" else self.param(
+                    "router_bias", nn.initializers.zeros, (z.n_experts,),
+                    f32)
+                if z.router == "softmax":
+                    chosen, weight = moe.route_softmax(
+                        x, router(), top_k=z.top_k, scaling=z.scaling)
+                elif z.router == "sigmoid":
                     chosen, weight = moe.route(
-                        x, self.param("router", lecun, (d, z.n_experts),
-                                      f32),
-                        bias, top_k=z.top_k, scaling=z.scaling)
+                        x, router(), bias, top_k=z.top_k, scaling=z.scaling)
                 else:
                     r = z.d_router
                     chosen, weight, state = moe.route_mlp_softmax(
@@ -538,8 +609,15 @@ class ExpertLayer(nn.Module):
                 out = moe.combine(routed, weight, plan, tokens)
             if z.d_shared:
                 with named_scope("moe-shared"):
-                    out = out + Relu2FeedForward(d, z.d_shared, self.dtype,
-                                                 name="shared")(x)
+                    ffn = (Relu2FeedForward if z.expert == "relu2"
+                           else GatedFeedForward)
+                    shared = ffn(d, z.d_shared, self.dtype, name="shared")(x)
+                    if z.shared_gate:
+                        shared = shared * nn.sigmoid(nn.Dense(
+                            1, dtype=self.dtype, use_bias=False,
+                            name="shared_gate")(x).astype(f32)).astype(
+                                shared.dtype)
+                    out = out + shared
             out = out.astype(self.dtype).reshape(lead + (d,))
             return out if state is None else (out, state)
 
@@ -769,6 +847,77 @@ class Mamba2Mixer(nn.Module):
                                 use_bias=False, name="out_proj")(y)
 
 
+class GatedDeltaNetMixer(nn.Module):
+    """The Gated DeltaNet mixer (arXiv:2412.06464) as the ``qwen3_next``
+    family lays it out (a :class:`GDNSpec` row): ``[q | k | v | z] =
+    in_proj_qkvz(h)``, ``[b | a] = in_proj_ba(h)``; a causal depthwise
+    convolution and SiLU over ``[q | k | v]``, no bias
+    (:func:`chainermn_tpu.ops.ssd.causal_conv_silu`, the Mamba-2 mixers'
+    kernels); per head ``q <- q / |q| / sqrt(d_k)``, ``k <- k / |k|``
+    with ``|x| = sqrt(sum x^2 + 1e-6)``; ``beta = sigmoid(b)``, ``g =
+    -exp(A_log) softplus(a + dt_bias)``, float32, one number a value head
+    a token; the chunked gated delta rule
+    (:func:`chainermn_tpu.ops.gated_delta.gated_delta_rule`); per head
+    ``RMSNorm(o) * silu(z)`` with one plain scale a channel of a head;
+    ``out_proj``.  Every sequence starts from a zero state (no document
+    boundaries inside a row, no recurrent cache: training and
+    whole-sequence evaluation)."""
+
+    d_model: int
+    gdn: GDNSpec
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, h):
+        from chainermn_tpu.ops.gated_delta import gated_delta_rule
+        from chainermn_tpu.ops.ssd import causal_conv_silu
+
+        z = self.gdn
+        f32 = jnp.float32
+        lead = h.shape[:2]
+        with named_scope("gdn-mixer"):
+            with named_scope("mixer-proj"):
+                proj = nn.Dense(z.conv_dim + z.value_dim, dtype=self.dtype,
+                                use_bias=False, name="in_proj_qkvz")(h)
+                ba = nn.Dense(2 * z.n_v_heads, dtype=self.dtype,
+                              use_bias=False, name="in_proj_ba")(h)
+            qkv, gate = jnp.split(proj, [z.conv_dim], axis=-1)
+            qkv = causal_conv_silu(
+                qkv, self.param("conv_kernel", nn.initializers.lecun_normal(),
+                                (z.d_conv, z.conv_dim), f32))
+            q, k, v = jnp.split(qkv, [z.key_dim, 2 * z.key_dim], axis=-1)
+            heads = (z.n_v_heads,)
+            with named_scope("mixer-gate"):
+                def unit(x):
+                    x = x.astype(f32).reshape(lead + (z.n_k_heads, z.d_k))
+                    return x * jax.lax.rsqrt(jnp.sum(
+                        jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+                q = (unit(q) * (1.0 / np.sqrt(z.d_k))).astype(self.dtype)
+                k = unit(k).astype(self.dtype)
+                b, a = jnp.split(ba.astype(f32), 2, axis=-1)
+                beta = jax.nn.sigmoid(b)
+                g = -jnp.exp(self.param("A_log", _a_log_init, heads)) * (
+                    jax.nn.softplus(
+                        a + self.param("dt_bias", _dt_bias_init, heads)))
+            o = gated_delta_rule(
+                q, k, v.reshape(lead + (z.n_v_heads, z.d_v)), g, beta,
+                chunk=z.chunk)
+            with named_scope("mixer-gate"):
+                o = o.astype(f32)
+                o = o * jax.lax.rsqrt(
+                    jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+                    + self.norm_eps)
+                scale = self.param("norm_scale", nn.initializers.ones,
+                                   (z.d_v,), f32)
+                y = o * scale * nn.silu(gate.astype(f32).reshape(o.shape))
+                y = y.astype(self.dtype).reshape(lead + (z.value_dim,))
+            with named_scope("mixer-proj"):
+                return nn.Dense(self.d_model, dtype=self.dtype,
+                                use_bias=False, name="out_proj")(y)
+
+
 class Block(nn.Module):
     """One layer, built from its row of the block table: ``x + rm *
     mixer(norm(x))`` where the row has a mixer, then ``x + rm *
@@ -797,9 +946,9 @@ class Block(nn.Module):
         row = self.row
 
         def normed(x):
-            cls = nn.LayerNorm if row.norm == "layernorm" else nn.RMSNorm
             with named_scope("norm"):
-                return cls(epsilon=row.norm_eps, dtype=self.dtype)(x)
+                return NORM_CLASSES[row.norm](
+                    epsilon=row.norm_eps, dtype=self.dtype)(x)
 
         def residual(x, branch):
             with named_scope("residual"):
@@ -818,6 +967,9 @@ class Block(nn.Module):
                     page_count=self.page_count, page_size=self.page_size,
                     kv_dtype=self.kv_dtype, sp_axis=self.sp_axis,
                     scale=row.attn_scale, d_head=row.d_head,
+                    rotary_dim=row.rotary_dim, rope_theta=row.rope_theta,
+                    qk_norm=row.norm if row.qk_norm else None,
+                    norm_eps=row.norm_eps, out_gate=row.out_gate,
                 )(h, h, mask, block_tables=block_tables, seq_lens=seq_lens)
             x = residual(x, branch)
         elif row.mixer == "mamba2":
@@ -839,6 +991,16 @@ class Block(nn.Module):
                 )
             x = residual(x, CCAMixer(self.d_model, row.cca, self.dtype,
                                      self.attention_fn)(normed(x), mask))
+        elif row.mixer == "gdn":
+            if self.decode or self.paged is not None:
+                raise ValueError(
+                    "a gdn layer keeps no recurrent state between calls "
+                    "(its matrix state a head and its convolution's "
+                    "window): incremental decoding and the paged KV cache "
+                    "are built for attention layers only"
+                )
+            x = residual(x, GatedDeltaNetMixer(
+                self.d_model, row.gdn, row.norm_eps, self.dtype)(normed(x)))
 
         def handed_on(x):
             return x if router_state is None else (x, router_state)
@@ -1028,9 +1190,10 @@ class TransformerLM(nn.Module):
         S = tokens.shape[1]
         if table.positions == "rotary" and position_offset is not None:
             raise ValueError(
-                "rotary positions are built from 0 inside the cca mixer, "
-                "whose convolutions and value read the token before: a "
-                "sharded or offset sequence is not built")
+                "rotary positions are built from 0 inside the mixers (the "
+                "cca mixer's convolutions and value read the token before; "
+                "an attention row rotates by its own index): a sharded or "
+                "offset sequence is not built")
         if inputs_embeds is not None and not return_hidden:
             raise ValueError(
                 "inputs_embeds requires return_hidden=True: the tied "
@@ -1104,11 +1267,10 @@ class TransformerLM(nn.Module):
                 x, router_state = layer(x, mask, router_state)
         if router_state is not None:
             self.sow("intermediates", "router_state", router_state)
-        norm_cls = (nn.LayerNorm if table.final_norm == "layernorm"
-                    else nn.RMSNorm)
         with named_scope("norm"):
-            x = norm_cls(epsilon=table.norm_eps, dtype=self.dtype,
-                         name="final_norm")(x)
+            x = NORM_CLASSES[table.final_norm](
+                epsilon=table.norm_eps, dtype=self.dtype,
+                name="final_norm")(x)
         head = None if table.tied_head else self.param(
             "lm_head", nn.initializers.normal(0.02),
             (self.vocab, self.d_model), jnp.float32)

@@ -66,13 +66,20 @@ _ALLREDUCE_STAGE = re.compile(r"^grad-stage\d+$")
 #: (``models/transformer.py``: the flash regions nest in it) and, nested
 #: in it, the two causal convolutions with the q-k mean and the value's
 #: shift (``cca-conv``) and the queries' and keys' L2 norms, the keys'
-#: learned scale and the rotation (``cca-rope``).  The innermost name of
-#: THIS tuple (or of an allreduce stage) on an op's path is its region.
+#: learned scale and the rotation (``cca-rope``); a Gated DeltaNet mixer
+#: from its input projections to its output projection (``gdn-mixer``:
+#: its convolution is ``ssm-conv``, the same kernels) and, nested in it,
+#: the chunked gated delta rule, forward and backward (``gdn-scan``,
+#: ``ops/gated_delta.py``); an attention row's QK-norm and rotary
+#: positions (``attn-rope``; the row's output gate is ``mixer-gate``
+#: below).  The innermost name of THIS tuple (or of an allreduce stage)
+#: on an op's path is its region.
 KERNEL_REGIONS = (
     "flash-fwd", "flash-bwd-dq", "flash-bwd-dkv", "fused-ce",
     "paged-decode-attn", "mamba-mixer", "ssd-scan", "ssm-conv",
     "moe-layer", "moe-route", "moe-dispatch", "moe-experts", "moe-shared",
     "cca-mixer", "cca-conv", "cca-rope",
+    "gdn-mixer", "gdn-scan", "attn-rope",
 )
 
 #: The model's parts (``models/transformer.py``), so that every op of
@@ -82,10 +89,11 @@ KERNEL_REGIONS = (
 #: residual's multiplier and add (``residual``), an attention layer from
 #: q/k/v to its output projection (``attn-mixer``: the flash regions nest
 #: in it as they do in ``cca-mixer``), every mixer's dense matrices
-#: (``mixer-proj``), the Mamba-2 mixer's float32 side — ``dt``'s softplus,
-#: ``-exp(A_log)``, the casts of ``y`` and the gate, ``y * silu(gate)``,
-#: the gated norm (``mixer-gate``) — and a dense FFN from ``wi`` to ``wo``
-#: (``ffn``).  The region reading does not see these names (a
+#: (``mixer-proj``), a mixer's float32 side (``mixer-gate``) — Mamba-2's
+#: ``dt`` softplus, ``-exp(A_log)``, the casts of ``y`` and the gate, ``y *
+#: silu(gate)`` and the gated norm; the Gated DeltaNet's ``beta``, ``g``,
+#: L2 norms and gated norm; a gated attention row's ``attn *
+#: sigmoid(gate)`` — and a dense FFN from ``wi`` to ``wo`` (``ffn``).  The region reading does not see these names (a
 #: ``mixer-proj`` op inside ``mamba-mixer`` is still region
 #: ``mamba-mixer``); the OWNER reading of ``device_trace`` takes the
 #: innermost name of both tuples.

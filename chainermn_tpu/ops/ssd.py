@@ -311,12 +311,13 @@ def _conv_silu_bwd(saved, dy):
 _conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 
 
-def causal_conv_silu(x, kernel, bias):
+def causal_conv_silu(x, kernel, bias=None):
     """``silu(conv(x) + bias)``: a causal depthwise convolution along the
     sequence.  ``x``: (B, S, C); ``kernel``: (K, C), tap ``K-1`` weighs
     the current token and tap ``j`` the one ``K-1-j`` back (zeros before
-    the sequence starts); ``bias``: (C,).  Sums in float32, returns
-    ``x.dtype``.
+    the sequence starts); ``bias``: (C,), or None for a convolution that
+    has none (the kernels then add a column of zeros made here: one
+    pair of kernels serves both).  Sums in float32, returns ``x.dtype``.
 
     The backward is written, not derived: autodiff transposes the
     forward's shifted slices into pads that are added, which the compiler
@@ -346,6 +347,8 @@ def causal_conv_silu(x, kernel, bias):
             "seq_tile": ts, "channel_tile": tc,
             "grid_steps": B * -(-S // ts) * -(-C // tc)},
             backward="one_pass")
+    if bias is None:
+        bias = jnp.zeros((x.shape[-1],), jnp.float32)
     return _conv_silu(x, kernel, bias)
 
 

@@ -1,12 +1,14 @@
 """Dropless top-k-of-many expert routing for one expert-parallel rank.
 
-Two routers, one dispatch: :func:`route` (sigmoid scores of one matrix,
-top-k, weights normalised over the chosen) and :func:`route_mlp_softmax`
-(a small MLP over a state handed from layer to layer, a softmax, the one
-most probable expert weighed by its probability).  Both are float32 at
-``highest`` throughout and return ``(chosen, weight)`` alike; everything
-after them is shared.  Neither's bias gets a gradient; :func:`rebalance`
-is one step of the controller that moves it by the load instead.
+Three routers, one dispatch: :func:`route` (sigmoid scores of one matrix,
+top-k, weights normalised over the chosen), :func:`route_softmax` (a
+softmax of one matrix over all the experts, top-k, renormalised over the
+chosen, no bias) and :func:`route_mlp_softmax` (a small MLP over a state
+handed from layer to layer, a softmax, the one most probable expert
+weighed by its probability).  All are float32 at ``highest`` throughout
+and return ``(chosen, weight)`` alike; everything after them is shared.
+No bias gets a gradient; :func:`rebalance` is one step of the controller
+that moves it by the load instead.
 
 The rank is told which experts it holds, ``(first, count)`` of the
 published ``n_experts``.  It routes every token over ALL the experts (the
@@ -118,6 +120,25 @@ def route(h, w_router, bias, *, top_k: int, scaling: float = 1.0):
     weight = jnp.take_along_axis(scores, chosen, axis=-1)
     weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
     return chosen, weight * scaling
+
+
+def route_softmax(h, w_router, *, top_k: int, scaling: float = 1.0):
+    """Softmax top-k router (an ``ExpertsSpec`` whose ``router`` is
+    ``"softmax"``), float32 throughout, no bias on the choice.
+
+    ``h``: (T, d); ``w_router``: (d, E).  Returns ``(chosen, weight)``,
+    (T, top_k) int32 and float32: the ``top_k`` experts with the largest
+    ``p = softmax(h w)`` over ALL the experts (ties to the lower index)
+    and ``scaling * p[chosen]`` over the chosen probabilities' sum."""
+    f32 = jnp.float32
+    p = jax.nn.softmax(jnp.dot(
+        h.astype(f32), w_router.astype(f32),
+        precision=lax.Precision.HIGHEST), axis=-1)
+    _, chosen = lax.top_k(p, top_k)
+    chosen = checkpoint_name(chosen.astype(jnp.int32), ROUTER_CHOICE)
+    weight = jnp.take_along_axis(p, chosen, axis=-1)
+    return chosen, weight * (
+        scaling / jnp.sum(weight, axis=-1, keepdims=True))
 
 
 def route_mlp_softmax(h, state, params, bias, *, eps: float):

@@ -224,9 +224,12 @@ class InferenceEngine:
                 f"InferenceEngine does not serve a model built from a "
                 f"block table (mixers: {kinds}): the paged cache and the "
                 f"scheduler keep keys and values only, no recurrent "
-                f"state (a mamba2 mixer's), no token before the first (a "
-                f"cca mixer's convolutions and value shift) and no router "
-                f"state handed from layer to layer, and the page geometry "
+                f"state (a mamba2 mixer's vector state, a gdn mixer's "
+                f"matrix state a head and its convolution's window), no "
+                f"token before the first (a cca mixer's convolutions and "
+                f"value shift), no positions for an attention row's rotary "
+                f"embedding and no router state handed from layer to "
+                f"layer, and the page geometry "
                 f"is read from n_heads / n_layers (ROADMAP.md R3)"
             )
         cfg = (config or EngineConfig(max_len=lm.max_len)).resolved()

@@ -502,3 +502,73 @@ def test_zaya_layer_compiles_at_the_cells_shape(one_chip, monkeypatch):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 3 + 9
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+@pytest.mark.parametrize("kind", ["gdn", "attention"])
+def test_qwen3next_layers_compile_at_the_cells_shape(one_chip, monkeypatch,
+                                                     kind):
+    """Both rows of the ``qwen3_next`` period at the cell's shape (2 x 8192
+    tokens, 32 of 512 gated experts of 512 held: a buffer of 192 tiles),
+    forward and backward under remat with the model's policy as in the
+    step.  The Gated DeltaNet row: the convolution's two kernels over
+    8,192 channels (forward twice: recomputed), no other Mosaic call in the
+    mixer (the chunked rule is XLA ops: a ``while`` over the head groups
+    and the scans inside it), and the temporaries of 8 heads a group
+    inside 4 GB.  The gated attention row: the three flash calls at
+    D = 256 (the forward ONCE), under the blocks ``auto_block_size``
+    picks (1024 forward, 512 backward).  Each with nine grouped calls of
+    the experts."""
+    from chainermn_tpu.models.block_table import (
+        ExpertsSpec,
+        GDNSpec,
+        LayerSpec,
+    )
+    from chainermn_tpu.models.transformer import Block, remat_policy
+    from chainermn_tpu.ops import make_flash_attention_fn
+
+    gm = importlib.import_module("chainermn_tpu.ops.grouped_matmul")
+    ssd = importlib.import_module("chainermn_tpu.ops.ssd")
+    for module in (fa, gm, ssd):
+        monkeypatch.setattr(module, "default_interpret", lambda: False)
+    assert fa.auto_block_size(8192, 256, jnp.bfloat16, "fwd") == 1024
+    assert fa.auto_block_size(8192, 256, jnp.bfloat16, "bwd") == 512
+    common = dict(
+        norm="rmsnorm_zc", ffn="experts", norm_eps=1e-6,
+        experts=ExpertsSpec(n_experts=512, top_k=10, d_expert=512,
+                            d_shared=512, held=(0, 32), router="softmax",
+                            expert="swiglu", shared_gate=True))
+    row = LayerSpec(mixer="gdn", gdn=GDNSpec(16, 32, 128, 128),
+                    **common) if kind == "gdn" else LayerSpec(
+        mixer="attention", n_heads=16, n_kv_heads=2, d_head=256,
+        rotary_dim=64, rope_theta=1e7, qk_norm=True, out_gate=True,
+        **common)
+    layer = Block(2048, row, jnp.bfloat16,
+                  make_flash_attention_fn(causal=True))
+
+    def arr(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    x = arr((2, 8192, 2048), jnp.bfloat16)
+    params = jax.tree.map(
+        lambda a: arr(a.shape, a.dtype),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 256, 2048), jnp.bfloat16))))
+
+    def loss(params, x):
+        fn = jax.checkpoint(lambda p, x: layer.apply(p, x),
+                            policy=remat_policy())
+        return jnp.sum(fn(params, x).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3 + 9
+    if kind == "gdn":
+        assert len(re.findall(r"ssm-conv-fwd", text)) >= 2
+        # (the kernels' names: the text's file table may name the module)
+        assert "ssm-conv-bwd" in text and "flash-fwd" not in text
+        assert "flash-bwd" not in text
+    else:
+        assert "ssm-conv" not in text and "gdn-scan" not in text
+    # read: 3.33 GB for the gdn row (0.55 of them its float32 gradients)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9

@@ -127,7 +127,7 @@ def test_table_from_config_refuses_by_key(key, value, needle):
     (dict(router="mlp_softmax"), "d_router"),
     (dict(d_router=16), "d_router"),
     (dict(router="mlp_softmax", d_router=16, top_k=2), "one expert"),
-    (dict(router="softmax"), "router must be"),
+    (dict(router="hash"), "router must be"),
     (dict(expert="geglu"), "expert one of"),
 ])
 def test_an_experts_spec_states_what_it_has(kw, needle):
