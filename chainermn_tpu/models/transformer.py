@@ -1244,7 +1244,7 @@ class TransformerLM(nn.Module):
                 publish_geometry("remat_geometry", "remat", remat_kept(
                     table, self.d_model, x.shape[0] * S,
                     jnp.dtype(self.dtype).itemsize,
-                    flash=self.attention_fn is not None))
+                    flash=self.attention_fn is not None, seq=S))
         if table.d_router_state and router_state is None:
             router_state = jnp.zeros(
                 x.shape[:2] + (table.d_router_state,), jnp.float32)
@@ -1288,20 +1288,26 @@ class TransformerLM(nn.Module):
 def remat_names():
     """The names (``jax.ad_checkpoint.checkpoint_name``) a rematerialised
     layer keeps beside its input, whatever its row: the expert layers'
-    routing and grouped products (``moe_dropless.REMAT_SAVES``) and the
+    routing and grouped products (``moe_dropless.REMAT_SAVES``), the
     flash kernel's output and row statistics
     (``flash_attention.FLASH_RESIDUALS``: one (tokens, heads x d_head)
     activation and 4 bytes a token and head a flash layer, for a forward
-    kernel call a layer-step not run twice).  A name no layer of a table
+    kernel call a layer-step not run twice) and the gated delta rule's
+    output and tile states (``gated_delta.GDN_RESIDUALS``: one (tokens,
+    value heads x d_v) activation and a float32 state a head and tile of
+    512 tokens, 201 MB a layer of the ``qwen3_next`` cell for a forward
+    kernel call of 6.3 ms, +0.054 GB on the compiled step and 5% of the
+    measured one: PERF.md section 6, PR 37).  A name no layer of a table
     emits is harmless.  NOT kept, each 20 to 100 times dearer a byte than
     flash's 67 MB for 9.7 ms a step (granite, PERF.md section 6, PR 35):
     the scan's ``y`` and block starts (268 MB a layer for 0.7 ms), the
     convolution's output (142 MB for ~1 ms), the dense FFN's gate and up
     (512 MB a layer)."""
     from chainermn_tpu.ops.flash_attention import FLASH_RESIDUALS
+    from chainermn_tpu.ops.gated_delta import GDN_RESIDUALS
     from chainermn_tpu.parallel.moe_dropless import REMAT_SAVES
 
-    return (*REMAT_SAVES, FLASH_RESIDUALS)
+    return (*REMAT_SAVES, FLASH_RESIDUALS, GDN_RESIDUALS)
 
 
 def remat_policy():
@@ -1311,22 +1317,25 @@ def remat_policy():
 
 
 def remat_kept(table: BlockTable, d_model: int, tokens: int, itemsize: int,
-               flash: bool = True) -> dict:
+               flash: bool = True, seq: Optional[int] = None) -> dict:
     """What the policy keeps of ``table``'s layers, ``d_model`` wide, over
-    a step of ``tokens`` tokens, from shapes: layers wrapped, flash and
-    expert layers among them, and ``<name>_bytes`` kept a step for every
-    name of :func:`remat_names` (activations ``itemsize`` bytes an
-    element).
+    a step of ``tokens`` tokens in rows of ``seq`` (one row if not
+    given), from shapes: layers wrapped, flash and expert layers among
+    them, and ``<name>_bytes`` kept a step for every name of
+    :func:`remat_names` (activations ``itemsize`` bytes an element).
     ``flash``: whether the attention rows reach the flash kernels (a
     model without an ``attention_fn`` runs the dense path, which names
     nothing)."""
+    from chainermn_tpu.ops.gated_delta import gdn_tiles
     from chainermn_tpu.ops.grouped_matmul import TILE_ROWS
     from chainermn_tpu.parallel import moe_dropless as moe
 
-    choice, products, flash_names = remat_names()
+    choice, products, flash_names, gdn_names = remat_names()
+    seq = seq or tokens
     kept = {"layers": len(table.layers), "flash_layers": 0,
             "expert_layers": 0, f"{choice}_bytes": 0,
-            f"{products}_bytes": 0, f"{flash_names}_bytes": 0}
+            f"{products}_bytes": 0, f"{flash_names}_bytes": 0,
+            f"{gdn_names}_bytes": 0}
     for row in table.layers:
         heads = {"attention": row, "cca": row.cca}.get(row.mixer)
         if flash and heads is not None:
@@ -1334,6 +1343,15 @@ def remat_kept(table: BlockTable, d_model: int, tokens: int, itemsize: int,
             kept["flash_layers"] += 1
             kept[f"{flash_names}_bytes"] += tokens * heads.n_heads * (
                 d_head * itemsize + 4)
+        if row.mixer == "gdn":
+            z = row.gdn
+            chunk = min(z.chunk, seq)
+            tile, _, _ = gdn_tiles(
+                seq, chunk, z.n_k_heads, z.n_v_heads, z.d_k, z.d_v,
+                jnp.float32 if itemsize == 4 else jnp.bfloat16)
+            tiles = tokens // seq * (-(-seq // chunk) * chunk // tile)
+            kept[f"{gdn_names}_bytes"] += z.n_v_heads * z.d_v * (
+                tokens * itemsize + tiles * z.d_k * 4)
         if row.ffn == "experts":
             z = row.experts
             count = z.experts_held[1]

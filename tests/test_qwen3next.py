@@ -438,41 +438,134 @@ def test_chunked_rule_is_the_recurrence(chunk, decay, write):
             atol=1e-7 + 1e-5 * float(jnp.max(jnp.abs(b))), err_msg=name)
 
 
-def test_head_groups_give_what_all_heads_at_once_give(monkeypatch):
-    args = delta_inputs(4)
+def rule_and_gradients(rule, args, w):
+    """``o`` and the gradients of ``sum(o w)`` by the five operands."""
+    def loss(*a):
+        o = rule(*a)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    with jax.default_matmul_precision("highest"):
+        (_, o), grads = jax.value_and_grad(
+            loss, argnums=range(5), has_aux=True)(*args)
+    return (o, *grads)
+
+
+def assert_close_by_operand(got, want, rtol, share, floor=0.0):
+    """``o`` and the five gradients, each within ``rtol`` over ``floor``
+    + ``share`` of the wanted one's largest entry."""
+    for a, b, name in zip(got, want, "o q k v g beta".split()):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), b, rtol=rtol,
+            atol=floor + share * float(jnp.max(jnp.abs(b))), err_msg=name)
+
+
+@pytest.mark.parametrize("hk,hv", [(2, 2), (2, 4), (1, 3)])
+@pytest.mark.parametrize("S,chunk,tokens", [
+    (96, 8, 32),        # three tiles of four chunks
+    (92, 8, 16),        # a ragged last chunk: six tiles of two, padded
+    (48, 16, 16)])      # a chunk a tile
+def test_state_and_cotangent_cross_a_tiles_edge(monkeypatch, S, chunk,
+                                                tokens, hk, hv):
+    """A grid step holds several chunks and a sequence several tiles: the
+    state in forward, its cotangent in backward, are carried in VMEM from
+    tile to tile; ``dq`` and ``dk`` sum over the ``hv / hk`` value heads
+    of a key head inside the kernel.  Against the recurrence, float32, at
+    the tolerances of the whole-sequence cases."""
+    monkeypatch.setattr(gated_delta, "_GDN_TOKENS", tokens)
+    jax.clear_caches()
+    assert gated_delta.gdn_tiles(S, chunk, hk, hv, 16, 8, jnp.float32)[:2] \
+        == (tokens, hv // hk)
+    args = delta_inputs(5, b=1, S=S, hk=hk, hv=hv)
     w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
-
-    def run():
-        with jax.default_matmul_precision("highest"):
-            return jax.value_and_grad(
-                lambda *a: jnp.sum(gated_delta.gated_delta_rule(
-                    *a, chunk=32) * w), argnums=range(5))(*args)
-
-    assert gated_delta.heads_a_group(200, 4) == 4
-    whole = run()
-    monkeypatch.setattr(gated_delta, "_GROUP_TOKEN_HEADS", 2 * 200)
-    assert gated_delta.heads_a_group(200, 4) == 2
-    grouped = run()
-    for a, b in zip(jax.tree.leaves(grouped), jax.tree.leaves(whole)):
-        np.testing.assert_allclose(        # the same sums, other fusions
-            a, b, rtol=1e-5, atol=1e-6 * float(jnp.max(jnp.abs(b))))
-    # the cell's shape: 8 of 32 value heads a group
-    monkeypatch.undo()
-    assert gated_delta.heads_a_group(2 * 8192, 32) == 8
-    assert gated_delta.heads_a_group(10**9, 32) == 1
+    got = rule_and_gradients(
+        lambda *a: gated_delta.gated_delta_rule(*a, chunk=chunk), args, w)
+    want = rule_and_gradients(recurrence, args, w)
+    jax.clear_caches()
+    assert_close_by_operand(got, want, rtol=1e-4, share=1e-5, floor=1e-7)
 
 
-@pytest.mark.parametrize("n,base", [(16, 16), (64, 16), (64, 64), (24, 4),
-                                    (5, 16)])
-def test_unit_lower_inverse_against_a_triangular_solve(n, base):
-    a = jnp.tril(jax.random.normal(jax.random.PRNGKey(n), (3, 2, n, n)), -1)
-    got = gated_delta.unit_lower_inverse(0.2 * a, base)
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("hk,hv", [(2, 2), (2, 4)])
+def test_bfloat16_rule_against_the_float32_recurrence(chunk, hk, hv):
+    """The cell's precision — ``q``, ``k``, ``v`` and the products'
+    operands rounded, the decays, the solve and the state float32 —
+    against the recurrence on the same (rounded) operands in float32: a
+    few roundings of the largest term, the ``ssd`` tests' tolerances."""
+    args = delta_inputs(6, hk=hk, hv=hv)
+    low = tuple(a.astype(jnp.bfloat16) for a in args[:3]) + args[3:]
+    same = tuple(a.astype(jnp.float32) for a in low)
+    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    got = rule_and_gradients(
+        lambda *a: gated_delta.gated_delta_rule(*a, chunk=chunk), low, w)
+    want = rule_and_gradients(recurrence, same, w)
+    assert got[1].dtype == jnp.bfloat16 and got[4].dtype == jnp.float32
+    assert_close_by_operand(got, want, rtol=2e-2, share=2e-2)
+
+
+@pytest.mark.parametrize("n,side", [(16, 1), (64, 2), (64, 1), (24, 2),
+                                    (5, 3)])
+def test_the_kernels_solve_against_a_triangular_solve(n, side):
+    """``(I + A_c^T)^-1`` as the kernels make it — substitution a column a
+    step from the last, ``side`` chunks side by side on the lanes — in a
+    kernel of its own (interpret mode), at the tolerance the XLA form's
+    solve was held to."""
+    from jax.experimental import pallas as pl
+
+    a = 0.2 * jnp.triu(jax.random.normal(
+        jax.random.PRNGKey(n), (side, n, n)), 1)
+
+    def kernel(a_ref, t_ref):
+        t_ref[...] = gated_delta._unit_upper_inverse(a_ref, n, side)
+
+    side_by_side = jnp.moveaxis(a, 0, 1).reshape(n, side * n)
+    got = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct(side_by_side.shape,
+                                               jnp.float32),
+        interpret=True)(side_by_side)
+    got = jnp.moveaxis(got.reshape(n, side, n), 1, 0)
     want = jax.scipy.linalg.solve_triangular(
-        jnp.eye(n) + 0.2 * a, jnp.broadcast_to(jnp.eye(n), a.shape),
-        lower=True, unit_diagonal=True)
+        jnp.eye(n) + a, jnp.broadcast_to(jnp.eye(n), a.shape),
+        lower=False, unit_diagonal=True)
     # two substitutions in another order: 1e-5 of the largest entry
     np.testing.assert_allclose(
         got, want, rtol=1e-4, atol=1e-5 * float(jnp.max(jnp.abs(want))))
+
+
+def test_tiles_follow_the_shapes(monkeypatch):
+    from chainermn_tpu.ops.flash_attention import VMEM_SCOPED_DEFAULT
+
+    tiles = gated_delta.gdn_tiles
+    # off the chip any shape runs (interpreted): whole chunks, the most
+    # that divide the padded sequence within the cap
+    assert tiles(100, 4, 2, 4, 16, 8, jnp.float32)[:2] == (100, 2)
+    assert tiles(100, 16, 2, 4, 16, 8, jnp.float32)[:2] == (112, 2)
+    assert tiles(100, 128, 2, 2, 16, 8, jnp.float32)[:2] == (100, 1)
+    assert tiles(2048, 64, 1, 1, 16, 8, jnp.float32)[0] == 512
+    with pytest.raises(ValueError, match="do not divide"):
+        tiles(64, 16, 3, 4, 16, 8, jnp.float32)
+    # on the chip: the cell's geometry (2 x 8192 tokens, 16 key and 32
+    # value heads of 128, chunk 64, bfloat16) is eight chunks, two side
+    # by side on the lanes, and both value heads of a key head a grid
+    # step, inside the default VMEM
+    monkeypatch.setattr(gated_delta, "default_interpret", lambda: False)
+    tokens, heads, vmem = tiles(8192, 64, 16, 32, 128, 128, jnp.bfloat16)
+    assert (tokens, heads) == (512, 2) and vmem <= VMEM_SCOPED_DEFAULT
+    # float32 operands still fit; what cannot be tiled raises by the
+    # rule, no other form takes over: channels that fill no register of
+    # sublanes, chunks that fill no register of lanes side by side, a
+    # sequence whose chunks do not pair up, a head too large to hold
+    assert tiles(8192, 64, 16, 32, 128, 128, jnp.float32)[0] == 512
+    assert tiles(8192, 128, 16, 32, 64, 256, jnp.bfloat16)[0] == 256
+    with pytest.raises(ValueError, match=r"whole\s+registers"):
+        tiles(8192, 64, 16, 32, 72, 128, jnp.bfloat16)
+    with pytest.raises(ValueError, match=r"whole\s+registers"):
+        tiles(8192, 60, 16, 32, 128, 128, jnp.bfloat16)
+    with pytest.raises(ValueError, match="no tile of whole chunks"):
+        tiles(8192, 16, 16, 32, 128, 128, jnp.bfloat16)
+    with pytest.raises(ValueError, match="no tile of whole chunks"):
+        tiles(64 * 11, 64, 16, 32, 128, 128, jnp.bfloat16)
+    with pytest.raises(ValueError, match="no tile of whole chunks"):
+        tiles(8192, 64, 4, 32, 512, 512, jnp.bfloat16)
 
 
 def test_rule_refuses_operands_that_do_not_fit():
@@ -721,14 +814,15 @@ def test_the_layers_publish_their_geometry_when_someone_listens(tmp_path):
     assert gauges["gdn/key_heads"] == 2 and gauges["gdn/value_heads"] == 4
     assert gauges["gdn/d_k"] == 8 and gauges["gdn/d_v"] == 8
     assert gauges["gdn/chunk"] == 8 and gauges["gdn/chunks"] == 1
-    assert gauges["gdn/heads_a_group"] == 4
-    assert gauges["gdn/xla_chunked_scan"] == 1
+    assert gauges["gdn/tokens_a_step"] == 8 and gauges["gdn/heads_a_step"] == 2
+    assert gauges["gdn/grid_steps"] == 2 * 2 and gauges["gdn/vmem_bytes"] > 0
+    assert gauges["gdn/kernel"] == 1 and "gdn/heads_a_group" not in gauges
     assert gauges["ssm_conv/channels"] == 64 and gauges["ssm_conv/taps"] == 4
     assert gauges["moe/experts"] == 8 and gauges["moe/experts_held"] == 4
     assert gauges["moe/top_k"] == 3 and gauges["moe/pair_rows"] == 48
     assert gauges["moe/softmax"] == 1 and gauges["moe/swiglu"] == 1
     rows = {r["event"]: r for r in map(json.loads, open(path))}
-    assert rows["gdn_geometry"]["form"] == "xla_chunked_scan"
+    assert rows["gdn_geometry"]["form"] == "kernel"
     assert rows["moe_geometry"]["router"] == "softmax"
 
 
@@ -751,12 +845,14 @@ def test_the_layers_ops_carry_their_scopes():
         return set(re.findall(r'op_name="([^"]*)"', text))
 
     def both_passes(paths, needle):
-        inside = [p for p in paths if needle in p]
+        # (a kernel's jitted wrapper stands between the mixer and its scope)
+        inside = [p for p in paths
+                  if all(part in p for part in needle.split("*"))]
         assert any("transpose(jvp(" in p for p in inside), needle
         assert any("transpose(jvp(" not in p for p in inside), needle
 
     gdn = paths_of(0)
-    for needle in ("/gdn-mixer/gdn-scan/", "/gdn-mixer/mixer-gate/",
+    for needle in ("/gdn-mixer/*/gdn-scan/", "/gdn-mixer/mixer-gate/",
                    "/gdn-mixer/mixer-proj/", "/moe-layer/moe-route/",
                    "/moe-layer/moe-dispatch/", "/moe-layer/moe-shared/"):
         both_passes(gdn, needle)

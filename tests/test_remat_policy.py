@@ -32,6 +32,7 @@ from chainermn_tpu.parallel.moe_dropless import ROUTER_CHOICE  # noqa: E402
 from chipbench import weights, weights_hybrid, weights_zaya  # noqa: E402
 
 fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
+gd = importlib.import_module("chainermn_tpu.ops.gated_delta")
 
 
 def kernel_calls(jaxpr, name):
@@ -50,9 +51,9 @@ def kernel_calls(jaxpr, name):
     return found
 
 
-def test_the_policy_is_the_three_names():
+def test_the_policy_is_the_four_names():
     assert remat_names() == (ROUTER_CHOICE, SAVED_PRODUCTS,
-                             fa.FLASH_RESIDUALS)
+                             fa.FLASH_RESIDUALS, gd.GDN_RESIDUALS)
 
 
 # ---------------------------------------------------------- the kernel's rule
@@ -100,6 +101,37 @@ def test_a_checkpoint_that_saves_the_name_runs_the_forward_once(
         assert kernel_calls(jaxpr, "flash-bwd-dq") == 1
         assert kernel_calls(jaxpr, "flash-bwd-dkv") == 1
         for got, want in zip(jax.jit(grad)(q, k, v), plain):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_a_checkpoint_that_saves_the_name_runs_the_delta_rule_once():
+    """As for flash: under ``jax.checkpoint`` with the model's policy the
+    gradient's jaxpr holds ONE ``gdn-fwd`` call (the one that keeps the
+    tiles' states), with no policy two; the gradients are the
+    unrematerialised ones to the bit either way."""
+    keys = jax.random.split(jax.random.PRNGKey(5), 6)
+    q = jax.random.normal(keys[0], (1, 32, 1, 8)) / 4
+    k = jax.random.normal(keys[1], (1, 32, 1, 8)) / 3
+    v = jax.random.normal(keys[2], (1, 32, 2, 8))
+    g = -jnp.exp(jax.random.normal(keys[3], (1, 32, 2)) - 2)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, 32, 2)))
+    w = jax.random.normal(keys[5], v.shape)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(gd.gated_delta_rule(
+            jnp.tanh(q), jnp.tanh(k), v, g, beta, chunk=8) * w)
+
+    def grad_of(fn):
+        return jax.grad(fn, argnums=range(5))
+
+    args = (q, k, v, g, beta)
+    plain = jax.jit(grad_of(loss))(*args)
+    for policy, fwd_calls in ((remat_policy(), 1), (None, 2)):
+        grad = grad_of(jax.checkpoint(loss, policy=policy))
+        jaxpr = jax.make_jaxpr(grad)(*args).jaxpr
+        assert kernel_calls(jaxpr, "gdn-fwd") == fwd_calls
+        assert kernel_calls(jaxpr, "gdn-bwd") == 1
+        for got, want in zip(jax.jit(grad)(*args), plain):
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -234,7 +266,8 @@ def test_kept_bytes_come_from_the_shapes():
     assert kept == {
         "layers": 4, "flash_layers": 2, "expert_layers": 0,
         f"{ROUTER_CHOICE}_bytes": 0, f"{SAVED_PRODUCTS}_bytes": 0,
-        f"{fa.FLASH_RESIDUALS}_bytes": 2 * 128 * 4 * (8 * 4 + 4)}
+        f"{fa.FLASH_RESIDUALS}_bytes": 2 * 128 * 4 * (8 * 4 + 4),
+        f"{gd.GDN_RESIDUALS}_bytes": 0}
     assert remat_kept(table, 32, BATCH * SEQ, 4, flash=False)[
         f"{fa.FLASH_RESIDUALS}_bytes"] == 0
     _, table, _ = _zaya_like()
@@ -243,7 +276,16 @@ def test_kept_bytes_come_from_the_shapes():
         "layers": 3, "flash_layers": 3, "expert_layers": 3,
         f"{ROUTER_CHOICE}_bytes": 3 * 128 * 4,
         f"{SAVED_PRODUCTS}_bytes": 3 * (256 * 5) * 2 * (24 + 24 + 32),
-        f"{fa.FLASH_RESIDUALS}_bytes": 3 * 128 * 4 * (16 * 2 + 4)}
+        f"{fa.FLASH_RESIDUALS}_bytes": 3 * 128 * 4 * (16 * 2 + 4),
+        f"{gd.GDN_RESIDUALS}_bytes": 0}
+    # a Gated DeltaNet row: ``o`` and a float32 state a value head and
+    # tile (two rows of 64 tokens, chunk 16: one tile of four chunks a row)
+    from chainermn_tpu.models.block_table import BlockTable, GDNSpec, LayerSpec
+
+    table = BlockTable((LayerSpec(mixer="gdn", gdn=GDNSpec(2, 4, 8, 16,
+                                                           chunk=16)),))
+    assert remat_kept(table, 32, BATCH * SEQ, 2, seq=SEQ)[
+        f"{gd.GDN_RESIDUALS}_bytes"] == 4 * 16 * (128 * 2 + 2 * 8 * 4)
 
 
 @pytest.mark.parametrize("name", sorted(TABLES))

@@ -1,7 +1,7 @@
 """The flash kernels, the loss head's gradient, the causal
-convolution's backward, the state-space scan's two kernels, the grouped
-matmuls and the dropless dispatch's row movers, compiled for a described
-TPU v5e, without the chip.
+convolution's backward, the state-space scan's and the gated delta rule's
+two kernels each, the grouped matmuls and the dropless dispatch's row
+movers, compiled for a described TPU v5e, without the chip.
 
 Interpret mode cannot show what Mosaic refuses: a block that is not
 aligned to the tiling, or more scoped VMEM than a kernel may use.  The
@@ -364,6 +364,100 @@ def test_mixer_layer_grows_no_copies_around_the_scan(one_chip, monkeypatch):
     assert sum(size >= 2 * 8192 * 1024 for size in sizes) <= 2
 
 
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_delta_rule_kernels_compile_at_the_cells_geometry(one_chip, which):
+    """``gdn-fwd`` and ``gdn-bwd`` at the ``qwen3next-train-1chip`` cell's
+    full geometry (2 x 8192 tokens, 16 key and 32 value heads of 128,
+    chunk 64, bfloat16): ONE Mosaic call a pass inside the default scoped
+    VMEM, at the tile ``gdn_tiles`` gives (eight chunks, both value heads
+    of a key head) — the calls ask for no limit of their own."""
+    gd = importlib.import_module("chainermn_tpu.ops.gated_delta")
+    b, S, Hk, Hv, d, chunk = 2, 8192, 16, 32, 128, 64
+
+    def arr(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    operands = (arr(b, S, Hk, d), arr(b, S, Hk, d), arr(b, S, Hv, d),
+                arr(b, S, Hv, dt=jnp.float32), arr(b, S, Hv, dt=jnp.float32))
+    if which == "fwd":
+        call = functools.partial(gd._gdn_fwd_call, C=chunk, keep=True,
+                                 interpret=False)
+    else:
+        call = functools.partial(gd._gdn_bwd_call, C=chunk, interpret=False)
+        operands += (arr(b, Hk, S // 512, Hv // Hk, d, d, dt=jnp.float32),
+                     arr(b, S, Hv, d))
+    # the rule's own tile: what the calls are built with on the chip
+    default = gd.default_interpret
+    gd.default_interpret = lambda: False
+    try:
+        tokens, heads, vmem = gd.gdn_tiles(S, chunk, Hk, Hv, d, d,
+                                           jnp.bfloat16)
+        compiled = jax.jit(call).lower(*operands).compile()
+    finally:
+        gd.default_interpret = default
+    assert (tokens, heads) == (512, 2) and vmem <= fa.VMEM_SCOPED_DEFAULT
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert " while(" not in compiled.as_text()
+
+
+def test_gdn_mixer_grows_no_copies_around_the_rule(one_chip, monkeypatch):
+    """One Gated DeltaNet mixer at the cell's shape, forward and backward
+    under remat as in the step: six Mosaic calls and no loop (the
+    convolution's forward twice and its backward, ``gdn-fwd`` twice —
+    once keeping the tiles' states — and ``gdn-bwd``).  The kernels take
+    the tokens on the lanes, as the convolution's do and as the compiler
+    lays out ``in_proj``'s result, where the split into heads moves
+    nothing: no ``copy`` stands under ``gdn-scan`` but those of the
+    per-token scalars (``g``, ``beta`` and their cotangents: (2, 8192,
+    32) float32), and the layer holds 14 copies, 4 of them of an
+    activation's size (the convolution's padded operand and the gate's
+    float32 reshape, forward and recomputed).  With the channels on the
+    lanes the same layer held 31 and 12: a transpose of ``q``, ``k``,
+    ``v``, ``o`` and of each cotangent a pass, and a float32 relayout a
+    reshape between (S, H d) and (H, d) tiles."""
+    from chainermn_tpu.models.block_table import GDNSpec
+    from chainermn_tpu.models.transformer import GatedDeltaNetMixer
+
+    ssd = importlib.import_module("chainermn_tpu.ops.ssd")
+    gd = importlib.import_module("chainermn_tpu.ops.gated_delta")
+    for module in (ssd, gd):
+        monkeypatch.setattr(module, "default_interpret", lambda: False)
+    d_model = 2048
+    mixer = GatedDeltaNetMixer(d_model, GDNSpec(16, 32, 128, 128), 1e-6,
+                               jnp.bfloat16)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: mixer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 256, d_model),
+                                             jnp.bfloat16))))
+    h = jax.ShapeDtypeStruct((2, 8192, d_model), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(params, h):
+        layer = jax.checkpoint(lambda p, h: h + mixer.apply(p, h))
+        return jnp.sum(layer(params, h).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, h).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 6 and " while(" not in text
+    assert len(re.findall(r'tpu_custom_call[^\n]*gdn-fwd', text)) == 2
+    sizes, under_rule = [], []
+    for line in text.splitlines():
+        found = re.search(r"= (\w+)\[([\d,]*)\]\S* (?:copy|transpose)\(", line)
+        if found:
+            size = (2 if found.group(1) == "bf16" else 4) * math.prod(
+                int(d) for d in found.group(2).split(",") if d)
+            sizes.append(size)
+            if "gdn-scan" in line:
+                under_rule.append(size)
+    assert len(sizes) <= 14
+    assert sum(size >= 2 * 8192 * 2048 * 2 for size in sizes) <= 4
+    assert max(under_rule, default=0) <= 2 * 8192 * 32 * 4
+    # read: 2.17 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.6e9
+
+
 @pytest.mark.parametrize("tile_rows", [256, 512])
 def test_grouped_matmuls_compile_at_the_expert_cells_widths(one_chip,
                                                             tile_rows):
@@ -511,13 +605,13 @@ def test_qwen3next_layers_compile_at_the_cells_shape(one_chip, monkeypatch,
     tokens, 32 of 512 gated experts of 512 held: a buffer of 192 tiles),
     forward and backward under remat with the model's policy as in the
     step.  The Gated DeltaNet row: the convolution's two kernels over
-    8,192 channels (forward twice: recomputed), no other Mosaic call in the
-    mixer (the chunked rule is XLA ops: a ``while`` over the head groups
-    and the scans inside it), and the temporaries of 8 heads a group
-    inside 4 GB.  The gated attention row: the three flash calls at
-    D = 256 (the forward ONCE), under the blocks ``auto_block_size``
-    picks (1024 forward, 512 backward).  Each with nine grouped calls of
-    the experts."""
+    8,192 channels (forward twice: recomputed) and the delta rule's two
+    (``gdn-fwd`` ONCE, keeping ``o`` and the tiles' states under the
+    policy's ``GDN_RESIDUALS``, and ``gdn-bwd``), the layer's only loops
+    the dispatch's row movers, and its temporaries inside 3 GB.  The gated
+    attention row: the three flash calls at D = 256 (the forward ONCE),
+    under the blocks ``auto_block_size`` picks (1024 forward, 512
+    backward).  Each with nine grouped calls of the experts."""
     from chainermn_tpu.models.block_table import (
         ExpertsSpec,
         GDNSpec,
@@ -528,7 +622,8 @@ def test_qwen3next_layers_compile_at_the_cells_shape(one_chip, monkeypatch,
 
     gm = importlib.import_module("chainermn_tpu.ops.grouped_matmul")
     ssd = importlib.import_module("chainermn_tpu.ops.ssd")
-    for module in (fa, gm, ssd):
+    gd = importlib.import_module("chainermn_tpu.ops.gated_delta")
+    for module in (fa, gm, ssd, gd):
         monkeypatch.setattr(module, "default_interpret", lambda: False)
     assert fa.auto_block_size(8192, 256, jnp.bfloat16, "fwd") == 1024
     assert fa.auto_block_size(8192, 256, jnp.bfloat16, "bwd") == 512
@@ -562,13 +657,25 @@ def test_qwen3next_layers_compile_at_the_cells_shape(one_chip, monkeypatch,
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         params, x).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 3 + 9
+    calls = {name: len(re.findall(
+        r'tpu_custom_call[^\n]*' + name + r'\b', text))
+        for name in ("ssm-conv-fwd", "ssm-conv-bwd", "gdn-fwd", "gdn-bwd",
+                     "flash-fwd", "flash-bwd-dq", "flash-bwd-dkv")}
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
     if kind == "gdn":
-        assert len(re.findall(r"ssm-conv-fwd", text)) >= 2
-        # (the kernels' names: the text's file table may name the module)
-        assert "ssm-conv-bwd" in text and "flash-fwd" not in text
-        assert "flash-bwd" not in text
+        assert text.count("tpu_custom_call") == 3 + 2 + 9
+        assert calls == {"ssm-conv-fwd": 2, "ssm-conv-bwd": 1, "gdn-fwd": 1,
+                         "gdn-bwd": 1, "flash-fwd": 0, "flash-bwd-dq": 0,
+                         "flash-bwd-dkv": 0}
+        # the loops left are the dispatch's row movers: none in the mixer
+        for line in text.splitlines():
+            assert " while(" not in line or "gdn-mixer" not in line, line
+        # read: 2.52 GB (3.33 with the XLA form, 8 of 32 heads a group)
+        assert temporaries < 3e9
     else:
-        assert "ssm-conv" not in text and "gdn-scan" not in text
-    # read: 3.33 GB for the gdn row (0.55 of them its float32 gradients)
-    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+        assert text.count("tpu_custom_call") == 3 + 9
+        assert calls == {"ssm-conv-fwd": 0, "ssm-conv-bwd": 0, "gdn-fwd": 0,
+                         "gdn-bwd": 0, "flash-fwd": 1, "flash-bwd-dq": 1,
+                         "flash-bwd-dkv": 1}
+        assert "gdn-scan" not in text
+        assert temporaries < 4e9
