@@ -21,7 +21,11 @@ state on to the next layer's, RMSNorm, gated experts, a tied head) and
 attention row — QK-norm, rotary positions on part of a head, a sigmoid
 gate on the output — every layer followed by a softmax top-k
 sparse-expert FFN with a gated shared expert; the zero-centred RMSNorm,
-an untied head).
+an untied head) and ``mellum`` (sliding-window attention rows to one
+full-attention row whose rotary positions are YaRN-scaled — two rows of
+one table that see and rotate differently — QK-norm, every layer followed
+by a softmax top-k sparse-expert FFN with no shared expert; RMSNorm, an
+untied head).
 
 Plain frozen dataclasses: hashable, so a table is a static field of the
 flax module.
@@ -31,6 +35,8 @@ from __future__ import annotations
 
 import dataclasses
 from typing import Mapping, Optional, Tuple
+
+import numpy as np
 
 MIXERS = ("attention", "mamba2", "cca", "gdn", "none")
 #: "rmsnorm_zc" is the zero-centred RMSNorm: the learned ``w`` starts at
@@ -141,6 +147,67 @@ class GDNSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnSpec:
+    """YaRN scaling of a row's rotary positions (arXiv:2309.00071): a
+    context of ``original_max_position`` stretched ``factor`` times.  The
+    dimensions that turn more than ``beta_fast`` times over the original
+    context keep their frequency, those that turn less than ``beta_slow``
+    times are slowed ``factor`` times, a linear ramp blends between; and
+    ``cos`` and ``sin`` are both multiplied by ``attention_factor`` (None:
+    ``0.1 ln(factor) + 1``), so the logits carry its square.  The ramp's
+    two ends are rounded outward to whole dimensions (the published
+    implementations' ``truncate``, on)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+    def __post_init__(self):
+        if self.factor < 1.0 or self.original_max_position < 1:
+            raise ValueError(f"yarn stretches a context of at least one "
+                             f"position by a factor >= 1, got {self}")
+
+    @property
+    def scale(self) -> float:
+        if self.attention_factor is not None:
+            return float(self.attention_factor)
+        return 0.1 * float(np.log(self.factor)) + 1.0
+
+
+def rotary_frequencies(rotary_dim: int, theta: float,
+                       yarn: Optional[YarnSpec] = None):
+    """``(inv_freq, scale)`` of a row's rotary positions: the
+    ``rotary_dim / 2`` float64 inverse frequencies ``theta^(-2 i /
+    rotary_dim)`` and 1.0 — or, under ``yarn``, the blend of those with
+    their ``factor``-th and the spec's ``scale`` for ``cos`` and ``sin``.
+
+    With ``c(r) = rotary_dim ln(L / (2 pi r)) / (2 ln theta)`` the
+    dimension that turns ``r`` times over the original context ``L``:
+    ``ramp_i = clip((i - low) / (high - low), 0, 1)`` from ``low =
+    floor(c(beta_fast))`` to ``high = ceil(c(beta_slow))``, held inside
+    the head, ``inv_freq_i *= (1 - ramp_i) + ramp_i / factor``."""
+    half = rotary_dim // 2
+    freq = theta ** (-np.arange(half, dtype=np.float64) * 2.0 / rotary_dim)
+    if yarn is None:
+        return freq, 1.0
+
+    def turns(r):
+        return (rotary_dim * np.log(yarn.original_max_position
+                                    / (2.0 * np.pi * r))
+                / (2.0 * np.log(theta)))
+
+    low = max(np.floor(turns(yarn.beta_fast)), 0.0)
+    high = min(np.ceil(turns(yarn.beta_slow)), rotary_dim - 1.0)
+    if low == high:
+        high += 0.001                  # a step, not a division by zero
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return freq * ((1.0 - ramp) + ramp / yarn.factor), yarn.scale
+
+
+@dataclasses.dataclass(frozen=True)
 class ExpertsSpec:
     """A sparse-expert FFN: a router over ``n_experts`` (the published
     count: its width), ``top_k`` chosen a token, none dropped; the
@@ -226,6 +293,12 @@ class LayerSpec:
                                        # the first so many of a head's
                                        # dimensions (0: none), at rope_theta
     rope_theta: float = 10000.0
+    yarn: Optional[YarnSpec] = None    # attention rows: the rotary
+                                       # positions YaRN-scaled (None: plain)
+    window: Optional[int] = None       # attention rows: a query sees its
+                                       # ``window`` most recent positions,
+                                       # itself among them (None: every
+                                       # earlier one)
     qk_norm: bool = False              # attention rows: the row's norm over
                                        # each query and key head
     out_gate: bool = False             # attention rows: [q | gate] = W_q h a
@@ -251,9 +324,15 @@ class LayerSpec:
         if (self.mixer == "gdn") != (self.gdn is not None):
             raise ValueError("a gdn row, and only it, carries a GDNSpec")
         if self.mixer != "attention" and (
-                self.rotary_dim or self.qk_norm or self.out_gate):
-            raise ValueError("rotary_dim, qk_norm and out_gate are an "
-                             "attention row's")
+                self.rotary_dim or self.qk_norm or self.out_gate
+                or self.window is not None):
+            raise ValueError("rotary_dim, qk_norm, out_gate and window "
+                             "are an attention row's")
+        if self.window is not None and self.window < 1:
+            raise ValueError(f"window must be >= 1, got {self.window}")
+        if self.yarn is not None and not self.rotary_dim:
+            raise ValueError("yarn scales rotary positions: the row has "
+                             "no rotary_dim")
         if self.rotary_dim % 2 or self.rotary_dim < 0 or (
                 self.d_head is not None and self.rotary_dim > self.d_head):
             raise ValueError(f"rotary_dim {self.rotary_dim} is no even "
@@ -333,10 +412,10 @@ def _first_layers(kinds, n_layers):
 def table_from_config(config: Mapping, n_layers: Optional[int] = None,
                       experts_held: Optional[Tuple[int, int]] = None
                       ) -> BlockTable:
-    """The table of a published ``config.json``, by its own keys.  Four
+    """The table of a published ``config.json``, by its own keys.  Five
     families are read, by ``model_type``: ``granitemoehybrid`` (its dense
-    members: ``num_local_experts`` 0), ``nemotron_h``, ``zaya`` and
-    ``qwen3_next``.  ``n_layers``
+    members: ``num_local_experts`` 0), ``nemotron_h``, ``zaya``,
+    ``qwen3_next`` and ``mellum``.  ``n_layers``
     keeps the first so many layers (a pipeline stage, a cut to fit); None
     keeps ``num_hidden_layers``.  ``experts_held`` is the ``(first,
     count)`` of the published experts this rank holds in every expert
@@ -345,7 +424,7 @@ def table_from_config(config: Mapping, n_layers: Optional[int] = None,
     What this system cannot build raises here, by key."""
     readers = {"granitemoehybrid": _granite_table,
                "nemotron_h": _nemotron_h_table, "zaya": _zaya_table,
-               "qwen3_next": _qwen3_next_table}
+               "qwen3_next": _qwen3_next_table, "mellum": _mellum_table}
     reader = readers.get(config.get("model_type"))
     if reader is None:
         raise ValueError(
@@ -608,3 +687,83 @@ def _qwen3_next_table(config, n_layers, experts_held):
     return BlockTable(
         layers=tuple(rows[k] for k in kinds), positions="rotary",
         final_norm="rmsnorm_zc", norm_eps=eps, tied_head=False)
+
+
+def _mellum_table(config, n_layers, experts_held):
+    """``mellum``: layer ``i`` by ``layer_types[i]`` — a
+    ``sliding_attention`` row sees ``sliding_window`` positions, a
+    ``full_attention`` row every earlier one; both ``num_attention_heads``
+    query and ``num_key_value_heads`` key/value heads of ``head_dim``,
+    QK-norm, rotary positions on the whole head as ``rope_parameters``
+    says for the row's kind (``default``: plain at ``rope_theta``;
+    ``yarn``: :class:`YarnSpec`) — every layer then ``num_experts`` gated
+    experts of ``moe_intermediate_size``, ``num_experts_per_tok`` a token
+    by a softmax router renormalised over the chosen, no shared expert;
+    RMSNorm, an untied head.  Refused by key: dense layers among the
+    sparse ones, sliding rows with ``use_sliding_window`` off or no
+    ``sliding_window``, another rope type or an untruncated YaRN ramp,
+    router weights not renormalised, biases, another activation, a tied head."""
+    kinds = list(config["layer_types"])
+    ropes = config.get("rope_parameters", {})
+    _refuse([
+        (len(kinds) != config["num_hidden_layers"]
+         or len(config.get("mlp_layer_types", kinds)) != len(kinds),
+         "layer_types / mlp_layer_types that do not list "
+         "num_hidden_layers entries"),
+        (set(config.get("mlp_layer_types", ())) - {"sparse"},
+         "mlp_layer_types other than sparse (dense layers among the "
+         "sparse ones)"),
+        (set(kinds) - {"sliding_attention", "full_attention"},
+         f"layer_types other than sliding_attention and full_attention: "
+         f"{sorted(set(kinds))}"),
+        ("sliding_attention" in kinds and not (
+            config.get("use_sliding_window")
+            and config.get("sliding_window")),
+         "sliding_attention rows without use_sliding_window and a "
+         "sliding_window"),
+        (set(kinds) - set(ropes), "a layer type rope_parameters has no "
+         "entry for"),
+        (any(r.get("rope_type", "default") not in ("default", "yarn")
+             for r in ropes.values()),
+         "rope_type other than default and yarn"),
+        (not all(r.get("truncate", True) for r in ropes.values()),
+         "a yarn ramp that is not truncated to whole dimensions"),
+        (not config.get("norm_topk_prob", True),
+         "norm_topk_prob false (router weights not renormalised over the "
+         "chosen)"),
+        (bool(config.get("attention_bias")), "attention_bias"),
+        (config.get("hidden_act") != "silu", "hidden_act other than silu"),
+        (bool(config.get("tie_word_embeddings")), "a tied output head"),
+    ])
+    eps = float(config["rms_norm_eps"])
+
+    def row(kind):
+        rope = ropes[kind]
+        yarn = None
+        if rope.get("rope_type", "default") == "yarn":
+            yarn = YarnSpec(
+                factor=float(rope["factor"]),
+                original_max_position=int(
+                    rope["original_max_position_embeddings"]),
+                beta_fast=float(rope.get("beta_fast", 32.0)),
+                beta_slow=float(rope.get("beta_slow", 1.0)),
+                attention_factor=rope.get("attention_factor"))
+        return LayerSpec(
+            mixer="attention", norm="rmsnorm", ffn="experts", norm_eps=eps,
+            n_heads=config["num_attention_heads"],
+            n_kv_heads=config["num_key_value_heads"],
+            d_head=config["head_dim"], rotary_dim=config["head_dim"],
+            rope_theta=float(rope["rope_theta"]), yarn=yarn, qk_norm=True,
+            window=(int(config["sliding_window"])
+                    if kind == "sliding_attention" else None),
+            experts=ExpertsSpec(
+                n_experts=config["num_experts"],
+                top_k=config["num_experts_per_tok"],
+                d_expert=config["moe_intermediate_size"], d_shared=0,
+                held=experts_held, router="softmax", expert="swiglu"))
+
+    rows = {kind: row(kind) for kind in set(kinds)}
+    return BlockTable(
+        layers=tuple(rows[k] for k in _first_layers(kinds, n_layers)),
+        positions="rotary", final_norm="rmsnorm", norm_eps=eps,
+        tied_head=False)

@@ -29,7 +29,9 @@ from chainermn_tpu.models.block_table import (
     GDNSpec,
     LayerSpec,
     SSMSpec,
+    YarnSpec,
     gpt2_table,
+    rotary_frequencies,
 )
 from chainermn_tpu.observability.spans import named_scope, telemetry_active
 
@@ -99,6 +101,13 @@ class MultiHeadAttention(nn.Module):
                                     # so many dimensions of a query and key
                                     # head (0: none), at ``rope_theta``
     rope_theta: float = 10000.0
+    yarn: Optional[YarnSpec] = None  # the rotary positions YaRN-scaled
+    window: Optional[int] = None    # a query sees its ``window`` most
+                                    # recent positions, itself among them
+                                    # (None: every earlier one).  The row's:
+                                    # handed to the ``attention_fn`` as
+                                    # ``window=``, a band on the dense
+                                    # path's mask
     qk_norm: Optional[str] = None   # a norm of ``NORM_CLASSES`` over each
                                     # query and key head, before the
                                     # rotation (``q_norm``, ``k_norm``)
@@ -111,13 +120,15 @@ class MultiHeadAttention(nn.Module):
                  seq_lens=None):
         d_head = self.d_head or self.d_model // self.n_heads
         n_kv = self.n_kv_heads or self.n_heads
-        if (self.rotary_dim or self.qk_norm or self.out_gate) and (
+        if (self.rotary_dim or self.qk_norm or self.out_gate
+                or self.window is not None) and (
                 self.decode or self.paged is not None):
             raise ValueError(
-                "an attention row with rotary positions, QK-norm or an "
-                "output gate is built for training and whole-sequence "
-                "evaluation: the KV caches take no positions and keep "
-                "no gate")
+                "an attention row with rotary positions, QK-norm, an "
+                "output gate or a window is built for training and "
+                "whole-sequence evaluation: the KV caches take no "
+                "positions, keep no gate and free no page behind a "
+                "window")
         if self.n_heads % n_kv:
             raise ValueError(
                 f"n_kv_heads ({n_kv}) must divide n_heads ({self.n_heads})"
@@ -162,7 +173,8 @@ class MultiHeadAttention(nn.Module):
                     pos = jnp.arange(q.shape[1])
                     q, k = (rotate_partial(
                         x.astype(jnp.float32), pos, self.rotary_dim,
-                        self.rope_theta).astype(self.dtype) for x in (q, k))
+                        self.rope_theta, self.yarn).astype(self.dtype)
+                        for x in (q, k))
 
         def project_out(out):
             if gate is not None:
@@ -398,9 +410,17 @@ class MultiHeadAttention(nn.Module):
 
         if self.attention_fn is not None:
             # GQA-aware adapters (flash and its SP compositions) consume
-            # the reduced kv head count directly.
-            out = self.attention_fn(q, k, v, mask)
+            # the reduced kv head count directly, and take the row's
+            # window where it has one.
+            banded = {} if self.window is None else {"window": self.window}
+            out = self.attention_fn(q, k, v, mask, **banded)
         else:
+            if self.window is not None:
+                # (a window is causal, as the kernels have it)
+                behind = jnp.arange(q.shape[1])[:, None] - jnp.arange(
+                    k.shape[1])[None, :]
+                band = (behind >= 0) & (behind < self.window)
+                mask = band if mask is None else mask & band
             if n_kv != self.n_heads:
                 # Dense-softmax path: broadcast kv heads (the grads sum
                 # back over the group through repeat's transpose).
@@ -642,16 +662,21 @@ def rebalance_routers(params, chosen, rate: float):
     return out
 
 
-def rotate_partial(x, positions, rotary_dim: int, theta: float):
+def rotate_partial(x, positions, rotary_dim: int, theta: float,
+                   yarn: Optional[YarnSpec] = None):
     """Rotary positions on the first ``rotary_dim`` of the last axis of
     ``x`` (b, S, heads, d_head), the rest passed through: dimension ``i``
     of the first half of the rotated part pairs with ``i + rotary_dim /
-    2``, at the angle ``positions x theta^(-2 i / rotary_dim)``."""
+    2``, at the angle ``positions x theta^(-2 i / rotary_dim)`` — or,
+    under ``yarn``, at the row's blended frequencies with ``cos`` and
+    ``sin`` times its scale (``block_table.rotary_frequencies``)."""
     half = rotary_dim // 2
-    freq = theta ** (-np.arange(half, dtype=np.float64) * 2.0 / rotary_dim)
+    freq, scale = rotary_frequencies(rotary_dim, theta, yarn)
     angle = positions.astype(jnp.float32)[:, None] * jnp.asarray(
         freq, jnp.float32)                                   # (S, half)
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    if yarn is not None:
+        cos, sin = cos * scale, sin * scale
     a, b, rest = (x[..., :half], x[..., half:rotary_dim],
                   x[..., rotary_dim:])
     return jnp.concatenate(
@@ -959,7 +984,8 @@ class Block(nn.Module):
 
         if row.mixer == "attention":
             h = normed(x)
-            with named_scope("attn-mixer"):
+            with named_scope(
+                    "attn-mixer" if row.window is None else "attn-window"):
                 branch = MultiHeadAttention(
                     self.d_model, row.n_heads, self.dtype, self.attention_fn,
                     decode=self.decode, cache_len=self.cache_len,
@@ -968,6 +994,7 @@ class Block(nn.Module):
                     kv_dtype=self.kv_dtype, sp_axis=self.sp_axis,
                     scale=row.attn_scale, d_head=row.d_head,
                     rotary_dim=row.rotary_dim, rope_theta=row.rope_theta,
+                    yarn=row.yarn, window=row.window,
                     qk_norm=row.norm if row.qk_norm else None,
                     norm_eps=row.norm_eps, out_gate=row.out_gate,
                 )(h, h, mask, block_tables=block_tables, seq_lens=seq_lens)
@@ -1325,7 +1352,8 @@ def remat_kept(table: BlockTable, d_model: int, tokens: int, itemsize: int,
     :func:`remat_names` (activations ``itemsize`` bytes an element).
     ``flash``: whether the attention rows reach the flash kernels (a
     model without an ``attention_fn`` runs the dense path, which names
-    nothing)."""
+    nothing).  A row with a ``window`` keeps what a full row keeps: the
+    kernel's ``o`` and ``lse`` are a token's, whatever it attended."""
     from chainermn_tpu.ops.gated_delta import gdn_tiles
     from chainermn_tpu.ops.grouped_matmul import TILE_ROWS
     from chainermn_tpu.parallel import moe_dropless as moe

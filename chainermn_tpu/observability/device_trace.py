@@ -35,6 +35,11 @@ Three readings of one step (the vocabulary is ``observability/spans.py``):
   phase.  ``pass`` cuts the same seconds by forward / recompute /
   backward (the path's ``transpose(...)`` wrappers and
   ``rematted_computation``), ``layer`` by the path's ``layer_<i>``.
+  ``within`` nests them: under every scope name on the owning path, the
+  seconds by owner — ``within["moe-layer"]`` is an expert layer by its
+  parts, ``within["attn-window"]["flash-fwd"]`` the forward kernel's time
+  in the windowed rows and ``within["attn-mixer"]["flash-fwd"]`` in the
+  full ones.  Not a partition: an op counts under each name it is in.
 
 A fusion carries the ``op_name`` of ONE of the ops the compiler fused
 into it, and the phase and region readings take that one: a
@@ -99,7 +104,10 @@ class ScopeTable(dict):
     path that owns an instruction where that is not its own
     (:meth:`owner_path`), ``owners_in`` the distinct ``owner(path)`` among
     the ops of each fusion, ``inherited`` the instructions whose own path
-    names no phase and that took a neighbour's."""
+    names no phase and that took a neighbour's.  ``tiles_within`` is
+    ``tiles`` by the other scope names on the kernel's path (``{outer:
+    {region: [geometry]}}``): the census of the flash calls of a
+    windowed row apart from a full row's."""
 
     def __init__(self, paths=(), containers=(), program="", mixed=(),
                  owner_paths=(), owners_in=(), inherited=()):
@@ -111,12 +119,18 @@ class ScopeTable(dict):
         self.owners_in = dict(owners_in)
         self.inherited = frozenset(inherited)
         self.tiles: Dict[str, List[dict]] = {}
+        self.tiles_within: Dict[str, Dict[str, List[dict]]] = {}
         for path in self.values():
             found = [t for t in map(spans.parse_tiles,
                                     scope_components(path)) if t]
             region = classify(path)[1]
-            if found and region is not None:
-                seen = self.tiles.setdefault(region, [])
+            if not found or region is None:
+                continue
+            by_region = [self.tiles] + [
+                self.tiles_within.setdefault(outer, {})
+                for outer in scopes_on(path) if outer != region]
+            for tiles in by_region:
+                seen = tiles.setdefault(region, [])
                 if found[-1] not in seen:
                     seen.append(found[-1])
 
@@ -280,6 +294,14 @@ def owner(path: str) -> Tuple[Optional[str], Optional[str]]:
 
 
 @functools.lru_cache(maxsize=1 << 16)
+def scopes_on(path: str) -> Tuple[str, ...]:
+    """The kernel regions and model parts a path passes through,
+    outermost first (the last is its :func:`owner`)."""
+    return tuple(part for part in scope_components(path)
+                 if part not in spans.STEP_PHASES and spans.is_scope(part))
+
+
+@functools.lru_cache(maxsize=1 << 16)
 def pass_of(path: str) -> str:
     """Which pass a ``fwd-bwd`` path belongs to: ``recompute`` under
     ``jax.checkpoint``'s ``rematted_computation``, ``backward`` under a
@@ -322,7 +344,9 @@ def attribute(ops: Iterable[Triple], table: ScopeTable) -> dict:
     ``"shared_fusions": {instruction: s}`` the same by fusion;
     ``"inherited"``, those of instructions that took a neighbour's path;
     ``"pass": {"forward" | "recompute" | "backward": {name: s}}`` and
-    ``"layer": {"<i>": s}``.  ``"unowned"`` is the busy time no phase
+    ``"layer": {"<i>": s}``; ``"within": {scope: {owner: s}}``, each
+    owner's seconds under every scope name on its owning path (its own
+    among them).  ``"unowned"`` is the busy time no phase
     owns by that rule (what ``unattributed`` is by the fusion's own
     name).
     """
@@ -337,7 +361,8 @@ def attribute(ops: Iterable[Triple], table: ScopeTable) -> dict:
            "mixed": 0.0, "busy": 0.0,
            "owner": {}, "shared": {}, "shared_fusions": {},
            "inherited": 0.0, "unowned": 0.0,
-           "pass": {name: {} for name in PASSES}, "layer": {}}
+           "pass": {name: {} for name in PASSES}, "layer": {},
+           "within": {}}
 
     def add(to, name, seconds):
         to[name] = to.get(name, 0.0) + seconds
@@ -352,6 +377,8 @@ def attribute(ops: Iterable[Triple], table: ScopeTable) -> dict:
         name = name or NO_OWNER
         add(out["owner"], name, seconds)
         add(out["pass"][pass_of(path)], name, seconds)
+        for outer in set(scopes_on(path)):
+            add(out["within"].setdefault(outer, {}), name, seconds)
         if len(table.owners_in.get(key, ())) > 1:
             add(out["shared"], name, seconds)
             add(out["shared_fusions"], key, seconds)
@@ -549,6 +576,7 @@ def report_from(devices, host, tables: Dict[str, ScopeTable]) -> dict:
             "phase_ms": ms_by_name("phase"),
             "region_ms": ms_by_name("region"),
             "region_tiles": table.tiles,
+            "region_tiles_within": table.tiles_within,
             "owner_ms": ms_by_name("owner"),
             "shared_ms": ms_by_name("shared"),
             "inherited_ms": ms("inherited"),
@@ -557,6 +585,11 @@ def report_from(devices, host, tables: Dict[str, ScopeTable]) -> dict:
                         for p in PASSES},
             "layer_ms": dict(sorted(ms_by_name("layer").items(),
                                     key=lambda kv: int(kv[0]))),
+            "within_ms": {
+                outer: ms_by_name(outer, [
+                    {outer: g["within"].get(outer, {})} for g in per_dev])
+                for outer in sorted({k for g in per_dev
+                                     for k in g["within"]})},
             "shared_fusions": _largest_shared(
                 ms_by_name("shared_fusions"), table),
         }

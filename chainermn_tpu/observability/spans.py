@@ -88,7 +88,10 @@ KERNEL_REGIONS = (
 #: path), a layer's pre-norms and the final norm (``norm``), the
 #: residual's multiplier and add (``residual``), an attention layer from
 #: q/k/v to its output projection (``attn-mixer``: the flash regions nest
-#: in it as they do in ``cca-mixer``), every mixer's dense matrices
+#: in it as they do in ``cca-mixer``; ``attn-window`` in its place where
+#: the row sees through a sliding window, so that a capture tells the two
+#: kinds of row apart — ``device_trace``'s ``within`` reading has the
+#: flash regions' time under each), every mixer's dense matrices
 #: (``mixer-proj``), a mixer's float32 side (``mixer-gate``) — Mamba-2's
 #: ``dt`` softplus, ``-exp(A_log)``, the casts of ``y`` and the gate, ``y *
 #: silu(gate)`` and the gated norm; the Gated DeltaNet's ``beta``, ``g``,
@@ -98,8 +101,8 @@ KERNEL_REGIONS = (
 #: ``mamba-mixer``); the OWNER reading of ``device_trace`` takes the
 #: innermost name of both tuples.
 MODEL_PARTS = (
-    "embed", "norm", "residual", "attn-mixer", "mixer-proj", "mixer-gate",
-    "ffn",
+    "embed", "norm", "residual", "attn-mixer", "attn-window", "mixer-proj",
+    "mixer-gate", "ffn",
 )
 
 #: What the jitted programs compile as (``jit_<name>`` in a capture's

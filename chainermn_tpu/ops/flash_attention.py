@@ -1155,6 +1155,13 @@ def seg_to_bh(ids, H: int):
     return jnp.repeat(ids.astype(jnp.int32), H, axis=0)[..., None]
 
 
+def row_window(own, handed):
+    """The window an ``attention_fn`` adapter runs under: the one the
+    calling row hands over (``MultiHeadAttention.window``), else the
+    adapter's own default."""
+    return own if handed is None else handed
+
+
 def make_flash_attention_fn(causal: bool = True, q_segment_ids=None,
                             kv_segment_ids=None, window=None,
                             block_q=None, block_k=None,
@@ -1162,6 +1169,11 @@ def make_flash_attention_fn(causal: bool = True, q_segment_ids=None,
                             scale: Optional[float] = None):
     """Adapter for the transformer layers' ``attention_fn`` slot (mask
     argument ignored; causality is the kernel's).
+
+    ``window``: one sliding window for every layer that calls the
+    adapter; a row of a block table that has its own hands it over at
+    the call (``fn(q, k, v, mask, window=...)``), so the rows of one
+    model can differ (:func:`row_window`).
 
     ``scale``: the softmax scale, passed through to
     :func:`flash_attention` (None = ``1/sqrt(D)``) and kept as the
@@ -1185,6 +1197,8 @@ def make_flash_attention_fn(causal: bool = True, q_segment_ids=None,
       sees (a mismatch raises rather than silently masking shard 1+ with
       shard 0's rows)."""
 
+    own_window = window
+
     def _match(ids, batch):
         if ids.ndim == 1:
             import jax.numpy as _jnp
@@ -1200,7 +1214,7 @@ def make_flash_attention_fn(causal: bool = True, q_segment_ids=None,
             )
         return ids
 
-    def fn(q, k, v, mask=None):
+    def fn(q, k, v, mask=None, window=None):
         del mask
         qs = ks = None
         if q_segment_ids is not None:
@@ -1212,7 +1226,8 @@ def make_flash_attention_fn(causal: bool = True, q_segment_ids=None,
             )
         return flash_attention(
             q, k, v, causal=causal, q_segment_ids=qs, kv_segment_ids=ks,
-            window=window, block_q=block_q, block_k=block_k,
+            window=row_window(own_window, window),
+            block_q=block_q, block_k=block_k,
             block_q_bwd=block_q_bwd, block_k_bwd=block_k_bwd, scale=scale,
         )
 

@@ -467,9 +467,14 @@ def make_ring_attention_fn(axis_name: str, causal: bool = True,
     """Adapter with the ``attention_fn(q, k, v, mask)`` signature the
     transformer layers accept (mask ignored: causality is positional).
     ``segment_ids``: optional row-uniform GLOBAL (S,) packed-sequence
-    ids, sliced per shard at call time via the traced axis index."""
+    ids, sliced per shard at call time via the traced axis index.
+    ``window``: as ``make_flash_attention_fn``'s — a row that has its own
+    hands it over at the call."""
+    from chainermn_tpu.ops.flash_attention import row_window
 
-    def fn(q, k, v, mask=None):
+    own_window = window
+
+    def fn(q, k, v, mask=None, window=None):
         del mask
         qs = ks = None
         if segment_ids is not None:
@@ -479,7 +484,8 @@ def make_ring_attention_fn(axis_name: str, causal: bool = True,
             ks = qs
         return ring_attention(
             q, k, v, axis_name, causal=causal,
-            q_segment_ids=qs, kv_segment_ids=ks, window=window,
+            q_segment_ids=qs, kv_segment_ids=ks,
+            window=row_window(own_window, window),
         )
 
     return fn
@@ -515,8 +521,13 @@ def make_zigzag_ring_attention_fn(axis_name: str, segment_ids=None):
     ``segment_ids``: optional row-uniform GLOBAL (S,) ids ALREADY in
     zigzag layout (apply the same permutation as the tokens)."""
 
-    def fn(q, k, v, mask=None):
+    def fn(q, k, v, mask=None, window=None):
         del mask
+        if window is not None:
+            raise ValueError(
+                "zigzag ring attention builds no sliding window: a table "
+                "with windowed rows takes make_ring_attention_fn or "
+                "make_ulysses_attention_fn")
         seg = None
         if segment_ids is not None:
             seg = _local_seg_slice(

@@ -119,9 +119,14 @@ def make_ulysses_attention_fn(axis_name: str, causal: bool = True,
                               segment_ids=None, window=None):
     """Adapter matching the transformer layers' ``attention_fn`` slot.
     ``segment_ids``: optional row-uniform GLOBAL (S,) packed-sequence
-    ids, sliced per shard at call time via the traced axis index."""
+    ids, sliced per shard at call time via the traced axis index.
+    ``window``: as ``make_flash_attention_fn``'s — a row that has its own
+    hands it over at the call."""
+    from chainermn_tpu.ops.flash_attention import row_window
 
-    def fn(q, k, v, mask=None):
+    own_window = window
+
+    def fn(q, k, v, mask=None, window=None):
         del mask
         qs = None
         if segment_ids is not None:
@@ -138,7 +143,7 @@ def make_ulysses_attention_fn(axis_name: str, causal: bool = True,
             )
         return ulysses_attention(
             q, k, v, axis_name, causal=causal, q_segment_ids=qs,
-            window=window,
+            window=row_window(own_window, window),
         )
 
     return fn

@@ -1,0 +1,10 @@
+"""Device ms a step of the held experts' grouped matmuls (three products
+forward, six backward, 8 groups of ~2,048 rows at K = 2304, N = 896), with
+the weights' rounding to the compute type and the gate's product between
+them."""
+
+from chipbench import scope_reduce
+
+
+def read(ctx):
+    return scope_reduce.region_ms(ctx, "moe-experts")
