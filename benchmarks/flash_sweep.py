@@ -57,6 +57,40 @@ def build(which, bq, bk, scale, causal):
     return jax.jit(fn)
 
 
+def device_report(programs, calls):
+    """``(report, failed)`` of ``{name: (jitted, operands)}``: every
+    program compiled, run ``calls`` times inside ONE profiler capture and
+    read by device time under each scope (``report["programs"][name]
+    ["region_ms"]``; off the chip the capture has no device plane and the
+    report no programs).  A program Mosaic refuses is left out and named
+    in ``failed`` with its error."""
+    compiled, tables, failed = {}, {}, {}
+    for name, (fn, operands) in programs.items():
+        try:
+            c = fn.lower(*operands).compile()
+            jax.block_until_ready(c(*operands))
+        except Exception as e:  # a geometry Mosaic refuses: report, go on
+            failed[name] = f"{type(e).__name__}: {e}"[:300]
+            continue
+        compiled[name] = (c, operands)
+        tables[name] = device_trace.scope_table(c)
+
+    logdir = tempfile.mkdtemp(prefix="flash_sweep_")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=options)
+    for c, operands in compiled.values():
+        for _ in range(calls):
+            out = c(*operands)
+        jax.block_until_ready(out)
+    jax.profiler.stop_trace()
+    found = glob.glob(os.path.join(
+        logdir, "plugins", "profile", "*", "*.xplane.pb"))
+    return device_trace.report_from(
+        *device_trace.read_capture(max(found, key=os.path.getmtime)),
+        tables), failed
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=8)
@@ -79,34 +113,13 @@ def main():
     edges = [int(b) for b in args.blocks.split(",") if S % int(b) == 0]
     o, lse = build("fwd", min(edges), min(edges), scale, args.causal)(q, k, v)
 
-    compiled, tables, failed = {}, {}, {}
+    programs = {}
     for which, (bq, bk) in itertools.product(
             ("fwd", "bwd"), itertools.product(edges, edges)):
-        name = f"{which}_{bq}x{bk}"
         operands = (q, k, v) if which == "fwd" else (q, k, v, o, lse, do)
-        try:
-            c = build(which, bq, bk, scale, args.causal).lower(
-                *operands).compile()
-            jax.block_until_ready(c(*operands))
-        except Exception as e:  # a geometry Mosaic refuses: report, go on
-            failed[name] = f"{type(e).__name__}: {e}"[:300]
-            continue
-        compiled[name] = (c, operands)
-        tables[name] = device_trace.scope_table(c)
-
-    logdir = tempfile.mkdtemp(prefix="flash_sweep_")
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    jax.profiler.start_trace(logdir, profiler_options=options)
-    for c, operands in compiled.values():
-        for _ in range(args.calls):
-            out = c(*operands)
-        jax.block_until_ready(out)
-    jax.profiler.stop_trace()
-    found = glob.glob(os.path.join(
-        logdir, "plugins", "profile", "*", "*.xplane.pb"))
-    report = device_trace.report_from(
-        *device_trace.read_capture(max(found, key=os.path.getmtime)), tables)
+        programs[f"{which}_{bq}x{bk}"] = (
+            build(which, bq, bk, scale, args.causal), operands)
+    report, failed = device_report(programs, args.calls)
 
     # Needed FLOPs (what the roofline counts): 2 matmuls forward, 5 in the
     # backward (2 in dq + its s, 3 in dk/dv + its s: 7 run), causal half.

@@ -14,9 +14,12 @@ whole Q block write nothing and skip the matmuls (``pl.when``), and their
 index maps stop at the band, so such a step copies nothing either.  A
 step has a fixed cost, so the blocks are as large as the scoped VMEM
 takes (:func:`auto_block_size`): at S=2048 a head row is four steps, not
-256.  What a call was built with — blocks, tiles live / visited / copied
-a head row (:func:`tile_census`) — rides in the kernels' scope path and
-goes to the telemetry sinks.
+256.  Under a sliding window the grid itself is the band: the inner axis
+is as long as the widest band is in tiles and the index maps start each
+resident block's walk at its band's first tile, so no step outside the
+band exists to be skipped.  What a call was built with — blocks, tiles
+live / visited / copied a head row (:func:`tile_census`) — rides in the
+kernels' scope path and goes to the telemetry sinks.
 
 Differentiable: a ``custom_vjp`` with explicit FlashAttention-2-style
 backward kernels — the forward saves one fp32 log-sum-exp per row, and the
@@ -123,13 +126,16 @@ def _q_live_range(ik, block_q, block_k, n_q, causal, window, xp=jnp):
     return lo, hi
 
 
-def _banded(live_range, causal, window):
-    """``clamp(outer, inner)`` for an index map: the streamed operand's
-    block index held inside the band of the resident one.  Pallas copies
-    a block only when its index changes between consecutive grid steps,
-    so a tile outside the band (which ``pl.when`` skips anyway) moves no
-    data.  Identity when nothing bands the attention."""
-    if not causal and window is None:
+def _banded(live_range, causal):
+    """``clamp(outer, inner)`` for an index map of a call WITHOUT a
+    window: the streamed operand's block index held inside the band of
+    the resident one.  Pallas copies a block only when its index changes
+    between consecutive grid steps, so a tile outside the causal bound
+    (which ``pl.when`` skips anyway) moves no data.  Identity when
+    nothing bands the attention.  A call with a window has no such
+    steps: its grid is the band (:func:`_band_steps`) and its index map
+    is :func:`_band_block`."""
+    if not causal:
         return lambda outer, inner: inner
 
     def clamp(outer, inner):
@@ -139,40 +145,104 @@ def _banded(live_range, causal, window):
     return clamp
 
 
+def _band_steps(live_range, n_outer):
+    """The inner grid axis of a call with a window: the most tiles the
+    band of any resident block reaches — a static number, computed with
+    numpy from the same bounds the index maps use."""
+    lo, hi = live_range(np.arange(n_outer), xp=np)
+    return int(np.max(hi - lo + 1))
+
+
+def _band_block(live_range, outer, step, xp=jnp):
+    """``(block, in_band)`` of band step ``step`` beside resident block
+    ``outer``: the streamed operand's block ``lo + step`` while that lies
+    in the band, else the band's last block again (a resident block near
+    the sequence's start reaches fewer tiles than the widest one: the
+    repeat copies nothing, and the kernels skip it by ``in_band`` so that
+    no tile is summed twice)."""
+    lo, hi = live_range(outer, xp=xp)
+    return xp.minimum(lo + step, hi), lo + step <= hi
+
+
+def _streamed_block(live_range, outer, step, window):
+    """``(block, in_band)`` of the streamed operand at inner grid step
+    ``step``, as a kernel reads it: the step itself without a window
+    (the grid is the whole axis, :func:`_band_live` alone decides and
+    ``in_band`` is None), :func:`_band_block` with one — the very block
+    the index map named."""
+    if window is None:
+        return step, None
+    return _band_block(live_range, outer, step)
+
+
+def _streamed_axis(live_range, n_outer, n_inner, causal, window):
+    """``(index_map, steps)`` of the streamed operand's grid axis beside
+    a resident axis of ``n_outer`` blocks: without a window all
+    ``n_inner`` blocks with the :func:`_banded` clamp, with one the
+    band's :func:`_band_steps` and :func:`_band_block`."""
+    if window is None:
+        return _banded(live_range, causal), n_inner
+    return (lambda outer, step: _band_block(live_range, outer, step)[0],
+            _band_steps(live_range, n_outer))
+
+
+def _live_ranges(Sq, Sk, block_q, block_k, causal, window):
+    """``(kv_range, q_range)``: :func:`_kv_live_range` of a q block and
+    :func:`_q_live_range` of a k block at this geometry, each a function
+    of the resident block's index (and ``xp``) alone — what the index
+    maps, the kernels and the census all read the band from."""
+    geometry = dict(block_q=block_q, block_k=block_k, causal=causal,
+                    window=window)
+    return (functools.partial(_kv_live_range, n_k=Sk // block_k, **geometry),
+            functools.partial(_q_live_range, n_q=Sq // block_q, **geometry))
+
+
 def tile_census(Sq, Sk, block_q, block_k, causal, window):
     """What one head row's grid does at this geometry — ``{"fwd", "dq",
     "dkv"}``, each ``{block_q, block_k, live, visited, copied}``: tiles
     that intersect the band (they run the matmuls), grid steps, and
     fetches of the streamed operand (K/V in forward and dq, the query
-    side in dk/dv: a step whose clamped block index repeats the previous
-    step's copies nothing).  Computed with the index maps' own bounds."""
+    side in dk/dv: a step whose block index repeats the previous step's
+    copies nothing).  Computed with the index maps' own bounds: without
+    a window ``visited`` is the whole ``n_q x n_k`` rectangle, the steps
+    past the causal bound entered and skipped; with one it is the band
+    grid's ``n_q x`` :func:`_band_steps` (``n_k x`` the query side's in
+    dk/dv), which is ``live`` and the few repeats of the blocks near the
+    sequence's start."""
     n_q, n_k = Sq // block_q, Sk // block_k
     iq = np.arange(n_q)[:, None]
     ik = np.arange(n_k)[None, :]
-    k_lo, k_hi = _kv_live_range(iq, block_q, block_k, n_k, causal, window,
-                                xp=np)
-    q_lo, q_hi = _q_live_range(ik, block_q, block_k, n_q, causal, window,
-                               xp=np)
-    grid = (n_q, n_k)
+    kv_range, q_range = _live_ranges(Sq, Sk, block_q, block_k, causal,
+                                     window)
     live = int(np.broadcast_to(_band_live(
         causal, window, iq * block_q, block_q, ik * block_k, block_k,
-    ), grid).sum())
-    kv_steps = np.broadcast_to(np.clip(ik, k_lo, k_hi), grid).ravel()
-    q_steps = np.broadcast_to(np.clip(iq, q_lo, q_hi), grid).T.ravel()
+    ), (n_q, n_k)).sum())
 
     def fetches(steps):
-        return 1 + int(np.count_nonzero(np.diff(steps)))
+        return 1 + int(np.count_nonzero(np.diff(steps.ravel())))
 
-    base = {"block_q": block_q, "block_k": block_k, "live": live,
-            "visited": n_q * n_k}
-    kv = dict(base, copied=fetches(kv_steps))
-    return {"fwd": kv, "dq": kv, "dkv": dict(base, copied=fetches(q_steps))}
+    if window is None:
+        kv_steps = np.broadcast_to(np.clip(ik, *kv_range(iq, xp=np)),
+                                   (n_q, n_k))
+        q_steps = np.broadcast_to(np.clip(iq, *q_range(ik, xp=np)),
+                                  (n_q, n_k)).T
+    else:
+        kv_steps, _ = _band_block(
+            kv_range, iq, np.arange(_band_steps(kv_range, n_q))[None, :],
+            xp=np)
+        q_steps, _ = _band_block(
+            q_range, ik.T, np.arange(_band_steps(q_range, n_k))[None, :],
+            xp=np)
+    base = {"block_q": block_q, "block_k": block_k, "live": live}
+    kv = dict(base, visited=kv_steps.size, copied=fetches(kv_steps))
+    return {"fwd": kv, "dq": kv,
+            "dkv": dict(base, visited=q_steps.size, copied=fetches(q_steps))}
 
 
 def _attn_kernel(
     *refs,
     scale: float, causal: bool, segmented: bool, block_q: int, block_k: int,
-    window=None,
+    kv_range, window=None,
 ):
     if segmented:
         (q_ref, k_ref, v_ref, qs_ref, ks_ref,
@@ -181,21 +251,25 @@ def _attn_kernel(
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
         qs_ref = ks_ref = None
     iq = pl.program_id(1)
-    ik = pl.program_id(2)
-    n_k = pl.num_programs(2)
+    j = pl.program_id(2)
+    n_j = pl.num_programs(2)
 
-    @pl.when(ik == 0)
+    @pl.when(j == 0)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
     q_start = iq * block_q
+    ik, in_band = _streamed_block(kv_range, iq, j, window)
     k_start = ik * block_k
 
     # Whole-block skip: K block past the causal bound OR entirely before
-    # the sliding window's reach.
+    # the sliding window's reach (a window's grid is its band: only the
+    # repeats of a short band's last block are left to skip).
     run = _band_live(causal, window, q_start, block_q, k_start, block_k)
+    if window is not None:
+        run = run & in_band
 
     @pl.when(run)
     def _():
@@ -234,7 +308,7 @@ def _attn_kernel(
         )
         m_ref[:, 0] = m_new
 
-    @pl.when(ik == n_k - 1)
+    @pl.when(j == n_j - 1)
     def _():
         denom = jnp.maximum(l_ref[:, 0], 1e-30)
         o_ref[0] = (acc_ref[:] / denom[:, None]).astype(o_ref.dtype)
@@ -341,23 +415,21 @@ def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
     BH, Sq, D = q.shape
     Sk = k.shape[1]
     G = _kv_group(BH, k.shape[0])
-    grid = (BH, Sq // block_q, Sk // block_k)
+    n_q = Sq // block_q
+    kv_range, _ = _live_ranges(Sq, Sk, block_q, block_k, causal, window)
+    kv_j, n_j = _streamed_axis(kv_range, n_q, Sk // block_k, causal, window)
+    grid = (BH, n_q, n_j)
     segmented = q_seg is not None
 
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal=causal, segmented=segmented,
-        block_q=block_q, block_k=block_k, window=window,
+        block_q=block_q, block_k=block_k, kv_range=kv_range, window=window,
     )
     scratch = [
         pltpu.VMEM((block_q, D), jnp.float32),
         pltpu.VMEM((block_q, 1), jnp.float32),
         pltpu.VMEM((block_q, 1), jnp.float32),
     ]
-    kv_j = _banded(
-        lambda i: _kv_live_range(i, block_q, block_k, grid[2], causal,
-                                 window),
-        causal, window,
-    )
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, D),
@@ -398,7 +470,7 @@ def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
 def _dq_kernel(
     *refs,
     scale: float, causal: bool, segmented: bool, block_q: int, block_k: int,
-    window=None,
+    kv_range, window=None,
 ):
     if segmented:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qs_ref, ks_ref,
@@ -408,16 +480,19 @@ def _dq_kernel(
          dq_ref, dq_acc) = refs
         qs_ref = ks_ref = None
     iq = pl.program_id(1)
-    ik = pl.program_id(2)
-    n_k = pl.num_programs(2)
+    j = pl.program_id(2)
+    n_j = pl.num_programs(2)
 
-    @pl.when(ik == 0)
+    @pl.when(j == 0)
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     q_start = iq * block_q
+    ik, in_band = _streamed_block(kv_range, iq, j, window)
     k_start = ik * block_k
     run = _band_live(causal, window, q_start, block_q, k_start, block_k)
+    if window is not None:
+        run = run & in_band
 
     @pl.when(run)
     def _():
@@ -439,7 +514,7 @@ def _dq_kernel(
         ds = (p * (dp - delta_ref[0, :, :]) * scale).astype(k.dtype)
         dq_acc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
-    @pl.when(ik == n_k - 1)
+    @pl.when(j == n_j - 1)
     def _():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -447,7 +522,7 @@ def _dq_kernel(
 def _dkv_kernel(
     *refs,
     scale: float, causal: bool, segmented: bool, block_q: int, block_k: int,
-    n_q: int, window=None,
+    n_q: int, q_range, window=None,
 ):
     if segmented:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qs_ref, ks_ref,
@@ -459,9 +534,10 @@ def _dkv_kernel(
     ik = pl.program_id(1)   # grid: (BHk, n_k, G*n_q) — (head, q) innermost
     # The innermost axis enumerates (g, iq) pairs: for GQA every query
     # head of the group contributes to this KV row's dk/dv, so the
-    # accumulator runs over all G * n_q steps and flushes once.
+    # accumulator runs over all G * n_q steps and flushes once.  (With
+    # a window ``n_q`` is the band's width in q blocks, not the axis'.)
     i = pl.program_id(2)
-    iq = i % n_q
+    iq, in_band = _streamed_block(q_range, ik, i % n_q, window)
     n_i = pl.num_programs(2)
 
     @pl.when(i == 0)
@@ -474,6 +550,8 @@ def _dkv_kernel(
     # Skip when the whole Q block precedes the whole K block (causal) or
     # lies entirely beyond the K block's window reach.
     run = _band_live(causal, window, q_start, block_q, k_start, block_k)
+    if window is not None:
+        run = run & in_band
 
     @pl.when(run)
     def _():
@@ -528,10 +606,9 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
     n_k = Sk // block_k
     params = _compiler_params(flash_vmem_bytes(
         block_q, block_k, D, q.dtype.itemsize, "bwd", segmented))
-    kv_j = _banded(
-        lambda i: _kv_live_range(i, block_q, block_k, n_k, causal, window),
-        causal, window,
-    )
+    kv_range, q_range = _live_ranges(Sq, Sk, block_q, block_k, causal,
+                                     window)
+    kv_j, n_j = _streamed_axis(kv_range, n_q, n_k, causal, window)
     q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
     k_spec = pl.BlockSpec((1, block_k, D),
                           lambda b, i, j: (b // G, kv_j(i, j), 0))
@@ -550,10 +627,11 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
         dq = pl.pallas_call(
             functools.partial(
                 _dq_kernel, scale=scale, causal=causal, segmented=segmented,
-                block_q=block_q, block_k=block_k, window=window,
+                block_q=block_q, block_k=block_k, kv_range=kv_range,
+                window=window,
             ),
             out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
-            grid=(BH, n_q, n_k),
+            grid=(BH, n_q, n_j),
             in_specs=dq_in,
             out_specs=pl.BlockSpec(
                 (1, block_q, D), lambda b, i, j: (b, i, 0)
@@ -570,14 +648,12 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
     # needs no extra pass.  Query-side rows for (kv row b, inner step i)
     # live at q row b*G + i // n_q, q block i % n_q.
     # The query side is the streamed one here: its block index stops at
-    # the band of k block j, within each query head of the group.
-    q_i = _banded(
-        lambda j: _q_live_range(j, block_q, block_k, n_q, causal, window),
-        causal, window,
-    )
+    # the band of k block j, within each query head of the group (with
+    # a window the group's inner axis IS that band, ``n_i`` blocks wide).
+    q_i, n_i = _streamed_axis(q_range, n_k, n_q, causal, window)
 
     def q_rows(b, j, i):
-        return (b * G + i // n_q, q_i(j, i % n_q), 0)
+        return (b * G + i // n_i, q_i(j, i % n_i), 0)
 
     qT_spec = pl.BlockSpec((1, block_q, D), q_rows)
     kT_spec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
@@ -595,13 +671,13 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
             functools.partial(
                 _dkv_kernel, scale=scale, causal=causal,
                 segmented=segmented, block_q=block_q, block_k=block_k,
-                n_q=n_q, window=window,
+                n_q=n_i, q_range=q_range, window=window,
             ),
             out_shape=[
                 jax.ShapeDtypeStruct((BHk, Sk, D), k.dtype),
                 jax.ShapeDtypeStruct((BHk, Sk, D), v.dtype),
             ],
-            grid=(BHk, n_k, G * n_q),
+            grid=(BHk, n_k, G * n_i),
             in_specs=dkv_in,
             out_specs=[
                 pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
@@ -890,9 +966,20 @@ def auto_block_size(S: int, D: int, dtype, which: str = "fwd",
     and the kernels come near the MXU only at large tiles, so few full
     steps beat many small ones (PERF.md §6, PR 25: the sweep at B=8,
     H=16, S=2048 took the three kernels from 41.9 ms a layer at
-    128 x 128 to 6.4 at 1024 x 1024).  Under a sliding window no wider
-    than the band, which a wider tile would only fill with masked work.
-    Each axis is sized alone: the footprint grows with either edge, so a
+    128 x 128 to 6.4 at 1024 x 1024).  Under a sliding window the
+    forward's edge is no wider than the band, which a wider tile would
+    only fill with masked work, and the backward's no wider than half of
+    it while that leaves 512: the grid of a windowed call is its band,
+    so an edge trades masked area (fill = window / (edge + window): 1/2
+    at the window's width, 2/3 at half of it) against steps alone, and
+    the backward's five products a tile make the masked area the dearer
+    of the two, the forward's rescaling of its accumulator a step the
+    steps (PERF.md §6, PR 40, ``benchmarks/flash_window_probe.py``: at
+    32/4 head rows, D=128, S=16,384 under a window of 1024 in bf16 the
+    backward pair takes 11.96 ms at 1024 x 1024 and 10.42 at 512 x 512,
+    the forward 5.55 and 6.11; 256-edge tiles lose everywhere; at
+    S=8,192 under 4,096 half the window is past what VMEM takes and 1024
+    wins both passes).  Each axis is sized alone: the footprint grows with either edge, so a
     pair of fitting edges fits.  A length no multiple of 128 divides
     keeps the old answer (``min(128, S)``: whole when short, else a
     block that does not divide and sends the call to XLA) — an auto pick
@@ -901,7 +988,10 @@ def auto_block_size(S: int, D: int, dtype, which: str = "fwd",
     if not edges:
         return min(128, S)
     if window is not None:
-        edges = [b for b in edges if b <= max(window, edges[0])]
+        widest = window
+        if which == "bwd":
+            widest = max(window // 2, min(window, 512))
+        edges = [b for b in edges if b <= max(widest, edges[0])]
     itemsize = jnp.dtype(dtype).itemsize
     fits = [b for b in edges
             if flash_vmem_bytes(b, b, D, itemsize, which, segmented)
@@ -950,8 +1040,12 @@ def flash_attention(
     ``window``: optional sliding-window size (Mistral-style local
     attention, causal only): query ``i`` attends keys ``[i - window + 1,
     i]``, intersected with the segment masks.  Whole tiles outside the
-    band are skipped in forward AND both backward kernels, so compute
-    scales O(S * window) instead of O(S²/2).
+    band are never entered, in forward AND both backward kernels: a
+    windowed call's inner grid axis is the band's width in tiles
+    (:func:`_band_steps`), not the sequence's, so compute AND grid steps
+    scale O(S * window) instead of O(S²/2) (PERF.md §6, PR 40: a step
+    that is entered and skipped costs 0.24 µs on a v5e, and 225 of a head
+    row's 256 were at S = 16,384 under a window of 1024).
 
     Uses the Pallas kernel when shapes allow (D ≤ 256, S divisible by the
     block sizes after clamping); otherwise falls back to XLA attention.

@@ -1,12 +1,18 @@
 """Pallas flash-attention kernel vs the XLA oracle (interpret mode on the
 CPU harness; the same kernel compiles for real on TPU)."""
 
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chainermn_tpu.ops.flash_attention import (
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import _flash_no_window  # noqa: E402
+
+from chainermn_tpu.ops.flash_attention import (  # noqa: E402
     _xla_attention,
     auto_block_size,
     flash_attention,
@@ -644,6 +650,24 @@ def test_auto_block_size_divides_aligns_and_fits(D, dtype, which, segmented):
     assert auto_block_size(2048, D, dtype, which, segmented, window=64) == 128
 
 
+@pytest.mark.parametrize("S,window,fwd,bwd", [
+    (16384, 1024, 1024, 512),    # mellum2-train-1chip's sliding rows
+    (8192, 4096, 1024, 1024),    # half the window is past what VMEM takes
+    (16384, 2048, 1024, 1024),
+    (16384, 768, 512, 512),      # never under 512 for the fill's sake
+    (16384, 512, 512, 512),
+    (2048, 300, 256, 256),       # nor wider than the band
+    (2048, 64, 128, 128),
+])
+def test_auto_block_size_under_a_window(S, window, fwd, bwd):
+    """The window rule at D=128 in bfloat16 (PERF.md §6, PR 40): the
+    forward's edge no wider than the band, the backward's no wider than
+    half of it while that leaves 512."""
+    got = [auto_block_size(S, 128, jnp.bfloat16, which, window=window)
+           for which in ("fwd", "bwd")]
+    assert got == [fwd, bwd]
+
+
 def test_flash_vmem_bytes_counts_tiles_and_columns():
     """The footprint grows with either edge, with D, with the dtype and
     with segment columns; the backward's streams outweigh the forward's;
@@ -692,16 +716,72 @@ _GEOMETRIES = [
     (128, 256, 32, 64, True, None),     # more keys than queries
     (256, 128, 64, 32, True, 17),       # more queries than keys
     (2048, 2048, 1024, 1024, True, None),
+    # the band grid's own cases: a window off the block's edge, smaller
+    # than a block, wider than S, at a block's edge, Sq != Sk both ways
+    (256, 256, 32, 32, True, 33),
+    (256, 256, 64, 64, True, 7),
+    (256, 256, 64, 128, True, 1000),
+    (256, 256, 64, 64, True, 64),
+    (256, 256, 128, 32, True, 96),
+    (128, 256, 32, 64, True, 40),
+    (256, 128, 32, 32, True, 48),
+    (16384, 16384, 1024, 1024, True, 1024),   # mellum2-train-1chip's row
+    (16384, 16384, 512, 512, True, 1024),
+    (8192, 8192, 1024, 1024, True, 4096),     # a Mistral-style row
 ]
+
+
+def _grid_walk(Sq, Sk, bq, bk, causal, window):
+    """``(kv_walk, q_walk)``: the grid of one head row as the wrappers
+    build it (``_streamed_axis``), each step as ``(resident block,
+    streamed block the index map names, whether the kernel runs the
+    tile)`` in the kernels' own step order — K/V streamed past a q block
+    (forward, dq), the query side streamed past a k block (dk/dv)."""
+    from chainermn_tpu.ops.flash_attention import (
+        _band_live,
+        _live_ranges,
+        _streamed_axis,
+        _streamed_block,
+    )
+
+    n_q, n_k = Sq // bq, Sk // bk
+    kv_range, q_range = _live_ranges(Sq, Sk, bq, bk, causal, window)
+
+    def walk(live_range, n_outer, n_inner, live):
+        index_map, steps = _streamed_axis(
+            live_range, n_outer, n_inner, causal, window)
+        out = []
+        for outer in range(n_outer):
+            for step in range(steps):
+                block, in_band = _streamed_block(
+                    live_range, outer, step, window)
+                named = int(index_map(outer, step))
+                runs = live(outer, int(block)) and (
+                    in_band is None or bool(in_band))
+                if runs:        # the kernel works on the block it was given
+                    assert named == int(block), (outer, step, named)
+                out.append((outer, named, runs))
+        return out, steps
+
+    def live_qk(i, j):
+        return bool(_band_live(causal, window, i * bq, bq, j * bk, bk))
+
+    kv_walk, kv_steps = walk(kv_range, n_q, n_k, live_qk)
+    q_walk, q_steps = walk(q_range, n_k, n_q, lambda j, i: live_qk(i, j))
+    return (kv_walk, kv_steps), (q_walk, q_steps)
 
 
 @pytest.mark.parametrize("Sq,Sk,bq,bk,causal,window", _GEOMETRIES)
 def test_band_clamp_stays_in_range_and_matches_band_live(
         Sq, Sk, bq, bk, causal, window):
-    """The clamped index maps never name a block outside [0, n); a tile
-    is live by `_band_live` exactly when the clamp leaves its index
-    alone, in the K/V maps (forward, dq) and the query-side map (dk/dv);
-    a dead tile repeats a live tile's index."""
+    """Without a window: the clamped index maps never name a block
+    outside [0, n); a tile is live by `_band_live` exactly when the clamp
+    leaves its index alone, in the K/V maps (forward, dq) and the
+    query-side map (dk/dv); a dead tile repeats a live tile's index.
+    With one, a walk of the band grid: no index out of range, every live
+    tile run exactly once and none twice, a step that does not run
+    repeats the block of the step before it (it copies nothing), and the
+    inner axis is no longer than the widest band."""
     from chainermn_tpu.ops.flash_attention import (
         _band_live,
         _banded,
@@ -710,15 +790,34 @@ def test_band_clamp_stays_in_range_and_matches_band_live(
     )
 
     n_q, n_k = Sq // bq, Sk // bk
+    live_tiles = {(i, j) for i in range(n_q) for j in range(n_k)
+                  if _band_live(causal, window, i * bq, bq, j * bk, bk)}
+    if window is not None:
+        (kv_walk, kv_steps), (q_walk, q_steps) = _grid_walk(
+            Sq, Sk, bq, bk, causal, window)
+        for walk, n_streamed, as_tile in (
+                (kv_walk, n_k, lambda outer, inner: (outer, inner)),
+                (q_walk, n_q, lambda outer, inner: (inner, outer))):
+            assert all(0 <= named < n_streamed for _, named, _ in walk)
+            ran = [as_tile(outer, named) for outer, named, runs in walk
+                   if runs]
+            assert len(ran) == len(set(ran)), "a tile summed twice"
+            assert set(ran) == live_tiles
+            for before, (outer, named, runs) in zip(walk, walk[1:]):
+                if not runs and before[0] == outer:
+                    assert named == before[1]
+        rows = [sum(1 for t in live_tiles if t[0] == i) for i in range(n_q)]
+        cols = [sum(1 for t in live_tiles if t[1] == j) for j in range(n_k)]
+        assert kv_steps == max(max(rows), 1) <= n_k
+        assert q_steps == max(max(cols), 1) <= n_q
+        return
     kv_j = _banded(
-        lambda i: _kv_live_range(i, bq, bk, n_k, causal, window),
-        causal, window)
+        lambda i: _kv_live_range(i, bq, bk, n_k, causal, None), causal)
     q_i = _banded(
-        lambda j: _q_live_range(j, bq, bk, n_q, causal, window),
-        causal, window)
+        lambda j: _q_live_range(j, bq, bk, n_q, causal, None), causal)
     for i in range(n_q):
         for j in range(n_k):
-            live = bool(_band_live(causal, window, i * bq, bq, j * bk, bk))
+            live = (i, j) in live_tiles
             jc, ic = int(kv_j(i, j)), int(q_i(j, i))
             assert 0 <= jc < n_k and 0 <= ic < n_q, (i, j, jc, ic)
             if live:
@@ -727,12 +826,10 @@ def test_band_clamp_stays_in_range_and_matches_band_live(
                 assert jc != j or ic != i or (n_q == 1 and n_k == 1)
             # Whatever the clamp names instead is live itself, unless the
             # whole row (column) is dead.
-            row_live = [jj for jj in range(n_k) if _band_live(
-                causal, window, i * bq, bq, jj * bk, bk)]
+            row_live = [jj for jj in range(n_k) if (i, jj) in live_tiles]
             if row_live:
                 assert jc in row_live, (i, j, jc, row_live)
-            col_live = [ii for ii in range(n_q) if _band_live(
-                causal, window, ii * bq, bq, j * bk, bk)]
+            col_live = [ii for ii in range(n_q) if (ii, j) in live_tiles]
             if col_live:
                 assert ic in col_live, (i, j, ic, col_live)
 
@@ -740,36 +837,54 @@ def test_band_clamp_stays_in_range_and_matches_band_live(
 @pytest.mark.parametrize("Sq,Sk,bq,bk,causal,window", _GEOMETRIES)
 def test_tile_census_counts_the_grid(Sq, Sk, bq, bk, causal, window):
     """live / visited / copied a head row, against a walk of the grid in
-    the kernels' own step order."""
-    from chainermn_tpu.ops.flash_attention import (
-        _band_live,
-        _banded,
-        _kv_live_range,
-        _q_live_range,
-        tile_census,
-    )
+    the kernels' own step order: the whole rectangle without a window,
+    the band's steps with one."""
+    from chainermn_tpu.ops.flash_attention import _band_live, tile_census
 
     n_q, n_k = Sq // bq, Sk // bk
-    kv_j = _banded(
-        lambda i: _kv_live_range(i, bq, bk, n_k, causal, window),
-        causal, window)
-    q_i = _banded(
-        lambda j: _q_live_range(j, bq, bk, n_q, causal, window),
-        causal, window)
     live = sum(
         bool(_band_live(causal, window, i * bq, bq, j * bk, bk))
         for i in range(n_q) for j in range(n_k))
-    kv_walk = [int(kv_j(i, j)) for i in range(n_q) for j in range(n_k)]
-    q_walk = [int(q_i(j, i)) for j in range(n_k) for i in range(n_q)]
+    (kv_walk, kv_steps), (q_walk, q_steps) = _grid_walk(
+        Sq, Sk, bq, bk, causal, window)
+    if window is None:
+        assert (kv_steps, q_steps) == (n_k, n_q)
 
     def fetches(walk):
-        return 1 + sum(a != b for a, b in zip(walk, walk[1:]))
+        named = [block for _, block, _ in walk]
+        return 1 + sum(a != b for a, b in zip(named, named[1:]))
 
     got = tile_census(Sq, Sk, bq, bk, causal, window)
-    base = {"block_q": bq, "block_k": bk, "live": live,
-            "visited": n_q * n_k}
-    assert got["fwd"] == got["dq"] == dict(base, copied=fetches(kv_walk))
-    assert got["dkv"] == dict(base, copied=fetches(q_walk))
+    base = {"block_q": bq, "block_k": bk, "live": live}
+    assert got["fwd"] == got["dq"] == dict(
+        base, visited=n_q * kv_steps, copied=fetches(kv_walk))
+    assert got["dkv"] == dict(
+        base, visited=n_k * q_steps, copied=fetches(q_walk))
+    if window is not None and window + max(bq, bk) <= min(Sq, Sk) // 4:
+        # a band far narrower than the sequence: no more steps than live
+        # tiles and one a resident block
+        assert got["fwd"]["visited"] <= live + n_q
+        assert got["dkv"]["visited"] <= live + n_k
+
+
+def test_tile_census_at_the_windowed_cells_shape():
+    """``mellum2-train-1chip``'s sliding row, S = 16,384 under a window
+    of 1024 at 1024 x 1024: 31 live tiles a head row in a grid of 16 x 2
+    steps (256 before the band grid, 225 of them entered and skipped),
+    one step more than live (q block 0 reaches one tile); the same row
+    without the window is the rectangle as it was."""
+    from chainermn_tpu.ops.flash_attention import tile_census
+
+    band = tile_census(16384, 16384, 1024, 1024, True, 1024)
+    for kernel in ("fwd", "dq", "dkv"):
+        t = band[kernel]
+        # (a K block serves the q block at its own index and the next:
+        # each of the 16 is fetched once)
+        assert (t["live"], t["visited"], t["copied"]) == (31, 32, 16)
+    full = tile_census(16384, 16384, 1024, 1024, True, None)["fwd"]
+    assert (full["live"], full["visited"], full["copied"]) == (136, 256, 135)
+    half = tile_census(16384, 16384, 512, 512, True, 1024)["fwd"]
+    assert (half["live"], half["visited"]) == (93, 96)
 
 
 def test_tile_census_at_the_benchmark_shape():
@@ -785,6 +900,154 @@ def test_tile_census_at_the_benchmark_shape():
     for kernel in ("fwd", "dq", "dkv"):
         t = new[kernel]
         assert (t["live"], t["visited"], t["copied"]) == (3, 4, 2)
+
+
+_BAND_CASES = {
+    # name: (Sq, Sk, block_q, block_k, window, Hk of H = 4, segmented)
+    "off-the-edge": (128, 128, 32, 32, 40, 4, False),
+    "smaller-than-a-block": (128, 128, 64, 32, 17, 4, False),
+    "one": (128, 128, 32, 64, 1, 4, False),
+    "wider-than-S": (128, 128, 32, 32, 300, 4, False),
+    "at-the-edge": (128, 128, 32, 32, 32, 4, False),
+    "wide-q-blocks": (256, 256, 128, 32, 96, 4, False),
+    "wide-k-blocks": (256, 256, 32, 128, 96, 4, False),
+    "more-keys": (128, 256, 32, 64, 40, 4, False),
+    "more-queries": (256, 128, 32, 32, 48, 4, False),
+    "gqa": (128, 128, 32, 32, 40, 2, False),
+    "mqa-rectangular": (128, 128, 16, 64, 24, 1, False),
+    "segments": (128, 128, 32, 32, 40, 4, True),
+    "gqa-segments-more-keys": (128, 256, 32, 64, 70, 2, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BAND_CASES))
+def test_flash_band_grid_matches_oracle(case):
+    """Output and all three gradients of a windowed call — its grid the
+    band's width in tiles, every kernel — against the dense oracle."""
+    Sq, Sk, bq, bk, window, Hk, segmented = _BAND_CASES[case]
+    B, H, D = 2, 4, 32
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(ks[0], (B, Sq, H, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, Sk, Hk, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, Sk, Hk, D), jnp.float32)
+    segs = {}
+    if segmented:
+        rng = np.random.RandomState(3)
+        ids = np.sort(rng.randint(0, 3, size=(B, max(Sq, Sk))), axis=1)
+        segs = {"q_segment_ids": jnp.asarray(ids[:, :Sq], jnp.int32),
+                "kv_segment_ids": jnp.asarray(ids[:, :Sk], jnp.int32)}
+
+    # A query past the last key's reach (more queries than keys) attends
+    # nothing: the kernels write zeros there, the oracle's softmax of an
+    # all-masked row is uniform garbage — compared on the other rows.
+    reached = (jnp.arange(Sq) - (window - 1) < Sk)[None, :, None, None]
+
+    def f_flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=bq, block_k=bk, **segs)
+
+    def f_ref(q, k, v):
+        return jnp.where(reached, _xla_attention(
+            q, k, v, 1.0 / D**0.5, True, window=window, **segs), 0.0)
+
+    np.testing.assert_allclose(
+        np.asarray(f_flash(q, k, v)), np.asarray(f_ref(q, k, v)),
+        rtol=2e-5, atol=2e-5)
+    g1 = jax.grad(lambda *a: (f_flash(*a) ** 2).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    g2 = jax.grad(lambda *a: (f_ref(*a) ** 2).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-4)
+
+
+@pytest.mark.parametrize("case", ["off-the-edge", "more-keys", "gqa",
+                                  "gqa-segments-more-keys"])
+def test_flash_band_grid_folds_the_lse_cotangent(case):
+    """``(o, lse)`` of the forward kernel under a window, and the
+    backward pair with a cotangent on BOTH (``dlse``, the path of
+    ``flash_attention_with_lse[_seg]``), against autodiff of the dense
+    masked softmax."""
+    from chainermn_tpu.ops.flash_attention import (
+        _flash_bh_bwd,
+        _flash_bh_fwd,
+    )
+
+    Sq, Sk, bq, bk, window, Hk, segmented = _BAND_CASES[case]
+    H, D = 4, 32
+    G = H // Hk
+    ks = jax.random.split(jax.random.PRNGKey(13), 5)
+    q = jax.random.normal(ks[0], (H, Sq, D), jnp.float32)
+    k = jax.random.normal(ks[1], (Hk, Sk, D), jnp.float32)
+    v = jax.random.normal(ks[2], (Hk, Sk, D), jnp.float32)
+    do = jax.random.normal(ks[3], (H, Sq, D), jnp.float32)
+    dlse = jax.random.normal(ks[4], (H, Sq), jnp.float32)
+    geometry = dict(scale=1.0 / D**0.5, causal=True, block_q=bq, block_k=bk,
+                    interpret=True, window=window)
+    mask = (jnp.arange(Sq)[:, None] >= jnp.arange(Sk)[None, :]) & (
+        jnp.arange(Sq)[:, None] - jnp.arange(Sk)[None, :] < window)
+    segs = {}
+    if segmented:
+        ids = np.sort(np.random.RandomState(5).randint(
+            0, 2, size=max(Sq, Sk))).astype(np.int32)
+        # every row keeps its own position's key: no fully masked row,
+        # whose lse the oracle and the kernel word differently
+        mask = mask & (ids[:Sq, None] == ids[None, :Sk])
+        segs = {"q_seg": jnp.broadcast_to(ids[None, :Sq, None], (H, Sq, 1)),
+                "kv_seg": jnp.broadcast_to(ids[None, :Sk, None],
+                                           (Hk, Sk, 1))}
+    rows = np.asarray(mask.any(axis=1))     # (more queries than keys: none)
+
+    def dense(q, k, v):
+        s = jnp.einsum("hqd,hkd->hqk", q, jnp.repeat(k, G, axis=0),
+                       precision="highest") * geometry["scale"]
+        s = jnp.where(mask[None], s, -1e30)
+        lse = jax.nn.logsumexp(s, axis=-1)
+        o = jnp.einsum("hqk,hkd->hqd", jnp.exp(s - lse[..., None]),
+                       jnp.repeat(v, G, axis=0), precision="highest")
+        return o, lse
+
+    o, lse = _flash_bh_fwd(q, k, v, **geometry, **segs)
+    (o_ref, lse_ref), vjp = jax.vjp(dense, q, k, v)
+    np.testing.assert_allclose(np.asarray(o)[:, rows],
+                               np.asarray(o_ref)[:, rows],
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse)[:, rows, 0],
+                               np.asarray(lse_ref)[:, rows],
+                               rtol=2e-5, atol=2e-5)
+    got = _flash_bh_bwd(q, k, v, o, lse, do, dlse=dlse, **geometry, **segs)
+    for a, b in zip(got, vjp((do, dlse))):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-4)
+
+
+@pytest.fixture(scope="module")
+def no_window_golden():
+    import json
+
+    path = os.path.join(os.path.dirname(__file__), "golden",
+                        "flash_no_window.json")
+    with open(path) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+@pytest.mark.parametrize("case", sorted(_flash_no_window.CASES))
+def test_flash_without_a_window_is_the_parents_program(
+        no_window_golden, case, which):
+    """A call WITHOUT a window keeps the grid, the index maps and the
+    kernels it had before the band grid (PR 40): the grids of its
+    ``pallas_call``s are the whole rectangle, and its JAXPR and its text
+    lowered for a TPU hash to what the parent commit's did
+    (``tests/_flash_no_window.py`` says how the golden file is made)."""
+    BH, BHk, Sq, Sk, _, bq, bk, *_ = _flash_no_window.CASES[case]
+    got = _flash_no_window.record(case)[which]
+    n_q, n_k, G = Sq // bq, Sk // bk, BH // BHk
+    assert got["grids"] == {
+        "fwd": [[BH, n_q, n_k]],
+        "bwd": [[BH, n_q, n_k], [BHk, n_k, G * n_q]]}[which]
+    assert got == no_window_golden[case][which]
 
 
 def _mode_kwargs(mode, B, S, H):

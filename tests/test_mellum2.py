@@ -691,11 +691,11 @@ def test_the_window_scope_is_on_the_sliding_rows_ops(compiled_text):
     assert {"flash-fwd", "flash-bwd-dq", "flash-bwd-dkv", "attn-rope",
             "mixer-proj"} <= under
     # and the regions' census rides in the path with the row's window: a
-    # band of 8 at blocks of 8 over 32 tokens runs 7 of 16 tiles, the
-    # triangle 10
+    # band of 8 at blocks of 8 over 32 tokens runs 7 tiles in a grid of
+    # 4 x 2 steps (the band, not the 16 of the rectangle), the triangle 10
     (census,) = table.tiles_within["attn-window"]["flash-fwd"]
     assert census == fa.tile_census(32, 32, 8, 8, True, 8)["fwd"]
-    assert (census["live"], census["visited"]) == (7, 16)
+    assert (census["live"], census["visited"]) == (7, 8)
     assert table.tiles_within["attn-mixer"]["flash-fwd"][0]["live"] == 10
     assert len(table.tiles["flash-fwd"]) == 2
 

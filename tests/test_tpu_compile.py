@@ -688,11 +688,13 @@ def test_mellum_layers_compile_at_the_cells_shape(one_chip, monkeypatch,
     tokens, GQA 32/4 at D = 128, 8 of 64 gated experts of 896 held: a
     buffer of 65,536 rows), forward and backward under remat with the
     model's policy as in the step: the three flash calls (the forward
-    ONCE) at the 1024-edge tiles ``auto_block_size`` picks under the
-    row's window of 1024 as without one — the window reaches the kernels
-    from the row, the adapter was given none — and nine grouped calls of
-    the experts; the windowed row's census is the band's 31 tiles of 256,
-    the full row's the triangle's 136."""
+    ONCE) at the tiles ``auto_block_size`` picks — 1024-edge without a
+    window and in the forward under the row's window of 1024, 512-edge
+    in the backward under it; the window reaches the kernels from the
+    row, the adapter was given none — and nine grouped calls of the
+    experts; the windowed row's census is the band's 31 tiles (93 at
+    512) in a grid of 32 (96) steps a head row (its grid is the band,
+    PR 40), the full row's the triangle's 136 of 256."""
     from chainermn_tpu.models.block_table import (
         ExpertsSpec,
         LayerSpec,
@@ -707,8 +709,10 @@ def test_mellum_layers_compile_at_the_cells_shape(one_chip, monkeypatch,
         monkeypatch.setattr(module, "default_interpret", lambda: False)
     for which in ("fwd", "bwd"):
         assert fa.auto_block_size(16384, 128, jnp.bfloat16, which) == 1024
-        assert fa.auto_block_size(16384, 128, jnp.bfloat16, which,
-                                  window=1024) == 1024
+    assert fa.auto_block_size(16384, 128, jnp.bfloat16, "fwd",
+                              window=1024) == 1024
+    assert fa.auto_block_size(16384, 128, jnp.bfloat16, "bwd",
+                              window=1024) == 512
     row = LayerSpec(
         mixer="attention", norm="rmsnorm", ffn="experts", n_heads=32,
         n_kv_heads=4, d_head=128, rotary_dim=128, rope_theta=5e5,
@@ -747,8 +751,13 @@ def test_mellum_layers_compile_at_the_cells_shape(one_chip, monkeypatch,
         {"attn-window", "attn-mixer"} - {scope}) & set(tiles)
     for region in ("flash-fwd", "flash-bwd-dq", "flash-bwd-dkv"):
         (census,) = tiles[scope][region]
-        assert (census["block_q"], census["block_k"],
-                census["visited"]) == (1024, 1024, 256)
-        assert census["live"] == (31 if kind == "sliding" else 136)
+        # a sliding row's grid is its band (PR 40): one step a query
+        # block more than live, the backward's tiles at half the window;
+        # the full row's is the rectangle
+        edge = 512 if kind == "sliding" and region != "flash-fwd" else 1024
+        assert (census["block_q"], census["block_k"]) == (edge, edge)
+        assert (census["live"], census["visited"]) == (
+            (136, 256) if kind == "full"
+            else (31, 32) if edge == 1024 else (93, 96))
     # read: 1.67 GB, either row (a window saves time, not memory)
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
