@@ -15,6 +15,7 @@ PartitionSpec rules, and the attention layer can run sequence-parallel via
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -172,9 +173,8 @@ class MultiHeadAttention(nn.Module):
                 if self.rotary_dim:
                     pos = jnp.arange(q.shape[1])
                     q, k = (rotate_partial(
-                        x.astype(jnp.float32), pos, self.rotary_dim,
-                        self.rope_theta, self.yarn).astype(self.dtype)
-                        for x in (q, k))
+                        x, pos, self.rotary_dim, self.rope_theta,
+                        self.yarn).astype(self.dtype) for x in (q, k))
 
         def project_out(out):
             if gate is not None:
@@ -662,25 +662,100 @@ def rebalance_routers(params, chosen, rate: float):
     return out
 
 
+def rotary_partner(rotary_dim: int, d_head: int) -> np.ndarray:
+    """The (d_head, d_head) signed permutation ``P`` that fetches every
+    lane's partner whole: ``(x @ P)[i] = -x[i + half]`` and ``(x @
+    P)[i + half] = x[i]`` for ``i < half = rotary_dim / 2``, 0 past
+    ``rotary_dim``.  A 0 / 1 / -1 matrix: a bfloat16 operand's product
+    with it, summed in float32, is that operand's own values."""
+    half = rotary_dim // 2
+    i = np.arange(half)
+    partner = np.zeros((d_head, d_head), np.float32)
+    partner[i + half, i] = -1.0
+    partner[i, i + half] = 1.0
+    return partner
+
+
+def _rotary_tables(positions, d_head: int, rotary_dim: int, theta: float,
+                   yarn: Optional[YarnSpec]):
+    """``cos`` and ``sin`` of a row's angles as (S, 1, d_head) float32,
+    the ``rotary_dim / 2`` frequencies laid twice side by side and a
+    frequency of 0 past ``rotary_dim`` (``cos`` 1, ``sin`` 0: those lanes
+    pass through); under ``yarn`` the turned lanes times its scale."""
+    freq, scale = rotary_frequencies(rotary_dim, theta, yarn)
+    rest = d_head - rotary_dim
+    angle = positions.astype(jnp.float32)[:, None] * jnp.asarray(
+        np.concatenate([freq, freq, np.zeros(rest)]), jnp.float32)
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    if yarn is not None:
+        cos = cos * jnp.asarray(np.concatenate(
+            [np.full(rotary_dim, scale), np.ones(rest)]), jnp.float32)
+        sin = sin * scale
+    return cos, sin
+
+
+def fetch_partner(x, rotary_dim: int):
+    """``x @ P`` in float32: every lane's partner, to the bit.  The
+    product's precision follows from the operand's dtype and nothing
+    else: one bfloat16 pass summed in float32 is exact for a bfloat16
+    ``x``, any other takes ``Precision.HIGHEST``."""
+    one_pass = x.dtype == jnp.bfloat16
+    return jnp.einsum(
+        "...d,de->...e", x,
+        jnp.asarray(rotary_partner(rotary_dim, x.shape[-1]), x.dtype),
+        precision=None if one_pass else jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _turn(x, cos, sin, rotary_dim: int):
+    """``x cos + (x P) sin`` in float32, one pass over whole heads."""
+    return x * cos + fetch_partner(x, rotary_dim) * sin
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def rotate_partial(x, positions, rotary_dim: int, theta: float,
                    yarn: Optional[YarnSpec] = None):
     """Rotary positions on the first ``rotary_dim`` of the last axis of
-    ``x`` (b, S, heads, d_head), the rest passed through: dimension ``i``
-    of the first half of the rotated part pairs with ``i + rotary_dim /
-    2``, at the angle ``positions x theta^(-2 i / rotary_dim)`` — or,
-    under ``yarn``, at the row's blended frequencies with ``cos`` and
-    ``sin`` times its scale (``block_table.rotary_frequencies``)."""
-    half = rotary_dim // 2
-    freq, scale = rotary_frequencies(rotary_dim, theta, yarn)
-    angle = positions.astype(jnp.float32)[:, None] * jnp.asarray(
-        freq, jnp.float32)                                   # (S, half)
-    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
-    if yarn is not None:
-        cos, sin = cos * scale, sin * scale
-    a, b, rest = (x[..., :half], x[..., half:rotary_dim],
-                  x[..., rotary_dim:])
-    return jnp.concatenate(
-        [a * cos - b * sin, b * cos + a * sin, rest], axis=-1)
+    ``x`` (b, S, heads, d_head), the rest passed through, in float32:
+    dimension ``i`` of the first half of the rotated part pairs with ``i
+    + rotary_dim / 2``, at the angle ``positions x theta^(-2 i /
+    rotary_dim)`` — or, under ``yarn``, at the row's blended frequencies
+    with ``cos`` and ``sin`` times its scale
+    (``block_table.rotary_frequencies``).
+
+    ``x`` comes in the type the caller holds it.  A head is turned whole,
+    the lanes never cut into halves: ``x cos + (x P) sin`` against
+    full-width tables, the partner lanes fetched by one product with the
+    signed permutation ``P`` (:func:`rotary_partner`).  ``positions`` are
+    token indices (integers: they take no gradient); the cotangent of
+    ``x`` is the cotangent turned back, rounded to ``x``'s type once."""
+    return _rotate_fwd(x, positions, rotary_dim, theta, yarn)[0]
+
+
+def _rotate_fwd(x, positions, rotary_dim, theta, yarn):
+    if telemetry_active():
+        from chainermn_tpu.ops.ssd import publish_geometry
+
+        publish_geometry(
+            "rope_geometry", "rope", {
+                "seq": x.shape[1], "heads": x.shape[2],
+                "d_head": x.shape[-1], "rotary_dim": rotary_dim},
+            form="lane_dense_product", operand=jnp.dtype(x.dtype).name,
+            precision=("one_bf16_pass" if x.dtype == jnp.bfloat16
+                       else "highest"))
+    cos, sin = _rotary_tables(positions, x.shape[-1], rotary_dim, theta,
+                              yarn)
+    # (an empty array carries the operand's type to the backward rule)
+    return _turn(x, cos, sin, rotary_dim), (cos, sin,
+                                            jnp.zeros((0,), x.dtype))
+
+
+def _rotate_bwd(rotary_dim, theta, yarn, residuals, g):
+    cos, sin, like = residuals
+    return _turn(g, cos, -sin, rotary_dim).astype(like.dtype), None
+
+
+rotate_partial.defvjp(_rotate_fwd, _rotate_bwd)
 
 
 def shift_tokens(x, by: int):

@@ -622,11 +622,21 @@ def older_digests():
 def test_an_older_familys_outputs_are_unchanged_to_the_bit(
         family, older_digests):
     """The traced program of the family's logits and gradient (every
-    equation, every constant) is the one the commit before this family
-    traced (``tests/_older_families.py`` says why the program and not its
-    output's bits)."""
+    equation, every constant) is the one the golden file's commit traced
+    (``tests/_older_families.py`` says why the program and not its
+    output's bits).  The ``zaya`` and ``qwen3_next`` digests are PR 41's
+    (they rotate: ``rotate_partial`` turns whole heads since), the three
+    others PR 39's parent's."""
     assert _older_families.digest(
-        *_older_families.tables()[family]) == older_digests[family]
+        *_older_families.tables()[family]) == older_digests[family], (
+        f"the {family} family traces another program than "
+        f"tests/golden/older_families.json holds.  Not meant: the change "
+        f"reached a family it should have left alone.  Meant (a change "
+        f"to a path this family runs): remake THIS family's digest and "
+        f"no other -- `PYTHONPATH=. JAX_PLATFORMS=cpu python "
+        f"tests/_older_families.py out.json`, copy the one line -- and "
+        f"show in CHANGES.md that the families left alone kept theirs "
+        f"byte for byte")
 
 
 def test_plain_rotation_is_the_old_formula_to_the_bit():

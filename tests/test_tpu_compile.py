@@ -761,3 +761,30 @@ def test_mellum_layers_compile_at_the_cells_shape(one_chip, monkeypatch,
             else (31, 32) if edge == 1024 else (93, 96))
     # read: 1.67 GB, either row (a window saves time, not memory)
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+def test_rotary_positions_turn_whole_heads(one_chip):
+    """``rotate_partial`` forward + backward at mellum's q (1 x 16,384 x
+    32 heads of 128, bfloat16, the whole head turned) as a caller runs
+    it: one pass over whole heads each way.  Read: 1.39 GB accessed (the
+    half-split form it replaced, with the float32 copy a caller made:
+    3.79 GB — every 64-lane half is padded to 128 lanes on the chip), and
+    no array in the optimised text whose minor axis is ``rotary_dim /
+    2``."""
+    from chainermn_tpu.models.transformer import rotate_partial
+
+    shape, rotary_dim = (1, 16384, 32, 128), 128
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def both_passes(x, g):
+        y, back = jax.vjp(
+            lambda x: rotate_partial(
+                x, jnp.arange(shape[1]), rotary_dim, 5e5).astype(
+                    jnp.bfloat16), x)
+        return y, back(g)[0]
+
+    compiled = jax.jit(both_passes).lower(x, x).compile()
+    assert compiled.cost_analysis()["bytes accessed"] <= 1.8e9
+    text = compiled.as_text()
+    assert not re.findall(r"\[(?:\d+,)*%d\]" % (rotary_dim // 2), text)
+    assert len(re.findall(r" convolution\(", text)) == 2   # x P, and back
