@@ -83,8 +83,9 @@ def create_communicator(
     ``overlap`` controls the backward-overlapped bucket emission
     (:mod:`chainermn_tpu.communicators.overlap`): ``None`` resolves the
     ``CHAINERMN_TPU_OVERLAP`` env gate (default ON), ``False`` pins the
-    eager pack-all-then-reduce-all schedule (the ``--no-overlap`` A/B in
-    bench.py).  ``overlap_granularity`` sets buckets emitted per
+    eager pack-all-then-reduce-all schedule (bit-exact against the staged
+    one; no A/B of the two on the chip is on the ledger).
+    ``overlap_granularity`` sets buckets emitted per
     schedule stage (``None`` = env → 1).
 
     ``comm_dtype`` puts gradient buckets on a low-precision wire

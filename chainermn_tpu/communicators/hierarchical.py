@@ -34,10 +34,12 @@ class HierarchicalCommunicator(CommunicatorBase):
     sharded.  ``scatter_inter=True`` decomposes the intra leg into
     ``psum_scatter → psum(inter) → all_gather``: the same math (a psum is
     definitionally reduce-scatter + all-gather), but the inter hop now
-    carries only ``1/intra_size`` of the bytes per chip — closing the
-    inter-leg gap BENCH_r05 measured against two_dimensional (4 MiB vs
-    512 KiB at intra=8) while keeping the per-leaf phase structure that
-    distinguishes this variant from the flat-packed 2-D communicator."""
+    carries only ``1/intra_size`` of the bytes per chip — the
+    two_dimensional backend's inter-leg bytes (the static census of
+    ``benchmarks/allreduce_bench.py --static-only``: 4 MiB against
+    512 KiB at intra=8; not measured on the chip) while keeping the
+    per-leaf phase structure that distinguishes this variant from the
+    flat-packed 2-D communicator."""
 
     name = "hierarchical"
 

@@ -36,10 +36,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-#: Default bucket cap.  4 MiB matches the fused single-buffer regime of
-#: BENCH_r05's allreduce table (one collective saturates the link well
-#: before this) while keeping the first bucket's launch early enough to
-#: overlap with the tail of the backward pass.
+#: Default bucket cap: large enough that a bucket is one fat collective,
+#: small enough that the first bucket launches under the tail of the
+#: backward pass.  The benchmark's dp4 cell pins and measures it
+#: (``tests/test_packing.py::test_default_communicator_is_the_measured_one``);
+#: no other size has been measured on the chip.
 DEFAULT_BUCKET_BYTES = 4 * 1024 * 1024
 
 #: Environment escape hatch: overrides an unset ``bucket_bytes`` on every

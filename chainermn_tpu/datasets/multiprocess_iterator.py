@@ -111,8 +111,7 @@ class MultiprocessBatchLoader:
         (``n_slots × batch_nbytes``).
     ``repeat``
         ``True`` → iterate epochs forever, reshuffling each epoch with
-        ``seed + epoch`` (the resident-loop shape ``bench.py --pipeline``
-        and real training use).
+        ``seed + epoch`` (the resident-loop shape real training uses).
     ``copy``
         ``True`` (default) → yield fresh arrays, valid forever.
         ``False`` → yield zero-copy views of the shared slot; a yielded

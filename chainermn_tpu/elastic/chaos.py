@@ -29,7 +29,7 @@ Kinds:
 * ``ckpt_slow`` — sleep ``secs`` inside every checkpoint save (slow
   snapshot I/O widening the crash window).
 
-The schedule drives both the test suite and ``bench.py --chaos``; the
+The schedule drives the test suite and ``tools.elastic --chaos``; the
 supervisor passes it to ranks via ``CHAINERMN_TPU_CHAOS``.
 
 Serving-tier coordinates: the same grammar also addresses *serving

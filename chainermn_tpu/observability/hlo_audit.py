@@ -1,7 +1,6 @@
 """Collective audit — jaxpr- and HLO-level census of a step's wire cost.
 
-This generalizes what ``benchmarks/allreduce_bench.py`` grew ad hoc: for
-any traceable function (a jitted train step, a communicator's
+For any traceable function (a jitted train step, a communicator's
 ``allreduce_grad``), count the collective primitives it lowers to and
 charge each collective's per-device operand bytes to the mesh axes it
 runs over.  The result is environment-independent evidence of an
@@ -10,11 +9,10 @@ CPU mesh, long before a v4-32 is available — and the input the
 two_dimensional backend's bandwidth claim is verified against (its
 inter-axis bytes must be the flat backend's divided by ``intra_size``).
 
-``benchmarks/allreduce_bench.py`` and ``bench.py``'s
-``allreduce_static_bytes_per_leg`` table now consume THIS module (one
-source of truth for the bytes-per-leg metric); examples call
-:func:`audit_fn` on their real train step and log the result as an
-``hlo_audit`` row in the step-event log.
+``benchmarks/allreduce_bench.py``'s ``allreduce_static_bytes_per_leg``
+table consumes THIS module (one source of truth for the bytes-per-leg
+metric); examples call :func:`audit_fn` on their real train step and
+log the result as an ``hlo_audit`` row in the step-event log.
 
 Two census sources, one :class:`CollectiveAudit` shape:
 
@@ -439,8 +437,7 @@ def audit_compiled(fn, *args, **kwargs) -> CollectiveAudit:
     """Compile ``fn(*args, **kwargs)`` (jitted or plain) and audit the
     OPTIMIZED HLO — the only level where async start/done pairs and the
     latency-hiding schedule are visible.  Args may be real arrays or
-    ``jax.ShapeDtypeStruct``s; nothing executes.  This is what
-    ``bench.py`` reports its ``overlap_fraction`` from."""
+    ``jax.ShapeDtypeStruct``s; nothing executes."""
     import jax
 
     jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
@@ -514,7 +511,8 @@ def _allreduce_jaxpr(comm, nbytes: int, dtype):
 
 def audit_allreduce(comm, nbytes: int, dtype=np.float32) -> CollectiveAudit:
     """Audit one communicator's gradient-allreduce path at a given
-    per-device payload — the library home of bench.py's
+    per-device payload — the library home of
+    ``benchmarks/allreduce_bench.py``'s
     ``allreduce_static_bytes_per_leg`` numbers."""
     return audit_jaxpr(_allreduce_jaxpr(comm, nbytes, dtype))
 

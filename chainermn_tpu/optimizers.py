@@ -69,9 +69,10 @@ def _forward_aot(step, jitted_for):
     not entries of its ``__dict__``, so ``functools.wraps`` does not carry
     them.  ``jitted_for(*args)`` returns the jitted callable ``step``
     dispatches those arguments to (ZeRO steps build theirs per parameter
-    tree).  ``bench.py`` lowers the step for XLA's cost model and the
-    collective linter reads donation off ``trace``: a step without the
-    surface is an error there, not a fallback."""
+    tree).  ``chip_smoke.py`` and the benchmark's scope reader lower the
+    step for its compiled text and the collective linter reads donation
+    off ``trace``: a step without the surface is an error there, not a
+    fallback."""
 
     def forward(name):
         def method(*args, **kwargs):

@@ -711,7 +711,7 @@ def serve_pipeline_order(n_micro: int, n_stages: int):
     from the host dispatcher: microbatch ``m`` enters stage ``s`` at
     tick ``m + s``, so total latency is ``n_micro + n_stages - 1``
     stage-times against ``n_micro * n_stages`` sequential (the GPipe
-    bubble).  Used by the bench's tp×pp model and pinned by unit test;
+    bubble).  Pinned by unit test and called by nothing else;
     the leader's own dispatch loop only needs the microbatch order
     (:func:`decode_microbatches`) because follower stages replay
     asynchronously."""

@@ -17,7 +17,7 @@ Two modes share this module:
   returns the chips.  The last line is ``FABRIC_REPORT {json}`` with
   the training report (digest included), serve summary, stream oracle
   parity, ledger conservation, and the arbiter's transition counts —
-  everything the bench and the multi-process soak assert on.
+  everything the multi-process soak asserts on.
 * **--worker**: the supervised training rank.  Same shape as the
   elastic soak worker (init_from_env, naive communicator, multi-node
   checkpointer, beat / check_preemption / exit_preempted, reshard on

@@ -1,8 +1,8 @@
 """Timing helpers and the compile-cache switch.
 
 SURVEY §5.1: the reference relied on Chainer's TimerHook + external nvprof.
-Here: ``slope_time`` / ``median_slope`` / ``sync`` are the host-clock
-timing of ``bench.py`` and ``benchmarks/``,
+Here: ``slope_time`` / ``sync`` are the host-clock timing of
+``benchmarks/allreduce_bench.py``,
 ``allreduce_bus_bandwidth_gbs`` the ``allreduce bus-bw GB/s``
 arithmetic BASELINE.json tracks, and
 ``setup_compilation_cache`` what every entry point calls first.  Profiler
@@ -25,9 +25,9 @@ def setup_compilation_cache() -> str:
     ``<checkout>/.jax_cache`` — a fixed path, because the path is part of
     the cache key and a directory that moves never hits.  The step
     programs of the flagships take minutes to compile; every entry point
-    (``chip_smoke.py``, ``bench.py``, ``benchmarks/``, ``tools.serve``,
-    the examples) calls this before its first jit so a second run starts
-    in seconds."""
+    (``chip_smoke.py``, ``benchmarks/``, ``tools.serve``, the examples)
+    calls this before its first jit so a second run starts in
+    seconds."""
     import os
 
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
@@ -49,21 +49,11 @@ def slope_time(run, n1: int, n2: Optional[int] = None) -> float:
     dispatch of the first program and the final readback are a constant
     per run, so a single run over-reports per-iteration time by
     constant/n — the slope between two run lengths cancels it exactly.
-    Used by bench.py and benchmarks/*.
     """
     if n2 is None:
         n2 = 5 * n1
     t1, t2 = run(n1), run(n2)
     return (t2 - t1) / (n2 - n1)
-
-
-def median_slope(run, n1: int = 5, repeats: int = 3):
-    """Median of ``repeats`` independent :func:`slope_time` measurements,
-    with the sorted samples, so the run-to-run spread is reported next
-    to the number.  Returns ``(median_seconds_per_iter,
-    sorted_samples)``."""
-    samples = sorted(slope_time(run, n1) for _ in range(repeats))
-    return samples[len(samples) // 2], samples
 
 
 def sync(tree):

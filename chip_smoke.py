@@ -7,8 +7,8 @@ Three phases through the entry points a user calls
 (``create_communicator("xla_ici")``, ``create_multi_node_optimizer``,
 ``make_train_step[_with_state]``, ``serving.InferenceEngine`` +
 ``ContinuousBatchingScheduler`` + ``ServeFrontend``), in ONE process on
-every chip that process sees, at the full width of ``bench.py``'s
-flagships with random weights from a seed:
+every chip that process sees, at full width (``LM_FULL``,
+``RESNET_FULL``, ``SERVE_FULL`` below) with random weights from a seed:
 
 * ``lm_train`` — the 470M dense LM (flash attention + fused CE, AdamW).
 * ``resnet50_train`` — ResNet-50 at 224x224, SGD+momentum, cross-replica
@@ -31,8 +31,8 @@ import statistics
 import sys
 import time
 
-# Full width: bench.py's defaults for the two training flagships, and the
-# LM geometry again for the server.
+# Full width: the two training flagships, and the LM geometry again for
+# the server.
 LM_FULL = dict(
     vocab=32768, d_model=2048, n_heads=16, d_ff=8192, n_layers=8,
     seq=4096, per_chip_batch=4, ce_chunk=1024,
@@ -162,7 +162,7 @@ def _train_phase(comm, step, carry, batch, split):
 
 def lm_train(width):
     """Dense decoder LM train step: flash attention + chunked fused CE,
-    AdamW, donated — as ``bench.py``'s ``bench_lm`` builds it."""
+    AdamW, donated."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -218,7 +218,7 @@ def lm_train(width):
 
 def resnet50_train(width):
     """ResNet train step with cross-replica BatchNorm, SGD+momentum,
-    donated — as ``bench.py``'s ``bench_resnet`` builds it."""
+    donated."""
     import jax
     import jax.numpy as jnp
     import numpy as np

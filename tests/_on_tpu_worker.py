@@ -13,7 +13,7 @@ Subcommands:
 
 The reference gated GPU tests with ``@attr.gpu`` markers (SURVEY §4); this
 is that tier for TPU — the compiled kernel path is correctness-asserted on
-the real chip, not just timed by bench.py.
+the real chip, not just timed.
 """
 
 import os

@@ -22,8 +22,8 @@ The resilience contract on top of the cluster tier
    requeued the victim's streams from their committed prefixes.
 
 All CPU, in-process.  The cross-process chaos-at-peak-load soak lives
-in tests/test_multiprocess.py; the end-to-end curve bench smoke rides
-the slow tier here.
+in tests/test_multiprocess.py; the ``tools.serve`` CLI smoke rides the
+slow tier here.
 """
 
 import json
@@ -522,7 +522,7 @@ def test_traffic_replay_over_fleet_is_bit_exact(lm, lm_params):
 
 
 # ---------------------------------------------------------------------------
-# CLI + bench smokes (subprocess — slow tier)
+# CLI smoke (subprocess — slow tier)
 # ---------------------------------------------------------------------------
 
 
@@ -553,42 +553,6 @@ def test_serve_cli_traffic_autoscale_chaos_smoke():
                for ev in traffic["autoscaler_events"])
     assert set(traffic["burn_rates"]) == {"queue", "decode"}
     assert all(v < 1.0 for v in traffic["burn_rates"].values())
-
-
-@pytest.mark.slow
-def test_bench_serve_traffic_curves_smoke():
-    from conftest import subprocess_env
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"),
-         "--serve-traffic", ("rate=150,requests=8,abusive_frac=0.1,"
-                             "prompt_buckets=4-8:0.6|10-20:0.4,"
-                             "output_buckets=4-8:0.7|10-16:0.3"),
-         "--serve-load-mults", "0.5,2",
-         "--lm-vocab", "64", "--lm-d-model", "16", "--lm-heads", "2",
-         "--lm-d-ff", "32", "--lm-layers", "1",
-         "--serve-batch-sizes", "4", "--serve-block-size", "4",
-         "--serve-blocks", "64", "--serve-max-len", "64",
-         "--serve-replicas", "2"],
-        capture_output=True, text=True, timeout=540,
-        env=subprocess_env(n_devices=1), cwd=repo,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.splitlines()[-1])
-    st = out["serve_traffic"]
-    # both curves, one point per load multiplier
-    assert len(st["curves"]["goodput_vs_offered_load"]) == 2
-    assert len(st["curves"]["p99_vs_load"]) == 2
-    assert st["curves"]["goodput_vs_offered_load"][0][0] == 75.0
-    # chaos point: kill at peak → backfill, bit-exact, SLO green
-    assert st["chaos"]["backfilled"] is True
-    assert st["chaos"]["parity"] == "ok"
-    assert st["chaos"]["slo_green"] is True
-    # scale-down point: drain-migrate-retire, zero dropped streams
-    assert st["scale_down"]["drained"] is True
-    assert st["scale_down"]["retired"] is True
-    assert st["scale_down"]["dropped_streams"] == 0
 
 
 def test_autoscaler_anomaly_forces_scale_up(lm, lm_params):

@@ -236,7 +236,7 @@ def test_audit_allreduce_flat_census(devices8):
 
 
 def test_audit_two_dimensional_inter_savings(devices8):
-    """The bench's headline static claim, now via the library: the 2D
+    """``allreduce_bench.py``'s headline static claim, via the library: the 2D
     backend's inter-axis operand bytes are flat's divided by intra."""
     nbytes = 1 << 20
     flat = audit_allreduce(_comm("flat"), nbytes)

@@ -513,9 +513,10 @@ def test_imperative_api_full_feature_matrix(mesh):
 
 @pytest.mark.parametrize("zero_stage", [0, 1, 3])
 def test_steps_expose_jit_aot_surface(devices8, zero_stage):
-    """bench.py lowers the step for XLA's cost model and the collective
-    linter reads donation off ``trace``: every built step carries jit's
-    ``lower``/``trace``/``eval_shape`` through its wrappers."""
+    """``chip_smoke.py`` lowers the step for its compiled text and the
+    collective linter reads donation off ``trace``: every built step
+    carries jit's ``lower``/``trace``/``eval_shape`` through its
+    wrappers."""
     comm = create_communicator("xla_ici")
     opt = create_multi_node_optimizer(
         optax.sgd(0.1), comm, zero_stage=zero_stage)

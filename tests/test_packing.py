@@ -443,7 +443,7 @@ def test_unbucketed_census_scales_with_leaves(mesh24):
 
 def test_single_leaf_tree_skips_bucketing(mesh24):
     """One leaf → the direct path, regardless of bucket_bytes: the
-    single-buffer census (BENCH_r05 table) must not change."""
+    single-buffer census must not change."""
     from chainermn_tpu.observability import audit_allreduce_tree
 
     comm = create_communicator("xla_ici", mesh=mesh24)

@@ -1361,68 +1361,8 @@ def test_tp_decode_collective_census_matches_golden():
 
 
 # ---------------------------------------------------------------------------
-# Subprocess smokes: bench --serve, the example
+# Subprocess smoke: the example
 # ---------------------------------------------------------------------------
-def test_bench_serve_emits_decode_throughput_json():
-    from conftest import subprocess_env
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--serve",
-         "--lm-vocab", "32", "--lm-d-model", "16", "--lm-heads", "2",
-         "--lm-d-ff", "32", "--lm-layers", "1",
-         "--serve-batch-sizes", "1,2", "--serve-requests", "3",
-         "--serve-prompt-len", "6", "--serve-new-tokens", "4",
-         "--serve-block-size", "4", "--serve-blocks", "32",
-         "--serve-max-len", "32"],
-        capture_output=True, text=True, timeout=420,
-        env=subprocess_env(n_devices=1), cwd=repo,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.splitlines()[-1])
-    # same report shape as the train benches: metric/value/unit headline
-    assert out["unit"] == "tokens/sec" and out["value"] > 0
-    assert "decode" in out["metric"]
-    assert [r["batch_size"] for r in out["sweep"]] == [1, 2]
-    for row in out["sweep"]:
-        assert row["finished"] == row["requests"] == 3
-        assert row["tokens_per_sec"] > 0
-        assert row["p50_token_latency_ms"] is not None
-        assert row["p99_token_latency_ms"] >= row["p50_token_latency_ms"]
-
-
-def test_bench_serve_tp_emits_group_size_curve():
-    """--serve-tp rides along additively: the usual --serve report plus
-    a "tp" section whose curve covers every valid group size with a
-    speedup relative to the K=1 baseline, and sizes the local device
-    count can't host reported as skipped, not dropped."""
-    from conftest import subprocess_env
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--serve",
-         "--serve-tp", "--serve-tp-sizes", "1,2,4",
-         "--lm-vocab", "32", "--lm-d-model", "16", "--lm-heads", "2",
-         "--lm-d-ff", "32", "--lm-layers", "1",
-         "--serve-batch-sizes", "2", "--serve-requests", "3",
-         "--serve-prompt-len", "6", "--serve-new-tokens", "4",
-         "--serve-block-size", "4", "--serve-blocks", "32",
-         "--serve-max-len", "32"],
-        capture_output=True, text=True, timeout=420,
-        env=subprocess_env(n_devices=2), cwd=repo,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.splitlines()[-1])
-    tp = out["tp"]
-    assert tp["devices"] == 2
-    assert [r["group_size"] for r in tp["curve"]] == [1, 2]
-    for r in tp["curve"]:
-        assert r["finished"] == 3 and r["tokens_per_sec"] > 0
-        assert r["speedup"] > 0
-    # K=4 exceeds both devices and head count: reported, not dropped
-    assert [s["group_size"] for s in tp["skipped"]] == [4]
-
-
 def test_serve_lm_example_smoke():
     from conftest import subprocess_env
 

@@ -922,36 +922,6 @@ def test_serve_cli_local_verify_smoke():
     assert out["tokens"] == 24
 
 
-def test_bench_serve_cluster_disagg_proof_smoke():
-    from conftest import subprocess_env
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--serve",
-         "--serve-replicas", "2",
-         "--lm-vocab", "32", "--lm-d-model", "16", "--lm-heads", "2",
-         "--lm-d-ff", "32", "--lm-layers", "1",
-         "--serve-batch-sizes", "2", "--serve-requests", "3",
-         "--serve-prompt-len", "6", "--serve-new-tokens", "4",
-         "--serve-block-size", "4", "--serve-blocks", "64",
-         "--serve-max-len", "64", "--serve-queue", "8"],
-        capture_output=True, text=True, timeout=420,
-        env=subprocess_env(n_devices=1), cwd=repo,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.splitlines()[-1])
-    # the single-engine report shape is intact...
-    assert out["unit"] == "tokens/sec" and out["value"] > 0
-    # ...and the cluster section carries the disaggregation evidence
-    cl = out["cluster"]
-    assert cl["replicas"] == 2
-    assert cl["routed"]["finished"] == cl["routed"]["requests"]
-    proof = cl["disagg_proof"]
-    assert proof["single_replica_mixed"]["finished"] == 4
-    assert proof["disaggregated"]["finished"] == 4
-    assert proof["long_prompt_len"] > 6
-
-
 def test_serving_cluster_soak_threaded_failover(lm, lm_params):
     """Soak (auto-marked slow): threaded replicas, concurrent
     submission, one replica killed mid-stream — every stream bit-exact

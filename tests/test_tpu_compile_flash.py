@@ -1,0 +1,123 @@
+"""The flash kernels and the rotary positions beside them, compiled for
+a described TPU v5e (``tests/_tpu_compile.py``), without the chip.
+
+The static default geometry has to compile inside the default scoped
+VMEM at every head dim, dtype and mask the rule sizes it for, and a
+pinned geometry past it under the limit the kernels compute.
+"""
+
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from _tpu_compile import one_chip  # noqa: F401
+
+fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
+
+
+def _compile(one_chip, *, S, D, dtype, bq, bk, which, segmented=False,
+             window=None, BH=128, BHk=None):
+    def arr(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    q, col = arr(BH, S, D), arr(BH, S, 1, dt=jnp.float32)
+    kv = arr(BHk or BH, S, D)       # fewer kv head rows: GQA
+    seg = {}
+    operands = [q, kv, kv] if which == "fwd" else [q, kv, kv, q, col, q]
+    if segmented:
+        operands += [arr(BH, S, 1, dt=jnp.int32)] * 2
+
+    def fn(*a):
+        if segmented:
+            *a, qs, ks = a
+            seg.update(q_seg=qs, kv_seg=ks)
+        kernel = fa._flash_bh_fwd if which == "fwd" else fa._flash_bh_bwd
+        return kernel(*a, scale=0.1, causal=True, block_q=bq, block_k=bk,
+                      interpret=False, window=window, **seg)
+
+    compiled = jax.jit(fn).lower(*operands).compile()
+    assert compiled.as_text().count("tpu_custom_call") == (
+        1 if which == "fwd" else 2)
+    return compiled
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+@pytest.mark.parametrize("D,dtype,segmented", [
+    (128, jnp.bfloat16, False),      # the benchmark's cells
+    (64, jnp.bfloat16, False),
+    (256, jnp.bfloat16, False),      # 1024 forward, 512 backward
+    (128, jnp.float32, False),
+    (128, jnp.bfloat16, True),       # a segment mask halves the tile
+    (256, jnp.float32, True),
+], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_default_geometry_compiles_inside_the_default_vmem(
+        one_chip, D, dtype, segmented, which):
+    S = 2048
+    b = fa.auto_block_size(S, D, dtype, which, segmented)
+    footprint = fa.flash_vmem_bytes(
+        b, b, D, jnp.dtype(dtype).itemsize, which, segmented)
+    assert fa._compiler_params(footprint) is None
+    _compile(one_chip, S=S, D=D, dtype=dtype, bq=b, bk=b, which=which,
+             segmented=segmented)
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_hybrid_cell_attention_compiles_at_its_default(one_chip, which):
+    """GQA 32 / 8 at D=64, S=8192, two rows: the attention layer of the
+    ``granite4hm-train-1chip`` cell at the geometry the rule gives it."""
+    S, D = 8192, 64
+    b = fa.auto_block_size(S, D, jnp.bfloat16, which)
+    assert fa._compiler_params(
+        fa.flash_vmem_bytes(b, b, D, 2, which)) is None
+    _compile(one_chip, S=S, D=D, dtype=jnp.bfloat16, bq=b, bk=b,
+             which=which, BH=2 * 32, BHk=2 * 8)
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_pinned_geometry_past_the_default_gets_its_limit(one_chip, which):
+    """2048 x 2048 needs more than the default 16 MiB: it compiles
+    because the kernels ask for their own footprint."""
+    footprint = fa.flash_vmem_bytes(2048, 2048, 128, 2, which)
+    assert fa._compiler_params(footprint).vmem_limit_bytes > footprint
+    _compile(one_chip, S=2048, D=128, dtype=jnp.bfloat16, bq=2048, bk=2048,
+             which=which)
+
+
+@pytest.mark.parametrize("bq,bk,window", [
+    (512, 1024, None), (1024, 256, None), (256, 256, 300)])
+def test_banded_index_maps_compile(one_chip, bq, bk, window):
+    """Rectangular blocks and a sliding window: the clamped index maps
+    lower through Mosaic in all three kernels."""
+    for which in ("fwd", "bwd"):
+        _compile(one_chip, S=2048, D=128, dtype=jnp.bfloat16, bq=bq, bk=bk,
+                 which=which, window=window)
+
+
+def test_rotary_positions_turn_whole_heads(one_chip):
+    """``rotate_partial`` forward + backward at mellum's q (1 x 16,384 x
+    32 heads of 128, bfloat16, the whole head turned) as a caller runs
+    it: one pass over whole heads each way.  Read: 1.39 GB accessed (the
+    half-split form it replaced, with the float32 copy a caller made:
+    3.79 GB — every 64-lane half is padded to 128 lanes on the chip), and
+    no array in the optimised text whose minor axis is ``rotary_dim /
+    2``."""
+    from chainermn_tpu.models.transformer import rotate_partial
+
+    shape, rotary_dim = (1, 16384, 32, 128), 128
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def both_passes(x, g):
+        y, back = jax.vjp(
+            lambda x: rotate_partial(
+                x, jnp.arange(shape[1]), rotary_dim, 5e5).astype(
+                    jnp.bfloat16), x)
+        return y, back(g)[0]
+
+    compiled = jax.jit(both_passes).lower(x, x).compile()
+    assert compiled.cost_analysis()["bytes accessed"] <= 1.8e9
+    text = compiled.as_text()
+    assert not re.findall(r"\[(?:\d+,)*%d\]" % (rotary_dim // 2), text)
+    assert len(re.findall(r" convolution\(", text)) == 2   # x P, and back
