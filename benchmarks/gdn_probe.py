@@ -5,7 +5,9 @@ geometry, for the two Mosaic kernels (``ops.gated_delta.gated_delta_rule``:
 ``gdn-fwd`` / ``gdn-bwd``) and for the XLA chunked form they replaced (PR
 37), which lives on here as the comparison: the solve by substitution on
 16-row blocks joined pairwise, a ``lax.scan`` step a chunk, the value
-heads in rematerialised groups under ``lax.map``, autodiff's backward.
+heads in rematerialised groups under ``lax.map``, autodiff's backward
+(the solve itself is ``ops.kda.unit_lower_inverse``, which the
+Kimi-Delta-Attention rule runs in the program).
 
 Each is compiled at ``(2, 8192, 16 key / 32 value heads of 128, chunk
 64)`` in bfloat16, forward and vjp apart, the operands' layouts left to
@@ -43,16 +45,13 @@ from jax.sharding import SingleDeviceSharding
 
 from chainermn_tpu.observability.spans import named_scope
 from chainermn_tpu.ops import gated_delta
+from chainermn_tpu.ops.kda import unit_lower_inverse
 from ssm_conv_probe import device_ms
 
 _HIGHEST = lax.Precision.HIGHEST
 
 
 # ---- the XLA chunked form, as ``ops/gated_delta.py`` had it until PR 37
-
-#: Side of the diagonal blocks inverted by substitution, a row a step;
-#: larger blocks are put together from their halves.
-_BASE = 16
 
 #: Tokens x value heads a group of heads holds at most (2 x 8192 tokens:
 #: 8 heads, about 1.5 GB between the passes).
@@ -65,57 +64,6 @@ def heads_a_group(tokens: int, heads: int) -> int:
     return max([h for h in range(1, heads + 1)
                 if heads % h == 0 and tokens * h <= _GROUP_TOKEN_HEADS],
                default=1)
-
-
-def _substitute(a):
-    """``(I + a)^-1`` by forward substitution, a row a step: row ``i`` is
-    ``e_i - sum_{j<i} a_ij row_j``.  ``a``: (m, m, N), strictly lower
-    triangular in its first two axes, the batch LAST (on the lanes)."""
-    m, _, N = a.shape
-    eye = jnp.eye(m, dtype=a.dtype)
-    rows = [jnp.broadcast_to(eye[0][:, None], (m, N))]
-    for i in range(1, m):
-        done = jnp.stack(rows)                              # (i, m, N)
-        rows.append(eye[i][:, None]
-                    - jnp.sum(a[i, :i, None, :] * done, axis=0))
-    return jnp.stack(rows)
-
-
-def _mm(x, y):
-    """``x @ y`` over the first two axes, the batch last: float32
-    multiplies and adds, no matrix unit (the blocks are 16 or 32 wide)."""
-    return jnp.sum(x[:, :, None, :] * y[None, :, :, :], axis=1)
-
-
-def _inverse(a, base):
-    n, _, N = a.shape
-    if n <= base or n % 2:
-        return _substitute(a)
-    h = n // 2
-    # Both halves' diagonal blocks side by side on the batch axis; then
-    # [[T11, 0], [-T22 A21 T11, T22]].
-    both = _inverse(
-        jnp.concatenate([a[:h, :h], a[h:, h:]], axis=-1), base)
-    t11, t22 = both[..., :N], both[..., N:]
-    t21 = -_mm(_mm(t22, a[h:, :h]), t11)
-    top = jnp.concatenate([t11, jnp.zeros_like(t21)], axis=1)
-    return jnp.concatenate(
-        [top, jnp.concatenate([t21, t22], axis=1)], axis=0)
-
-
-def unit_lower_inverse(a, base: int = _BASE):
-    """``(I + a)^-1`` for ``a`` (..., n, n) strictly lower triangular
-    (what lies on or above the diagonal is NOT read as zero: the caller
-    masks it), float32.  Substitution on the diagonal blocks of ``base``
-    rows, the blocks joined pairwise by ``-T22 A21 T11``: backward-stable
-    as substitution is, which the Neumann product ``(I - a)(I + a^2)(I +
-    a^4)...`` is not (its terms cancel from 1e10 at 64 rows and entries
-    near 0.5).  Worked with the batch on the last axis, so that a step's
-    small rows fill whole registers of lanes."""
-    lead, n = a.shape[:-2], a.shape[-1]
-    flat = jnp.moveaxis(a.reshape((-1, n, n)), 0, -1)
-    out = _inverse(flat, base)
-    return jnp.moveaxis(out, -1, 0).reshape(lead + (n, n))
 
 
 def _chunked(q, k, v, g, beta, C):

@@ -25,7 +25,11 @@ an untied head) and ``mellum`` (sliding-window attention rows to one
 full-attention row whose rotary positions are YaRN-scaled — two rows of
 one table that see and rotate differently — QK-norm, every layer followed
 by a softmax top-k sparse-expert FFN with no shared expert; RMSNorm, an
-untied head).
+untied head) and ``bailing_hybrid`` (five Kimi-Delta-Attention rows — a
+delta rule under a decay a key channel — to one latent-attention (MLA)
+row whose scores are wider than its values, a head-wise sigmoid gate on
+both; two leading dense SwiGLU FFNs, then a group-limited sigmoid top-k
+sparse-expert FFN with a shared expert; RMSNorm, an untied head).
 
 Plain frozen dataclasses: hashable, so a table is a static field of the
 flax module.
@@ -38,7 +42,7 @@ from typing import Mapping, Optional, Tuple
 
 import numpy as np
 
-MIXERS = ("attention", "mamba2", "cca", "gdn", "none")
+MIXERS = ("attention", "mamba2", "cca", "gdn", "kda", "none")
 #: "rmsnorm_zc" is the zero-centred RMSNorm: the learned ``w`` starts at
 #: 0 and scales by ``1 + w``.
 NORMS = ("layernorm", "rmsnorm", "rmsnorm_zc")
@@ -147,6 +151,73 @@ class GDNSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class KDASpec:
+    """Geometry of a Kimi-Delta-Attention mixer (Kimi Linear,
+    arXiv:2510.26692): ``n_heads`` heads with keys of ``d_k`` and values
+    of ``d_v``, each with a ``d_k x d_v`` state that decays by a factor of
+    its own A KEY CHANNEL; a causal depthwise convolution of ``d_conv``
+    taps over the query, key and value channels, queries and keys then
+    L2-normalised a head; the log-decay
+    ``lower_bound * sigmoid(.)``, in ``(lower_bound, 0)``; ``chunk``
+    tokens a chunk of the chunked rule."""
+
+    n_heads: int
+    d_k: int
+    d_v: int
+    d_conv: int = 4
+    chunk: int = 64
+    lower_bound: float = -5.0
+
+    def __post_init__(self):
+        if not self.lower_bound < 0.0:
+            raise ValueError(f"the log-decay's lower bound is negative, "
+                             f"got {self.lower_bound}")
+
+    @property
+    def key_dim(self) -> int:
+        return self.n_heads * self.d_k
+
+    @property
+    def value_dim(self) -> int:
+        return self.n_heads * self.d_v
+
+    @property
+    def conv_dim(self) -> int:
+        return 2 * self.key_dim + self.value_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class MLASpec:
+    """An attention row as multi-head latent attention (DeepSeek-V2,
+    arXiv:2405.04434), for training (no cache, no absorbed form): keys
+    and values come up from one latent of ``kv_rank`` a token, RMSNormed;
+    a query and key head is ``d_nope`` dimensions without positions and
+    ``d_rope`` with rotary positions at ``rope_theta`` (the pairs
+    interleaved, ``(2i, 2i + 1)``, where ``interleave``; else ``(i, i +
+    d_rope / 2)``), the rotary part of the key ONE vector a token that
+    every head shares; values are ``d_v`` wide, which need not be the
+    scores' ``d_nope + d_rope``; the softmax scale is ``1 / sqrt(d_nope +
+    d_rope)``."""
+
+    kv_rank: int
+    d_nope: int
+    d_rope: int
+    d_v: int
+    rope_theta: float = 10000.0
+    interleave: bool = True
+
+    def __post_init__(self):
+        if self.d_rope % 2 or min(self.kv_rank, self.d_nope, self.d_rope,
+                                  self.d_v) < 1:
+            raise ValueError(f"an MLA row has a latent, nope and value "
+                             f"widths and an even rope width, got {self}")
+
+    @property
+    def d_qk(self) -> int:
+        return self.d_nope + self.d_rope
+
+
+@dataclasses.dataclass(frozen=True)
 class YarnSpec:
     """YaRN scaling of a row's rotary positions (arXiv:2309.00071): a
     context of ``original_max_position`` stretched ``factor`` times.  The
@@ -231,6 +302,14 @@ class ExpertsSpec:
     weighs them by their probabilities over the chosen ones' sum, times
     ``scaling``.
 
+    ``n_group`` > 0 (the ``sigmoid`` router only) limits the choice to
+    groups (DeepSeek-V3, arXiv:2412.19437): the ``n_experts`` are
+    ``n_group`` equal runs, a group's score is the sum of its two largest
+    ``score + bias``, a token keeps its ``topk_group`` best groups and
+    chooses its ``top_k`` among their experts; 0 is no groups.  The
+    groups are the router's, over the published experts: a held share
+    ``(first, count)`` need not align with one.
+
     ``expert``: ``"relu2"`` is ``w_down relu(w_up h)^2``, ``"swiglu"``
     ``w_down (silu(w_gate h) * (w_up h))``, three matrices."""
 
@@ -244,6 +323,8 @@ class ExpertsSpec:
     expert: str = "relu2"              # one of EXPERTS
     d_router: int = 0                  # "mlp_softmax": the state's width
     shared_gate: bool = False          # sigmoid(h w_s) on the shared expert
+    n_group: int = 0                   # router groups (0: none)
+    topk_group: int = 0                # groups a token keeps
 
     def __post_init__(self):
         if self.router not in ROUTERS or self.expert not in EXPERTS:
@@ -257,6 +338,18 @@ class ExpertsSpec:
                 self.top_k != 1 or self.scaling != 1.0):
             raise ValueError("the mlp_softmax router chooses one expert a "
                              "token and weighs it by its probability")
+        if self.n_group and (
+                self.router != "sigmoid" or self.n_experts % self.n_group
+                or not 1 <= self.topk_group <= self.n_group
+                or self.n_experts // self.n_group < 2
+                or self.top_k > self.topk_group
+                * (self.n_experts // self.n_group)):
+            raise ValueError(
+                f"router groups are the sigmoid router's: {self.n_group} "
+                f"equal runs of at least two of {self.n_experts} experts, "
+                f"{self.topk_group} kept with room for top_k {self.top_k}")
+        if self.topk_group and not self.n_group:
+            raise ValueError("topk_group without n_group")
         if self.shared_gate and not self.d_shared:
             raise ValueError("a shared_gate gates a shared expert: "
                              "d_shared is 0")
@@ -303,9 +396,15 @@ class LayerSpec:
                                        # each query and key head
     out_gate: bool = False             # attention rows: [q | gate] = W_q h a
                                        # head, out = W_o (attn * sigmoid(gate))
+    mla: Optional[MLASpec] = None      # attention rows: latent attention
+                                       # (n_heads heads; qk_norm there is
+                                       # over the nope part of a head)
+    head_gate: bool = False            # mla rows: out = W_o (attn *
+                                       # sigmoid(h W_g)), one number a head
     ssm: Optional[SSMSpec] = None      # mamba2 rows
     cca: Optional[CCASpec] = None      # cca rows
     gdn: Optional[GDNSpec] = None      # gdn rows
+    kda: Optional[KDASpec] = None      # kda rows
     experts: Optional[ExpertsSpec] = None   # "experts" rows
     residual_multiplier: float = 1.0   # rm above
     norm_eps: float = 1e-6
@@ -323,11 +422,24 @@ class LayerSpec:
             raise ValueError("a cca row, and only it, carries a CCASpec")
         if (self.mixer == "gdn") != (self.gdn is not None):
             raise ValueError("a gdn row, and only it, carries a GDNSpec")
+        if (self.mixer == "kda") != (self.kda is not None):
+            raise ValueError("a kda row, and only it, carries a KDASpec")
         if self.mixer != "attention" and (
                 self.rotary_dim or self.qk_norm or self.out_gate
-                or self.window is not None):
-            raise ValueError("rotary_dim, qk_norm, out_gate and window "
-                             "are an attention row's")
+                or self.window is not None or self.mla is not None
+                or self.head_gate):
+            raise ValueError("rotary_dim, qk_norm, out_gate, window, mla "
+                             "and head_gate are an attention row's")
+        if self.mla is not None and (
+                self.rotary_dim or self.yarn is not None or self.out_gate
+                or self.window is not None or self.attn_scale is not None
+                or self.n_kv_heads not in (None, self.n_heads)):
+            raise ValueError(
+                "an mla row rotates, scales and gates by its MLASpec: "
+                "rotary_dim, yarn, out_gate, window, attn_scale and fewer "
+                "kv heads are a plain attention row's")
+        if self.head_gate and self.mla is None:
+            raise ValueError("head_gate is an mla row's")
         if self.window is not None and self.window < 1:
             raise ValueError(f"window must be >= 1, got {self.window}")
         if self.yarn is not None and not self.rotary_dim:
@@ -369,9 +481,10 @@ class BlockTable:
         if not self.layers:
             raise ValueError("a block table has at least one layer")
         if self.positions != "rotary" and any(
-                r.rotary_dim for r in self.layers):
-            raise ValueError("an attention row with a rotary_dim belongs "
-                             "to a table whose positions are 'rotary'")
+                r.rotary_dim or r.mla is not None for r in self.layers):
+            raise ValueError("an attention row with a rotary_dim or an "
+                             "MLASpec belongs to a table whose positions "
+                             "are 'rotary'")
         if len({r.experts.d_router for r in self.layers
                 if r.experts is not None}) > 1:
             raise ValueError("every expert row of a table has the same "
@@ -412,10 +525,10 @@ def _first_layers(kinds, n_layers):
 def table_from_config(config: Mapping, n_layers: Optional[int] = None,
                       experts_held: Optional[Tuple[int, int]] = None
                       ) -> BlockTable:
-    """The table of a published ``config.json``, by its own keys.  Five
+    """The table of a published ``config.json``, by its own keys.  Six
     families are read, by ``model_type``: ``granitemoehybrid`` (its dense
     members: ``num_local_experts`` 0), ``nemotron_h``, ``zaya``,
-    ``qwen3_next`` and ``mellum``.  ``n_layers``
+    ``qwen3_next``, ``mellum`` and ``bailing_hybrid``.  ``n_layers``
     keeps the first so many layers (a pipeline stage, a cut to fit); None
     keeps ``num_hidden_layers``.  ``experts_held`` is the ``(first,
     count)`` of the published experts this rank holds in every expert
@@ -424,7 +537,8 @@ def table_from_config(config: Mapping, n_layers: Optional[int] = None,
     What this system cannot build raises here, by key."""
     readers = {"granitemoehybrid": _granite_table,
                "nemotron_h": _nemotron_h_table, "zaya": _zaya_table,
-               "qwen3_next": _qwen3_next_table, "mellum": _mellum_table}
+               "qwen3_next": _qwen3_next_table, "mellum": _mellum_table,
+               "bailing_hybrid": _bailing_hybrid_table}
     reader = readers.get(config.get("model_type"))
     if reader is None:
         raise ValueError(
@@ -765,5 +879,159 @@ def _mellum_table(config, n_layers, experts_held):
     rows = {kind: row(kind) for kind in set(kinds)}
     return BlockTable(
         layers=tuple(rows[k] for k in _first_layers(kinds, n_layers)),
+        positions="rotary", final_norm="rmsnorm", norm_eps=eps,
+        tied_head=False)
+
+
+#: Every key the ``bailing_hybrid`` reader takes: read, held to the one
+#: value this system builds, or ignored (the last line: a context length,
+#: a window count with no window key beside it, a loss the config gives
+#: no coefficient for, and the multi-token-prediction layer, which lives
+#: on the last pipeline stage and whose loss weight must be 0).
+BAILING_HYBRID_KEYS = frozenset((
+    "model_type", "num_hidden_layers", "hidden_size", "vocab_size",
+    "intermediate_size", "rms_norm_eps", "hidden_act",
+    "tie_word_embeddings", "layer_group_size", "first_k_dense_replace",
+    "num_attention_heads", "num_key_value_heads", "head_dim",
+    "kv_lora_rank", "q_lora_rank", "qk_head_dim", "qk_nope_head_dim",
+    "qk_rope_head_dim", "v_head_dim", "rope_theta", "rope_interleave",
+    "rope_scaling", "rotary_dim", "partial_rotary_factor", "use_qk_norm",
+    "use_mla_nope", "gated_attention_proj_granularity_type",
+    "short_conv_kernel_size", "linear_silu", "kda_safe_gate",
+    "kda_lower_bound", "no_kda_lora", "use_kda_lora", "group_norm_size",
+    "num_kv_heads_for_linear_attn", "num_experts", "num_experts_per_tok",
+    "num_shared_experts", "moe_intermediate_size",
+    "moe_shared_expert_intermediate_size", "moe_router_enable_expert_bias",
+    "n_group", "topk_group", "topk_method", "norm_topk_prob",
+    "routed_scaling_factor", "score_function", "scoring_func",
+    "scale_router_input", "expert_swiglu_limit_list",
+    "share_expert_swiglu_limit_list", "use_nGPT", "value_norm",
+    "up_proj_norm", "use_bias", "use_qkv_bias", "mtp_use_kda",
+    "mtp_loss_scaling_factor", "num_nextn_predict_layers",
+    "max_position_embeddings", "max_window_layers", "seq_aux",
+))
+
+
+def _bailing_hybrid_table(config, n_layers, experts_held):
+    """``bailing_hybrid`` (Ling-3.0): layer ``i`` a latent-attention row
+    where ``(i + 1) % layer_group_size == 0`` — ``num_attention_heads``
+    heads scoring over ``qk_nope_head_dim + qk_rope_head_dim`` and summing
+    values of ``v_head_dim``, keys and values from a latent of
+    ``kv_lora_rank``, rotary positions on the rope part at ``rope_theta``
+    (``rope_interleave``: pairs ``(2i, 2i + 1)``), a head-wise sigmoid
+    gate — else a Kimi-Delta-Attention row (``num_attention_heads`` heads
+    of ``head_dim`` keys and values, a convolution of
+    ``short_conv_kernel_size`` taps, the log-decay bounded at
+    ``kda_lower_bound``, chunks of 64, the same head-wise gate);
+    ``use_qk_norm``: the KDA rows' L2 norms and an RMSNorm over the nope
+    part of the MLA row's query and key heads.  The first
+    ``first_k_dense_replace`` layers carry a SwiGLU of
+    ``intermediate_size``; the others ``num_experts`` gated experts of
+    ``moe_intermediate_size``, ``num_experts_per_tok`` a token by a
+    sigmoid router with an expert bias, limited to ``topk_group`` of
+    ``n_group`` groups, renormalised over the chosen times
+    ``routed_scaling_factor``, and one shared expert of
+    ``moe_shared_expert_intermediate_size``; RMSNorm, an untied head.
+    The two ``*_swiglu_limit_list``s are carried, and a KEPT layer whose
+    entry is not 0 is refused: the clamp's form is not in the config.
+    Refused by key: an unknown key, every switch the published row gives
+    as off at any other value, a query latent, scaled rotary positions,
+    fewer key/value heads, no QK-norm, another gate granularity, norm
+    group, router
+    score or method, several or no shared experts, a multi-token
+    prediction loss, a tied head."""
+    unknown = sorted(set(config) - BAILING_HYBRID_KEYS)
+    _refuse([(unknown, f"bailing_hybrid keys {unknown}; the reader takes "
+                       f"{sorted(BAILING_HYBRID_KEYS)}")])
+    off = ("use_nGPT", "value_norm", "up_proj_norm", "scale_router_input",
+           "use_mla_nope", "use_bias", "use_qkv_bias", "use_kda_lora",
+           "mtp_use_kda")
+    n = config["num_hidden_layers"]
+    heads, d_head = config["num_attention_heads"], config["head_dim"]
+    nope, rope = config["qk_nope_head_dim"], config["qk_rope_head_dim"]
+    clamps = {key: list(config.get(key, [0] * n)) for key in (
+        "expert_swiglu_limit_list", "share_expert_swiglu_limit_list")}
+    _refuse([(bool(config.get(key)), f"{key} true") for key in off] + [
+        (config.get("num_kv_heads_for_linear_attn", 0) != 0,
+         "num_kv_heads_for_linear_attn other than 0"),
+        (config.get("rope_scaling") is not None,
+         "rope_scaling (scaled rotary positions)"),
+        (config.get("q_lora_rank") is not None,
+         "q_lora_rank (a latent query projection)"),
+        (not config.get("no_kda_lora", True), "no_kda_lora false"),
+        (not config.get("kda_safe_gate", False),
+         "kda_safe_gate false (an unbounded decay gate)"),
+        (not config.get("linear_silu", True), "linear_silu false"),
+        (not config.get("use_qk_norm", True),
+         "use_qk_norm false (KDA rows without their L2 norms)"),
+        (config.get("hidden_act") != "silu", "hidden_act other than silu"),
+        (config.get("group_norm_size", 1) != 1,
+         "group_norm_size other than 1"),
+        (config.get("gated_attention_proj_granularity_type") != "head_wise",
+         "gated_attention_proj_granularity_type other than head_wise"),
+        (config.get("num_key_value_heads", heads) != heads,
+         "num_key_value_heads other than num_attention_heads"),
+        (config.get("qk_head_dim", nope + rope) != nope + rope
+         or config.get("rotary_dim", rope) != rope
+         or int(d_head * config.get("partial_rotary_factor", rope / d_head))
+         != rope,
+         "qk_head_dim, rotary_dim or partial_rotary_factor that disagree "
+         "with qk_nope_head_dim and qk_rope_head_dim"),
+        ({config.get("score_function", "sigmoid"),
+          config.get("scoring_func", "sigmoid")} != {"sigmoid"},
+         "score_function / scoring_func other than sigmoid"),
+        (config.get("topk_method") != "noaux_tc",
+         "topk_method other than noaux_tc"),
+        (not config.get("moe_router_enable_expert_bias", True),
+         "moe_router_enable_expert_bias false"),
+        (not config.get("norm_topk_prob", True),
+         "norm_topk_prob false (router weights not renormalised over the "
+         "chosen)"),
+        (config.get("num_shared_experts", 1) != 1,
+         "num_shared_experts other than 1"),
+        (config.get("mtp_loss_scaling_factor", 0) != 0,
+         "mtp_loss_scaling_factor other than 0 (a multi-token-prediction "
+         "loss)"),
+        (bool(config.get("tie_word_embeddings")), "a tied output head"),
+        (any(len(v) != n for v in clamps.values()),
+         "*_swiglu_limit_list that do not list num_hidden_layers entries"),
+    ])
+    every, dense = config["layer_group_size"], config["first_k_dense_replace"]
+    kept = _first_layers(list(range(n)), n_layers)
+    clamped = [i for i in kept if i >= dense and any(
+        v[i] != 0 for v in clamps.values())]
+    _refuse([(clamped, f"a SwiGLU clamp (layers {clamped}: a non-zero "
+                       f"*_swiglu_limit_list entry, its form not given)")])
+    eps = float(config["rms_norm_eps"])
+    mixers = {
+        "mla": dict(
+            mixer="attention", n_heads=heads, qk_norm=True,
+            head_gate=True, mla=MLASpec(
+                kv_rank=config["kv_lora_rank"], d_nope=nope, d_rope=rope,
+                d_v=config["v_head_dim"],
+                rope_theta=float(config.get("rope_theta", 10000.0)),
+                interleave=bool(config.get("rope_interleave", False)))),
+        "kda": dict(mixer="kda", kda=KDASpec(
+            n_heads=heads, d_k=d_head, d_v=d_head,
+            d_conv=config["short_conv_kernel_size"],
+            lower_bound=float(config["kda_lower_bound"]))),
+    }
+    ffns = {"dense": dict(ffn="swiglu", d_ff=config["intermediate_size"])}
+    if kept[-1] >= dense:
+        ffns["sparse"] = dict(ffn="experts", experts=ExpertsSpec(
+            n_experts=config["num_experts"],
+            top_k=config["num_experts_per_tok"],
+            d_expert=config["moe_intermediate_size"],
+            d_shared=config["moe_shared_expert_intermediate_size"],
+            held=experts_held,
+            scaling=float(config.get("routed_scaling_factor", 1.0)),
+            router="sigmoid", expert="swiglu",
+            n_group=config.get("n_group", 0),
+            topk_group=config.get("topk_group", 0)))
+    return BlockTable(
+        layers=tuple(LayerSpec(
+            norm="rmsnorm", norm_eps=eps,
+            **mixers["mla" if (i + 1) % every == 0 else "kda"],
+            **ffns["dense" if i < dense else "sparse"]) for i in kept),
         positions="rotary", final_norm="rmsnorm", norm_eps=eps,
         tied_head=False)

@@ -16,7 +16,7 @@ PartitionSpec rules, and the attention layer can run sequence-parallel via
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -28,7 +28,9 @@ from chainermn_tpu.models.block_table import (
     CCASpec,
     ExpertsSpec,
     GDNSpec,
+    KDASpec,
     LayerSpec,
+    MLASpec,
     SSMSpec,
     YarnSpec,
     gpt2_table,
@@ -520,7 +522,7 @@ class ExpertLayer(nn.Module):
     :class:`Relu2FeedForward` or a :class:`GatedFeedForward`) over every
     token where the spec has one, times ``sigmoid(h w_s)`` where the spec
     gates it.  By the spec's kinds: the router ``sigmoid`` (top-k,
-    :func:`moe_dropless.route`), ``softmax`` (top-k, no bias,
+    :func:`moe_dropless.route`, group-limited where the spec has groups), ``softmax`` (top-k, no bias,
     :func:`moe_dropless.route_softmax`) or ``mlp_softmax`` (top-1,
     :func:`moe_dropless.route_mlp_softmax`, which takes the router state
     of the layer before and hands its own on: then the layer is called
@@ -585,7 +587,8 @@ class ExpertLayer(nn.Module):
                         x, router(), top_k=z.top_k, scaling=z.scaling)
                 elif z.router == "sigmoid":
                     chosen, weight = moe.route(
-                        x, router(), bias, top_k=z.top_k, scaling=z.scaling)
+                        x, router(), bias, top_k=z.top_k, scaling=z.scaling,
+                        n_group=z.n_group, topk_group=z.topk_group)
                 else:
                     r = z.d_router
                     chosen, weight, state = moe.route_mlp_softmax(
@@ -662,39 +665,54 @@ def rebalance_routers(params, chosen, rate: float):
     return out
 
 
-def rotary_partner(rotary_dim: int, d_head: int) -> np.ndarray:
+def rotary_partner(rotary_dim: int, d_head: int,
+                   lanes: Tuple[int, bool] = (0, False)) -> np.ndarray:
     """The (d_head, d_head) signed permutation ``P`` that fetches every
     lane's partner whole: ``(x @ P)[i] = -x[i + half]`` and ``(x @
     P)[i + half] = x[i]`` for ``i < half = rotary_dim / 2``, 0 past
-    ``rotary_dim``.  A 0 / 1 / -1 matrix: a bfloat16 operand's product
-    with it, summed in float32, is that operand's own values."""
+    ``rotary_dim``.  ``lanes`` = ``(start, interleave)``: the turned
+    lanes begin at ``start``, and where ``interleave`` the pairs are
+    neighbours, ``(2i, 2i + 1)``.  A 0 / 1 / -1 matrix: a bfloat16
+    operand's product with it, summed in float32, is that operand's own
+    values."""
+    start, interleave = lanes
     half = rotary_dim // 2
     i = np.arange(half)
+    a, b = (start + 2 * i, start + 2 * i + 1) if interleave else (
+        start + i, start + i + half)
     partner = np.zeros((d_head, d_head), np.float32)
-    partner[i + half, i] = -1.0
-    partner[i, i + half] = 1.0
+    partner[b, a] = -1.0
+    partner[a, b] = 1.0
     return partner
 
 
 def _rotary_tables(positions, d_head: int, rotary_dim: int, theta: float,
-                   yarn: Optional[YarnSpec]):
+                   yarn: Optional[YarnSpec],
+                   lanes: Tuple[int, bool] = (0, False)):
     """``cos`` and ``sin`` of a row's angles as (S, 1, d_head) float32,
-    the ``rotary_dim / 2`` frequencies laid twice side by side and a
-    frequency of 0 past ``rotary_dim`` (``cos`` 1, ``sin`` 0: those lanes
-    pass through); under ``yarn`` the turned lanes times its scale."""
+    the ``rotary_dim / 2`` frequencies laid twice side by side (each
+    twice in a row where the pairs interleave) from the turned lanes'
+    start and a frequency of 0 elsewhere (``cos`` 1, ``sin`` 0: those
+    lanes pass through); under ``yarn`` the turned lanes times its
+    scale."""
     freq, scale = rotary_frequencies(rotary_dim, theta, yarn)
-    rest = d_head - rotary_dim
+    start, interleave = lanes
+    rest = d_head - start - rotary_dim
+    turned = np.repeat(freq, 2) if interleave else np.concatenate(
+        [freq, freq])
     angle = positions.astype(jnp.float32)[:, None] * jnp.asarray(
-        np.concatenate([freq, freq, np.zeros(rest)]), jnp.float32)
+        np.concatenate([np.zeros(start), turned, np.zeros(rest)]),
+        jnp.float32)
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
     if yarn is not None:
         cos = cos * jnp.asarray(np.concatenate(
-            [np.full(rotary_dim, scale), np.ones(rest)]), jnp.float32)
+            [np.ones(start), np.full(rotary_dim, scale), np.ones(rest)]),
+            jnp.float32)
         sin = sin * scale
     return cos, sin
 
 
-def fetch_partner(x, rotary_dim: int):
+def fetch_partner(x, rotary_dim: int, lanes: Tuple[int, bool] = (0, False)):
     """``x @ P`` in float32: every lane's partner, to the bit.  The
     product's precision follows from the operand's dtype and nothing
     else: one bfloat16 pass summed in float32 is exact for a bfloat16
@@ -702,26 +720,30 @@ def fetch_partner(x, rotary_dim: int):
     one_pass = x.dtype == jnp.bfloat16
     return jnp.einsum(
         "...d,de->...e", x,
-        jnp.asarray(rotary_partner(rotary_dim, x.shape[-1]), x.dtype),
+        jnp.asarray(rotary_partner(rotary_dim, x.shape[-1], lanes), x.dtype),
         precision=None if one_pass else jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 
-def _turn(x, cos, sin, rotary_dim: int):
+def _turn(x, cos, sin, rotary_dim: int, lanes=(0, False)):
     """``x cos + (x P) sin`` in float32, one pass over whole heads."""
-    return x * cos + fetch_partner(x, rotary_dim) * sin
+    return x * cos + fetch_partner(x, rotary_dim, lanes) * sin
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
 def rotate_partial(x, positions, rotary_dim: int, theta: float,
-                   yarn: Optional[YarnSpec] = None):
+                   yarn: Optional[YarnSpec] = None,
+                   lanes: Tuple[int, bool] = (0, False)):
     """Rotary positions on the first ``rotary_dim`` of the last axis of
     ``x`` (b, S, heads, d_head), the rest passed through, in float32:
     dimension ``i`` of the first half of the rotated part pairs with ``i
     + rotary_dim / 2``, at the angle ``positions x theta^(-2 i /
     rotary_dim)`` — or, under ``yarn``, at the row's blended frequencies
     with ``cos`` and ``sin`` times its scale
-    (``block_table.rotary_frequencies``).
+    (``block_table.rotary_frequencies``).  ``lanes`` = ``(start,
+    interleave)``, a latent-attention row's: the ``rotary_dim`` turned
+    dimensions begin at lane ``start`` (a head ``[nope | rope]``), and
+    where ``interleave`` pair ``i`` is lanes ``(2i, 2i + 1)`` of them.
 
     ``x`` comes in the type the caller holds it.  A head is turned whole,
     the lanes never cut into halves: ``x cos + (x P) sin`` against
@@ -729,10 +751,10 @@ def rotate_partial(x, positions, rotary_dim: int, theta: float,
     signed permutation ``P`` (:func:`rotary_partner`).  ``positions`` are
     token indices (integers: they take no gradient); the cotangent of
     ``x`` is the cotangent turned back, rounded to ``x``'s type once."""
-    return _rotate_fwd(x, positions, rotary_dim, theta, yarn)[0]
+    return _rotate_fwd(x, positions, rotary_dim, theta, yarn, lanes)[0]
 
 
-def _rotate_fwd(x, positions, rotary_dim, theta, yarn):
+def _rotate_fwd(x, positions, rotary_dim, theta, yarn, lanes=(0, False)):
     if telemetry_active():
         from chainermn_tpu.ops.ssd import publish_geometry
 
@@ -744,15 +766,15 @@ def _rotate_fwd(x, positions, rotary_dim, theta, yarn):
             precision=("one_bf16_pass" if x.dtype == jnp.bfloat16
                        else "highest"))
     cos, sin = _rotary_tables(positions, x.shape[-1], rotary_dim, theta,
-                              yarn)
+                              yarn, lanes)
     # (an empty array carries the operand's type to the backward rule)
-    return _turn(x, cos, sin, rotary_dim), (cos, sin,
-                                            jnp.zeros((0,), x.dtype))
+    return _turn(x, cos, sin, rotary_dim, lanes), (
+        cos, sin, jnp.zeros((0,), x.dtype))
 
 
-def _rotate_bwd(rotary_dim, theta, yarn, residuals, g):
+def _rotate_bwd(rotary_dim, theta, yarn, lanes, residuals, g):
     cos, sin, like = residuals
-    return _turn(g, cos, -sin, rotary_dim).astype(like.dtype), None
+    return _turn(g, cos, -sin, rotary_dim, lanes).astype(like.dtype), None
 
 
 rotate_partial.defvjp(_rotate_fwd, _rotate_bwd)
@@ -1018,6 +1040,201 @@ class GatedDeltaNetMixer(nn.Module):
                                 use_bias=False, name="out_proj")(y)
 
 
+class KDAMixer(nn.Module):
+    """The Kimi-Delta-Attention mixer (Kimi Linear, arXiv:2510.26692) as
+    the ``bailing_hybrid`` family lays it out (a :class:`KDASpec` row):
+    ``[q | k | v | f] = in_proj_qkvf(h)``, ``[b | a] = in_proj_bg(h)``; a
+    causal depthwise convolution and SiLU over ``[q | k | v]``, no bias
+    (:func:`chainermn_tpu.ops.ssd.causal_conv_silu`, the Mamba-2 mixers'
+    kernels); per head ``q <- q / |q| / sqrt(d_k)``, ``k <- k / |k|``
+    with ``|x| = sqrt(sum x^2 + 1e-6)``; ``beta = sigmoid(b)`` a head; the log-decay
+    a head and KEY CHANNEL ``g = lower_bound * sigmoid(exp(A_log) * (f +
+    dt_bias))``, float32, in ``(lower_bound, 0)``; the chunked rule
+    (:func:`chainermn_tpu.ops.kda.kda_rule`); per head ``RMSNorm(o) *
+    sigmoid(a)`` with one plain scale a channel of a head and ONE gate a
+    head; ``out_proj``.  Every sequence starts from a zero state.  From
+    the convolution's output to the gated norm the heads are worked in
+    groups (:func:`chainermn_tpu.ops.kda.heads_a_group`) under
+    ``lax.map``, each rematerialised: the float32 side and the rule's
+    chunk matrices of one group are live at a time (at 32 heads of 16,384
+    tokens, all at once is 5.5 GB of temporaries a layer, 4 GB of it the
+    float32 copies of every head's ``q``, ``k``, ``g`` and ``o``)."""
+
+    d_model: int
+    kda: KDASpec
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, h):
+        from chainermn_tpu.ops.kda import heads_a_group, kda_rule
+        from chainermn_tpu.ops.ssd import causal_conv_silu
+
+        z = self.kda
+        f32 = jnp.float32
+        lead, H = h.shape[:2], z.n_heads
+        hg = heads_a_group(lead[0] * lead[1], H)
+
+        def grouped(x, width=None):
+            """(b, S, H [x width]) -> (H / hg, b, S, hg [, width])"""
+            tail = () if width is None else (width,)
+            return jnp.moveaxis(
+                x.reshape(x.shape[:2] + (H // hg, hg) + tail), 2, 0)
+
+        def group(xs):
+            """The float32 side and the rule of ``hg`` heads."""
+            q, k, v, f, b, gate, a_log, dt_bias = xs
+            with named_scope("mixer-gate"):
+                q, k = (x * jax.lax.rsqrt(jnp.sum(
+                    jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+                    for x in (q.astype(f32), k.astype(f32)))
+                q = (q * (1.0 / np.sqrt(z.d_k))).astype(self.dtype)
+                k = k.astype(self.dtype)
+                beta = jax.nn.sigmoid(b.astype(f32))
+                g = z.lower_bound * jax.nn.sigmoid(
+                    jnp.exp(a_log)[:, None] * (f.astype(f32) + dt_bias))
+            o = kda_rule(q, k, v, g, beta, chunk=z.chunk)
+            with named_scope("mixer-gate"):
+                o = o.astype(f32)
+                o = o * jax.lax.rsqrt(
+                    jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+                    + self.norm_eps)
+                y = o * scale * jax.nn.sigmoid(gate.astype(f32))[..., None]
+                return y.astype(self.dtype)
+
+        with named_scope("kda-mixer"):
+            with named_scope("mixer-proj"):
+                proj = nn.Dense(z.conv_dim + z.key_dim, dtype=self.dtype,
+                                use_bias=False, name="in_proj_qkvf")(h)
+                bg = nn.Dense(2 * H, dtype=self.dtype,
+                              use_bias=False, name="in_proj_bg")(h)
+            qkv, f = jnp.split(proj, [z.conv_dim], axis=-1)
+            qkv = causal_conv_silu(
+                qkv, self.param("conv_kernel", nn.initializers.lecun_normal(),
+                                (z.d_conv, z.conv_dim), f32))
+            q, k, v = jnp.split(qkv, [z.key_dim, 2 * z.key_dim], axis=-1)
+            b, gate = jnp.split(bg, 2, axis=-1)
+            scale = self.param("norm_scale", nn.initializers.ones,
+                               (z.d_v,), f32)
+            xs = (grouped(q, z.d_k), grouped(k, z.d_k), grouped(v, z.d_v),
+                  grouped(f, z.d_k), grouped(b), grouped(gate),
+                  self.param("A_log", _a_log_init, (H,)).reshape(-1, hg),
+                  self.param("dt_bias", _dt_bias_init, (z.key_dim,)
+                             ).reshape(-1, hg, z.d_k))
+            if hg == H:
+                y = group(jax.tree.map(lambda x: x[0], xs))[None]
+            else:
+                # a group at a time, rematerialised: one group's float32
+                # copies and chunk matrices are live, not every head's
+                y = jax.lax.map(jax.checkpoint(group), xs)
+            y = jnp.moveaxis(y, 0, 2).reshape(lead + (z.value_dim,))
+            with named_scope("mixer-proj"):
+                return nn.Dense(self.d_model, dtype=self.dtype,
+                                use_bias=False, name="out_proj")(y)
+
+
+def norm_leading(x, scale, eps: float, width: int):
+    """RMSNorm over the first ``width`` lanes of the last axis of ``x``
+    times ``scale`` (width,), the other lanes passed through, in float32
+    and without cutting the head: the statistics are a masked mean."""
+    x32 = x.astype(jnp.float32)
+    lead = jnp.arange(x.shape[-1]) < width
+    mean = jnp.sum(jnp.where(lead, jnp.square(x32), 0.0), axis=-1,
+                   keepdims=True) / width
+    gain = jnp.concatenate([scale.astype(jnp.float32), jnp.ones(
+        (x.shape[-1] - width,), jnp.float32)])
+    return jnp.where(lead, x32 * jax.lax.rsqrt(mean + eps) * gain, x32)
+
+
+class MLAMixer(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434) for
+    training, as the ``bailing_hybrid`` family lays it out (an attention
+    row with an :class:`MLASpec`): per head ``[q_nope | q_rope] =
+    query(h)``; ``[c | k_rope] = kv_a(h)``, ``c <- kv_norm(c)``
+    (RMSNorm over the latent), per head ``[k_nope | v] = kv_b(c)``; with
+    ``qk_norm`` an RMSNorm over the nope part of every query and key head
+    (``q_norm``, ``k_norm``: one scale a channel, shared by the heads);
+    rotary positions 0..S-1 on ``q_rope`` and on ``k_rope``, ONE key
+    vector a token that every head shares; ``k = [k_nope | k_rope]``;
+    causal softmax of ``q k^T / sqrt(d_nope + d_rope)`` over values of
+    ``d_v`` (the ``attention_fn``, whose kernels take a value width of
+    their own, else the dense path); with ``head_gate`` times
+    ``sigmoid(gate(h))``, one number a head; ``out``.  No cache and no
+    absorbed form: training and whole-sequence evaluation."""
+
+    d_model: int
+    n_heads: int
+    mla: MLASpec
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+    qk_norm: bool = False
+    head_gate: bool = False
+    norm_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, h, mask=None):
+        z, H = self.mla, self.n_heads
+        f32 = jnp.float32
+        ones = nn.initializers.ones
+        with named_scope("mla-mixer"):
+            with named_scope("mixer-proj"):
+                q = nn.DenseGeneral((H, z.d_qk), dtype=self.dtype,
+                                    use_bias=False, name="query")(h)
+                kva = nn.Dense(z.kv_rank + z.d_rope, dtype=self.dtype,
+                               use_bias=False, name="kv_a")(h)
+                gate = None if not self.head_gate else nn.Dense(
+                    H, dtype=self.dtype, use_bias=False, name="gate")(h)
+            with named_scope("attn-rope"):
+                latent = norm_leading(
+                    kva, self.param("kv_norm", ones, (z.kv_rank,), f32),
+                    self.norm_eps, z.kv_rank)
+                c = latent[..., :z.kv_rank].astype(self.dtype)
+                k_rope = latent[..., None, z.kv_rank:].astype(self.dtype)
+            with named_scope("mixer-proj"):
+                kv = nn.DenseGeneral((H, z.d_nope + z.d_v), dtype=self.dtype,
+                                     use_bias=False, name="kv_b")(c)
+            k_nope, v = jnp.split(kv, [z.d_nope], axis=-1)
+            with named_scope("attn-rope"):
+                if self.qk_norm:
+                    q = norm_leading(
+                        q, self.param("q_norm", ones, (z.d_nope,), f32),
+                        self.norm_eps, z.d_nope).astype(self.dtype)
+                    k_nope = norm_leading(
+                        k_nope, self.param("k_norm", ones, (z.d_nope,), f32),
+                        self.norm_eps, z.d_nope).astype(self.dtype)
+                pos = jnp.arange(h.shape[1])
+                q = rotate_partial(
+                    q, pos, z.d_rope, z.rope_theta, None,
+                    (z.d_nope, z.interleave)).astype(self.dtype)
+                k_rope = rotate_partial(
+                    k_rope, pos, z.d_rope, z.rope_theta, None,
+                    (0, z.interleave)).astype(self.dtype)
+                k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                    k_rope, k_nope.shape[:3] + (z.d_rope,))], axis=-1)
+            if self.attention_fn is not None:
+                if getattr(self.attention_fn, "scale", None) is not None:
+                    raise ValueError(
+                        "an mla row scales its scores by 1/sqrt(d_nope + "
+                        "d_rope): build the attention_fn without a scale")
+                out = self.attention_fn(q, k, v, mask)
+            else:
+                logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (
+                    1.0 / np.sqrt(z.d_qk))
+                if mask is not None:
+                    logits = jnp.where(mask, logits,
+                                       jnp.finfo(jnp.float32).min)
+                weights = nn.softmax(logits.astype(f32)).astype(self.dtype)
+                out = jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+            if gate is not None:
+                with named_scope("mixer-gate"):
+                    out = out * jax.nn.sigmoid(
+                        gate.astype(f32))[..., None].astype(out.dtype)
+            with named_scope("mixer-proj"):
+                return nn.DenseGeneral(
+                    self.d_model, axis=(-2, -1), dtype=self.dtype,
+                    name="out", use_bias=False)(out)
+
+
 class Block(nn.Module):
     """One layer, built from its row of the block table: ``x + rm *
     mixer(norm(x))`` where the row has a mixer, then ``x + rm *
@@ -1057,7 +1274,29 @@ class Block(nn.Module):
                         row.residual_multiplier, branch.dtype)
                 return x + branch
 
-        if row.mixer == "attention":
+        def no_cache(what):
+            if self.decode or self.paged is not None:
+                raise ValueError(
+                    f"{what}: incremental decoding and the paged KV cache "
+                    f"are built for plain attention layers only")
+
+        if row.mixer == "attention" and row.mla is not None:
+            no_cache("an mla row keeps no latent cache and has no absorbed "
+                     "decode")
+            h = normed(x)
+            with named_scope("attn-mixer"):
+                branch = MLAMixer(
+                    self.d_model, row.n_heads, row.mla, self.dtype,
+                    self.attention_fn, qk_norm=row.qk_norm,
+                    head_gate=row.head_gate, norm_eps=row.norm_eps)(h, mask)
+            x = residual(x, branch)
+        elif row.mixer == "kda":
+            no_cache("a kda layer keeps no recurrent state between calls "
+                     "(its matrix state a head and its convolution's "
+                     "window)")
+            x = residual(x, KDAMixer(
+                self.d_model, row.kda, row.norm_eps, self.dtype)(normed(x)))
+        elif row.mixer == "attention":
             h = normed(x)
             with named_scope(
                     "attn-mixer" if row.window is None else "attn-window"):
@@ -1442,7 +1681,8 @@ def remat_kept(table: BlockTable, d_model: int, tokens: int, itemsize: int,
     for row in table.layers:
         heads = {"attention": row, "cca": row.cca}.get(row.mixer)
         if flash and heads is not None:
-            d_head = heads.d_head or d_model // heads.n_heads
+            d_head = row.mla.d_v if row.mla is not None else (
+                heads.d_head or d_model // heads.n_heads)
             kept["flash_layers"] += 1
             kept[f"{flash_names}_bytes"] += tokens * heads.n_heads * (
                 d_head * itemsize + 4)

@@ -72,14 +72,22 @@ _ALLREDUCE_STAGE = re.compile(r"^grad-stage\d+$")
 #: the chunked gated delta rule, forward and backward (``gdn-scan``,
 #: ``ops/gated_delta.py``); an attention row's QK-norm and rotary
 #: positions (``attn-rope``; the row's output gate is ``mixer-gate``
-#: below).  The innermost name of THIS tuple (or of an allreduce stage)
-#: on an op's path is its region.
+#: below); a Kimi-Delta-Attention mixer from its projections to its
+#: output projection (``kda-mixer``: its three convolutions are
+#: ``ssm-conv``, the same kernels) and, nested in it, the chunked delta
+#: rule under a decay a key channel, forward and backward (``kda-scan``,
+#: ``ops/kda.py``); a latent-attention (MLA) row from its query and
+#: latent projections to its output projection (``mla-mixer``, inside the
+#: row's ``attn-mixer``: the flash regions and ``attn-rope`` nest in it).
+#: The innermost name of THIS tuple (or of an allreduce stage) on an op's
+#: path is its region.
 KERNEL_REGIONS = (
     "flash-fwd", "flash-bwd-dq", "flash-bwd-dkv", "fused-ce",
     "paged-decode-attn", "mamba-mixer", "ssd-scan", "ssm-conv",
     "moe-layer", "moe-route", "moe-dispatch", "moe-experts", "moe-shared",
     "cca-mixer", "cca-conv", "cca-rope",
     "gdn-mixer", "gdn-scan", "attn-rope",
+    "kda-mixer", "kda-scan", "mla-mixer",
 )
 
 #: The model's parts (``models/transformer.py``), so that every op of

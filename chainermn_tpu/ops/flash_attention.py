@@ -331,8 +331,11 @@ def _lanes(d: int) -> int:
 
 
 def flash_vmem_bytes(block_q: int, block_k: int, D: int, itemsize: int,
-                     which: str = "fwd", segmented: bool = False) -> int:
-    """VMEM bytes one grid program of the flash kernels holds.
+                     which: str = "fwd", segmented: bool = False,
+                     D_v: Optional[int] = None) -> int:
+    """VMEM bytes one grid program of the flash kernels holds, for heads
+    whose queries and keys are ``D`` wide and whose values — and so the
+    output, its cotangent and ``dv`` — are ``D_v`` wide (None: ``D``).
 
     Streamed blocks and outputs count twice (the pipeline fetches tile
     ``t+1`` while ``t`` computes), scratch once, a ``(block, 1)`` column
@@ -347,20 +350,23 @@ def flash_vmem_bytes(block_q: int, block_k: int, D: int, itemsize: int,
     fails to compile inside the default there.  ``which``: ``"fwd"``, or
     ``"bwd"`` for the larger of the dq and dk/dv kernels (two
     ``pallas_call``s at the same blocks)."""
+    D_v = D if D_v is None else D_v
     qd = block_q * _lanes(D)
     kd = block_k * _lanes(D)
+    od = block_q * _lanes(D_v)          # o, do
+    vd = block_k * _lanes(D_v)          # v, dv
     q_col = block_q * 128 * 4
     seg = 2 * (q_col + block_k * 128 * 4) if segmented else 0
     tiles = (3 if segmented else 2) * block_q * block_k * 4
     if which == "fwd":
-        streamed = 2 * (qd + 2 * kd) * itemsize + seg
-        outputs = 2 * (qd * itemsize + q_col)
-        scratch = qd * 4 + 2 * q_col
+        streamed = 2 * (qd + kd + vd) * itemsize + seg
+        outputs = 2 * (od * itemsize + q_col)
+        scratch = od * 4 + 2 * q_col
         return streamed + outputs + scratch + tiles
     # q, k, v, do and the lse and delta columns stream in both kernels.
-    streamed = 2 * (2 * qd + 2 * kd) * itemsize + 2 * 2 * q_col + seg
+    streamed = 2 * (qd + od + kd + vd) * itemsize + 2 * 2 * q_col + seg
     dq = streamed + 2 * qd * itemsize + qd * 4
-    dkv = streamed + 2 * 2 * kd * itemsize + 2 * kd * 4
+    dkv = streamed + 2 * (kd + vd) * itemsize + (kd + vd) * 4
     return max(dq, dkv) + tiles
 
 
@@ -403,7 +409,9 @@ _KERNEL_STATICS = ("scale", "causal", "block_q", "block_k", "interpret",
 @functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
 def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
                   q_seg=None, kv_seg=None, window=None):
-    """(BH, S, D) flash attention forward; returns (o, lse).
+    """(BH, S, D) flash attention forward; returns (o, lse).  ``v`` may
+    be narrower or wider than ``q`` and ``k`` (BHk, Sk, D_v): ``o`` is
+    then (BH, Sq, D_v).
 
     ``k``/``v`` may carry FEWER head rows than ``q`` (GQA/MQA): with
     ``G = BHq // BHk``, q row ``b`` attends to kv row ``b // G`` — pure
@@ -413,7 +421,7 @@ def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
     ``q_seg``/``kv_seg``: optional (BH, S, 1) int32 segment ids for packed
     sequences — attention is masked to segment-id equality."""
     BH, Sq, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[2]
     G = _kv_group(BH, k.shape[0])
     n_q = Sq // block_q
     kv_range, _ = _live_ranges(Sq, Sk, block_q, block_k, causal, window)
@@ -426,7 +434,7 @@ def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
         block_q=block_q, block_k=block_k, kv_range=kv_range, window=window,
     )
     scratch = [
-        pltpu.VMEM((block_q, D), jnp.float32),
+        pltpu.VMEM((block_q, Dv), jnp.float32),
         pltpu.VMEM((block_q, 1), jnp.float32),
         pltpu.VMEM((block_q, 1), jnp.float32),
     ]
@@ -434,7 +442,7 @@ def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, D),
                      lambda b, i, j: (b // G, kv_j(i, j), 0)),
-        pl.BlockSpec((1, block_k, D),
+        pl.BlockSpec((1, block_k, Dv),
                      lambda b, i, j: (b // G, kv_j(i, j), 0)),
     ]
     args = [q, k, v]
@@ -450,18 +458,19 @@ def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
         return pl.pallas_call(
             kernel,
             out_shape=[
-                jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
+                jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype),
                 jax.ShapeDtypeStruct((BH, Sq, 1), jnp.float32),
             ],
             grid=grid,
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
                 pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
             ],
             scratch_shapes=scratch,
             compiler_params=_compiler_params(flash_vmem_bytes(
-                block_q, block_k, D, q.dtype.itemsize, "fwd", segmented)),
+                block_q, block_k, D, q.dtype.itemsize, "fwd", segmented,
+                Dv)),
             interpret=interpret,
             name="flash-fwd",
         )(*args)
@@ -591,7 +600,7 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
     into the per-row residual: ds = p·(dp − (δ − dlse)).
     """
     BH, Sq, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[2]
     BHk = k.shape[0]
     G = _kv_group(BH, BHk)
     segmented = q_seg is not None
@@ -605,15 +614,17 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
     n_q = Sq // block_q
     n_k = Sk // block_k
     params = _compiler_params(flash_vmem_bytes(
-        block_q, block_k, D, q.dtype.itemsize, "bwd", segmented))
+        block_q, block_k, D, q.dtype.itemsize, "bwd", segmented, Dv))
     kv_range, q_range = _live_ranges(Sq, Sk, block_q, block_k, causal,
                                      window)
     kv_j, n_j = _streamed_axis(kv_range, n_q, n_k, causal, window)
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, D),
-                          lambda b, i, j: (b // G, kv_j(i, j), 0))
+    q_spec, do_spec = (pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+                       for d in (D, Dv))
+    k_spec, v_spec = (pl.BlockSpec((1, block_k, d),
+                                   lambda b, i, j: (b // G, kv_j(i, j), 0))
+                      for d in (D, Dv))
     r_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    dq_in = [q_spec, k_spec, k_spec, q_spec, r_spec, r_spec]
+    dq_in = [q_spec, k_spec, v_spec, do_spec, r_spec, r_spec]
     dq_args = [q, k, v, do, lse, delta]
     if segmented:
         dq_in += [
@@ -655,10 +666,13 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
     def q_rows(b, j, i):
         return (b * G + i // n_i, q_i(j, i % n_i), 0)
 
-    qT_spec = pl.BlockSpec((1, block_q, D), q_rows)
-    kT_spec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
+    qT_spec, doT_spec = (pl.BlockSpec((1, block_q, d), q_rows)
+                         for d in (D, Dv))
+    kT_spec, vT_spec = (pl.BlockSpec((1, block_k, d),
+                                     lambda b, j, i: (b, j, 0))
+                        for d in (D, Dv))
     rT_spec = pl.BlockSpec((1, block_q, 1), q_rows)
-    dkv_in = [qT_spec, kT_spec, kT_spec, qT_spec, rT_spec, rT_spec]
+    dkv_in = [qT_spec, kT_spec, vT_spec, doT_spec, rT_spec, rT_spec]
     dkv_args = [q, k, v, do, lse, delta]
     if segmented:
         dkv_in += [
@@ -675,17 +689,14 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
             ),
             out_shape=[
                 jax.ShapeDtypeStruct((BHk, Sk, D), k.dtype),
-                jax.ShapeDtypeStruct((BHk, Sk, D), v.dtype),
+                jax.ShapeDtypeStruct((BHk, Sk, Dv), v.dtype),
             ],
             grid=(BHk, n_k, G * n_i),
             in_specs=dkv_in,
-            out_specs=[
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            ],
+            out_specs=[kT_spec, vT_spec],
             scratch_shapes=[
                 pltpu.VMEM((block_k, D), jnp.float32),
-                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((block_k, Dv), jnp.float32),
             ],
             compiler_params=params,
             interpret=interpret,
@@ -956,11 +967,13 @@ def _sublane(dtype) -> int:
 
 def auto_block_size(S: int, D: int, dtype, which: str = "fwd",
                     segmented: bool = False,
-                    window: Optional[int] = None) -> int:
+                    window: Optional[int] = None,
+                    D_v: Optional[int] = None) -> int:
     """The STATIC default block edge along a length-``S`` axis: the
     largest multiple of 128 that divides ``S`` and whose square tile fits
     Mosaic's default scoped VMEM (:data:`VMEM_SCOPED_DEFAULT`) by
-    :func:`flash_vmem_bytes` for this head dim, dtype, kernel (``which``:
+    :func:`flash_vmem_bytes` for this width of queries and keys ``D``
+    and of values ``D_v`` (None: ``D``), dtype, kernel (``which``:
     ``"fwd"`` or ``"bwd"``) and segment mask: 1024 at D=128 in bf16, 512
     at D=256 in fp32 or with segment ids.  A grid step has a fixed cost
     and the kernels come near the MXU only at large tiles, so few full
@@ -994,7 +1007,7 @@ def auto_block_size(S: int, D: int, dtype, which: str = "fwd",
         edges = [b for b in edges if b <= max(widest, edges[0])]
     itemsize = jnp.dtype(dtype).itemsize
     fits = [b for b in edges
-            if flash_vmem_bytes(b, b, D, itemsize, which, segmented)
+            if flash_vmem_bytes(b, b, D, itemsize, which, segmented, D_v)
             <= VMEM_SCOPED_DEFAULT]
     return max(fits, default=edges[0])
 
@@ -1035,7 +1048,13 @@ def flash_attention(
     block_k_bwd: Optional[int] = None,
 ):
     """Flash attention over (B, S, H, D) tensors (layout matches the
-    transformer layers in ``chainermn_tpu.models``).
+    transformer layers in ``chainermn_tpu.models``).  ``q`` and ``k`` are
+    ``D`` wide, the width the scores are taken over; ``v`` is (B, Sk,
+    H_kv, D_v) with a ``D_v`` of its own (latent attention scores over
+    192 and sums values of 128), and the result (B, Sq, H, D_v): the
+    kernels stream ``v``, ``o``, ``do`` and ``dv`` at ``D_v`` and make
+    ``p v`` and ``do v^T`` at that width, nothing is padded.  The default
+    ``scale`` is ``1/sqrt(D)`` either way.
 
     ``window``: optional sliding-window size (Mistral-style local
     attention, causal only): query ``i`` attends keys ``[i - window + 1,
@@ -1047,7 +1066,7 @@ def flash_attention(
     that is entered and skipped costs 0.24 µs on a v5e, and 225 of a head
     row's 256 were at S = 16,384 under a window of 1024).
 
-    Uses the Pallas kernel when shapes allow (D ≤ 256, S divisible by the
+    Uses the Pallas kernel when shapes allow (D, D_v ≤ 256, S divisible by the
     block sizes after clamping); otherwise falls back to XLA attention.
     The compiled path handles any D ≤ 256 (Mosaic pads the lane dim;
     verified on a v5e-class chip against the XLA oracle at D ∈ {16..128}
@@ -1083,6 +1102,11 @@ def flash_attention(
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     Hk = k.shape[2]
+    Dv = v.shape[3]
+    if k.shape[3] != D:
+        raise ValueError(
+            f"queries ({D}) and keys ({k.shape[3]}) are scored against "
+            f"each other: one width")
     if H % Hk or v.shape[2] != Hk:
         raise ValueError(
             f"kv heads ({Hk}, v {v.shape[2]}) must be equal and divide "
@@ -1111,7 +1135,8 @@ def flash_attention(
     pinned = block_q is not None or block_k is not None
 
     def static(S, which):
-        return auto_block_size(S, D, q.dtype, which, segmented, window)
+        return auto_block_size(S, D, q.dtype, which, segmented, window,
+                               None if Dv == D else Dv)
 
     if not pinned and block_q_bwd is None and block_k_bwd is None:
         # Nothing pinned: the backward gets the rule's own
@@ -1136,7 +1161,7 @@ def flash_attention(
     # Wide heads: Mosaic pads the lane dim, so any D ≤ 256 compiles
     # (verified on-chip at D ∈ {160, 192, 256} against the oracle);
     # beyond 256 the VMEM block economics favor the XLA fallback.
-    d_ok = D <= 256
+    d_ok = max(D, Dv) <= 256
     usable = (
         d_ok
         and Sq % block_q == 0
@@ -1146,7 +1171,8 @@ def flash_attention(
     if not usable:
         warnings.warn(
             f"flash_attention: the Pallas kernel does not cover Sq={Sq}, "
-            f"Sk={Sk}, D={D} at blocks ({block_q}, {block_k}); using XLA "
+            f"Sk={Sk}, D={D}, D_v={Dv} at blocks ({block_q}, {block_k}); "
+            "using XLA "
             "attention, which materializes the Sq x Sk logits",
             stacklevel=2,
         )
@@ -1180,7 +1206,7 @@ def flash_attention(
     # exactly b // (H // Hk) (see _kv_group).
     qt = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
     kt = k.transpose(0, 2, 1, 3).reshape(B * Hk, Sk, D)
-    vt = v.transpose(0, 2, 1, 3).reshape(B * Hk, Sk, D)
+    vt = v.transpose(0, 2, 1, 3).reshape(B * Hk, Sk, Dv)
     if q_segment_ids is not None:
         qs = seg_to_bh(q_segment_ids, H)
         ks = seg_to_bh(kv_segment_ids, Hk)
@@ -1193,7 +1219,7 @@ def flash_attention(
             qt, kt, vt, scale, causal, block_q, block_k, interpret, window,
             block_q_bwd, block_k_bwd,
         )
-    return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+    return out.reshape(B, H, Sq, Dv).transpose(0, 2, 1, 3)
 
 
 def flash_block_plan(S: int, D: int, dtype, interpret: bool):

@@ -99,7 +99,26 @@ def rows_bound(pairs: int, count: int, n_experts: int) -> int:
     return min(pairs, -(-BOUND_OVER_EXPECTED * pairs * count // n_experts))
 
 
-def route(h, w_router, bias, *, top_k: int, scaling: float = 1.0):
+def keep_groups(biased, n_group: int, topk_group: int):
+    """``biased`` (T, E) with the experts outside each token's
+    ``topk_group`` best groups at ``-inf``.  The ``E`` experts are
+    ``n_group`` equal runs; a group's score is the sum of its two largest
+    entries; the best groups are kept (ties to the lower index)."""
+    T, E = biased.shape
+    if E % n_group or not 1 <= topk_group <= n_group or E // n_group < 2:
+        raise ValueError(
+            f"{n_group} groups, {topk_group} kept, of {E} experts: the "
+            f"groups are equal runs of at least two experts")
+    best_two, _ = lax.top_k(biased.reshape(T, n_group, E // n_group), 2)
+    _, kept = lax.top_k(jnp.sum(best_two, axis=-1), topk_group)
+    mask = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None, :],
+                   axis=1)                              # (T, n_group)
+    return jnp.where(jnp.repeat(mask, E // n_group, axis=1), biased,
+                     -jnp.inf)
+
+
+def route(h, w_router, bias, *, top_k: int, scaling: float = 1.0,
+          n_group: int = 0, topk_group: int = 0):
     """Sigmoid top-k router (an ``ExpertsSpec`` whose ``router`` is
     ``"sigmoid"``; ``"mlp_softmax"`` is :func:`route_mlp_softmax`),
     float32 throughout.
@@ -109,13 +128,19 @@ def route(h, w_router, bias, *, top_k: int, scaling: float = 1.0):
     gradient, and the weights do not see it).  Returns ``(chosen, weight)``,
     (T, top_k) int32 and float32: the ``top_k`` experts with the largest
     ``sigmoid(h w) + bias`` (ties to the lower index) and ``scaling *
-    s[chosen]`` over the chosen scores' sum."""
+    s[chosen]`` over the chosen scores' sum.  With ``n_group`` > 0 the
+    choice is group-limited (DeepSeek-V3, arXiv:2412.19437): only the
+    experts of a token's ``topk_group`` best groups stand
+    (:func:`keep_groups`, by the biased scores); ``topk_group ==
+    n_group`` keeps every group and is the choice without groups."""
     f32 = jnp.float32
     scores = jax.nn.sigmoid(jnp.dot(
         h.astype(f32), w_router.astype(f32),
         precision=lax.Precision.HIGHEST))
-    _, chosen = lax.top_k(scores + lax.stop_gradient(bias.astype(f32)),
-                          top_k)
+    biased = scores + lax.stop_gradient(bias.astype(f32))
+    if n_group:
+        biased = keep_groups(biased, n_group, topk_group)
+    _, chosen = lax.top_k(biased, top_k)
     chosen = checkpoint_name(chosen.astype(jnp.int32), ROUTER_CHOICE)
     weight = jnp.take_along_axis(scores, chosen, axis=-1)
     weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
@@ -425,14 +450,23 @@ combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def load_stats(chosen, n_experts: int, held: Tuple[int, int],
-               tile_rows: int = TILE_ROWS) -> dict:
+               tile_rows: int = TILE_ROWS, n_group: int = 0) -> dict:
     """What one batch's routing did, from the chosen experts on the host
     (``chosen``: (T, top_k) integers): pairs in all and on the held
     experts, the largest held expert's load over the mean load of an
     expert, the tiles the held rows take, and the pairs past the buffer's
-    bound."""
+    bound; with ``n_group`` router groups also ``held_group_token_share``,
+    the share of tokens that chose an expert of a group the held experts
+    lie in (such a token kept that group: only then can it reach them)."""
     chosen = np.asarray(chosen)
     first, count = held
+    groups = {}
+    if n_group:
+        size = n_experts // n_group
+        group = chosen // size
+        near = (group >= first // size) & (
+            group <= (first + count - 1) // size)
+        groups["held_group_token_share"] = float(near.any(axis=-1).mean())
     pairs = chosen.size
     load = np.bincount(chosen.reshape(-1), minlength=n_experts)
     mine = load[first:first + count]
@@ -448,4 +482,5 @@ def load_stats(chosen, n_experts: int, held: Tuple[int, int],
         "live_tiles": live, "buffer_tiles": int(n_tiles),
         "live_rows_share": live / n_tiles,
         "pairs_past_bound": int(np.maximum(mine - room, 0).sum()),
+        **groups,
     }

@@ -1,0 +1,142 @@
+"""The ``ling3flash-train-1chip`` cell's Kimi-Delta-Attention mixer and its
+latent-attention row, forward and backward under remat as in the step,
+compiled for a described TPU v5e (``tests/_tpu_compile.py``), without the
+chip.
+"""
+
+import importlib
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+
+from _tpu_compile import one_chip  # noqa: F401
+
+fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
+
+
+def _copies(text):
+    """``(bytes, line)`` of every standalone copy or transpose."""
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= (\w+)\[([\d,]*)\]\S* (?:copy|transpose)\(", line)
+        if m:
+            found.append(((2 if m.group(1) == "bf16" else 4) * math.prod(
+                int(d) for d in m.group(2).split(",") if d), line))
+    return found
+
+
+def test_kda_mixer_compiles_at_the_cells_shape(one_chip, monkeypatch):
+    """One KDA mixer at the cell's shape (1 x 16,384 tokens, 32 heads of
+    128), forward and backward under remat as in the step: the
+    convolution's three Mosaic calls and the rule as XLA loops (a group of
+    4 heads at a time under ``lax.map``, a chunk a ``scan`` step).  With
+    the float32 side inside the groups no float32 copy of every head's
+    ``q``, ``k``, ``g`` or ``o`` stands in the layer: 2.6 GB of
+    temporaries where every head at once was 5.5."""
+    from chainermn_tpu.models.block_table import KDASpec
+    from chainermn_tpu.models.transformer import KDAMixer
+    from chainermn_tpu.ops.kda import heads_a_group
+
+    ssd = importlib.import_module("chainermn_tpu.ops.ssd")
+    monkeypatch.setattr(ssd, "default_interpret", lambda: False)
+    assert heads_a_group(16384, 32) == 4
+    d_model = 2560
+    mixer = KDAMixer(d_model, KDASpec(32, 128, 128), 1e-6, jnp.bfloat16)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: mixer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 256, d_model),
+                                             jnp.bfloat16))))
+    h = jax.ShapeDtypeStruct((1, 16384, d_model), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(params, h):
+        layer = jax.checkpoint(lambda p, h: h + mixer.apply(p, h))
+        return jnp.sum(layer(params, h).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, h).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3 and " while(" in text
+    assert "kda-scan" in text and "kda-mixer" in text
+    # every head's float32 copy is 268 MB: none stands outside the groups
+    whole = 16384 * 32 * 128 * 4
+    assert not [line for size, line in _copies(text)
+                if size >= whole and "f32[" in line]
+    # read: 2.61 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
+
+
+def test_mla_row_compiles_at_the_cells_shape(one_chip, monkeypatch):
+    """The latent-attention row with its expert FFN at the cell's shape
+    (32 heads scoring over 192 and summing values of 128, 8 of 512 gated
+    experts of 768 held in 8 groups of which 4 stay), forward and backward
+    under remat with the model's policy as in the step: the three flash
+    calls (the forward ONCE) at 1024-edge tiles, which the rule picks for
+    D = 192, D_v = 128 inside the default scoped VMEM, and nine grouped
+    calls of the experts; no transposing copy of an activation's size
+    stands around the flash calls."""
+    from chainermn_tpu.models.block_table import (
+        ExpertsSpec,
+        LayerSpec,
+        MLASpec,
+    )
+    from chainermn_tpu.models.transformer import Block, remat_policy
+    from chainermn_tpu.observability import device_trace
+    from chainermn_tpu.ops import make_flash_attention_fn
+
+    gm = importlib.import_module("chainermn_tpu.ops.grouped_matmul")
+    for module in (fa, gm):
+        monkeypatch.setattr(module, "default_interpret", lambda: False)
+    for which in ("fwd", "bwd"):
+        assert fa.auto_block_size(16384, 192, jnp.bfloat16, which,
+                                  D_v=128) == 1024
+        assert fa.flash_vmem_bytes(1024, 1024, 192, 2, which,
+                                   D_v=128) <= fa.VMEM_SCOPED_DEFAULT
+    row = LayerSpec(
+        mixer="attention", norm="rmsnorm", ffn="experts", n_heads=32,
+        qk_norm=True, head_gate=True,
+        mla=MLASpec(512, 128, 64, 128, 6e6, True),
+        experts=ExpertsSpec(
+            n_experts=512, top_k=8, d_expert=768, d_shared=768, held=(0, 8),
+            scaling=2.5, router="sigmoid", expert="swiglu", n_group=8,
+            topk_group=4))
+    layer = Block(2560, row, jnp.bfloat16,
+                  make_flash_attention_fn(causal=True))
+
+    def arr(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    x = arr((1, 16384, 2560), jnp.bfloat16)
+    params = jax.tree.map(
+        lambda a: arr(a.shape, a.dtype),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 256, 2560), jnp.bfloat16))))
+
+    def loss(params, x):
+        fn = jax.checkpoint(lambda p, x: layer.apply(p, x),
+                            policy=remat_policy())
+        return jnp.sum(fn(params, x).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    text = compiled.as_text()
+    calls = {name: len(re.findall(
+        r'tpu_custom_call[^\n]*' + name + r'\b', text))
+        for name in ("flash-fwd", "flash-bwd-dq", "flash-bwd-dkv")}
+    assert calls == {"flash-fwd": 1, "flash-bwd-dq": 1, "flash-bwd-dkv": 1}
+    assert text.count("tpu_custom_call") == 3 + 9
+    tiles = device_trace.scope_table(text).tiles_within["mla-mixer"]
+    for region in ("flash-fwd", "flash-bwd-dq", "flash-bwd-dkv"):
+        (census,) = tiles[region]
+        assert (census["block_q"], census["block_k"]) == (1024, 1024)
+        assert (census["live"], census["visited"]) == (136, 256)
+    # q and k are (1, 16384, 32, 192) bfloat16, v and o 128 wide: no copy
+    # or transpose of that size under the latent row's scope
+    heads = 16384 * 32 * 128 * 2
+    assert not [line for size, line in _copies(text)
+                if size >= heads and "mla-mixer" in line]
+    # read: 2.71 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.3e9
