@@ -6,8 +6,8 @@ geometry, for the two Mosaic kernels (``ops.gated_delta.gated_delta_rule``:
 37), which lives on here as the comparison: the solve by substitution on
 16-row blocks joined pairwise, a ``lax.scan`` step a chunk, the value
 heads in rematerialised groups under ``lax.map``, autodiff's backward
-(the solve itself is ``ops.kda.unit_lower_inverse``, which the
-Kimi-Delta-Attention rule runs in the program).
+(the solve itself is ``kda_probe.unit_lower_inverse``, which the
+Kimi-Delta-Attention rule's XLA form runs there too).
 
 Each is compiled at ``(2, 8192, 16 key / 32 value heads of 128, chunk
 64)`` in bfloat16, forward and vjp apart, the operands' layouts left to
@@ -45,7 +45,7 @@ from jax.sharding import SingleDeviceSharding
 
 from chainermn_tpu.observability.spans import named_scope
 from chainermn_tpu.ops import gated_delta
-from chainermn_tpu.ops.kda import unit_lower_inverse
+from kda_probe import unit_lower_inverse
 from ssm_conv_probe import device_ms
 
 _HIGHEST = lax.Precision.HIGHEST

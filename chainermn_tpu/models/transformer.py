@@ -1050,15 +1050,13 @@ class KDAMixer(nn.Module):
     with ``|x| = sqrt(sum x^2 + 1e-6)``; ``beta = sigmoid(b)`` a head; the log-decay
     a head and KEY CHANNEL ``g = lower_bound * sigmoid(exp(A_log) * (f +
     dt_bias))``, float32, in ``(lower_bound, 0)``; the chunked rule
-    (:func:`chainermn_tpu.ops.kda.kda_rule`); per head ``RMSNorm(o) *
-    sigmoid(a)`` with one plain scale a channel of a head and ONE gate a
-    head; ``out_proj``.  Every sequence starts from a zero state.  From
-    the convolution's output to the gated norm the heads are worked in
-    groups (:func:`chainermn_tpu.ops.kda.heads_a_group`) under
-    ``lax.map``, each rematerialised: the float32 side and the rule's
-    chunk matrices of one group are live at a time (at 32 heads of 16,384
-    tokens, all at once is 5.5 GB of temporaries a layer, 4 GB of it the
-    float32 copies of every head's ``q``, ``k``, ``g`` and ``o``)."""
+    (:func:`chainermn_tpu.ops.kda.kda_rule`: two Mosaic kernels whose
+    grid walks the heads); per head ``RMSNorm(o) * sigmoid(a)`` with one
+    plain scale a channel of a head and ONE gate a head; ``out_proj``.
+    Every sequence starts from a zero state.  The gate side is float32
+    arithmetic whose results are ``q``, ``k`` and ``y`` in the
+    activations' type: of every head's float32 numbers only ``g`` (and
+    its cotangent) stands as an array."""
 
     d_model: int
     kda: KDASpec
@@ -1067,41 +1065,12 @@ class KDAMixer(nn.Module):
 
     @nn.compact
     def __call__(self, h):
-        from chainermn_tpu.ops.kda import heads_a_group, kda_rule
+        from chainermn_tpu.ops.kda import kda_rule
         from chainermn_tpu.ops.ssd import causal_conv_silu
 
         z = self.kda
         f32 = jnp.float32
         lead, H = h.shape[:2], z.n_heads
-        hg = heads_a_group(lead[0] * lead[1], H)
-
-        def grouped(x, width=None):
-            """(b, S, H [x width]) -> (H / hg, b, S, hg [, width])"""
-            tail = () if width is None else (width,)
-            return jnp.moveaxis(
-                x.reshape(x.shape[:2] + (H // hg, hg) + tail), 2, 0)
-
-        def group(xs):
-            """The float32 side and the rule of ``hg`` heads."""
-            q, k, v, f, b, gate, a_log, dt_bias = xs
-            with named_scope("mixer-gate"):
-                q, k = (x * jax.lax.rsqrt(jnp.sum(
-                    jnp.square(x), axis=-1, keepdims=True) + 1e-6)
-                    for x in (q.astype(f32), k.astype(f32)))
-                q = (q * (1.0 / np.sqrt(z.d_k))).astype(self.dtype)
-                k = k.astype(self.dtype)
-                beta = jax.nn.sigmoid(b.astype(f32))
-                g = z.lower_bound * jax.nn.sigmoid(
-                    jnp.exp(a_log)[:, None] * (f.astype(f32) + dt_bias))
-            o = kda_rule(q, k, v, g, beta, chunk=z.chunk)
-            with named_scope("mixer-gate"):
-                o = o.astype(f32)
-                o = o * jax.lax.rsqrt(
-                    jnp.mean(jnp.square(o), axis=-1, keepdims=True)
-                    + self.norm_eps)
-                y = o * scale * jax.nn.sigmoid(gate.astype(f32))[..., None]
-                return y.astype(self.dtype)
-
         with named_scope("kda-mixer"):
             with named_scope("mixer-proj"):
                 proj = nn.Dense(z.conv_dim + z.key_dim, dtype=self.dtype,
@@ -1116,18 +1085,29 @@ class KDAMixer(nn.Module):
             b, gate = jnp.split(bg, 2, axis=-1)
             scale = self.param("norm_scale", nn.initializers.ones,
                                (z.d_v,), f32)
-            xs = (grouped(q, z.d_k), grouped(k, z.d_k), grouped(v, z.d_v),
-                  grouped(f, z.d_k), grouped(b), grouped(gate),
-                  self.param("A_log", _a_log_init, (H,)).reshape(-1, hg),
-                  self.param("dt_bias", _dt_bias_init, (z.key_dim,)
-                             ).reshape(-1, hg, z.d_k))
-            if hg == H:
-                y = group(jax.tree.map(lambda x: x[0], xs))[None]
-            else:
-                # a group at a time, rematerialised: one group's float32
-                # copies and chunk matrices are live, not every head's
-                y = jax.lax.map(jax.checkpoint(group), xs)
-            y = jnp.moveaxis(y, 0, 2).reshape(lead + (z.value_dim,))
+            a_log = self.param("A_log", _a_log_init, (H,))
+            dt_bias = self.param("dt_bias", _dt_bias_init, (z.key_dim,))
+            with named_scope("mixer-gate"):
+                def unit(x):
+                    x = x.astype(f32).reshape(lead + (H, z.d_k))
+                    return x * jax.lax.rsqrt(jnp.sum(
+                        jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+                q = (unit(q) * (1.0 / np.sqrt(z.d_k))).astype(self.dtype)
+                k = unit(k).astype(self.dtype)
+                beta = jax.nn.sigmoid(b.astype(f32))
+                g = z.lower_bound * jax.nn.sigmoid(
+                    jnp.exp(a_log)[:, None] * (f.astype(f32) + dt_bias
+                                               ).reshape(lead + (H, z.d_k)))
+            o = kda_rule(q, k, v.reshape(lead + (H, z.d_v)), g, beta,
+                         chunk=z.chunk)
+            with named_scope("mixer-gate"):
+                o = o.astype(f32)
+                o = o * jax.lax.rsqrt(
+                    jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+                    + self.norm_eps)
+                y = o * scale * jax.nn.sigmoid(gate.astype(f32))[..., None]
+                y = y.astype(self.dtype).reshape(lead + (z.value_dim,))
             with named_scope("mixer-proj"):
                 return nn.Dense(self.d_model, dtype=self.dtype,
                                 use_bias=False, name="out_proj")(y)
@@ -1638,17 +1618,21 @@ def remat_names():
     value heads x d_v) activation and a float32 state a head and tile of
     512 tokens, 201 MB a layer of the ``qwen3_next`` cell for a forward
     kernel call of 6.3 ms, +0.054 GB on the compiled step and 5% of the
-    measured one: PERF.md section 6, PR 37).  A name no layer of a table
-    emits is harmless.  NOT kept, each 20 to 100 times dearer a byte than
+    measured one: PERF.md section 6, PR 37) and, the same for a
+    Kimi-Delta-Attention row, ``kda.KDA_RESIDUALS`` (201 MB a layer of
+    the ``ling3flash`` cell for a forward kernel call of 10.1 ms and the
+    running sums beside it: PERF.md section 6, PR 44).  A name no layer
+    of a table emits is harmless.  NOT kept, each 20 to 100 times dearer a byte than
     flash's 67 MB for 9.7 ms a step (granite, PERF.md section 6, PR 35):
     the scan's ``y`` and block starts (268 MB a layer for 0.7 ms), the
     convolution's output (142 MB for ~1 ms), the dense FFN's gate and up
     (512 MB a layer)."""
     from chainermn_tpu.ops.flash_attention import FLASH_RESIDUALS
     from chainermn_tpu.ops.gated_delta import GDN_RESIDUALS
+    from chainermn_tpu.ops.kda import KDA_RESIDUALS
     from chainermn_tpu.parallel.moe_dropless import REMAT_SAVES
 
-    return (*REMAT_SAVES, FLASH_RESIDUALS, GDN_RESIDUALS)
+    return (*REMAT_SAVES, FLASH_RESIDUALS, GDN_RESIDUALS, KDA_RESIDUALS)
 
 
 def remat_policy():
@@ -1670,14 +1654,24 @@ def remat_kept(table: BlockTable, d_model: int, tokens: int, itemsize: int,
     kernel's ``o`` and ``lse`` are a token's, whatever it attended."""
     from chainermn_tpu.ops.gated_delta import gdn_tiles
     from chainermn_tpu.ops.grouped_matmul import TILE_ROWS
+    from chainermn_tpu.ops.kda import kda_tiles
     from chainermn_tpu.parallel import moe_dropless as moe
 
-    choice, products, flash_names, gdn_names = remat_names()
+    choice, products, flash_names, gdn_names, kda_names = remat_names()
     seq = seq or tokens
+    dtype = jnp.float32 if itemsize == 4 else jnp.bfloat16
     kept = {"layers": len(table.layers), "flash_layers": 0,
             "expert_layers": 0, f"{choice}_bytes": 0,
             f"{products}_bytes": 0, f"{flash_names}_bytes": 0,
-            f"{gdn_names}_bytes": 0}
+            f"{gdn_names}_bytes": 0, f"{kda_names}_bytes": 0}
+
+    def delta_rule_bytes(z, tile, n_heads):
+        """A delta rule's ``o`` and the float32 state each tile of
+        ``tile`` tokens started from, ``n_heads`` value heads."""
+        chunk = min(z.chunk, seq)
+        tiles = tokens // seq * (-(-seq // chunk) * chunk // tile)
+        return n_heads * z.d_v * (tokens * itemsize + tiles * z.d_k * 4)
+
     for row in table.layers:
         heads = {"attention": row, "cca": row.cca}.get(row.mixer)
         if flash and heads is not None:
@@ -1688,13 +1682,17 @@ def remat_kept(table: BlockTable, d_model: int, tokens: int, itemsize: int,
                 d_head * itemsize + 4)
         if row.mixer == "gdn":
             z = row.gdn
-            chunk = min(z.chunk, seq)
             tile, _, _ = gdn_tiles(
-                seq, chunk, z.n_k_heads, z.n_v_heads, z.d_k, z.d_v,
-                jnp.float32 if itemsize == 4 else jnp.bfloat16)
-            tiles = tokens // seq * (-(-seq // chunk) * chunk // tile)
-            kept[f"{gdn_names}_bytes"] += z.n_v_heads * z.d_v * (
-                tokens * itemsize + tiles * z.d_k * 4)
+                seq, min(z.chunk, seq), z.n_k_heads, z.n_v_heads, z.d_k,
+                z.d_v, dtype)
+            kept[f"{gdn_names}_bytes"] += delta_rule_bytes(
+                z, tile, z.n_v_heads)
+        if row.mixer == "kda":
+            z = row.kda
+            tile, _, _ = kda_tiles(
+                seq, min(z.chunk, seq), z.n_heads, z.d_k, z.d_v, dtype)
+            kept[f"{kda_names}_bytes"] += delta_rule_bytes(
+                z, tile, z.n_heads)
         if row.ffn == "experts":
             z = row.experts
             count = z.experts_held[1]

@@ -41,176 +41,602 @@ from the float32 product, ``T``, ``W``, ``U``) and the carried state are
 float32; the other products take operands in the activations' type and
 accumulate in float32.
 
-XLA ops under the scope ``kda-scan`` (the scalar rule came so in PR 36
-and became two Mosaic kernels in PR 37): ``T`` by substitution on 16-row
-diagonal blocks joined pairwise, the batch on the lanes; a ``lax.scan``
-step a chunk over the carried state; autodiff's backward.  Every head
-given is worked at once: a caller with many heads and a long sequence
-works them in groups (:func:`heads_a_group`; ``KDAMixer`` does, with its
-float32 gate side inside the group, so that one group's chunk matrices
-and float32 copies are live at a time).  ``gated_delta.py``'s helpers are the bodies of its
-kernels (refs, transposed tiles) and none fits here: this file shares its
-geometry record and nothing else, and leaves ``gdn-fwd`` / ``gdn-bwd``
-as they are.
+Each pass is ONE Mosaic kernel (``kda-fwd``, ``kda-bwd``), both under the
+scope ``kda-scan``, built as ``gated_delta.py``'s are (PR 37) and from
+its helpers: the grid walks (batch row, pair of heads, tile of tokens),
+the tiles in order (no key head is shared: the pair is two independent
+chains for the scheduler to interleave, worth 4% of the forward call and
+9% of the backward at the ``ling3flash`` cell's shape); the TOKENS lie
+on the lanes and a head's channels on the sublanes, so every matrix
+above is worked as its transpose and ``G`` is a float32 (d_k, tokens)
+tile; a grid step holds several chunks, worked
+two side by side on the lanes (two chunks of 64 fill a register).  What
+is the vector decay's: a sub-block is 16 lanes, and everything is worked
+on whole tiles under lane masks — ONE tile of row factors ``e^{G -
+r_own}`` serves every sub-block, each sub-block ``I`` has a tile of
+column factors ``e^{r_I - G}`` (0 on the lanes of later sub-blocks), and
+``k k^T`` and ``q k^T`` of a group are one product whose contraction
+runs over (sub-block, channel): the keys' scaled copies stacked on the
+sublanes against the rows' operands cut to their sub-block's lanes.  The
+state is kept as ``S`` (d_k, d_v), not its transpose: a chunk's
+``e^{G_C}`` is then a column that scales rows, and what the state's
+decay hands back to ``G_C`` is a sum along the lanes.  The heads' states
+(in backward their cotangents) are carried from tile to tile in VMEM.  The
+forward writes ``o`` and, kept only for a backward pass, the state each
+TILE started from.  The backward is written by hand: a tile first walks
+its groups forward from the kept state (``T^T``, ``W^T``, ``U^T``,
+``v_new^T`` and the chunks' starting states stay in VMEM), then in
+reverse with the state's cotangent; the cotangent passes through the
+solve as ``dA = -(T^T dW) W^T - (T^T dU) U^T`` under the triangle.  The
+cotangent of ``G`` is one float32 number a token, head and channel,
+summed in the kernel from every factor ``G`` enters (the rows' and the
+columns' factors, ``e^G``, ``e^{G_C - G}``, ``e^{G_C}``); the reference
+points carry none (``e^{G_i - r} e^{r - G_j}`` does not depend on ``r``:
+what differentiation through them would add cancels to rounding).  The
+running sums of ``g`` and their cotangent's way back are products with
+the triangle beside the calls.  :func:`kda_tiles` is the one rule for
+the tokens a grid step holds, from the operands' shapes.  The XLA form
+these kernels replaced (PR 43) lives on as the comparison in
+``benchmarks/kda_probe.py``.
 """
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from chainermn_tpu.observability.spans import named_scope, telemetry_active
+from chainermn_tpu.ops.flash_attention import (
+    VMEM_SCOPED_DEFAULT,
+    default_interpret,
+)
+from chainermn_tpu.ops.gated_delta import (
+    _HIGHEST,
+    _NT,
+    _TN,
+    _block_diagonal,
+    _by_chunk,
+    _diagonal,
+    _dot,
+    _in_chunk,
+    _pad,
+    _side_by_side,
+    _unit_upper_inverse,
+    _within_chunk,
+)
 from chainermn_tpu.ops.ssd import publish_geometry
 
-_HIGHEST = lax.Precision.HIGHEST
-
 #: Tokens a sub-block of a chunk: the distance between the reference
-#: points the decays inside ``k k^T`` and ``q k^T`` are taken around.
+#: points the decays inside ``k k^T`` and ``q k^T`` are taken around (a
+#: power of two: a lane's place in its sub-block is a mask of its bits).
 #: ``(SUB - 1) x 5 = 75 < 88``: float32 holds ``e^75``.
 SUB = 16
 
-#: Side of the diagonal blocks inverted by substitution, a row a step;
-#: larger blocks are put together from their halves.
-_BASE = 16
+#: The name (``jax.ad_checkpoint.checkpoint_name``) of what the backward
+#: kernel takes from the forward one — ``o`` and the state each tile
+#: started from — put on them inside the ``custom_vjp``'s forward rule: a
+#: rematerialised layer whose policy saves the name recomputes ``q``,
+#: ``k``, ``v``, ``g``, ``beta`` and not the kernel (``remat_names``).
+KDA_RESIDUALS = "kda-residuals"
 
-#: Tokens x heads a group of heads holds at most (16,384 tokens: 4 heads,
-#: about 0.7 GB of chunk matrices between the passes).
-_GROUP_TOKEN_HEADS = 16384 * 4
-
-
-def heads_a_group(tokens: int, heads: int) -> int:
-    """Heads a caller works together: the most that divide ``heads`` with
-    ``tokens x heads`` within :data:`_GROUP_TOKEN_HEADS` (at least one)."""
-    return max([h for h in range(1, heads + 1)
-                if heads % h == 0 and tokens * h <= _GROUP_TOKEN_HEADS],
-               default=1)
+#: Tokens a grid step holds at most: eight chunks of 64 (``gdn_tiles``'
+#: bound: a grid step has a fixed cost, and the backward keeps a tile's
+#: ``T^T``, ``W^T``, ``U^T``, ``v_new^T`` and chunk states in VMEM).
+_KDA_TOKENS = 512
 
 
-def _substitute(a):
-    """``(I + a)^-1`` by forward substitution, a row a step: row ``i`` is
-    ``e_i - sum_{j<i} a_ij row_j``.  ``a``: (m, m, N), strictly lower
-    triangular in its first two axes, the batch LAST (on the lanes)."""
-    m, _, N = a.shape
-    eye = jnp.eye(m, dtype=a.dtype)
-    rows = [jnp.broadcast_to(eye[0][:, None], (m, N))]
-    for i in range(1, m):
-        done = jnp.stack(rows)                              # (i, m, N)
-        rows.append(eye[i][:, None]
-                    - jnp.sum(a[i, :i, None, :] * done, axis=0))
-    return jnp.stack(rows)
+def _sub_block(C):
+    """Tokens a sub-block of a chunk of ``C``: :data:`SUB`, or the whole
+    chunk where that does not divide it."""
+    return SUB if C % SUB == 0 else C
 
 
-def _mm(x, y):
-    """``x @ y`` over the first two axes, the batch last: float32
-    multiplies and adds, no matrix unit (the blocks are 16 or 32 wide)."""
-    return jnp.sum(x[:, :, None, :] * y[None, :, :, :], axis=1)
+def _lanes(dk, C, w):
+    """What a group's tiles are masked and gathered by, from shapes alone
+    (made once a grid step, outside the loop over the groups): for a
+    (d_k, w C) tile a lane (``lane``), its place in its chunk (``col``),
+    its chunk's first lane (``start``) and its sub-block's (``own``);
+    for a (C, w C) matrix ``[j, (c, i)]`` the triangles ``j < i`` and
+    ``j <= i``; for the (d_k, 2 w C) rows' operands ``[k | q]`` the lanes
+    of each sub-block (``cut``)."""
+    sub = _sub_block(C)
+    _, col = _within_chunk((dk, w * C), C)
+    lane = lax.broadcasted_iota(jnp.int32, col.shape, 1)
+    start = lane - col
+    j, i = _within_chunk((C, w * C), C)
+    _, both = _within_chunk((dk, 2 * w * C), C)     # [k rows | q rows]
+    return dict(lane=lane, col=col, start=start, above=j < i, upto=j <= i,
+                cut=[(both >= I * sub) & (both < (I + 1) * sub)
+                     for I in range(C // sub)],
+                own=lane - (col & (sub - 1)) if sub < C else start)
 
 
-def _inverse(a):
-    n, _, N = a.shape
-    if n <= _BASE or n % 2:
-        return _substitute(a)
-    h = n // 2
-    # Both halves' diagonal blocks side by side on the batch axis; then
-    # [[T11, 0], [-T22 A21 T11, T22]].
-    both = _inverse(jnp.concatenate([a[:h, :h], a[h:, h:]], axis=-1))
-    t11, t22 = both[..., :N], both[..., N:]
-    t21 = -_mm(_mm(t22, a[h:, :h]), t11)
-    top = jnp.concatenate([t11, jnp.zeros_like(t21)], axis=1)
-    return jnp.concatenate(
-        [top, jnp.concatenate([t21, t22], axis=1)], axis=0)
+def _group(refs, at, h, m, C, w):
+    """Group ``m`` of ``w`` chunks of the step's head ``h``: ``q^T``, ``k^T``
+    and the running sums ``G`` (d_k, w C), ``beta`` (1, w C); the decays'
+    tiles (the rows' factors, each sub-block's columns' factors, ``e^G``,
+    ``e^{G_C - G}``, ``G_C`` a column a chunk); and ``k_j . k_i``, ``k_j .
+    q_i`` under ``e^{G_i - G_j}`` of each chunk, side by side (C, w C)
+    float32: right where sub-block(j) <= sub-block(i), 0 past it."""
+    q_ref, k_ref, g_ref, b_ref = refs
+    f32, L, sub = jnp.float32, w * C, _sub_block(C)
+    lanes = pl.ds(pl.multiple_of(m * L, L), L)
+    qT, kT = q_ref[0, h, :, lanes], k_ref[0, h, :, lanes]
+    op = kT.dtype
+    q32, k32 = qT.astype(f32), kT.astype(f32)
+    G, beta = g_ref[0, h, :, lanes], b_ref[0, h, :, lanes]
+    col, start = at["col"], at["start"]
+
+    def ref(idx):                       # G at lane idx[., l], on lane l
+        return jnp.take_along_axis(G, idx, axis=1)
+
+    rows = jnp.exp(G - ref(at["own"]))                      # <= 1
+    rowed = jnp.concatenate([k32 * rows, q32 * rows], axis=1)
+    cols, scaled, cut = [], [], []
+    for I in range(C // sub):
+        cols.append(jnp.exp(jnp.where(
+            col < (I + 1) * sub, ref(start + I * sub) - G, -jnp.inf)))
+        scaled.append((k32 * cols[I]).astype(op))
+        cut.append(jnp.where(at["cut"][I], rowed, 0.0).astype(op))
+    scaled_all = jnp.concatenate(scaled, axis=0)            # (nb d_k, L)
+    cut_all = jnp.concatenate(cut, axis=0)                  # (nb d_k, 2 L)
+    both = _dot(scaled_all, cut_all, _TN)                   # (L, 2 L)
+    last = [G[:, (c + 1) * C - 1:(c + 1) * C] for c in range(w)]
+    return dict(
+        lanes=lanes, q32=q32, k32=k32, beta=beta, rows=rows, cols=cols,
+        scaled=scaled, cut_all=cut_all, last=last,
+        kk=_diagonal(both[:, :L], C), qk=_diagonal(both[:, L:], C),
+        grow=jnp.exp(G), to_end=jnp.exp(_by_chunk(last, C) - G))
 
 
-def unit_lower_inverse(a):
-    """``(I + a)^-1`` for ``a`` (..., n, n) strictly lower triangular
-    (what lies on or above the diagonal is NOT read as zero: the caller
-    masks it), float32.  Substitution on the diagonal blocks of 16 rows,
-    the blocks joined pairwise by ``-T22 A21 T11``: backward-stable as
-    substitution is.  Worked with the batch on the last axis, so that a
-    step's small rows fill whole registers of lanes."""
-    lead, n = a.shape[:-2], a.shape[-1]
-    flat = jnp.moveaxis(a.reshape((-1, n, n)), 0, -1)
-    return jnp.moveaxis(_inverse(flat), -1, 0).reshape(lead + (n, n))
+def _solved(p, at, vT, a_s, C, w):
+    """``T_c^T`` of the group's chunks side by side (C, w C), and ``W^T =
+    K_b^T T^T`` over ``U^T = V_b^T T^T`` (d_k + d_v, w C), float32, with
+    ``K_b^T = k^T beta e^G`` and ``V_b^T = v^T beta``: ``A^T`` is put
+    together from the float32 product in ``a_s``, inverted by
+    substitution, and meets both right-hand sides in one product."""
+    a_s[...] = jnp.where(at["above"], p["beta"] * p["kk"], 0.0)
+    TT = _unit_upper_inverse(a_s, C, w)
+    KbT = p["k32"] * (p["beta"] * p["grow"])
+    VbT = vT.astype(jnp.float32) * p["beta"]
+    return TT, _dot(jnp.concatenate([KbT, VbT], axis=0),
+                    _block_diagonal(TT, C), precision=_HIGHEST)
 
 
-def _chunked(q, k, v, g, beta, C):
-    """The chunked rule for heads that all fit at once: ``q``, ``k`` (b,
-    S, H, d_k), ``v`` (b, S, H, d_v), ``g`` (b, S, H, d_k) and ``beta``
-    (b, S, H) float32, chunks of ``C`` tokens."""
+def _walk(p, WU, state, C, w, op, read=None):
+    """The group's chunks in order from ``state`` (d_k, d_v): ``v_new^T``
+    (d_v, w C) float32, the state each chunk started from, and the state
+    after the last.  A product with the state is made over the group's
+    width and kept on its chunk's lanes; with ``read`` (d_k, w C) also
+    ``S^T read`` of each chunk on its lanes (the same product, wider)."""
+    dk, L = p["k32"].shape[0], w * C
+    W, UT = WU[:dk].astype(op), WU[dk:]
+    kG = (p["k32"] * p["to_end"]).astype(op)
+    against = W if read is None else jnp.concatenate([W, read], axis=1)
+    v_new, answer, starts = None, None, []
+    for c in range(w):
+        starts.append(state)
+        mine = _in_chunk(UT.shape, c, C)
+        seen = _dot(state.astype(op), against, _TN)         # S^T [W | read]
+        here = UT - seen[:, :L]
+        v_new = here if c == 0 else jnp.where(mine, here, v_new)
+        if read is not None:
+            answer = seen[:, L:] if c == 0 else jnp.where(
+                mine, seen[:, L:], answer)
+        state = jnp.exp(p["last"][c]) * state + _dot(
+            kG, jnp.where(mine, here, 0.0).astype(op), _NT)
+    return v_new, answer, starts, state
+
+
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, C, w,
+                    keep):
+    """One (batch row, heads, tile of tokens) of the forward: the tile's
+    groups of ``w`` chunks in order, ``s_s`` the heads' states, carried
+    from tile to tile.  The step's heads are independent chains the
+    scheduler may interleave (a substitution is 63 dependent steps)."""
+    starts_ref = rest[0] if keep else None
+    s_s, a_s = rest[-2:]
+    f32, op = jnp.float32, v_ref.dtype
+    L, heads = w * C, q_ref.shape[1]
+    at = _lanes(q_ref.shape[2], C, w)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_s[...] = jnp.zeros(s_s.shape, f32)
+
+    if keep:
+        starts_ref[0, :, 0] = s_s[...]
+
+    def group(m, carry):
+        for h in range(heads):
+            p = _group((q_ref, k_ref, g_ref, b_ref), at, h, m, C, w)
+            _, WU = _solved(p, at, v_ref[0, h, :, p["lanes"]], a_s.at[h],
+                            C, w)
+            v_new, inter, _, s_s[h] = _walk(
+                p, WU, s_s[h], C, w, op,
+                read=(p["q32"] * p["grow"]).astype(op))
+            PT = jnp.where(at["upto"], p["qk"], 0.0).astype(op)
+            o = inter + _dot(v_new.astype(op), _block_diagonal(PT, C))
+            o_ref[0, h, :, p["lanes"]] = o.astype(o_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, q_ref.shape[3] // L, group, 0)
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, starts_ref, do_ref,
+                    dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
+                    ds_s, s_s, a_s, t_c, wu_c, vn_c, s_c, *, C, w):
+    """One (batch row, heads, tile of tokens) of the backward, the tiles in
+    reverse.  First the tile's groups forward from the kept state
+    (``s_s``), leaving in VMEM a group's ``T^T`` (``t_c``), ``W^T`` over
+    ``U^T`` (``wu_c``), ``v_new^T`` (``vn_c``) and the chunks' starting
+    states (``s_c``); then the groups in reverse with ``ds_s``, the
+    cotangent of the state, carried from tile to tile.  Written a group:
+    ``dq^T``, ``dk^T``, ``dv^T``, ``dbeta`` and the cotangent of the
+    running sums ``G`` (d_k, w C), float32, which the caller sums back
+    into ``dg``."""
+    f32, op = jnp.float32, v_ref.dtype
+    dk_, L, heads = q_ref.shape[2], w * C, q_ref.shape[1]
+    ng = q_ref.shape[3] // L
+    refs = (q_ref, k_ref, g_ref, b_ref)
+    at = _lanes(dk_, C, w)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_s[...] = jnp.zeros(ds_s.shape, f32)
+
+    s_s[...] = starts_ref[0, :, 0]
+
+    def forward(m, carry):
+        for h in range(heads):
+            p = _group(refs, at, h, m, C, w)
+            t_c[m, h], wu_c[m, h] = _solved(
+                p, at, v_ref[0, h, :, p["lanes"]], a_s.at[h], C, w)
+            vn_c[m, h], _, starts, s_s[h] = _walk(
+                p, wu_c[m, h], s_s[h], C, w, op)
+            for c, state in enumerate(starts):
+                s_c[m * w + c, h] = state
+        return carry
+
+    lax.fori_loop(0, ng, forward, 0)
+
+    def over_rows(x):               # (1, w C)
+        return jnp.sum(x, axis=0, keepdims=True)
+
+    def along(x):                   # (rows, 1)
+        return jnp.sum(x, axis=1, keepdims=True)
+
+    def backward(step, carry):
+        for h in range(heads):
+            head(ng - 1 - step, h)
+        return carry
+
+    def head(m, h):
+        p = _group(refs, at, h, m, C, w)
+        q32, k32, beta, rows = p["q32"], p["k32"], p["beta"], p["rows"]
+        grow, to_end = p["grow"], p["to_end"]
+        TT, WU, v_new = t_c[m, h], wu_c[m, h], vn_c[m, h]
+        v32 = v_ref[0, h, :, p["lanes"]].astype(f32)
+        do = do_ref[0, h, :, p["lanes"]]
+        W, v_in = WU[:dk_].astype(op), v_new.astype(op)
+        PT = _block_diagonal(
+            jnp.where(at["upto"], p["qk"], 0.0).astype(op), C)
+        qG32, kG32 = q32 * grow, k32 * to_end
+        qG, kG = qG32.astype(op), kG32.astype(op)
+        Kh = k32 * grow                                      # e^G k
+        # o = S^T qG + v_new P^T;  S' = e^{G_C} S + kG v_new^T
+        dv_intra = _dot(do, PT, _NT)
+        dP = jnp.where(at["upto"], _diagonal(_dot(v_in, do, _TN), C), 0.0)
+        dstate = ds_s[h]
+        dv_new = jnp.zeros(v32.shape, f32)
+        dqG = jnp.zeros(q32.shape, f32)
+        dkG = jnp.zeros(k32.shape, f32)
+        dW = jnp.zeros(k32.shape, f32)
+        at_ends = jnp.zeros(k32.shape, f32)
+        for c in range(w - 1, -1, -1):
+            state = s_c[m * w + c, h]
+            held, dnext = state.astype(op), dstate.astype(op)
+            mine = _in_chunk(v32.shape, c, C)
+            here = jnp.where(mine, dv_intra + _dot(dnext, kG, _TN), 0.0)
+            dv_new = dv_new + here
+            here = here.astype(op)
+            do_c = jnp.where(mine, do, jnp.zeros_like(do))
+            moved = _dot(dnext, jnp.where(mine, v_in, jnp.zeros_like(v_in)))
+            dqG = dqG + _dot(held, do_c)
+            dkG = dkG + moved
+            # v_new = U - S^T W
+            dW = dW - _dot(held, here)
+            dheld = _dot(qG, do_c, _NT) - _dot(W, here, _NT)
+            decay = jnp.exp(p["last"][c])                    # (d_k, 1)
+            at_ends = at_ends + jnp.where(
+                at["lane"] == (c + 1) * C - 1,
+                along(moved * kG32) + decay * along(state * dstate), 0.0)
+            dstate = decay * dstate + dheld
+        ds_s[h] = dstate
+        # W^T = K_b^T T^T, U^T = V_b^T T^T, T^T = (I + A^T)^-1
+        dKV = _dot(jnp.concatenate([dW, dv_new], axis=0),
+                   _block_diagonal(TT, C), _NT, precision=_HIGHEST)
+        dKb, dVb = dKV[:dk_], dKV[dk_:]
+        dA = -_diagonal(_dot(WU, dKV, _TN, precision=_HIGHEST), C)
+        M = jnp.where(at["above"], dA, 0.0)                  # A^T = beta kk
+        db_ref[0, h, :, p["lanes"]] = (
+            over_rows(M * p["kk"]) + over_rows(dKb * Kh)
+            + over_rows(dVb * v32))
+        # kk, qk = sum_I scaled_I^T [cut_I(k rows) | cut_I(q rows)]
+        D = jnp.concatenate(
+            [_block_diagonal((beta * M).astype(op), C),
+             _block_diagonal(dP.astype(op), C)], axis=1)     # (L_j, 2 L_i)
+        dscaled = _dot(p["cut_all"], D, _NT)                 # (nb d_k, L_j)
+        dk_cols = jnp.zeros(k32.shape, f32)
+        dcut = None
+        for I, (cols, scaled) in enumerate(zip(p["cols"], p["scaled"])):
+            dk_cols = dk_cols + dscaled[I * dk_:(I + 1) * dk_] * cols
+            here = _dot(scaled, D)                           # (d_k, 2 L_i)
+            dcut = here if I == 0 else jnp.where(at["cut"][I], here, dcut)
+        dKR, dQR = dcut[:, :L], dcut[:, L:]
+        # what each factor hands to G: the rows' +, the columns' -; the
+        # reference points none
+        dg_ref[0, h, :, p["lanes"]] = (
+            rows * (dKR * k32 + dQR * q32) - k32 * dk_cols
+            + dKb * (beta * Kh) + dqG * qG32 - dkG * kG32 + at_ends)
+        dv_ref[0, h, :, p["lanes"]] = (beta * dVb).astype(dv_ref.dtype)
+        dq_ref[0, h, :, p["lanes"]] = (
+            dqG * grow + dQR * rows).astype(dq_ref.dtype)
+        dk_ref[0, h, :, p["lanes"]] = (
+            dKb * (beta * grow) + dkG * to_end + dKR * rows
+            + dk_cols).astype(dk_ref.dtype)
+
+    lax.fori_loop(0, ng, backward, 0)
+
+
+def _kda_vmem(tokens, C, heads, dk, dv, itemsize):
+    """VMEM bytes of the backward kernel (the larger of the two) at
+    ``tokens`` and ``heads`` a grid step: its blocks twice (the
+    pipeline's two buffers; a block's lanes padded to whole registers)
+    and its scratch a head, and what the compiler keeps of a group's
+    values (three dozen ``d x w C`` float32 values, the sub-blocks'
+    stacked operands and the ``w C x 2 w C`` products)."""
+    nc = tokens // C
+    w, nb = _side_by_side(nc), C // _sub_block(C)
+    T, L = _pad(tokens, 128), _pad(w * C, 128)
+    state = dk * _pad(dv, 128) * 4
+    blocks = (T * itemsize * (4 * dk + 3 * dv)            # q k dq dk; v do dv
+              + 2 * dk * T * 4                            # G, dG
+              + 2 * 8 * T * 4                             # beta, dbeta
+              + state)                                    # the tile's state
+    scratch = (2 * state + C * L * 4                      # ds_s, s_s; a_s
+               + nc // w * L * 4 * (C + dk + 2 * dv)      # T; W, U; v_new
+               + nc * state)                              # the chunks' states
+    body = (36 * max(dk, dv) + 6 * nb * dk + 6 * w * C) * L * 4
+    return heads * (2 * blocks + scratch) + body
+
+
+def kda_tiles(S, chunk, H, d_k, d_v, dtype):
+    """``(tokens a grid step, heads a grid step, VMEM bytes)`` of the
+    rule's two kernels, from the operands' shapes alone: the most whole
+    chunks, at most :data:`_KDA_TOKENS` tokens, that divide the (padded)
+    sequence, and two heads where the heads pair up (no key head is
+    shared: the pair is there for the scheduler, two independent chains
+    of substitution steps to interleave), one where not or where two do
+    not fit — whose blocks and scratch fit the scoped VMEM a kernel gets
+    by default.  On the chip a tile's tokens and the chunks worked side by
+    side fill whole registers of 128 lanes, and a head's channels whole
+    registers of sublanes.  Raises where nothing fits."""
+    C = min(chunk, S)
+    n, itemsize = -(-S // C), jnp.dtype(dtype).itemsize
+    chip = not default_interpret()
+    if chip and (d_k % (32 // itemsize) or d_v % (32 // itemsize) or C % 8):
+        raise ValueError(
+            f"kda_rule: on the chip a head's channels and a chunk's rows "
+            f"fill whole registers of sublanes; d_k {d_k}, d_v {d_v}, "
+            f"chunk {C} do not")
+    vmem = None
+    for nc in range(min(n, max(1, _KDA_TOKENS // C)), 0, -1):
+        if n % nc or (chip and (_side_by_side(nc) * C) % 128):
+            continue
+        for heads in (2, 1) if H % 2 == 0 else (1,):
+            vmem = _kda_vmem(nc * C, C, heads, d_k, d_v, itemsize)
+            if vmem <= VMEM_SCOPED_DEFAULT:
+                return nc * C, heads, vmem
+    raise ValueError(
+        f"kda_rule: no tile of whole chunks of {C} tokens over a head of "
+        f"{d_k} x {d_v} divides {n} chunks inside the {VMEM_SCOPED_DEFAULT} "
+        f"bytes of VMEM a kernel gets"
+        + (f" (the smallest needs {vmem})" if vmem else ""))
+
+
+def _sums(a, C, back=False):
+    """``a`` (..., S') float32 summed along the tokens (the LAST axis)
+    inside each chunk of ``C``: ``G_t = sum_{s <= t} a_s``, or with
+    ``back`` its transpose ``sum_{t >= s} a_t`` (the running sums'
+    cotangent) — a product with the triangle at full precision, as
+    ``ssd._block_sums`` is over an axis in the middle.  Where chunks fill
+    a register of 128 lanes evenly the product is made a register at a
+    time, with the chunks' triangles on the diagonal: cutting the lanes
+    into rows of 128 moves nothing, into rows of 64 it copies the array."""
+    S = a.shape[-1]
+    width = 128 if 128 % C == 0 and S % 128 == 0 else C
+    t = jnp.arange(width)
+    live = ((t[:, None] >= t[None, :])                      # [t, s]: s <= t
+            & (t[:, None] // C == t[None, :] // C)).astype(jnp.float32)
+    return jnp.einsum(
+        "...t,ts->...s" if back else "...s,ts->...t",
+        a.reshape(a.shape[:-1] + (S // width, width)), live,
+        precision=_HIGHEST).reshape(a.shape)
+
+
+def _kda_layout(q, k, v, g, beta, C):
+    """What both kernels are called with — the tokens on the lanes, a
+    head's channels on the sublanes: ``q^T``, ``k^T`` (b, H, d_k, S'),
+    ``v^T`` (b, H, d_v, S'), the sequence padded to whole chunks with
+    tokens that write nothing (``beta`` 0) and decay nothing (``g`` 0);
+    the running sums ``G`` (b, H, d_k, S') and ``beta`` (b, H, 1, S')
+    float32 — with the grid, the block specs' maker and the sizes."""
     b, S, H, dk = q.shape
-    dv = v.shape[-1]
-    n = -(-S // C)
-    f32, dt = jnp.float32, v.dtype
-    pad = n * C - S
-    sub = SUB if C % SUB == 0 else C
-    nb = C // sub
+    dv = v.shape[3]
+    n, f32 = -(-S // C), jnp.float32
+    Sp = n * C
+    tokens, heads, _ = kda_tiles(S, C, H, dk, dv, v.dtype)
+    nc, nt = tokens // C, Sp // tokens
 
-    def chunks(x):
-        """(b, S, H, ...) -> (b, H, n, C, ...)"""
-        if pad:
-            x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
-        x = x.reshape((b, n, C) + x.shape[2:])
-        return jnp.moveaxis(x, 3, 1)
+    def tokens_last(x):             # (b, S, H, d) -> (b, H, d, S')
+        x = jnp.pad(x, [(0, 0), (0, Sp - S), (0, 0), (0, 0)])
+        return x.transpose(0, 2, 3, 1)
 
-    qc, kc, vc, gc, bc = (chunks(x) for x in (q, k, v, g, beta))
-    q32, k32 = qc.astype(f32), kc.astype(f32)
+    operands = (
+        tokens_last(q.astype(v.dtype)), tokens_last(k.astype(v.dtype)),
+        tokens_last(v), _sums(tokens_last(g.astype(f32)), C),
+        tokens_last(beta.astype(f32)[..., None]))
 
-    G = jnp.cumsum(gc, axis=3)                        # (b, H, n, C, dk)
-    row = jnp.arange(C)
-    below = row[:, None] > row[None, :]
-    upto = row[:, None] >= row[None, :]
+    def specs(tile_of):
+        """Block specs with the tile axis read through ``tile_of``."""
+        def tokens_by(rows):
+            return pl.BlockSpec(
+                (1, heads, rows, tokens),
+                lambda bi, h, i: (bi, h, 0, tile_of(i)))
 
-    def blocks(x):
-        """(b, H, n, C, dk) -> (b, H, n, nb, sub, dk)"""
-        return x.reshape(x.shape[:3] + (nb, sub, dk))
+        return {
+            "key": tokens_by(dk), "value": tokens_by(dv),
+            "row": tokens_by(1),
+            "state": pl.BlockSpec(
+                (1, heads, 1, dk, dv),
+                lambda bi, h, i: (bi, h, tile_of(i), 0, 0))}
 
-    Gb = blocks(G)
-    ref = Gb[..., :1, :]                              # (b, H, n, nb, 1, dk)
-    rows = jnp.exp(Gb - ref)                          # <= 1
-    # e^{r_I - G_j} for the columns of sub-blocks up to I, 0 past them
-    seen = (row[None, :] // sub <= jnp.arange(nb)[:, None])[..., None]
-    cols = jnp.where(seen, jnp.exp(jnp.where(
-        seen, ref - G[:, :, :, None], 0.0)), 0.0)     # (b, H, n, nb, C, dk)
-    k_cols = (k32[:, :, :, None] * cols).astype(dt)
+    # the state is carried over the tiles: every axis in order; the
+    # rule's tiles fit the default scoped VMEM, so no limit is asked for
+    params = pltpu.CompilerParams(dimension_semantics=("arbitrary",) * 3)
+    return operands, (b, H // heads, nt), specs, params, (heads, nc, nt, Sp)
 
-    def against_the_columns(x32):
-        out = jnp.einsum("bhnIic,bhnIjc->bhnIij",
-                         (blocks(x32) * rows).astype(dt), k_cols,
-                         preferred_element_type=f32)
-        return out.reshape(out.shape[:3] + (C, C))
 
-    A = jnp.where(below, bc[..., None] * against_the_columns(k32), 0.0)
-    P = jnp.where(upto, against_the_columns(q32), 0.0).astype(dt)
-    T = unit_lower_inverse(A)
-    eG = jnp.exp(G)
-    rhs = jnp.concatenate(
-        [bc[..., None] * eG * k32, bc[..., None] * vc.astype(f32)], axis=-1)
-    WU = jnp.einsum("bhnij,bhnjd->bhnid", T, rhs, precision=_HIGHEST)
-    W, U = WU[..., :dk].astype(dt), WU[..., dk:]
-    qG = (q32 * eG).astype(dt)
-    last = G[..., -1, :]                              # (b, H, n, dk)
-    kG = (k32 * jnp.exp(last[..., None, :] - G)).astype(dt)
+def _tokens_first(xT, S):
+    """(b, H, d, S') -> (b, S, H, d)"""
+    return xT.transpose(0, 3, 1, 2)[:, :S]
 
-    def step(state, now):
-        W_c, U_c, qG_c, kG_c, P_c, keep = now
-        held = state.astype(dt)
-        v_new = U_c - jnp.einsum("bhck,bhkv->bhcv", W_c, held,
-                                 preferred_element_type=f32)
-        v_in = v_new.astype(dt)
-        o = jnp.einsum("bhck,bhkv->bhcv", qG_c, held,
-                       preferred_element_type=f32) + jnp.einsum(
-            "bhij,bhjv->bhiv", P_c, v_in, preferred_element_type=f32)
-        state = keep[..., None] * state + jnp.einsum(
-            "bhck,bhcv->bhkv", kG_c, v_in, preferred_element_type=f32)
-        return state, o.astype(dt)
 
-    by_chunk = lambda x: jnp.moveaxis(x, 2, 0)  # noqa: E731
-    _, o = lax.scan(
-        step, jnp.zeros((b, H, dk, dv), f32),
-        tuple(by_chunk(x) for x in (W, U, qG, kG, P, jnp.exp(last))))
-    # (n, b, H, C, d_v) -> (b, S, H, d_v)
-    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, n * C, H, dv)
-    return o[:, :S]
+def _matrix_flops(C, dk, dv):
+    """Matrix FLOPs a token and head of the forward kernel: the
+    sub-blocks' product, the solve's right-hand sides, the state's read,
+    write and answer, ``tril(q k^T) v_new``."""
+    return 2 * (2 * C // _sub_block(C) * dk * C + C * (dk + dv)
+                + 3 * dk * dv + C * dv)
+
+
+#: The wrappers are jitted in their own right: the layers of a model share
+#: one lowering of each.
+@functools.partial(jax.jit, static_argnames=("C", "keep", "interpret"))
+def _kda_fwd_call(q, k, v, g, beta, *, C, keep, interpret):
+    """``o`` (b, S, H, d_v) and, where ``keep``, the state each tile
+    started from (b, H, tiles, d_k, d_v) float32, for the backward."""
+    b, S, H, dk = q.shape
+    dv = v.shape[3]
+    f32 = jnp.float32
+    with named_scope("kda-scan"):
+        operands, grid, specs, params, (heads, nc, nt, Sp) = _kda_layout(
+            q, k, v, g, beta, C)
+        s, w = specs(lambda i: i), _side_by_side(nc)
+        out_shape = [jax.ShapeDtypeStruct(operands[2].shape, v.dtype)]
+        out_specs = [s["value"]]
+        if keep:
+            out_shape.append(jax.ShapeDtypeStruct((b, H, nt, dk, dv), f32))
+            out_specs.append(s["state"])
+        out = pl.pallas_call(
+            functools.partial(_kda_fwd_kernel, C=C, w=w, keep=keep),
+            out_shape=out_shape, grid=grid,
+            in_specs=[s["key"], s["key"], s["value"], s["key"], s["row"]],
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((heads, dk, dv), f32),           # s_s
+                pltpu.VMEM((heads, C, w * C), f32)],        # a_s
+            compiler_params=params,
+            cost_estimate=pl.CostEstimate(
+                flops=b * Sp * H * _matrix_flops(C, dk, dv),
+                transcendentals=b * Sp * H * dk * (
+                    3 + C // _sub_block(C)),
+                bytes_accessed=b * Sp * H * (
+                    v.dtype.itemsize * (2 * dk + 2 * dv) + 4 * dk) + (
+                    b * nt * H * dk * dv * 4 if keep else 0)),
+            interpret=interpret, name="kda-fwd",
+        )(*operands)
+        o = _tokens_first(out[0], S)
+        return (o, out[1]) if keep else o
+
+
+@functools.partial(jax.jit, static_argnames=("C", "interpret"))
+def _kda_bwd_call(q, k, v, g, beta, starts, do, *, C, interpret):
+    b, S, H, dk = q.shape
+    dv = v.shape[3]
+    f32 = jnp.float32
+    with named_scope("kda-scan"):
+        operands, grid, specs, params, (heads, nc, nt, Sp) = _kda_layout(
+            q, k, v, g, beta, C)
+        s, w = specs(lambda i: nt - 1 - i), _side_by_side(nc)
+        qT, _, vT, G, rows = operands
+        doT = jnp.pad(do.astype(v.dtype), (
+            (0, 0), (0, Sp - S), (0, 0), (0, 0))).transpose(0, 2, 3, 1)
+        L, ng = w * C, nc // w
+        dqT, dkT, dvT, dG, dbeta = pl.pallas_call(
+            functools.partial(_kda_bwd_kernel, C=C, w=w),
+            out_shape=[
+                jax.ShapeDtypeStruct(qT.shape, q.dtype),
+                jax.ShapeDtypeStruct(qT.shape, k.dtype),
+                jax.ShapeDtypeStruct(vT.shape, v.dtype),
+                jax.ShapeDtypeStruct(G.shape, f32),
+                jax.ShapeDtypeStruct(rows.shape, f32)],
+            grid=grid,
+            in_specs=[s["key"], s["key"], s["value"], s["key"], s["row"],
+                      s["state"], s["value"]],
+            out_specs=[s["key"], s["key"], s["value"], s["key"], s["row"]],
+            scratch_shapes=[
+                pltpu.VMEM((heads, dk, dv), f32),           # ds_s
+                pltpu.VMEM((heads, dk, dv), f32),           # s_s
+                pltpu.VMEM((heads, C, L), f32),             # a_s
+                pltpu.VMEM((ng, heads, C, L), f32),         # t_c
+                pltpu.VMEM((ng, heads, dk + dv, L), f32),   # wu_c
+                pltpu.VMEM((ng, heads, dv, L), f32),        # vn_c
+                pltpu.VMEM((nc, heads, dk, dv), f32)],      # s_c
+            compiler_params=params,
+            cost_estimate=pl.CostEstimate(
+                flops=4 * b * Sp * H * _matrix_flops(C, dk, dv),
+                transcendentals=2 * b * Sp * H * dk * (
+                    3 + C // _sub_block(C)),
+                bytes_accessed=b * Sp * H * (
+                    v.dtype.itemsize * (4 * dk + 3 * dv) + 8 * dk)
+                + starts.size * 4),
+            interpret=interpret, name="kda-bwd",
+        )(*operands, starts, doT)
+        # the running sums' cotangent back through the sums
+        dg = _tokens_first(_sums(dG, C, back=True), S)
+        return (_tokens_first(dqT, S), _tokens_first(dkT, S),
+                _tokens_first(dvT, S), dg.astype(g.dtype),
+                _tokens_first(dbeta, S)[..., 0].astype(beta.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _chunked(q, k, v, g, beta, C):
+    """The chunked rule, chunks of ``C`` tokens: ``q``, ``k``, ``g`` (b,
+    S, H, d_k), ``v`` (b, S, H, d_v), ``beta`` (b, S, H)."""
+    return _kda_fwd_call(q, k, v, g, beta, C=C, keep=False,
+                         interpret=default_interpret())
+
+
+def _chunked_fwd(q, k, v, g, beta, C):
+    o, starts = _kda_fwd_call(q, k, v, g, beta, C=C, keep=True,
+                              interpret=default_interpret())
+    o, starts = (checkpoint_name(x, KDA_RESIDUALS) for x in (o, starts))
+    return o, (q, k, v, g, beta, starts)
+
+
+def _chunked_bwd(C, saved, do):
+    return _kda_bwd_call(*saved, do, C=C, interpret=default_interpret())
+
+
+_chunked.defvjp(_chunked_fwd, _chunked_bwd)
 
 
 def kda_rule(q, k, v, g, beta, *, chunk: int = 64):
@@ -223,8 +649,9 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64):
     is padded with tokens that write nothing (``beta`` 0) and decay
     nothing (``g`` 0).  ``g`` no lower than ``-88 / (SUB - 1)`` keeps
     every factor inside float32 (the family's gate is bounded at -5).
-    Returns (b, S, H, d_v) in ``v.dtype``.  Every sequence starts from a
-    zero state: a batch row is one document."""
+    ``q`` and ``k`` are worked in ``v``'s type, ``g`` and ``beta`` in
+    float32.  Returns (b, S, H, d_v) in ``v.dtype``.  Every sequence
+    starts from a zero state: a batch row is one document."""
     b, S, H, dk = q.shape
     dv = v.shape[3]
     if k.shape != q.shape or v.shape[:3] != q.shape[:3] or (
@@ -233,11 +660,14 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64):
             f"kda_rule: q {q.shape}, k {k.shape}, v {v.shape}, g {g.shape}, "
             f"beta {beta.shape} do not fit together")
     C = min(chunk, S)
+    tokens, heads, vmem = kda_tiles(S, C, H, dk, dv, v.dtype)
     if telemetry_active():
+        n = -(-S // C)
         publish_geometry("kda_geometry", "kda", {
-            "chunk": C, "chunks": -(-S // C), "heads": H, "d_k": dk,
-            "d_v": dv, "sub_block": SUB if C % SUB == 0 else C},
-            form="xla_chunked")
-    with named_scope("kda-scan"):
-        return _chunked(q, k, v, g.astype(jnp.float32),
-                        beta.astype(jnp.float32), C)
+            "chunk": C, "chunks": n, "heads": H, "d_k": dk, "d_v": dv,
+            "sub_block": _sub_block(C), "tokens_a_step": tokens,
+            "heads_a_step": heads,
+            "grid_steps": b * (H // heads) * (n * C // tokens),
+            "vmem_bytes": vmem}, form="kernel")
+    return _chunked(q, k, v, g.astype(jnp.float32),
+                    beta.astype(jnp.float32), C)

@@ -2,7 +2,9 @@
 channel — against the recurrence it is the chunked form of, one token a
 step; with ``g`` at the family's lower bound everywhere (the float32 range
 the sub-blocks' reference points are there for); and with ``g`` equal
-across a head's channels against ``gated_delta_rule``."""
+across a head's channels against ``gated_delta_rule``.  The rule is two
+Mosaic kernels, interpreted here; a tile's edge, the tile rule and the
+kept states have their own cases."""
 
 import jax
 import jax.numpy as jnp
@@ -11,12 +13,7 @@ import pytest
 from jax import lax
 
 from chainermn_tpu.ops.gated_delta import gated_delta_rule
-from chainermn_tpu.ops.kda import (
-    SUB,
-    heads_a_group,
-    kda_rule,
-    unit_lower_inverse,
-)
+from chainermn_tpu.ops.kda import SUB, kda_rule
 
 
 def recurrence(q, k, v, g, beta):
@@ -132,19 +129,99 @@ def test_a_ragged_sequence_is_padded_with_tokens_that_write_nothing():
         rtol=2e-4, atol=2e-5)
 
 
-def test_heads_a_group_divides_the_heads_within_the_bound():
-    assert heads_a_group(16384, 32) == 4        # the cell's mixer
-    assert heads_a_group(2 * 8192, 32) == 4
-    assert heads_a_group(64, 4) == 4            # a tiny model: all at once
-    assert heads_a_group(16384 * 5, 6) == 1     # never less than one
+@pytest.fixture
+def two_tiles(monkeypatch):
+    """Tiles of two chunks of 64: 256 tokens are two grid steps a head."""
+    from chainermn_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_KDA_TOKENS", 128)
+    jax.clear_caches()              # the calls are jitted by shapes alone
+    yield kda
+    jax.clear_caches()
 
 
-def test_unit_lower_inverse_inverts():
-    rng = np.random.RandomState(0)
-    a = np.tril(rng.randn(3, 64, 64) * 0.3, -1).astype(np.float32)
-    t = np.asarray(unit_lower_inverse(jnp.asarray(a)))
-    np.testing.assert_allclose(t @ (np.eye(64) + a), np.broadcast_to(
-        np.eye(64), a.shape), atol=2e-4)
+@pytest.mark.parametrize("which", ["o", "dq", "dk", "dv", "dg", "dbeta"])
+def test_the_state_crosses_tiles_forward_and_its_cotangent_back(
+        two_tiles, which):
+    """More than one TILE a head: forward the state is carried from tile
+    to tile, backward each tile starts from the state the forward kept
+    and hands the state's cotangent to the tile before it."""
+    ops = operands(S=256, seed=6)
+    assert two_tiles.kda_tiles(256, 64, 2, 16, 8, jnp.float32)[:2] == (128, 2)
+    do = jnp.asarray(np.random.RandomState(7).randn(
+        *ops[2].shape), jnp.float32)
+    if which == "o":
+        got, want = kda_rule(*ops, chunk=64), recurrence(*ops)
+    else:
+        i = ["dq", "dk", "dv", "dg", "dbeta"].index(which)
+        got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * do), argnums=i)(
+            *ops) for f in (lambda *a: kda_rule(*a, chunk=64), recurrence))
+    scale = max(float(jnp.max(jnp.abs(want))), 1.0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-3, atol=2e-5 * scale)
+
+
+def test_the_kept_states_are_the_states_the_tiles_started_from(two_tiles):
+    q, k, v, g, beta = operands(S=256, seed=8)
+    o, starts = two_tiles._kda_fwd_call(q, k, v, g, beta, C=64, keep=True,
+                                        interpret=True)
+    assert starts.shape == (1, 2, 2, 16, 8)
+    np.testing.assert_array_equal(np.asarray(starts[:, :, 0]), 0.0)
+    hi = lax.Precision.HIGHEST
+    state = jnp.zeros((1, 2, 16, 8), jnp.float32)
+    for t in range(128):                # the recurrence over the first tile
+        state = jnp.exp(g[:, t])[..., None] * state
+        read = jnp.einsum("bhkv,bhk->bhv", state, k[:, t], precision=hi)
+        state = state + jnp.einsum(
+            "bhk,bhv->bhkv", k[:, t],
+            beta[:, t][..., None] * (v[:, t] - read), precision=hi)
+    np.testing.assert_allclose(np.asarray(starts[:, :, 1]),
+                               np.asarray(state), rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(o), np.asarray(kda_rule(q, k, v, g, beta, chunk=64)),
+        rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("shape,tile", [
+    ((16384, 64, 32, 128, 128, jnp.bfloat16), (512, 2)),    # the cell's rows
+    ((128, 16, 2, 16, 8, jnp.float32), (128, 2)),
+    ((100, 64, 3, 16, 8, jnp.float32), (128, 1)),   # padded; an odd head
+    ((8, 64, 1, 8, 8, jnp.float32), (8, 1))])       # one short chunk
+def test_tiles_come_from_the_shapes_inside_the_default_vmem(
+        monkeypatch, shape, tile):
+    from chainermn_tpu.ops import kda
+
+    if shape[0] == 16384:               # as on the chip: whole registers
+        monkeypatch.setattr(kda, "default_interpret", lambda: False)
+    tokens, heads, vmem = kda.kda_tiles(*shape)
+    assert (tokens, heads) == tile
+    assert 0 < vmem <= kda.VMEM_SCOPED_DEFAULT
+
+
+def test_channels_that_do_not_fill_registers_are_refused_on_the_chip(
+        monkeypatch):
+    from chainermn_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "default_interpret", lambda: False)
+    with pytest.raises(ValueError, match="whole registers"):
+        kda.kda_tiles(256, 64, 2, 12, 128, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_dg_where_a_heads_channels_decay_at_very_different_rates(chunk):
+    """One channel at the lower bound (its history gone in three tokens),
+    one that barely decays, the rest in between: ``dg`` is a number a
+    channel, and every channel's is the recurrence's."""
+    q, k, v, g, beta = operands(seed=9)
+    g = g.at[..., 0].set(-5.0).at[..., 1].set(-1e-4)
+    do = jnp.asarray(np.random.RandomState(10).randn(*v.shape), jnp.float32)
+    got, want = (jax.grad(lambda g: jnp.sum(f(q, k, v, g, beta) * do))(g)
+                 for f in (lambda *a: kda_rule(*a, chunk=chunk), recurrence))
+    for channel in (0, 1, slice(2, None)):
+        scale = max(float(jnp.max(jnp.abs(want[..., channel]))), 1e-3)
+        np.testing.assert_allclose(
+            np.asarray(got[..., channel]), np.asarray(want[..., channel]),
+            rtol=1e-3, atol=2e-4 * scale)
 
 
 def test_shapes_that_do_not_fit_are_refused():

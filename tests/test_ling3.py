@@ -33,6 +33,8 @@ from chainermn_tpu.models.transformer import (  # noqa: E402
     TransformerLM,
     causal_mask,
     remat_kept,
+    remat_names,
+    remat_policy,
     rotate_partial,
 )
 from chainermn_tpu.observability import device_trace, spans  # noqa: E402
@@ -40,6 +42,7 @@ from chainermn_tpu.ops import make_flash_attention_fn  # noqa: E402
 from chainermn_tpu.parallel import moe_dropless  # noqa: E402
 from chipbench import weights, weights_ling3  # noqa: E402
 from chipbench.refs import ling3 as reference  # noqa: E402
+from test_remat_policy import kernel_calls  # noqa: E402
 
 D_MODEL, VOCAB = 32, 96
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -580,6 +583,86 @@ def test_remat_kept_reckons_an_mla_rows_output_at_its_value_width():
             kept["expert_layers"]) == (6, 1, 4)
     assert kept["flash-residuals_bytes"] == 16384 * 32 * (128 * 2 + 4)
     assert kept["gdn-residuals_bytes"] == 0
+    # five KDA rows: ``o`` (134 MB) and a float32 state a head and tile of
+    # 512 tokens (67 MB), 201 MB a row
+    assert kept["kda-residuals_bytes"] == 5 * 32 * 128 * (
+        16384 * 2 + 32 * 128 * 4) == 5 * 201326592
+
+
+def test_remat_kept_for_kda_rows_is_what_the_kept_names_hold():
+    """``remat_kept`` against the arrays the forward rule names, at a
+    tiny shape: two rows of 64 tokens through four KDA rows of 2 heads of
+    16, chunk 64."""
+    from chainermn_tpu.ops import kda
+
+    c = config()
+    table = table_of(c)
+    rows = [row for row in table.layers if row.mixer == "kda"]
+    assert len(rows) == 4 and "kda-residuals" in remat_names()
+    z = rows[0].kda
+    q = jnp.zeros((2, 64, z.n_heads, z.d_k), jnp.float32)
+    o, starts = jax.eval_shape(
+        lambda q, v, beta: kda._kda_fwd_call(
+            q, q, v, q, beta, C=z.chunk, keep=True, interpret=True),
+        q, jnp.zeros((2, 64, z.n_heads, z.d_v), jnp.float32),
+        jnp.zeros((2, 64, z.n_heads), jnp.float32))
+    held = sum(x.size * x.dtype.itemsize for x in (o, starts))
+    assert remat_kept(table, D_MODEL, 128, 4, seq=64)[
+        "kda-residuals_bytes"] == 4 * held
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_a_train_step_calls_the_forward_kernel_once_a_kda_layer(remat):
+    """Under ``remat=True`` the policy keeps ``KDA_RESIDUALS``: the
+    gradient of the tiny model holds ONE ``kda-fwd`` and one ``kda-bwd``
+    call a KDA layer, as without remat — the layer's recomputation does
+    not run the kernel again."""
+    c = config(held=(3, 6), n_layer=3)
+    lm = model(c, dtype=jnp.float32, remat=remat)
+    x = tokens(4, 1, 32)
+    params = jax.eval_shape(lambda: lm.init(jax.random.PRNGKey(0), x))
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: jnp.sum(
+        lm.apply(p, x) ** 2)))(params).jaxpr
+    assert kernel_calls(jaxpr, "kda-fwd") == 2
+    assert kernel_calls(jaxpr, "kda-bwd") == 2
+
+
+def test_a_checkpoint_without_the_name_would_run_the_forward_twice():
+    from chainermn_tpu.ops.kda import kda_rule
+
+    q = jnp.ones((1, 32, 1, 8)) / 4
+
+    def loss(q, g):
+        return jnp.sum(kda_rule(q, q, q, g, jnp.ones((1, 32, 1)) / 2,
+                                chunk=16))
+
+    for policy, fwd_calls in ((remat_policy(), 1), (None, 2)):
+        jaxpr = jax.make_jaxpr(jax.grad(jax.checkpoint(
+            loss, policy=policy), argnums=(0, 1)))(q, -q).jaxpr
+        assert kernel_calls(jaxpr, "kda-fwd") == fwd_calls
+        assert kernel_calls(jaxpr, "kda-bwd") == 1
+
+
+def test_the_kda_layer_says_its_kernels_geometry_when_someone_listens(
+        tmp_path):
+    from chainermn_tpu.observability import reporter, step_log
+
+    c = config(n_layer=1)
+    x = jnp.zeros((2, 32, D_MODEL))
+    layer = Block(D_MODEL, table_of(c).layers[0], jnp.float32)
+    rep, path = reporter.Reporter(), str(tmp_path / "steps.jsonl")
+    with reporter.scope(rep), step_log.recording(path):
+        layer.init(jax.random.PRNGKey(0), x, None)
+    gauges = {k: v["value"] for k, v in rep.summary()["gauges"].items()}
+    assert gauges["kda/kernel"] == 1 and "kda/xla_chunked" not in gauges
+    assert gauges["kda/heads"] == 2 and gauges["kda/d_k"] == 16
+    assert gauges["kda/chunk"] == 32 and gauges["kda/chunks"] == 1
+    assert gauges["kda/sub_block"] == 16
+    assert gauges["kda/tokens_a_step"] == 32
+    assert gauges["kda/heads_a_step"] == 2          # the heads pair up
+    assert gauges["kda/grid_steps"] == 2 and gauges["kda/vmem_bytes"] > 0
+    rows = {r["event"]: r for r in map(json.loads, open(path))}
+    assert rows["kda_geometry"]["form"] == "kernel"
 
 
 def test_the_latent_row_through_flash_is_the_dense_path():

@@ -51,9 +51,12 @@ def kernel_calls(jaxpr, name):
     return found
 
 
-def test_the_policy_is_the_four_names():
+def test_the_policy_is_the_five_names():
+    from chainermn_tpu.ops.kda import KDA_RESIDUALS
+
     assert remat_names() == (ROUTER_CHOICE, SAVED_PRODUCTS,
-                             fa.FLASH_RESIDUALS, gd.GDN_RESIDUALS)
+                             fa.FLASH_RESIDUALS, gd.GDN_RESIDUALS,
+                             KDA_RESIDUALS)
 
 
 # ---------------------------------------------------------- the kernel's rule
@@ -267,7 +270,7 @@ def test_kept_bytes_come_from_the_shapes():
         "layers": 4, "flash_layers": 2, "expert_layers": 0,
         f"{ROUTER_CHOICE}_bytes": 0, f"{SAVED_PRODUCTS}_bytes": 0,
         f"{fa.FLASH_RESIDUALS}_bytes": 2 * 128 * 4 * (8 * 4 + 4),
-        f"{gd.GDN_RESIDUALS}_bytes": 0}
+        f"{gd.GDN_RESIDUALS}_bytes": 0, "kda-residuals_bytes": 0}
     assert remat_kept(table, 32, BATCH * SEQ, 4, flash=False)[
         f"{fa.FLASH_RESIDUALS}_bytes"] == 0
     _, table, _ = _zaya_like()
@@ -277,7 +280,7 @@ def test_kept_bytes_come_from_the_shapes():
         f"{ROUTER_CHOICE}_bytes": 3 * 128 * 4,
         f"{SAVED_PRODUCTS}_bytes": 3 * (256 * 5) * 2 * (24 + 24 + 32),
         f"{fa.FLASH_RESIDUALS}_bytes": 3 * 128 * 4 * (16 * 2 + 4),
-        f"{gd.GDN_RESIDUALS}_bytes": 0}
+        f"{gd.GDN_RESIDUALS}_bytes": 0, "kda-residuals_bytes": 0}
     # a Gated DeltaNet row: ``o`` and a float32 state a value head and
     # tile (two rows of 64 tokens, chunk 16: one tile of four chunks a row)
     from chainermn_tpu.models.block_table import BlockTable, GDNSpec, LayerSpec

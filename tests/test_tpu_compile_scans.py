@@ -168,3 +168,40 @@ def test_delta_rule_kernels_compile_at_the_cells_geometry(one_chip, which):
     assert (tokens, heads) == (512, 2) and vmem <= fa.VMEM_SCOPED_DEFAULT
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert " while(" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_kda_kernels_compile_at_the_cells_geometry(one_chip, which):
+    """``kda-fwd`` and ``kda-bwd`` at the ``ling3flash-train-1chip``
+    cell's full geometry (1 x 16,384 tokens, 32 heads of 128, chunk 64,
+    bfloat16 with ``g`` in float32): ONE Mosaic call a pass inside the
+    default scoped VMEM, at the tile ``kda_tiles`` gives (eight chunks of
+    two heads) — the calls ask for no limit of their own — and no loop
+    beside it (the running sums are products with the triangle)."""
+    kd = importlib.import_module("chainermn_tpu.ops.kda")
+    b, S, H, d, chunk = 1, 16384, 32, 128, 64
+
+    def arr(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    operands = (arr(b, S, H, d), arr(b, S, H, d), arr(b, S, H, d),
+                arr(b, S, H, d, dt=jnp.float32),
+                arr(b, S, H, dt=jnp.float32))
+    if which == "fwd":
+        call = functools.partial(kd._kda_fwd_call, C=chunk, keep=True,
+                                 interpret=False)
+    else:
+        call = functools.partial(kd._kda_bwd_call, C=chunk, interpret=False)
+        operands += (arr(b, H, S // 512, d, d, dt=jnp.float32),
+                     arr(b, S, H, d))
+    # the rule's own tile: what the calls are built with on the chip
+    default = kd.default_interpret
+    kd.default_interpret = lambda: False
+    try:
+        tokens, heads, vmem = kd.kda_tiles(S, chunk, H, d, d, jnp.bfloat16)
+        compiled = jax.jit(call).lower(*operands).compile()
+    finally:
+        kd.default_interpret = default
+    assert (tokens, heads) == (512, 2) and vmem <= fa.VMEM_SCOPED_DEFAULT
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert " while(" not in compiled.as_text()
