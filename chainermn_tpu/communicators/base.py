@@ -52,6 +52,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import kvtransport, mesh_utils, overlap as overlap_mod, packing, quant
+from . import ring as ring_mod
 
 
 def shard_map_compat(fn, mesh, in_specs, out_specs, check_vma: bool = False):
@@ -562,7 +563,7 @@ class CommunicatorBase:
             return tree
         dtypes = jax.tree.map(lambda x: x.dtype, tree)
         tree = _tree_cast(tree, self.allreduce_grad_dtype)
-        bb = self.resolve_bucket_bytes() if len(leaves) > 1 else 0
+        bb = self._bucket_bytes_for(leaves)
         if bb > 0:
             out = self._allreduce_bucketed(tree, bb, overlap=overlap)
         else:
@@ -683,16 +684,53 @@ class CommunicatorBase:
           then reduce every bucket — the pre-overlap lowering, kept as
           the escape hatch and the parity oracle.
         """
-        packer = packing.GradPacker.for_tree(tree, bucket_bytes=bucket_bytes)
-        self._report_packing(packer)
+        packer, reduce_bucket, schedule = self._exchange_plan(
+            tree, bucket_bytes, overlap)
+        self._report_plan(packer, ())
         from chainermn_tpu.observability.spans import named_scope
 
+        if schedule is None:
+            with named_scope("grad-pack"):
+                bufs = packer.pack(tree)
+            outs = [reduce_bucket(b) for b in bufs]
+            with named_scope("grad-unpack"):
+                return packer.unpack(outs)
+        return self._emit_staged(
+            packer, packer._check_tree(tree), reduce_bucket, schedule,
+            [None] * packer.n_buckets)
+
+    def _emit_staged(self, packer, leaves, reduce_bucket, schedule, outs):
+        """The staged emission: stage by stage, pack and reduce every
+        bucket ``outs`` does not hold yet, then unpack."""
+        from chainermn_tpu.observability.spans import named_scope
+
+        for s, stage in enumerate(schedule.stages):
+            with named_scope(f"grad-stage{s}"):
+                todo = [i for i in stage if outs[i] is None]
+                bufs = [packer.pack_bucket(leaves, i) for i in todo]
+                for i, buf in zip(todo, bufs):
+                    outs[i] = reduce_bucket(buf)
+        with named_scope("grad-unpack"):
+            return packer.unpack(outs)
+
+    def _bucket_bytes_for(self, leaves) -> int:
+        """The cap :meth:`allreduce_grad` packs ``leaves`` under; 0 (no
+        packing: one collective over the tree as it is) for a single leaf
+        and where bucketing is off."""
+        return self.resolve_bucket_bytes() if len(leaves) > 1 else 0
+
+    def _exchange_plan(self, tree, bucket_bytes: int, overlap: bool | None):
+        """How ``tree``'s buckets are exchanged: ``(packer, reduce_bucket,
+        schedule)`` — ``reduce_bucket(buf)`` the characteristic collective
+        (quantised where a wire dtype resolves), ``schedule`` the staged
+        emission's (``None``: the eager one).  ``tree``'s leaves need a
+        shape and a dtype only."""
+        packer = packing.GradPacker.for_tree(tree, bucket_bytes=bucket_bytes)
         # Low-precision wire: quantize each float bucket around its sum
         # collective (quant.py's blessed pattern).  Integer buckets pass
-        # through at full precision, and the schedule below is untouched
-        # — scaled buckets still stage in reverse leaf-production order.
+        # through at full precision, and the schedule is untouched —
+        # scaled buckets still stage in reverse leaf-production order.
         wire_dt = quant.wire_dtype(self.resolve_comm_dtype())
-        self._report_quant(packer, wire_dt)
 
         def reduce_bucket(buf):
             if wire_dt is not None and quant.quantizable(buf.dtype):
@@ -700,24 +738,167 @@ class CommunicatorBase:
             return self._allreduce_impl(buf)
 
         if not self.resolve_overlap(overlap):
-            with named_scope("grad-pack"):
-                bufs = packer.pack(tree)
-            outs = [reduce_bucket(b) for b in bufs]
-            with named_scope("grad-unpack"):
-                return packer.unpack(outs)
+            return packer, reduce_bucket, None
+        return packer, reduce_bucket, overlap_mod.build_overlap_schedule(
+            packer, self.resolve_overlap_granularity())
 
-        schedule = overlap_mod.build_overlap_schedule(
-            packer, self.resolve_overlap_granularity()
-        )
-        leaves = packer._check_tree(tree)
+    def _report_plan(self, packer, ring) -> None:
+        """Publish one exchange's plan to the Reporter (trace-time)."""
+        self._report_packing(packer)
+        self._report_quant(
+            packer, quant.wire_dtype(self.resolve_comm_dtype()))
+        self._report_exchange(packer, ring)
+
+    def mean_grads_under(self, grad_fn, params, *rest,
+                         overlap: bool | None = None):
+        """``out, grads = grad_fn(params, *rest)`` with the large float
+        buckets exchanged UNDER the computation, as rings: a bucket's
+        ring (:mod:`.ring`) starts where its last gradient is made and
+        each hop is pinned to a later matrix product of the backward pass
+        (:func:`.overlap.walk_with_exchange`).  Returns ``(out, grads,
+        exchanged)``; ``exchanged`` says that ``grads`` (of ``params``'
+        structure) is already the world's mean, what
+        :meth:`allreduce_grad` would have made of it: the same buckets,
+        every one the ring does not take through the same collective.
+
+        A ring that nothing pins runs after the backward pass and is
+        slower there than the ``psum`` it replaces, so this is the ONE
+        place a bucket rides it, and only if the pass holds a product to
+        pin a hop to after the first ring starts
+        (:func:`.overlap.pin_sites`).  Anywhere else — no ring on this
+        communicator, the eager emission, a quantised or cast wire, no
+        bucket of :data:`.overlap.RING_MIN_BYTES`, a backward pass that
+        is one ``scan`` — the result is ``grad_fn``'s own, untouched, and
+        ``False``: the caller's :meth:`allreduce_grad` does the exchange.
+        """
+        bb = self._bucket_bytes_for(jax.tree.leaves(params))
+        ring = ()
+        if (bb and self.allreduce_grad_dtype is None
+                and quant.wire_dtype(self.resolve_comm_dtype()) is None):
+            # a gradient has its parameter's shape and dtype
+            packer, reduce_bucket, schedule = self._exchange_plan(
+                params, bb, overlap)
+            if schedule is not None:
+                ring = frozenset(i for i, b in enumerate(packer.buckets)
+                                 if self._rides_ring(b))
+        if not ring:
+            return (*grad_fn(params, *rest), False)
+        from chainermn_tpu.observability.spans import named_scope
+
+        closed, shapes = jax.make_jaxpr(grad_fn, return_shape=True)(
+            params, *rest)
+        args = jax.tree.leaves((params, *rest))
+        n_grads = packer.n_leaves
+        n_out = len(closed.jaxpr.outvars) - n_grads
+        born = overlap_mod.made_at(closed.jaxpr, n_out)
+        first_start = min(max(born[k] for k in packer.buckets[i].leaf_indices)
+                          for i in ring)
+        if not any(site > first_start
+                   for site in overlap_mod.pin_sites(closed.jaxpr)):
+            # nothing to pin a hop to: the traced pass as it is
+            flat = jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *args)
+            return (*jax.tree.unflatten(jax.tree.structure(shapes), flat),
+                    False)
+        self._report_plan(packer, ring)
+        stage_of = {i: s for s, st in enumerate(schedule.stages) for i in st}
+        bucket_of = {k: i for i in ring
+                     for k in packer.buckets[i].leaf_indices}
+        missing = {i: len(packer.buckets[i].leaf_indices) for i in ring}
+        made: list = [None] * n_grads
         outs: list = [None] * packer.n_buckets
-        for s, stage in enumerate(schedule.stages):
-            with named_scope(f"grad-stage{s}"):
-                bufs = [packer.pack_bucket(leaves, i) for i in stage]
-                for i, buf in zip(stage, bufs):
-                    outs[i] = reduce_bucket(buf)
-        with named_scope("grad-unpack"):
-            return packer.unpack(outs)
+        rings: dict = {}  # bucket -> [its ring_steps, the pieces in flight]
+
+        def go_on(i, steps, landed):
+            with named_scope("allreduce"), \
+                    named_scope(f"grad-stage{stage_of[i]}"):
+                try:
+                    rings[i] = [steps, steps.send(landed)]
+                except StopIteration as done:
+                    rings.pop(i, None)
+                    outs[i] = done.value
+
+        def on_value(k, value):
+            made[k] = value
+            i = bucket_of.get(k)
+            if i is None:
+                return False
+            missing[i] -= 1
+            if missing[i]:
+                return False
+            with named_scope("allreduce"), \
+                    named_scope(f"grad-stage{stage_of[i]}"):
+                buf = self._ring_input(packer, made, i)
+            go_on(i, self._ring_steps(buf), None)
+            return True
+
+        def flying():
+            return [piece for _, pieces in rings.values()
+                    for piece in pieces]
+
+        def land(landed):
+            landed = iter(landed)
+            for i, (steps, pieces) in list(rings.items()):
+                go_on(i, steps, [next(landed) for _ in pieces])
+
+        flat, ties = overlap_mod.walk_with_exchange(
+            closed, args, n_out, on_value, flying, land)
+        self._count("grad_exchange/ties", ties)
+        # The last rings' remaining hops have only the update of the other
+        # leaves to fly under.
+        while rings:
+            land(flying())
+        out = jax.tree.unflatten(jax.tree.structure(shapes[0]), flat[:n_out])
+        # What rode no ring — and a ring bucket whose gradients were never
+        # made (constants of the program) — is reduced here, after the
+        # pass, as allreduce_grad would have.
+        with named_scope("allreduce"):
+            return out, self._emit_staged(
+                packer, flat[n_out:], reduce_bucket, schedule, outs), True
+
+    def _ring_input(self, packer, leaves, i):
+        """Bucket ``i`` as the ring takes it: a bucket that is one whole
+        2-D leaf in the leaf's own shape (if it has rows enough to cut
+        into pieces), any other packed."""
+        k = packer.whole_leaf(i)
+        # (a 3-D leaf cut along its leading axis is relaid out before
+        # every write-back: six copies a leaf in the compiled step)
+        if k is not None and leaves[k].ndim == 2 and ring_mod.piece_rows(
+                leaves[k].shape, self.device_size):
+            return leaves[k]
+        return packer.pack_bucket(leaves, i)
+
+    def _rides_ring(self, bucket) -> bool:
+        """Whether :meth:`mean_grads_under` may reduce ``bucket`` (a
+        :class:`packing.Bucket`) through :meth:`_ring_steps` instead of
+        ``_allreduce_impl``.  No ring here: ``xla_ici`` has one."""
+        return False
+
+    def _ring_steps(self, buf):
+        """One bucket's ring as the generator of :func:`ring.ring_steps`."""
+        raise NotImplementedError
+
+    def _count(self, name: str, value: int) -> None:
+        """One trace-time counter to the Reporter, where telemetry is on."""
+        from chainermn_tpu.observability import reporter as _reporter
+        from chainermn_tpu.observability import spans as _spans
+
+        rep = _reporter.get_reporter() if _spans.telemetry_active() else None
+        if rep is not None:
+            rep.count(name, value)
+
+    def _report_exchange(self, packer, ring) -> None:
+        """Publish how the buckets are exchanged (trace-time, beside
+        :meth:`_report_packing`): how many ride the ring and how many a
+        ``psum``, the ring's bytes and its collective-permutes.
+        (:meth:`mean_grads_under` adds ``grad_exchange/ties``: how many
+        times the rings were tied to the backward pass.)"""
+        self._count("grad_exchange/ring_buckets", len(ring))
+        self._count("grad_exchange/psum_buckets",
+                    packer.n_buckets - len(ring))
+        self._count("grad_exchange/ring_bytes", sum(
+            packer.buckets[i].padded_bytes for i in ring))
+        self._count("grad_exchange/hops",
+                    len(ring) * ring_mod.ring_hops(self.device_size))
 
     def _report_packing(self, packer) -> None:
         """Publish the packing plan to the Reporter — at TRACE time (the

@@ -320,6 +320,16 @@ class GradPacker:
             parts.append(jnp.zeros((pad,), dtype=b.dtype))
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
+    def whole_leaf(self, index: int):
+        """The leaf that IS bucket ``index`` (one leaf, no padding), or
+        ``None``: such a bucket can be exchanged in the leaf's own shape
+        (:meth:`unpack` takes it back so) — raveling a tiled 2-D array is
+        a copy on the chip."""
+        b = self.buckets[index]
+        if len(b.leaf_indices) == 1 and b.padded_elems == b.elems:
+            return b.leaf_indices[0]
+        return None
+
     def unpack(self, bufs: Sequence[jax.Array]):
         """Bucket buffers → pytree (inverse of :meth:`pack`; padding is
         discarded)."""
@@ -334,6 +344,9 @@ class GradPacker:
                     f"buffer has {buf.size} elems, bucket expects "
                     f"{b.padded_elems}"
                 )
+            if buf.ndim != 1:  # a whole leaf, exchanged in its shape
+                out[b.leaf_indices[0]] = buf
+                continue
             off = 0
             for i in b.leaf_indices:
                 out[i] = jnp.reshape(

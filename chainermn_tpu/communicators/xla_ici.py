@@ -17,17 +17,28 @@ why BASELINE.json maps it to the ``xla_ici`` name.  The optional
 low-precision leg uses bfloat16 (TPU's native low-precision format) instead
 of the reference's fp16.
 
-There is no explicit stream management: XLA's async collectives already
-overlap the allreduce with surrounding compute where data dependence allows
-(SURVEY §7.6).
+There is no stream to manage, and on libtpu 0.0.34 nothing overlaps that
+``psum`` either: it compiles to a synchronous ``all-reduce`` in the core's
+instruction stream (an asynchronous one is folded back under every flag
+set tried).  So where a train step has one backward pass
+(``make_train_step``, ``overlap=True``, the default) its large float
+buckets are reduced as two-way rings of ``lax.ppermute`` hops pinned
+under that pass instead (``CommunicatorBase.mean_grads_under``,
+:mod:`.ring`: collective-permutes stay asynchronous DMAs), in ring order
+by the chips' coordinates.  ``allreduce_grad`` itself, small and integer
+buckets, a world of one, the quantised wire and the eager emission keep
+the ``psum`` below: a ring nothing pins is slower than it.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import overlap, ring
 from .base import CommunicatorBase
 
 # The flatten/concat core now lives in packing.py (shared with the
@@ -57,6 +68,21 @@ class XlaIciCommunicator(CommunicatorBase):
             lambda x, ref: x if x.dtype == ref.dtype else x.astype(ref.dtype),
             out, tree,
         )
+
+
+    def _rides_ring(self, bucket):
+        return (
+            self.device_size > 1
+            and bucket.quantizable  # a float bucket
+            and bucket.padded_bytes >= overlap.RING_MIN_BYTES
+        )
+
+    @functools.cached_property
+    def _ring_order(self):
+        return ring.ring_order(self.mesh, self.axes)
+
+    def _ring_steps(self, buf):
+        return ring.ring_steps(buf, self.axes, self._ring_order)
 
 
 # ``flat`` is the CUDA-aware-MPI spelling of the same algorithm in the

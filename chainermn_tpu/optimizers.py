@@ -503,7 +503,7 @@ class MultiNodeOptimizer:
             return lsum / n_accum, auxs, grads
 
     def _apply_update(self, params, state, grads, loss_scale=None,
-                      overlap=None):
+                      overlap=None, exchanged=False):
         """Allreduce local grads and apply the inner optimizer — the shared
         tail of the stage-0 step bodies.
 
@@ -524,9 +524,17 @@ class MultiNodeOptimizer:
         """
         comm = self.communicator
         opt = self.actual_optimizer
-        if self.double_buffering:
+
+        def mean(grads):
+            # ``exchanged``: CommunicatorBase.mean_grads_under has laid
+            # the exchange under the backward pass already.
+            if exchanged:
+                return grads
             with named_scope("allreduce"):
-                new_mean = comm.allreduce_grad(grads, overlap=overlap)
+                return comm.allreduce_grad(grads, overlap=overlap)
+
+        if self.double_buffering:
+            new_mean = mean(grads)
             stale = state.comm_buf
 
             def do_update(operand):
@@ -546,8 +554,7 @@ class MultiNodeOptimizer:
             return params, MultiNodeOptimizerState(
                 inner=inner, step=state.step + 1, comm_buf=new_mean
             )
-        with named_scope("allreduce"):
-            grads = comm.allreduce_grad(grads, overlap=overlap)
+        grads = mean(grads)
         if loss_scale is not None:
             grads = jax.tree.map(lambda g: g / loss_scale, grads)
         with named_scope("opt-update"):
@@ -696,12 +703,25 @@ class MultiNodeOptimizer:
         one = self._make_micro_grad_fn(loss_fn, has_aux, loss_scale)
 
         def body(params, state, batch):
-            loss, aux, grads = self._accum_local_grads(
-                one, params, batch, self._base_key(rng, state.step), n_accum
-            )
+            key = self._base_key(rng, state.step)
+            if n_accum == 1:
+                # One backward pass: the communicator may lay the
+                # exchange of its gradients under it.
+                def grads_of(p, b):
+                    loss, aux, grads = self._accum_local_grads(
+                        one, p, b, key, 1)
+                    return (loss, aux), grads
+
+                (loss, aux), grads, exchanged = comm.mean_grads_under(
+                    grads_of, params, batch, overlap=overlap)
+            else:
+                loss, aux, grads = self._accum_local_grads(
+                    one, params, batch, key, n_accum)
+                exchanged = False
             loss = lax.pmean(loss, axes)
             params, new_state = self._apply_update(
-                params, state, grads, loss_scale, overlap=overlap
+                params, state, grads, loss_scale, overlap=overlap,
+                exchanged=exchanged,
             )
             if has_aux:
                 return params, new_state, loss, aux

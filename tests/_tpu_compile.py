@@ -25,8 +25,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 
-@pytest.fixture(scope="module")
-def one_chip():
+def _described():
+    """The described topology's devices, with the persistent compile
+    cache off while a test file uses them."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -40,6 +41,19 @@ def one_chip():
     # on every entry.  Off for this file's tests.
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield list(topo.devices)
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    for devices in _described():
+        yield SingleDeviceSharding(devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips():
+    """The four described devices of the 2x2, for a step that spans
+    them (``tests/test_tpu_compile_exchange.py``)."""
+    yield from _described()

@@ -253,6 +253,30 @@ def test_overlapped_matches_eager_bit_exact(mesh24, name):
         )
 
 
+def test_overlapped_stays_bit_exact_where_a_ring_could_engage(
+        mesh24, monkeypatch):
+    """``xla_ici`` has a ring (``communicators/ring.py``) for buckets this
+    large (from 64 KiB here), but a bucket rides it only where a train
+    step pins its hops under the backward pass
+    (``mean_grads_under``): ``allreduce_grad`` pins nothing, so its
+    staged emission keeps ``psum`` and the contract above holds bit for
+    bit — the same bits on every device, the eager program's bits."""
+    from chainermn_tpu.communicators import overlap
+
+    monkeypatch.setattr(overlap, "RING_MIN_BYTES", 64 * 1024)
+    tree = synthetic_grad_tree(6, 2 << 20, dtypes=("float32",))
+    make = lambda over: create_communicator(  # noqa: E731
+        "xla_ici", mesh=mesh24, bucket_bytes=256 * 1024, overlap=over)
+    stacked = _stacked(tree, 8)
+    out_o = make(True).eager_allreduce_grad(stacked)
+    out_e = make(False).eager_allreduce_grad(stacked)
+    for k in tree:
+        a, b = np.asarray(out_o[k]), np.asarray(out_e[k])
+        for r in range(1, 8):
+            np.testing.assert_array_equal(a[r], a[0], err_msg=k)
+        np.testing.assert_array_equal(a, b, err_msg=k)
+
+
 def test_overlap_granularity_bit_exact(mesh24):
     """Stage width changes the emission batching, never the values."""
     tree = synthetic_grad_tree(12, 256 * 1024)
