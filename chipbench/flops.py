@@ -1,12 +1,30 @@
-"""Operations and bytes the algorithm needs, from shapes alone, and the
-table of peaks.  The yardstick's own copy: ``bench.py``'s model-FLOP
-arithmetic (Megatron convention, no recomputation counted) was sound and is
-copied here; ``PERF.md`` lists the original for a later PR to delete."""
+"""Operations and bytes the algorithm needs, from shapes alone, the table
+of peaks, and the way from a configuration to the module that counts for
+its family.  Model FLOPs follow the Megatron convention (6 a parameter a
+token, no recomputation counted): the yardstick's own arithmetic, and
+since PR 42 the repository's only copy of it."""
 
+import importlib
 import json
 import os
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
+#: The families whose module is older than the rule its name now follows.
+_OLDER = {"granitemoehybrid": "flops_hybrid", "nemotron_h": "flops_nemotron",
+          "qwen3_next": "flops_qwen3next", "mellum": "flops_mellum2",
+          "bailing_hybrid": "flops_ling3"}
+
+
+def family(config):
+    """The module that counts for a configuration:
+    ``chipbench/flops_<model_type>.py`` (``gpt2`` where the file states no
+    ``model_type``).  Each has ``train_flops_per_step(config, mix)`` and,
+    for the kernels its family runs, ``<kernel>_roofline_seconds(config,
+    mix, device_kind, ...)``; a reader shared between cells asks here for
+    its cell's count, so a new family brings a file and edits none."""
+    model_type = config.get("model_type", "gpt2")
+    return importlib.import_module(
+        "chipbench." + _OLDER.get(model_type, "flops_" + model_type))
 
 
 def peaks(device_kind):

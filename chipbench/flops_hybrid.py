@@ -8,7 +8,7 @@ well under 0.01%), causal attention in the attention layers only, nothing
 recomputed — plus what the state-space **recurrence** needs.  The program
 computes the recurrence in its chunked dual form, which spends more
 operations than these to reach the matrix unit; they are not counted, so
-``hybrid.mfu`` and ``kernel.ssd_roofline`` cannot be raised by a costlier
+``step.mfu`` and ``kernel.ssd_roofline`` cannot be raised by a costlier
 way of computing the same thing.
 """
 

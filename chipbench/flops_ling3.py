@@ -14,7 +14,7 @@ over 192 and summing values of 128, the delta rule under its per-channel
 decay as :func:`kda_scan_flops` counts it, nothing recomputed; and, for a
 held expert's three matrices, 6 a parameter a PAIR routed to it: with 8 of
 512 experts held and 8 chosen a token, 0.125 pairs a token in expectation.
-``ling.mfu`` takes the expectation; ``ling.gmm_roofline`` takes the pairs
+``step.mfu`` takes the expectation; ``kernel.moe_gmm_roofline`` takes the pairs
 the traced steps themselves routed to the held experts.
 
 The rule is counted in its CHUNKED form at :data:`CHUNK` tokens a chunk —

@@ -10,7 +10,7 @@ them), causal attention in the attention layers only, the recurrence as
 ``flops_hybrid.ssd_flops`` counts it, nothing recomputed — and, for a held
 expert's two matrices, 6 a parameter a PAIR routed to it: with 8 of 128
 experts held and 6 chosen a token, 6 x 8/128 = 0.375 pairs a token in
-expectation.  ``nemo.mfu`` takes the expectation, so that it does not move
+expectation.  ``step.mfu`` takes the expectation, so that it does not move
 with a seed's routing; the grouped matmul's roofline share takes the pairs
 the traced steps themselves routed to the held experts (the step hands
 its routers' choice back), so that it moves with the kernels and not with

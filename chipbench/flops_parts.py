@@ -21,6 +21,9 @@ def ffn_shape(config):
     if config.get("model_type") == "granitemoehybrid":       # SwiGLU
         z = weights_hybrid.sizes(config)
         return z["d"], z["d_ff"], 3, z["layers"]
+    if config.get("model_type") == "bailing_hybrid":  # the leading SwiGLUs
+        return (config["hidden_size"], config["intermediate_size"], 3,
+                min(config["first_k_dense_replace"], config["n_layer"]))
     return None
 
 

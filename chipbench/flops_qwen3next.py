@@ -12,8 +12,8 @@ embedding table is a lookup and is NOT counted, unlike the older files'
 of 256, the gated delta rule as :func:`gdn_scan_flops` counts it, nothing
 recomputed; and, for a held expert's three matrices, 6 a parameter a PAIR
 routed to it: with 32 of 512 experts held and 10 chosen a token, 0.625
-pairs a token in expectation.  ``qnext.mfu`` takes the expectation;
-``qnext.gmm_roofline`` takes the pairs the traced steps themselves routed
+pairs a token in expectation.  ``step.mfu`` takes the expectation;
+``kernel.moe_gmm_roofline`` takes the pairs the traced steps themselves routed
 to the held experts.
 
 The gated delta rule is counted in its CHUNKED form at :data:`CHUNK`

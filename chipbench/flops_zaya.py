@@ -10,7 +10,7 @@ them; the convolutions' taps and the vectors ride along), causal
 attention at the LATENT's width (``num_attention_heads x head_dim``, not
 the model's), nothing recomputed — and, for a held expert's three
 matrices, 6 a parameter a PAIR routed to it: with 8 of 16 experts held
-and one chosen a token, 0.5 pairs a token in expectation.  ``zaya.mfu``
+and one chosen a token, 0.5 pairs a token in expectation.  ``step.mfu``
 takes the expectation, so that it does not move with a seed's routing;
 the grouped matmul's roofline share takes the pairs the traced steps
 themselves routed to the held experts (the step hands its routers' choice

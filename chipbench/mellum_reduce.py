@@ -15,7 +15,7 @@ carries no ``"within"`` (a program from before it).
 
 from chipbench import flops_mellum2, parts_reduce, scope_reduce, weights_mellum2
 
-FLASH = ("flash-fwd", "flash-bwd-dq", "flash-bwd-dkv")
+FLASH = scope_reduce.FLASH
 #: layer_types' kinds -> the scope their rows are traced under.
 SCOPE = {"sliding_attention": "attn-window", "full_attention": "attn-mixer"}
 

@@ -43,22 +43,3 @@ def train_mfu_pct(ctx):
     tokens = int(mix["global_batch"]) * int(mix["seq_len"])
     rate = per_token * tokens / (ctx["clear_step_ms"] / 1e3)
     return 100.0 * rate / (len(ctx["devices"]) * peak)
-
-
-def flash_roofline_pct(ctx, pattern, exclude=None):
-    """Needed FLOPs and bytes of causal attention forward + backward for
-    one chip's rows, over the peaks, over the kernels' device time."""
-    if ctx.get("trace") is None:
-        return None
-    sec = ctx["trace"].seconds(pattern, exclude)
-    if not sec:
-        return None
-    mix, z = ctx["mix"], weights.sizes(ctx["config"])
-    rows = int(mix["global_batch"]) // len(ctx["devices"])
-    args = (rows, int(mix["seq_len"]), z["heads"], z["d_head"], z["layers"])
-    least, bound = flops.roofline_seconds(
-        flops.causal_attention_flops(*args),
-        flops.causal_attention_bytes(*args),
-        flops.peaks(ctx["device_kind"]))
-    ctx.setdefault("notes", {})["flash_roofline_bound"] = bound
-    return 100.0 * least / (sec / ctx["trace_steps"])

@@ -19,9 +19,6 @@ import sys  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
-# The program's tune cache lives at a fixed path outside the checkout;
-# every block size is pinned in the configuration files, so it is not read.
-os.environ["CHAINERMN_TPU_AUTOTUNE"] = "0"
 
 
 def main(argv=None):

@@ -1,7 +1,7 @@
 """Device time by program scope, for the per-layer readers that ask for
-it (``step.*``, ``comm.pack_ms``, ``kernel.fused_ce_ms``,
-``kernel.flash_fwd_ms`` / ``kernel.flash_bwd_ms``,
-``trace.unattributed_pct``).
+it: a step phase (``step.*``, ``comm.pack_ms``), kernel regions
+(``kernel.*``, ``moe.*``, ``ssm.*``, ``cca.*`` and the one-cell mixers),
+a region's share of its roofline, ``trace.unattributed_pct``.
 
 The trace's op events are named by HLO instruction and carry no scope;
 the compiled step does (``metadata={op_name="jit(train_step)/fwd-bwd/
@@ -22,9 +22,15 @@ attribution is worse than none.
 
 import re
 
-from chipbench import harness, traffic, weights
+from chipbench import flops, harness, traffic, weights
 
-ALL_REDUCE = re.compile(r"\sall-reduce(-start|-done)?\(")
+#: The ops of the gradient exchange itself: the synchronous ``all-reduce``s
+#: and, since the exchange is a ring (PR 45), the hops'
+#: ``collective-permute-start`` / ``-done`` (the wait sits in ``-done``).
+#: ``comm.exchange_ms`` and ``comm.exposed_ms`` name the same pattern.
+EXCHANGE = re.compile(r"\s(all-reduce|collective-permute)(-start|-done)?\(")
+#: The flash kernels' three regions.
+FLASH = ("flash-fwd", "flash-bwd-dq", "flash-bwd-dkv")
 
 
 def _device_trace():
@@ -60,7 +66,7 @@ def build_table(ctx, device_trace):
 
 
 def attribution(ctx):
-    """``{"all": [per device], "no_allreduce": [per device]}`` of
+    """``{"all": [per device], "no_exchange": [per device]}`` of
     ``device_trace.attribute`` results, memoised in ``ctx``; ``None``
     where there is nothing sound to read."""
     if "_scope_reduce" in ctx:
@@ -73,7 +79,7 @@ def attribution(ctx):
     table = ctx.get("scope_table")
     if table is None:
         table = ctx["scope_table"] = build_table(ctx, device_trace)
-    out = {"all": [], "no_allreduce": []}
+    out = {"all": [], "no_exchange": []}
     for d in trace.devices:
         ops = [(o.name, o.start, o.end) for o in d["ops"]]
         got = device_trace.attribute(ops, table)
@@ -88,8 +94,8 @@ def attribution(ctx):
                 "nothing is reported")
             return None
         out["all"].append(got)
-        out["no_allreduce"].append(device_trace.attribute(
-            [op for op in ops if not ALL_REDUCE.search(op[0])], table))
+        out["no_exchange"].append(device_trace.attribute(
+            [op for op in ops if not EXCHANGE.search(op[0])], table))
     ctx["_scope_reduce"] = out
     return out
 
@@ -99,12 +105,13 @@ def _ms_per_step(ctx, values):
     return sum(values) / len(values) / ctx["trace_steps"] * 1e3
 
 
-def phase_ms(ctx, phase, without_allreduce=False):
-    """Device ms a step of a step phase (mean over devices)."""
+def phase_ms(ctx, phase, without_exchange=False):
+    """Device ms a step of a step phase (mean over devices), with or
+    without the ``EXCHANGE`` ops under it."""
     got = attribution(ctx)
     if got is None:
         return None
-    rows = got["no_allreduce" if without_allreduce else "all"]
+    rows = got["no_exchange" if without_exchange else "all"]
     if not any(phase in g["phase"] for g in rows):
         return None
     return _ms_per_step(ctx, (g["phase"].get(phase, 0.0) for g in rows))
@@ -120,6 +127,24 @@ def region_ms(ctx, *regions):
         return None
     return _ms_per_step(ctx, (
         sum(g["region"].get(r, 0.0) for r in regions) for g in rows))
+
+
+def roofline_pct(ctx, count, *regions, extra=()):
+    """What the algorithm needs over the peaks, over the regions' device
+    time.  ``count`` names the function of the configuration's own flops
+    module (``flops.family``) that returns ``(least seconds, bound)`` from
+    the configuration, ONE CHIP's rows of the step, the device kind and
+    ``extra``; which bound applies is left in ``ctx["notes"]`` under
+    ``count``'s name with ``_bound`` for ``_seconds``."""
+    ms = region_ms(ctx, *regions)
+    if not ms:
+        return None
+    mix = dict(ctx["mix"], global_batch=(
+        int(ctx["mix"]["global_batch"]) // len(ctx["devices"])))
+    least, bound = getattr(flops.family(ctx["config"]), count)(
+        ctx["config"], mix, ctx["device_kind"], *extra)
+    ctx.setdefault("notes", {})[count.replace("_seconds", "_bound")] = bound
+    return 100.0 * least / (ms / 1e3)
 
 
 def unattributed_pct(ctx):

@@ -1,27 +1,17 @@
 """The Ling-3.0-flash configuration, its cell and its arithmetic."""
 
-import gzip
-import importlib.util
 import json
 import math
 import os
 
 import pytest
 
-from chipbench import (flops_ling3, harness, scope_reduce, trace_reduce,
-                       weights_ling3)
+from chipbench import flops_ling3, harness, weights_ling3
+from chipbench.tests import captures
 
-ROOT = harness.ROOT
 CELL = "ling3flash-train-1chip"
 CONFIG = "ling3flash-train"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-DATA = os.path.join(harness.HERE, "data")
-METRICS = (
-    "kda_mixer_ms", "kda_scan_ms", "kda_scan_roofline", "conv_ms",
-    "mla_mixer_ms", "flash_ms", "flash_roofline", "moe_layer_ms",
-    "route_ms", "dispatch_ms", "gmm_ms", "gmm_roofline", "dense_ffn_ms",
-    "fused_ce_ms", "fwd_bwd_ms", "opt_update_ms", "mfu", "idle_pct",
-    "unattributed_pct")
 
 
 @pytest.fixture(scope="module")
@@ -147,7 +137,7 @@ def test_the_program_builds_the_cells_table_from_the_file(cell):
         "reckoning"]["total"]
 
 
-def test_cell_traffic_and_metrics(cell):
+def test_cell_traffic_and_limits(cell):
     entry, config, mix, limits = cell
     assert entry["chips"] == 1 and entry["traffic"] == "kdamla16k-b1"
     assert entry["config"] == CONFIG
@@ -160,22 +150,6 @@ def test_cell_traffic_and_metrics(cell):
             "router_pair_diff_share", "set_from"} <= set(limits)
     assert "PROVISIONAL" not in limits["set_from"]
     assert "8,192 rows" in config["program"]["moe_rows_bound_note"]
-    m = harness.load_manifest()
-    metrics = harness.cell_metrics(m, CELL, "per_layer")
-    assert [x["name"] for x in metrics] == ["ling." + n for n in METRICS]
-    for x in metrics:
-        assert x["workloads"] == [CELL] and x["moves"] == "train_step_ms"
-        assert callable(harness.layer_reader(x["name"]))
-        if x["name"].endswith(("_roofline", ".mfu")):
-            assert (x["unit"], x["better"]) == ("%", "higher")
-    e2e = {x["name"] for x in harness.cell_metrics(m, CELL, "end_to_end")}
-    assert e2e == {"train_step_ms", "setup_s"}
-    # found by name, nowhere by position: a later PR appends after these
-    assert CELL in [x["name"] for x in m["workloads"]]
-    assert CONFIG in [c["name"] for c in m["configs"]]
-    train = [x for x in m["end_to_end"] if x["name"] == "train_step_ms"][0]
-    assert CELL in train["workloads"]
-    assert len(m["per_layer"]) <= 128
 
 
 def test_rows_bound_of_the_cells_shape():
@@ -238,14 +212,6 @@ def test_flop_and_byte_arithmetic_against_a_hand_count(cell):
     assert total == pytest.approx(56.03e12, rel=1e-3)
 
 
-def test_readers_return_nothing_without_a_trace(cell):
-    _, config, mix, _ = cell
-    ctx = {"config": config, "mix": mix, "device_kind": "TPU v5 lite",
-           "devices": [None], "trace_steps": 4, "trace": None}
-    for name in METRICS:
-        assert harness.layer_reader("ling." + name)(ctx) is None
-
-
 def test_readers_return_nothing_on_a_program_without_the_regions(cell):
     """A parent commit's step has no ``kda-scan`` and no ``mla-mixer``,
     and its attribution may carry no owner reading: the readers say
@@ -254,75 +220,29 @@ def test_readers_return_nothing_on_a_program_without_the_regions(cell):
     row = {"region": {"flash-fwd": 1.0}, "phase": {}, "busy": 1.0}
     ctx = {"config": config, "mix": mix, "device_kind": "TPU v5 lite",
            "trace_steps": 4, "scope_table": {},
-           "_scope_reduce": {"all": [row], "no_allreduce": [row]}}
-    for name in ("kda_mixer_ms", "kda_scan_ms", "kda_scan_roofline",
-                 "conv_ms", "gmm_roofline", "dense_ffn_ms", "fwd_bwd_ms"):
-        assert harness.layer_reader("ling." + name)(ctx) is None
+           "_scope_reduce": {"all": [row], "no_exchange": [row]}}
+    for name in ("ling.kda_mixer_ms", "ling.kda_scan_ms",
+                 "ling.kda_scan_roofline", "kernel.ssm_conv_ms",
+                 "kernel.moe_gmm_roofline", "part.ffn_ms",
+                 "step.fwd_bwd_ms"):
+        assert harness.layer_reader(name)(ctx) is None
 
 
 # ------------------------------------- the readers on a recorded capture
 
-def _tool():
-    spec = importlib.util.spec_from_file_location(
-        "record_kda_mla_moe_trace",
-        os.path.join(harness.HERE, "tools", "record_kda_mla_moe_trace.py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-TOOL = _tool()
-
-
 @pytest.fixture(scope="module")
 def recorded():
-    device_trace = pytest.importorskip(
-        "chainermn_tpu.observability.device_trace")
-    trace = trace_reduce.TraceData.from_file(
-        os.path.join(DATA, "tiny_kda_mla_moe.xplane.pb.gz"), n_devices=1)
-    with gzip.open(os.path.join(DATA, "tiny_kda_mla_moe.hlo.txt.gz"),
-                   "rt") as f:
-        table = device_trace.scope_table(f.read())
-    return {"trace": trace, "trace_steps": TOOL.STEPS, "scope_table": table,
-            "config": TOOL.CONFIG, "mix": TOOL.MIX, "devices": [None],
-            "device_kind": "TPU v5 lite", "moe_held_pairs": None}
-
-
-def test_the_recorder_asks_for_the_cells_metrics():
-    assert [m["name"] for m in TOOL.readers()] == [
-        "ling." + n for n in METRICS]
-
-
-@pytest.mark.parametrize("name", METRICS)
-def test_each_reader_on_the_recorded_capture(recorded, name):
-    ctx = dict(recorded)
-    value = harness.layer_reader("ling." + name)(ctx)
-    phase_ms = scope_reduce.phase_ms(ctx, "fwd-bwd")
-    assert value is not None
-    if name.endswith(("_roofline", "mfu")):
-        assert 0 < value < 100     # tiny shapes keep the matrix unit idle
-    elif name.endswith("_pct"):
-        assert 0 <= value <= 100
-    elif name in ("fwd_bwd_ms", "opt_update_ms"):
-        assert value > 0
-    else:
-        assert 0 < value < phase_ms
+    return captures.recorded(CELL)
 
 
 def test_the_mixers_nest_on_the_recorded_capture(recorded):
     """The scan and the convolution are inside the KDA mixers' time, the
     flash kernels inside the latent row's."""
     ctx = dict(recorded)
-    read = lambda n: harness.layer_reader("ling." + n)(ctx)  # noqa: E731
-    assert read("kda_scan_ms") + read("conv_ms") < read("kda_mixer_ms")
-    assert read("flash_ms") < read("mla_mixer_ms")
-    assert read("route_ms") + read("dispatch_ms") + read("gmm_ms") < read(
-        "moe_layer_ms")
+    read = lambda n: harness.layer_reader(n)(ctx)  # noqa: E731
+    assert read("ling.kda_scan_ms") + read("kernel.ssm_conv_ms") < read(
+        "ling.kda_mixer_ms")
+    assert read("kernel.flash_ms") < read("ling.mla_mixer_ms")
+    assert (read("moe.route_ms") + read("moe.dispatch_ms")
+            + read("kernel.moe_gmm_ms")) < read("moe.layer_ms")
 
-
-def test_the_reference_imports_nothing_of_the_program():
-    for name in ("refs/ling3.py", "weights_ling3.py", "flops_ling3.py"):
-        with open(os.path.join(ROOT, "chipbench", name)) as f:
-            text = f.read()
-        assert "import chainermn_tpu" not in text
-        assert "from chainermn_tpu" not in text

@@ -34,17 +34,28 @@ def test_names_units_sources_and_chips():
     bad["workloads"][0]["chips"] = 2
     bad["per_layer"][1]["moves"] = "no_such_metric"
     bad["per_layer"][3]["workloads"] = ["cgpt-train-dp4", "no-such-cell"]
+    bad["per_layer"].extend(dict(bad["per_layer"][2], name=f"more.{i}")
+                            for i in range(128))
     text = "\n".join(faults(bad))
     for needle in ("unit 'tokens per second'", "-starts.with.dash",
                    "source: 1 to 200", "chips must be 1 or 4",
                    "moves 'no_such_metric', not an end-to-end metric",
-                   "unknown workload 'no-such-cell'"):
+                   "unknown workload 'no-such-cell'", "per_layer: 1 to 128"):
         assert needle in text, (needle, text)
 
 
 def test_share_of_four_chip_cells():
+    """At most a quarter of the cells, rounded down (one always may): one
+    more four-chip cell than that is refused, however many cells there
+    are."""
     m = harness.load_manifest()
-    m["workloads"][0]["chips"] = 4
+    allowed = max(1, len(m["workloads"]) // 4)
+    one_chip = [w for w in m["workloads"] if w["chips"] == 1]
+    have = len(m["workloads"]) - len(one_chip)
+    for w in one_chip[:allowed - have]:
+        w["chips"] = 4
+    assert faults(m) == []
+    one_chip[allowed - have]["chips"] = 4
     assert any("ask for 4 chips" in f for f in faults(m))
 
 

@@ -1,7 +1,5 @@
 """The Mellum2-12B-A2.5B configuration, its cell and its arithmetic."""
 
-import gzip
-import importlib.util
 import json
 import math
 import os
@@ -9,19 +7,12 @@ import os
 import pytest
 
 from chipbench import (flops_mellum2, harness, mellum_reduce, scope_reduce,
-                       trace_reduce, weights_mellum2)
+                       weights_mellum2)
+from chipbench.tests import captures
 
-ROOT = harness.ROOT
 CELL = "mellum2-train-1chip"
 CONFIG = "mellum2-12b-a2.5b-train"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-DATA = os.path.join(harness.HERE, "data")
-METRICS = (
-    "attn_window_ms", "attn_full_ms", "flash_window_ms", "flash_full_ms",
-    "flash_window_roofline", "flash_full_roofline", "window_tile_fill_pct",
-    "rope_ms", "moe_layer_ms", "route_ms", "dispatch_ms", "gmm_ms",
-    "gmm_roofline", "fused_ce_ms", "fwd_bwd_ms", "opt_update_ms", "mfu",
-    "idle_pct", "unattributed_pct")
 
 #: The source's ``config.json`` as the model catalog carries it
 #: (https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct), without
@@ -139,7 +130,7 @@ def test_the_cut_counts_what_the_issue_counted(cell):
                    for path in shapes for part in path)
 
 
-def test_cell_traffic_and_metrics(cell):
+def test_cell_traffic_and_limits(cell):
     entry, config, mix, limits = cell
     assert entry["chips"] == 1 and entry["traffic"] == "swamoe16k-b1"
     assert entry["config"] == CONFIG
@@ -152,21 +143,6 @@ def test_cell_traffic_and_metrics(cell):
             "router_pair_diff_share", "set_from"} <= set(limits)
     assert "PROVISIONAL" not in limits["set_from"]
     assert "65,536" in config["program"]["moe_rows_bound_note"]
-    m = harness.load_manifest()
-    metrics = harness.cell_metrics(m, CELL, "per_layer")
-    assert [x["name"] for x in metrics] == ["mellum." + n for n in METRICS]
-    for x in metrics:
-        assert x["workloads"] == [CELL] and x["moves"] == "train_step_ms"
-        assert callable(harness.layer_reader(x["name"]))
-        if x["name"].endswith(("_roofline", ".mfu")):
-            assert (x["unit"], x["better"]) == ("%", "higher")
-    e2e = {x["name"] for x in harness.cell_metrics(m, CELL, "end_to_end")}
-    assert e2e == {"train_step_ms", "setup_s"}
-    # found by name, nowhere by position: a later PR appends after these
-    assert CELL in [x["name"] for x in m["workloads"]]
-    assert CONFIG in [c["name"] for c in m["configs"]]
-    train = [x for x in m["end_to_end"] if x["name"] == "train_step_ms"][0]
-    assert CELL in train["workloads"]
 
 
 def test_rows_bound_and_tiles_of_the_cells_shape():
@@ -235,14 +211,6 @@ def test_flop_and_byte_arithmetic(cell):
         6 * (32 + 4) * 128 * 2 * 16384)
 
 
-def test_readers_return_nothing_without_a_trace(cell):
-    _, config, mix, _ = cell
-    ctx = {"config": config, "mix": mix, "device_kind": "TPU v5 lite",
-           "devices": [None], "trace_steps": 4, "trace": None}
-    for name in METRICS:
-        assert harness.layer_reader("mellum." + name)(ctx) is None
-
-
 def test_readers_return_nothing_on_a_program_without_the_reading(cell):
     """A parent commit's attribution has no ``within`` and its scope table
     no ``tiles_within``: the readers say nothing and do not raise."""
@@ -250,7 +218,7 @@ def test_readers_return_nothing_on_a_program_without_the_reading(cell):
     row = {"owner": {"attn-mixer": 1.0}, "busy": 1.0}
     ctx = {"config": config, "mix": mix, "device_kind": "TPU v5 lite",
            "trace_steps": 4, "scope_table": {},
-           "_scope_reduce": {"all": [row], "no_allreduce": [row]}}
+           "_scope_reduce": {"all": [row], "no_exchange": [row]}}
     assert mellum_reduce.within_ms(ctx, "attn-window") is None
     assert mellum_reduce.flash_roofline_pct(ctx, "full_attention") is None
     assert mellum_reduce.window_tile_fill_pct(ctx) is None
@@ -258,50 +226,9 @@ def test_readers_return_nothing_on_a_program_without_the_reading(cell):
 
 # ------------------------------------- the readers on a recorded capture
 
-def _tool():
-    spec = importlib.util.spec_from_file_location(
-        "record_swa_moe_trace",
-        os.path.join(harness.HERE, "tools", "record_swa_moe_trace.py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-TOOL = _tool()
-
-
 @pytest.fixture(scope="module")
 def recorded():
-    device_trace = pytest.importorskip(
-        "chainermn_tpu.observability.device_trace")
-    trace = trace_reduce.TraceData.from_file(
-        os.path.join(DATA, "tiny_swa_moe.xplane.pb.gz"), n_devices=1)
-    with gzip.open(os.path.join(DATA, "tiny_swa_moe.hlo.txt.gz"), "rt") as f:
-        table = device_trace.scope_table(f.read())
-    return {"trace": trace, "trace_steps": TOOL.STEPS, "scope_table": table,
-            "config": TOOL.CONFIG, "mix": TOOL.MIX, "devices": [None],
-            "device_kind": "TPU v5 lite", "moe_held_pairs": None}
-
-
-def test_the_recorder_asks_for_the_cells_metrics():
-    assert [m["name"] for m in TOOL.readers()] == [
-        "mellum." + n for n in METRICS]
-
-
-@pytest.mark.parametrize("name", METRICS)
-def test_each_reader_on_the_recorded_capture(recorded, name):
-    ctx = dict(recorded)
-    value = harness.layer_reader("mellum." + name)(ctx)
-    phase_ms = scope_reduce.phase_ms(ctx, "fwd-bwd")
-    assert value is not None
-    if name.endswith(("_roofline", "mfu")):
-        assert 0 < value < 100     # tiny shapes keep the matrix unit idle
-    elif name.endswith("_pct"):
-        assert 0 <= value <= 100
-    elif name in ("fwd_bwd_ms", "opt_update_ms"):
-        assert value > 0
-    else:
-        assert 0 < value < phase_ms
+    return captures.recorded(CELL)
 
 
 def test_the_row_kinds_add_up_on_the_recorded_capture(recorded):
@@ -320,15 +247,6 @@ def test_the_row_kinds_add_up_on_the_recorded_capture(recorded):
     tiles = ctx["notes"]["window_tiles"]
     assert len(tiles) == 3 and all(
         (t["live"], t["visited"]) == (7, 16) for t in tiles)
-
-
-def test_the_reference_imports_nothing_of_the_program():
-    for name in ("refs/mellum2.py", "weights_mellum2.py",
-                 "flops_mellum2.py"):
-        with open(os.path.join(ROOT, "chipbench", name)) as f:
-            text = f.read()
-        assert "import chainermn_tpu" not in text
-        assert "from chainermn_tpu" not in text
 
 
 # ------------------------------------------- the placement and the row

@@ -1,13 +1,11 @@
 """The per-layer readers that read device time by OWNER
 (``chipbench/parts_reduce.py``, ``flops_parts.py``): on a capture recorded
 on the chip with its step's ``as_text()``
-(``chipbench/tools/record_parts_trace.py``: a tiny ``train_hybrid`` run,
+(``chipbench/tools/record_trace.py tiny_hybrid``: a tiny ``train_hybrid`` run,
 two Mamba-2 layers, an attention layer and a third Mamba-2 layer under
 ``remat``, three traced steps on one TPU v5 lite), on the older fixture
 that has no table, and on contexts a parent commit would hand over."""
 
-import gzip
-import importlib.util
 import json
 import os
 
@@ -15,6 +13,7 @@ import pytest
 
 from chipbench import (check_manifest, flops, flops_parts, harness,
                        parts_reduce, scope_reduce, trace_reduce)
+from chipbench.tests import captures
 
 device_trace = pytest.importorskip(
     "chainermn_tpu.observability.device_trace")
@@ -22,17 +21,13 @@ device_trace = pytest.importorskip(
 DATA = os.path.join(harness.HERE, "data")
 
 
-def _tool():
-    spec = importlib.util.spec_from_file_location(
-        "record_parts_trace",
-        os.path.join(harness.HERE, "tools", "record_parts_trace.py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-TOOL = _tool()
-READERS = TOOL.READERS
+CELL = "granite4hm-train-1chip"      # the capture stands for it
+STEPS = captures.TOOL.STEPS
+_, CONFIG, MIX, _ = captures.TOOL.context(captures.RECORDED[CELL])
+READERS = ("part.ffn_ms", "part.ffn_roofline", "part.mixer_proj_ms",
+           "part.mixer_gate_ms", "part.norm_ms", "part.residual_ms",
+           "part.embed_ms", "part.recompute_ms", "parts.unowned_pct",
+           "parts.shared_pct")
 
 
 def read(name, ctx):
@@ -41,13 +36,7 @@ def read(name, ctx):
 
 @pytest.fixture(scope="module")
 def recorded():
-    trace = trace_reduce.TraceData.from_file(
-        os.path.join(DATA, "tiny_hybrid.xplane.pb.gz"), n_devices=1)
-    with gzip.open(os.path.join(DATA, "tiny_hybrid.hlo.txt.gz"), "rt") as f:
-        table = device_trace.scope_table(f.read())
-    return {"trace": trace, "trace_steps": TOOL.STEPS, "scope_table": table,
-            "config": TOOL.CONFIG, "mix": TOOL.MIX, "devices": [None],
-            "device_kind": "TPU v5 lite"}
+    return captures.recorded(CELL)
 
 
 @pytest.mark.parametrize("name", READERS)
@@ -60,7 +49,7 @@ def test_each_reader_on_the_recorded_capture(recorded, name):
         # a tiny FFN keeps the matrix unit idle: far under its peak
         assert 0 < value < 100
         assert value == pytest.approx(
-            100 * flops_parts.ffn_train_flops(TOOL.CONFIG, TOOL.MIX, 1)
+            100 * flops_parts.ffn_train_flops(CONFIG, MIX, 1)
             / flops.peaks("TPU v5 lite")["bf16_flops"]
             / (read("part.ffn_ms", ctx) / 1e3))
     elif name == "parts.unowned_pct":
@@ -80,7 +69,7 @@ def test_each_reader_on_the_recorded_capture(recorded, name):
 def test_the_owners_add_up_to_the_phase_on_the_recorded_capture(recorded):
     ctx = dict(recorded)
     (got,) = scope_reduce.attribution(ctx)["all"]
-    per_step = 1e3 / TOOL.STEPS
+    per_step = 1e3 / STEPS
     by_owner = sum(got["owner"].values()) * per_step
     assert sum(parts_reduce.pass_ms(ctx, p) for p in (
         "forward", "recompute", "backward")) == pytest.approx(by_owner)
@@ -131,16 +120,19 @@ def test_the_older_fixture_has_no_table_and_reads_nothing():
         assert read(name, ctx) in (None, 0.0), name
 
 
-@pytest.mark.parametrize("config,matrices", [
-    ("cgpt1p3b-train", 2), ("granite4hmicro-train", 3)])
-def test_ffn_flops_from_the_cells_configurations(config, matrices):
+@pytest.mark.parametrize("config,width,matrices,n_layers", [
+    ("cgpt1p3b-train", (2048, 8192), 2, 8),
+    ("granite4hmicro-train", (2048, 8192), 3, 10),
+    ("ling3flash-train", (2560, 6144), 3, 2)])
+def test_ffn_flops_from_the_cells_configurations(config, width, matrices,
+                                                 n_layers):
     c = harness.load_json(os.path.join(
         harness.HERE, "configs", config + ".json"))
     mix = {"global_batch": 8, "seq_len": 2048}
     d, d_ff, m, layers = flops_parts.ffn_shape(c)
-    assert (d, d_ff, m) == (2048, 8192, matrices)
+    assert ((d, d_ff), m, layers) == (width, matrices, n_layers)
     assert flops_parts.ffn_train_flops(c, mix, 1) == (
-        3 * 2 * 16384 * 2048 * 8192 * matrices * layers)
+        3 * 2 * 16384 * d * d_ff * matrices * layers)
     # a chip's own tokens
     assert flops_parts.ffn_train_flops(
         c, dict(mix, global_batch=32), 4) == (
@@ -149,22 +141,27 @@ def test_ffn_flops_from_the_cells_configurations(config, matrices):
         None)
 
 
-def test_the_manifest_lists_the_new_readers_where_they_read():
+def test_the_manifest_lists_the_owner_readers_where_they_read():
+    """Found by name: every cell reads the parts every model has, the
+    cells with a dense FFN the ``ffn`` pair, the cells whose mixers have a
+    float32 side ``mixer-gate``."""
     manifest = harness.load_manifest()
     assert check_manifest.check(manifest, harness.ROOT) == []
     entries = {m["name"]: m for m in manifest["per_layer"]}
     cells = [w["name"] for w in manifest["workloads"]]
-    assert [m["name"] for m in manifest["per_layer"][-len(READERS):]] == (
-        list(READERS))
     for name in READERS:
         entry = entries[name]
         assert entry["source"] == "device_trace"
         assert entry["moves"] == "train_step_ms"
         assert set(entry["workloads"]) <= set(cells)
+    for name in ("part.mixer_proj_ms", "part.norm_ms", "part.residual_ms",
+                 "part.embed_ms", "parts.unowned_pct", "parts.shared_pct"):
+        assert entries[name]["workloads"] == cells, name
+    dense = [c for c in cells if flops_parts.ffn_shape(
+        harness.find_cell(manifest, c)[1]) is not None]
     assert entries["part.ffn_roofline"]["workloads"] == (
-        entries["part.ffn_ms"]["workloads"]) == cells[:3]
-    assert entries["part.mixer_gate_ms"]["workloads"] == cells[2:4]
-    remat = [w["name"] for w in manifest["workloads"]
-             if harness.find_cell(manifest, w["name"])[1]["program"]["remat"]]
-    assert entries["part.recompute_ms"]["workloads"] == remat
+        entries["part.ffn_ms"]["workloads"]) == dense
+    assert len(dense) == 4
+    assert set(entries["part.mixer_gate_ms"]["workloads"]) == {
+        c for c in cells if not c.startswith(("cgpt", "zaya1", "mellum2"))}
     json.dumps(entries)

@@ -116,6 +116,42 @@ def test_pack_is_the_allreduce_phase_less_the_collectives():
     assert read("kernel.fused_ce_ms", ctx) is None     # no such region
 
 
+def test_the_exchange_as_a_ring_of_collective_permutes():
+    """PR 45's exchange: the hops are ``collective-permute-start`` /
+    ``-done`` pairs laid under ``fwd-bwd``; the wait sits in ``-done``,
+    the ring's adds and write-backs are the phase's local work."""
+    table = device_trace.ScopeTable({
+        "f": "jit(train_step)/fwd-bwd/jvp(LM)/dot",
+        "s": "jit(train_step)/allreduce/grad-stage0/ppermute",
+        "d": "jit(train_step)/allreduce/grad-stage0/ppermute",
+        "a": "jit(train_step)/allreduce/grad-stage0/add",
+        "w": "jit(train_step)/allreduce/grad-stage0/dynamic_update_slice",
+        "ar": "jit(train_step)/allreduce/psum",
+    }, program="jit_train_step")
+    ops = [_op("%s = (f32[8]) collective-permute-start(%g)", 0.0, 0.1),
+           _op("%f = f32[8] fusion(%x), kind=kLoop", 0.1, 4.1),
+           _op("%d = f32[8] collective-permute-done(%s)", 4.1, 4.6),
+           _op("%a = f32[8] fusion(%d, %g), kind=kLoop", 4.6, 5.0),
+           _op("%w = f32[8] dynamic-update-slice(%b, %a)", 5.0, 5.8),
+           _op("%ar = f32[] all-reduce(%l), replica_groups={}", 5.8, 6.0)]
+    ctx = {"trace": trace_reduce.TraceData(
+        [{"name": "/device:TPU:0", "ops": ops,
+          "modules": [_op("jit_train_step(1)", 0.0, 6.0)]}], []),
+        "trace_steps": 2, "scope_table": table}
+    assert read("comm.exchange_ms", ctx) == pytest.approx(400.0)
+    assert read("comm.exposed_ms", ctx) == pytest.approx(400.0)
+    assert read("comm.pack_ms", ctx) == pytest.approx(600.0)
+    assert scope_reduce.phase_ms(ctx, "allreduce") == pytest.approx(1000.0)
+    # a hop an op of another stream covers is not exposed
+    ops.append(_op("%f2 = f32[8] fusion(%x), kind=kLoop", 4.1, 4.4))
+    ctx = dict(ctx, trace=trace_reduce.TraceData(
+        [{"name": "/device:TPU:0", "ops": ops,
+          "modules": [_op("jit_train_step(1)", 0.0, 6.0)]}], []))
+    ctx.pop("_scope_reduce", None)
+    assert read("comm.exposed_ms", ctx) == pytest.approx(250.0)
+    assert read("comm.exchange_ms", ctx) == pytest.approx(400.0)
+
+
 def test_readers_refuse_a_slice_that_joins_under_98_percent():
     ok = _made_up(1, stranger=0.15)           # 9.5 of 9.65 s join: 98.4%
     assert read("trace.unattributed_pct", ok) == pytest.approx(

@@ -12,7 +12,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
-os.environ["CHAINERMN_TPU_AUTOTUNE"] = "0"
 
 from chipbench import harness  # noqa: E402
 

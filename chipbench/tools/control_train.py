@@ -17,7 +17,6 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
-os.environ["CHAINERMN_TPU_AUTOTUNE"] = "0"
 
 
 def gaps(readings, ref, worst_leaf_gap):
