@@ -4,13 +4,13 @@ a training cell (``chipbench/run.py``'s own, its arguments passed
 through) with one more reading of the traced slice written to ``$DUMP``
 as JSON — device ms a step by (phase of the step, scopes on the op's
 path, kind of op), the ``all-reduce`` and ``collective-permute`` ops'
-time and the exposed part of it.  The manifest's ``comm.allreduce_ms`` /
-``comm.exposed_ms`` match ``all-reduce(`` only and ``comm.pack_ms`` is
-the ``allreduce`` scope less those ops, so since PR 45 (the exchange as
-rings of collective-permutes) the ring's waits, adds and write-backs all
-read as ``comm.pack_ms``; this is the split behind it until a
-``benchmark`` PR re-points the readers (ROADMAP D13 i).  PERF.md §5 (dp4)
-rests on it.
+time and the exposed part of it.  Since PR 46 the manifest's
+``comm.exchange_ms`` (``comm.allreduce_ms`` before) and
+``comm.exposed_ms`` match ``all-reduce`` and ``collective-permute`` ops
+alike (``scope_reduce.EXCHANGE``, the pattern below) and
+``comm.pack_ms`` is the ``allreduce`` scope less those ops: the ring's
+piece cuts, adds and write-backs.  This tool is the finer split behind
+them — by scope and kind of op — on which PERF.md §5 (dp4) rests.
 
     chiprun --chips 4 -- env DUMP=chiprun_out/exchange_split.json python \
         benchmarks/exchange_split.py --workload cgpt-train-dp4 \

@@ -29,7 +29,12 @@ untied head) and ``bailing_hybrid`` (five Kimi-Delta-Attention rows — a
 delta rule under a decay a key channel — to one latent-attention (MLA)
 row whose scores are wider than its values, a head-wise sigmoid gate on
 both; two leading dense SwiGLU FFNs, then a group-limited sigmoid top-k
-sparse-expert FFN with a shared expert; RMSNorm, an untied head).
+sparse-expert FFN with a shared expert; RMSNorm, an untied head) and
+``sdar_moe`` (identical layers of a GQA row with QK-norm and whole-head
+rotary positions and a softmax top-k sparse-expert FFN with no shared
+expert; RMSNorm, an untied head — trained by block diffusion, which the
+table states once: every attention row sees a document's clean and
+noised copies through one block-causal mask).
 
 Plain frozen dataclasses: hashable, so a table is a static field of the
 flax module.
@@ -470,8 +475,22 @@ class BlockTable:
     final_norm: str = "layernorm"
     norm_eps: float = 1e-6
     tied_head: bool = True
+    block_diffusion: Optional[int] = None   # the table is trained by
+    # block diffusion with this block length: a step runs a document's
+    # clean copy and its noised one as 2 L rows, and EVERY attention row
+    # sees them through the block-diffusion mask
+    # (``ops.flash_attention.blockdiff_mask``), whatever else the rows say
 
     def __post_init__(self):
+        if self.block_diffusion is not None and (
+                self.block_diffusion < 1 or any(
+                    r.mixer not in ("attention", "none") or r.mla is not None
+                    or r.window is not None for r in self.layers)):
+            raise ValueError(
+                "block_diffusion is a block length >= 1 of a table whose "
+                "mixers are plain attention rows without a window: the "
+                "mixers that read the token before (cca, gdn, kda, mamba2) "
+                "and a latent or a windowed row have no such mask")
         if self.positions not in POSITIONS:
             raise ValueError(f"positions must be one of {POSITIONS}, "
                              f"got {self.positions!r}")
@@ -525,10 +544,10 @@ def _first_layers(kinds, n_layers):
 def table_from_config(config: Mapping, n_layers: Optional[int] = None,
                       experts_held: Optional[Tuple[int, int]] = None
                       ) -> BlockTable:
-    """The table of a published ``config.json``, by its own keys.  Six
+    """The table of a published ``config.json``, by its own keys.  Seven
     families are read, by ``model_type``: ``granitemoehybrid`` (its dense
     members: ``num_local_experts`` 0), ``nemotron_h``, ``zaya``,
-    ``qwen3_next``, ``mellum`` and ``bailing_hybrid``.  ``n_layers``
+    ``qwen3_next``, ``mellum``, ``bailing_hybrid`` and ``sdar_moe``.  ``n_layers``
     keeps the first so many layers (a pipeline stage, a cut to fit); None
     keeps ``num_hidden_layers``.  ``experts_held`` is the ``(first,
     count)`` of the published experts this rank holds in every expert
@@ -538,7 +557,8 @@ def table_from_config(config: Mapping, n_layers: Optional[int] = None,
     readers = {"granitemoehybrid": _granite_table,
                "nemotron_h": _nemotron_h_table, "zaya": _zaya_table,
                "qwen3_next": _qwen3_next_table, "mellum": _mellum_table,
-               "bailing_hybrid": _bailing_hybrid_table}
+               "bailing_hybrid": _bailing_hybrid_table,
+               "sdar_moe": _sdar_moe_table}
     reader = readers.get(config.get("model_type"))
     if reader is None:
         raise ValueError(
@@ -881,6 +901,64 @@ def _mellum_table(config, n_layers, experts_held):
         layers=tuple(rows[k] for k in _first_layers(kinds, n_layers)),
         positions="rotary", final_norm="rmsnorm", norm_eps=eps,
         tied_head=False)
+
+
+#: The block length an ``sdar_moe`` table trains with where the config
+#: states none: the family's released ``block_length`` (its generation
+#: config's; ``config.json`` has no key for it).
+SDAR_BLOCK_LENGTH = 4
+
+
+def _sdar_moe_table(config, n_layers, experts_held):
+    """``sdar_moe``: ``num_hidden_layers`` identical layers — a GQA
+    attention row (``num_attention_heads`` query and
+    ``num_key_value_heads`` key/value heads of ``head_dim``, no biases,
+    the family's QK-norm, rotary positions on the whole head at
+    ``rope_theta``) and ``num_experts`` gated experts of
+    ``moe_intermediate_size``, ``num_experts_per_tok`` a token by a
+    softmax router renormalised over the chosen, no shared expert;
+    RMSNorm, an untied head.  The model is trained by block diffusion:
+    the TABLE says so (``block_diffusion``, the block length: the
+    config's ``block_length`` where it states one, else
+    :data:`SDAR_BLOCK_LENGTH`), and every attention row then runs under
+    that mask, at the positions it is handed.  Refused by key:
+    ``use_sliding_window``, dense layers among the sparse ones
+    (``mlp_only_layers``, a ``decoder_sparse_step`` other than 1), a
+    ``rope_scaling``, router weights not renormalised, biases, another
+    activation, a tied head."""
+    _refuse([
+        (bool(config.get("use_sliding_window")), "use_sliding_window true"),
+        (bool(config.get("mlp_only_layers")),
+         "a non-empty mlp_only_layers (dense layers among the sparse "
+         "ones)"),
+        (config.get("decoder_sparse_step", 1) != 1,
+         "decoder_sparse_step other than 1"),
+        (config.get("rope_scaling") is not None, "a rope_scaling"),
+        (not config.get("norm_topk_prob", True),
+         "norm_topk_prob false (router weights not renormalised over the "
+         "chosen)"),
+        (bool(config.get("attention_bias")), "attention_bias"),
+        (config.get("hidden_act") != "silu", "hidden_act other than silu"),
+        (bool(config.get("tie_word_embeddings")), "a tied output head"),
+    ])
+    eps = float(config["rms_norm_eps"])
+    row = LayerSpec(
+        mixer="attention", norm="rmsnorm", ffn="experts", norm_eps=eps,
+        n_heads=config["num_attention_heads"],
+        n_kv_heads=config["num_key_value_heads"],
+        d_head=config["head_dim"], rotary_dim=config["head_dim"],
+        rope_theta=float(config["rope_theta"]), qk_norm=True,
+        experts=ExpertsSpec(
+            n_experts=config["num_experts"],
+            top_k=config["num_experts_per_tok"],
+            d_expert=config["moe_intermediate_size"], d_shared=0,
+            held=experts_held, router="softmax", expert="swiglu"))
+    return BlockTable(
+        layers=tuple(_first_layers(
+            [row] * config["num_hidden_layers"], n_layers)),
+        positions="rotary", final_norm="rmsnorm", norm_eps=eps,
+        tied_head=False,
+        block_diffusion=int(config.get("block_length", SDAR_BLOCK_LENGTH)))
 
 
 #: Every key the ``bailing_hybrid`` reader takes: read, held to the one

@@ -111,6 +111,15 @@ class MultiHeadAttention(nn.Module):
                                     # handed to the ``attention_fn`` as
                                     # ``window=``, a band on the dense
                                     # path's mask
+    block_diffusion: Optional[int] = None  # the row runs under the
+                                    # block-diffusion mask with this block
+                                    # (its table's): the rows are a
+                                    # document's clean copy then its noised
+                                    # one (``flash_attention.
+                                    # blockdiff_mask``), handed to the
+                                    # ``attention_fn`` as
+                                    # ``block_diffusion=``, the dense
+                                    # path's whole mask
     qk_norm: Optional[str] = None   # a norm of ``NORM_CLASSES`` over each
                                     # query and key head, before the
                                     # rotation (``q_norm``, ``k_norm``)
@@ -120,18 +129,21 @@ class MultiHeadAttention(nn.Module):
 
     @nn.compact
     def __call__(self, q_in, kv_in, mask=None, *, block_tables=None,
-                 seq_lens=None):
+                 seq_lens=None, positions=None):
+        """``positions``: the (S,) token positions the row's rotary
+        positions turn by; None is ``0 .. S-1``."""
         d_head = self.d_head or self.d_model // self.n_heads
         n_kv = self.n_kv_heads or self.n_heads
         if (self.rotary_dim or self.qk_norm or self.out_gate
-                or self.window is not None) and (
+                or self.window is not None
+                or self.block_diffusion is not None) and (
                 self.decode or self.paged is not None):
             raise ValueError(
                 "an attention row with rotary positions, QK-norm, an "
-                "output gate or a window is built for training and "
-                "whole-sequence evaluation: the KV caches take no "
-                "positions, keep no gate and free no page behind a "
-                "window")
+                "output gate, a window or the block-diffusion mask is "
+                "built for training and whole-sequence evaluation: the "
+                "KV caches take no positions, keep no gate, free no page "
+                "behind a window and yield a token a step, not a block")
         if self.n_heads % n_kv:
             raise ValueError(
                 f"n_kv_heads ({n_kv}) must divide n_heads ({self.n_heads})"
@@ -173,7 +185,8 @@ class MultiHeadAttention(nn.Module):
                     with nn.override_named_call(False):
                         q, k = norm("q_norm")(q), norm("k_norm")(k)
                 if self.rotary_dim:
-                    pos = jnp.arange(q.shape[1])
+                    pos = (jnp.arange(q.shape[1]) if positions is None
+                           else positions)
                     q, k = (rotate_partial(
                         x, pos, self.rotary_dim, self.rope_theta,
                         self.yarn).astype(self.dtype) for x in (q, k))
@@ -413,10 +426,19 @@ class MultiHeadAttention(nn.Module):
         if self.attention_fn is not None:
             # GQA-aware adapters (flash and its SP compositions) consume
             # the reduced kv head count directly, and take the row's
-            # window where it has one.
-            banded = {} if self.window is None else {"window": self.window}
-            out = self.attention_fn(q, k, v, mask, **banded)
+            # window where it has one, its block where its table trains
+            # by block diffusion.
+            from chainermn_tpu.ops.flash_attention import row_mask
+
+            out = self.attention_fn(q, k, v, mask, **row_mask({
+                "window": self.window,
+                "block_diffusion": self.block_diffusion}))
         else:
+            if self.block_diffusion is not None:
+                from chainermn_tpu.ops.flash_attention import blockdiff_mask
+
+                mask = blockdiff_mask(
+                    q.shape[1] // 2, self.block_diffusion)[None, None]
             if self.window is not None:
                 # (a window is causal, as the kernels have it)
                 behind = jnp.arange(q.shape[1])[:, None] - jnp.arange(
@@ -1236,10 +1258,11 @@ class Block(nn.Module):
     page_size: int = 0
     kv_dtype: Optional[str] = None
     sp_axis: Optional[str] = None
+    block_diffusion: Optional[int] = None   # the table's
 
     @nn.compact
     def __call__(self, x, mask=None, router_state=None, *,
-                 block_tables=None, seq_lens=None):
+                 block_tables=None, seq_lens=None, positions=None):
         row = self.row
 
         def normed(x):
@@ -1279,7 +1302,9 @@ class Block(nn.Module):
         elif row.mixer == "attention":
             h = normed(x)
             with named_scope(
-                    "attn-mixer" if row.window is None else "attn-window"):
+                    "attn-blockdiff" if self.block_diffusion is not None
+                    else "attn-mixer" if row.window is None
+                    else "attn-window"):
                 branch = MultiHeadAttention(
                     self.d_model, row.n_heads, self.dtype, self.attention_fn,
                     decode=self.decode, cache_len=self.cache_len,
@@ -1291,7 +1316,9 @@ class Block(nn.Module):
                     yarn=row.yarn, window=row.window,
                     qk_norm=row.norm if row.qk_norm else None,
                     norm_eps=row.norm_eps, out_gate=row.out_gate,
-                )(h, h, mask, block_tables=block_tables, seq_lens=seq_lens)
+                    block_diffusion=self.block_diffusion,
+                )(h, h, mask, block_tables=block_tables, seq_lens=seq_lens,
+                  positions=positions)
             x = residual(x, branch)
         elif row.mixer == "mamba2":
             if self.decode or self.paged is not None:
@@ -1461,6 +1488,10 @@ class TransformerLM(nn.Module):
                  inputs_embeds=None, block_tables=None, seq_lens=None,
                  router_state=None):
         """``position_offset``: global position of this shard's first token —
+        for a table with rotary positions whose rows are all plain
+        attention rows, the positions those rows turn by (a scalar, or an
+        ``(S,)`` array: block-diffusion training runs ``0 .. L-1`` twice);
+        for sinusoidal positions,
         pass ``axis_index * S_local`` when the sequence dimension is sharded
         (sequence parallelism); requires a sequence-aware ``attention_fn``
         (ring/Ulysses), since the dense path's causal mask is local.
@@ -1509,12 +1540,39 @@ class TransformerLM(nn.Module):
 
         table = self.block_table
         S = tokens.shape[1]
+        positions = None
         if table.positions == "rotary" and position_offset is not None:
-            raise ValueError(
-                "rotary positions are built from 0 inside the mixers (the "
-                "cca mixer's convolutions and value read the token before; "
-                "an attention row rotates by its own index): a sharded or "
-                "offset sequence is not built")
+            if any(row.mixer not in ("attention", "none")
+                   or row.mla is not None for row in table.layers):
+                raise ValueError(
+                    "rotary positions are built from 0 inside the mixers "
+                    "that read the token before (the cca mixer's "
+                    "convolutions and value; the gdn, kda and mamba2 "
+                    "mixers' convolutions and state) and inside a "
+                    "latent-attention row: a sharded or offset sequence "
+                    "is not built for them")
+            # Plain attention rows turn by the positions they are handed:
+            # an (S,) array as it is, a scalar as the first token's.
+            positions = (position_offset
+                         if getattr(position_offset, "ndim", 0) == 1
+                         else position_offset + jnp.arange(S))
+            if positions.shape != (S,):
+                raise ValueError(
+                    f"rotary positions are one (S,) array for every row "
+                    f"of the batch, got {positions.shape}")
+        if table.block_diffusion is not None:
+            if positions is None or S % 2:
+                raise ValueError(
+                    "a table trained by block diffusion runs 2 L rows, a "
+                    "document's clean copy then its noised one, at the "
+                    "positions it is handed (0 .. L-1 twice: "
+                    "models.block_diffusion.block_diffusion_loss builds "
+                    "them)")
+            if self.decode or self.paged is not None:
+                raise ValueError(
+                    "generation by iterative unmasking inside a block is "
+                    "not built: the serving step yields a token, not a "
+                    "block")
         if inputs_embeds is not None and not return_hidden:
             raise ValueError(
                 "inputs_embeds requires return_hidden=True: the tied "
@@ -1579,13 +1637,16 @@ class TransformerLM(nn.Module):
                 cache_len=self.max_len if self.decode else 0,
                 paged=self.paged, page_count=self.page_count,
                 page_size=self.page_size, kv_dtype=self.kv_dtype,
-                sp_axis=self.sp_axis,
+                sp_axis=self.sp_axis, block_diffusion=table.block_diffusion,
             )
+            # (a row is handed positions only where the table has them to
+            # hand: every other call is the one it was)
+            placed = {} if positions is None else {"positions": positions}
             if router_state is None:
                 x = layer(x, mask, block_tables=block_tables,
-                          seq_lens=seq_lens)
+                          seq_lens=seq_lens, **placed)
             else:
-                x, router_state = layer(x, mask, router_state)
+                x, router_state = layer(x, mask, router_state, **placed)
         if router_state is not None:
             self.sow("intermediates", "router_state", router_state)
         with named_scope("norm"):

@@ -100,7 +100,9 @@ KERNEL_REGIONS = (
 #: in it as they do in ``cca-mixer``; ``attn-window`` in its place where
 #: the row sees through a sliding window, so that a capture tells the two
 #: kinds of row apart — ``device_trace``'s ``within`` reading has the
-#: flash regions' time under each), every mixer's dense matrices
+#: flash regions' time under each — and ``attn-blockdiff`` where the
+#: row's table trains by block diffusion and the row sees ``[clean ;
+#: noisy]`` through that mask), every mixer's dense matrices
 #: (``mixer-proj``), a mixer's float32 side (``mixer-gate``) — Mamba-2's
 #: ``dt`` softplus, ``-exp(A_log)``, the casts of ``y`` and the gate, ``y *
 #: silu(gate)`` and the gated norm; the Gated DeltaNet's ``beta``, ``g``,
@@ -110,8 +112,8 @@ KERNEL_REGIONS = (
 #: ``mamba-mixer``); the OWNER reading of ``device_trace`` takes the
 #: innermost name of both tuples.
 MODEL_PARTS = (
-    "embed", "norm", "residual", "attn-mixer", "attn-window", "mixer-proj",
-    "mixer-gate", "ffn",
+    "embed", "norm", "residual", "attn-mixer", "attn-window",
+    "attn-blockdiff", "mixer-proj", "mixer-gate", "ffn",
 )
 
 #: What the jitted programs compile as (``jit_<name>`` in a capture's

@@ -197,7 +197,106 @@ def _live_ranges(Sq, Sk, block_q, block_k, causal, window):
             functools.partial(_q_live_range, n_q=Sq // block_q, **geometry))
 
 
-def tile_census(Sq, Sk, block_q, block_k, causal, window):
+def blockdiff_pairs(L, B):
+    """(query, key) pairs one head row attends under the block-diffusion
+    mask over ``[clean ; noisy]``: a clean row its own block and every
+    block before, a noisy row every clean block before its own and its
+    own noisy block — ``L (L + B) / 2 + L (L - B) / 2 + L B``."""
+    return L * L + L * B
+
+
+def _blockdiff_tiles(L, B, block_q, block_k):
+    """``(live, full)`` boolean ``(2 L / block_q, 2 L / block_k)``: the
+    tiles of the ``[clean ; noisy]`` rectangle in which the
+    block-diffusion mask (:func:`blockdiff_mask`) lets any pair attend,
+    and those among them in which it lets every pair (an interior tile:
+    clean keys wholly before the queries' first block).  From the first
+    and last block ``B`` a tile's rows and columns lie in."""
+    n_q, n_k = L // block_q, L // block_k
+    iq = np.arange(2 * n_q)[:, None]
+    ik = np.arange(2 * n_k)[None, :]
+    q_noisy, k_noisy = iq >= n_q, ik >= n_k
+    q0 = (iq % n_q) * block_q // B
+    q1 = ((iq % n_q) * block_q + block_q - 1) // B
+    k0 = (ik % n_k) * block_k // B
+    k1 = ((ik % n_k) * block_k + block_k - 1) // B
+    live = np.where(k_noisy, q_noisy & (k0 <= q1) & (q0 <= k1),
+                    k0 <= q1 - q_noisy)
+    return live, ~k_noisy & (k1 <= q0 - q_noisy)
+
+
+@functools.lru_cache(maxsize=None)
+def _blockdiff_walk(L, B, block_q, block_k, streamed):
+    """The live tiles of the block-diffusion mask as the three int32
+    tables a kernel's grid walks (scalar-prefetched: the index maps and
+    the kernel read step ``t``'s row): the q tile, the k tile, and flags
+    — 1 the first tile of its resident block, 2 the last, 4 a tile the
+    mask cuts (an interior tile pays no compare).  ``streamed="kv"``:
+    q-major, a q tile's clean K tiles in order and then, for a noisy
+    tile, its diagonal noisy ones (forward and dq); ``"q"``: k-major, the
+    transposed statement (dk/dv).  Dead tiles are in no table: none is
+    visited.  (A pure function of its arguments, asked for by the census
+    and by each kernel's wrapper at every trace: cached.)"""
+    live, full = _blockdiff_tiles(L, B, block_q, block_k)
+    if streamed == "q":
+        tk, tq = np.nonzero(live.T)
+        resident = tk
+    else:
+        tq, tk = np.nonzero(live)
+        resident = tq
+    edge = np.flatnonzero(np.diff(resident)) + 1
+    flags = np.where(full[tq, tk], 0, 4)
+    flags[np.r_[0, edge]] |= 1
+    flags[np.r_[edge - 1, len(flags) - 1]] |= 2
+    return tuple(np.asarray(a, np.int32) for a in (tq, tk, flags))
+
+
+def _blockdiff_step(tables, t, L, B, block_q, block_k):
+    """What a kernel under the block-diffusion mask reads of its tables
+    at walk step ``t``: ``(iq, ik, first, last, cut, mask_of)`` —
+    ``cut`` whether the mask cuts the tile and ``mask_of(shape)`` its
+    (block_q, block_k) boolean then.  The rows of a tile lie on one side
+    (``block`` divides ``L``), so the side is a scalar and the mask one
+    pair of comparisons between the queries' blocks, a column, and the
+    keys', a row: clean keys ``blk(k) <= blk(q) - [q noisy]``, noisy keys
+    ``blk(k) == blk(q)``."""
+    tq, tk, fl = tables
+    iq, ik, flags = tq[t], tk[t], fl[t]
+    n_q, n_k = L // block_q, L // block_k
+
+    def blocks(tile, n, block, shape, axis):
+        pos = (tile % n) * block + jax.lax.broadcasted_iota(
+            jnp.int32, shape, axis)
+        if B & (B - 1) == 0:
+            return jax.lax.shift_right_logical(
+                pos, jnp.int32(B.bit_length() - 1))
+        return jax.lax.div(pos, jnp.int32(B))
+
+    def mask_of(shape):
+        qb = blocks(iq, n_q, block_q, (shape[0], 1), 0)
+        kb = blocks(ik, n_k, block_k, (1, shape[1]), 1)
+        q_noisy = (iq >= n_q).astype(jnp.int32)
+        k_noisy = (ik >= n_k).astype(jnp.int32)
+        return (kb >= qb * k_noisy) & (kb <= qb - q_noisy * (1 - k_noisy))
+
+    return (iq, ik, (flags & 1) != 0, (flags & 2) != 0, (flags & 4) != 0,
+            mask_of)
+
+
+def blockdiff_mask(L, B):
+    """The block-diffusion mask itself, ``(2 L, 2 L)`` boolean over the
+    row layout ``[clean ; noisy]``, by comparison (the dense path's and
+    the tests'): with ``blk(i) = (i mod L) // B``, clean q and clean k
+    ``blk(k) <= blk(q)``; noisy q and clean k ``blk(k) < blk(q)``; noisy
+    q and noisy k ``blk(k) == blk(q)``; clean q and noisy k never."""
+    pos = jnp.arange(2 * L)
+    noisy, blk = pos >= L, (pos % L) // B
+    qn, kn = noisy[:, None], noisy[None, :]
+    qb, kb = blk[:, None], blk[None, :]
+    return jnp.where(kn, qn & (kb == qb), kb <= qb - qn)
+
+
+def tile_census(Sq, Sk, block_q, block_k, causal, window, blockdiff=None):
     """What one head row's grid does at this geometry — ``{"fwd", "dq",
     "dkv"}``, each ``{block_q, block_k, live, visited, copied}``: tiles
     that intersect the band (they run the matmuls), grid steps, and
@@ -208,7 +307,20 @@ def tile_census(Sq, Sk, block_q, block_k, causal, window):
     past the causal bound entered and skipped; with one it is the band
     grid's ``n_q x`` :func:`_band_steps` (``n_k x`` the query side's in
     dk/dv), which is ``live`` and the few repeats of the blocks near the
-    sequence's start."""
+    sequence's start.  Under the block-diffusion mask (``blockdiff`` =
+    ``(L, B)``) the grid is the list of live tiles itself
+    (:func:`_blockdiff_walk`): ``visited`` is ``live``."""
+
+    def fetches(steps):
+        return 1 + int(np.count_nonzero(np.diff(steps.ravel())))
+
+    if blockdiff is not None:
+        base = {"block_q": block_q, "block_k": block_k}
+        _, tk, _ = _blockdiff_walk(*blockdiff, block_q, block_k, "kv")
+        tq, _, _ = _blockdiff_walk(*blockdiff, block_q, block_k, "q")
+        kv = dict(base, live=len(tk), visited=len(tk), copied=fetches(tk))
+        return {"fwd": kv, "dq": kv, "dkv": dict(
+            base, live=len(tq), visited=len(tq), copied=fetches(tq))}
     n_q, n_k = Sq // block_q, Sk // block_k
     iq = np.arange(n_q)[:, None]
     ik = np.arange(n_k)[None, :]
@@ -217,10 +329,6 @@ def tile_census(Sq, Sk, block_q, block_k, causal, window):
     live = int(np.broadcast_to(_band_live(
         causal, window, iq * block_q, block_q, ik * block_k, block_k,
     ), (n_q, n_k)).sum())
-
-    def fetches(steps):
-        return 1 + int(np.count_nonzero(np.diff(steps.ravel())))
-
     if window is None:
         kv_steps = np.broadcast_to(np.clip(ik, *kv_range(iq, xp=np)),
                                    (n_q, n_k))
@@ -239,40 +347,85 @@ def tile_census(Sq, Sk, block_q, block_k, causal, window):
             "dkv": dict(base, visited=q_steps.size, copied=fetches(q_steps))}
 
 
+def _walked(refs, blockdiff, block_q, block_k):
+    """``(walk, refs)`` of a kernel's operands: under the
+    block-diffusion mask the three scalar-prefetched tables lead them,
+    and ``walk`` is :func:`_blockdiff_step` at this program's step of the
+    flattened tile axis; else None and the operands as they are."""
+    if blockdiff is None:
+        return None, refs
+    return _blockdiff_step(refs[:3], pl.program_id(1), *blockdiff,
+                           block_q, block_k), refs[3:]
+
+
+def _edges(walk, step, steps):
+    """``(first, last)``, each a function of nothing: whether this
+    program opens or closes its resident block's accumulation — the ends
+    of the inner grid axis, or what the walk's flags say.  (Functions,
+    so that a call without the mask traces each comparison where it
+    always did: ``tests/golden/flash_no_window.json`` holds the order of
+    a kernel's equations.)"""
+    if walk is None:
+        return (lambda: step == 0), (lambda: step == steps - 1)
+    return (lambda: walk[2]), (lambda: walk[3])
+
+
+def _band_run(causal, window, q_start, block_q, k_start, block_k, in_band):
+    """Whole-block skip of a call whose grid is a rectangle or a band: K
+    block past the causal bound OR entirely before the sliding window's
+    reach (a window's grid is its band: only the repeats of a short
+    band's last block are left to skip)."""
+    run = _band_live(causal, window, q_start, block_q, k_start, block_k)
+    if window is not None:
+        run = run & in_band
+    return run
+
+
+def _run_tiles(tile, walk, run, mask_of):
+    """Enter a kernel's tile body: where the grid is a rectangle or a
+    band, when ``run()`` says the tile is live, under ``mask_of``; where
+    it is the walk of the block-diffusion mask's live tiles every step
+    is live, and a tile the mask cuts runs the body with the mask, an
+    interior one the body without."""
+    if walk is None:
+        pl.when(run())(lambda: tile(mask_of))
+        return
+    *_, cut, cut_mask = walk
+    pl.when(cut)(lambda: tile(cut_mask))
+    pl.when(jnp.logical_not(cut))(lambda: tile(lambda shape: None))
+
+
 def _attn_kernel(
     *refs,
     scale: float, causal: bool, segmented: bool, block_q: int, block_k: int,
-    kv_range, window=None,
+    kv_range, window=None, blockdiff=None,
 ):
+    walk, refs = _walked(refs, blockdiff, block_q, block_k)
     if segmented:
         (q_ref, k_ref, v_ref, qs_ref, ks_ref,
          o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
         qs_ref = ks_ref = None
-    iq = pl.program_id(1)
-    j = pl.program_id(2)
-    n_j = pl.num_programs(2)
+    j = n_j = None
+    if walk is None:
+        iq = pl.program_id(1)
+        j = pl.program_id(2)
+        n_j = pl.num_programs(2)
+    first, last = _edges(walk, j, n_j)
 
-    @pl.when(j == 0)
+    @pl.when(first())
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q_start = iq * block_q
-    ik, in_band = _streamed_block(kv_range, iq, j, window)
-    k_start = ik * block_k
+    if walk is None:
+        q_start = iq * block_q
+        ik, in_band = _streamed_block(kv_range, iq, j, window)
+        k_start = ik * block_k
 
-    # Whole-block skip: K block past the causal bound OR entirely before
-    # the sliding window's reach (a window's grid is its band: only the
-    # repeats of a short band's last block are left to skip).
-    run = _band_live(causal, window, q_start, block_q, k_start, block_k)
-    if window is not None:
-        run = run & in_band
-
-    @pl.when(run)
-    def _():
+    def tile(mask_of):
         # MXU-native matmuls: operands stay in their input dtype (bf16 on
         # the training path — one MXU pass) with fp32 accumulation via
         # preferred_element_type; only the softmax runs in fp32.
@@ -281,8 +434,7 @@ def _attn_kernel(
         v = v_ref[0]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
 
-        mask = _block_mask(s.shape, causal, q_start, k_start, qs_ref,
-                           ks_ref, window)
+        mask = mask_of(s.shape)
         if mask is not None:
             s = jnp.where(mask, s, _NEG_INF)
 
@@ -290,7 +442,8 @@ def _attn_kernel(
         m_blk = jnp.max(s, axis=1)
         m_new = jnp.maximum(m_prev, m_blk)
         p = jnp.exp(s - m_new[:, None])
-        if segmented or window is not None:
+        if mask is not None and (segmented or window is not None
+                                 or blockdiff is not None):
             # A row fully masked in this block has m_new == _NEG_INF ==
             # its masked scores, making exp(s - m_new) = 1 — zero those
             # entries so padding rows accumulate nothing.  (Causal-only
@@ -298,7 +451,10 @@ def _attn_kernel(
             # low-k windowed block is admitted because the q block's
             # EARLY rows still reach it, while its LATE rows — whose
             # window starts later — can be fully masked on this, their
-            # first visited block, so the window path needs this too.)
+            # first visited block, so the window path needs this too.
+            # Under the block-diffusion mask a noisy row of the first
+            # block sees no clean key at all, and its first tile is a
+            # clean one.)
             p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
 
@@ -308,7 +464,12 @@ def _attn_kernel(
         )
         m_ref[:, 0] = m_new
 
-    @pl.when(j == n_j - 1)
+    _run_tiles(tile, walk, lambda: _band_run(
+        causal, window, q_start, block_q, k_start, block_k, in_band),
+        lambda shape: _block_mask(shape, causal, q_start, k_start, qs_ref,
+                                  ks_ref, window))
+
+    @pl.when(last())
     def _():
         denom = jnp.maximum(l_ref[:, 0], 1e-30)
         o_ref[0] = (acc_ref[:] / denom[:, None]).astype(o_ref.dtype)
@@ -397,18 +558,64 @@ def _kv_group(BHq: int, BHk: int) -> int:
     return BHq // BHk
 
 
+def _masked(blockdiff):
+    """The kernels' ``blockdiff`` keyword where the call has the mask: a
+    call without it builds its kernel from the keywords it always had."""
+    return {} if blockdiff is None else {"blockdiff": blockdiff}
+
+
+def _kv_walk(kv_range, n_q, n_k, causal, window, blockdiff, block_q,
+             block_k):
+    """``(q_at, k_at, inner, tables)`` of a call that keeps a Q block
+    resident and streams K and V (forward, dq): the two block indices as
+    functions of what an index map gets after the head row, the grid's
+    inner axes, and the tables to prefetch.  Without the block-diffusion
+    mask the axes are ``(q block, step)`` and :func:`_streamed_axis` maps
+    the step; with it one axis walks :func:`_blockdiff_walk`'s tables,
+    which the index maps get after the step."""
+    if blockdiff is None:
+        kv_j, n_j = _streamed_axis(kv_range, n_q, n_k, causal, window)
+        return (lambda i, j: i), kv_j, (n_q, n_j), ()
+    tables = _walk_operands(blockdiff, block_q, block_k, "kv")
+    return ((lambda t, tq, tk, fl: tq[t]), (lambda t, tq, tk, fl: tk[t]),
+            (len(tables[0]),), tables)
+
+
+def _walk_operands(blockdiff, block_q, block_k, streamed):
+    """:func:`_blockdiff_walk`'s tables as a ``pallas_call``'s leading
+    operands."""
+    return tuple(jnp.asarray(a) for a in _blockdiff_walk(
+        *blockdiff, block_q, block_k, streamed))
+
+
 #: The two kernel wrappers are jitted in their own right: a model calls
 #: them once a layer with the same shapes, and a jitted callee is traced
 #: once and lowered to one function that every layer calls — Mosaic's
 #: lowering of a 1024 x 1024 tile, paid at every process start even with
 #: the compile cache warm, is then paid three times and not 3 x layers.
 _KERNEL_STATICS = ("scale", "causal", "block_q", "block_k", "interpret",
-                   "window")
+                   "window", "blockdiff")
+
+
+def _pallas(kernel, tables, *, grid, in_specs, out_specs, scratch_shapes,
+            **params):
+    """``pl.pallas_call`` of one of the three kernels: as it always was
+    where no table leads the operands; with ``tables`` (the
+    block-diffusion walk's) scalar-prefetched, so that the index maps and
+    the kernel read them by the step."""
+    if not tables:
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes, **params)
+    return pl.pallas_call(
+        kernel, grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables), grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes), **params)
 
 
 @functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
 def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
-                  q_seg=None, kv_seg=None, window=None):
+                  q_seg=None, kv_seg=None, window=None, blockdiff=None):
     """(BH, S, D) flash attention forward; returns (o, lse).  ``v`` may
     be narrower or wider than ``q`` and ``k`` (BHk, Sk, D_v): ``o`` is
     then (BH, Sq, D_v).
@@ -419,19 +626,26 @@ def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
     head with no materialized repeat.
 
     ``q_seg``/``kv_seg``: optional (BH, S, 1) int32 segment ids for packed
-    sequences — attention is masked to segment-id equality."""
+    sequences — attention is masked to segment-id equality.
+
+    ``blockdiff``: ``(L, B)``, the block-diffusion mask over ``S = 2 L``
+    rows ``[clean ; noisy]`` (:func:`blockdiff_mask`): the grid's inner
+    axis is then the q-major list of the mask's live tiles."""
     BH, Sq, D = q.shape
     Sk, Dv = k.shape[1], v.shape[2]
     G = _kv_group(BH, k.shape[0])
     n_q = Sq // block_q
     kv_range, _ = _live_ranges(Sq, Sk, block_q, block_k, causal, window)
-    kv_j, n_j = _streamed_axis(kv_range, n_q, Sk // block_k, causal, window)
-    grid = (BH, n_q, n_j)
+    q_at, k_at, inner, tables = _kv_walk(
+        kv_range, n_q, Sk // block_k, causal, window, blockdiff, block_q,
+        block_k)
+    grid = (BH, *inner)
     segmented = q_seg is not None
 
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal=causal, segmented=segmented,
         block_q=block_q, block_k=block_k, kv_range=kv_range, window=window,
+        **_masked(blockdiff),
     )
     scratch = [
         pltpu.VMEM((block_q, Dv), jnp.float32),
@@ -439,24 +653,25 @@ def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
         pltpu.VMEM((block_q, 1), jnp.float32),
     ]
     in_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, block_q, D), lambda b, *g: (b, q_at(*g), 0)),
         pl.BlockSpec((1, block_k, D),
-                     lambda b, i, j: (b // G, kv_j(i, j), 0)),
+                     lambda b, *g: (b // G, k_at(*g), 0)),
         pl.BlockSpec((1, block_k, Dv),
-                     lambda b, i, j: (b // G, kv_j(i, j), 0)),
+                     lambda b, *g: (b // G, k_at(*g), 0)),
     ]
     args = [q, k, v]
     if segmented:
         in_specs += [
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, *g: (b, q_at(*g), 0)),
             pl.BlockSpec((1, block_k, 1),
-                         lambda b, i, j: (b // G, kv_j(i, j), 0)),
+                         lambda b, *g: (b // G, k_at(*g), 0)),
         ]
         args += [q_seg, kv_seg]
-    tiles = tile_census(Sq, Sk, block_q, block_k, causal, window)["fwd"]
+    tiles = tile_census(Sq, Sk, block_q, block_k, causal, window,
+                        blockdiff)["fwd"]
     with named_scope("flash-fwd"), tiles_scope(**tiles):
-        return pl.pallas_call(
-            kernel,
+        return _pallas(
+            kernel, tables,
             out_shape=[
                 jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype),
                 jax.ShapeDtypeStruct((BH, Sq, 1), jnp.float32),
@@ -464,8 +679,10 @@ def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
             grid=grid,
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_q, Dv),
+                             lambda b, *g: (b, q_at(*g), 0)),
+                pl.BlockSpec((1, block_q, 1),
+                             lambda b, *g: (b, q_at(*g), 0)),
             ],
             scratch_shapes=scratch,
             compiler_params=_compiler_params(flash_vmem_bytes(
@@ -473,14 +690,15 @@ def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
                 Dv)),
             interpret=interpret,
             name="flash-fwd",
-        )(*args)
+        )(*tables, *args)
 
 
 def _dq_kernel(
     *refs,
     scale: float, causal: bool, segmented: bool, block_q: int, block_k: int,
-    kv_range, window=None,
+    kv_range, window=None, blockdiff=None,
 ):
+    walk, refs = _walked(refs, blockdiff, block_q, block_k)
     if segmented:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qs_ref, ks_ref,
          dq_ref, dq_acc) = refs
@@ -488,30 +706,29 @@ def _dq_kernel(
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dq_ref, dq_acc) = refs
         qs_ref = ks_ref = None
-    iq = pl.program_id(1)
-    j = pl.program_id(2)
-    n_j = pl.num_programs(2)
+    j = n_j = None
+    if walk is None:
+        iq = pl.program_id(1)
+        j = pl.program_id(2)
+        n_j = pl.num_programs(2)
+    first, last = _edges(walk, j, n_j)
 
-    @pl.when(j == 0)
+    @pl.when(first())
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    q_start = iq * block_q
-    ik, in_band = _streamed_block(kv_range, iq, j, window)
-    k_start = ik * block_k
-    run = _band_live(causal, window, q_start, block_q, k_start, block_k)
-    if window is not None:
-        run = run & in_band
+    if walk is None:
+        q_start = iq * block_q
+        ik, in_band = _streamed_block(kv_range, iq, j, window)
+        k_start = ik * block_k
 
-    @pl.when(run)
-    def _():
+    def tile(mask_of):
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        mask = _block_mask(s.shape, causal, q_start, k_start, qs_ref,
-                           ks_ref, window)
+        mask = mask_of(s.shape)
         if mask is not None:
             s = jnp.where(mask, s, _NEG_INF)
         p = jnp.exp(s - lse_ref[0, :, :])             # exact probabilities
@@ -523,7 +740,12 @@ def _dq_kernel(
         ds = (p * (dp - delta_ref[0, :, :]) * scale).astype(k.dtype)
         dq_acc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
-    @pl.when(j == n_j - 1)
+    _run_tiles(tile, walk, lambda: _band_run(
+        causal, window, q_start, block_q, k_start, block_k, in_band),
+        lambda shape: _block_mask(shape, causal, q_start, k_start, qs_ref,
+                                  ks_ref, window))
+
+    @pl.when(last())
     def _():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -531,8 +753,21 @@ def _dq_kernel(
 def _dkv_kernel(
     *refs,
     scale: float, causal: bool, segmented: bool, block_q: int, block_k: int,
-    n_q: int, q_range, window=None,
+    n_q: int, q_range, window=None, blockdiff=None, group: int = 1,
 ):
+    # (Under the block-diffusion mask the walk is the k-major list of
+    # live tiles, every query head of the group at each: tile ``t //
+    # group``, and a K block opens with its first tile's first head and
+    # closes with its last tile's last.)
+    walk = None
+    if blockdiff is not None:
+        t = pl.program_id(1)
+        g = t % group
+        iq, ik, opens, closes, cut, cut_mask = _blockdiff_step(
+            refs[:3], t // group, *blockdiff, block_q, block_k)
+        walk = (iq, ik, opens & (g == 0), closes & (g == group - 1), cut,
+                cut_mask)
+        refs = refs[3:]
     if segmented:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qs_ref, ks_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
@@ -540,37 +775,37 @@ def _dkv_kernel(
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
         qs_ref = ks_ref = None
-    ik = pl.program_id(1)   # grid: (BHk, n_k, G*n_q) — (head, q) innermost
-    # The innermost axis enumerates (g, iq) pairs: for GQA every query
-    # head of the group contributes to this KV row's dk/dv, so the
-    # accumulator runs over all G * n_q steps and flushes once.  (With
-    # a window ``n_q`` is the band's width in q blocks, not the axis'.)
-    i = pl.program_id(2)
-    iq, in_band = _streamed_block(q_range, ik, i % n_q, window)
-    n_i = pl.num_programs(2)
+    i = n_i = None
+    if walk is None:
+        ik = pl.program_id(1)   # grid: (BHk, n_k, G*n_q) — (head, q) innermost
+        # The innermost axis enumerates (g, iq) pairs: for GQA every query
+        # head of the group contributes to this KV row's dk/dv, so the
+        # accumulator runs over all G * n_q steps and flushes once.  (With
+        # a window ``n_q`` is the band's width in q blocks, not the axis'.)
+        i = pl.program_id(2)
+        iq, in_band = _streamed_block(q_range, ik, i % n_q, window)
+        n_i = pl.num_programs(2)
+    first, last = _edges(walk, i, n_i)
 
-    @pl.when(i == 0)
+    @pl.when(first())
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q_start = iq * block_q
-    k_start = ik * block_k
-    # Skip when the whole Q block precedes the whole K block (causal) or
-    # lies entirely beyond the K block's window reach.
-    run = _band_live(causal, window, q_start, block_q, k_start, block_k)
-    if window is not None:
-        run = run & in_band
+    if walk is None:
+        q_start = iq * block_q
+        k_start = ik * block_k
 
-    @pl.when(run)
-    def _():
+    # (The rectangle's and the band's skip: when the whole Q block
+    # precedes the whole K block (causal) or lies entirely beyond the K
+    # block's window reach.)
+    def tile(mask_of):
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        mask = _block_mask(s.shape, causal, q_start, k_start, qs_ref,
-                           ks_ref, window)
+        mask = mask_of(s.shape)
         if mask is not None:
             s = jnp.where(mask, s, _NEG_INF)
         p = jnp.exp(s - lse_ref[0, :, :])
@@ -582,7 +817,12 @@ def _dkv_kernel(
         ds = (p * (dp - delta_ref[0, :, :]) * scale).astype(q.dtype)
         dk_acc[:] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
 
-    @pl.when(i == n_i - 1)
+    _run_tiles(tile, walk, lambda: _band_run(
+        causal, window, q_start, block_q, k_start, block_k, in_band),
+        lambda shape: _block_mask(shape, causal, q_start, k_start, qs_ref,
+                                  ks_ref, window))
+
+    @pl.when(last())
     def _():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -591,7 +831,7 @@ def _dkv_kernel(
 @functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
 def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
                   interpret, dlse=None, q_seg=None, kv_seg=None,
-                  window=None):
+                  window=None, blockdiff=None):
     """(BH, S, D) flash attention backward: (dq, dk, dv).
 
     ``dlse``: optional cotangent of the row log-sum-exp output (used when
@@ -617,41 +857,43 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
         block_q, block_k, D, q.dtype.itemsize, "bwd", segmented, Dv))
     kv_range, q_range = _live_ranges(Sq, Sk, block_q, block_k, causal,
                                      window)
-    kv_j, n_j = _streamed_axis(kv_range, n_q, n_k, causal, window)
-    q_spec, do_spec = (pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+    q_at, k_at, inner, tables = _kv_walk(
+        kv_range, n_q, n_k, causal, window, blockdiff, block_q, block_k)
+    q_spec, do_spec = (pl.BlockSpec((1, block_q, d),
+                                    lambda b, *g: (b, q_at(*g), 0))
                        for d in (D, Dv))
     k_spec, v_spec = (pl.BlockSpec((1, block_k, d),
-                                   lambda b, i, j: (b // G, kv_j(i, j), 0))
+                                   lambda b, *g: (b // G, k_at(*g), 0))
                       for d in (D, Dv))
-    r_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
+    r_spec = pl.BlockSpec((1, block_q, 1), lambda b, *g: (b, q_at(*g), 0))
     dq_in = [q_spec, k_spec, v_spec, do_spec, r_spec, r_spec]
     dq_args = [q, k, v, do, lse, delta]
     if segmented:
         dq_in += [
             r_spec,
             pl.BlockSpec((1, block_k, 1),
-                         lambda b, i, j: (b // G, kv_j(i, j), 0)),
+                         lambda b, *g: (b // G, k_at(*g), 0)),
         ]
         dq_args += [q_seg, kv_seg]
-    tiles = tile_census(Sq, Sk, block_q, block_k, causal, window)
+    tiles = tile_census(Sq, Sk, block_q, block_k, causal, window, blockdiff)
     with named_scope("flash-bwd-dq"), tiles_scope(**tiles["dq"]):
-        dq = pl.pallas_call(
+        dq = _pallas(
             functools.partial(
                 _dq_kernel, scale=scale, causal=causal, segmented=segmented,
                 block_q=block_q, block_k=block_k, kv_range=kv_range,
-                window=window,
-            ),
+                window=window, **_masked(blockdiff),
+            ), tables,
             out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
-            grid=(BH, n_q, n_j),
+            grid=(BH, *inner),
             in_specs=dq_in,
             out_specs=pl.BlockSpec(
-                (1, block_q, D), lambda b, i, j: (b, i, 0)
+                (1, block_q, D), lambda b, *g: (b, q_at(*g), 0)
             ),
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
             compiler_params=params,
             interpret=interpret,
             name="flash-bwd-dq",
-        )(*dq_args)
+        )(*tables, *dq_args)
 
     # dkv grid walks (BHk, n_k, G*n_q): one program chain per KV row with
     # every query head of its group innermost — the group's contributions
@@ -661,15 +903,32 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
     # The query side is the streamed one here: its block index stops at
     # the band of k block j, within each query head of the group (with
     # a window the group's inner axis IS that band, ``n_i`` blocks wide).
-    q_i, n_i = _streamed_axis(q_range, n_k, n_q, causal, window)
+    # Under the block-diffusion mask the axis after the KV row is the
+    # k-major walk of the live tiles with the group's heads innermost:
+    # step ``t`` is tile ``t // G`` under query head ``t % G``.
+    if blockdiff is None:
+        q_i, n_i = _streamed_axis(q_range, n_k, n_q, causal, window)
+        tables, inner = (), (n_k, G * n_i)
 
-    def q_rows(b, j, i):
-        return (b * G + i // n_i, q_i(j, i % n_i), 0)
+        def q_rows(b, j, i):
+            return (b * G + i // n_i, q_i(j, i % n_i), 0)
+
+        def k_rows(b, j, i):
+            return (b, j, 0)
+    else:
+        n_i = None
+        tables = _walk_operands(blockdiff, block_q, block_k, "q")
+        inner = (G * len(tables[0]),)
+
+        def q_rows(b, t, tq, tk, fl):
+            return (b * G + t % G, tq[t // G], 0)
+
+        def k_rows(b, t, tq, tk, fl):
+            return (b, tk[t // G], 0)
 
     qT_spec, doT_spec = (pl.BlockSpec((1, block_q, d), q_rows)
                          for d in (D, Dv))
-    kT_spec, vT_spec = (pl.BlockSpec((1, block_k, d),
-                                     lambda b, j, i: (b, j, 0))
+    kT_spec, vT_spec = (pl.BlockSpec((1, block_k, d), k_rows)
                         for d in (D, Dv))
     rT_spec = pl.BlockSpec((1, block_q, 1), q_rows)
     dkv_in = [qT_spec, kT_spec, vT_spec, doT_spec, rT_spec, rT_spec]
@@ -677,21 +936,23 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
     if segmented:
         dkv_in += [
             rT_spec,
-            pl.BlockSpec((1, block_k, 1), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, 1), k_rows),
         ]
         dkv_args += [q_seg, kv_seg]
+    walked = {} if blockdiff is None else {"blockdiff": blockdiff,
+                                           "group": G}
     with named_scope("flash-bwd-dkv"), tiles_scope(**tiles["dkv"]):
-        dk, dv = pl.pallas_call(
+        dk, dv = _pallas(
             functools.partial(
                 _dkv_kernel, scale=scale, causal=causal,
                 segmented=segmented, block_q=block_q, block_k=block_k,
-                n_q=n_i, q_range=q_range, window=window,
-            ),
+                n_q=n_i, q_range=q_range, window=window, **walked,
+            ), tables,
             out_shape=[
                 jax.ShapeDtypeStruct((BHk, Sk, D), k.dtype),
                 jax.ShapeDtypeStruct((BHk, Sk, Dv), v.dtype),
             ],
-            grid=(BHk, n_k, G * n_i),
+            grid=(BHk, *inner),
             in_specs=dkv_in,
             out_specs=[kT_spec, vT_spec],
             scratch_shapes=[
@@ -701,7 +962,7 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
             compiler_params=params,
             interpret=interpret,
             name="flash-bwd-dkv",
-        )(*dkv_args)
+        )(*tables, *dkv_args)
     return dq, dk, dv
 
 
@@ -745,42 +1006,46 @@ def _lse_column(lse):
     return lse.reshape(lse.shape + (1,))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_bh(q, k, v, scale, causal, block_q, block_k, interpret,
-              window=None, block_q_bwd=None, block_k_bwd=None):
+              window=None, block_q_bwd=None, block_k_bwd=None,
+              blockdiff=None):
     """(BH, S, D) flash attention, differentiable (FlashAttention-2-style
     explicit backward: recompute probabilities blockwise from the saved row
     LSE, never materializing the S×S matrix in either pass).
 
     ``block_q_bwd``/``block_k_bwd``: optional separate geometry for the
     backward kernels (their tile economics differ — two extra streamed
-    operands, two kernels); None means reuse the forward blocks."""
+    operands, two kernels); None means reuse the forward blocks.
+    ``blockdiff``: ``(L, B)`` of the block-diffusion mask, or None."""
     o, _ = _flash_bh_fwd(
         q, k, v, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, interpret=interpret,
-        window=window,
+        window=window, **_masked(blockdiff),
     )
     return o
 
 
 def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-                   window=None, block_q_bwd=None, block_k_bwd=None):
+                   window=None, block_q_bwd=None, block_k_bwd=None,
+                   blockdiff=None):
     o, lse = _flash_bh_fwd(
         q, k, v, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, interpret=interpret,
-        window=window,
+        window=window, **_masked(blockdiff),
     )
     o, lse = _named_residuals(o, lse)
     return o, (q, k, v, o, lse)
 
 
 def _flash_vjp_bwd(scale, causal, block_q, block_k, interpret, window,
-                   block_q_bwd, block_k_bwd, res, do):
+                   block_q_bwd, block_k_bwd, blockdiff, res, do):
     q, k, v, o, lse = res
     dq, dk, dv = _flash_bh_bwd(
         q, k, v, o, _lse_column(lse), do, scale=scale, causal=causal,
         block_q=block_q_bwd or block_q, block_k=block_k_bwd or block_k,
-        interpret=interpret, window=window,
+        interpret=interpret, window=window, **_masked(blockdiff),
     )
     return dq, dk, dv
 
@@ -924,7 +1189,7 @@ flash_attention_with_lse_seg.defvjp(
 
 
 def _xla_attention(q, k, v, scale, causal, q_segment_ids=None,
-                   kv_segment_ids=None, window=None):
+                   kv_segment_ids=None, window=None, blockdiff=None):
     if k.shape[2] != q.shape[2]:
         # GQA/MQA fallback: broadcast KV heads to the query head count.
         # jnp.repeat's transpose sums the group's dk/dv — exactly the
@@ -944,6 +1209,10 @@ def _xla_attention(q, k, v, scale, causal, q_segment_ids=None,
             jnp.arange(Sq)[:, None] - jnp.arange(Sk)[None, :] < window
         )[None]
         mask = band if mask is None else (mask & band)
+    if blockdiff is not None:
+        # (the block-diffusion mask stands alone: its "causal" is between
+        # blocks, and flash_attention admits neither window nor segments)
+        mask = blockdiff_mask(*blockdiff)[None]
     if q_segment_ids is not None:
         seg = segment_mask(q_segment_ids, kv_segment_ids)
         mask = seg if mask is None else (mask & seg)
@@ -968,7 +1237,8 @@ def _sublane(dtype) -> int:
 def auto_block_size(S: int, D: int, dtype, which: str = "fwd",
                     segmented: bool = False,
                     window: Optional[int] = None,
-                    D_v: Optional[int] = None) -> int:
+                    D_v: Optional[int] = None,
+                    blockdiff: Optional[tuple] = None) -> int:
     """The STATIC default block edge along a length-``S`` axis: the
     largest multiple of 128 that divides ``S`` and whose square tile fits
     Mosaic's default scoped VMEM (:data:`VMEM_SCOPED_DEFAULT`) by
@@ -996,7 +1266,13 @@ def auto_block_size(S: int, D: int, dtype, which: str = "fwd",
     pair of fitting edges fits.  A length no multiple of 128 divides
     keeps the old answer (``min(128, S)``: whole when short, else a
     block that does not divide and sends the call to XLA) — an auto pick
-    must not demote a shape that compiles."""
+    must not demote a shape that compiles.  Under the block-diffusion
+    mask (``blockdiff`` = ``(L, B)``, ``S = 2 L``) an edge divides ``L``,
+    so that a tile's rows are all clean or all noisy; the mask's dead
+    tiles are not visited and its cut tiles are the diagonals', as the
+    triangle's are, so the largest fitting edge wins as it does there."""
+    if blockdiff is not None:
+        S = blockdiff[0]
     edges = [b for b in range(128, S + 1, 128) if S % b == 0]
     if not edges:
         return min(128, S)
@@ -1012,7 +1288,24 @@ def auto_block_size(S: int, D: int, dtype, which: str = "fwd",
     return max(fits, default=edges[0])
 
 
-def _publish_geometry(fwd: dict, bwd: dict) -> None:
+def _publish_blockdiff(blockdiff, record) -> None:
+    """The block-diffusion mask's own record beside ``flash_geometry``,
+    through the publisher the other ops use (``ops.ssd.publish_geometry``):
+    a ``blockdiff_geometry`` row, ``blockdiff/*`` gauges and a
+    ``blockdiff/calls`` counter — ``L``, ``B``, the rows a call runs, the
+    pairs a head row attends, and ``<kernel>/live|visited|copied``, each
+    kernel's tiles a head row."""
+    from chainermn_tpu.ops.ssd import publish_geometry
+
+    L, B = blockdiff
+    publish_geometry("blockdiff_geometry", "blockdiff", {
+        "L": L, "B": B, "rows": 2 * L, "live_pairs": blockdiff_pairs(L, B),
+        **{f"{kernel}/{field}": tiles[field]
+           for kernel, tiles in record.items()
+           for field in ("live", "visited", "copied")}})
+
+
+def _publish_geometry(fwd: dict, bwd: dict, blockdiff=None) -> None:
     """One record a :func:`flash_attention` call that reaches the kernels
     (at TRACE time: a jitted step publishes again only when retraced):
     the blocks each kernel was given and the tiles it finds live, visits
@@ -1030,6 +1323,8 @@ def _publish_geometry(fwd: dict, bwd: dict) -> None:
         for kernel, tiles in record.items():
             for field, value in tiles.items():
                 rep.gauge(f"flash/{kernel}/{field}", value)
+    if blockdiff is not None:
+        _publish_blockdiff(blockdiff, record)
 
 
 def flash_attention(
@@ -1046,6 +1341,7 @@ def flash_attention(
     window: Optional[int] = None,
     block_q_bwd: Optional[int] = None,
     block_k_bwd: Optional[int] = None,
+    block_diffusion: Optional[int] = None,
 ):
     """Flash attention over (B, S, H, D) tensors (layout matches the
     transformer layers in ``chainermn_tpu.models``).  ``q`` and ``k`` are
@@ -1084,6 +1380,20 @@ def flash_attention(
     training shape.  Rows whose segment matches nothing (padding, e.g.
     segment id -1 against all-nonnegative kv ids) produce zero output
     and zero gradients.
+
+    ``block_diffusion``: the block length ``B`` of block-diffusion
+    training.  The ``S = 2 L`` rows are a document's clean copy followed
+    by its noised one, and the mask is :func:`blockdiff_mask`'s, stated
+    by ``(L, B)`` and nothing else: a clean row sees the clean blocks up
+    to its own, a noisy row the clean blocks before its own and its own
+    noisy block.  Causal between blocks (``causal=True``), alone (no
+    window, no segment ids).  The three kernels' grids walk the mask's
+    live tiles only — a q tile's clean prefix and, for a noisy tile, its
+    diagonal noisy tile; the dk/dv side the transposed statement — from
+    a scalar-prefetched list (:func:`_blockdiff_walk`), and a tile the
+    mask does not cut pays no compare.  At ``L`` = 8192 and 1024 x 1024
+    tiles that is 80 of the rectangle's 256 tiles a head row and
+    ``L (L + B)`` pairs, half of the causal triangle's at the same rows.
 
     ``block_q``/``block_k`` default to the rule of
     :func:`auto_block_size`: along each axis the largest multiple of
@@ -1127,6 +1437,20 @@ def flash_attention(
         raise ValueError(
             "q_segment_ids and kv_segment_ids must be passed together"
         )
+    blockdiff = None
+    if block_diffusion is not None:
+        B_blk = int(block_diffusion)
+        if (not causal or window is not None or q_segment_ids is not None
+                or Sq != Sk or Sq % 2 or B_blk < 1
+                or (Sq // 2) % B_blk):
+            raise ValueError(
+                f"block_diffusion={block_diffusion}: the mask is over "
+                f"2 L rows [clean ; noisy] of queries and keys alike "
+                f"(got Sq={Sq}, Sk={Sk}), L a multiple of the block, "
+                f"causal between blocks, and takes no window and no "
+                f"segment ids (packed documents under this mask are not "
+                f"built)")
+        blockdiff = (Sq // 2, B_blk)
 
     if interpret is None:
         interpret = default_interpret()
@@ -1136,7 +1460,7 @@ def flash_attention(
 
     def static(S, which):
         return auto_block_size(S, D, q.dtype, which, segmented, window,
-                               None if Dv == D else Dv)
+                               None if Dv == D else Dv, blockdiff)
 
     if not pinned and block_q_bwd is None and block_k_bwd is None:
         # Nothing pinned: the backward gets the rule's own
@@ -1162,10 +1486,12 @@ def flash_attention(
     # (verified on-chip at D ∈ {160, 192, 256} against the oracle);
     # beyond 256 the VMEM block economics favor the XLA fallback.
     d_ok = max(D, Dv) <= 256
+    # (under the block-diffusion mask a tile lies in one copy)
+    Lq, Lk = (Sq, Sk) if blockdiff is None else (Sq // 2, Sk // 2)
     usable = (
         d_ok
-        and Sq % block_q == 0
-        and Sk % block_k == 0
+        and Lq % block_q == 0
+        and Lk % block_k == 0
         and tile_ok
     )
     if not usable:
@@ -1179,7 +1505,7 @@ def flash_attention(
         return _xla_attention(
             q, k, v, scale, causal,
             q_segment_ids=q_segment_ids, kv_segment_ids=kv_segment_ids,
-            window=window,
+            window=window, blockdiff=blockdiff,
         )
 
     # Backward geometry rides the same gate as the forward's: an invalid
@@ -1189,16 +1515,18 @@ def flash_attention(
         bq_b = block_q_bwd or block_q
         bk_b = block_k_bwd or block_k
         bwd_ok = (
-            Sq % bq_b == 0 and Sk % bk_b == 0
+            Lq % bq_b == 0 and Lk % bk_b == 0
             and (interpret or (bq_b % sublane == 0 and bk_b % sublane == 0))
         )
         block_q_bwd, block_k_bwd = (bq_b, bk_b) if bwd_ok else (None, None)
 
     if telemetry_active():
         _publish_geometry(
-            tile_census(Sq, Sk, block_q, block_k, causal, window)["fwd"],
+            tile_census(Sq, Sk, block_q, block_k, causal, window,
+                        blockdiff)["fwd"],
             tile_census(Sq, Sk, block_q_bwd or block_q,
-                        block_k_bwd or block_k, causal, window),
+                        block_k_bwd or block_k, causal, window, blockdiff),
+            blockdiff,
         )
 
     # (B, S, H, D) → (B*H, S, D); kv keep their own (possibly smaller)
@@ -1217,7 +1545,7 @@ def flash_attention(
     else:
         out = _flash_bh(
             qt, kt, vt, scale, causal, block_q, block_k, interpret, window,
-            block_q_bwd, block_k_bwd,
+            block_q_bwd, block_k_bwd, blockdiff,
         )
     return out.reshape(B, H, Sq, Dv).transpose(0, 2, 1, 3)
 
@@ -1275,6 +1603,15 @@ def seg_to_bh(ids, H: int):
     return jnp.repeat(ids.astype(jnp.int32), H, axis=0)[..., None]
 
 
+def row_mask(handed: dict) -> dict:
+    """What an attention row hands its ``attention_fn`` beside ``(q, k,
+    v, mask)``, as :func:`flash_attention`'s keywords: its ``window``
+    where it has one (:func:`row_window` settles it against the
+    adapter's own), the ``block_diffusion`` block where its table trains
+    under that mask.  A row with neither hands nothing."""
+    return {k: v for k, v in handed.items() if v is not None}
+
+
 def row_window(own, handed):
     """The window an ``attention_fn`` adapter runs under: the one the
     calling row hands over (``MultiHeadAttention.window``), else the
@@ -1293,7 +1630,8 @@ def make_flash_attention_fn(causal: bool = True, q_segment_ids=None,
     ``window``: one sliding window for every layer that calls the
     adapter; a row of a block table that has its own hands it over at
     the call (``fn(q, k, v, mask, window=...)``), so the rows of one
-    model can differ (:func:`row_window`).
+    model can differ (:func:`row_window`); a row of a table trained by
+    block diffusion hands its block the same way (``block_diffusion=``).
 
     ``scale``: the softmax scale, passed through to
     :func:`flash_attention` (None = ``1/sqrt(D)``) and kept as the
@@ -1334,7 +1672,7 @@ def make_flash_attention_fn(causal: bool = True, q_segment_ids=None,
             )
         return ids
 
-    def fn(q, k, v, mask=None, window=None):
+    def fn(q, k, v, mask=None, window=None, block_diffusion=None):
         del mask
         qs = ks = None
         if q_segment_ids is not None:
@@ -1349,6 +1687,7 @@ def make_flash_attention_fn(causal: bool = True, q_segment_ids=None,
             window=row_window(own_window, window),
             block_q=block_q, block_k=block_k,
             block_q_bwd=block_q_bwd, block_k_bwd=block_k_bwd, scale=scale,
+            **row_mask({"block_diffusion": block_diffusion}),
         )
 
     fn.scale = scale

@@ -114,15 +114,19 @@ def _chunk_lse(logits, strat):
     return m + jnp.log(se)
 
 
-def _chunk_loss(carry, logits, lse_c, l_c, strat):
+def _chunk_loss(carry, logits, lse_c, l_c, strat, w_c=None):
     """``(loss_sum, n_valid)`` carry plus this chunk's valid tokens'
-    ``lse - picked``."""
+    ``lse - picked``, each times its weight where the call has weights
+    (``w_c``, float32 a row)."""
     loss_sum, n_valid = carry
     valid = l_c >= 0
     idx, owner = strat.label_local(l_c)
     picked_s = jnp.take_along_axis(logits, idx[:, None], axis=-1)[:, 0]
     picked = strat.merge_pick(jnp.where(owner, picked_s, 0.0))
-    tok_loss = jnp.where(valid, lse_c - picked, 0.0)
+    tok_loss = lse_c - picked
+    if w_c is not None:
+        tok_loss = tok_loss * w_c
+    tok_loss = jnp.where(valid, tok_loss, 0.0)
     return (loss_sum + tok_loss.sum(),
             n_valid + valid.sum().astype(jnp.float32))
 
@@ -154,30 +158,35 @@ def _chunk_grads(dlogits, h_c, embedding, strat):
     return dh_c, d_emb_c
 
 
-def _chunked(hidden, labels, chunk):
-    """``(h_chunks (n, C, D), l_chunks (n, C))`` for the row scan."""
+def _chunked(hidden, labels, chunk, weights=None):
+    """``(h_chunks (n, C, D), l_chunks (n, C))`` for the row scan, and
+    the weights' ``(n, C)`` after them where the call has weights."""
     N = hidden.shape[0]
     C = _pick_chunk(N, chunk)
-    return (hidden.reshape(N // C, C, hidden.shape[1]),
-            labels.reshape(N // C, C))
+    chunks = (hidden.reshape(N // C, C, hidden.shape[1]),
+              labels.reshape(N // C, C))
+    if weights is None:
+        return chunks
+    return chunks + (weights.astype(jnp.float32).reshape(N // C, C),)
 
 
-def ce_scan_fwd(hidden, embedding, labels, chunk, strat):
+def ce_scan_fwd(hidden, embedding, labels, chunk, strat, weights=None):
     """Chunked CE forward: sum over valid tokens of ``lse - picked`` plus
     the valid count and per-token lse, never holding more than one
     ``(chunk, V_local)`` logit tile.  ``strat`` supplies the cross-shard
-    merges (identity for the local case)."""
-    h_chunks, l_chunks = _chunked(hidden, labels, chunk)
+    merges (identity for the local case).  ``weights``: a float32 weight
+    a row on its term of the sum, or None."""
+    chunks = _chunked(hidden, labels, chunk, weights)
 
-    def body(carry, hc_lc):
-        h_c, l_c = hc_lc
+    def body(carry, chunk_c):
+        h_c, l_c, *w_c = chunk_c
         logits = _chunk_logits(h_c, embedding)  # (C, V_local) fp32
         lse_c = _chunk_lse(logits, strat)
-        return _chunk_loss(carry, logits, lse_c, l_c, strat), lse_c
+        return _chunk_loss(carry, logits, lse_c, l_c, strat, *w_c), lse_c
 
     with named_scope("fused-ce"):
         (loss_sum, n_valid), lse = jax.lax.scan(
-            body, (jnp.float32(0.0), jnp.float32(0.0)), (h_chunks, l_chunks)
+            body, (jnp.float32(0.0), jnp.float32(0.0)), chunks
         )
     return loss_sum, n_valid, lse.reshape(hidden.shape[0])
 
@@ -217,30 +226,35 @@ def ce_scan_bwd(hidden, embedding, labels, lse, g_loss, g_lse, chunk,
     )
 
 
-def ce_scan_fwd_grads(hidden, embedding, labels, chunk):
+def ce_scan_fwd_grads(hidden, embedding, labels, chunk, weights=None):
     """Chunked CE forward that also makes the gradients of ``loss_sum``
     (a cotangent of 1) while each chunk's logits exist: one scan, three
     matmuls a chunk, ``d embedding`` in the fp32 carry.  Full vocabulary
-    on one device only.  Returns (loss_sum, n_valid, dh, d_emb), the
-    gradients in the input dtypes."""
+    on one device only.  ``weights``: a float32 weight a row on its term
+    of the sum and so on its row of ``dlogits``, or None.  Returns
+    (loss_sum, n_valid, dh, d_emb), the gradients in the input dtypes."""
     N, D = hidden.shape
     strat = LocalVocabStrategy()
-    h_chunks, l_chunks = _chunked(hidden, labels, chunk)
+    chunks = _chunked(hidden, labels, chunk, weights)
 
-    def body(carry, hc_lc):
+    def body(carry, chunk_c):
         loss_carry, d_emb = carry
-        h_c, l_c = hc_lc
+        h_c, l_c, *w_c = chunk_c
         logits = _chunk_logits(h_c, embedding)  # (C, V) fp32, made once
         lse_c = _chunk_lse(logits, strat)
-        loss_carry = _chunk_loss(loss_carry, logits, lse_c, l_c, strat)
+        loss_carry = _chunk_loss(loss_carry, logits, lse_c, l_c, strat,
+                                 *w_c)
         p, onehot, valid = _chunk_softmax_onehot(logits, lse_c, l_c, strat)
+        dlogits = p - onehot
+        if w_c:
+            dlogits = dlogits * w_c[0][:, None]
         # Made once, in bf16, for both gradient matmuls (as the
         # recomputing rule's backward has it): left free, the compiler
         # redoes the softmax inside each matmul's operand and reads the
         # fp32 tile twice — +2.1 ms a call at V=50257 on a v5e, -0.2 at
         # V=25088 where the tile stays on-chip (PERF.md §6, PR 27).
         dlogits = jax.lax.optimization_barrier(
-            jnp.where(valid, p - onehot, 0.0).astype(jnp.bfloat16))
+            jnp.where(valid, dlogits, 0.0).astype(jnp.bfloat16))
         dh_c, d_emb_c = _chunk_grads(dlogits, h_c, embedding, strat)
         return (loss_carry, d_emb + d_emb_c), dh_c
 
@@ -249,7 +263,7 @@ def ce_scan_fwd_grads(hidden, embedding, labels, chunk):
             body,
             ((jnp.float32(0.0), jnp.float32(0.0)),
              jnp.zeros(embedding.shape, jnp.float32)),
-            (h_chunks, l_chunks),
+            chunks,
         )
     return (
         loss_sum, n_valid,
@@ -318,6 +332,33 @@ def _fused_ce_loss_vjp_bwd(chunk, res, cots):
 _fused_ce_loss.defvjp(_fused_ce_loss_vjp_fwd, _fused_ce_loss_vjp_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _fused_ce_loss_weighted(hidden, embedding, labels, weights, chunk):
+    """:func:`_fused_ce_loss` with a float32 weight a row inside the scan,
+    forward and backward: ``(sum_i w_i (lse_i - picked_i), n_valid)``,
+    the gradients made in the forward scan as there.  The weights are
+    data (a noise level's ``1 / t``): their cotangent is zero."""
+    loss_sum, n_valid, _lse = ce_scan_fwd(
+        hidden, embedding, labels, chunk, LocalVocabStrategy(), weights)
+    return loss_sum, n_valid
+
+
+def _fused_ce_weighted_vjp_fwd(hidden, embedding, labels, weights, chunk):
+    loss_sum, n_valid, dh, d_emb = ce_scan_fwd_grads(
+        hidden, embedding, labels, chunk, weights)
+    return (loss_sum, n_valid), (dh, d_emb, weights)
+
+
+def _fused_ce_weighted_vjp_bwd(chunk, res, cots):
+    *grads, weights = res
+    dh, d_emb, _ = _fused_ce_loss_vjp_bwd(chunk, grads, cots)
+    return dh, d_emb, None, jnp.zeros_like(weights)
+
+
+_fused_ce_loss_weighted.defvjp(_fused_ce_weighted_vjp_fwd,
+                               _fused_ce_weighted_vjp_bwd)
+
+
 def _publish_geometry(h2, embedding, chunk, form: str) -> None:
     """One ``ce_geometry`` record a traced loss head (at TRACE time,
     beside ``flash_geometry`` and ``ssd_geometry``): a row of the
@@ -340,10 +381,12 @@ def _publish_geometry(h2, embedding, chunk, form: str) -> None:
             rep.gauge(f"fused_ce/{field}", value)
 
 
-def fused_cross_entropy(hidden, embedding, labels, *, chunk=None):
+def fused_cross_entropy(hidden, embedding, labels, *, chunk=None,
+                        weights=None, normaliser=None):
     """Mean softmax cross-entropy of ``hidden @ embedding.T`` against
     ``labels``, computed without materializing the ``(N, V)`` logit
-    matrix (peak extra memory ``chunk x V`` fp32).
+    matrix (peak extra memory ``chunk x V`` fp32).  With ``weights``:
+    ``sum_i w_i (lse_i - picked_i) / normaliser``.
 
     * ``hidden`` — ``(..., D)`` final hidden states (any float dtype; the
       logit matmuls run bf16 on the MXU with fp32 accumulation).
@@ -363,11 +406,33 @@ def fused_cross_entropy(hidden, embedding, labels, *, chunk=None):
     Without differentiation only the loss scan runs.
 
     ``chunk`` — rows per scan tile; None is :data:`DEFAULT_CHUNK`.
+
+    ``weights`` — ``(...,)`` like ``labels``, a float32 weight on each
+    row's term of the sum, applied inside the chunked scan, forward and
+    backward (a masked-diffusion loss: ``1 / t`` on the rows whose token
+    was masked at level ``t``, 0 on the others, so that every step runs
+    the head over the same rows).  Data, not differentiated.  None is
+    the unweighted program, to the letter.
+
+    ``normaliser`` — what the weighted sum is divided by (a masked
+    -diffusion loss divides by the document's length, not by the count
+    of kept rows); None divides by the count of rows with a label >= 0,
+    as the unweighted mean does.  Only with ``weights``.
     """
     h2, l2, chunk = _prepare(
         hidden, embedding, labels, chunk, "grad_in_forward")
-    loss_sum, n_valid = _fused_ce_loss(h2, embedding, l2, chunk)
-    return loss_sum / jnp.maximum(n_valid, 1.0)
+    if weights is None:
+        if normaliser is not None:
+            raise ValueError("normaliser divides a weighted sum: pass "
+                             "weights (ones for the plain sum)")
+        loss_sum, n_valid = _fused_ce_loss(h2, embedding, l2, chunk)
+        return loss_sum / jnp.maximum(n_valid, 1.0)
+    w2 = jnp.asarray(weights).reshape(-1)
+    if w2.shape != l2.shape:
+        raise ValueError(f"weights {w2.shape[0]} != labels {l2.shape[0]}")
+    loss_sum, n_valid = _fused_ce_loss_weighted(h2, embedding, l2, w2, chunk)
+    return loss_sum / (jnp.maximum(n_valid, 1.0) if normaliser is None
+                       else normaliser)
 
 
 def fused_cross_entropy_with_lse(hidden, embedding, labels, *, chunk=None):

@@ -462,6 +462,19 @@ def _local_seg_slice(segment_ids, axis_name, s_local, batch):
     return jnp.broadcast_to(row[None], (batch, s_local))
 
 
+def refuse_block_diffusion(block_diffusion, what: str) -> None:
+    """A row of a table trained by block diffusion hands its block to
+    the ``attention_fn``; the sequence-parallel adapters have no such
+    mask (a shard's rows would be part clean, part noisy, and the blocks
+    rotating past it would need the mask's two intervals): say so by
+    name, do not attend causally instead."""
+    if block_diffusion is not None:
+        raise ValueError(
+            f"{what} builds no block-diffusion mask (block_diffusion="
+            f"{block_diffusion}): the [clean ; noisy] rows are not "
+            f"sharded over a sequence axis; take make_flash_attention_fn")
+
+
 def make_ring_attention_fn(axis_name: str, causal: bool = True,
                            segment_ids=None, window=None):
     """Adapter with the ``attention_fn(q, k, v, mask)`` signature the
@@ -474,8 +487,9 @@ def make_ring_attention_fn(axis_name: str, causal: bool = True,
 
     own_window = window
 
-    def fn(q, k, v, mask=None, window=None):
+    def fn(q, k, v, mask=None, window=None, block_diffusion=None):
         del mask
+        refuse_block_diffusion(block_diffusion, "ring attention")
         qs = ks = None
         if segment_ids is not None:
             qs = _local_seg_slice(
@@ -521,8 +535,9 @@ def make_zigzag_ring_attention_fn(axis_name: str, segment_ids=None):
     ``segment_ids``: optional row-uniform GLOBAL (S,) ids ALREADY in
     zigzag layout (apply the same permutation as the tokens)."""
 
-    def fn(q, k, v, mask=None, window=None):
+    def fn(q, k, v, mask=None, window=None, block_diffusion=None):
         del mask
+        refuse_block_diffusion(block_diffusion, "zigzag ring attention")
         if window is not None:
             raise ValueError(
                 "zigzag ring attention builds no sliding window: a table "

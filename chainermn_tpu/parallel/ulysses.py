@@ -124,10 +124,13 @@ def make_ulysses_attention_fn(axis_name: str, causal: bool = True,
     hands it over at the call."""
     from chainermn_tpu.ops.flash_attention import row_window
 
+    from chainermn_tpu.parallel.ring_attention import refuse_block_diffusion
+
     own_window = window
 
-    def fn(q, k, v, mask=None, window=None):
+    def fn(q, k, v, mask=None, window=None, block_diffusion=None):
         del mask
+        refuse_block_diffusion(block_diffusion, "ulysses attention")
         qs = None
         if segment_ids is not None:
             if segment_ids.ndim != 1:

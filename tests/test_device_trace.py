@@ -432,7 +432,7 @@ def test_compiled_step_carries_the_flash_geometry(tiny_step):
 def test_the_models_parts_are_in_the_vocabulary():
     assert spans.MODEL_PARTS == (
         "embed", "norm", "residual", "attn-mixer", "attn-window",
-        "mixer-proj", "mixer-gate", "ffn")
+        "attn-blockdiff", "mixer-proj", "mixer-gate", "ffn")
     assert not set(spans.MODEL_PARTS) & set(spans.KERNEL_REGIONS)
     for name in spans.MODEL_PARTS:
         with spans.named_scope(name):
