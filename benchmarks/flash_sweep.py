@@ -2,10 +2,12 @@
 """Block-geometry sweep of the three flash kernels, alone, on the chip.
 
 For every ``block_q x block_k`` of ``--blocks`` it compiles the forward
-call and the backward pair (dq, dk/dv) at one shape, runs each a few
-times inside one profiler capture and reads the DEVICE time of each
-kernel by its scope (``observability.device_trace``) — no host clock, so
-a dispatch constant cannot hide a short grid.  A geometry that does not
+call and the backward (one pass under ``flash-bwd-dkv`` where the rows
+fit its footprint, else dq and dk/dv: ``_flash_bh_bwd``'s rule;
+``flash_bwd_probe.py`` times the two sides against each other) at one
+shape, runs each a few times inside one profiler capture and reads the
+DEVICE time of each kernel by its scope (``observability.device_trace``)
+— no host clock, so a dispatch constant cannot hide a short grid.  A geometry that does not
 compile is reported as such and skipped.
 
     chiprun -- env PYTHONPATH=. python benchmarks/flash_sweep.py \
@@ -122,7 +124,8 @@ def main():
     report, failed = device_report(programs, args.calls)
 
     # Needed FLOPs (what the roofline counts): 2 matmuls forward, 5 in the
-    # backward (2 in dq + its s, 3 in dk/dv + its s: 7 run), causal half.
+    # backward (what the one pass runs; the two kernels run 7: 2 in dq +
+    # its s, 3 in dk/dv + its s), causal half.
     mm = 2 * S * S * D * BH * (0.5 if args.causal else 1.0)
     rows = []
     for bq, bk in itertools.product(edges, edges):
@@ -139,8 +142,9 @@ def main():
                     row[kern + "_ms"] = region[kern]
         if "flash-fwd_ms" in row:
             row["fwd_tflops"] = 2 * mm / row["flash-fwd_ms"] / 1e9
-        if "flash-bwd-dq_ms" in row:
-            row["bwd_ms"] = row["flash-bwd-dq_ms"] + row["flash-bwd-dkv_ms"]
+        if "flash-bwd-dkv_ms" in row:
+            row["bwd_ms"] = (row.get("flash-bwd-dq_ms", 0.0)
+                             + row["flash-bwd-dkv_ms"])
             row["bwd_tflops"] = 5 * mm / row["bwd_ms"] / 1e9
         rows.append(row)
         print(json.dumps(row))

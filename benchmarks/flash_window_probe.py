@@ -6,9 +6,10 @@ each call (``ops.flash_attention.tile_census``: tiles live, grid steps
 visited, blocks copied a head row).
 
 Every geometry of ``--blocks-q x --blocks-k`` is compiled for the forward
-call and for the backward pair (dq, dk/dv), run ``--calls`` times inside
-one profiler capture and read by DEVICE time under each kernel's scope
-(``flash_sweep.device_report``).  A geometry Mosaic refuses is reported
+call and for the backward (one pass under ``flash-bwd-dkv`` on dq's grid
+where the rows fit its footprint — every shape here — else dq and
+dk/dv), run ``--calls`` times inside one profiler capture and read by
+DEVICE time under each kernel's scope (``flash_sweep.device_report``).  A geometry Mosaic refuses is reported
 and skipped.  ``--window 0`` probes the causal triangle at the same
 shape (the price of a live tile with no window mask).
 
@@ -43,8 +44,19 @@ from chainermn_tpu.ops.flash_attention import (
     tile_census,
 )
 
-KERNELS = {"fwd": ("flash-fwd",), "bwd": ("flash-bwd-dq", "flash-bwd-dkv")}
-CENSUS = {"flash-fwd": "fwd", "flash-bwd-dq": "dq", "flash-bwd-dkv": "dkv"}
+try:    # (a checkout from before the one-pass backward has no rule)
+    from chainermn_tpu.ops.flash_attention import bwd_fused_vmem_bytes
+except ImportError:
+    def bwd_fused_vmem_bytes(*shape):
+        return None
+
+
+def kernels_of(fused):
+    """``{pass: {scope: census key}}``: the one-pass backward runs under
+    ``flash-bwd-dkv`` on dq's grid."""
+    return {"fwd": {"flash-fwd": "fwd"},
+            "bwd": {"flash-bwd-dkv": "dq"} if fused else {
+                "flash-bwd-dq": "dq", "flash-bwd-dkv": "dkv"}}
 
 
 def build(which, bq, bk, scale, window):
@@ -104,7 +116,7 @@ def main():
     o, lse = build("fwd", *pairs[0], scale, window)(q, k, v)
 
     programs = {}
-    for which, (bq, bk) in itertools.product(KERNELS, pairs):
+    for which, (bq, bk) in itertools.product(("fwd", "bwd"), pairs):
         operands = (q, k, v) if which == "fwd" else (q, k, v, o, lse, do)
         programs[f"{which}_{bq}x{bk}"] = (
             build(which, bq, bk, scale, window), operands)
@@ -116,19 +128,22 @@ def main():
         row = {"block_q": bq, "block_k": bk,
                "fill_pct": 100.0 * attended_pairs(S, window)
                / (census["fwd"]["live"] * bq * bk)}
-        for which, kernels in KERNELS.items():
+        kernels = kernels_of(bwd_fused_vmem_bytes(
+            S, bq, bk, D, dtype.itemsize) is not None)
+        for which, scopes in kernels.items():
             name = f"{which}_{bq}x{bk}"
             if name in failed:
                 row[which + "_error"] = failed[name]
                 continue
             # Off the chip the capture has no device plane: no times.
             region = report["programs"].get(name, {}).get("region_ms", {})
-            for kern in kernels:
+            for kern, grid in scopes.items():
                 row[kern] = dict(
-                    {f: census[CENSUS[kern]][f]
+                    {f: census[grid][f]
                      for f in ("live", "visited", "copied")},
                     **({"ms": region[kern]} if kern in region else {}))
-        times = [row.get(kern, {}).get("ms") for kern in CENSUS]
+        times = [row.get(kern, {}).get("ms")
+                 for scopes in kernels.values() for kern in scopes]
         if None not in times:
             row["all_ms"] = sum(times)
         rows.append(row)

@@ -21,11 +21,33 @@ band exists to be skipped.  What a call was built with — blocks, tiles
 live / visited / copied a head row (:func:`tile_census`) — rides in the
 kernels' scope path and goes to the telemetry sinks.
 
-Differentiable: a ``custom_vjp`` with explicit FlashAttention-2-style
-backward kernels — the forward saves one fp32 log-sum-exp per row, and the
-dQ / dK+dV kernels recompute probabilities blockwise from it, so neither
-pass ever materializes the S×S matrix: O(S) memory in both passes.
-Device times on a TPU v5e are in PERF.md (§5 and §6, PR 25).
+Differentiable: a ``custom_vjp`` with an explicit FlashAttention-2-style
+backward — the forward saves one fp32 log-sum-exp per row, and the
+backward recomputes probabilities blockwise from it, so neither pass ever
+materializes the S×S matrix: O(S) memory in both passes.  The backward is
+ONE pass over the forward's grid (:func:`_flash_bwd_fused`, the kernel
+and scope ``flash-bwd-dkv``): each live tile makes ``s``, the mask,
+``p``, ``dp`` and ``ds`` once and from them all three gradients — five
+products and one fetch of each operand — ``dq`` summed over a Q block's
+K tiles in a ``(block_q, D)`` scratch, ``dk`` and ``dv`` summed into
+float32 buffers that hold the KV row WHOLE in VMEM over the row's group
+of query heads (:func:`bwd_resident_bytes`: 2 MiB at S = 2,048 and
+D = 128, 16 at S = 16,384, 24 at scores 192 / values 128) and cast into
+whole-row outputs once.  The rule is the shape's
+(:func:`bwd_fused_vmem_bytes`): one pass where that footprint — the
+streamed tile, the resident rows, the whole-row outputs twice — is within
+:data:`VMEM_LIMIT_MAX`, else the two kernels it replaced
+(:func:`_flash_bwd_pair`: ``flash-bwd-dq`` and ``flash-bwd-dkv``, each
+recomputing ``s``, ``p`` and ``dp``, seven products a tile), which sum
+the same terms in the same order — ring attention's long blocks, a 128k
+row.  No argument chooses.  The tile edge is sized as before, against
+the default scoped VMEM (:func:`auto_block_size`), the resident rows
+counted beside it.  A traced call says which side it got:
+``flash/bwd_fused`` and ``flash/bwd_resident_bytes`` gauges, a
+``flash/bwd_fused_calls`` counter beside ``flash/calls``, the
+``bwd_fused`` and ``bwd_resident_bytes`` fields of the ``flash_geometry``
+row (:func:`_publish_geometry`).  Device times on a TPU v5e are in
+PERF.md (§5 and §6, PR 25; the one pass against the two, PR 48).
 
 Optional segment-id masks support packed-sequence training: tokens attend
 only within their own segment, and padding rows produce zero output and
@@ -491,9 +513,16 @@ def _lanes(d: int) -> int:
     return max(128, -(-int(d) // 128) * 128)
 
 
+def bwd_resident_bytes(rows: int, D: int, D_v: int) -> int:
+    """What the one-pass backward keeps in VMEM from a KV row's first
+    query head to its last: the row's ``dk`` and ``dv`` in float32."""
+    return rows * (_lanes(D) + _lanes(D_v)) * 4
+
+
 def flash_vmem_bytes(block_q: int, block_k: int, D: int, itemsize: int,
                      which: str = "fwd", segmented: bool = False,
-                     D_v: Optional[int] = None) -> int:
+                     D_v: Optional[int] = None,
+                     rows: Optional[int] = None) -> int:
     """VMEM bytes one grid program of the flash kernels holds, for heads
     whose queries and keys are ``D`` wide and whose values — and so the
     output, its cotangent and ``dv`` — are ``D_v`` wide (None: ``D``).
@@ -508,9 +537,20 @@ def flash_vmem_bytes(block_q: int, block_k: int, D: int, itemsize: int,
     the least scoped limit under which each kernel compiles for a v5e at
     128 head rows, over D 128-256, bf16 and fp32, with and without
     segment ids (PERF.md §6, PR 25): the rule never picks a tile that
-    fails to compile inside the default there.  ``which``: ``"fwd"``, or
+    fails to compile inside the default there.  ``which``: ``"fwd"``,
     ``"bwd"`` for the larger of the dq and dk/dv kernels (two
-    ``pallas_call``s at the same blocks)."""
+    ``pallas_call``s at the same blocks: what the tile edge is sized
+    against), or ``"bwd_fused"`` for the one-pass backward over KV rows
+    ``rows`` long: the streamed operands and ``dq`` as in the dq kernel,
+    and beside them the RESIDENT rows — the float32 ``dk`` and ``dv`` of
+    the whole KV row in scratch, ``rows x (lanes(D) + lanes(D_v)) x 4``
+    bytes (2 MiB at S = 2,048 and D = 128, 16 at S = 16,384, 24 at
+    192 / 128), and the two whole-row outputs they are cast into, twice
+    as every output is — and the same share of a tile's intermediates
+    (read off the compiler the same way at the nine cells' backward
+    geometries, PERF.md §6, PR 48: the least limit it compiles under is
+    1.2-1.5 tiles past the operands, and under the rule's sum at every
+    one)."""
     D_v = D if D_v is None else D_v
     qd = block_q * _lanes(D)
     kd = block_k * _lanes(D)
@@ -527,6 +567,9 @@ def flash_vmem_bytes(block_q: int, block_k: int, D: int, itemsize: int,
     # q, k, v, do and the lse and delta columns stream in both kernels.
     streamed = 2 * (qd + od + kd + vd) * itemsize + 2 * 2 * q_col + seg
     dq = streamed + 2 * qd * itemsize + qd * 4
+    if which == "bwd_fused":
+        resident = bwd_resident_bytes(rows, D, D_v)
+        return dq + resident + 2 * (resident // 4) * itemsize + tiles
     dkv = streamed + 2 * (kd + vd) * itemsize + (kd + vd) * 4
     return max(dq, dkv) + tiles
 
@@ -588,11 +631,12 @@ def _walk_operands(blockdiff, block_q, block_k, streamed):
         *blockdiff, block_q, block_k, streamed))
 
 
-#: The two kernel wrappers are jitted in their own right: a model calls
+#: The kernel wrappers are jitted in their own right: a model calls
 #: them once a layer with the same shapes, and a jitted callee is traced
 #: once and lowered to one function that every layer calls — Mosaic's
 #: lowering of a 1024 x 1024 tile, paid at every process start even with
-#: the compile cache warm, is then paid three times and not 3 x layers.
+#: the compile cache warm, is then paid once a kernel and not once a
+#: kernel and layer.
 _KERNEL_STATICS = ("scale", "causal", "block_q", "block_k", "interpret",
                    "window", "blockdiff")
 
@@ -828,53 +872,244 @@ def _dkv_kernel(
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
-def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
-                  interpret, dlse=None, q_seg=None, kv_seg=None,
-                  window=None, blockdiff=None):
-    """(BH, S, D) flash attention backward: (dq, dk, dv).
+def _bwd_kernel(
+    *refs,
+    scale: float, causal: bool, segmented: bool, block_q: int, block_k: int,
+    kv_range, group: int, window=None, blockdiff=None,
+):
+    """The whole backward in one pass over :func:`_dq_kernel`'s grid:
+    ``s``, the mask, ``p``, ``dp`` and ``ds`` once a tile, ``dq`` summed
+    over the row's K tiles in its ``(block_q, D)`` scratch as there, and
+    ``dk`` / ``dv`` summed into float32 buffers that hold the KV row
+    WHOLE, resident over the ``group`` query heads of the KV row (they
+    are consecutive head rows, :func:`_kv_group`): zeroed at the group's
+    first step, a tile's rows added to at each step, cast and written at
+    its last.  A K tile so receives its terms in ``(head, q block)``
+    order, :func:`_dkv_kernel`'s on a rectangle and on a band."""
+    walk, refs = _walked(refs, blockdiff, block_q, block_k)
+    if segmented:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qs_ref, ks_ref,
+         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
+        qs_ref = ks_ref = None
+    j = n_j = None
+    if walk is None:
+        iq = pl.program_id(1)
+        j = pl.program_id(2)
+        n_j = pl.num_programs(2)
+    first, last = _edges(walk, j, n_j)
+    # The group's ends: its first head row's first step, its last head
+    # row's last — every inner axis of the grid at its end.
+    g = pl.program_id(0) % group
+    inner = range(1, 2 if walk is not None else 3)
+    opens = functools.reduce(
+        jnp.logical_and, [pl.program_id(a) == 0 for a in inner], g == 0)
+    closes = functools.reduce(
+        jnp.logical_and,
+        [pl.program_id(a) == pl.num_programs(a) - 1 for a in inner],
+        g == group - 1)
+    n_k = dk_acc.shape[0] // block_k
 
-    ``dlse``: optional cotangent of the row log-sum-exp output (used when
-    the LSE itself feeds downstream math, e.g. cross-block merging in ring
-    attention).  Since ∂lse_i/∂s_ij = p_ij, the whole contribution folds
-    into the per-row residual: ds = p·(dp − (δ − dlse)).
-    """
-    BH, Sq, D = q.shape
-    Sk, Dv = k.shape[1], v.shape[2]
-    BHk = k.shape[0]
-    G = _kv_group(BH, BHk)
-    segmented = q_seg is not None
-    # delta_i = rowsum(dO ∘ O) — cheap elementwise, XLA handles it.
+    def k_rows(ik):
+        return pl.ds(pl.multiple_of(ik * block_k, block_k), block_k)
+
+    @pl.when(opens)
+    def _():
+        def zero(ik, carry):
+            dk_acc[k_rows(ik), :] = jnp.zeros((block_k, dk_acc.shape[1]),
+                                              jnp.float32)
+            dv_acc[k_rows(ik), :] = jnp.zeros((block_k, dv_acc.shape[1]),
+                                              jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, n_k, zero, 0)
+
+    @pl.when(first())
+    def _():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    if walk is None:
+        q_start = iq * block_q
+        ik, in_band = _streamed_block(kv_range, iq, j, window)
+        k_start = ik * block_k
+    else:
+        ik = walk[1]
+
+    def tile(mask_of):
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        do = do_ref[0]
+        rows = k_rows(ik)
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        mask = mask_of(s.shape)
+        if mask is not None:
+            s = jnp.where(mask, s, _NEG_INF)
+        p = jnp.exp(s - lse_ref[0, :, :])             # exact probabilities
+        if segmented or window is not None:
+            p = jnp.where(mask, p, 0.0)  # see _dq_kernel
+        pt = p.astype(do.dtype).T
+        dv_acc[rows, :] += jnp.dot(pt, do,
+                                   preferred_element_type=jnp.float32)
+        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[0, :, :]) * scale).astype(q.dtype)
+        dk_acc[rows, :] += jnp.dot(ds.T, q,
+                                   preferred_element_type=jnp.float32)
+        dq_acc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+
+    _run_tiles(tile, walk, lambda: _band_run(
+        causal, window, q_start, block_q, k_start, block_k, in_band),
+        lambda shape: _block_mask(shape, causal, q_start, k_start, qs_ref,
+                                  ks_ref, window))
+
+    @pl.when(last())
+    def _():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+    @pl.when(closes)
+    def _():
+        def write(ik, carry):
+            rows = k_rows(ik)
+            dk_ref[0, rows, :] = dk_acc[rows, :].astype(dk_ref.dtype)
+            dv_ref[0, rows, :] = dv_acc[rows, :].astype(dv_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, n_k, write, 0)
+
+
+def _bwd_delta(o, do, dlse=None):
+    """The rows' residual of the backward, ``delta_i = rowsum(dO ∘ O)``
+    as a (BH, Sq, 1) float32 column — cheap elementwise, XLA handles it.
+    ``dlse``: the cotangent of the row log-sum-exp where that is an
+    output (ring attention merges blocks by it): since
+    ∂lse_i/∂s_ij = p_ij, the whole contribution folds into the column,
+    ds = p·(dp − (δ − dlse))."""
     delta = jnp.sum(
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     )[..., None]                                   # (BH, Sq, 1)
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)[..., None]
+    return delta
 
+
+def bwd_fused_vmem_bytes(Sk: int, block_q: int, block_k: int, D: int,
+                         itemsize: int, segmented: bool = False,
+                         D_v: Optional[int] = None) -> Optional[int]:
+    """The footprint of the one-pass backward over KV rows ``Sk`` long
+    (:func:`flash_vmem_bytes`, ``which="bwd_fused"``) where that is
+    within :data:`VMEM_LIMIT_MAX`, else None: THE rule by which
+    :func:`_flash_bh_bwd` runs one kernel or two.  By shape alone."""
+    footprint = flash_vmem_bytes(block_q, block_k, D, itemsize, "bwd_fused",
+                                 segmented, D_v, rows=Sk)
+    return footprint if footprint <= VMEM_LIMIT_MAX else None
+
+
+def _kv_streamed(q, k, v, do, lse, delta, q_seg, kv_seg, *, block_q,
+                 block_k, causal, window, blockdiff):
+    """What a backward call on the forward's grid — a Q block resident,
+    K and V streamed (:func:`_kv_walk`): dq alone, or the fused pass —
+    is built from: ``(kv_range, tables, inner, q_spec(d), in_specs,
+    args)``."""
+    BH, Sq, D = q.shape
+    Sk, Dv = k.shape[1], v.shape[2]
+    G = _kv_group(BH, k.shape[0])
+    kv_range, _ = _live_ranges(Sq, Sk, block_q, block_k, causal, window)
+    q_at, k_at, inner, tables = _kv_walk(
+        kv_range, Sq // block_q, Sk // block_k, causal, window, blockdiff,
+        block_q, block_k)
+
+    def q_spec(d):
+        return pl.BlockSpec((1, block_q, d), lambda b, *g: (b, q_at(*g), 0))
+
+    def k_spec(d):
+        return pl.BlockSpec((1, block_k, d),
+                            lambda b, *g: (b // G, k_at(*g), 0))
+
+    in_specs = [q_spec(D), k_spec(D), k_spec(Dv), q_spec(Dv), q_spec(1),
+                q_spec(1)]
+    args = [q, k, v, do, lse, delta]
+    if q_seg is not None:
+        in_specs += [q_spec(1), k_spec(1)]
+        args += [q_seg, kv_seg]
+    return kv_range, tables, inner, q_spec, in_specs, args
+
+
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
+def _flash_bwd_fused(q, k, v, o, lse, do, *, scale, causal, block_q,
+                     block_k, interpret, dlse=None, q_seg=None, kv_seg=None,
+                     window=None, blockdiff=None):
+    """(dq, dk, dv) from ONE ``pallas_call`` (:func:`_bwd_kernel`) under
+    the scope and the name ``flash-bwd-dkv``: five products a live tile
+    and one fetch of each operand, where the pair below runs seven and
+    two.  ``dk`` and ``dv`` leave as whole-row blocks indexed by the KV
+    row alone, so each is written once, when the row's last query head
+    is done.  (The operands are :func:`_flash_bh_bwd`'s.)"""
+    delta = _bwd_delta(o, do, dlse)
+    BH, Sq, D = q.shape
+    Sk, Dv = k.shape[1], v.shape[2]
+    BHk = k.shape[0]
+    G = _kv_group(BH, BHk)
+    segmented = q_seg is not None
+    kv_range, tables, inner, q_spec, in_specs, args = _kv_streamed(
+        q, k, v, do, lse, delta, q_seg, kv_seg, block_q=block_q,
+        block_k=block_k, causal=causal, window=window, blockdiff=blockdiff)
+    dk_spec, dv_spec = (pl.BlockSpec((1, Sk, d), lambda b, *g: (b // G, 0, 0))
+                        for d in (D, Dv))
+    tiles = tile_census(Sq, Sk, block_q, block_k, causal, window,
+                        blockdiff)["dq"]
+    with named_scope("flash-bwd-dkv"), tiles_scope(**tiles):
+        return _pallas(
+            functools.partial(
+                _bwd_kernel, scale=scale, causal=causal, segmented=segmented,
+                block_q=block_q, block_k=block_k, kv_range=kv_range,
+                group=G, window=window, **_masked(blockdiff),
+            ), tables,
+            out_shape=[
+                jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
+                jax.ShapeDtypeStruct((BHk, Sk, D), k.dtype),
+                jax.ShapeDtypeStruct((BHk, Sk, Dv), v.dtype),
+            ],
+            grid=(BH, *inner),
+            in_specs=in_specs,
+            out_specs=[q_spec(D), dk_spec, dv_spec],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((Sk, D), jnp.float32),
+                pltpu.VMEM((Sk, Dv), jnp.float32),
+            ],
+            compiler_params=_compiler_params(flash_vmem_bytes(
+                block_q, block_k, D, q.dtype.itemsize, "bwd_fused",
+                segmented, Dv, rows=Sk)),
+            interpret=interpret,
+            name="flash-bwd-dkv",
+        )(*tables, *args)
+
+
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
+def _flash_bwd_pair(q, k, v, o, lse, do, *, scale, causal, block_q,
+                    block_k, interpret, dlse=None, q_seg=None, kv_seg=None,
+                    window=None, blockdiff=None):
+    """(dq, dk, dv) from two ``pallas_call``s, ``flash-bwd-dq`` and
+    ``flash-bwd-dkv``, each computing ``s``, ``p`` and ``dp`` for itself:
+    the backward of a KV row too long for :func:`_flash_bwd_fused`'s
+    resident accumulators (:func:`bwd_fused_vmem_bytes`), the same sums
+    in the same order.  (The operands are :func:`_flash_bh_bwd`'s.)"""
+    delta = _bwd_delta(o, do, dlse)
+    BH, Sq, D = q.shape
+    Sk, Dv = k.shape[1], v.shape[2]
+    BHk = k.shape[0]
+    G = _kv_group(BH, BHk)
+    segmented = q_seg is not None
     n_q = Sq // block_q
     n_k = Sk // block_k
     params = _compiler_params(flash_vmem_bytes(
         block_q, block_k, D, q.dtype.itemsize, "bwd", segmented, Dv))
-    kv_range, q_range = _live_ranges(Sq, Sk, block_q, block_k, causal,
-                                     window)
-    q_at, k_at, inner, tables = _kv_walk(
-        kv_range, n_q, n_k, causal, window, blockdiff, block_q, block_k)
-    q_spec, do_spec = (pl.BlockSpec((1, block_q, d),
-                                    lambda b, *g: (b, q_at(*g), 0))
-                       for d in (D, Dv))
-    k_spec, v_spec = (pl.BlockSpec((1, block_k, d),
-                                   lambda b, *g: (b // G, k_at(*g), 0))
-                      for d in (D, Dv))
-    r_spec = pl.BlockSpec((1, block_q, 1), lambda b, *g: (b, q_at(*g), 0))
-    dq_in = [q_spec, k_spec, v_spec, do_spec, r_spec, r_spec]
-    dq_args = [q, k, v, do, lse, delta]
-    if segmented:
-        dq_in += [
-            r_spec,
-            pl.BlockSpec((1, block_k, 1),
-                         lambda b, *g: (b // G, k_at(*g), 0)),
-        ]
-        dq_args += [q_seg, kv_seg]
+    _, q_range = _live_ranges(Sq, Sk, block_q, block_k, causal, window)
+    kv_range, tables, inner, q_spec, dq_in, dq_args = _kv_streamed(
+        q, k, v, do, lse, delta, q_seg, kv_seg, block_q=block_q,
+        block_k=block_k, causal=causal, window=window, blockdiff=blockdiff)
     tiles = tile_census(Sq, Sk, block_q, block_k, causal, window, blockdiff)
     with named_scope("flash-bwd-dq"), tiles_scope(**tiles["dq"]):
         dq = _pallas(
@@ -886,9 +1121,7 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
             out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
             grid=(BH, *inner),
             in_specs=dq_in,
-            out_specs=pl.BlockSpec(
-                (1, block_q, D), lambda b, *g: (b, q_at(*g), 0)
-            ),
+            out_specs=q_spec(D),
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
             compiler_params=params,
             interpret=interpret,
@@ -966,6 +1199,29 @@ def _flash_bh_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
     return dq, dk, dv
 
 
+def _flash_bh_bwd(q, k, v, o, lse, do, *, block_q, block_k, q_seg=None,
+                  **call):
+    """(BH, S, D) flash attention backward: (dq, dk, dv) — in one pass
+    over the tiles (:func:`_flash_bwd_fused`) where the KV row's float32
+    ``dk`` and ``dv`` fit in VMEM beside the tile
+    (:func:`bwd_fused_vmem_bytes`: every row up to 16,384 at D <= 256),
+    else by the two kernels (:func:`_flash_bwd_pair`).  Either side is
+    jitted in its own right, as the forward is, and takes this
+    function's operands and keywords (``scale``, ``causal``,
+    ``interpret``, ``kv_seg``, ``window``, ``blockdiff``).
+
+    ``dlse``: optional cotangent of the row log-sum-exp output (used when
+    the LSE itself feeds downstream math, e.g. cross-block merging in ring
+    attention), folded into the rows' residual (:func:`_bwd_delta`).
+    """
+    fused = bwd_fused_vmem_bytes(
+        k.shape[1], block_q, block_k, q.shape[2], q.dtype.itemsize,
+        q_seg is not None, v.shape[2]) is not None
+    return (_flash_bwd_fused if fused else _flash_bwd_pair)(
+        q, k, v, o, lse, do, block_q=block_q, block_k=block_k, q_seg=q_seg,
+        **call)
+
+
 #: The name (``jax.ad_checkpoint.checkpoint_name``) of what the backward
 #: kernels read of the forward kernel: its output ``o`` and the rows'
 #: log-sum-exp ``lse``.  It is put on them INSIDE the forward rules of
@@ -1016,8 +1272,8 @@ def _flash_bh(q, k, v, scale, causal, block_q, block_k, interpret,
     LSE, never materializing the S×S matrix in either pass).
 
     ``block_q_bwd``/``block_k_bwd``: optional separate geometry for the
-    backward kernels (their tile economics differ — two extra streamed
-    operands, two kernels); None means reuse the forward blocks.
+    backward (its tile economics differ — two extra streamed
+    operands, five products a tile); None means reuse the forward blocks.
     ``blockdiff``: ``(L, B)`` of the block-diffusion mask, or None."""
     o, _ = _flash_bh_fwd(
         q, k, v, scale=scale, causal=causal,
@@ -1305,21 +1561,39 @@ def _publish_blockdiff(blockdiff, record) -> None:
            for field in ("live", "visited", "copied")}})
 
 
-def _publish_geometry(fwd: dict, bwd: dict, blockdiff=None) -> None:
+def _publish_geometry(fwd: dict, bwd: dict, blockdiff=None,
+                      resident_bytes: Optional[int] = None) -> None:
     """One record a :func:`flash_attention` call that reaches the kernels
     (at TRACE time: a jitted step publishes again only when retraced):
     the blocks each kernel was given and the tiles it finds live, visits
-    and copies a head row.  To the installed sinks: a ``flash_geometry``
-    row of the StepRecorder, ``flash/<kernel>/<field>`` gauges and a
-    ``flash/calls`` counter of the Reporter."""
-    record = {"flash-fwd": fwd, "flash-bwd-dq": bwd["dq"],
-              "flash-bwd-dkv": bwd["dkv"]}
+    and copies a head row, and which backward the footprint rule gave it
+    — ``resident_bytes`` the float32 ``dk`` / ``dv`` rows the one-pass
+    backward holds in VMEM (its tiles are then ``flash-bwd-dkv``'s, on
+    dq's grid, and there is no ``flash-bwd-dq`` entry), None the two
+    kernels.  To the installed sinks: a ``flash_geometry`` row of the
+    StepRecorder (the kernels' entries, ``bwd_fused`` and
+    ``bwd_resident_bytes``), ``flash/<kernel>/<field>`` gauges,
+    ``flash/bwd_fused`` (1 or 0) and ``flash/bwd_resident_bytes`` gauges
+    and the ``flash/calls`` and ``flash/bwd_fused_calls`` counters of the
+    Reporter."""
+    fused = resident_bytes is not None
+    record = {"flash-fwd": fwd}
+    if fused:
+        record["flash-bwd-dkv"] = bwd["dq"]
+    else:
+        record.update({"flash-bwd-dq": bwd["dq"],
+                       "flash-bwd-dkv": bwd["dkv"]})
     rec = _step_log.current_recorder()
     if rec is not None:
-        rec.record("flash_geometry", **record)
+        rec.record("flash_geometry", **record, bwd_fused=fused,
+                   bwd_resident_bytes=resident_bytes or 0)
     rep = _reporter.get_reporter()
     if rep is not None:
         rep.count("flash/calls")
+        if fused:
+            rep.count("flash/bwd_fused_calls")
+        rep.gauge("flash/bwd_fused", int(fused))
+        rep.gauge("flash/bwd_resident_bytes", resident_bytes or 0)
         for kernel, tiles in record.items():
             for field, value in tiles.items():
                 rep.gauge(f"flash/{kernel}/{field}", value)
@@ -1355,7 +1629,7 @@ def flash_attention(
     ``window``: optional sliding-window size (Mistral-style local
     attention, causal only): query ``i`` attends keys ``[i - window + 1,
     i]``, intersected with the segment masks.  Whole tiles outside the
-    band are never entered, in forward AND both backward kernels: a
+    band are never entered, in forward AND backward: a
     windowed call's inner grid axis is the band's width in tiles
     (:func:`_band_steps`), not the sequence's, so compute AND grid steps
     scale O(S * window) instead of O(S²/2) (PERF.md §6, PR 40: a step
@@ -1404,8 +1678,8 @@ def flash_attention(
     of the roofline) and on the block sweep of PERF.md §6, PR 25.
 
     ``block_q_bwd``/``block_k_bwd``: optional separate geometry for the
-    backward kernels (the backward streams two extra operands and runs
-    two kernels, so its optimum can differ).  With nothing pinned they
+    backward (it streams two extra operands and holds more per tile,
+    so its optimum can differ).  With nothing pinned they
     default to the rule's answer for the backward's footprint; blocks
     pinned for the forward carry over.
     """
@@ -1521,12 +1795,15 @@ def flash_attention(
         block_q_bwd, block_k_bwd = (bq_b, bk_b) if bwd_ok else (None, None)
 
     if telemetry_active():
+        bq_b, bk_b = block_q_bwd or block_q, block_k_bwd or block_k
+        fused = bwd_fused_vmem_bytes(Sk, bq_b, bk_b, D, q.dtype.itemsize,
+                                     segmented, Dv) is not None
         _publish_geometry(
             tile_census(Sq, Sk, block_q, block_k, causal, window,
                         blockdiff)["fwd"],
-            tile_census(Sq, Sk, block_q_bwd or block_q,
-                        block_k_bwd or block_k, causal, window, blockdiff),
+            tile_census(Sq, Sk, bq_b, bk_b, causal, window, blockdiff),
             blockdiff,
+            bwd_resident_bytes(Sk, D, Dv) if fused else None,
         )
 
     # (B, S, H, D) → (B*H, S, D); kv keep their own (possibly smaller)
@@ -1558,7 +1835,7 @@ def flash_block_plan(S: int, D: int, dtype, interpret: bool):
     non-dividing block floors the grid and silently drops tail rows —
     interpret mode included), sized by :func:`auto_block_size`: the
     largest tile that fits the default scoped VMEM in the forward AND
-    the backward kernels, which share the one block here."""
+    the backward, which share the one block here."""
     if interpret:
         # Interpreter-mode block policy: a full-S block materializes the
         # S×S matrix (defeating the O(S) property), while a degenerate
@@ -1577,7 +1854,7 @@ def flash_block_plan(S: int, D: int, dtype, interpret: bool):
     if D > 256:
         return False, 0
     if S % 128 == 0:
-        # One block serves the forward and both backward kernels here.
+        # One block serves the forward and the backward here.
         return True, min(auto_block_size(S, D, dtype, "fwd"),
                          auto_block_size(S, D, dtype, "bwd"))
     if S <= 512 and S % _sublane(dtype) == 0:

@@ -11,7 +11,13 @@ digest is what holds the kernel itself).
 writes them; run in a checkout of the commit BEFORE a change to
 ``ops/flash_attention.py``, it gives what
 ``tests/test_flash_attention.py`` holds the change to
-(``tests/golden/flash_no_window.json``, from the parent of PR 40).
+(``tests/golden/flash_no_window.json``: the five ``fwd`` records from
+the parent of PR 40).  A change that is MEANT to move this path remakes
+its records on the changed tree and shows that the others kept theirs:
+the five ``bwd`` records are PR 48's, whose backward is one
+``pallas_call`` on the forward's grid where the parent ran two (``bwd``
+goes through ``_flash_bh_bwd``, so it records the side the footprint
+rule gives rows this short: the one pass).
 """
 
 import hashlib

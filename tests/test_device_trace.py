@@ -61,8 +61,10 @@ def test_compiled_step_holds_every_phase_and_kernel_region(tiny_step):
     phases = {phase for phase, _ in found}
     regions = {region for _, region in found}
     assert set(spans.STEP_PHASES) <= phases
-    assert {"flash-fwd", "flash-bwd-dq", "flash-bwd-dkv", "fused-ce",
+    # (the flash backward is one pass under flash-bwd-dkv: PR 48)
+    assert {"flash-fwd", "flash-bwd-dkv", "fused-ce",
             "grad-stage0", "grad-unpack"} <= regions
+    assert "flash-bwd-dq" not in regions
     # a kernel region sits inside the phase that runs it
     assert ("fwd-bwd", "fused-ce") in found
     assert ("allreduce", "grad-unpack") in found
@@ -414,14 +416,14 @@ def test_report_puts_the_tiles_beside_the_region():
 
 
 def test_compiled_step_carries_the_flash_geometry(tiny_step):
-    """The three kernels' blocks and tile census ride in the compiled
-    step's own paths: a capture shows them whether or not the step was
-    traced under a telemetry sink."""
+    """The kernels' blocks and tile census ride in the compiled step's
+    own paths: a capture shows them whether or not the step was traced
+    under a telemetry sink.  (Two kernels since PR 48: the backward is
+    one pass on dq's grid, under ``flash-bwd-dkv``.)"""
     step, params, state, feed = tiny_step
     table = device_trace.scope_table(
         step.lower(params, state, feed(0)).compile())
-    assert set(table.tiles) == {"flash-fwd", "flash-bwd-dq",
-                                "flash-bwd-dkv"}
+    assert set(table.tiles) == {"flash-fwd", "flash-bwd-dkv"}
     for found in table.tiles.values():
         assert len(found) == 1 and set(found[0]) == set(spans.TILE_FIELDS)
         assert 0 < found[0]["live"] <= found[0]["visited"]

@@ -1,6 +1,7 @@
 """Pallas flash-attention kernel vs the XLA oracle (interpret mode on the
 CPU harness; the same kernel compiles for real on TPU)."""
 
+import importlib
 import os
 import sys
 
@@ -17,6 +18,9 @@ from chainermn_tpu.ops.flash_attention import (  # noqa: E402
     auto_block_size,
     flash_attention,
 )
+
+# (the module: ``chainermn_tpu.ops.flash_attention`` names the function)
+fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
 
 
 def make_qkv(B=2, S=256, H=2, D=64, seed=0, dtype=jnp.float32):
@@ -1040,14 +1044,23 @@ def test_flash_without_a_window_is_the_parents_program(
     kernels it had before the band grid (PR 40): the grids of its
     ``pallas_call``s are the whole rectangle, and its JAXPR and its text
     lowered for a TPU hash to what the parent commit's did
-    (``tests/_flash_no_window.py`` says how the golden file is made)."""
+    (``tests/_flash_no_window.py`` says how the golden file is made).
+    The backward is one ``pallas_call`` on the forward's grid since
+    PR 48 (rows this short fit the one-pass footprint), and its five
+    digests are that program's; the forward's are PR 40's parent's."""
     BH, BHk, Sq, Sk, _, bq, bk, *_ = _flash_no_window.CASES[case]
     got = _flash_no_window.record(case)[which]
-    n_q, n_k, G = Sq // bq, Sk // bk, BH // BHk
-    assert got["grids"] == {
-        "fwd": [[BH, n_q, n_k]],
-        "bwd": [[BH, n_q, n_k], [BHk, n_k, G * n_q]]}[which]
-    assert got == no_window_golden[case][which]
+    n_q, n_k = Sq // bq, Sk // bk
+    assert got["grids"] == [[BH, n_q, n_k]]
+    assert got == no_window_golden[case][which], (
+        f"{case}/{which}: the program of a call without a window moved. "
+        f"If ops/flash_attention.py was meant to change it, remake the "
+        f"file ON THE CHANGED TREE with `PYTHONPATH=. JAX_PLATFORMS=cpu "
+        f"python tests/_flash_no_window.py tests/golden/"
+        f"flash_no_window.json` and show in the PR that the cases the "
+        f"change does not reach kept their digests (`git diff` of the "
+        f"file); a change that was NOT meant to reach this path has a "
+        f"fault.")
 
 
 def _mode_kwargs(mode, B, S, H):
@@ -1141,14 +1154,176 @@ def test_flash_geometry_record_reaches_the_sinks(tmp_path):
         f(q, k, v)                      # no retrace: no second record
     summary = rep.summary()
     assert summary["counters"]["flash/calls"] == 1
+    assert summary["counters"]["flash/bwd_fused_calls"] == 1
     gauges = {n: g["value"] for n, g in summary["gauges"].items()}
     assert gauges["flash/flash-fwd/block_q"] == 32
     assert gauges["flash/flash-fwd/block_k"] == 64
     assert gauges["flash/flash-bwd-dkv/visited"] == 8
+    assert gauges["flash/bwd_fused"] == 1
+    # (dk and dv of the 128-row KV row in float32, 128 lanes each)
+    assert gauges["flash/bwd_resident_bytes"] == 128 * 2 * 128 * 4
+    assert "flash/flash-bwd-dq/visited" not in gauges
     assert gauges["flash/flash-fwd/live"] == 6
     rows = [json.loads(line) for line in open(path)]
     rows = [r for r in rows if r["event"] == "flash_geometry"]
     assert len(rows) == 1
     assert rows[0]["flash-fwd"] == {
         "block_q": 32, "block_k": 64, "live": 6, "visited": 8, "copied": 4}
-    assert set(rows[0]) >= {"flash-fwd", "flash-bwd-dq", "flash-bwd-dkv"}
+    # The one-pass backward: its tiles are flash-bwd-dkv's, on dq's grid.
+    assert set(rows[0]) >= {"flash-fwd", "flash-bwd-dkv", "bwd_fused",
+                            "bwd_resident_bytes"}
+    assert "flash-bwd-dq" not in rows[0]
+    assert rows[0]["bwd_fused"] is True
+    assert rows[0]["flash-bwd-dkv"] == rows[0]["flash-fwd"]
+
+
+#: name -> (BH, BHk, Sq, Sk, D, D_v, block_q, block_k, causal, window,
+#: segmented, dlse, dtype): the backward's two sides on the same
+#: operands.
+_BWD_SIDES = {
+    "causal": (4, 4, 256, 256, 64, 64, 64, 64, True, None, False, False,
+               jnp.bfloat16),
+    "full": (2, 2, 256, 256, 128, 128, 128, 64, False, None, False, False,
+             jnp.bfloat16),
+    "gqa": (4, 2, 256, 256, 64, 64, 64, 128, True, None, False, False,
+            jnp.bfloat16),
+    "gqa-fp32": (8, 2, 256, 256, 32, 32, 64, 64, True, None, False, False,
+                 jnp.float32),
+    "window": (4, 4, 256, 256, 64, 64, 32, 32, True, 40, False, False,
+               jnp.bfloat16),
+    "segments": (4, 2, 256, 256, 64, 64, 128, 64, True, None, True, False,
+                 jnp.bfloat16),
+    "window-segments-gqa": (4, 2, 256, 256, 64, 64, 32, 64, True, 40, True,
+                            False, jnp.float32),
+    "dlse-more-keys": (4, 1, 128, 256, 64, 64, 32, 64, True, None, False,
+                       True, jnp.bfloat16),
+    "dlse-segments-more-keys": (4, 2, 128, 256, 32, 32, 32, 64, True, None,
+                                True, True, jnp.float32),
+}
+
+
+def _bwd_operands(case):
+    """``(operands, geometry)`` of one :data:`_BWD_SIDES` case: ``(q, k,
+    v, o, lse, do)`` from the forward kernel on seeded inputs, the
+    segment ids and ``dlse`` in ``geometry``."""
+    (BH, BHk, Sq, Sk, D, Dv, bq, bk, causal, window, segmented, dlse,
+     dtype) = _BWD_SIDES[case]
+    ks = jax.random.split(jax.random.PRNGKey(48), 5)
+    q = jax.random.normal(ks[0], (BH, Sq, D), dtype)
+    k = jax.random.normal(ks[1], (BHk, Sk, D), dtype)
+    v = jax.random.normal(ks[2], (BHk, Sk, Dv), dtype)
+    do = jax.random.normal(ks[3], (BH, Sq, Dv), dtype)
+    geometry = dict(scale=1.0 / D**0.5, causal=causal, block_q=bq,
+                    block_k=bk, interpret=True, window=window)
+    if segmented:
+        ids = np.sort(np.random.RandomState(5).randint(
+            0, 3, size=max(Sq, Sk))).astype(np.int32)
+        geometry.update(
+            q_seg=jnp.broadcast_to(ids[None, :Sq, None], (BH, Sq, 1)),
+            kv_seg=jnp.broadcast_to(ids[None, :Sk, None], (BHk, Sk, 1)))
+    o, lse = fa._flash_bh_fwd(q, k, v, **geometry)
+    if dlse:
+        geometry["dlse"] = jax.random.normal(ks[4], (BH, Sq), jnp.float32)
+    return (q, k, v, o, lse, do), geometry
+
+
+@pytest.mark.parametrize("case", sorted(_BWD_SIDES))
+def test_flash_backward_in_one_pass_is_the_two_kernels_to_the_bit(case):
+    """``dq``, ``dk``, ``dv`` of the one-pass backward
+    (``_flash_bwd_fused``) against the two kernels (``_flash_bwd_pair``,
+    the other side of the footprint rule) on the same operands: every
+    float32 sum runs in the same order — ``dq`` over the row's K tiles, a
+    ``dk`` / ``dv`` tile over ``(head of the group, q block)`` — so the
+    three are EQUAL, bit for bit, in interpret mode."""
+    operands, geometry = _bwd_operands(case)
+    fused = fa._flash_bwd_fused(*operands, **geometry)
+    pair = fa._flash_bwd_pair(*operands, **geometry)
+    for name, a, b in zip(("dq", "dk", "dv"), fused, pair):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+        assert np.asarray(a, np.float32).any(), name
+
+
+def _pallas_names(jaxpr):
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.extend(_pallas_names(sub))
+    return out
+
+
+def test_flash_backward_past_the_footprint_rule_runs_the_two_kernels(
+        monkeypatch):
+    """The choice is by shape alone: a KV row whose float32 ``dk`` and
+    ``dv`` fit in VMEM beside the tile (every row of the benchmark's
+    cells; ``bwd_fused_vmem_bytes``) takes the one pass, a longer one the
+    parent's two kernels — here the limit is brought down under the
+    row, as ring attention's long blocks and 128k rows lie over the real
+    one — with the same gradients and no ``flash/bwd_fused_calls``."""
+    from chainermn_tpu.observability import Reporter
+    from chainermn_tpu.observability import reporter as reporter_mod
+
+    q, k, v = make_qkv(B=1, S=384, H=4, D=32)
+    k, v = k[:, :, :2], v[:, :, :2]
+    blocks = dict(block_q=64, block_k=128)
+
+    def traced():
+        # (a function of its own each time: make_jaxpr keeps what it
+        # traced of one)
+        def grads(q, k, v):
+            return jax.grad(lambda q, k, v: (flash_attention(
+                q, k, v, causal=True, **blocks) ** 2).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+
+        rep = Reporter()
+        with reporter_mod.scope(rep):
+            names = _pallas_names(jax.make_jaxpr(grads)(q, k, v).jaxpr)
+            return names, rep.summary()["counters"], grads(q, k, v)
+
+    # The real limit: 384 rows are nothing beside it.
+    assert fa.bwd_fused_vmem_bytes(384, 64, 128, 32, 4, False, 32) is not None
+    assert fa.bwd_fused_vmem_bytes(131072, 1024, 1024, 128, 2) is None
+    names, counters, fused = traced()
+    assert sorted(names) == ["flash-bwd-dkv", "flash-fwd"]
+    assert counters["flash/bwd_fused_calls"] == counters["flash/calls"] == 1
+    # Under the row: the pair's own footprint still fits, the row's not.
+    monkeypatch.setattr(
+        fa, "VMEM_LIMIT_MAX",
+        fa.flash_vmem_bytes(64, 128, 32, 4, "bwd_fused", False, 32,
+                            rows=384) - 1)
+    names, counters, pair = traced()
+    assert sorted(names) == ["flash-bwd-dkv", "flash-bwd-dq", "flash-fwd"]
+    assert counters["flash/calls"] == 1
+    assert "flash/bwd_fused_calls" not in counters
+    for a, b in zip(fused, pair):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_flash_vmem_bytes_counts_the_resident_rows():
+    """The one-pass backward's footprint is the dq kernel's and the
+    resident rows beside it — float32 ``dk`` and ``dv`` of the whole KV
+    row, and the two whole-row outputs twice — so it grows with the row
+    and not only with the tile, and the tile edge rule (``which="bwd"``)
+    does not see it."""
+    MiB = 1 << 20
+    # The issue's table: 2 MiB in the cgpt cells, 8 in granite (64 lanes
+    # padded to 128), nemo and zaya, 16 in qwen3next, mellum and sdar, 24
+    # in ling3flash (192 -> 256 lanes, + 128).
+    for rows, D, Dv, want in [(2048, 128, 128, 2), (8192, 64, 64, 8),
+                              (8192, 128, 128, 8), (8192, 256, 256, 16),
+                              (16384, 128, 128, 16), (16384, 192, 128, 24)]:
+        assert fa.bwd_resident_bytes(rows, D, Dv) == want * MiB
+    short = fa.flash_vmem_bytes(1024, 1024, 128, 2, "bwd_fused", rows=2048)
+    long = fa.flash_vmem_bytes(1024, 1024, 128, 2, "bwd_fused", rows=16384)
+    per_row = (128 + 128) * (4 + 2 * 2)
+    assert long - short == (16384 - 2048) * per_row
+    assert fa.auto_block_size(16384, 128, jnp.bfloat16, "bwd") == 1024
+    # Every cell's backward is inside the limit, the largest (ling3flash)
+    # under 64 MiB; a 128k row is past it.
+    largest = fa.bwd_fused_vmem_bytes(16384, 1024, 1024, 192, 2, False, 128)
+    assert fa.VMEM_SCOPED_DEFAULT < largest < 64 * MiB < fa.VMEM_LIMIT_MAX
+    assert fa._compiler_params(largest).vmem_limit_bytes <= fa.VMEM_LIMIT_MAX
+    assert fa.bwd_fused_vmem_bytes(131072, 1024, 1024, 128, 2) is None

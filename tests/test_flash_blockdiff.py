@@ -147,7 +147,11 @@ def test_the_grid_of_a_call_under_the_mask_is_its_live_tiles():
     assert grids(fwd.jaxpr) == [(BH, live)]
     bwd = jax.make_jaxpr(lambda q, k, v, o, lse, do: fa._flash_bh_bwd(
         q, k, v, o, lse, do, **geometry))(q, k, k, q, lse, q)
-    assert grids(bwd.jaxpr) == [(BH, live), (BHk, BH // BHk * live)]
+    # (one pass on the forward's walk: rows this short fit its footprint)
+    assert grids(bwd.jaxpr) == [(BH, live)]
+    pair = jax.make_jaxpr(lambda q, k, v, o, lse, do: fa._flash_bwd_pair(
+        q, k, v, o, lse, do, **geometry))(q, k, k, q, lse, q)
+    assert grids(pair.jaxpr) == [(BH, live), (BHk, BH // BHk * live)]
 
 
 def test_the_dense_path_and_the_adapter_take_the_same_mask():
@@ -226,7 +230,10 @@ def test_the_mask_publishes_its_geometry():
     assert gauges["blockdiff/L"] == L and gauges["blockdiff/B"] == B
     assert gauges["blockdiff/rows"] == 2 * L
     assert gauges["blockdiff/live_pairs"] == L * (L + B)
-    for kernel in ("flash-fwd", "flash-bwd-dq", "flash-bwd-dkv"):
+    # (the backward is one pass: its tiles are flash-bwd-dkv's)
+    assert gauges["flash/bwd_fused"] == 1
+    assert "flash/flash-bwd-dq/visited" not in gauges
+    for kernel in ("flash-fwd", "flash-bwd-dkv"):
         assert gauges[f"blockdiff/{kernel}/live"] == live
         assert gauges[f"blockdiff/{kernel}/visited"] == live
         assert gauges[f"flash/{kernel}/visited"] == live
@@ -235,3 +242,39 @@ def test_the_mask_publishes_its_geometry():
     with reporter.scope(rep):
         fa.flash_attention(x, x, x, block_q=64, block_k=64)
     assert not [k for k in rep.summary()["gauges"] if "blockdiff" in k]
+
+
+@pytest.mark.parametrize("which", ["dq", "dk", "dv"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_one_pass_backward_under_the_mask_is_the_two_kernels(case, which):
+    """``_flash_bwd_fused`` against ``_flash_bwd_pair`` on the walk of the
+    mask's live tiles, bfloat16 operands: ``dq`` sums over a q tile's K
+    tiles in the walk's order on both sides and is EQUAL; so are ``dk``
+    and ``dv`` where a KV row has one query head.  With a group the two
+    kernels' dk/dv walk puts the heads INSIDE a tile's steps (``(q block,
+    head)``) and the one pass has them outside (``(head, q block)``), so
+    the float32 sums can differ in their last place: within 2e-3 of the
+    largest gradient after the cast to bfloat16 (measured on these cases:
+    ``gqa-q64-k128`` alone differs, 3 of 65,536 ``dk`` elements and 1
+    ``dv`` by one bfloat16 step, 6.1e-5 at |dk| <= 3.6; the two MQA cases
+    come out equal)."""
+    L, B, bq, bk, H, Hk = CASES[case]
+    key = jax.random.split(jax.random.PRNGKey(len(case)), 4)
+    q = jax.random.normal(key[0], (2 * H, 2 * L, D), jnp.bfloat16)
+    k = jax.random.normal(key[1], (2 * Hk, 2 * L, D), jnp.bfloat16)
+    v = jax.random.normal(key[2], (2 * Hk, 2 * L, D), jnp.bfloat16)
+    do = jax.random.normal(key[3], (2 * H, 2 * L, D), jnp.bfloat16)
+    geometry = dict(scale=D ** -0.5, causal=True, block_q=bq, block_k=bk,
+                    interpret=True, blockdiff=(L, B))
+    o, lse = fa._flash_bh_fwd(q, k, v, **geometry)
+    i = ["dq", "dk", "dv"].index(which)
+    fused = np.asarray(fa._flash_bwd_fused(
+        q, k, v, o, lse, do, **geometry)[i], np.float32)
+    pair = np.asarray(fa._flash_bwd_pair(
+        q, k, v, o, lse, do, **geometry)[i], np.float32)
+    assert np.abs(pair).max() > 0
+    if which == "dq" or H == Hk:
+        np.testing.assert_array_equal(fused, pair)
+    else:
+        np.testing.assert_allclose(fused, pair, rtol=0,
+                                   atol=2e-3 * np.abs(pair).max())

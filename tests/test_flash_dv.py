@@ -81,3 +81,34 @@ def test_narrower_values_hold_less_vmem_than_padded_ones():
     for which in ("fwd", "bwd"):
         assert flash_vmem_bytes(1024, 1024, 192, 2, which, D_v=128) < (
             flash_vmem_bytes(1024, 1024, 192, 2, which))
+
+
+@pytest.mark.parametrize("which", [0, 1, 2], ids=["dq", "dk", "dv"])
+@pytest.mark.parametrize("H,Hk", HEADS, ids=["mha", "gqa"])
+@pytest.mark.parametrize("D,Dv", WIDTHS, ids=["192-128", "64-32"])
+def test_the_one_pass_backward_at_its_own_value_width_is_the_two_kernels(
+        D, Dv, H, Hk, which):
+    """``dq``, ``dk`` (``D`` wide) and ``dv`` (``D_v`` wide) of the
+    one-pass backward, its resident rows ``(Sk, D)`` and ``(Sk, D_v)``,
+    EQUAL to the two kernels' bit for bit (bfloat16 operands, the cells'
+    dtype; a rectangular tile)."""
+    from chainermn_tpu.ops.flash_attention import (
+        _flash_bh_fwd,
+        _flash_bwd_fused,
+        _flash_bwd_pair,
+        bwd_resident_bytes,
+        to_bh,
+    )
+
+    q, k, v, do = (to_bh(x.astype(jnp.bfloat16))
+                   for x in _qkv(D, Dv, H, Hk, S=256, B=2, seed=2))
+    geometry = dict(scale=D ** -0.5, causal=True, block_q=64, block_k=128,
+                    interpret=True)
+    o, lse = _flash_bh_fwd(q, k, v, **geometry)
+    fused = _flash_bwd_fused(q, k, v, o, lse, do, **geometry)[which]
+    pair = _flash_bwd_pair(q, k, v, o, lse, do, **geometry)[which]
+    assert fused.shape == (q, k, v)[which].shape
+    assert np.asarray(pair, np.float32).any()
+    np.testing.assert_array_equal(np.asarray(fused), np.asarray(pair))
+    # (192 takes 256 lanes, 128 and under 128: 24 MiB at the Ling row)
+    assert bwd_resident_bytes(16384, 192, 128) == 24 << 20

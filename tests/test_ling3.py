@@ -777,8 +777,9 @@ def test_the_new_regions_are_on_their_rows_ops(compiled_text):
     assert by_layer == {"kda-mixer": {"0", "1"}, "mla-mixer": {"2"}}
     assert {"kda-scan", "ssm-conv", "mixer-proj", "mixer-gate"} <= under[
         "kda-mixer"]
-    assert {"flash-fwd", "flash-bwd-dq", "flash-bwd-dkv", "attn-rope",
+    assert {"flash-fwd", "flash-bwd-dkv", "attn-rope",
             "mixer-proj", "mixer-gate"} <= under["mla-mixer"]
+    assert "flash-bwd-dq" not in under["mla-mixer"]   # one backward pass
     # the latent row is an attention row: its region sits inside the part
     assert all("attn-mixer" in device_trace.scopes_on(p)
                for p in table.values()

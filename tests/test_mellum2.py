@@ -624,9 +624,10 @@ def test_an_older_familys_outputs_are_unchanged_to_the_bit(
     """The traced program of the family's logits and gradient (every
     equation, every constant) is the one the golden file's commit traced
     (``tests/_older_families.py`` says why the program and not its
-    output's bits).  The ``zaya`` and ``qwen3_next`` digests are PR 41's
-    (they rotate: ``rotate_partial`` turns whole heads since), the three
-    others PR 39's parent's."""
+    output's bits).  All five digests are PR 48's: every family's
+    gradient runs the flash backward, one Mosaic kernel since, and
+    nothing else of their programs moved — the test below holds the
+    two-kernel side to the digests they had before."""
     assert _older_families.digest(
         *_older_families.tables()[family]) == older_digests[family], (
         f"the {family} family traces another program than "
@@ -637,6 +638,26 @@ def test_an_older_familys_outputs_are_unchanged_to_the_bit(
         f"tests/_older_families.py out.json`, copy the one line -- and "
         f"show in CHANGES.md that the families left alone kept theirs "
         f"byte for byte")
+
+
+@pytest.mark.parametrize("family", [
+    "gpt2", "granitemoehybrid", "nemotron_h", "zaya", "qwen3_next"])
+def test_past_the_footprint_rule_an_older_family_traces_the_parents_program(
+        family, older_digests, monkeypatch):
+    """With the flash backward's footprint rule brought down under every
+    row (``VMEM_LIMIT_MAX`` 0: what a 128k row meets at the real limit)
+    a family's logits and gradient trace, equation for equation, the
+    program the golden file held BEFORE the one-pass backward (its
+    ``two_kernels`` group: ``zaya`` and ``qwen3_next`` PR 41's, the three
+    others PR 39's parent's) — the two kernels are the parent's to the
+    letter, and nothing but the choice of backward moved.  (The jitted
+    wrapper around the two kernels was called ``_flash_bh_bwd`` then.)"""
+    monkeypatch.setattr(fa, "VMEM_LIMIT_MAX", 0)
+    got = _older_families.digest(
+        *_older_families.tables()[family],
+        text_of=lambda traced: str(traced).replace(
+            "_flash_bwd_pair", "_flash_bh_bwd"))
+    assert got == older_digests["two_kernels"][family]
 
 
 def test_plain_rotation_is_the_old_formula_to_the_bit():
@@ -698,8 +719,9 @@ def test_the_window_scope_is_on_the_sliding_rows_ops(compiled_text):
     # unchanged, forward and backward
     under = {device_trace.owner(p)[1] for p in table.values()
              if "attn-window" in device_trace.scopes_on(p)}
-    assert {"flash-fwd", "flash-bwd-dq", "flash-bwd-dkv", "attn-rope",
+    assert {"flash-fwd", "flash-bwd-dkv", "attn-rope",
             "mixer-proj"} <= under
+    assert "flash-bwd-dq" not in under      # the backward is one pass
     # and the regions' census rides in the path with the row's window: a
     # band of 8 at blocks of 8 over 32 tokens runs 7 tiles in a grid of
     # 4 x 2 steps (the band, not the 16 of the rectangle), the triangle 10

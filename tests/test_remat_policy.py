@@ -101,7 +101,9 @@ def test_a_checkpoint_that_saves_the_name_runs_the_forward_once(
         grad = grad_of(jax.checkpoint(loss, policy=policy))
         jaxpr = jax.make_jaxpr(grad)(q, k, v).jaxpr
         assert kernel_calls(jaxpr, "flash-fwd") == fwd_calls
-        assert kernel_calls(jaxpr, "flash-bwd-dq") == 1
+        # (the backward is one pass, under flash-bwd-dkv's name, where
+        # the KV row fits its footprint: here and in every cell)
+        assert kernel_calls(jaxpr, "flash-bwd-dq") == 0
         assert kernel_calls(jaxpr, "flash-bwd-dkv") == 1
         for got, want in zip(jax.jit(grad)(q, k, v), plain):
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -241,7 +243,7 @@ def sides(request):
 def test_a_remat_model_calls_the_forward_kernel_once_a_layer(sides):
     n = sides["flash_layers"]
     assert kernel_calls(sides["jaxpr"], "flash-fwd") == n
-    assert kernel_calls(sides["jaxpr"], "flash-bwd-dq") == n
+    assert kernel_calls(sides["jaxpr"], "flash-bwd-dq") == 0
     assert kernel_calls(sides["jaxpr"], "flash-bwd-dkv") == n
 
 

@@ -586,8 +586,9 @@ def test_the_blockdiff_scope_is_on_the_rows_ops():
     assert not on & {"attn-mixer", "attn-window"}
     under = {device_trace.owner(p)[1] for p in table.values()
              if "attn-blockdiff" in device_trace.scopes_on(p)}
-    assert {"flash-fwd", "flash-bwd-dq", "flash-bwd-dkv", "attn-rope",
+    assert {"flash-fwd", "flash-bwd-dkv", "attn-rope",
             "mixer-proj"} <= under
+    assert "flash-bwd-dq" not in under      # the backward is one pass
     # the flash calls under the scope carry the mask's census
     (census,) = table.tiles_within["attn-blockdiff"]["flash-fwd"]
     assert census == fa.tile_census(2 * L, 2 * L, 16, 16, True, None,

@@ -19,16 +19,24 @@ fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
 
 
 def _compile(one_chip, *, S, D, dtype, bq, bk, which, segmented=False,
-             window=None, BH=128, BHk=None):
+             window=None, BH=128, BHk=None, Dv=None, blockdiff=None):
+    """The forward kernel or the backward compiled at one geometry: one
+    ``tpu_custom_call`` forward, and in the backward what the footprint
+    rule says — one where the KV row's ``dk`` / ``dv`` fit beside the
+    tile (``bwd_fused_vmem_bytes``), else the two kernels."""
+    Dv = Dv or D
+
     def arr(*shape, dt=dtype):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     q, col = arr(BH, S, D), arr(BH, S, 1, dt=jnp.float32)
-    kv = arr(BHk or BH, S, D)       # fewer kv head rows: GQA
+    k, v = arr(BHk or BH, S, D), arr(BHk or BH, S, Dv)  # fewer rows: GQA
+    o = arr(BH, S, Dv)
     seg = {}
-    operands = [q, kv, kv] if which == "fwd" else [q, kv, kv, q, col, q]
+    operands = [q, k, v] if which == "fwd" else [q, k, v, o, col, o]
     if segmented:
-        operands += [arr(BH, S, 1, dt=jnp.int32)] * 2
+        operands += [arr(BH, S, 1, dt=jnp.int32),
+                     arr(BHk or BH, S, 1, dt=jnp.int32)]
 
     def fn(*a):
         if segmented:
@@ -36,11 +44,14 @@ def _compile(one_chip, *, S, D, dtype, bq, bk, which, segmented=False,
             seg.update(q_seg=qs, kv_seg=ks)
         kernel = fa._flash_bh_fwd if which == "fwd" else fa._flash_bh_bwd
         return kernel(*a, scale=0.1, causal=True, block_q=bq, block_k=bk,
-                      interpret=False, window=window, **seg)
+                      interpret=False, window=window, blockdiff=blockdiff,
+                      **seg)
 
+    fused = fa.bwd_fused_vmem_bytes(
+        S, bq, bk, D, jnp.dtype(dtype).itemsize, segmented, Dv) is not None
     compiled = jax.jit(fn).lower(*operands).compile()
     assert compiled.as_text().count("tpu_custom_call") == (
-        1 if which == "fwd" else 2)
+        1 if which == "fwd" or fused else 2)
     return compiled
 
 
@@ -84,6 +95,63 @@ def test_pinned_geometry_past_the_default_gets_its_limit(one_chip, which):
     assert fa._compiler_params(footprint).vmem_limit_bytes > footprint
     _compile(one_chip, S=2048, D=128, dtype=jnp.bfloat16, bq=2048, bk=2048,
              which=which)
+
+
+#: cell -> BH, BHk, S, D, D_v, window, block-diffusion (L, B): the
+#: backward each cell's runner builds (ROADMAP S4; the tile is the
+#: rule's, ``auto_block_size(which="bwd")``).
+_CELLS_BACKWARD = {
+    "cgpt": (128, 128, 2048, 128, 128, None, None),
+    "granite4hm": (64, 16, 8192, 64, 64, None, None),
+    "nemo3nano": (64, 4, 8192, 128, 128, None, None),
+    "zaya1": (16, 4, 8192, 128, 128, None, None),
+    "qwen3next": (32, 4, 8192, 256, 256, None, None),
+    "mellum2-full": (32, 4, 16384, 128, 128, None, None),
+    "mellum2-window": (32, 4, 16384, 128, 128, 1024, None),
+    "ling3flash": (32, 32, 16384, 192, 128, None, None),
+    "sdar30b": (32, 4, 16384, 128, 128, None, (8192, 4)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELLS_BACKWARD))
+def test_the_cells_backward_is_one_kernel_inside_the_limit(one_chip, cell):
+    """The one-pass backward compiled for the described v5e at the nine
+    cells' backward geometries: ONE ``tpu_custom_call`` (the rule reads
+    fused at every one), its resident rows and whole-row outputs inside
+    the ``vmem_limit_bytes`` the kernel asks for, and that within
+    ``VMEM_LIMIT_MAX``."""
+    BH, BHk, S, D, Dv, window, blockdiff = _CELLS_BACKWARD[cell]
+    b = fa.auto_block_size(S, D, jnp.bfloat16, "bwd", window=window,
+                           D_v=None if Dv == D else Dv, blockdiff=blockdiff)
+    assert b == {"qwen3next": 512, "mellum2-window": 512}.get(cell, 1024)
+    footprint = fa.bwd_fused_vmem_bytes(S, b, b, D, 2, False, Dv)
+    assert footprint is not None
+    assert footprint > fa.VMEM_SCOPED_DEFAULT       # it asks for its own
+    compiled = _compile(one_chip, S=S, D=D, Dv=Dv, dtype=jnp.bfloat16, bq=b,
+                        bk=b, which="bwd", window=window, BH=BH, BHk=BHk,
+                        blockdiff=blockdiff)
+    # (the kernel's vmem_limit_bytes, as its custom call carries it, and
+    # beside it what the compiler made use of)
+    call, = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    config = (r'"%sscoped_memory_configs":\[\{"memory_space":"1",'
+              r'"offset":"0","size":"(\d+)"')
+    limit, = map(int, re.findall('[^_]' + config % "", call))
+    assert limit == fa._compiler_params(footprint).vmem_limit_bytes
+    assert footprint < limit <= fa.VMEM_LIMIT_MAX
+    used, = map(int, re.findall(config % "used_", call))
+    assert used <= footprint
+
+
+def test_a_row_past_the_limit_compiles_as_the_two_kernels(one_chip):
+    """A 128k-row block (ring attention's, R7's) is past the footprint
+    rule: the parent's two kernels, inside the default scoped VMEM."""
+    S, D = 131072, 128
+    b = fa.auto_block_size(S, D, jnp.bfloat16, "bwd")
+    assert fa.bwd_fused_vmem_bytes(S, b, b, D, 2) is None
+    compiled = _compile(one_chip, S=S, D=D, dtype=jnp.bfloat16, bq=b, bk=b,
+                        which="bwd", BH=8, BHk=2)
+    assert compiled.as_text().count("tpu_custom_call") == 2
 
 
 @pytest.mark.parametrize("bq,bk,window", [

@@ -136,10 +136,12 @@ def test_mla_row_compiles_at_the_cells_shape(one_chip, monkeypatch):
     calls = {name: len(re.findall(
         r'tpu_custom_call[^\n]*' + name + r'\b', text))
         for name in ("flash-fwd", "flash-bwd-dq", "flash-bwd-dkv")}
-    assert calls == {"flash-fwd": 1, "flash-bwd-dq": 1, "flash-bwd-dkv": 1}
-    assert text.count("tpu_custom_call") == 3 + 9
+    # (one backward pass, under flash-bwd-dkv's name)
+    assert calls == {"flash-fwd": 1, "flash-bwd-dq": 0, "flash-bwd-dkv": 1}
+    assert text.count("tpu_custom_call") == 2 + 9
     tiles = device_trace.scope_table(text).tiles_within["mla-mixer"]
-    for region in ("flash-fwd", "flash-bwd-dq", "flash-bwd-dkv"):
+    assert "flash-bwd-dq" not in tiles
+    for region in ("flash-fwd", "flash-bwd-dkv"):
         (census,) = tiles[region]
         assert (census["block_q"], census["block_k"]) == (1024, 1024)
         assert (census["live"], census["visited"]) == (136, 256)

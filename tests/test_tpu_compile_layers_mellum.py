@@ -77,13 +77,15 @@ def test_mellum_layers_compile_at_the_cells_shape(one_chip, monkeypatch,
     calls = {name: len(re.findall(
         r'tpu_custom_call[^\n]*' + name + r'\b', text))
         for name in ("flash-fwd", "flash-bwd-dq", "flash-bwd-dkv")}
-    assert calls == {"flash-fwd": 1, "flash-bwd-dq": 1, "flash-bwd-dkv": 1}
-    assert text.count("tpu_custom_call") == 3 + 9
+    # (one backward pass, under flash-bwd-dkv's name)
+    assert calls == {"flash-fwd": 1, "flash-bwd-dq": 0, "flash-bwd-dkv": 1}
+    assert text.count("tpu_custom_call") == 2 + 9
     scope = "attn-window" if kind == "sliding" else "attn-mixer"
     tiles = device_trace.scope_table(text).tiles_within
     assert set(tiles) >= {scope} and not (
         {"attn-window", "attn-mixer"} - {scope}) & set(tiles)
-    for region in ("flash-fwd", "flash-bwd-dq", "flash-bwd-dkv"):
+    assert "flash-bwd-dq" not in tiles[scope]
+    for region in ("flash-fwd", "flash-bwd-dkv"):
         (census,) = tiles[scope][region]
         # a sliding row's grid is its band (PR 40): one step a query
         # block more than live, the backward's tiles at half the window;
