@@ -8,11 +8,20 @@ multi-node evaluator), and the model-parallel API (differentiable
 point-to-point and collective functions, ``MultiNodeChainList``).
 """
 
-from chainermn_tpu.communicators import (  # noqa: F401
+import time as _time
+
+_IMPORT_FIRST = _time.perf_counter()   # the start-up ledger's `import` span
+import jax as _jax  # noqa: E402,F401  (timed apart: `import_jax`)
+
+_IMPORT_JAX = (_IMPORT_FIRST, _time.perf_counter())
+
+from chainermn_tpu.communicators import (  # noqa: E402,F401
     CommunicatorBase,
     create_communicator,
     build_mesh,
 )
+
+from chainermn_tpu.observability import startup as _startup  # noqa: E402
 
 __version__ = "0.1.0"
 
@@ -59,3 +68,6 @@ def __getattr__(name):
 
         return importlib.import_module("chainermn_tpu.global_except_hook")
     raise AttributeError(f"module 'chainermn_tpu' has no attribute {name!r}")
+
+
+_startup.finish_import(_IMPORT_FIRST, _IMPORT_JAX)

@@ -97,6 +97,9 @@ def create_communicator(
     pins it off.  Error vs the fp32 allreduce is bounded per dtype
     (docs/performance.md).
     """
+    from chainermn_tpu.observability import startup
+
+    startup.mark("create_communicator")
     try:
         cls = _COMMUNICATORS[communicator_name]
     except KeyError:

@@ -1084,21 +1084,26 @@ class CommunicatorBase:
         ``jnp.asarray`` puts it (the first device), a resident batch
         would be re-sharded off that one chip at every step.
         """
+        from chainermn_tpu.observability import startup
         from chainermn_tpu.observability.spans import annotate
 
-        with annotate("global_batch"):
-            if self.size == 1:
-                sharding = jax.sharding.NamedSharding(
-                    self.mesh, self._world_spec
-                )
-                return jax.device_put(batch, sharding)
-            from jax.experimental import multihost_utils
+        call = startup.open_call("global_batch")    # None past the record
+        try:
+            with annotate("global_batch"):
+                if self.size == 1:
+                    sharding = jax.sharding.NamedSharding(
+                        self.mesh, self._world_spec
+                    )
+                    return jax.device_put(batch, sharding)
+                from jax.experimental import multihost_utils
 
-            spec = self._world_spec
-            specs = jax.tree.map(lambda _: spec, batch)
-            return multihost_utils.host_local_array_to_global_array(
-                batch, self.mesh, specs
-            )
+                spec = self._world_spec
+                specs = jax.tree.map(lambda _: spec, batch)
+                return multihost_utils.host_local_array_to_global_array(
+                    batch, self.mesh, specs
+                )
+        finally:
+            startup.close(call)
 
     # ------------------------------------------------------------------
     # Host/object plane (reference pickle-over-MPI *_obj methods)

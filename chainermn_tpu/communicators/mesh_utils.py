@@ -51,6 +51,9 @@ def build_mesh(
     ``build_mesh(inter_size=2, intra_size=4)`` on 8 virtual CPU devices to
     exercise both collective legs of the hierarchical/2-D algorithms.
     """
+    from chainermn_tpu.observability import startup
+
+    startup.mark("build_mesh")
     if devices is None:
         devices = jax.devices()
     devices = list(devices)

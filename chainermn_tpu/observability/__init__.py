@@ -10,7 +10,12 @@ telemetry is library-native and SPMD-aware:
   communicator's object plane (mean/sum/max on rank 0, off-TPU safe).
 * :class:`StepRecorder` — structured JSONL step-event log with atomic
   append, rotation, crash-safe partial-line recovery, compile events
-  (``jax.monitoring``) and device-memory stats.
+  (drained from the start-up ledger) and device-memory stats.
+* :mod:`startup` — the start-up ledger, the program's one
+  ``jax.monitoring`` bridge: import, boundary marks, the first calls,
+  :func:`startup.phase` for an entry point's own stretches, every
+  compile stage by program name with the cache's answer
+  (:func:`startup.summary`).
 * :mod:`hlo_audit` — per-collective counts and per-mesh-axis operand
   bytes of any traced step fn (the generalized bench census).
 * :mod:`spans` — the scope vocabulary: :func:`named_scope` for traced
@@ -63,6 +68,7 @@ from chainermn_tpu.observability.anomaly import (  # noqa: F401
     AnomalyDetector,
 )
 from chainermn_tpu.observability import device_trace  # noqa: F401
+from chainermn_tpu.observability import startup  # noqa: F401
 from chainermn_tpu.observability.spans import (  # noqa: F401
     annotate,
     named_scope,

@@ -13,6 +13,7 @@ by them, so the join needs nothing but public API:
     attribute(ops, table)        device seconds by phase, by region and,
                                  within ``fwd-bwd``, by owner
     idle_by_host_span(ops, host) idle seconds by ``chainermn:`` host span
+                                 or compile stage of JAX's
     capture({name: jitted})      profile a caller's k steps, return one
                                  report (and hand it to the sinks)
 
@@ -80,6 +81,13 @@ HOST_PLANE = "/host:CPU"
 #: A capture is refused (``attribute`` still returns, readers must not
 #: report) when less than this share of the busy time joins to the table.
 MIN_JOINED_SHARE = 0.98
+
+#: An op is its program run's while it ends no later than the run's own
+#: event by this much: both ends are ``(start_ns + duration_ns) * 1e-9``
+#: of different events, and a program's LAST op ends with the program to
+#: the rounding (a kernel under study that ends its program was dropped
+#: in one call of three, PERF.md section 6, PR 48).
+END_TOLERANCE_S = 1e-6
 
 #: The owner of ``fwd-bwd`` time whose owning path names no region.
 NO_OWNER = "(none)"
@@ -438,17 +446,27 @@ def _merged(ops: Iterable[Triple]) -> List[List[float]]:
 def idle_by_host_span(ops: Iterable[Triple],
                       host_events: Iterable[Triple]) -> Dict[str, float]:
     """Idle seconds of one device between its first and last op, each gap
-    put down to the ``chainermn:`` host annotation that covers most of
-    it (``"unannotated"`` where none does), on the capture's one clock."""
+    put down to a host event on the capture's one clock
+    (``spans.host_label``: a ``chainermn:`` annotation by its name, a
+    compile-stage event of JAX's as ``"compile"`` / ``"lower"``): the
+    innermost of those that cover more than half of it — a recompilation
+    inside a step reads ``compile``, not ``chainermn:train_step`` — else
+    the one that covers most of it, ``"unannotated"`` where none does."""
     busy = _merged(ops)
-    host = [h for h in host_events if h[0].startswith(spans.HOST_PREFIX)]
+    host = [(label, start, end) for name, start, end in host_events
+            if (label := spans.host_label(name)) is not None]
     out: Dict[str, float] = {}
     for (_, gap_start), (gap_end, _) in zip(busy, busy[1:]):
-        best, label = 0.0, "unannotated"
+        best, label, inner = 0.0, "unannotated", None
         for name, start, end in host:
             cover = min(gap_end, end) - max(gap_start, start)
             if cover > best:
                 best, label = cover, name
+            if cover > (gap_end - gap_start) / 2 and (
+                    inner is None or end - start < inner[0]):
+                inner = (end - start, name)
+        if inner is not None:
+            label = inner[1]
         out[label] = out.get(label, 0.0) + (gap_end - gap_start)
     return out
 
@@ -476,7 +494,8 @@ def read_capture(path: str):
     """``(devices, host)`` of an ``.xplane.pb`` file (``.gz`` accepted),
     through ``jax.profiler.ProfileData``: per device plane its op and
     program (module) events as ``(name, start, end)`` triples in seconds,
-    and the host threads' ``chainermn:`` annotations."""
+    and the host threads' ``chainermn:`` annotations and compile-stage
+    events (``spans.host_label``)."""
     from jax.profiler import ProfileData
 
     if path.endswith(".gz"):
@@ -506,7 +525,7 @@ def read_capture(path: str):
         elif plane.name == HOST_PLANE:
             for ln in plane.lines:
                 host.extend(t for t in triples(ln)
-                            if t[0].startswith(spans.HOST_PREFIX))
+                            if spans.host_label(t[0]) is not None)
     return devices, host
 
 
@@ -533,7 +552,7 @@ def report_from(devices, host, tables: Dict[str, ScopeTable]) -> dict:
         inside: Dict[str, list] = {}
         for op in ops:
             i = bisect.bisect_right(starts, op[1]) - 1
-            if i >= 0 and op[2] <= runs[i][1]:
+            if i >= 0 and op[2] <= runs[i][1] + END_TOLERANCE_S:
                 inside.setdefault(runs[i][2], []).append(op)
         for name, picked in inside.items():
             got = attribute(picked, tables.get(name, ScopeTable()))

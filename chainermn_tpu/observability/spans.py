@@ -139,6 +139,27 @@ HOST_SPANS = (
     "table-build", "dispatch", "readback",
 )
 
+#: JAX's own host events around the compile stages, as a capture holds
+#: them on its clock (``jax.profiler.annotate_function`` in
+#: ``jax/_src/compiler.py`` and ``interpreters/pxla.py``, read off a
+#: capture of a compiling step), by the stage of the start-up ledger
+#: (``observability/startup.py``) each belongs to.  Tracing has none:
+#: it is the host's Python between the call's start and ``lower``.
+COMPILE_HOST_EVENTS = {
+    "lower_sharding_computation": "lower",
+    "backend_compile_and_load": "compile",
+    "backend_compile": "compile",
+}
+
+
+def host_label(event_name: str):
+    """What an idle gap under this host event is put down to: the
+    library's own annotation by its name, a compile-stage event of JAX's
+    by its stage, anything else ``None``."""
+    if event_name.startswith(HOST_PREFIX):
+        return event_name
+    return COMPILE_HOST_EVENTS.get(event_name)
+
 
 #: Inside a flash kernel's region: the block geometry the call was built
 #: with and its tile census a head row (``ops.flash_attention``).  Decided

@@ -11,7 +11,7 @@ File contract:
 
 * **Atomic append** — each row is one ``os.write`` of a complete
   ``...\\n`` line on an ``O_APPEND`` descriptor, so concurrent writers
-  (the train loop, the prefetch thread, a monitoring listener) never
+  (the train loop, the prefetch thread, an exporter's thread) never
   interleave bytes within a line.
 * **Rotation** — when a write would push the file past ``rotate_bytes``
   the file rotates through ``path.1 … path.<max_files>`` (highest =
@@ -21,10 +21,14 @@ File contract:
   :func:`recover` truncates it in place, so a resumed run appends to a
   valid file.
 
-Compile/recompile visibility rides ``jax.monitoring`` where available:
-the recorder registers an event-duration listener and turns every
-``...compile...`` event into a ``{"event": "compile", ...}`` row —
-the per-step recompile evidence XLA profiling otherwise hides in logs.
+Compile/recompile visibility comes from the start-up ledger
+(:mod:`~chainermn_tpu.observability.startup`, the program's one
+``jax.monitoring`` bridge): at each step row the recorder drains the
+compile-stage spans closed since its last row and writes one
+``{"event": "compile", "name": <JAX's event>, "secs": ..., "stage":
+"trace" | "lower" | "compile", "program": ..., "cache": "hit" | "miss" |
+"uncached" | null}`` row each — the per-step recompile evidence XLA
+profiling otherwise hides in logs.
 """
 
 from __future__ import annotations
@@ -35,6 +39,9 @@ import os
 import threading
 import time
 from typing import Iterator, List, Optional
+
+from chainermn_tpu.observability import reporter as _reporter
+from chainermn_tpu.observability import startup as _startup
 
 
 def _jsonable(v):
@@ -111,40 +118,11 @@ class StepRecorder:
         self._prev_t: Optional[float] = None
         self._step_count = 0
         self._pending_spans: dict = {}
-        self._pending_compiles: list = []
-        self._unregister = None
-        if capture_compile_events:
-            self._register_compile_listener()
-
-    # -- jax.monitoring bridge ----------------------------------------
-    def _register_compile_listener(self):
-        try:
-            from jax import monitoring
-        except Exception:
-            return
-
-        def listener(event: str, secs: float, **kw):
-            if "compile" not in event:
-                return
-            # Buffer only: listeners fire inside the compile path and
-            # must not re-enter file IO or raise into XLA.
-            with self._lock:
-                self._pending_compiles.append((event, float(secs)))
-
-        try:
-            monitoring.register_event_duration_secs_listener(listener)
-        except Exception:
-            return
-
-        def unregister():
-            try:
-                from jax._src import monitoring as _m
-
-                _m._unregister_event_duration_listener_by_callback(listener)
-            except Exception:
-                pass
-
-        self._unregister = unregister
+        # Where this recorder's drains of the start-up ledger begin
+        # (``None``: it does not drain).
+        self._ledger_cursor: Optional[int] = (
+            _startup.current().cursor() if capture_compile_events else None)
+        self._phases_published = False
 
     # -- write side ----------------------------------------------------
     def record(self, event: str, **fields) -> None:
@@ -201,9 +179,7 @@ class StepRecorder:
             self._step_count += 1
             n = self._step_count
             spans, self._pending_spans = self._pending_spans, {}
-            compiles, self._pending_compiles = self._pending_compiles, []
-        for event, secs in compiles:
-            self.record("compile", name=event, secs=secs)
+        self._drain_ledger()
         row: dict = {"step": n - 1 if step is None else int(step)}
         if dt is not None:
             row["dt"] = dt
@@ -222,14 +198,35 @@ class StepRecorder:
         row["event"] = "step"
         return row
 
+    def _drain_ledger(self) -> None:
+        """One ``compile`` row a compile-stage span the ledger closed
+        since the last drain; the installed Reporter gets the same drain
+        (``startup/<phase>_s``, ``compile/{requests,hits,misses}``)."""
+        if self._ledger_cursor is None:
+            return
+        ledger = _startup.current()
+        self._ledger_cursor, drained = ledger.since(self._ledger_cursor)
+        for s in drained:
+            if s.kind == "stage":
+                self.record("compile", name=_startup.EVENT_OF_STAGE[s.name],
+                            secs=s.end - s.start, stage=s.name,
+                            program=s.program, cache=s.cache_state)
+        rep = _reporter.get_reporter()
+        if rep is not None:
+            if not self._phases_published:
+                # The phases from before this recorder opened too: import,
+                # backend, weights are over by the first step (a gauge
+                # set twice to one value is set once).
+                self._phases_published = True
+                drained = [s for s in ledger.since(0)[1]
+                           if s.kind != "stage"] + drained
+            _startup.publish(rep, drained)
+
     def close(self) -> None:
         with self._lock:
             if self._fd is not None:
                 os.close(self._fd)
                 self._fd = None
-        if self._unregister is not None:
-            self._unregister()
-            self._unregister = None
 
     # -- current-recorder stack ---------------------------------------
     def __enter__(self):

@@ -44,6 +44,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from chainermn_tpu.communicators.base import CommunicatorBase
+from chainermn_tpu.observability import startup as _startup
 from chainermn_tpu.observability.spans import named_scope
 
 
@@ -102,22 +103,28 @@ def _instrument_step(step_fn):
     gaps down to it.  When a Reporter or StepRecorder is installed
     (``observability.telemetry_active``) the call also runs under
     ``span("train_step")`` — host-side duration into both sinks — and
-    bumps the reporter's ``train_step_calls`` counter."""
+    bumps the reporter's ``train_step_calls`` counter.  The first calls'
+    start and end go to the start-up ledger (``observability.startup``:
+    the compile stages of the first call parent to it)."""
     from chainermn_tpu.observability import spans as _spans
 
     @functools.wraps(step_fn)
     def instrumented(*args, **kwargs):
-        if not _spans.telemetry_active():
-            with _spans.annotate("train_step"):
-                return step_fn(*args, **kwargs)
-        from chainermn_tpu.observability import reporter as _rep
+        call = _startup.open_call("train_step")    # None past the record
+        try:
+            if not _spans.telemetry_active():
+                with _spans.annotate("train_step"):
+                    return step_fn(*args, **kwargs)
+            from chainermn_tpu.observability import reporter as _rep
 
-        with _spans.span("train_step"):
-            out = step_fn(*args, **kwargs)
-        rep = _rep.get_reporter()
-        if rep is not None:
-            rep.count("train_step_calls")
-        return out
+            with _spans.span("train_step"):
+                out = step_fn(*args, **kwargs)
+            rep = _rep.get_reporter()
+            if rep is not None:
+                rep.count("train_step_calls")
+            return out
+        finally:
+            _startup.close(call)
 
     return _carry_step_surface(instrumented, step_fn)
 
@@ -640,6 +647,7 @@ class MultiNodeOptimizer:
     def _finalize_step(self, step_fn):
         """Every built train step exits through here: the opt-in lint
         hook (innermost, so it traces the bare step) then telemetry."""
+        _startup.mark("make_train_step.return")
         return _instrument_step(_lint_hook(step_fn, self.communicator))
 
     def make_train_step(
@@ -685,6 +693,7 @@ class MultiNodeOptimizer:
 
         Returns ``step(params, state, batch) -> (params, state, loss[, aux])``.
         """
+        _startup.mark("make_train_step")
         comm = self.communicator
         axes = comm.axes
         if batch_spec is None:
@@ -975,6 +984,7 @@ class MultiNodeOptimizer:
         :meth:`make_train_step` does) — ``step(flat_params, opt_state,
         model_state, batch)``.
         """
+        _startup.mark("make_train_step")
         comm = self.communicator
         axes = comm.axes
         if batch_spec is None:
@@ -1180,6 +1190,7 @@ def create_multi_node_optimizer(
     """Reference-parity factory (REF:chainermn/optimizers.py), extended
     with ZeRO sharding: ``zero_stage=1`` (optimizer state), ``2`` (+ sharded
     gradient accumulation), ``3`` (+ sharded master parameters)."""
+    _startup.mark("create_multi_node_optimizer")
     return MultiNodeOptimizer(
         actual_optimizer,
         communicator,

@@ -125,6 +125,23 @@ def summarize(rows: List[dict], curve_points: int = 16) -> dict:
             "count": len(compiles),
             "total_s": sum(float(r.get("secs", 0.0)) for r in compiles),
         }
+        # Rows of a recorder that drains the start-up ledger say which
+        # program and which stage: a row a program.
+        programs: Dict[str, dict] = {}
+        for r in compiles:
+            if "program" not in r:
+                continue
+            row = programs.setdefault(str(r["program"]), {
+                "trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
+                "compiles": 0, "hits": 0, "misses": 0})
+            row[f"{r.get('stage', 'compile')}_s"] += float(
+                r.get("secs", 0.0))
+            if r.get("stage") == "compile":
+                row["compiles"] += 1
+                row["hits"] += r.get("cache") == "hit"
+                row["misses"] += r.get("cache") == "miss"
+        if programs:
+            out["compile"]["programs"] = programs
 
     gauge_rows = [r for r in rows if r.get("event") == "gauge"
                   and "name" in r]

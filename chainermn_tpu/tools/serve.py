@@ -75,14 +75,16 @@ def _build_model(args):
     import jax.numpy as jnp
 
     from chainermn_tpu.models.transformer import TransformerLM
+    from chainermn_tpu.observability import startup
 
     model = TransformerLM(
         vocab=args.vocab, d_model=args.d_model, n_heads=args.heads,
         d_ff=args.d_ff, n_layers=args.layers, max_len=args.max_len,
     )
-    params = model.init(
-        jax.random.PRNGKey(args.seed), jnp.zeros((1, 8), jnp.int32)
-    )
+    with startup.phase("weights"):
+        params = model.init(
+            jax.random.PRNGKey(args.seed), jnp.zeros((1, 8), jnp.int32)
+        )
     return model, params
 
 
@@ -516,6 +518,8 @@ def _start_exporter(args, source):
 def _init_distributed(args) -> None:
     import jax
 
+    from chainermn_tpu.observability import startup
+
     if not args.coordinator:
         raise SystemExit(
             "--role router/replica needs --coordinator host:port"
@@ -528,7 +532,8 @@ def _init_distributed(args) -> None:
     # Force backend creation NOW, on every rank: the global topology
     # exchange blocks until all processes join, and a router that never
     # touches jax would otherwise deadlock the whole cluster.
-    jax.devices()
+    with startup.phase("backend"):
+        jax.devices()
 
 
 def _flight_path(args) -> Optional[str]:

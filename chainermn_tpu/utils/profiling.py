@@ -30,6 +30,9 @@ def setup_compilation_cache() -> str:
     seconds."""
     import os
 
+    from chainermn_tpu.observability import startup
+
+    startup.mark("setup_compilation_cache")
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = os.path.join(

@@ -64,7 +64,10 @@ def log(message):
 def require_tpu():
     import jax
 
-    platform = jax.devices()[0].platform
+    from chainermn_tpu.observability import startup
+
+    with startup.phase("backend"):
+        platform = jax.devices()[0].platform
     if platform != "tpu":
         raise SystemExit(
             f"chip_smoke: no TPU was found (JAX reports platform "
@@ -135,11 +138,18 @@ def _train_phase(comm, step, carry, batch, split):
               "no all-reduce in the compiled multi-device step")
 
     losses, step_s = [], []
-    for _ in range(1 + N_STEPS):
+    for i in range(1 + N_STEPS):
         t0 = time.perf_counter()
         carry, loss = split(step(*carry, batch))
         losses.append(float(jax.block_until_ready(loss)))
         step_s.append(time.perf_counter() - t0)
+        if i == 0:
+            # Where the start went: phases, marks, every program's trace /
+            # lower / compile seconds and how the cache answered.
+            from chainermn_tpu.observability import startup
+
+            for line in startup.report_lines():
+                log(line)
     check(np.isfinite(losses).all(), f"non-finite loss: {losses}")
 
     report = {
@@ -170,6 +180,7 @@ def lm_train(width):
 
     import chainermn_tpu
     from chainermn_tpu.models.transformer import TransformerLM
+    from chainermn_tpu.observability import startup
     from chainermn_tpu.ops import make_flash_attention_fn
     from chainermn_tpu.ops.fused_ce import fused_cross_entropy
 
@@ -187,13 +198,14 @@ def lm_train(width):
         rng.randint(0, width["vocab"], size=shape).astype(np.int32)
         for _ in range(2)
     ))
-    params = jax.jit(model.init)(
-        jax.random.PRNGKey(0), jnp.zeros((1, S), jnp.int32)
-    )["params"]
     opt = chainermn_tpu.create_multi_node_optimizer(
         optax.adamw(3e-4, weight_decay=0.1), comm
     )
-    state = opt.init(params)
+    with startup.phase("weights"):
+        params = jax.jit(model.init)(
+            jax.random.PRNGKey(0), jnp.zeros((1, S), jnp.int32)
+        )["params"]
+        state = opt.init(params)
 
     def loss_fn(p, batch):
         tokens, labels = batch
@@ -226,6 +238,7 @@ def resnet50_train(width):
 
     import chainermn_tpu
     from chainermn_tpu.models.resnet import ResNet50
+    from chainermn_tpu.observability import startup
 
     comm = chainermn_tpu.create_communicator("xla_ici")
     image = (width["image"], width["image"], 3)
@@ -233,15 +246,16 @@ def resnet50_train(width):
         num_classes=width["num_classes"], num_filters=width["num_filters"],
         stage_sizes=list(width["stage_sizes"]),
     )
-    variables = jax.jit(model.init, static_argnames="train")(
-        jax.random.PRNGKey(0), jnp.zeros((1, *image), jnp.float32),
-        train=True,
-    )
-    params, batch_stats = variables["params"], variables["batch_stats"]
     opt = chainermn_tpu.create_multi_node_optimizer(
         optax.sgd(0.1, momentum=0.9), comm
     )
-    state = opt.init(params)
+    with startup.phase("weights"):
+        variables = jax.jit(model.init, static_argnames="train")(
+            jax.random.PRNGKey(0), jnp.zeros((1, *image), jnp.float32),
+            train=True,
+        )
+        params, batch_stats = variables["params"], variables["batch_stats"]
+        state = opt.init(params)
 
     def loss_fn(params, batch_stats, batch):
         x, y = batch
@@ -275,6 +289,7 @@ def lm_serve(width):
     import numpy as np
 
     from chainermn_tpu.models.transformer import TransformerLM
+    from chainermn_tpu.observability import startup
     from chainermn_tpu.serving import (
         ContinuousBatchingScheduler,
         EngineConfig,
@@ -288,9 +303,10 @@ def lm_serve(width):
         n_heads=width["n_heads"], d_ff=width["d_ff"],
         n_layers=width["n_layers"], max_len=width["max_len"],
     )
-    params = jax.jit(model.init)(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
-    )
+    with startup.phase("weights"):
+        params = jax.jit(model.init)(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+        )
     engine = InferenceEngine(model, params, EngineConfig(
         block_size=width["block_size"], n_blocks=width["n_blocks"],
         max_len=width["max_len"], max_batch=width["max_batch"],

@@ -32,6 +32,7 @@ from chainermn_tpu.datasets.toy import SyntheticImageDataset, batch_iterator
 from chainermn_tpu.extensions import Evaluator
 from chainermn_tpu.models.convnets import AlexNet, GoogLeNet, NiN
 from chainermn_tpu.models.resnet import ResNet18, ResNet50
+from chainermn_tpu.observability import startup
 
 
 def main(argv=None):
@@ -118,9 +119,11 @@ def main(argv=None):
     }
     model = archs[args.arch](num_classes=args.num_classes)
     has_bn = args.arch.startswith("resnet")
-    variables = model.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, *shape), jnp.float32), train=False
-    )
+    with startup.phase("weights"):
+        variables = model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, *shape), jnp.float32),
+            train=False,
+        )
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
 

@@ -37,6 +37,7 @@ from jax.sharding import PartitionSpec as P
 
 import chainermn_tpu
 from chainermn_tpu.models.transformer import TransformerLM
+from chainermn_tpu.observability import startup
 from chainermn_tpu.ops import make_flash_attention_fn
 from chainermn_tpu.parallel.ring_attention import make_ring_attention_fn
 from chainermn_tpu.parallel.ulysses import make_ulysses_attention_fn
@@ -223,8 +224,9 @@ def main(argv=None):
         d_ff=args.d_ff, n_layers=args.layers, max_len=S, dtype=dtype,
         attention_fn=None, n_kv_heads=args.kv_heads,
     )
-    params = init_model.init(jax.random.PRNGKey(0), tok0)
-    params = jax.tree.map(lambda x: x.astype(jnp.float32), params)
+    with startup.phase("weights"):
+        params = init_model.init(jax.random.PRNGKey(0), tok0)
+        params = jax.tree.map(lambda x: x.astype(jnp.float32), params)
 
     opt = optax.adamw(args.lr, weight_decay=0.01)
     opt_state = opt.init(params)

@@ -37,6 +37,7 @@ from chainermn_tpu.utils.profiling import sync
 from chainermn_tpu.datasets.toy import SyntheticImageDataset, batch_iterator
 from chainermn_tpu.extensions import Evaluator
 from chainermn_tpu.models import MLP
+from chainermn_tpu.observability import startup
 
 
 def main(argv=None):
@@ -99,7 +100,8 @@ def main(argv=None):
     val = chainermn_tpu.scatter_dataset(val, comm)
 
     model = MLP(n_units=args.unit, n_out=10)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 28, 28)))
+    with startup.phase("weights"):
+        params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 28, 28)))
 
     def loss_fn(params, batch):
         x, y = batch
