@@ -15,10 +15,20 @@ compile is reported as such and skipped.
 
 The default static geometry (``auto_block_size``) rests on this table
 (PERF.md §6, PR 25).
+
+``--kinds`` times a live tile BY KIND instead (PR 50): an interior
+tile, a diagonal tile (the backward's by halves, and whole with the
+halves bypassed from here), the band's far tile, a tile both edges cut,
+a step entered and skipped and a resident block's opening and closing,
+forward and backward, at ``--kind-widths`` (D = 64 / 128 / 256 and
+scores 192 / values 128 by default) — each read off whole calls of a few
+tiles a head row, by differences (:func:`tile_kind_times`).  About four
+minutes on one chip.
 """
 
 import argparse
 import glob
+import importlib
 import itertools
 import json
 import os
@@ -41,6 +51,9 @@ from chainermn_tpu.ops.flash_attention import (
 )
 
 KERNELS = ("flash-fwd", "flash-bwd-dq", "flash-bwd-dkv")
+
+# (the module: ``chainermn_tpu.ops.flash_attention`` names the function)
+fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
 
 
 def build(which, bq, bk, scale, causal):
@@ -93,8 +106,146 @@ def device_report(programs, calls):
         tables), failed
 
 
+class _Whole:
+    """A jitted function that lowers with the backward's halves bypassed
+    from outside, as ``tests/test_flash_attention.py`` does (there is no
+    switch in the module; a checkout from before PR 50 has nothing to
+    bypass)."""
+
+    def __init__(self, fn):
+        self.fn = fn
+
+    def lower(self, *operands):
+        halves = getattr(fa, "_by_halves", None)
+        if halves is not None:
+            fa._by_halves = lambda *a, **kw: False
+        jax.clear_caches()
+        try:
+            return self.fn.lower(*operands)
+        finally:
+            if halves is not None:
+                fa._by_halves = halves
+            jax.clear_caches()
+
+
+#: name -> (causal, window in tile edges, tiles a side, halves): the
+#: calls :func:`tile_kind_times` solves the kinds from.  A head row of
+#: ``n`` tiles a side runs, non-causal, n^2 interior tiles; causal, n
+#: diagonal and n (n - 1) / 2 interior tiles and as many steps entered
+#: and skipped; under a window of one edge n diagonal and n - 1 far
+#: tiles and one skipped step; under half an edge n tiles both edges
+#: cut and n - 1 far ones.
+_KIND_CALLS = {
+    "full-2": (False, None, 2, True), "full-4": (False, None, 4, True),
+    "causal-2": (True, None, 2, True), "causal-4": (True, None, 4, True),
+    "causal-2-whole": (True, None, 2, False),
+    "causal-4-whole": (True, None, 4, False),
+    "band-4": (True, 1.0, 4, True), "both-4": (True, 0.5, 4, True),
+}
+
+
+def tile_kind_times(D, Dv, dtype, head_rows=32, calls=3,
+                    passes=("fwd", "bwd"), blocks=None):
+    """``[row]``, one a pass: the µs a tile of each kind at this width,
+    at the tile the rule gives the pass (``blocks``: ``{pass: edge}`` to
+    pin it), solved from the DEVICE times of :data:`_KIND_CALLS` a head
+    row: ``interior`` and ``row`` (a resident block's opening and
+    closing steps' own work) from the two non-causal calls, ``skipped``
+    and ``diagonal`` from the two causal ones, ``far`` from the band,
+    ``both`` from the narrow band; ``diagonal-whole`` and
+    ``skipped-whole`` the causal calls with the backward's halves
+    bypassed.  Off the chip: rows without times."""
+    dtype = jnp.dtype(dtype)
+    rng = np.random.RandomState(0)
+    programs, edges = {}, {}
+    for which in passes:
+        b = (blocks or {}).get(which) or fa.auto_block_size(
+            16384, D, dtype, which, D_v=None if Dv == D else Dv)
+        edges[which] = b
+        for name, (causal, window, n, halves) in _KIND_CALLS.items():
+            S = n * b
+            q = jnp.asarray(rng.randn(head_rows, S, D), dtype) / D**0.25
+            k = jnp.asarray(rng.randn(head_rows, S, D), dtype) / D**0.25
+            v, do = (jnp.asarray(rng.randn(head_rows, S, Dv), dtype)
+                     / D**0.25 for _ in range(2))
+            geometry = dict(scale=1.0 / D**0.5, causal=causal, block_q=b,
+                            block_k=b, interpret=default_interpret(),
+                            window=window and int(window * b))
+
+            def fn(*operands, geometry=geometry, which=which):
+                if which == "fwd":
+                    out = _flash_bh_fwd(*operands, **geometry)
+                else:
+                    out = _flash_bh_bwd(*operands, **geometry)
+                # (an op behind the kernel: see flash_bwd_probe.build)
+                return out, sum(x[0, 0, 0].astype(jnp.float32) for x in out)
+
+            fn.__name__ = f"{which}_{name}_d{D}x{Dv}".replace("-", "_")
+            operands = (q, k, v)
+            if which == "bwd":
+                o, lse = _flash_bh_fwd(q, k, v, **geometry)
+                operands = (q, k, v, o, lse, do)
+            jitted = jax.jit(fn)
+            programs[fn.__name__] = (
+                jitted if halves else _Whole(jitted), operands)
+    report, failed = device_report(programs, calls)
+
+    rows = []
+    for which in passes:
+        kernels = ("flash-fwd",) if which == "fwd" else (
+            "flash-bwd-dq", "flash-bwd-dkv")
+        row = {"pass": which, "D": D, "D_v": Dv, "block": edges[which],
+               "head_rows": head_rows}
+        us = {}
+        for name in _KIND_CALLS:
+            full = f"{which}_{name}_d{D}x{Dv}".replace("-", "_")
+            if full in failed:
+                row.setdefault("errors", {})[name] = failed[full]
+                continue
+            region = report["programs"].get(full, {}).get("region_ms", {})
+            if any(kern in region for kern in kernels):
+                us[name] = 1e3 * sum(
+                    region.get(kern, 0.0) for kern in kernels) / head_rows
+        for name in _KIND_CALLS:
+            # (a pass that halves nothing lowers its "-whole" calls to the
+            # very program of the plain ones, and the capture, which tells
+            # programs apart by module, reads one of the two)
+            twin = name[:-6] if name.endswith("-whole") else name + "-whole"
+            if name not in us and twin in us:
+                us[name] = us[twin]
+        if len(us) == len(_KIND_CALLS):
+            t = {"interior": (us["full-4"] - 2 * us["full-2"]) / 8}
+            t["row"] = (us["full-2"] - 4 * t["interior"]) / 2
+            for tag in ("", "-whole"):
+                c2, c4 = us["causal-2" + tag], us["causal-4" + tag]
+                skipped = (c4 - 2 * c2) / 4 - t["interior"]
+                t["skipped" + tag] = skipped
+                t["diagonal" + tag] = (
+                    c2 - t["interior"] - skipped - 2 * t["row"]) / 2
+            t["far"] = (us["band-4"] - 4 * t["diagonal"] - t["skipped"]
+                        - 4 * t["row"]) / 3
+            t["both"] = (us["both-4"] - 3 * t["far"] - t["skipped"]
+                         - 4 * t["row"]) / 4
+            row["us_a_tile"] = t
+        row["us_a_head_row"] = us
+        rows.append(row)
+        print(json.dumps(row))
+    return rows
+
+
+def write_json(path, result):
+    """``result`` to ``path`` (None: nowhere), its directory made."""
+    if path:
+        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+        with open(path, "w") as f:
+            json.dump(result, f, indent=1)
+
+
 def main():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--kinds", action="store_true",
+                    help="the µs a tile by kind, and nothing else")
+    ap.add_argument("--kind-widths", default="64,128,256,192/128")
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--heads", type=int, default=16)
     ap.add_argument("--seq", type=int, default=2048)
@@ -106,6 +257,15 @@ def main():
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
+    if args.kinds:
+        rows = []
+        for width in args.kind_widths.split(","):
+            D, _, Dv = width.partition("/")
+            rows += tile_kind_times(int(D), int(Dv or D), args.dtype,
+                                    args.batch * args.heads // 4, args.calls)
+        write_json(args.out, {"device": jax.devices()[0].device_kind,
+                              "rows": rows})
+        return
     BH, S, D = args.batch * args.heads, args.seq, args.d_head
     dtype = jnp.dtype(args.dtype)
     rng = np.random.RandomState(0)
@@ -154,10 +314,7 @@ def main():
                   "causal": args.causal},
         "rows": rows,
     }
-    if args.out:
-        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
-        with open(args.out, "w") as f:
-            json.dump(result, f, indent=1)
+    write_json(args.out, result)
 
 
 if __name__ == "__main__":

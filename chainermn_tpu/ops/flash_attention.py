@@ -17,9 +17,24 @@ takes (:func:`auto_block_size`): at S=2048 a head row is four steps, not
 256.  Under a sliding window the grid itself is the band: the inner axis
 is as long as the widest band is in tiles and the index maps start each
 resident block's walk at its band's first tile, so no step outside the
-band exists to be skipped.  What a call was built with — blocks, tiles
-live / visited / copied a head row (:func:`tile_census`) — rides in the
-kernels' scope path and goes to the telemetry sinks.
+band exists to be skipped.  Every live tile runs the one masked body;
+two things are cut from it by what the device's own times say (PERF.md
+§6, PR 50).  A square tile ON the causal diagonal holds a triangle, and
+in the BACKWARD kernels, where a half keeps an edge of 512, it runs BY
+HALVES (:func:`_by_halves`, :func:`_run_tiles`): three runs of the same
+body on row and column slices of the same refs, two of them the triangle
+again at half the edge and one unmasked, and the quarter that holds no
+live pair is not computed — same grid, same blocks, same copies, three
+quarters of the products and of the time (the forward's halves lose to
+its rows' statistics and it keeps whole tiles).  And under a window a
+row that has met no key is guarded by one select a row
+(:func:`_reached`), not one an element.  All three backward kernels
+enter their bodies the one way, so the one-pass backward and the two
+kernels past its footprint rule run the same parts in the same order.
+What a call was built with — blocks, tiles live / visited / copied a
+head row and of the live ones those an edge of the mask cuts and those
+halved (:func:`tile_census`) — goes to the telemetry sinks, the first
+five in the kernels' scope path too.
 
 Differentiable: a ``custom_vjp`` with an explicit FlashAttention-2-style
 backward — the forward saves one fp32 log-sum-exp per row, and the
@@ -74,6 +89,7 @@ from jax.experimental.pallas import tpu as pltpu
 from chainermn_tpu.observability import reporter as _reporter
 from chainermn_tpu.observability import step_log as _step_log
 from chainermn_tpu.observability.spans import (
+    TILE_FIELDS,
     named_scope,
     telemetry_active,
     tiles_scope,
@@ -120,6 +136,44 @@ def _band_live(causal, window, q_start, block_q, k_start, block_k):
     if window is not None:
         run = run & (k_start + block_k - 1 >= q_start - (window - 1))
     return run
+
+
+def _tile_cuts(causal, window, q_start, block_q, k_start, block_k):
+    """``(diagonal, edge)`` of a LIVE tile of a rectangle or a band, from
+    the numbers :func:`_band_live` reads "live" from: whether the causal
+    diagonal cuts it (some key of the tile lies after some query) and
+    whether the window's far edge does (some query lies ``window`` or
+    more past some key).  A tile neither cuts is interior: every pair of
+    it attends.  Python's False where the call has no such edge; a
+    scalar each in a kernel, arrays in the census."""
+    diagonal = edge = False
+    if causal:
+        diagonal = k_start + block_k - 1 > q_start
+    if window is not None:
+        edge = q_start + block_q - 1 - k_start >= window
+    return diagonal, edge
+
+
+def _by_halves(causal, window, block_q, block_k, segmented):
+    """Whether a BACKWARD kernel runs the tiles on the causal diagonal BY
+    HALVES (:func:`_run_tiles`).  A square tile's live diagonal tile
+    starts at ``q_start == k_start`` (both are multiples of the one
+    edge), so its upper rows reach the first half of its keys only and
+    three quarters of it hold every live pair; under a window no
+    narrower than the edge the window does not cut it.  By the device's
+    own times (PERF.md §6, PR 50; ``benchmarks/flash_sweep.py --kinds``):
+    the backward's parts are independent and their five products fill
+    the matrix unit, so a 1024-edge tile by halves takes three quarters
+    of its time (5.3 µs against 7.0 at D = 128), while 256-edge parts of
+    a 512-edge tile gain nothing (3.24 against 3.27) — a half keeps an
+    edge of 512; the FORWARD's parts each wait on their rows' running
+    maximum and sum, and its halved 1024-edge tile takes 5.0 µs where
+    the whole one takes 3.7, so the forward halves none.  A segment mask
+    keeps the whole tile (its refs are not cut)."""
+    half = block_q // 2
+    return (causal and not segmented and block_q == block_k
+            and half % 128 == 0 and half >= 512
+            and (window is None or window >= block_q))
 
 
 def _kv_live_range(iq, block_q, block_k, n_k, causal, window, xp=jnp):
@@ -318,39 +372,51 @@ def blockdiff_mask(L, B):
     return jnp.where(kn, qn & (kb == qb), kb <= qb - qn)
 
 
-def tile_census(Sq, Sk, block_q, block_k, causal, window, blockdiff=None):
+def tile_census(Sq, Sk, block_q, block_k, causal, window, blockdiff=None,
+                segmented=False):
     """What one head row's grid does at this geometry — ``{"fwd", "dq",
-    "dkv"}``, each ``{block_q, block_k, live, visited, copied}``: tiles
-    that intersect the band (they run the matmuls), grid steps, and
-    fetches of the streamed operand (K/V in forward and dq, the query
-    side in dk/dv: a step whose block index repeats the previous step's
-    copies nothing).  Computed with the index maps' own bounds: without
-    a window ``visited`` is the whole ``n_q x n_k`` rectangle, the steps
-    past the causal bound entered and skipped; with one it is the band
-    grid's ``n_q x`` :func:`_band_steps` (``n_k x`` the query side's in
-    dk/dv), which is ``live`` and the few repeats of the blocks near the
-    sequence's start.  Under the block-diffusion mask (``blockdiff`` =
-    ``(L, B)``) the grid is the list of live tiles itself
-    (:func:`_blockdiff_walk`): ``visited`` is ``live``."""
+    "dkv"}``, each ``{block_q, block_k, live, visited, copied, cut,
+    halved}``: tiles that intersect the band (they run the matmuls), grid
+    steps, fetches of the streamed operand (K/V in forward and dq, the
+    query side in dk/dv: a step whose block index repeats the previous
+    step's copies nothing), of the live tiles those an edge of the mask
+    cuts (:func:`_tile_cuts`; the others are interior: their mask is all
+    true) and of the cut ones those a backward kernel runs by halves
+    (:func:`_by_halves`, which a call with segment ids — ``segmented`` —
+    never does; a halved tile is one live, visited tile).
+    Computed with the index maps' own bounds: without a window
+    ``visited`` is the whole ``n_q x n_k`` rectangle, the steps past the
+    causal bound entered and skipped; with one it is the band grid's
+    ``n_q x`` :func:`_band_steps` (``n_k x`` the query side's in dk/dv),
+    which is ``live`` and the few repeats of the blocks near the
+    sequence's start.  Under the block-diffusion mask
+    (``blockdiff`` = ``(L, B)``) the grid is the list of live tiles
+    itself (:func:`_blockdiff_walk`): ``visited`` is ``live``, ``cut``
+    the tiles its flags mark, none halved."""
 
     def fetches(steps):
         return 1 + int(np.count_nonzero(np.diff(steps.ravel())))
 
     if blockdiff is not None:
-        base = {"block_q": block_q, "block_k": block_k}
-        _, tk, _ = _blockdiff_walk(*blockdiff, block_q, block_k, "kv")
+        _, tk, flags = _blockdiff_walk(*blockdiff, block_q, block_k, "kv")
         tq, _, _ = _blockdiff_walk(*blockdiff, block_q, block_k, "q")
-        kv = dict(base, live=len(tk), visited=len(tk), copied=fetches(tk))
-        return {"fwd": kv, "dq": kv, "dkv": dict(
-            base, live=len(tq), visited=len(tq), copied=fetches(tq))}
+        base = {"block_q": block_q, "block_k": block_k, "live": len(tk),
+                "visited": len(tk),
+                "cut": int(np.count_nonzero(flags & 4)), "halved": 0}
+        kv = dict(base, copied=fetches(tk))
+        return {"fwd": kv, "dq": kv, "dkv": dict(base, copied=fetches(tq))}
     n_q, n_k = Sq // block_q, Sk // block_k
     iq = np.arange(n_q)[:, None]
     ik = np.arange(n_k)[None, :]
     kv_range, q_range = _live_ranges(Sq, Sk, block_q, block_k, causal,
                                      window)
-    live = int(np.broadcast_to(_band_live(
-        causal, window, iq * block_q, block_q, ik * block_k, block_k,
-    ), (n_q, n_k)).sum())
+    tile = (causal, window, iq * block_q, block_q, ik * block_k, block_k)
+    live, diagonal, edge = (
+        np.broadcast_to(np.asarray(a, bool), (n_q, n_k))
+        for a in (_band_live(*tile), *_tile_cuts(*tile)))
+    halved = 0
+    if _by_halves(causal, window, block_q, block_k, segmented):
+        halved = int((live & diagonal).sum())
     if window is None:
         kv_steps = np.broadcast_to(np.clip(ik, *kv_range(iq, xp=np)),
                                    (n_q, n_k))
@@ -363,10 +429,18 @@ def tile_census(Sq, Sk, block_q, block_k, causal, window, blockdiff=None):
         q_steps, _ = _band_block(
             q_range, ik.T, np.arange(_band_steps(q_range, n_k))[None, :],
             xp=np)
-    base = {"block_q": block_q, "block_k": block_k, "live": live}
+    base = {"block_q": block_q, "block_k": block_k, "live": int(live.sum()),
+            "cut": int((live & (diagonal | edge)).sum())}
     kv = dict(base, visited=kv_steps.size, copied=fetches(kv_steps))
-    return {"fwd": kv, "dq": kv,
-            "dkv": dict(base, visited=q_steps.size, copied=fetches(q_steps))}
+    return {"fwd": dict(kv, halved=0), "dq": dict(kv, halved=halved),
+            "dkv": dict(base, visited=q_steps.size, copied=fetches(q_steps),
+                        halved=halved)}
+
+
+def _tiles_scope(tiles):
+    """:func:`tiles_scope` of one kernel's census: the five fields the
+    scope path spells (``spans.TILE_FIELDS``)."""
+    return tiles_scope(**{field: tiles[field] for field in TILE_FIELDS})
 
 
 def _walked(refs, blockdiff, block_q, block_k):
@@ -403,18 +477,72 @@ def _band_run(causal, window, q_start, block_q, k_start, block_k, in_band):
     return run
 
 
-def _run_tiles(tile, walk, run, mask_of):
+#: A tile's rows, or its columns, whole.
+_WHOLE = slice(None)
+
+
+def _reached(stat):
+    """A row statistic — the running maximum in the forward, the saved
+    log-sum-exp in the backward — as the exponent's shift under a
+    window: 0 in place of ``_NEG_INF`` for a row that has met no key
+    (its window starts past the tile, or past every key), so that
+    ``exp(s - shift)`` of its masked scores is ``exp(_NEG_INF)``, 0, and
+    not ``exp(0)`` — one select a ROW where the bodies with a segment
+    mask spend one an element (``p = where(mask, p, 0)``: 1.0 µs of a
+    1024-edge forward tile, PERF.md §6, PR 50).  The bits of every other
+    row are what they were."""
+    return jnp.where(stat < 0.5 * _NEG_INF, 0.0, stat)
+
+
+def _halves(walk, causal, window, segmented, q_start, block_q, k_start,
+            block_k):
+    """What a backward kernel hands :func:`_run_tiles` as ``halves``:
+    None, or where :func:`_by_halves` holds ``(on_diagonal, half)`` —
+    whether the causal diagonal cuts this tile (:func:`_tile_cuts`) and
+    half the tile's edge."""
+    if walk is not None or not _by_halves(causal, window, block_q, block_k,
+                                          segmented):
+        return None
+    on_diagonal, _ = _tile_cuts(causal, window, q_start, block_q, k_start,
+                                block_k)
+    return on_diagonal, block_q // 2
+
+
+def _run_tiles(tile, walk, run, mask_of, halves=None):
     """Enter a kernel's tile body: where the grid is a rectangle or a
     band, when ``run()`` says the tile is live, under ``mask_of``; where
     it is the walk of the block-diffusion mask's live tiles every step
     is live, and a tile the mask cuts runs the body with the mask, an
-    interior one the body without."""
-    if walk is None:
+    interior one the body without.
+
+    Under ``halves`` (:func:`_halves`; the body is then ``tile(mask_of,
+    rows, cols)``, the part ``rows x cols`` of the tile) a live tile on
+    the diagonal runs BY HALVES: its upper rows against its first keys
+    (the diagonal again, at half the edge), its lower rows against the
+    first keys without a mask and against the last under the diagonal,
+    in that order.  The quarter left out holds no live pair."""
+    if walk is not None:
+        *_, cut, cut_mask = walk
+        pl.when(cut)(lambda: tile(cut_mask))
+        pl.when(jnp.logical_not(cut))(lambda: tile(lambda shape: None))
+    elif halves is None:
         pl.when(run())(lambda: tile(mask_of))
-        return
-    *_, cut, cut_mask = walk
-    pl.when(cut)(lambda: tile(cut_mask))
-    pl.when(jnp.logical_not(cut))(lambda: tile(lambda shape: None))
+    else:
+        on_diagonal, half = halves
+        first, last = slice(0, half), slice(half, 2 * half)
+
+        def triangle(shape):    # (the part's rows and keys start level)
+            return _block_mask(shape, True, 0, 0, None, None)
+
+        live = run()
+
+        @pl.when(live & on_diagonal)
+        def _():
+            tile(triangle, first, first)
+            tile(lambda shape: None, last, first)
+            tile(triangle, last, last)
+
+        pl.when(live & jnp.logical_not(on_diagonal))(lambda: tile(mask_of))
 
 
 def _attn_kernel(
@@ -463,20 +591,24 @@ def _attn_kernel(
         m_prev = m_ref[:, 0]
         m_blk = jnp.max(s, axis=1)
         m_new = jnp.maximum(m_prev, m_blk)
-        p = jnp.exp(s - m_new[:, None])
-        if mask is not None and (segmented or window is not None
-                                 or blockdiff is not None):
-            # A row fully masked in this block has m_new == _NEG_INF ==
-            # its masked scores, making exp(s - m_new) = 1 — zero those
-            # entries so padding rows accumulate nothing.  (Causal-only
-            # running blocks always have >= 1 valid entry per row; a
-            # low-k windowed block is admitted because the q block's
-            # EARLY rows still reach it, while its LATE rows — whose
-            # window starts later — can be fully masked on this, their
-            # first visited block, so the window path needs this too.
-            # Under the block-diffusion mask a noisy row of the first
-            # block sees no clean key at all, and its first tile is a
-            # clean one.)
+        # A row fully masked in this block has m_new == _NEG_INF == its
+        # masked scores, making exp(s - m_new) = 1 where it must be 0,
+        # so that padding rows accumulate nothing.  (Causal-only running
+        # blocks always have >= 1 valid entry per row; a low-k windowed
+        # block is admitted because the q block's EARLY rows still reach
+        # it, while its LATE rows — whose window starts later — can be
+        # fully masked on this, their first visited block, so the window
+        # path needs a guard too.  Under the block-diffusion mask a
+        # noisy row of the first block sees no clean key at all, and its
+        # first tile is a clean one.)  With a segment mask, or under the
+        # block-diffusion mask, the entries are zeroed; under a window
+        # alone such a row is shifted by 0 (:func:`_reached`).
+        zeroed = segmented or blockdiff is not None
+        shift = m_new
+        if window is not None and not zeroed:
+            shift = _reached(m_new)
+        p = jnp.exp(s - shift[:, None])
+        if mask is not None and zeroed:
             p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
 
@@ -713,7 +845,7 @@ def _flash_bh_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
         args += [q_seg, kv_seg]
     tiles = tile_census(Sq, Sk, block_q, block_k, causal, window,
                         blockdiff)["fwd"]
-    with named_scope("flash-fwd"), tiles_scope(**tiles):
+    with named_scope("flash-fwd"), _tiles_scope(tiles):
         return _pallas(
             kernel, tables,
             out_shape=[
@@ -761,33 +893,42 @@ def _dq_kernel(
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
+    q_start = k_start = None
     if walk is None:
         q_start = iq * block_q
         ik, in_band = _streamed_block(kv_range, iq, j, window)
         k_start = ik * block_k
 
-    def tile(mask_of):
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
+    def tile(mask_of, rows=_WHOLE, cols=_WHOLE):
+        q = q_ref[0, rows]
+        k = k_ref[0, cols]
+        v = v_ref[0, cols]
+        do = do_ref[0, rows]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         mask = mask_of(s.shape)
         if mask is not None:
             s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0, :, :])             # exact probabilities
-        if segmented or window is not None:
-            # A FULLY-masked row (padding) has lse ~ _NEG_INF, making
-            # exp(s - lse) = 1 at masked entries; zero them explicitly.
+        # A FULLY-masked row (padding, or a query past the last key's
+        # reach under a window: it lies only in tiles the window cuts)
+        # has lse ~ _NEG_INF, making exp(s - lse) = 1 at masked entries:
+        # with a segment mask they are zeroed explicitly, under a window
+        # alone the row is shifted by 0 (:func:`_reached`).
+        lse = lse_ref[0, rows, :]
+        if window is not None and not segmented:
+            lse = _reached(lse)
+        p = jnp.exp(s - lse)
+        if segmented:
             p = jnp.where(mask, p, 0.0)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0, :, :]) * scale).astype(k.dtype)
-        dq_acc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[0, rows, :]) * scale).astype(k.dtype)
+        dq_acc[rows] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
     _run_tiles(tile, walk, lambda: _band_run(
         causal, window, q_start, block_q, k_start, block_k, in_band),
         lambda shape: _block_mask(shape, causal, q_start, k_start, qs_ref,
-                                  ks_ref, window))
+                                  ks_ref, window),
+        _halves(walk, causal, window, segmented, q_start, block_q, k_start,
+                block_k))
 
     @pl.when(last())
     def _():
@@ -836,6 +977,7 @@ def _dkv_kernel(
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
+    q_start = k_start = None
     if walk is None:
         q_start = iq * block_q
         k_start = ik * block_k
@@ -843,28 +985,33 @@ def _dkv_kernel(
     # (The rectangle's and the band's skip: when the whole Q block
     # precedes the whole K block (causal) or lies entirely beyond the K
     # block's window reach.)
-    def tile(mask_of):
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
+    def tile(mask_of, rows=_WHOLE, cols=_WHOLE):
+        q = q_ref[0, rows]
+        k = k_ref[0, cols]
+        v = v_ref[0, cols]
+        do = do_ref[0, rows]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         mask = mask_of(s.shape)
         if mask is not None:
             s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0, :, :])
-        if segmented or window is not None:
+        lse = lse_ref[0, rows, :]
+        if window is not None and not segmented:
+            lse = _reached(lse)
+        p = jnp.exp(s - lse)
+        if segmented:
             p = jnp.where(mask, p, 0.0)  # see _dq_kernel
         pt = p.astype(do.dtype).T
-        dv_acc[:] += jnp.dot(pt, do, preferred_element_type=jnp.float32)
+        dv_acc[cols] += jnp.dot(pt, do, preferred_element_type=jnp.float32)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0, :, :]) * scale).astype(q.dtype)
-        dk_acc[:] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[0, rows, :]) * scale).astype(q.dtype)
+        dk_acc[cols] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
 
     _run_tiles(tile, walk, lambda: _band_run(
         causal, window, q_start, block_q, k_start, block_k, in_band),
         lambda shape: _block_mask(shape, causal, q_start, k_start, qs_ref,
-                                  ks_ref, window))
+                                  ks_ref, window),
+        _halves(walk, causal, window, segmented, q_start, block_q, k_start,
+                block_k))
 
     @pl.when(last())
     def _():
@@ -912,8 +1059,14 @@ def _bwd_kernel(
         g == group - 1)
     n_k = dk_acc.shape[0] // block_k
 
-    def k_rows(ik):
-        return pl.ds(pl.multiple_of(ik * block_k, block_k), block_k)
+    def k_rows(ik, cols=_WHOLE):
+        """K tile ``ik``'s rows of the resident buffers, or the part
+        ``cols`` of them."""
+        first, end, _ = cols.indices(block_k)
+        start = ik * block_k
+        if first:
+            start = start + first
+        return pl.ds(pl.multiple_of(start, end - first), end - first)
 
     @pl.when(opens)
     def _():
@@ -930,6 +1083,7 @@ def _bwd_kernel(
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
+    q_start = k_start = None
     if walk is None:
         q_start = iq * block_q
         ik, in_band = _streamed_block(kv_range, iq, j, window)
@@ -937,32 +1091,39 @@ def _bwd_kernel(
     else:
         ik = walk[1]
 
-    def tile(mask_of):
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        rows = k_rows(ik)
+    def tile(mask_of, rows=_WHOLE, cols=_WHOLE):
+        # (``dq`` is per query row and ``dk`` / ``dv`` per key row: a
+        # part of the tile adds its rows' terms at its keys' rows.)
+        q = q_ref[0, rows]
+        k = k_ref[0, cols]
+        v = v_ref[0, cols]
+        do = do_ref[0, rows]
+        keys = k_rows(ik, cols)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         mask = mask_of(s.shape)
         if mask is not None:
             s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0, :, :])             # exact probabilities
-        if segmented or window is not None:
+        lse = lse_ref[0, rows, :]
+        if window is not None and not segmented:
+            lse = _reached(lse)
+        p = jnp.exp(s - lse)
+        if segmented:
             p = jnp.where(mask, p, 0.0)  # see _dq_kernel
         pt = p.astype(do.dtype).T
-        dv_acc[rows, :] += jnp.dot(pt, do,
+        dv_acc[keys, :] += jnp.dot(pt, do,
                                    preferred_element_type=jnp.float32)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0, :, :]) * scale).astype(q.dtype)
-        dk_acc[rows, :] += jnp.dot(ds.T, q,
+        ds = (p * (dp - delta_ref[0, rows, :]) * scale).astype(q.dtype)
+        dk_acc[keys, :] += jnp.dot(ds.T, q,
                                    preferred_element_type=jnp.float32)
-        dq_acc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        dq_acc[rows] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
     _run_tiles(tile, walk, lambda: _band_run(
         causal, window, q_start, block_q, k_start, block_k, in_band),
         lambda shape: _block_mask(shape, causal, q_start, k_start, qs_ref,
-                                  ks_ref, window))
+                                  ks_ref, window),
+        _halves(walk, causal, window, segmented, q_start, block_q, k_start,
+                block_k))
 
     @pl.when(last())
     def _():
@@ -1058,8 +1219,8 @@ def _flash_bwd_fused(q, k, v, o, lse, do, *, scale, causal, block_q,
     dk_spec, dv_spec = (pl.BlockSpec((1, Sk, d), lambda b, *g: (b // G, 0, 0))
                         for d in (D, Dv))
     tiles = tile_census(Sq, Sk, block_q, block_k, causal, window,
-                        blockdiff)["dq"]
-    with named_scope("flash-bwd-dkv"), tiles_scope(**tiles):
+                        blockdiff, segmented)["dq"]
+    with named_scope("flash-bwd-dkv"), _tiles_scope(tiles):
         return _pallas(
             functools.partial(
                 _bwd_kernel, scale=scale, causal=causal, segmented=segmented,
@@ -1110,8 +1271,9 @@ def _flash_bwd_pair(q, k, v, o, lse, do, *, scale, causal, block_q,
     kv_range, tables, inner, q_spec, dq_in, dq_args = _kv_streamed(
         q, k, v, do, lse, delta, q_seg, kv_seg, block_q=block_q,
         block_k=block_k, causal=causal, window=window, blockdiff=blockdiff)
-    tiles = tile_census(Sq, Sk, block_q, block_k, causal, window, blockdiff)
-    with named_scope("flash-bwd-dq"), tiles_scope(**tiles["dq"]):
+    tiles = tile_census(Sq, Sk, block_q, block_k, causal, window, blockdiff,
+                        segmented)
+    with named_scope("flash-bwd-dq"), _tiles_scope(tiles["dq"]):
         dq = _pallas(
             functools.partial(
                 _dq_kernel, scale=scale, causal=causal, segmented=segmented,
@@ -1174,7 +1336,7 @@ def _flash_bwd_pair(q, k, v, o, lse, do, *, scale, causal, block_q,
         dkv_args += [q_seg, kv_seg]
     walked = {} if blockdiff is None else {"blockdiff": blockdiff,
                                            "group": G}
-    with named_scope("flash-bwd-dkv"), tiles_scope(**tiles["dkv"]):
+    with named_scope("flash-bwd-dkv"), _tiles_scope(tiles["dkv"]):
         dk, dv = _pallas(
             functools.partial(
                 _dkv_kernel, scale=scale, causal=causal,
@@ -1571,7 +1733,8 @@ def _publish_geometry(fwd: dict, bwd: dict, blockdiff=None,
     backward holds in VMEM (its tiles are then ``flash-bwd-dkv``'s, on
     dq's grid, and there is no ``flash-bwd-dq`` entry), None the two
     kernels.  To the installed sinks: a ``flash_geometry`` row of the
-    StepRecorder (the kernels' entries, ``bwd_fused`` and
+    StepRecorder (the kernels' entries — :func:`tile_census`'s, ``cut``
+    and ``halved`` among them —, ``bwd_fused`` and
     ``bwd_resident_bytes``), ``flash/<kernel>/<field>`` gauges,
     ``flash/bwd_fused`` (1 or 0) and ``flash/bwd_resident_bytes`` gauges
     and the ``flash/calls`` and ``flash/bwd_fused_calls`` counters of the
@@ -1801,7 +1964,8 @@ def flash_attention(
         _publish_geometry(
             tile_census(Sq, Sk, block_q, block_k, causal, window,
                         blockdiff)["fwd"],
-            tile_census(Sq, Sk, bq_b, bk_b, causal, window, blockdiff),
+            tile_census(Sq, Sk, bq_b, bk_b, causal, window, blockdiff,
+                        segmented),
             blockdiff,
             bwd_resident_bytes(Sk, D, Dv) if fused else None,
         )

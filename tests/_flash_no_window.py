@@ -17,7 +17,11 @@ its records on the changed tree and shows that the others kept theirs:
 the five ``bwd`` records are PR 48's, whose backward is one
 ``pallas_call`` on the forward's grid where the parent ran two (``bwd``
 goes through ``_flash_bh_bwd``, so it records the side the footprint
-rule gives rows this short: the one pass).
+rule gives rows this short: the one pass).  PR 50 (the backward's
+1024-edge diagonal tiles by halves, a windowed row's guard by row) moved
+none of them — no case here has a window or an edge that halves — and
+added ``blockdiff``, a call under the block-diffusion mask, recorded in
+that PR's PARENT checkout (335fd5b, with this script) and held to it.
 """
 
 import hashlib
@@ -31,22 +35,26 @@ import jax.numpy as jnp
 
 fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
 
-#: name -> (BH, BHk, Sq, Sk, D, block_q, block_k, causal, segmented, dlse)
+#: name -> (BH, BHk, Sq, Sk, D, block_q, block_k, causal, segmented, dlse
+#: [, block-diffusion (L, B)])
 CASES = {
     "causal": (4, 4, 256, 256, 64, 64, 64, True, False, False),
     "gqa-rectangular": (4, 2, 256, 256, 64, 64, 128, True, False, False),
     "full": (2, 2, 256, 256, 128, 128, 64, False, False, False),
     "segments": (4, 2, 256, 256, 64, 128, 64, True, True, False),
     "more-keys-dlse": (4, 1, 128, 256, 64, 32, 64, True, False, True),
+    "blockdiff": (4, 2, 512, 512, 64, 64, 128, True, False, False, (256, 4)),
 }
 
 
 def programs(case, interpret):
     """``{"fwd": (fn, operands), "bwd": (fn, operands)}`` of one case, the
     operands as shapes."""
-    BH, BHk, Sq, Sk, D, bq, bk, causal, segmented, dlse = CASES[case]
+    BH, BHk, Sq, Sk, D, bq, bk, causal, segmented, dlse, *mask = CASES[case]
     geometry = dict(scale=D ** -0.5, causal=causal, block_q=bq, block_k=bk,
                     interpret=interpret)
+    if mask:
+        geometry["blockdiff"] = mask[0]
 
     def arr(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype)

@@ -859,11 +859,16 @@ def test_tile_census_counts_the_grid(Sq, Sk, bq, bk, causal, window):
         return 1 + sum(a != b for a, b in zip(named, named[1:]))
 
     got = tile_census(Sq, Sk, bq, bk, causal, window)
-    base = {"block_q": bq, "block_k": bk, "live": live}
-    assert got["fwd"] == got["dq"] == dict(
-        base, visited=n_q * kv_steps, copied=fetches(kv_walk))
+    # (``cut`` and ``halved``, the backward's alone, are held to the
+    # kernels' own choice by
+    # test_the_backward_halves_the_diagonal_and_the_census_counts_it)
+    base = {"block_q": bq, "block_k": bk, "live": live,
+            "cut": got["fwd"]["cut"]}
+    assert got["fwd"] == dict(got["dq"], halved=0) == dict(
+        base, visited=n_q * kv_steps, copied=fetches(kv_walk), halved=0)
     assert got["dkv"] == dict(
-        base, visited=n_k * q_steps, copied=fetches(q_walk))
+        base, visited=n_k * q_steps, copied=fetches(q_walk),
+        halved=got["dq"]["halved"])
     if window is not None and window + max(bq, bk) <= min(Sq, Sk) // 4:
         # a band far narrower than the sequence: no more steps than live
         # tiles and one a resident block
@@ -1046,12 +1051,18 @@ def test_flash_without_a_window_is_the_parents_program(
     lowered for a TPU hash to what the parent commit's did
     (``tests/_flash_no_window.py`` says how the golden file is made).
     The backward is one ``pallas_call`` on the forward's grid since
-    PR 48 (rows this short fit the one-pass footprint), and its five
-    digests are that program's; the forward's are PR 40's parent's."""
+    PR 48 (rows this short fit the one-pass footprint).  PR 50 moved
+    none of the five (no tile here has an edge that halves) and added
+    ``blockdiff``: the program its parent traced for a call under the
+    block-diffusion mask."""
     BH, BHk, Sq, Sk, _, bq, bk, *_ = _flash_no_window.CASES[case]
     got = _flash_no_window.record(case)[which]
     n_q, n_k = Sq // bq, Sk // bk
-    assert got["grids"] == [[BH, n_q, n_k]]
+    if case == "blockdiff":     # the walk of its live tiles
+        assert got["grids"] == [[BH, fa.tile_census(
+            Sq, Sk, bq, bk, True, None, (Sq // 2, 4))["fwd"]["live"]]]
+    else:
+        assert got["grids"] == [[BH, n_q, n_k]]
     assert got == no_window_golden[case][which], (
         f"{case}/{which}: the program of a call without a window moved. "
         f"If ops/flash_attention.py was meant to change it, remake the "
@@ -1167,8 +1178,12 @@ def test_flash_geometry_record_reaches_the_sinks(tmp_path):
     rows = [json.loads(line) for line in open(path)]
     rows = [r for r in rows if r["event"] == "flash_geometry"]
     assert len(rows) == 1
+    # (of the 6 live tiles the diagonal cuts 4; rectangular: none halved)
     assert rows[0]["flash-fwd"] == {
-        "block_q": 32, "block_k": 64, "live": 6, "visited": 8, "copied": 4}
+        "block_q": 32, "block_k": 64, "live": 6, "visited": 8, "copied": 4,
+        "cut": 4, "halved": 0}
+    assert gauges["flash/flash-fwd/cut"] == 4
+    assert gauges["flash/flash-bwd-dkv/halved"] == 0
     # The one-pass backward: its tiles are flash-bwd-dkv's, on dq's grid.
     assert set(rows[0]) >= {"flash-fwd", "flash-bwd-dkv", "bwd_fused",
                             "bwd_resident_bytes"}
@@ -1327,3 +1342,286 @@ def test_flash_vmem_bytes_counts_the_resident_rows():
     assert fa.VMEM_SCOPED_DEFAULT < largest < 64 * MiB < fa.VMEM_LIMIT_MAX
     assert fa._compiler_params(largest).vmem_limit_bytes <= fa.VMEM_LIMIT_MAX
     assert fa.bwd_fused_vmem_bytes(131072, 1024, 1024, 128, 2) is None
+
+
+# ---------------------------------------------------------------------
+# The backward's diagonal tiles by halves, a windowed row's guard (PR 50)
+
+#: ``_GEOMETRIES`` and the halves' own cases: S = 2 x edge, Sq != Sk,
+#: block_q != block_k at edges that halve, windows off / under / at /
+#: over a halving edge.
+_HALVES_GEOMETRIES = _GEOMETRIES + [
+    (512, 512, 256, 256, True, None),       # S = 2 x edge; no halves at 256
+    (3072, 2048, 1024, 1024, True, None),   # more queries, halved backward
+    (2048, 3072, 1024, 1024, True, None),   # more keys
+    (4096, 4096, 1024, 1024, True, 2048),   # a window two halving edges wide
+    (4096, 4096, 1024, 1024, True, 1500),   # off a halving edge
+    (4096, 4096, 1024, 1024, True, 1024),   # at the edge: still halved
+    (4096, 4096, 1024, 1024, True, 1000),   # under it: the window cuts the
+                                            # diagonal's tile, kept whole
+    (2048, 2048, 1024, 1024, False, None),  # nothing positional masks
+    (2048, 2048, 1024, 512, True, None),    # block_q != block_k: no halves
+    (2048, 2048, 512, 1024, True, None),
+    (768, 512, 256, 256, True, None),       # more queries than keys
+    (1024, 1024, 256, 256, True, 100),
+    (1024, 1024, 256, 256, True, 512),
+    (8192, 8192, 512, 512, True, None),     # qwen3next's backward
+]
+
+
+def _eager_when(cond):
+    """``pl.when`` on concrete scalars: the body runs now, or not."""
+    return lambda body: body() if bool(cond) else None
+
+
+def _parts_of(monkeypatch, Sq, Sk, bq, bk, causal, window, seg=None):
+    """What a backward kernel runs through ``_run_tiles`` — the one way
+    into its tile body — at every step of a head row's grid, on concrete
+    scalars: ``{(iq, ik): [(rows, cols, mask or None), ...]}`` in dq's
+    step order, a step that runs nothing left out.  ``seg``: ``(q ids,
+    k ids)`` of a call with segment ids."""
+    monkeypatch.setattr(fa.pl, "when", _eager_when)
+    kv_range, _ = fa._live_ranges(Sq, Sk, bq, bk, causal, window)
+    _, steps = fa._streamed_axis(kv_range, Sq // bq, Sk // bk, causal,
+                                 window)
+    ran = {}
+    for iq in range(Sq // bq):
+        for j in range(steps):
+            ik, in_band = fa._streamed_block(kv_range, iq, j, window)
+            ik = int(ik)
+            q_start, k_start = iq * bq, ik * bk
+            qs = ks = None
+            if seg is not None:
+                qs = seg[0][None, q_start:q_start + bq, None]
+                ks = seg[1][None, k_start:k_start + bk, None]
+
+            def tile(mask_of, rows=fa._WHOLE, cols=fa._WHOLE):
+                mask = mask_of((len(range(bq)[rows]), len(range(bk)[cols])))
+                ran.setdefault((iq, ik), []).append(
+                    (rows, cols, None if mask is None else np.asarray(mask)))
+
+            before = len(ran.get((iq, ik), ()))
+            fa._run_tiles(
+                tile, None,
+                lambda: fa._band_run(causal, window, q_start, bq, k_start,
+                                     bk, in_band),
+                lambda shape: fa._block_mask(shape, causal, q_start,
+                                             k_start, qs, ks, window),
+                fa._halves(None, causal, window, seg is not None, q_start,
+                           bq, k_start, bk))
+            assert not before or len(ran[iq, ik]) == before, (
+                "a tile run at two steps")
+    return ran
+
+
+def _dense_masks(bq, bk, causal, window, seg=None):
+    """``tile(iq, ik) -> (triangle, band, segments)``: the dense masks'
+    (bq, bk) tile, each all-true where the call has no such mask."""
+    true = np.ones((bq, bk), bool)
+
+    def tile(iq, ik):
+        q_pos = iq * bq + np.arange(bq, dtype=np.int32)[:, None]
+        k_pos = ik * bk + np.arange(bk, dtype=np.int32)[None, :]
+        return (q_pos >= k_pos if causal else true,
+                q_pos - k_pos < window if window is not None else true,
+                seg[0][q_pos] == seg[1][k_pos] if seg is not None else true)
+
+    return tile
+
+
+@pytest.mark.parametrize("segmented", [False, True], ids=["", "segments"])
+@pytest.mark.parametrize("Sq,Sk,bq,bk,causal,window", _HALVES_GEOMETRIES)
+def test_the_backward_halves_the_diagonal_and_the_census_counts_it(
+        monkeypatch, Sq, Sk, bq, bk, causal, window, segmented):
+    """What the backward kernels' scalar tests run at each tile of a head
+    row against the dense mask: a tile that runs is live and a live tile
+    runs once; the masks its part(s) apply ARE the dense mask's tile; a
+    tile run by halves lies on the diagonal, clear of the window, in a
+    call without segment ids, its dropped quarter all false and its
+    unmasked quarter all true.  ``tile_census``'s ``cut`` is the live
+    tiles an edge of the dense mask crosses, ``halved`` what ran by
+    halves (none in the forward)."""
+    seg = None
+    if segmented:
+        ids = np.sort(np.random.RandomState(2).randint(
+            0, 3, size=max(Sq, Sk))).astype(np.int32)
+        seg = (ids[:Sq], ids[:Sk])
+    ran = _parts_of(monkeypatch, Sq, Sk, bq, bk, causal, window, seg)
+    dense_tile = _dense_masks(bq, bk, causal, window, seg)
+    cut = halved = 0
+    for iq in range(Sq // bq):
+        for ik in range(Sk // bk):
+            triangle, band, segments = dense_tile(iq, ik)
+            dense = triangle & band & segments
+            live = (triangle & band).any()
+            assert ((iq, ik) in ran) == live, (iq, ik)
+            if not live:
+                continue
+            parts = ran[iq, ik]
+            applied = np.zeros((bq, bk), bool)
+            for rows, cols, mask in parts:
+                applied[rows, cols] = True if mask is None else mask
+            np.testing.assert_array_equal(applied, dense, str((iq, ik)))
+            cut += not (triangle & band).all()
+            if len(parts) == 1:
+                assert parts[0][:2] == (fa._WHOLE, fa._WHOLE)
+                continue
+            assert not triangle.all() and band.all() and not segmented
+            assert bq == bk and iq == ik
+            half = bq // 2
+            top, low = np.s_[:half], np.s_[half:]
+            assert not dense[top, low].any() and dense[low, top].all()
+            assert [(r.start, c.start, m is None) for r, c, m in parts] == [
+                (0, 0, False), (half, 0, True), (half, half, False)]
+            halved += 1
+    census = fa.tile_census(Sq, Sk, bq, bk, causal, window,
+                            segmented=segmented)
+    assert (census["fwd"]["cut"], census["fwd"]["halved"]) == (cut, 0)
+    for kernel in ("dq", "dkv"):
+        assert (census[kernel]["cut"], census[kernel]["halved"]) == (
+            cut, halved)
+    halves = (causal and not segmented and bq == bk and bq >= 1024
+              and (window is None or window >= bq))
+    assert (halved > 0) == halves
+
+
+def test_cut_and_halved_at_the_cells_shapes():
+    """The census at the cells' rows: a causal row of 16,384 at
+    1024-edge tiles has 136 live tiles, 16 of them on the diagonal,
+    halved in the backward; S = 8,192: 8 of 36; cgpt's S = 2,048: 2 of
+    3; qwen3next's 512-edge backward halves none (its halves would be
+    256 wide); mellum's windowed rows — forward (1024) a diagonal and a
+    far tile a q block, backward (512) a diagonal, a far tile and an
+    interior tile between them, none halved at that edge; the same row
+    at 1024-edge backward tiles would halve its 16 diagonal tiles."""
+    for S, b, window, live, cut, halved in [
+            (16384, 1024, None, 136, 16, 16),
+            (8192, 1024, None, 36, 8, 8),
+            (8192, 512, None, 136, 16, 0),
+            (2048, 1024, None, 3, 2, 2),
+            (16384, 1024, 1024, 31, 31, 16),
+            (16384, 512, 1024, 93, 62, 0)]:
+        census = fa.tile_census(S, S, b, b, True, window)
+        for kernel, halves in (("fwd", 0), ("dq", halved), ("dkv", halved)):
+            t = census[kernel]
+            assert (t["live"], t["cut"], t["halved"]) == (live, cut, halves)
+    # nothing positional masks: nothing is cut
+    t = fa.tile_census(2048, 2048, 1024, 1024, False, None)["dq"]
+    assert (t["live"], t["cut"], t["halved"]) == (4, 0, 0)
+
+
+#: name -> (Sq, Sk, block_q, block_k, window, Hk of H = 4, segmented)
+_HALVES_CASES = {
+    "halved": (2048, 2048, 1024, 1024, None, 2, False),
+    "halved-more-keys": (1024, 2048, 1024, 1024, None, 4, False),
+    "halved-window-at-the-edge": (3072, 3072, 1024, 1024, 1024, 1, False),
+    "square": (512, 512, 256, 256, None, 4, False),
+    "rectangular": (512, 512, 256, 128, None, 2, False),
+    "window-at-the-edge": (768, 768, 256, 256, 256, 4, False),
+    "window-under-the-edge": (512, 512, 256, 256, 100, 4, False),
+    "window-off-the-edge": (768, 768, 256, 256, 300, 2, False),
+    "window-two-edges": (1024, 1024, 256, 256, 512, 4, False),
+    "window-small-blocks": (256, 256, 32, 64, 70, 4, False),
+    "segments": (512, 512, 256, 256, None, 4, True),
+    "segments-window": (512, 512, 128, 128, 200, 2, True),
+}
+
+
+def _halves_case(case):
+    Sq, Sk, bq, bk, window, Hk, segmented = _HALVES_CASES[case]
+    B, H, D = 1, 4, 16
+    ks = jax.random.split(jax.random.PRNGKey(50), 3)
+    q = jax.random.normal(ks[0], (B, Sq, H, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, Sk, Hk, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, Sk, Hk, D), jnp.float32)
+    kwargs = {} if window is None else {"window": window}
+    if segmented:
+        ids = np.sort(np.random.RandomState(4).randint(
+            0, 3, size=(B, max(Sq, Sk))), axis=1)
+        kwargs.update(q_segment_ids=jnp.asarray(ids[:, :Sq], jnp.int32),
+                      kv_segment_ids=jnp.asarray(ids[:, :Sk], jnp.int32))
+    return (q, k, v), kwargs, dict(block_q=bq, block_k=bk)
+
+
+def _out_and_grads(f, operands):
+    out, vjp = jax.vjp(f, *operands)
+    return (out, *vjp(2.0 * out))
+
+
+@pytest.mark.parametrize("case", sorted(_HALVES_CASES))
+def test_flash_by_halves_matches_oracle_and_the_whole_tiles(
+        monkeypatch, case):
+    """Output, ``dq``, ``dk`` and ``dv`` of calls that halve, of calls
+    the rule keeps whole and of windowed calls (the guard by row),
+    against the dense oracle at the tolerances that are there; and
+    where tiles are halved, against the same kernels with the halves
+    bypassed from here: the forward EQUAL bit for bit (it halves none),
+    the gradients within the float32 tolerance (a halved tile's sums
+    reassociate)."""
+    operands, kwargs, blocks = _halves_case(case)
+    Sq, Sk, bq, bk, window, _, segmented = _HALVES_CASES[case]
+    D = operands[0].shape[-1]
+
+    def f_flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, **blocks, **kwargs)
+
+    def f_ref(q, k, v):
+        return _xla_attention(q, k, v, 1.0 / D**0.5, True, **kwargs)
+
+    got = _out_and_grads(f_flash, operands)
+    tols = (2e-5, 5e-4, 5e-4, 5e-4)
+    for a, b, tol in zip(got, _out_and_grads(f_ref, operands), tols):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=tol,
+                                   atol=tol)
+
+    halved = fa.tile_census(Sq, Sk, bq, bk, True, window,
+                            segmented=segmented)["dq"]["halved"]
+    assert (halved > 0) == case.startswith("halved")
+    if not halved:
+        return
+    monkeypatch.setattr(fa, "_by_halves", lambda *a, **kw: False)
+    jax.clear_caches()
+    try:
+        whole = _out_and_grads(f_flash, operands)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(whole[0]))
+    for name, a, b, tol in zip(("dq", "dk", "dv"), got[1:], whole[1:],
+                               tols[1:]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=tol,
+                                   atol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("Sq,Sk,b,window", [
+    (768, 512, 256, 256), (768, 512, 256, 100), (256, 128, 32, 48),
+    (512, 256, 128, 128), (3072, 1024, 1024, 1024)])
+def test_a_row_past_every_key_under_a_window_yields_zeros(Sq, Sk, b, window):
+    """More queries than keys under a window: a late row's first visited
+    tile is one the window cuts and the row has no key in it — nor
+    anywhere, past ``Sk + window - 2`` — so its scores are all
+    ``_NEG_INF`` and ``exp(s - m)`` would be 1: the bodies with the
+    window's compare, by halves or whole, shift such a row by 0 in place
+    of its statistic (``_reached``; with segment ids the entries are
+    zeroed), which is what makes the row's output, and ``dq``, exactly
+    zero."""
+    B, H, D = 1, 2, 16
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (B, Sq, H, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, Sk, H, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, Sk, H, D), jnp.float32)
+    past = np.arange(Sq) - (window - 1) >= Sk
+    assert past.any() and not past.all()
+
+    def f(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=b, block_k=b)
+
+    out, dq, dk, dv = _out_and_grads(f, (q, k, v))
+    assert not np.asarray(out)[:, past].any()
+    assert not np.asarray(dq)[:, past].any()
+    ref = _xla_attention(q, k, v, 1.0 / D**0.5, True, window=window)
+    np.testing.assert_allclose(np.asarray(out)[:, ~past],
+                               np.asarray(ref)[:, ~past], rtol=2e-5,
+                               atol=2e-5)
+    assert np.isfinite(np.asarray(dk)).all() and np.asarray(dv).any()

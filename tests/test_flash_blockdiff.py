@@ -114,8 +114,11 @@ def test_the_census_counts_the_masks_own_tiles(L, B, bq, bk):
 
 def test_the_cells_geometry_is_eighty_tiles_of_the_rectangles_256():
     census = fa.tile_census(16384, 16384, 1024, 1024, True, None, (8192, 4))
+    # (56 of the 80 are interior — clean keys wholly before the queries'
+    # first block — and run without a compare; the walk halves none)
     assert census["fwd"] == {"block_q": 1024, "block_k": 1024, "live": 80,
-                             "visited": 80, "copied": 79}
+                             "visited": 80, "copied": 79, "cut": 24,
+                             "halved": 0}
     assert census["dkv"]["live"] == census["dkv"]["visited"] == 80
     assert fa.blockdiff_pairs(8192, 4) == 67_141_632
     # half of the causal triangle of the same 16,384 rows (and a block)

@@ -591,6 +591,8 @@ def test_the_blockdiff_scope_is_on_the_rows_ops():
     assert "flash-bwd-dq" not in under      # the backward is one pass
     # the flash calls under the scope carry the mask's census
     (census,) = table.tiles_within["attn-blockdiff"]["flash-fwd"]
-    assert census == fa.tile_census(2 * L, 2 * L, 16, 16, True, None,
-                                    (L, B))["fwd"]
+    # (the five fields the path spells; the sinks get ``cut`` and
+    # ``halved`` beside them)
+    whole = fa.tile_census(2 * L, 2 * L, 16, 16, True, None, (L, B))["fwd"]
+    assert census == {field: whole[field] for field in spans.TILE_FIELDS}
     assert census["live"] == census["visited"] == 8

@@ -141,6 +141,40 @@ def test_the_cells_backward_is_one_kernel_inside_the_limit(one_chip, cell):
     assert footprint < limit <= fa.VMEM_LIMIT_MAX
     used, = map(int, re.findall(config % "used_", call))
     assert used <= footprint
+    # (a 1024-edge triangle runs by halves, a 512-edge one whole)
+    tiles = fa.tile_census(S, S, b, b, True, window, blockdiff)["dq"]
+    assert tiles["halved"] == (
+        S // b if b == 1024 and blockdiff is None else 0)
+
+
+@pytest.mark.parametrize("cell", sorted(_CELLS_BACKWARD))
+def test_the_cells_forward_compiles_inside_the_default_vmem(one_chip, cell):
+    """The forward kernel compiled for the described v5e at the nine
+    cells' forward geometries, inside the default scoped VMEM — mellum's
+    windowed row with the guard by row (``_reached``) among them.  The
+    census beside it says which tiles an edge of the mask cuts; the
+    forward halves none.  (The backward's bodies, the halves of a
+    diagonal tile on slices of the same refs among them, compile in the
+    test above.)"""
+    BH, BHk, S, D, Dv, window, blockdiff = _CELLS_BACKWARD[cell]
+    b = fa.auto_block_size(S, D, jnp.bfloat16, "fwd", window=window,
+                           D_v=None if Dv == D else Dv, blockdiff=blockdiff)
+    assert b == 1024
+    assert fa._compiler_params(fa.flash_vmem_bytes(
+        b, b, D, 2, "fwd", False, Dv)) is None
+    _compile(one_chip, S=S, D=D, Dv=Dv, dtype=jnp.bfloat16, bq=b, bk=b,
+             which="fwd", window=window, BH=BH, BHk=BHk,
+             blockdiff=blockdiff)
+    tiles = fa.tile_census(S, S, b, b, True, window, blockdiff)["fwd"]
+    n = S // b
+    assert tiles["halved"] == 0
+    if blockdiff is not None:
+        assert tiles["cut"] == 24
+    elif window is not None:        # a diagonal and a far tile a q block
+        assert tiles["cut"] == 2 * n - 1 == tiles["live"]
+    else:
+        assert tiles["cut"] == n
+        assert tiles["live"] - tiles["cut"] == n * (n - 1) // 2
 
 
 def test_a_row_past_the_limit_compiles_as_the_two_kernels(one_chip):
