@@ -34,7 +34,13 @@ sparse-expert FFN with a shared expert; RMSNorm, an untied head) and
 rotary positions and a softmax top-k sparse-expert FFN with no shared
 expert; RMSNorm, an untied head — trained by block diffusion, which the
 table states once: every attention row sees a document's clean and
-noised copies through one block-causal mask).
+noised copies through one block-causal mask) and ``laguna`` (one
+full-attention row to three sliding-window rows whose SHAPES differ —
+the query heads a layer are a list — each with a sigmoid gate a head on
+the attention output, rotary positions on the whole head in a sliding
+row and YaRN-scaled on half of it in a full one; a leading dense SwiGLU
+FFN, then a sigmoid top-k sparse-expert FFN with an ungated shared
+expert; RMSNorm, an untied head).
 
 Plain frozen dataclasses: hashable, so a table is a static field of the
 flax module.
@@ -404,8 +410,9 @@ class LayerSpec:
     mla: Optional[MLASpec] = None      # attention rows: latent attention
                                        # (n_heads heads; qk_norm there is
                                        # over the nope part of a head)
-    head_gate: bool = False            # mla rows: out = W_o (attn *
-                                       # sigmoid(h W_g)), one number a head
+    head_gate: bool = False            # attention rows, plain or mla:
+                                       # out = W_o (attn * sigmoid(h W_g)),
+                                       # one number a head
     ssm: Optional[SSMSpec] = None      # mamba2 rows
     cca: Optional[CCASpec] = None      # cca rows
     gdn: Optional[GDNSpec] = None      # gdn rows
@@ -443,8 +450,10 @@ class LayerSpec:
                 "an mla row rotates, scales and gates by its MLASpec: "
                 "rotary_dim, yarn, out_gate, window, attn_scale and fewer "
                 "kv heads are a plain attention row's")
-        if self.head_gate and self.mla is None:
-            raise ValueError("head_gate is an mla row's")
+        if self.head_gate and self.out_gate:
+            raise ValueError("a row gates its attention output a head "
+                             "(head_gate) or a channel (out_gate), not "
+                             "both")
         if self.window is not None and self.window < 1:
             raise ValueError(f"window must be >= 1, got {self.window}")
         if self.yarn is not None and not self.rotary_dim:
@@ -544,10 +553,14 @@ def _first_layers(kinds, n_layers):
 def table_from_config(config: Mapping, n_layers: Optional[int] = None,
                       experts_held: Optional[Tuple[int, int]] = None
                       ) -> BlockTable:
-    """The table of a published ``config.json``, by its own keys.  Seven
+    """The table of a published ``config.json``, by its own keys.  Eight
     families are read, by ``model_type``: ``granitemoehybrid`` (its dense
     members: ``num_local_experts`` 0), ``nemotron_h``, ``zaya``,
-    ``qwen3_next``, ``mellum``, ``bailing_hybrid`` and ``sdar_moe``.  ``n_layers``
+    ``qwen3_next``, ``mellum``, ``bailing_hybrid``, ``sdar_moe`` and
+    ``laguna`` (the rows' query heads, windows and rotary positions a
+    layer, by ``num_attention_heads_per_layer``, ``layer_types`` and
+    ``rope_parameters``; a gate a head; dense then sparse FFNs by
+    ``mlp_layer_types``, with a shared expert).  ``n_layers``
     keeps the first so many layers (a pipeline stage, a cut to fit); None
     keeps ``num_hidden_layers``.  ``experts_held`` is the ``(first,
     count)`` of the published experts this rank holds in every expert
@@ -558,7 +571,7 @@ def table_from_config(config: Mapping, n_layers: Optional[int] = None,
                "nemotron_h": _nemotron_h_table, "zaya": _zaya_table,
                "qwen3_next": _qwen3_next_table, "mellum": _mellum_table,
                "bailing_hybrid": _bailing_hybrid_table,
-               "sdar_moe": _sdar_moe_table}
+               "sdar_moe": _sdar_moe_table, "laguna": _laguna_table}
     reader = readers.get(config.get("model_type"))
     if reader is None:
         raise ValueError(
@@ -823,6 +836,19 @@ def _qwen3_next_table(config, n_layers, experts_held):
         final_norm="rmsnorm_zc", norm_eps=eps, tied_head=False)
 
 
+def _yarn_of(rope):
+    """The :class:`YarnSpec` of one ``rope_parameters`` entry (None where
+    its ``rope_type`` is ``default``)."""
+    if rope.get("rope_type", "default") != "yarn":
+        return None
+    return YarnSpec(
+        factor=float(rope["factor"]),
+        original_max_position=int(rope["original_max_position_embeddings"]),
+        beta_fast=float(rope.get("beta_fast", 32.0)),
+        beta_slow=float(rope.get("beta_slow", 1.0)),
+        attention_factor=rope.get("attention_factor"))
+
+
 def _mellum_table(config, n_layers, experts_held):
     """``mellum``: layer ``i`` by ``layer_types[i]`` — a
     ``sliding_attention`` row sees ``sliding_window`` positions, a
@@ -873,21 +899,13 @@ def _mellum_table(config, n_layers, experts_held):
 
     def row(kind):
         rope = ropes[kind]
-        yarn = None
-        if rope.get("rope_type", "default") == "yarn":
-            yarn = YarnSpec(
-                factor=float(rope["factor"]),
-                original_max_position=int(
-                    rope["original_max_position_embeddings"]),
-                beta_fast=float(rope.get("beta_fast", 32.0)),
-                beta_slow=float(rope.get("beta_slow", 1.0)),
-                attention_factor=rope.get("attention_factor"))
         return LayerSpec(
             mixer="attention", norm="rmsnorm", ffn="experts", norm_eps=eps,
             n_heads=config["num_attention_heads"],
             n_kv_heads=config["num_key_value_heads"],
             d_head=config["head_dim"], rotary_dim=config["head_dim"],
-            rope_theta=float(rope["rope_theta"]), yarn=yarn, qk_norm=True,
+            rope_theta=float(rope["rope_theta"]), yarn=_yarn_of(rope),
+            qk_norm=True,
             window=(int(config["sliding_window"])
                     if kind == "sliding_attention" else None),
             experts=ExpertsSpec(
@@ -901,6 +919,124 @@ def _mellum_table(config, n_layers, experts_held):
         layers=tuple(rows[k] for k in _first_layers(kinds, n_layers)),
         positions="rotary", final_norm="rmsnorm", norm_eps=eps,
         tied_head=False)
+
+
+def _laguna_table(config, n_layers, experts_held):
+    """``laguna``: layer ``i`` by ``layer_types[i]`` — a
+    ``sliding_attention`` row sees ``sliding_window`` positions, a
+    ``full_attention`` row every earlier one — with
+    ``num_attention_heads_per_layer[i]`` query heads (where the list is
+    absent: ``num_attention_heads``) over ``num_key_value_heads``
+    key/value heads of ``head_dim``, so the rows of one table differ in
+    SHAPE; rotary positions as ``rope_parameters`` says for the row's
+    kind, on the first ``partial_rotary_factor`` of the head
+    (``default``: plain at ``rope_theta``; ``yarn``: :class:`YarnSpec`
+    over those dimensions); ``gating`` ``per-head``: the attention output
+    of every head times ``sigmoid(h W_g)``, one number a head
+    (``LayerSpec.head_gate``); no QK-norm (no key names one).  Layer
+    ``i``'s FFN by ``mlp_layer_types[i]`` (where absent: ``dense`` for
+    the layers of ``mlp_only_layers``, else ``sparse``): ``dense`` a
+    SwiGLU of ``intermediate_size``, ``sparse`` ``num_experts`` gated
+    experts of ``moe_intermediate_size``, ``num_experts_per_tok`` a token
+    by a sigmoid router with a correction bias on the choice, weights
+    over the chosen ones' sum times ``moe_routed_scaling_factor``, and an
+    ungated shared expert of ``shared_expert_intermediate_size``;
+    RMSNorm, an untied head.  Refused by key: a ``gating`` other than
+    ``per-head`` or a ``gating_types`` entry that differs, lists that do
+    not have ``num_hidden_layers`` entries or disagree with each other,
+    layer or FFN kinds it does not know, sliding rows without a
+    ``sliding_window``, another rope type, an untruncated YaRN ramp, a
+    ``partial_rotary_factor`` that leaves no even part of the head, a
+    ``decoder_sparse_step`` other than 1, ``norm_topk_prob`` false,
+    ``moe_apply_router_weight_on_input``, a router soft-cap, biases,
+    another activation, a tied head."""
+    n = config["num_hidden_layers"]
+    kinds = list(config["layer_types"])
+    heads = list(config.get("num_attention_heads_per_layer",
+                            [config["num_attention_heads"]] * n))
+    dense_at = set(config.get("mlp_only_layers") or ())
+    ffns = list(config.get(
+        "mlp_layer_types",
+        ["dense" if i in dense_at else "sparse" for i in range(n)]))
+    gates = list(config.get("gating_types", ["per_head"] * n))
+    ropes = config.get("rope_parameters", {})
+    d_head = config["head_dim"]
+
+    def rotary_dim(rope):
+        return int(d_head * rope.get("partial_rotary_factor", 1.0))
+
+    _refuse([
+        (config.get("gating") != "per-head",
+         f"gating {config.get('gating')!r} (only 'per-head')"),
+        (set(gates) - {"per_head"},
+         f"gating_types other than per_head: {sorted(set(gates))}"),
+        (any(len(x) != n for x in (kinds, heads, ffns, gates)),
+         "layer_types / num_attention_heads_per_layer / mlp_layer_types / "
+         "gating_types that do not list num_hidden_layers entries"),
+        (set(kinds) - {"sliding_attention", "full_attention"},
+         f"layer_types other than sliding_attention and full_attention: "
+         f"{sorted(set(kinds))}"),
+        (set(ffns) - {"dense", "sparse"},
+         f"mlp_layer_types other than dense and sparse: "
+         f"{sorted(set(ffns))}"),
+        ({i for i, f in enumerate(ffns) if f == "dense"} != dense_at
+         and "mlp_only_layers" in config,
+         "mlp_only_layers that disagrees with mlp_layer_types"),
+        (config.get("decoder_sparse_step", 1) != 1,
+         "decoder_sparse_step other than 1"),
+        ("sliding_attention" in kinds and not config.get("sliding_window"),
+         "sliding_attention rows without a sliding_window"),
+        (set(kinds) - set(ropes), "a layer type rope_parameters has no "
+         "entry for"),
+        (any(r.get("rope_type", "default") not in ("default", "yarn")
+             for r in ropes.values()),
+         "rope_type other than default and yarn"),
+        (not all(r.get("truncate", True) for r in ropes.values()),
+         "a yarn ramp that is not truncated to whole dimensions"),
+        (any(rotary_dim(r) < 2 or rotary_dim(r) % 2
+             or rotary_dim(r) > d_head for r in ropes.values()),
+         "a partial_rotary_factor that leaves no even part of a head"),
+        (not config.get("norm_topk_prob", True),
+         "norm_topk_prob false (router weights not renormalised over the "
+         "chosen)"),
+        (bool(config.get("moe_apply_router_weight_on_input")),
+         "moe_apply_router_weight_on_input true"),
+        (config.get("moe_router_logit_softcapping", 0) != 0,
+         "a non-zero moe_router_logit_softcapping"),
+        (bool(config.get("attention_bias")), "attention_bias"),
+        (bool(config.get("mlp_bias")), "mlp_bias"),
+        (config.get("hidden_act", "silu") != "silu",
+         "hidden_act other than silu"),
+        (bool(config.get("tie_word_embeddings")), "a tied output head"),
+    ])
+    eps = float(config["rms_norm_eps"])
+    kept = _first_layers(list(range(n)), n_layers)
+    sparse = dict(ffn="experts", experts=ExpertsSpec(
+        n_experts=config["num_experts"],
+        top_k=config["num_experts_per_tok"],
+        d_expert=config["moe_intermediate_size"],
+        d_shared=config.get("shared_expert_intermediate_size", 0),
+        held=experts_held,
+        scaling=float(config.get("moe_routed_scaling_factor", 1.0)),
+        router="sigmoid", expert="swiglu")) if any(
+            ffns[i] == "sparse" for i in kept) else None
+
+    def row(i):
+        rope = ropes[kinds[i]]
+        return LayerSpec(
+            mixer="attention", norm="rmsnorm", norm_eps=eps,
+            n_heads=heads[i], n_kv_heads=config["num_key_value_heads"],
+            d_head=d_head, rotary_dim=rotary_dim(rope),
+            rope_theta=float(rope["rope_theta"]), yarn=_yarn_of(rope),
+            window=(int(config["sliding_window"])
+                    if kinds[i] == "sliding_attention" else None),
+            head_gate=True,
+            **(sparse if ffns[i] == "sparse" else dict(
+                ffn="swiglu", d_ff=config["intermediate_size"])))
+
+    return BlockTable(
+        layers=tuple(row(i) for i in kept), positions="rotary",
+        final_norm="rmsnorm", norm_eps=eps, tied_head=False)
 
 
 #: The block length an ``sdar_moe`` table trains with where the config

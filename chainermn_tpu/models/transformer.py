@@ -126,6 +126,9 @@ class MultiHeadAttention(nn.Module):
     norm_eps: float = 1e-6          # its epsilon
     out_gate: bool = False          # ``query`` projects [q | gate] a head
                                     # and ``out`` takes attn * sigmoid(gate)
+    head_gate: bool = False         # ``out`` takes attn * sigmoid(gate(h)),
+                                    # ``gate`` one number a head (d_model x
+                                    # n_heads), as an mla row's
 
     @nn.compact
     def __call__(self, q_in, kv_in, mask=None, *, block_tables=None,
@@ -135,7 +138,7 @@ class MultiHeadAttention(nn.Module):
         d_head = self.d_head or self.d_model // self.n_heads
         n_kv = self.n_kv_heads or self.n_heads
         if (self.rotary_dim or self.qk_norm or self.out_gate
-                or self.window is not None
+                or self.head_gate or self.window is not None
                 or self.block_diffusion is not None) and (
                 self.decode or self.paged is not None):
             raise ValueError(
@@ -175,6 +178,10 @@ class MultiHeadAttention(nn.Module):
                 q = dense("query", self.n_heads)(q_in)
             k = dense("key", n_kv)(kv_in)
             v = dense("value", n_kv)(kv_in)
+        if self.head_gate:
+            with named_scope("mixer-gate"):
+                gate = nn.Dense(self.n_heads, dtype=self.dtype,
+                                use_bias=False, name="gate")(q_in)[..., None]
         if self.qk_norm or self.rotary_dim:
             with named_scope("attn-rope"):
                 if self.qk_norm:
@@ -1316,6 +1323,7 @@ class Block(nn.Module):
                     yarn=row.yarn, window=row.window,
                     qk_norm=row.norm if row.qk_norm else None,
                     norm_eps=row.norm_eps, out_gate=row.out_gate,
+                    head_gate=row.head_gate,
                     block_diffusion=self.block_diffusion,
                 )(h, h, mask, block_tables=block_tables, seq_lens=seq_lens,
                   positions=positions)

@@ -107,7 +107,8 @@ KERNEL_REGIONS = (
 #: ``dt`` softplus, ``-exp(A_log)``, the casts of ``y`` and the gate, ``y *
 #: silu(gate)`` and the gated norm; the Gated DeltaNet's ``beta``, ``g``,
 #: L2 norms and gated norm; a gated attention row's ``attn *
-#: sigmoid(gate)`` — and a dense FFN from ``wi`` to ``wo`` (``ffn``).  The region reading does not see these names (a
+#: sigmoid(gate)`` and, where the gate is one number a head of a plain
+#: row, its projection ``x W_g`` too — and a dense FFN from ``wi`` to ``wo`` (``ffn``).  The region reading does not see these names (a
 #: ``mixer-proj`` op inside ``mamba-mixer`` is still region
 #: ``mamba-mixer``); the OWNER reading of ``device_trace`` takes the
 #: innermost name of both tuples.

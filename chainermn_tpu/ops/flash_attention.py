@@ -1723,8 +1723,16 @@ def _publish_blockdiff(blockdiff, record) -> None:
            for field in ("live", "visited", "copied")}})
 
 
+def shape_key(heads: int, kv_heads: int, window: Optional[int]) -> str:
+    """The name a call's ROW SHAPE goes by in the published geometry:
+    its query and key/value heads and its window (0: none).  The rows of
+    one block table may differ in any of the three."""
+    return f"h{heads}-kv{kv_heads}-w{window or 0}"
+
+
 def _publish_geometry(fwd: dict, bwd: dict, blockdiff=None,
-                      resident_bytes: Optional[int] = None) -> None:
+                      resident_bytes: Optional[int] = None,
+                      shape: Optional[tuple] = None) -> None:
     """One record a :func:`flash_attention` call that reaches the kernels
     (at TRACE time: a jitted step publishes again only when retraced):
     the blocks each kernel was given and the tiles it finds live, visits
@@ -1738,7 +1746,14 @@ def _publish_geometry(fwd: dict, bwd: dict, blockdiff=None,
     ``bwd_resident_bytes``), ``flash/<kernel>/<field>`` gauges,
     ``flash/bwd_fused`` (1 or 0) and ``flash/bwd_resident_bytes`` gauges
     and the ``flash/calls`` and ``flash/bwd_fused_calls`` counters of the
-    Reporter."""
+    Reporter.  The ``flash/<kernel>/<field>`` gauges are the LAST traced
+    call's; ``shape`` (``(heads, kv_heads, window)``) says whose they are:
+    the row gains ``heads``, ``kv_heads``, ``group`` and ``window``, and
+    the same census goes a second time under the row shape's own name
+    (:func:`shape_key`), ``flash/shape/<key>/<kernel>/<field>`` gauges
+    with ``flash/shape/<key>/heads|kv_heads|group|window`` and a
+    ``flash/shape/<key>/calls`` counter, so that a step whose attention
+    rows differ in shape publishes every one of them."""
     fused = resident_bytes is not None
     record = {"flash-fwd": fwd}
     if fused:
@@ -1746,10 +1761,15 @@ def _publish_geometry(fwd: dict, bwd: dict, blockdiff=None,
     else:
         record.update({"flash-bwd-dq": bwd["dq"],
                        "flash-bwd-dkv": bwd["dkv"]})
+    row = {}
+    if shape is not None:
+        heads, kv_heads, window = shape
+        row = {"heads": heads, "kv_heads": kv_heads,
+               "group": heads // kv_heads, "window": window or 0}
     rec = _step_log.current_recorder()
     if rec is not None:
         rec.record("flash_geometry", **record, bwd_fused=fused,
-                   bwd_resident_bytes=resident_bytes or 0)
+                   bwd_resident_bytes=resident_bytes or 0, **row)
     rep = _reporter.get_reporter()
     if rep is not None:
         rep.count("flash/calls")
@@ -1757,9 +1777,16 @@ def _publish_geometry(fwd: dict, bwd: dict, blockdiff=None,
             rep.count("flash/bwd_fused_calls")
         rep.gauge("flash/bwd_fused", int(fused))
         rep.gauge("flash/bwd_resident_bytes", resident_bytes or 0)
-        for kernel, tiles in record.items():
-            for field, value in tiles.items():
-                rep.gauge(f"flash/{kernel}/{field}", value)
+        prefixes = ["flash"]
+        if shape is not None:
+            prefixes.append(f"flash/shape/{shape_key(*shape)}")
+            rep.count(f"{prefixes[1]}/calls")
+            for field, value in row.items():
+                rep.gauge(f"{prefixes[1]}/{field}", value)
+        for prefix in prefixes:
+            for kernel, tiles in record.items():
+                for field, value in tiles.items():
+                    rep.gauge(f"{prefix}/{kernel}/{field}", value)
     if blockdiff is not None:
         _publish_blockdiff(blockdiff, record)
 
@@ -1968,6 +1995,7 @@ def flash_attention(
                         segmented),
             blockdiff,
             bwd_resident_bytes(Sk, D, Dv) if fused else None,
+            (H, Hk, window),
         )
 
     # (B, S, H, D) → (B*H, S, D); kv keep their own (possibly smaller)

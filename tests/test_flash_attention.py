@@ -596,6 +596,36 @@ def test_flash_window_composes_with_gqa_and_segments():
         )
 
 
+@pytest.mark.parametrize("H,Hk", [(18, 2), (12, 2)])
+@pytest.mark.parametrize("window,block", [(32, 32), (32, 16), (40, 32)])
+def test_flash_window_at_gqa_groups_of_nine_and_six(H, Hk, window, block):
+    """The ``laguna`` rows' shapes in small: 9 and 6 query head rows to a
+    key/value row (neither a power of two) under a window as wide as the
+    tile's edge (fill 1/2: a diagonal tile and a far tile a query block),
+    twice it, and off it — output and all three gradients against
+    ``_xla_attention``'s dense masked softmax."""
+    xla = importlib.import_module(
+        "chainermn_tpu.ops.flash_attention")._xla_attention
+    q, k, v = make_gqa(B=1, S=128, H=H, Hk=Hk, D=16, seed=H)
+    scale = 0.25
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, window=window, block_q=block, block_k=block,
+        scale=scale)
+    dense = lambda q, k, v: xla(  # noqa: E731
+        q, k, v, scale, True, window=window)
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v),
+                               rtol=2e-5, atol=2e-5)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=5e-4, atol=5e-4)
+
+
 def test_flash_window_fallback_and_validation():
     # Unaligned shapes route to the XLA fallback with the same band.
     q, k, v = make_qkv(S=100)
@@ -660,6 +690,8 @@ def test_auto_block_size_divides_aligns_and_fits(D, dtype, which, segmented):
     (16384, 2048, 1024, 1024),
     (16384, 768, 512, 512),      # never under 512 for the fill's sake
     (16384, 512, 512, 512),
+    (8192, 512, 512, 512),       # laguna-train-1chip's sliding rows
+    (4096, 512, 512, 512),
     (2048, 300, 256, 256),       # nor wider than the band
     (2048, 64, 128, 128),
 ])
@@ -894,6 +926,24 @@ def test_tile_census_at_the_windowed_cells_shape():
     assert (full["live"], full["visited"], full["copied"]) == (136, 256, 135)
     half = tile_census(16384, 16384, 512, 512, True, 1024)["fwd"]
     assert (half["live"], half["visited"]) == (93, 96)
+
+
+def test_tile_census_at_the_narrow_windowed_cells_shape():
+    """``laguna-train-1chip``'s sliding row, S = 8,192 under a window of
+    512 at the rule's 512 x 512, forward and backward: 31 live tiles a
+    head row (a diagonal tile and a far tile a query block, every one
+    cut by an edge of the band) in a grid of 16 x 2 steps: the band's
+    pairs fill half of the tiles' area."""
+    from chainermn_tpu.ops.flash_attention import tile_census
+
+    band = tile_census(8192, 8192, 512, 512, True, 512)
+    for kernel in ("fwd", "dq", "dkv"):
+        t = band[kernel]
+        assert (t["live"], t["visited"], t["cut"]) == (31, 32, 31)
+    pairs = 512 * 513 // 2 + (8192 - 512) * 512
+    assert 0.50 < pairs / (31 * 512 * 512) < 0.51
+    full = tile_census(8192, 8192, 1024, 1024, True, None)["fwd"]
+    assert (full["live"], full["visited"]) == (36, 64)
 
 
 def test_tile_census_at_the_benchmark_shape():
@@ -1190,6 +1240,14 @@ def test_flash_geometry_record_reaches_the_sinks(tmp_path):
     assert "flash-bwd-dq" not in rows[0]
     assert rows[0]["bwd_fused"] is True
     assert rows[0]["flash-bwd-dkv"] == rows[0]["flash-fwd"]
+    # and whose it is: the row's shape, under which the census goes a
+    # second time (a step's rows may differ in shape)
+    assert {f: rows[0][f] for f in (
+        "heads", "kv_heads", "group", "window")} == {
+            "heads": 2, "kv_heads": 2, "group": 1, "window": 0}
+    assert summary["counters"]["flash/shape/h2-kv2-w0/calls"] == 1
+    assert gauges["flash/shape/h2-kv2-w0/flash-fwd/live"] == 6
+    assert gauges["flash/shape/h2-kv2-w0/heads"] == 2
 
 
 #: name -> (BH, BHk, Sq, Sk, D, D_v, block_q, block_k, causal, window,

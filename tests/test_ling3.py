@@ -220,7 +220,10 @@ def test_a_clamp_on_a_layer_that_is_not_kept_is_carried():
     (dict(mixer="mamba2", mla=MLASpec(8, 8, 4, 8)), "SSMSpec|attention"),
     (dict(mla=MLASpec(8, 8, 4, 8), rotary_dim=4), "MLASpec"),
     (dict(mla=MLASpec(8, 8, 4, 8), n_kv_heads=1, n_heads=2), "MLASpec"),
-    (dict(head_gate=True), "head_gate"),
+    # (a gate a head is a plain attention row's too since PR 51: what a
+    # row may not have is both gates, or the gate without attention)
+    (dict(head_gate=True, out_gate=True), "head_gate"),
+    (dict(mixer="kda", head_gate=True), "KDASpec|head_gate"),
     (dict(ffn="experts", experts=dict(n_group=3)), "router groups"),
     (dict(ffn="experts", experts=dict(n_group=4, topk_group=5)),
      "router groups"),
