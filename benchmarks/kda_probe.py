@@ -1,32 +1,45 @@
 #!/usr/bin/env python
-"""The Kimi-Delta-Attention rule alone, on the chip: device time a call of
-its forward and of its backward at the ``ling3flash-train-1chip`` cell's
-geometry, for the two Mosaic kernels (``ops.kda.kda_rule``: ``kda-fwd`` /
-``kda-bwd``) and for the XLA chunked form they replaced (PR 44), which
-lives on here as the comparison: every decay a float32 array in HBM, the
-solve by substitution on 16-row blocks joined pairwise with the batch on
-the lanes (:func:`unit_lower_inverse`, which ``gdn_probe.py``'s XLA form
-runs too), a ``lax.scan`` step a chunk, the heads in rematerialised
-groups under ``lax.map``, autodiff's backward.
+"""The Kimi-Delta-Attention rule WITH its gate side, on the chip: device
+time a call of its forward and of its backward at the
+``ling3flash-train-1chip`` cell's geometry, from what ``KDAMixer``'s
+convolution and projections hand over (``q``, ``k``, ``v``, ``f`` in
+bfloat16, ``beta``, ``A_log``, ``dt_bias``) to ``o`` and back, for
 
-Each is compiled at ``(1, 16384, 32 heads of 128, chunk 64)`` in bfloat16
-with ``g`` in float32, forward and vjp apart, the operands' layouts left
-to the compiler as inside a step.  Each program runs ``--calls`` times
-inside one profiler capture and is read by DEVICE time
-(``observability.device_trace``), with its largest ops; then the kernels'
-results against the XLA form's on this device, by operand (``o``, ``dq``,
-``dk``, ``dv``, ``dg``, ``dbeta``, each over the XLA form's largest).
+- ``kernel``: the tree's ``ops.kda.kda_rule``.  Since PR 52 its two
+  Mosaic kernels (``kda-fwd`` / ``kda-bwd``) make the heads' float32 side
+  themselves (``gate_side: "kernel"``).  Run from an older checkout
+  (``PYTHONPATH=<checkout>``) the same row is that tree's form: the gate
+  side as XLA ops beside the calls (:func:`gate_side`, ``KDAMixer``'s
+  formulas until PR 52), the running sums a product with the triangle
+  over a float32 array, then the kernels (``gate_side: "xla"``);
+- ``xla``: the XLA chunked form the kernels replaced (PR 44), behind the
+  same XLA gate side, which lives on here as the comparison: every decay
+  a float32 array in HBM, the solve by substitution on 16-row blocks
+  joined pairwise with the batch on the lanes
+  (:func:`unit_lower_inverse`, which ``gdn_probe.py``'s XLA form runs
+  too), a ``lax.scan`` step a chunk, the heads in rematerialised groups
+  under ``lax.map``, autodiff's backward.
+
+Each is compiled at ``(1, 16384, 32 heads of 128, chunk 64)``, forward
+and vjp apart, the operands' layouts left to the compiler as inside a
+step.  Each program runs ``--calls`` times inside one profiler capture
+and is read by DEVICE time (``observability.device_trace``), with its
+largest ops; then the kernels' results against the XLA form's on this
+device, by operand (``o``, ``dq``, ``dk``, ``dv``, ``df``, ``dbeta``,
+``dA_log``, ``ddt_bias``, each over the XLA form's largest).
 
     chiprun -- env PYTHONPATH=. python benchmarks/kda_probe.py \
         --out chiprun_out/kda_probe.json
 
 About two minutes on one chip.  Off the chip the kernels run interpreted
 and the capture has no device plane: rows without times (use ``--seq 256
---heads 2`` there).  PERF.md section 6 (PR 44) rests on this table.
+--heads 2`` there).  PERF.md section 6 (PR 44, PR 52) rests on this
+table.
 """
 
 import argparse
 import functools
+import inspect
 import json
 import os
 
@@ -219,6 +232,46 @@ def xla_rule(q, k, v, g, beta, *, chunk):
         return jnp.moveaxis(o, 0, 2).reshape(b, S, H, dv)
 
 
+def gate_side(q, k, f, a_log, dt_bias, floor):
+    """``KDAMixer``'s gate side before the rule as XLA ops, as it stood
+    until PR 52: float32 unit norms of ``q`` (over ``sqrt(d_k)``) and
+    ``k`` rounded to the activations' type, and the float32 log-decay a
+    token, head and channel."""
+    f32 = jnp.float32
+    with named_scope("mixer-gate"):
+        def unit(x):
+            x = x.astype(f32)
+            return x * lax.rsqrt(
+                jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+        g = floor * jax.nn.sigmoid(
+            jnp.exp(a_log)[:, None] * (f.astype(f32) + dt_bias))
+        return ((unit(q) * (1.0 / np.sqrt(q.shape[-1]))).astype(q.dtype),
+                unit(k).astype(k.dtype), g)
+
+
+#: Whether the tree's kernels make the gate side themselves (PR 52 on).
+IN_KERNEL = "f" in inspect.signature(kda.kda_rule).parameters
+
+
+def kernel_form(q, k, v, f, beta, a_log, dt_bias, *, floor, chunk):
+    """The tree's rule from the mixer's operands."""
+    if IN_KERNEL:
+        return kda.kda_rule(q, k, v, f, beta, jnp.exp(a_log), dt_bias,
+                            lower_bound=floor, chunk=chunk)
+    q, k, g = gate_side(q, k, f, a_log, dt_bias, floor)
+    return kda.kda_rule(q, k, v, g, beta, chunk=chunk)
+
+
+def xla_form(q, k, v, f, beta, a_log, dt_bias, *, floor, chunk):
+    q, k, g = gate_side(q, k, f, a_log, dt_bias, floor)
+    return xla_rule(q, k, v, g, beta, chunk=chunk)
+
+
+#: The forms' results in order: ``o``, then the seven cotangents.
+RESULTS = ("o", "dq", "dk", "dv", "df", "dbeta", "dA_log", "ddt_bias")
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=1)
@@ -251,33 +304,30 @@ def main():
 
     rng = np.random.RandomState(0)
     bf16, f32 = jnp.bfloat16, jnp.float32
-
-    def unit(x):
-        return x / np.sqrt(np.sum(np.square(x), axis=-1, keepdims=True)
-                           + 1e-6)
-
+    # what a convolution with SiLU and a projection hand over: no unit
+    # lengths; the family's initial ``A_log`` and ``dt_bias`` ranges
     operands = (
-        jnp.asarray(unit(rng.randn(b, S, H, d)) / np.sqrt(d), bf16),
-        jnp.asarray(unit(rng.randn(b, S, H, d)), bf16),
         jnp.asarray(rng.randn(b, S, H, d), bf16),
-        jnp.asarray(args.floor / (1 + np.exp(-2 * rng.randn(b, S, H, d))),
-                    f32),
-        jnp.asarray(1 / (1 + np.exp(-3 * rng.randn(b, S, H))), f32))
+        jnp.asarray(rng.randn(b, S, H, d), bf16),
+        jnp.asarray(rng.randn(b, S, H, d), bf16),
+        jnp.asarray(2 * rng.randn(b, S, H, d), bf16),
+        jnp.asarray(1 / (1 + np.exp(-3 * rng.randn(b, S, H))), f32),
+        jnp.asarray(np.log(rng.uniform(1, 16, H)), f32),
+        jnp.asarray(rng.randn(H, d), f32))
     do = jnp.asarray(rng.randn(b, S, H, d), bf16)
 
     def vjp_of(rule):
-        return lambda *a: jax.vjp(
-            functools.partial(rule, chunk=chunk), *a[:-1])[1](a[-1])
+        return lambda *a: jax.vjp(rule, *a[:-1])[1](a[-1])
 
-    forms = {"kernel": kda.kda_rule}
+    forms = {"kernel": kernel_form}
     if not args.no_xla:
-        forms["xla"] = xla_rule
+        forms["xla"] = xla_form
     programs = {}
     for form, rule in forms.items():
-        programs[f"{form}.forward"] = compiled(
-            functools.partial(rule, chunk=chunk), operands, 1)
+        rule = functools.partial(rule, floor=args.floor, chunk=chunk)
+        programs[f"{form}.forward"] = compiled(rule, operands, 1)
         programs[f"{form}.backward"] = compiled(
-            vjp_of(rule), operands + (do,), 5)
+            vjp_of(rule), operands + (do,), len(operands))
     tokens, heads, vmem = kda.kda_tiles(S, chunk, H, d, d, bf16)
     rows = []
     for name, timed in device_ms(programs, args.calls).items():
@@ -288,7 +338,8 @@ def main():
                    c.memory_analysis().temp_size_in_bytes / 1e6, 1),
                **timed}
         if name.startswith("kernel"):
-            row.update(tokens_a_step=tokens, heads_a_step=heads,
+            row.update(gate_side="kernel" if IN_KERNEL else "xla",
+                       tokens_a_step=tokens, heads_a_step=heads,
                        vmem_mb=round(vmem / 2**20, 2),
                        grid_steps=b * H * (-(-S // tokens)))
         rows.append(row)
@@ -302,18 +353,19 @@ def main():
 
     gaps = None
     if not args.no_xla:
-        gaps = [float(np.abs(got - want).max()
-                      / max(np.abs(want).max(), 1e-30))
-                for got, want in zip(
-                    ran("kernel.forward") + ran("kernel.backward"),
-                    ran("xla.forward") + ran("xla.backward"))]
-        print(json.dumps({"gap_o_dq_dk_dv_dg_dbeta": gaps}), flush=True)
+        gaps = {
+            name: float(np.abs(got - want).max()
+                        / max(np.abs(want).max(), 1e-30))
+            for name, got, want in zip(
+                RESULTS, ran("kernel.forward") + ran("kernel.backward"),
+                ran("xla.forward") + ran("xla.backward"))}
+        print(json.dumps({"gaps_to_xla": gaps}), flush=True)
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:
             json.dump({"device": jax.devices()[0].device_kind,
-                       "rows": rows, "gap_o_dq_dk_dv_dg_dbeta": gaps},
-                      f, indent=1)
+                       "gate_side": "kernel" if IN_KERNEL else "xla",
+                       "rows": rows, "gaps_to_xla": gaps}, f, indent=1)
 
 
 if __name__ == "__main__":

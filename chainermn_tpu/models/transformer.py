@@ -1075,17 +1075,19 @@ class KDAMixer(nn.Module):
     ``[q | k | v | f] = in_proj_qkvf(h)``, ``[b | a] = in_proj_bg(h)``; a
     causal depthwise convolution and SiLU over ``[q | k | v]``, no bias
     (:func:`chainermn_tpu.ops.ssd.causal_conv_silu`, the Mamba-2 mixers'
-    kernels); per head ``q <- q / |q| / sqrt(d_k)``, ``k <- k / |k|``
-    with ``|x| = sqrt(sum x^2 + 1e-6)``; ``beta = sigmoid(b)`` a head; the log-decay
-    a head and KEY CHANNEL ``g = lower_bound * sigmoid(exp(A_log) * (f +
-    dt_bias))``, float32, in ``(lower_bound, 0)``; the chunked rule
+    kernels); ``beta = sigmoid(b)`` a head; the chunked rule
     (:func:`chainermn_tpu.ops.kda.kda_rule`: two Mosaic kernels whose
-    grid walks the heads); per head ``RMSNorm(o) * sigmoid(a)`` with one
-    plain scale a channel of a head and ONE gate a head; ``out_proj``.
-    Every sequence starts from a zero state.  The gate side is float32
-    arithmetic whose results are ``q``, ``k`` and ``y`` in the
-    activations' type: of every head's float32 numbers only ``g`` (and
-    its cotangent) stands as an array."""
+    grid walks the heads), whose kernels make the heads' float32 side
+    themselves, in VMEM, from the convolution's ``q``, ``k`` and the
+    projection's ``f`` — per head ``q <- q / |q| / sqrt(d_k)``, ``k <- k
+    / |k|`` with ``|x| = sqrt(sum x^2 + 1e-6)``, and the log-decay a head
+    and KEY CHANNEL ``g = lower_bound * sigmoid(exp(A_log) * (f +
+    dt_bias))`` in ``(lower_bound, 0)`` with its running sums — and take
+    the cotangents back through it; per head ``RMSNorm(o) * sigmoid(a)``
+    with one plain scale a channel of a head and ONE gate a head;
+    ``out_proj``.  Every sequence starts from a zero state.  The output
+    side is float32 arithmetic whose result is ``y`` in the activations'
+    type: of every head's float32 numbers none stands as an array."""
 
     d_model: int
     kda: KDASpec
@@ -1117,19 +1119,14 @@ class KDAMixer(nn.Module):
             a_log = self.param("A_log", _a_log_init, (H,))
             dt_bias = self.param("dt_bias", _dt_bias_init, (z.key_dim,))
             with named_scope("mixer-gate"):
-                def unit(x):
-                    x = x.astype(f32).reshape(lead + (H, z.d_k))
-                    return x * jax.lax.rsqrt(jnp.sum(
-                        jnp.square(x), axis=-1, keepdims=True) + 1e-6)
-
-                q = (unit(q) * (1.0 / np.sqrt(z.d_k))).astype(self.dtype)
-                k = unit(k).astype(self.dtype)
                 beta = jax.nn.sigmoid(b.astype(f32))
-                g = z.lower_bound * jax.nn.sigmoid(
-                    jnp.exp(a_log)[:, None] * (f.astype(f32) + dt_bias
-                                               ).reshape(lead + (H, z.d_k)))
-            o = kda_rule(q, k, v.reshape(lead + (H, z.d_v)), g, beta,
-                         chunk=z.chunk)
+                rate = jnp.exp(a_log)
+            by_head = lead + (H, z.d_k)
+            o = kda_rule(
+                q.reshape(by_head), k.reshape(by_head),
+                v.reshape(lead + (H, z.d_v)), f.reshape(by_head), beta,
+                rate, dt_bias.reshape(H, z.d_k),
+                lower_bound=z.lower_bound, chunk=z.chunk)
             with named_scope("mixer-gate"):
                 o = o.astype(f32)
                 o = o * jax.lax.rsqrt(
@@ -1689,8 +1686,12 @@ def remat_names():
     kernel call of 6.3 ms, +0.054 GB on the compiled step and 5% of the
     measured one: PERF.md section 6, PR 37) and, the same for a
     Kimi-Delta-Attention row, ``kda.KDA_RESIDUALS`` (201 MB a layer of
-    the ``ling3flash`` cell for a forward kernel call of 10.1 ms and the
-    running sums beside it: PERF.md section 6, PR 44).  A name no layer
+    the ``ling3flash`` cell for a forward kernel call that makes the
+    heads' norms, decay and running sums too: PERF.md section 6, PR 44
+    and PR 52; what the row recomputes is its projections and its
+    convolution, whose ``q``, ``k``, ``v``, ``f`` the backward kernel
+    reads in the activations' type — no float32 gate side is run again,
+    nothing of it was ever kept).  A name no layer
     of a table emits is harmless.  NOT kept, each 20 to 100 times dearer a byte than
     flash's 67 MB for 9.7 ms a step (granite, PERF.md section 6, PR 35):
     the scan's ``y`` and block starts (268 MB a layer for 0.7 ms), the
@@ -1736,7 +1737,10 @@ def remat_kept(table: BlockTable, d_model: int, tokens: int, itemsize: int,
 
     def delta_rule_bytes(z, tile, n_heads):
         """A delta rule's ``o`` and the float32 state each tile of
-        ``tile`` tokens started from, ``n_heads`` value heads."""
+        ``tile`` tokens started from, ``n_heads`` value heads (a KDA
+        row's kernels take ``q``, ``k``, ``f`` as its convolution and
+        projection hand them over and make the float32 side in VMEM:
+        the names keep nothing of it, and the row recomputes none)."""
         chunk = min(z.chunk, seq)
         tiles = tokens // seq * (-(-seq // chunk) * chunk // tile)
         return n_heads * z.d_v * (tokens * itemsize + tiles * z.d_k * 4)

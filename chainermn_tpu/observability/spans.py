@@ -76,8 +76,9 @@ _ALLREDUCE_STAGE = re.compile(r"^grad-stage\d+$")
 #: output projection (``kda-mixer``: its three convolutions are
 #: ``ssm-conv``, the same kernels) and, nested in it, the chunked delta
 #: rule under a decay a key channel, forward and backward (``kda-scan``,
-#: ``ops/kda.py``: the kernels ``kda-fwd`` and ``kda-bwd`` and the
-#: running sums beside them); a latent-attention (MLA) row from its query and
+#: ``ops/kda.py``: the kernels ``kda-fwd`` and ``kda-bwd``, which make the
+#: heads' norms, decay and running sums themselves, and the bfloat16
+#: transposes beside them); a latent-attention (MLA) row from its query and
 #: latent projections to its output projection (``mla-mixer``, inside the
 #: row's ``attn-mixer``: the flash regions and ``attn-rope`` nest in it).
 #: The innermost name of THIS tuple (or of an allreduce stage) on an op's

@@ -606,7 +606,8 @@ def test_remat_kept_for_kda_rows_is_what_the_kept_names_hold():
     q = jnp.zeros((2, 64, z.n_heads, z.d_k), jnp.float32)
     o, starts = jax.eval_shape(
         lambda q, v, beta: kda._kda_fwd_call(
-            q, q, v, q, beta, C=z.chunk, keep=True, interpret=True),
+            q, q, v, q, beta, beta[0, 0], q[0, 0], C=z.chunk,
+            floor=z.lower_bound, keep=True, interpret=True),
         q, jnp.zeros((2, 64, z.n_heads, z.d_v), jnp.float32),
         jnp.zeros((2, 64, z.n_heads), jnp.float32))
     held = sum(x.size * x.dtype.itemsize for x in (o, starts))
@@ -635,9 +636,10 @@ def test_a_checkpoint_without_the_name_would_run_the_forward_twice():
 
     q = jnp.ones((1, 32, 1, 8)) / 4
 
-    def loss(q, g):
-        return jnp.sum(kda_rule(q, q, q, g, jnp.ones((1, 32, 1)) / 2,
-                                chunk=16))
+    def loss(q, f):
+        return jnp.sum(kda_rule(q, q, q, f, jnp.ones((1, 32, 1)) / 2,
+                                jnp.ones((1,)), jnp.zeros((1, 8)),
+                                lower_bound=-5.0, chunk=16))
 
     for policy, fwd_calls in ((remat_policy(), 1), (None, 2)):
         jaxpr = jax.make_jaxpr(jax.grad(jax.checkpoint(
@@ -664,8 +666,12 @@ def test_the_kda_layer_says_its_kernels_geometry_when_someone_listens(
     assert gauges["kda/tokens_a_step"] == 32
     assert gauges["kda/heads_a_step"] == 2          # the heads pair up
     assert gauges["kda/grid_steps"] == 2 and gauges["kda/vmem_bytes"] > 0
+    # q, k, f, v in float32 and the beta row, two heads of 32 tokens
+    assert gauges["kda/operand_bytes_a_step"] == 2 * 32 * (
+        4 * (3 * 16 + gauges["kda/d_v"]) + 4)
     rows = {r["event"]: r for r in map(json.loads, open(path))}
     assert rows["kda_geometry"]["form"] == "kernel"
+    assert rows["kda_geometry"]["gate_side"] == "kernel"
 
 
 def test_the_latent_row_through_flash_is_the_dense_path():

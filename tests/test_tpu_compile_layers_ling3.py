@@ -27,22 +27,39 @@ def _copies(text):
     return found
 
 
+def _standing(text, at_least):
+    """``(type, line)`` of every float array of ``at_least`` bytes that
+    STANDS in the compiled program: a result (or a tuple's part) of an
+    instruction of the entry computation — what lies inside a fusion is
+    registers, not an array."""
+    entry = text[text.index("\nENTRY "):]
+    found = []
+    for line in entry[:entry.index("\n}")].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) [\w-]+\(", line)
+        for kind, dims in re.findall(
+                r"\b(bf16|f32)\[([\d,]+)\]", m.group(1) if m else ""):
+            if (2 if kind == "bf16" else 4) * math.prod(
+                    int(d) for d in dims.split(",")) >= at_least:
+                found.append((kind, line))
+    return found
+
+
 def test_kda_mixer_compiles_at_the_cells_shape(one_chip, monkeypatch):
     """One KDA mixer at the cell's shape (1 x 16,384 tokens, 32 heads of
     128), forward and backward under remat with the model's policy as in
     the step: the convolution's three Mosaic calls and the rule's two
     (``kda-fwd`` ONCE, keeping ``o`` and the tiles' states under the
     policy's ``KDA_RESIDUALS``, and ``kda-bwd``), every head in one call
-    and no loop left.  The gate side hands the rule ``q`` and ``k`` in
-    bfloat16: of every head's float32 numbers only ``g`` and its
-    cotangent (and their running sums) stand as arrays."""
+    and no loop left.  The mixer hands the rule the convolution's ``q``,
+    ``k`` and the projection's ``f`` in bfloat16 and the kernels make the
+    heads' float32 side in VMEM: no float32 array a token, head and
+    channel stands anywhere in the program."""
     from chainermn_tpu.models.block_table import KDASpec
     from chainermn_tpu.models.transformer import KDAMixer, remat_policy
 
-    for name in ("ssd", "kda"):
-        monkeypatch.setattr(
-            importlib.import_module(f"chainermn_tpu.ops.{name}"),
-            "default_interpret", lambda: False)
+    kd = importlib.import_module("chainermn_tpu.ops.kda")
+    for module in (importlib.import_module("chainermn_tpu.ops.ssd"), kd):
+        monkeypatch.setattr(module, "default_interpret", lambda: False)
     d_model = 2560
     mixer = KDAMixer(d_model, KDASpec(32, 128, 128), 1e-6, jnp.bfloat16)
     params = jax.tree.map(
@@ -66,17 +83,19 @@ def test_kda_mixer_compiles_at_the_cells_shape(one_chip, monkeypatch):
     assert calls == {"kda-fwd": 1, "kda-bwd": 1}
     assert text.count("tpu_custom_call") == 5 and " while(" not in text
     assert "kda-scan" in text and "kda-mixer" in text
-    # a head-wide float32 array is 268 MB.  The gate side writes ``g`` with
-    # the tokens on the lanes, as the kernels read it; the three copies
-    # that stand re-tile ``g`` (forward, and again for the backward call)
-    # and ``dG`` for the running sums' product with the triangle — no
-    # float32 copy of ``q``, ``k`` or ``o`` stands
+    # the rule's own tile, inside the default scoped VMEM (the calls ask
+    # for no limit of their own): eight chunks of two heads
+    tokens, heads, vmem = kd.kda_tiles(16384, 64, 32, 128, 128, jnp.bfloat16)
+    assert (tokens, heads) == (512, 2) and vmem <= fa.VMEM_SCOPED_DEFAULT
+    # a head-wide float32 array is 268 MB: ``g``, its running sums and
+    # their cotangents live in the kernels' VMEM only
     whole = 16384 * 32 * 128 * 4
-    standing = [line for size, line in _copies(text)
-                if size >= whole and "f32[" in line]
-    assert len(standing) <= 3, standing
-    # read: 2.58 GB (the XLA form in groups of four heads: 2.61)
-    assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
+    assert [kind for kind, _ in _standing(text, whole // 2)].count(
+        "bf16") > 8                         # q, k, v, f, o and cotangents
+    assert not [line for kind, line in _standing(text, whole)
+                if kind == "f32"]
+    # read: 1.63 GB (2.58 with the gate side beside the calls)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
 
 
 def test_mla_row_compiles_at_the_cells_shape(one_chip, monkeypatch):

@@ -174,10 +174,12 @@ def test_delta_rule_kernels_compile_at_the_cells_geometry(one_chip, which):
 def test_kda_kernels_compile_at_the_cells_geometry(one_chip, which):
     """``kda-fwd`` and ``kda-bwd`` at the ``ling3flash-train-1chip``
     cell's full geometry (1 x 16,384 tokens, 32 heads of 128, chunk 64,
-    bfloat16 with ``g`` in float32): ONE Mosaic call a pass inside the
-    default scoped VMEM, at the tile ``kda_tiles`` gives (eight chunks of
-    two heads) — the calls ask for no limit of their own — and no loop
-    beside it (the running sums are products with the triangle)."""
+    ``q``, ``k``, ``v``, ``f`` in bfloat16: the heads' float32 side is
+    made in the kernels): ONE Mosaic call a pass inside the default
+    scoped VMEM, at the tile ``kda_tiles`` gives (eight chunks of two
+    heads) — the calls ask for no limit of their own — no loop beside it
+    and no float32 array a token, head and channel: the norms, the decay
+    and its running sums never leave VMEM."""
     kd = importlib.import_module("chainermn_tpu.ops.kda")
     b, S, H, d, chunk = 1, 16384, 32, 128, 64
 
@@ -185,13 +187,14 @@ def test_kda_kernels_compile_at_the_cells_geometry(one_chip, which):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     operands = (arr(b, S, H, d), arr(b, S, H, d), arr(b, S, H, d),
-                arr(b, S, H, d, dt=jnp.float32),
-                arr(b, S, H, dt=jnp.float32))
+                arr(b, S, H, d), arr(b, S, H, dt=jnp.float32),
+                arr(H, dt=jnp.float32), arr(H, d, dt=jnp.float32))
     if which == "fwd":
-        call = functools.partial(kd._kda_fwd_call, C=chunk, keep=True,
-                                 interpret=False)
+        call = functools.partial(kd._kda_fwd_call, C=chunk, floor=-5.0,
+                                 keep=True, interpret=False)
     else:
-        call = functools.partial(kd._kda_bwd_call, C=chunk, interpret=False)
+        call = functools.partial(kd._kda_bwd_call, C=chunk, floor=-5.0,
+                                 interpret=False)
         operands += (arr(b, H, S // 512, d, d, dt=jnp.float32),
                      arr(b, S, H, d))
     # the rule's own tile: what the calls are built with on the chip
@@ -203,5 +206,7 @@ def test_kda_kernels_compile_at_the_cells_geometry(one_chip, which):
     finally:
         kd.default_interpret = default
     assert (tokens, heads) == (512, 2) and vmem <= fa.VMEM_SCOPED_DEFAULT
-    assert compiled.as_text().count("tpu_custom_call") == 1
-    assert " while(" not in compiled.as_text()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and " while(" not in text
+    assert f"f32[{b},{H},{d},{S}]" not in text
+    assert f"f32[{b},{S},{H},{d}]" not in text
